@@ -1,4 +1,5 @@
-"""Headline benchmark: end-to-end engine decode throughput on real hardware.
+"""Engine decode throughput on the chip (no BENCHMARK.json cells yet — this
+is the pre-cell headline script; ROADMAP queue 3 item 1 replaces it).
 
 Runs the full native serving path — scheduler, paged KV manager, jitted
 forward+sampling steps, token streaming — on the flagship architecture
@@ -14,6 +15,11 @@ against our own recorded target of 1.0 until absolute reference numbers
 exist.
 
 Env knobs: BENCH_MODEL, BENCH_LAYERS, BENCH_REQUESTS, BENCH_ISL, BENCH_OSL.
+
+Every JSON line names the device it ran on (``device``: platform,
+device_kind, count).  There is no fallback: on a CPU backend the script
+exits non-zero unless ``--cpu-smoke`` ASKS for the tiny CPU configuration,
+whose metric name says so and whose device metrics (MFU) are null.
 """
 
 from __future__ import annotations
@@ -26,13 +32,57 @@ import time
 
 import jax
 
+CPU_SMOKE = "--cpu-smoke" in sys.argv[1:]
+
+# Published per-chip peaks, keyed by jax's ``device_kind``.  Source: Google
+# Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 393 TOP/s int8, 819 GB/s
+# HBM bandwidth, 16 GB HBM).  A device that is not here is an error.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {
+        "bf16_flops": 197e12, "int8_ops": 393e12,
+        "hbm_bytes_per_s": 819e9, "hbm_bytes": 16e9,
+    },
+}
+
+
+def device_info() -> dict:
+    d = jax.devices()
+    return {
+        "platform": d[0].platform,
+        "device_kind": d[0].device_kind,
+        "count": len(d),
+    }
+
+
+def device_peaks() -> dict:
+    kind = jax.devices()[0].device_kind
+    if kind not in DEVICE_PEAKS:
+        raise SystemExit(
+            f"bench: no published peaks for device_kind {kind!r} "
+            f"(known: {sorted(DEVICE_PEAKS)}); add it to DEVICE_PEAKS with "
+            "its source"
+        )
+    return DEVICE_PEAKS[kind]
+
+
+def emit(result: dict) -> None:
+    """The ONE stdout JSON line, naming the device it was measured on."""
+    result["device"] = device_info()
+    if CPU_SMOKE:
+        result["metric"] += "_cpu_smoke"
+    print(json.dumps(result))
+
 
 def _engine_config():
     from dynamo_tpu.engine.config import EngineConfig
 
-    backend = jax.default_backend()
-    if backend == "cpu" and not os.environ.get("BENCH_MODEL"):
-        # CI / no-accelerator fallback: tiny model, same code path.
+    if jax.default_backend() == "cpu":
+        if not CPU_SMOKE:
+            raise SystemExit(
+                "bench: JAX found no accelerator (backend cpu).  This "
+                "benchmark measures the chip; `--cpu-smoke` runs the tiny "
+                "CPU configuration, labelled as such."
+            )
         return (
             EngineConfig(
                 model="debug-tiny",
@@ -49,9 +99,8 @@ def _engine_config():
     layers = int(os.environ.get("BENCH_LAYERS", "0"))
     isl = int(os.environ.get("BENCH_ISL", "128"))
     osl = int(os.environ.get("BENCH_OSL", "64"))
-    # Decode is weights-bound, so tok/s scales nearly linearly with batch:
-    # measured 988/1710/3119/4717/6705 tok/s at 16/32/64/128/256 rows (512
-    # OOMs at 18 layers) — round-4 scaling table in benchmarks/RESULTS.md.
+    # Decode is weights-bound, so tok/s should scale nearly linearly with
+    # batch (the scaling itself: not measured on this machine).
     max_batch = int(os.environ.get("BENCH_MAX_BATCH", "256"))
     max_model_len = max(256, 1 << (isl + osl + 16 - 1).bit_length())
     # Tight KV budgeting for large batches: the pool is num_blocks ~
@@ -78,10 +127,8 @@ def _engine_config():
         # the window tight to the workload (power-of-two padded).
         max_model_len=max_model_len,
         prefill_chunk=512,
-        # 8-step fused chunks with an 8-deep pipeline measured fastest at
-        # full depth (r5 sweep: 27.7 ms/step vs 32.6 at 32-step chunks —
-        # shorter scans schedule better; the deep pipeline keeps the chip
-        # busy across chunk boundaries).
+        # 8-step fused chunks with an 8-deep pipeline: an earlier round's
+        # choice whose sweep is gone; not measured on this machine.
         decode_steps=int(os.environ.get("BENCH_DECODE_STEPS", "8")),
         pipeline_depth=int(os.environ.get("BENCH_PIPELINE_DEPTH", "8")),
         weight_quant=quant,
@@ -242,28 +289,26 @@ def _spec_bench(cfg, model_cfg) -> None:
     print("bench[spec]: token streams identical on/off", file=sys.stderr)
     rep = results[("repetitive", "on")] / results[("repetitive", "off")]
     rnd = results[("random", "on")] / results[("random", "off")]
-    print(
-        json.dumps(
-            {
-                "metric": "spec_decode_speedup_repetitive",
-                "value": round(rep, 3),
-                "unit": "x",
-                "vs_baseline": round(rep, 3),
-                "random_ratio": round(rnd, 3),
-                "repetitive_tok_s": {
-                    "off": round(results[("repetitive", "off")], 2),
-                    "on": round(results[("repetitive", "on")], 2),
-                },
-                "random_tok_s": {
-                    "off": round(results[("random", "off")], 2),
-                    "on": round(results[("random", "on")], 2),
-                },
-                "acceptance_rate": round(results[("acceptance", "on")], 4),
-                "tokens_per_dispatch": round(
-                    results[("tok_per_dispatch", "on")], 2
-                ),
-            }
-        )
+    emit(
+        {
+            "metric": "spec_decode_speedup_repetitive",
+            "value": round(rep, 3),
+            "unit": "x",
+            "vs_baseline": round(rep, 3),
+            "random_ratio": round(rnd, 3),
+            "repetitive_tok_s": {
+                "off": round(results[("repetitive", "off")], 2),
+                "on": round(results[("repetitive", "on")], 2),
+            },
+            "random_tok_s": {
+                "off": round(results[("random", "off")], 2),
+                "on": round(results[("random", "on")], 2),
+            },
+            "acceptance_rate": round(results[("acceptance", "on")], 4),
+            "tokens_per_dispatch": round(
+                results[("tok_per_dispatch", "on")], 2
+            ),
+        }
     )
 
 
@@ -371,44 +416,42 @@ def _churn_bench(cfg, model_cfg) -> None:
             f"host_gap={pipe['host_gap_frac']}",
             file=sys.stderr,
         )
-    print(
-        json.dumps(
-            {
-                "metric": "continuous_decode_rebuilds",
-                "decode_kernel": on["decode_kernel"],
-                "value": pipe_on["rebuilds"],
-                "unit": "rebuilds",
-                "vs_baseline": round(
-                    pipe_on["rebuilds"] / max(1, pipe_off["rebuilds"]), 3
-                ),
-                "rebuilds": {
-                    "continuous": pipe_on["rebuilds"],
-                    "forced": pipe_off["rebuilds"],
-                },
-                "sessions": {
-                    "continuous": pipe_on["sessions"],
-                    "forced": pipe_off["sessions"],
-                },
-                "continuous_admissions": pipe_on["continuous_admissions"],
-                "continuous_retired": pipe_on["continuous_retired"],
-                "host_gap_frac": pipe_on["host_gap_frac"],
-                "compile_counts_stable": bool(
-                    on["compiles_stable"] and off["compiles_stable"]
-                ),
-                "dispatch": {
-                    k: {
-                        "dispatches": v["dispatches"],
-                        "p50_ms": v["p50_ms"],
-                        "p99_ms": v["p99_ms"],
-                    }
-                    for k, v in on["summary"]["kinds"].items()
-                },
-                "tok_s": {
-                    "continuous": round(on["tok_s"], 2),
-                    "forced": round(off["tok_s"], 2),
-                },
-            }
-        )
+    emit(
+        {
+            "metric": "continuous_decode_rebuilds",
+            "decode_kernel": on["decode_kernel"],
+            "value": pipe_on["rebuilds"],
+            "unit": "rebuilds",
+            "vs_baseline": round(
+                pipe_on["rebuilds"] / max(1, pipe_off["rebuilds"]), 3
+            ),
+            "rebuilds": {
+                "continuous": pipe_on["rebuilds"],
+                "forced": pipe_off["rebuilds"],
+            },
+            "sessions": {
+                "continuous": pipe_on["sessions"],
+                "forced": pipe_off["sessions"],
+            },
+            "continuous_admissions": pipe_on["continuous_admissions"],
+            "continuous_retired": pipe_on["continuous_retired"],
+            "host_gap_frac": pipe_on["host_gap_frac"],
+            "compile_counts_stable": bool(
+                on["compiles_stable"] and off["compiles_stable"]
+            ),
+            "dispatch": {
+                k: {
+                    "dispatches": v["dispatches"],
+                    "p50_ms": v["p50_ms"],
+                    "p99_ms": v["p99_ms"],
+                }
+                for k, v in on["summary"]["kinds"].items()
+            },
+            "tok_s": {
+                "continuous": round(on["tok_s"], 2),
+                "forced": round(off["tok_s"], 2),
+            },
+        }
     )
 
 
@@ -588,24 +631,22 @@ def _prefix_bench(cfg, model_cfg) -> None:
             "control — the exact-stream equivalence invariant is broken"
         )
     print("bench[prefix]: streams identical across all modes", file=sys.stderr)
-    print(
-        json.dumps(
-            {
-                "metric": "prefix_reuse_skip_frac",
-                "value": results["host"]["skip_frac"],
-                "unit": "frac",
-                "vs_baseline": 0.0,
-                "modes": {
-                    m: {k: v for k, v in r.items() if k != "streams"}
-                    for m, r in results.items()
-                },
-                "identical": identical,
-                "compile_stable": all(
-                    r["compile_stable"] for r in results.values()
-                ),
-                "pull_served_blocks": results["pull"]["pulled_blocks"],
-            }
-        )
+    emit(
+        {
+            "metric": "prefix_reuse_skip_frac",
+            "value": results["host"]["skip_frac"],
+            "unit": "frac",
+            "vs_baseline": 0.0,
+            "modes": {
+                m: {k: v for k, v in r.items() if k != "streams"}
+                for m, r in results.items()
+            },
+            "identical": identical,
+            "compile_stable": all(
+                r["compile_stable"] for r in results.values()
+            ),
+            "pull_served_blocks": results["pull"]["pulled_blocks"],
+        }
     )
 
 
@@ -620,10 +661,7 @@ def main() -> None:
         # bf16 fallback: fit single-chip HBM by truncating depth
         # (~0.5 GB/layer bf16 + embed/head ~1 GB + KV).  The int8 default
         # runs FULL depth — no truncation.
-        try:
-            mem = jax.devices()[0].memory_stats().get("bytes_limit", 16 << 30)
-        except Exception:
-            mem = 16 << 30
+        mem = jax.devices()[0].memory_stats()["bytes_limit"]
         layers = max(2, min(32, int((mem * 0.7 - (2 << 30)) / (520 << 20))))
     if layers and layers != model_cfg.num_layers:
         get_config(cfg.model)  # ensure registered
@@ -669,15 +707,13 @@ def main() -> None:
         f"in {cold_s:.1f}s",
         file=sys.stderr,
     )
-    try:
-        ms = jax.devices()[0].memory_stats()
+    ms = jax.devices()[0].memory_stats()  # None on a CPU device
+    if ms:
         print(
-            f"bench: device memory {ms.get('bytes_in_use', 0)/2**30:.2f} GiB"
-            f" in use / {ms.get('bytes_limit', 0)/2**30:.2f} GiB limit",
+            f"bench: device memory {ms['bytes_in_use']/2**30:.2f} GiB"
+            f" in use / {ms['bytes_limit']/2**30:.2f} GiB limit",
             file=sys.stderr,
         )
-    except Exception:
-        pass
     if os.environ.get("BENCH_WARM_CHECK"):
         # Persistent-compilation-cache diagnostic (instead of the throughput
         # bench): a SECOND engine — fresh jit closures, as a restarted
@@ -700,15 +736,13 @@ def main() -> None:
             f"(first start {cold_s:.1f}s, persistent XLA cache)",
             file=sys.stderr,
         )
-        print(
-            json.dumps(
-                {
-                    "metric": "warm_restart_warmup_s",
-                    "value": round(warm_s, 1),
-                    "unit": "s",
-                    "vs_baseline": round(cold_s / warm_s, 2) if warm_s else 0.0,
-                }
-            )
+        emit(
+            {
+                "metric": "warm_restart_warmup_s",
+                "value": round(warm_s, 1),
+                "unit": "s",
+                "vs_baseline": round(cold_s / warm_s, 2) if warm_s else 0.0,
+            }
         )
         return
 
@@ -752,21 +786,23 @@ def main() -> None:
             f"({100 * (dt - device_s) / dt:.0f}%)",
             file=sys.stderr,
         )
-        # Decode MFU: 2 * params * tokens / (wall * peak_flops); v5e bf16
-        # peak ~197 TFLOP/s.  Rough param count from config.
+        # End-to-end decode utilisation: 2 * params * tokens / (wall *
+        # peak), prefill inside the window — not a kernel's roofline share.
+        # Peak: the rate the matmuls run at (int8 under weight_quant).
+        # null on a CPU smoke: a CPU run has no device metric.
         c = model_cfg
         p_layer = c.hidden_size * (c.q_size + 2 * c.kv_size + c.q_size) + (
             3 * c.hidden_size * c.intermediate_size
         )
         n_params = c.num_layers * p_layer + 2 * c.vocab_size * c.hidden_size
-        mfu = 2 * n_params * total / (dt * 197e12)
-        note = ""
-        if cfg.weight_quant:
-            # int8 MACs run on the 2x-rate MXU path; the bf16-peak number
-            # stays the headline for cross-round comparability.
-            note = f" (vs int8 peak 394T: {mfu * 197 / 394 * 100:.2f}%)"
+        peak = None
+        if not CPU_SMOKE:
+            peaks = device_peaks()
+            peak = peaks["int8_ops" if cfg.weight_quant else "bf16_flops"]
+        mfu = 2 * n_params * total / (dt * peak) if peak else None
         print(
-            f"bench: ~{n_params/1e9:.2f}B params, decode MFU {mfu*100:.2f}%{note}",
+            f"bench: ~{n_params/1e9:.2f}B params, decode utilisation "
+            + (f"{mfu*100:.2f}% of {peak/1e12:.0f}T" if peak else "not measured"),
             file=sys.stderr,
         )
         # Attention-time share (analytic HBM-byte attribution): decode is
@@ -807,10 +843,13 @@ def main() -> None:
         pf_wall = pf.get("wall_s", 0.0)
         pf_tokens = pf.get("prompt_tokens", 0)
         pf_mfu = (
-            2 * n_params * pf_tokens / (pf_wall * 197e12) if pf_wall else 0.0
+            2 * n_params * pf_tokens / (pf_wall * peak)
+            if peak and pf_wall else None
         )
         print(
-            f"bench: prefill MFU {pf_mfu*100:.2f}% ({pf_tokens} prompt "
+            "bench: prefill utilisation "
+            + (f"{pf_mfu*100:.2f}%" if pf_mfu is not None else "not measured")
+            + f" ({pf_tokens} prompt "
             f"tokens over {pf.get('chunks', 0)} chunks in {pf_wall:.2f}s, "
             f"chunk p50 {pf.get('p50_ms', 0.0)}ms p99 {pf.get('p99_ms', 0.0)}"
             f"ms, kernel={dispatch.get('prefill_kernel')})",
@@ -820,9 +859,9 @@ def main() -> None:
         # parseable and the ROADMAP quoted MFU/host-gap by hand from stderr.
         extras.update(
             {
-                "decode_mfu": round(mfu, 4),
+                "decode_mfu": None if mfu is None else round(mfu, 4),
                 "decode_kernel": dispatch.get("decode_kernel"),
-                "prefill_mfu": round(pf_mfu, 4),
+                "prefill_mfu": None if pf_mfu is None else round(pf_mfu, 4),
                 "prefill_kernel": dispatch.get("prefill_kernel"),
                 "prefill": pf,
                 "attention": {
@@ -847,37 +886,18 @@ def main() -> None:
         return total / dt
 
     tps = asyncio.run(bench())
-    # vs_baseline tracks the trend against the round-4 headline (8040.16
-    # tok/s, BENCH_r04.json — the driver-captured number of record).  r4 ran
-    # 18 of 32 layers (bf16 could not fit full depth); this default runs the
-    # FULL 32-layer model under int8 weight quantization — that change IS
-    # the round-5 claim (VERDICT r4 next #1: end truncated-geometry
-    # headlines).  Any BENCH_* override benchmarks something else and must
-    # not claim the trend line.
-    default_workload = not any(k.startswith("BENCH_") for k in os.environ)
-    default_prior = (
-        "8040.16" if jax.default_backend() != "cpu" and default_workload else "0"
-    )
-    prior = float(os.environ.get("BENCH_PRIOR_TPS", default_prior))
-    if prior > 0 and default_workload and model_cfg.num_layers != 18:
-        # Only for the DEFAULT workload, where the prior is known to be
-        # r4's 18-layer number (a BENCH_PRIOR_TPS override may be measured
-        # at any depth — normalizing it by 18 would fabricate a trend).
-        norm = (tps * model_cfg.num_layers) / (prior * 18)
-        print(
-            f"bench: per-layer-normalized vs r4 prior (18L): {norm:.2f}x",
-            file=sys.stderr,
-        )
-    print(
-        json.dumps(
-            {
-                "metric": "engine_output_tokens_per_sec",
-                "value": round(tps, 2),
-                "unit": "tokens/s",
-                "vs_baseline": round(tps / prior, 3) if prior > 0 else 1.0,
-                **extras,
-            }
-        )
+    # vs_baseline: against BENCH_PRIOR_TPS when the caller supplies a prior
+    # measured on the same device and workload; none is built in (default 0
+    # → 1.0): no earlier number was measured on this machine.
+    prior = float(os.environ.get("BENCH_PRIOR_TPS", "0"))
+    emit(
+        {
+            "metric": "engine_output_tokens_per_sec",
+            "value": round(tps, 2),
+            "unit": "tokens/s",
+            "vs_baseline": round(tps / prior, 3) if prior > 0 else 1.0,
+            **extras,
+        }
     )
 
 

@@ -1,0 +1,903 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that dynamo-tpu still starts on the chip.
+
+Default run (one TPU v5e chip; what the driver runs):
+
+1. kernel parity ON THE CHIP, in a child process that exits before the server
+   starts: every attention kernel ``auto`` resolves to on a TPU (fused decode,
+   chunked prefill; int8 and bf16 pages; qwen2.5-7b head geometry; a traced
+   ``kv_scale``) against the ``xla`` oracle of ops/ragged_attention.py;
+2. serve: ``python -m dynamo_tpu.cli run in=http out=tpu --arch qwen2.5-7b`` at
+   full published width and depth (28 layers), int8 weights, int8 KV pages,
+   seeded random weights, and a handful of requests over HTTP;
+3. what the server says about itself (``/metrics`` + its start-up line);
+4. stop the server, check it exited cleanly, print the contract's last line.
+
+``--chips 4`` (the builder runs it; the driver has one chip) runs ONLY the
+sharded path and what it is compared with: llama-3.1-8b int8/int8 full depth
+at tp=1 on one chip (a child that exits), then tp=4 over four chips in one
+process, compared on logprobs; then README's own bf16 ``--tp 4`` CLI line
+answers one request.
+
+``--rehearse-cpu`` walks the same control flow at debug-tiny size on the CPU
+backend (Pallas interpreter) — a rehearsal, never a result: its last line
+says ``"ok": false``.
+
+The parent process never imports JAX: a chip belongs to one process at a
+time, and every phase that needs it runs in a child that exits.  Every phase
+prints one JSON line with its seconds; any failure ends the run non-zero.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import re
+import signal
+import socket
+import subprocess
+import sys
+import threading
+import time
+import urllib.error
+import urllib.request
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+OUT = os.path.join(HERE, "chiprun_out", "chip_smoke")  # git-ignored
+
+# --------------------------------------------------------------- tolerances
+# Kernel parity: |kernel - oracle| / max|oracle| per case.  Both sides
+# accumulate in f32 and round the result to bf16 (2^-8 relative = 0.4%); the
+# kernel and the oracle order the online-softmax sums differently, and the
+# chip's f32 dots may run as bf16 passes.  The oracle runs under
+# default_matmul_precision("highest") so the bound measures the KERNEL.
+PARITY_TOL = 2e-2
+# tp=4 vs tp=1 logprobs (absolute, nats), on the top-5 tokens both runs name
+# at a position both runs reached with the same tokens (always the prompt's
+# last position).  The sharding rules themselves are exact: on the CPU
+# backend tp=4 equals tp=1 BIT FOR BIT at 8 layers of llama-3.1-8b width, bf16
+# activations, int8/int8 (PR 21).  On the chip the two programs are compiled
+# differently (tp=1 fuses wqkv/w_gateup; XLA:TPU picks other fusions and
+# summation orders), which seeds 1-ulp differences — and a W8A8 pipeline
+# amplifies a seed: one flipped bf16 rounding moves int8 activation roundings
+# (1 LSB = 0.8% of the row max) in the next matmul, until the difference sits
+# at the quantisation-noise floor, which then grows like sqrt(depth).
+# Measured on four v5e chips (PR 21): max 0.123 / median 0.035 nats at one
+# layer (all 32 greedy tokens agreeing), max 0.637 / median 0.213 at 32
+# layers — a ratio of 5.2 against sqrt(32) = 5.7 — where the top logprobs sit
+# 0.1 apart, yet every top-5 pair still overlapped.  So:
+# - SHALLOW (one layer, full width: every sharded op kind once, little depth
+#   to amplify in): a tight bound, twice the measured maximum;
+# - FULL DEPTH (32 layers): the top-5 sets must overlap at every position (a
+#   wrong shard — heads permuted, a scale misplaced — decorrelates the logits
+#   and two top-5 sets out of 128k tokens are then disjoint), and the
+#   difference on the common tokens stays within a few noise floors.
+TP_SHALLOW_TOL = 0.25
+TP_FULL_TOL = 1.5
+# Per-device peak over the per-device share of weights + KV pages: what
+# "activations" may add under tp=4 (step temporaries, sampler buffers).  The
+# sharded initialiser itself needs no temporaries: its memory_analysis()
+# for a described v5e:2x2 says 0.00 GB per device (PR 21).
+TP_PEAK_ALLOWANCE = 1 << 30
+
+SERVE = {  # the one-chip deployment
+    "arch": "qwen2.5-7b", "layers": 28, "dtype": "bfloat16",
+    "block_size": 16, "num_blocks": 12288, "max_model_len": 4096,
+    "max_batch": 8, "prefill_chunk": 1024, "decode_steps": 4,
+    "short_prompt": 511, "long_prompt": 3000, "max_tokens": 64,
+}
+SERVE_REHEARSAL = {
+    "arch": "debug-tiny", "layers": 2, "dtype": "float32",
+    "block_size": 16, "num_blocks": 256, "max_model_len": 512,
+    "max_batch": 8, "prefill_chunk": 128, "decode_steps": 4,
+    "short_prompt": 63, "long_prompt": 300, "max_tokens": 16,
+}
+TP = {  # the four-chip comparison
+    "model": "llama-3.1-8b", "block_size": 16, "num_blocks": 4096,
+    "max_batch": 8, "max_model_len": 1024, "prefill_chunk": 256,
+    "dtype": "bfloat16", "prompt_len": 200, "max_tokens": 4, "prompts": 8,
+    "shallow_layers": 1,
+}
+TP_REHEARSAL = dict(  # debug-tiny widened to 4 KV heads (see _tp_engine)
+    TP, model="debug-tiny-kv4", num_blocks=128, max_model_len=256,
+    prefill_chunk=64, dtype="float32", prompt_len=40, shallow_layers=1,
+)
+
+
+def emit(phase: str, t0: float, **fields) -> None:
+    print(
+        json.dumps({"phase": phase, "seconds": round(time.time() - t0, 2), **fields}),
+        flush=True,
+    )
+
+
+def fail(msg: str) -> "NoReturn":  # noqa: F821
+    print(f"chip_smoke.py: FAILED: {msg}", file=sys.stderr, flush=True)
+    sys.exit(1)
+
+
+# ====================================================================== parent
+def child_env(rehearse: bool, devices: int = 1) -> dict:
+    env = dict(os.environ)
+    env["PYTHONPATH"] = HERE + os.pathsep + env.get("PYTHONPATH", "")
+    env.setdefault("TPU_LOG_DIR", "disabled")
+    if rehearse:
+        # The CPU rehearsal is ASKED for, here and nowhere else.
+        env["JAX_PLATFORMS"] = "cpu"
+        env["DYN_PALLAS_INTERPRET"] = "1"
+        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    return env
+
+
+def run_child(name: str, rehearse: bool, *extra: str, devices: int = 1,
+              must: bool = True):
+    """Run one JAX-touching phase in a child; its LAST stdout line is its
+    JSON result.  A non-zero exit ends the run (``must=False``: returns
+    None instead, for a caller that has more to show before it fails)."""
+    cmd = [sys.executable, os.path.abspath(__file__), "--child", name, *extra]
+    if rehearse:
+        cmd.append("--rehearse-cpu")
+    p = subprocess.run(
+        cmd, env=child_env(rehearse, devices), cwd=HERE,
+        stdout=subprocess.PIPE, text=True,
+    )
+    lines = [l for l in p.stdout.splitlines() if l.strip()]
+    for l in lines[:-1]:
+        print(l, flush=True)
+    if p.returncode != 0:
+        for l in lines[-1:]:
+            print(l, flush=True)
+        if not must:
+            return None
+        fail(f"phase {name!r} exited {p.returncode}")
+    return json.loads(lines[-1])
+
+
+def free_port() -> int:
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
+def http(port: int, path: str, body=None, timeout: float = 600.0):
+    req = urllib.request.Request(
+        f"http://127.0.0.1:{port}{path}",
+        data=None if body is None else json.dumps(body).encode(),
+        headers={"content-type": "application/json"},
+    )
+    return urllib.request.urlopen(req, timeout=timeout)
+
+
+def complete(port: int, model: str, prompt: str, max_tokens: int, *,
+             stream: bool = False, temperature: float = 0.0, seed=None) -> dict:
+    """One /v1/completions call → {status, text, completion_tokens}."""
+    body = {
+        "model": model, "prompt": prompt, "max_tokens": max_tokens,
+        "temperature": temperature, "stream": stream,
+        "nvext": {"ignore_eos": True},
+    }
+    if seed is not None:
+        body["seed"] = seed
+    with http(port, "/v1/completions", body) as r:
+        status = r.status
+        if not stream:
+            d = json.loads(r.read())
+            return {
+                "status": status,
+                "text": d["choices"][0]["text"],
+                "completion_tokens": d["usage"]["completion_tokens"],
+            }
+        text, usage = [], None
+        for raw in r:
+            line = raw.decode().strip()
+            if not line.startswith("data:"):
+                continue
+            data = line[5:].strip()
+            if data == "[DONE]":
+                break
+            chunk = json.loads(data)
+            for c in chunk.get("choices", []):
+                text.append(c.get("text") or "")
+            usage = chunk.get("usage") or usage
+        return {
+            "status": status,
+            "text": "".join(text),
+            "completion_tokens": (usage or {}).get("completion_tokens"),
+        }
+
+
+class Server:
+    """The CLI server as a child process (its log under chiprun_out/)."""
+
+    def __init__(self, argv, rehearse: bool, log_name: str, devices: int = 1):
+        os.makedirs(OUT, exist_ok=True)
+        self.port = free_port()
+        self.log_path = os.path.join(OUT, log_name)
+        self.log = open(self.log_path, "w")
+        self.proc = subprocess.Popen(
+            [sys.executable, "-m", "dynamo_tpu.cli", "run", "in=http", "out=tpu",
+             *argv, "--host", "127.0.0.1", "--port", str(self.port)],
+            env=child_env(rehearse, devices), cwd=HERE,
+            stdout=self.log, stderr=subprocess.STDOUT,
+        )
+
+    def wait_ready(self, timeout: float) -> None:
+        t_end = time.time() + timeout
+        while time.time() < t_end:
+            if self.proc.poll() is not None:
+                self.dump()
+                fail(f"server exited {self.proc.returncode} before readiness")
+            try:
+                with http(self.port, "/health", timeout=2.0) as r:
+                    if r.status == 200:
+                        return
+            except (urllib.error.URLError, OSError):
+                time.sleep(1.0)
+        self.kill()
+        self.dump()
+        fail(f"server not ready after {timeout:.0f}s")
+
+    def engine_line(self) -> dict:
+        with open(self.log_path) as f:
+            for line in f:
+                if line.startswith("engine {"):
+                    return json.loads(line[len("engine "):])
+        fail("server log has no 'engine {...}' line")
+
+    def metrics(self) -> str:
+        with http(self.port, "/metrics", timeout=60.0) as r:
+            return r.read().decode()
+
+    def stop(self) -> int:
+        self.proc.send_signal(signal.SIGTERM)
+        try:
+            rc = self.proc.wait(timeout=120)
+        except subprocess.TimeoutExpired:
+            self.kill()
+            fail("server did not exit within 120s of SIGTERM")
+        self.log.close()
+        return rc
+
+    def kill(self) -> None:
+        if self.proc.poll() is None:
+            self.proc.kill()
+            self.proc.wait()
+
+    def dump(self, n: int = 60) -> None:
+        self.log.flush()
+        with open(self.log_path) as f:
+            tail = f.readlines()[-n:]
+        sys.stderr.write("".join(tail))
+
+
+def gauge(text: str, name: str, labels: str = "") -> float:
+    m = re.search(
+        rf"^{re.escape(name)}(?:\{{[^}}]*{re.escape(labels)}[^}}]*\}})? (\S+)$",
+        text, re.M,
+    )
+    if m is None:
+        fail(f"/metrics has no {name} {labels}")
+    return float(m.group(1))
+
+
+def info_labels(text: str, name: str) -> dict:
+    m = re.search(rf"^{re.escape(name)}\{{(.*)\}} 1$", text, re.M)
+    if m is None:
+        fail(f"/metrics has no {name}")
+    return dict(re.findall(r'(\w+)="((?:[^"\\]|\\.)*)"', m.group(1)))
+
+
+def compiled_programs(text: str) -> dict:
+    return {
+        k: int(float(v))
+        for k, v in re.findall(
+            r'^dynamo_tpu_engine_compiled_programs\{fn="(\w+)"\} (\S+)$', text, re.M
+        )
+    }
+
+
+def build_native() -> None:
+    """native/build/ is git-ignored: build the hasher here, from source
+    (make rebuilds when a source is newer), never use one as found."""
+    t0 = time.time()
+    p = subprocess.run(
+        ["make", "-C", os.path.join(HERE, "native")],
+        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
+    )
+    if p.returncode != 0:
+        sys.stderr.write(p.stdout)
+        fail("make -C native failed")
+    emit("native_build", t0, lib="native/build/libdyn_native.so")
+
+
+def prompt_of(n: int, tag: str) -> str:
+    """n ASCII chars (the byte tokenizer: n tokens + BOS), distinct per tag
+    from the first character on so requests share no cached prefix."""
+    words = f"{tag} the quick brown fox jumps over the lazy dog; "
+    return (words * (n // len(words) + 1))[:n]
+
+
+def phase_serve(rehearse: bool) -> dict:
+    cfg = SERVE_REHEARSAL if rehearse else SERVE
+    model = cfg["arch"]
+    argv = [
+        "--arch", cfg["arch"], "--model", model, "--dtype", cfg["dtype"],
+        "--weight-quant", "int8", "--kv-cache-dtype", "int8", "--kv-scale", "auto",
+        "--max-model-len", str(cfg["max_model_len"]),
+        "--num-blocks", str(cfg["num_blocks"]), "--block-size", str(cfg["block_size"]),
+        "--max-batch", str(cfg["max_batch"]), "--prefill-chunk", str(cfg["prefill_chunk"]),
+        "--decode-steps", str(cfg["decode_steps"]),
+    ]
+    t0 = time.time()
+    srv = Server(argv, rehearse, "server.log")
+    try:
+        # Cold, ready came after 651-662 s on the chip (PR 21); the whole
+        # smoke has 1200 s, of which parity takes about 55 and the
+        # requests about 10.
+        srv.wait_ready(1050.0)
+        emit("serve_ready", t0, argv=" ".join(argv))
+        before = srv.metrics()
+
+        # --- four concurrent ~512-token prompts, 64 tokens out, 2 streamed
+        t1 = time.time()
+        n_out = cfg["max_tokens"]
+        results = [None] * 4
+
+        def one(i: int) -> None:
+            results[i] = complete(
+                srv.port, model, prompt_of(cfg["short_prompt"], f"req{i}"),
+                n_out, stream=(i % 2 == 0),
+            )
+
+        threads = [threading.Thread(target=one, args=(i,)) for i in range(4)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        for i, r in enumerate(results):
+            if r is None or r["status"] != 200 or r["completion_tokens"] != n_out:
+                fail(f"concurrent request {i}: {r}")
+        emit("serve_concurrent", t1, requests=4, streamed=2,
+             prompt_tokens=cfg["short_prompt"] + 1, tokens_each=n_out)
+
+        # --- one ~3000-token prompt twice at temperature 0: several prefill
+        # chunks against a growing paged prefix; the repeat must be
+        # byte-identical and served from the prefix cache.
+        t1 = time.time()
+        long_p = prompt_of(cfg["long_prompt"], "long")
+        hit0 = gauge(srv.metrics(), "dynamo_tpu_kv_tier_prefix_hit_rate")
+        a = complete(srv.port, model, long_p, n_out)
+        b = complete(srv.port, model, long_p, n_out)
+        hit1 = gauge(srv.metrics(), "dynamo_tpu_kv_tier_prefix_hit_rate")
+        for r in (a, b):
+            if r["status"] != 200 or r["completion_tokens"] != n_out:
+                fail(f"long request: {r}")
+        if a["text"] != b["text"]:
+            fail("long prompt repeated at temperature 0 is not byte-identical")
+        if not hit1 > hit0:
+            fail(f"no prefix-cache hit on the repeat: hit rate {hit0} -> {hit1}")
+        emit("serve_long_prompt", t1, prompt_tokens=cfg["long_prompt"] + 1,
+             prefill_chunks=-(-(cfg["long_prompt"] + 1) // cfg["prefill_chunk"]),
+             byte_identical=True, prefix_hit_rate=[hit0, hit1])
+
+        # --- one seeded sampled request, repeated with the same seed
+        t1 = time.time()
+        sp = prompt_of(cfg["short_prompt"], "seeded")
+        c = complete(srv.port, model, sp, n_out, temperature=0.8, seed=1234)
+        d = complete(srv.port, model, sp, n_out, temperature=0.8, seed=1234)
+        for r in (c, d):
+            if r["status"] != 200 or r["completion_tokens"] != n_out:
+                fail(f"seeded request: {r}")
+        if c["text"] != d["text"]:
+            fail("seeded sampled request is not reproducible")
+        emit("serve_seeded", t1, reproducible=True)
+        requests_wall = time.time() - t0
+
+        # --- phase 3: what the server says about itself
+        t1 = time.time()
+        after = srv.metrics()
+        info = info_labels(after, "dynamo_tpu_engine_info")
+        line = srv.engine_line()
+        dk = info_labels(after, "dynamo_tpu_engine_dispatch_decode_kernel_info")["kernel"]
+        pk = info_labels(after, "dynamo_tpu_engine_dispatch_prefill_kernel_info")["kernel"]
+        cc0, cc1 = compiled_programs(before), compiled_programs(after)
+        report = {
+            "jax": info["jax"], "libtpu": info["libtpu"],
+            "platform": info["platform"], "device_kind": info["device_kind"],
+            "device_count": int(info["device_count"]),
+            "model": info["model"], "num_layers": int(info["num_layers"]),
+            "weight_quant": info["weight_quant"], "cache_dtype": info["cache_dtype"],
+            "attn_impl": info["attn_impl"], "decode_kernel": dk, "prefill_kernel": pk,
+            "hasher": info["hasher"],
+            "warmup_seconds": gauge(after, "dynamo_tpu_engine_warmup_seconds"),
+            "compiled_programs_before": cc0, "compiled_programs_after": cc1,
+            "compile_cache_dir": info["compile_cache_dir"],
+            "compile_cache_entries": int(gauge(after, "dynamo_tpu_engine_compile_cache_entries")),
+            "compile_cache_hits": int(gauge(after, "dynamo_tpu_engine_compile_cache_hits")),
+            "compile_cache_misses": int(gauge(after, "dynamo_tpu_engine_compile_cache_misses")),
+            "hbm_bytes_in_use": int(gauge(after, "dynamo_tpu_engine_hbm_bytes_in_use", 'device="0"')),
+            "hbm_bytes_limit": int(gauge(after, "dynamo_tpu_engine_hbm_bytes_limit", 'device="0"')),
+            "tokens_generated": 8 * n_out,
+            "prompt_tokens_computed": int(gauge(after, "dynamo_tpu_prefill_tokens_total")),
+            "wall_seconds_since_start": round(requests_wall, 2),
+        }
+        emit("server_report", t1, **report)
+        want = (
+            {"platform": "cpu", "attn_impl": "xla", "decode_kernel": "stock",
+             "prefill_kernel": "stock"}
+            if rehearse else
+            # What `auto` resolves to on a TPU (ops/ragged_attention.py).
+            {"platform": "tpu", "attn_impl": "tpu", "decode_kernel": "pallas_fused",
+             "prefill_kernel": "pallas"}
+        )
+        for k, v in want.items():
+            if report[k] != v:
+                fail(f"server reports {k}={report[k]!r}, expected {v!r}")
+        if (report["num_layers"], report["weight_quant"], report["cache_dtype"]) != (
+            cfg["layers"], "int8", "int8"
+        ):
+            fail(f"server is not the full-depth int8/int8 model: {report}")
+        if line["device_kind"] != report["device_kind"]:
+            fail("start-up line and /metrics disagree on device_kind")
+        if cc0 != cc1 or not cc0 or min(cc0.values()) < 0:
+            fail(f"programs compiled after warmup: {cc0} -> {cc1}")
+        if report["warmup_seconds"] <= 0:
+            fail("server did not warm up before serving")
+        if report["hasher"] != "native":
+            fail("the Python hasher served although native/ was built")
+    except BaseException:
+        srv.kill()
+        raise
+    # --- phase 4: stop, exited cleanly
+    t1 = time.time()
+    rc = srv.stop()
+    if rc != 0:
+        srv.dump()
+        fail(f"server exited {rc} on SIGTERM")
+    emit("serve_stopped", t1, exit_code=rc)
+    return report
+
+
+def run_one_chip(rehearse: bool) -> dict:
+    build_native()
+    dev = run_child("parity", rehearse)
+    report = phase_serve(rehearse)
+    for k_dev, k_rep in (("platform", "platform"), ("kind", "device_kind"),
+                         ("count", "device_count")):
+        if dev[k_dev] != report[k_rep]:
+            fail(f"parity child and server saw different devices: {dev} / {report}")
+    return dev
+
+
+def run_four_chips(rehearse: bool) -> dict:
+    build_native()
+    os.makedirs(OUT, exist_ok=True)
+    ref_path = os.path.join(OUT, "tp1_reference.json")
+    run_child("tp1", rehearse, "--ref", ref_path, devices=4)
+    # The README phase runs even when the comparison failed (its lines are
+    # worth the chips already held); the run still ends non-zero.
+    dev = run_child("tp4", rehearse, "--ref", ref_path, devices=4, must=False)
+    # README's own quick-start line: bf16, --tp 4, through the CLI.  With
+    # --no-warmup: four chips cost four times a second, and the request
+    # compiles the programs it needs (the one-chip run proves warmup).
+    t0 = time.time()
+    argv = ["--arch", TP["model"], "--model", "m", "--tp", "4", "--no-warmup"]
+    if rehearse:  # debug-tiny has two KV heads
+        argv = ["--arch", "debug-tiny", "--model", "m", "--tp", "2", "--no-warmup",
+                "--dtype", "float32", "--max-model-len", "256", "--num-blocks", "64"]
+    srv = Server(argv, rehearse, "server_tp4.log", devices=4)
+    try:
+        srv.wait_ready(900.0)
+        r = complete(srv.port, "m", prompt_of(100, "readme"), 8)
+        if r["status"] != 200 or r["completion_tokens"] != 8:
+            fail(f"README --tp 4 line: {r}")
+        line = srv.engine_line()
+    except BaseException:
+        srv.kill()
+        raise
+    rc = srv.stop()
+    if rc != 0:
+        srv.dump()
+        fail(f"--tp 4 server exited {rc} on SIGTERM")
+    emit("readme_tp4_cli", t0, argv=" ".join(argv), completion_tokens=8,
+         device_count=line["device_count"], weight_quant=line["weight_quant"],
+         hbm_bytes_in_use=line["hbm_bytes_in_use"], exit_code=rc)
+    if dev is None:
+        fail("phase 'tp4' failed (see above)")
+    return dev
+
+
+# ==================================================================== children
+def child_device(rehearse: bool) -> dict:
+    """First thing every child does: name the device, refuse a CPU."""
+    import jax
+
+    d = jax.devices()
+    dev = {"platform": d[0].platform, "kind": d[0].device_kind, "count": len(d)}
+    if rehearse:
+        if dev["platform"] != "cpu":
+            fail("--rehearse-cpu is for the CPU backend")
+    elif dev["platform"] != "tpu":
+        fail(
+            f"JAX found no TPU (platform={dev['platform']!r}, "
+            f"kind={dev['kind']!r}): this smoke measures the chip and does "
+            "not fall back to a CPU.  `--rehearse-cpu` walks the control "
+            "flow at debug-tiny size without claiming a result."
+        )
+    return dev
+
+
+def child_parity(rehearse: bool) -> None:
+    t0 = time.time()
+    dev = child_device(rehearse)
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dynamo_tpu.ops.decode_attention import fused_decode_attention
+    from dynamo_tpu.ops.prefill_attention import fused_prefill_attention
+    from dynamo_tpu.ops.ragged_attention import (
+        ragged_attention, ragged_decode_attention,
+        resolve_decode_kernel, resolve_prefill_kernel,
+    )
+
+    if not rehearse:
+        got = (resolve_decode_kernel("auto"), resolve_prefill_kernel("auto"))
+        if got != ("pallas_fused", "pallas"):
+            fail(f"auto resolves to {got} on this TPU backend")
+    emit("device", t0, **dev, jax=jax.__version__)
+
+    H, KV, D, ps = 28, 4, 128, 16  # qwen2.5-7b heads; the engine's page size
+    rng = np.random.default_rng(0)
+    SCALE = 0.02
+    if rehearse:  # the interpreter is slow: same geometry, short contexts
+        dec_lens, PPd, P = [1, 17, 100, 256, 33, 0, 0, 0], 16, 160
+        pre_rows, PPp = [(20, 60), (7, 7), (1, 40), (30, 100)], 8
+    else:
+        dec_lens = [1, 17, 500, 4096, 3000, 16, 2049, 777, 4095, 64, 1000, 31, 2, 0, 0, 0]
+        PPd, P = 256, 4096
+        # (q_len, kv_len): a chunk against a paged prefix, a whole short
+        # prompt, a decode row riding a mixed step, a long chunk.
+        pre_rows, PPp = [(100, 700), (37, 37), (1, 300), (118, 1000)], 64
+
+    def pages_of(dtype):
+        if jnp.dtype(dtype).itemsize == 1:
+            return jnp.asarray(rng.integers(-127, 128, (P, ps, 2 * KV, D)), dtype)
+        return jnp.asarray(rng.normal(0, 1.0, (P, ps, 2 * KV, D)), dtype)
+
+    def tables(S, PP):
+        return jnp.asarray(
+            rng.permutation(P)[: S * PP].reshape(S, PP) if S * PP <= P
+            else rng.integers(0, P, (S, PP)), jnp.int32,
+        )
+
+    def check(name, fn_kernel, fn_oracle, args, scale):
+        t1 = time.time()
+        traced = jnp.asarray(1.0 if scale is None else scale, jnp.float32)
+        compiled = jax.jit(fn_kernel).lower(*args, traced).compile()
+        if not rehearse and "tpu_custom_call" not in compiled.as_text():
+            fail(f"{name}: no tpu_custom_call in the compiled program "
+                 "(the kernel was not compiled for the chip)")
+        out = np.asarray(compiled(*args, traced), np.float32)
+        with jax.default_matmul_precision("highest"):
+            ref = np.asarray(jax.jit(fn_oracle)(*args), np.float32)
+        if out.shape != ref.shape or not np.isfinite(out).all():
+            fail(f"{name}: shape {out.shape} vs {ref.shape} / non-finite values")
+        ref_max = float(np.max(np.abs(ref)))
+        err = float(np.max(np.abs(out - ref)) / (ref_max + 1e-30))
+        emit("parity_" + name, t1, rel_err=err, tol=PARITY_TOL, ref_max=ref_max,
+             shape=list(out.shape), compiled=not rehearse)
+        if ref_max == 0.0 or not err <= PARITY_TOL:
+            fail(f"{name}: rel_err {err} > {PARITY_TOL}")
+
+    for dtype in ("int8", "bfloat16"):
+        scale = SCALE if dtype == "int8" else None
+        pages = pages_of(dtype)
+        # ---- decode: one token per row
+        S = len(dec_lens)
+        q = jnp.asarray(rng.normal(0, 1, (S, H, D)), jnp.bfloat16)
+        dargs = (q, pages, jnp.asarray(dec_lens, jnp.int32), tables(S, PPd),
+                 jnp.asarray([sum(1 for n in dec_lens if n)], jnp.int32))
+        check(
+            f"decode_{dtype}",
+            lambda q, pg, kl, pi, ns, sc: fused_decode_attention(
+                q, pg, kl, pi, ns, sm_scale=D ** -0.5,
+                kv_scale=None if scale is None else sc),
+            lambda q, pg, kl, pi, ns: ragged_decode_attention(
+                q, pg, kl, pi, ns, sm_scale=D ** -0.5, kv_scale=scale,
+                kernel="xla"),
+            dargs, scale,
+        )
+        # ---- prefill: ragged chunks against paged prefixes (+ padding)
+        S = len(pre_rows) + 2
+        qlens = [a for a, _ in pre_rows] + [0, 0]
+        T = 1 << (sum(qlens) - 1).bit_length()  # a token bucket, like the engine
+        q = jnp.asarray(rng.normal(0, 1, (T, H, D)), jnp.bfloat16)
+        pargs = (q, pages,
+                 jnp.asarray([b for _, b in pre_rows] + [0, 0], jnp.int32),
+                 tables(S, PPp),
+                 jnp.asarray(np.concatenate([[0], np.cumsum(qlens)]), jnp.int32),
+                 jnp.asarray([len(pre_rows)], jnp.int32))
+        check(
+            f"prefill_{dtype}",
+            lambda q, pg, kl, pi, cu, ns, sc: fused_prefill_attention(
+                q, pg, kl, pi, cu, ns, sm_scale=D ** -0.5,
+                kv_scale=None if scale is None else sc),
+            lambda q, pg, kl, pi, cu, ns: ragged_attention(
+                q, pg, kl, pi, cu, ns, sm_scale=D ** -0.5, kv_scale=scale,
+                prefill_kernel="xla"),
+            pargs, scale,
+        )
+    print(json.dumps(dev), flush=True)
+
+
+def _tp_engine(cfg: dict, tp: int, kv_scale, layers: int = 0):
+    """The comparison's engine; ``layers`` > 0 cuts DEPTH only (widths stay
+    the published ones) for the shallow, tightly-toleranced comparison."""
+    from dataclasses import replace
+
+    from dynamo_tpu.engine.config import EngineConfig
+    from dynamo_tpu.engine.engine import TpuEngine
+    from dynamo_tpu.models.config import get_config, register_config
+
+    if cfg["model"] == "debug-tiny-kv4":  # the rehearsal's tp=4-able toy
+        register_config(replace(
+            get_config("debug-tiny"), name="debug-tiny-kv4",
+            num_heads=8, num_kv_heads=4,
+        ))
+    model = cfg["model"]
+    if layers:
+        model = f"{model}-{layers}L"
+        register_config(replace(
+            get_config(cfg["model"]), name=model, num_layers=layers
+        ))
+    return TpuEngine(EngineConfig(
+        model=model, block_size=cfg["block_size"],
+        num_blocks=cfg["num_blocks"], max_batch=cfg["max_batch"],
+        max_model_len=cfg["max_model_len"], prefill_chunk=cfg["prefill_chunk"],
+        dtype=cfg["dtype"], cache_dtype="int8", kv_scale=kv_scale,
+        weight_quant="int8", tp=tp, decode_steps=4,
+    ))
+
+
+def _tp_requests(engine, cfg: dict) -> list:
+    """The same few greedy requests, with top-5 logprobs per token."""
+    import asyncio
+
+    import numpy as np
+
+    from dynamo_tpu.llm.protocols import (
+        PreprocessedRequest, SamplingOptions, StopConditions,
+    )
+    from dynamo_tpu.runtime.engine import Context, collect
+
+    vocab = engine.model_config.vocab_size
+    rng = np.random.default_rng(7)
+    prompts = [
+        [int(x) for x in rng.integers(0, vocab, cfg["prompt_len"] + 17 * i)]
+        for i in range(cfg["prompts"])
+    ]
+
+    async def one(tokens):
+        req = PreprocessedRequest(
+            token_ids=tokens,
+            stop_conditions=StopConditions(max_tokens=cfg["max_tokens"], ignore_eos=True),
+            sampling_options=SamplingOptions(temperature=0.0, logprobs=5),
+        ).to_dict()
+        items = await collect(await engine.generate(Context(req)))
+        toks = [t for it in items for t in it["token_ids"]]
+        lps = [it["logprobs"] for it in items if it.get("logprobs")]
+        return {"tokens": toks, "logprobs": lps}
+
+    async def main():
+        out = await asyncio.gather(*[one(p) for p in prompts])
+        await engine.close()
+        return out
+
+    return asyncio.run(main())
+
+
+def _flat_logprobs(entry) -> list:
+    """Engine logprob payloads → [{chosen, top: {id: lp}} per token]."""
+    out = []
+    for lp in entry:
+        for one in lp if isinstance(lp, list) else [lp]:
+            out.append({"chosen": one["logprob"],
+                        "top": {int(i): float(v) for i, v in one["top"]}})
+    return out
+
+
+def _compare(res: list, ref_results: list) -> dict:
+    """Logprob agreement of two runs of the same greedy requests."""
+    diffs, overlaps, compared, agree = [], [], 0, 0
+    for got, want in zip(res, ref_results):
+        g, w = _flat_logprobs(got["logprobs"]), _flat_logprobs(want["logprobs"])
+        for i, (a, b) in enumerate(zip(g, w)):
+            # Position i is comparable while both runs fed the same tokens:
+            # always the prompt's last position (i == 0).
+            if got["tokens"][:i] != want["tokens"][:i]:
+                break
+            common = set(a["top"]) & set(b["top"])
+            overlaps.append(len(common))
+            diffs += [abs(a["top"][t] - b["top"][t]) for t in common]
+            compared += 1
+            agree += got["tokens"][i] == want["tokens"][i]
+    diffs.sort()
+    return {
+        "requests": len(res), "positions_compared": compared,
+        "tokens_agreeing": agree, "top5_overlap_min": min(overlaps, default=0),
+        "top5_overlap_mean": round(sum(overlaps) / max(1, len(overlaps)), 2),
+        "max_abs_logprob_diff": diffs[-1] if diffs else None,
+        "median_abs_logprob_diff": diffs[len(diffs) // 2] if diffs else None,
+    }
+
+
+def _drop(engine) -> None:
+    """Free an engine's device arrays before the next one is built."""
+    import gc
+
+    engine.params = engine.cache = None
+    gc.collect()
+
+
+def child_tp1(rehearse: bool, ref_path: str) -> None:
+    t0 = time.time()
+    dev = child_device(rehearse)
+    cfg = TP_REHEARSAL if rehearse else TP
+    out = {}
+    for name, layers in (("shallow", cfg["shallow_layers"]), ("full", 0)):
+        t1 = time.time()
+        engine = _tp_engine(cfg, 1, "auto", layers)
+        out[name] = {
+            "kv_scale": [float(x) for x in engine.kv_scale],
+            "results": _tp_requests(engine, cfg),
+        }
+        emit(f"tp1_reference_{name}", t1, model=engine.cfg.model,
+             layers=engine.model_config.num_layers,
+             first_tokens=[r["tokens"][0] for r in out[name]["results"]])
+        _drop(engine)
+    with open(ref_path, "w") as f:
+        json.dump(out, f)
+    emit("tp1_reference", t0, **dev)
+    print(json.dumps(dev), flush=True)
+
+
+def child_tp4(rehearse: bool, ref_path: str) -> None:
+    dev = child_device(rehearse)
+    if dev["count"] < 4:
+        fail(f"--chips 4 needs four devices, JAX reports {dev['count']}")
+    import jax
+    import numpy as np
+
+    cfg = TP_REHEARSAL if rehearse else TP
+    with open(ref_path) as f:
+        ref = json.load(f)
+    # Every check runs and prints before any of them ends the run: four
+    # chips are too dear to learn one fact per call.
+    failures = []
+
+    # --- shallow: one layer at full width, tight tolerance
+    t0 = time.time()
+    engine = _tp_engine(cfg, 4, ref["shallow"]["kv_scale"], cfg["shallow_layers"])
+    cmp_s = _compare(_tp_requests(engine, cfg), ref["shallow"]["results"])
+    emit("tp4_vs_tp1_shallow", t0, layers=engine.model_config.num_layers,
+         tol=TP_SHALLOW_TOL, **cmp_s)
+    if cmp_s["positions_compared"] < cmp_s["requests"] or not (
+        cmp_s["top5_overlap_min"] >= 1
+        and cmp_s["max_abs_logprob_diff"] <= TP_SHALLOW_TOL
+    ):
+        failures.append(f"shallow tp=4 disagrees with tp=1: {cmp_s}")
+    _drop(engine)
+
+    # --- full depth
+    t0 = time.time()
+    engine = _tp_engine(cfg, 4, ref["full"]["kv_scale"])
+    devices = jax.local_devices()[:4]
+
+    # spread: every array has four addressable shards on four devices, and
+    # each device's bytes are a quarter of the total.
+    per_dev = {d.id: 0 for d in devices}
+    total = 0
+    n_sharded = 0
+    for a in jax.tree_util.tree_leaves((engine.params, engine.cache)):
+        total += a.nbytes
+        shards = a.addressable_shards
+        if {s.device.id for s in shards} != set(per_dev):
+            failures.append(f"an array of shape {a.shape} is not on all four devices")
+        if shards[0].data.nbytes * 4 == a.nbytes:
+            n_sharded += 1
+        for s in shards:
+            per_dev[s.device.id] += s.data.nbytes
+    share = max(per_dev.values())
+    if share > total / 4 * 1.05 + (64 << 20):
+        failures.append(f"a device holds {share} of {total} bytes: not spread four ways")
+    pages = engine.cache.pages
+    if len(pages.addressable_shards) != 4 or (
+        pages.addressable_shards[0].data.shape[3] * 4 != pages.shape[3]
+    ):
+        failures.append("KV pages are not sharded four ways on the head axis")
+    stats0 = [d.memory_stats() or {} for d in devices]
+    emit("tp4_spread", t0, total_bytes=total, per_device_bytes=per_dev,
+         sharded_leaves=n_sharded,
+         bytes_in_use=[s.get("bytes_in_use") for s in stats0],
+         peak_bytes_in_use=[s.get("peak_bytes_in_use") for s in stats0])
+
+    # collectives in the compiled unified step
+    t1 = time.time()
+    from dynamo_tpu.models.llama import RaggedBatch
+
+    c = engine.cfg
+    S, PP, T = c.max_batch, c.max_blocks_per_seq, c.bucket_tokens(cfg["prompt_len"])
+    cu = np.zeros((S + 1,), np.int32)
+    cu[1:] = T
+    rb = RaggedBatch(
+        token_ids=np.zeros((T,), np.int32), positions=np.zeros((T,), np.int32),
+        slot_mapping=np.full((T,), -1, np.int32),
+        kv_lens=np.asarray([T] + [0] * (S - 1), np.int32),
+        page_indices=np.zeros((S, PP), np.int32), cu_q_lens=cu,
+        num_seqs=np.asarray([1], np.int32),
+    )
+    text = engine._step_fn.lower(
+        engine.params, engine.cache, rb, engine._sampling_arrays([])
+    ).compile().as_text()
+    found = {
+        k: len(re.findall(rf"= \S+ {k}(?:-start)?\(", text))
+        for k in ("all-reduce", "all-gather", "reduce-scatter",
+                  "collective-permute", "all-to-all")
+    }
+    emit("tp4_collectives", t1, step_tokens=T, **found,
+         tpu_custom_call=text.count("tpu_custom_call"))
+    if not rehearse and not (found["all-reduce"] or found["reduce-scatter"]):
+        failures.append("the tp=4 step has no all-reduce: it is not sharded on tp")
+
+    # the same requests; logprobs against the tp=1 reference
+    t1 = time.time()
+    cmp_f = _compare(_tp_requests(engine, cfg), ref["full"]["results"])
+    stats1 = [d.memory_stats() or {} for d in devices]
+    peaks = [s.get("peak_bytes_in_use") for s in stats1]
+    emit("tp4_vs_tp1_full", t1, layers=engine.model_config.num_layers,
+         tol=TP_FULL_TOL, **cmp_f, peak_bytes_in_use=peaks, share_bytes=share,
+         peak_allowance=TP_PEAK_ALLOWANCE)
+    if cmp_f["positions_compared"] < cmp_f["requests"] or not (
+        cmp_f["top5_overlap_min"] >= 1
+        and cmp_f["max_abs_logprob_diff"] <= TP_FULL_TOL
+    ):
+        failures.append(f"full-depth tp=4 disagrees with tp=1: {cmp_f}")
+    if not rehearse:
+        # Chip 0 is where everything used to land before sharding.
+        for d, peak in zip(devices, peaks):
+            if peak is None or peak > share + TP_PEAK_ALLOWANCE:
+                failures.append(
+                    f"device {d.id} peaked at {peak} bytes: more than its "
+                    f"share {share} + {TP_PEAK_ALLOWANCE}")
+    if failures:
+        fail("; ".join(failures))
+    print(json.dumps(dev), flush=True)
+
+
+# ======================================================================== main
+def main() -> None:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    ap.add_argument("--rehearse-cpu", action="store_true")
+    ap.add_argument("--child", default=None, help=argparse.SUPPRESS)
+    ap.add_argument("--ref", default=None, help=argparse.SUPPRESS)
+    args = ap.parse_args()
+    if not os.path.isdir(os.path.join(HERE, "dynamo_tpu")):
+        fail("no dynamo_tpu package next to this script: nothing to smoke")
+    if args.child:
+        sys.path.insert(0, HERE)
+        {"parity": child_parity,
+         "tp1": lambda r: child_tp1(r, args.ref),
+         "tp4": lambda r: child_tp4(r, args.ref)}[args.child](args.rehearse_cpu)
+        return
+    t0 = time.time()
+    dev = (run_four_chips if args.chips == 4 else run_one_chip)(args.rehearse_cpu)
+    emit("total", t0, chips=args.chips)
+    # The contract's last line, nothing added.  A rehearsal is not a result.
+    print(json.dumps({"ok": not args.rehearse_cpu, "device": dev}), flush=True)
+
+
+if __name__ == "__main__":
+    main()

@@ -18,4 +18,11 @@ basetenlabs/dynamo @ 2025-05-23) for TPU hardware:
 - ``sdk``      — service-graph SDK (@service/@endpoint/depends) + supervisor.
 """
 
+import os
+
 __version__ = "0.1.0"
+
+# The checkout this package runs from: the ONE anchor for state the program
+# keeps beside its code (native/build, .xla_cache, decode_tune.json) —
+# nothing is read or written under ``~``.
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import json
 import logging
 import os
 import signal
@@ -269,6 +270,13 @@ async def _run(args) -> None:
             ).start()
         engine.attach_publisher(publisher)
 
+    warm = getattr(args, "warmup", None)
+    if (inp == "http" if warm is None else warm) and hasattr(
+        engine, "run_warmup"
+    ):
+        # No cold XLA compile may land inside a request.
+        await engine.run_warmup()
+
     if getattr(args, "record", None):
         # Tap every request/response stream to JSONL (reference:
         # recorder.rs) — replayable via runtime.recorder.replay_into.
@@ -341,6 +349,13 @@ async def _run(args) -> None:
         pipeline = _console_pipeline()
         service.models.add_chat_model(args.model, pipeline)
         service.models.add_completion_model(args.model, pipeline)
+        if hasattr(engine, "device_summary"):
+            # One line saying what this process serves on (chip_smoke.py
+            # and operators read it; /metrics carries the same facts).
+            print(
+                "engine " + json.dumps(engine.device_summary(), sort_keys=True),
+                flush=True,
+            )
         # LoRA adapters (llm/tenancy) serve as additional MODEL NAMES on
         # the same resident engine: each gets its own preprocessor that
         # stamps the adapter id + KV salt (one grammar compile cache shared
@@ -369,10 +384,13 @@ async def _run(args) -> None:
             flush=True,
         )
         try:
-            await service.run()
+            await service.run(_stop_event())
         finally:
             if exporter is not None:
                 await exporter.stop()
+            close = getattr(engine, "close", None)
+            if close is not None:
+                await close()
     elif inp == "none":
         # Start the engine with no input surface (reference Input::None,
         # opt.rs:40-43: externally-coordinated deployments — here, e.g., a
@@ -1016,7 +1034,9 @@ async def _run_api_store(args) -> None:
         await hub.close()
 
 
-async def _wait_forever() -> None:
+def _stop_event() -> asyncio.Event:
+    """An event SIGINT/SIGTERM set, so the caller's ``finally`` runs and
+    the process exits 0 instead of dying in the default handler."""
     stop = asyncio.Event()
     loop = asyncio.get_running_loop()
     for sig in (signal.SIGINT, signal.SIGTERM):
@@ -1024,7 +1044,11 @@ async def _wait_forever() -> None:
             loop.add_signal_handler(sig, stop.set)
         except NotImplementedError:
             pass
-    await stop.wait()
+    return stop
+
+
+async def _wait_forever() -> None:
+    await _stop_event().wait()
 
 
 def main(argv: Optional[list] = None) -> None:
@@ -1179,11 +1203,13 @@ def main(argv: Optional[list] = None) -> None:
     p_run.add_argument(
         "--attn-impl",
         default="auto",
-        choices=["auto", "xla", "pallas", "jax"],
+        choices=["auto", "tpu", "xla"],
         dest="attn_impl",
-        help="decode attention backend",
+        help="attention backend: tpu = the Pallas kernels, xla = the "
+        "gather fallback (the oracle); auto picks tpu on a TPU backend at "
+        "head_dim % 128 == 0",
     )
-    from .engine.config import DECODE_KERNELS
+    from .engine.config import DECODE_KERNELS, PREFILL_KERNELS
 
     p_run.add_argument(
         "--decode-kernel",
@@ -1195,6 +1221,31 @@ def main(argv: Optional[list] = None) -> None:
         "jax pallas ragged kernel with tuned hints, xla = the "
         "bit-exactness oracle.  auto resolves DYN_DECODE_KERNEL, then "
         "pallas_fused on TPU / stock elsewhere",
+    )
+    p_run.add_argument(
+        "--prefill-kernel",
+        default="auto",
+        choices=["auto", *PREFILL_KERNELS],
+        dest="prefill_kernel",
+        help="prefill-path attention kernel (ops/prefill_attention.py): "
+        "pallas = our chunked paged kernel, stock = the jax pallas ragged "
+        "kernel, xla = the byte-identity oracle.  auto resolves "
+        "DYN_PREFILL_KERNEL, then pallas on TPU / stock elsewhere",
+    )
+    p_run.add_argument(
+        "--weight-quant",
+        default=None,
+        choices=["int8"],
+        dest="weight_quant",
+        help="int8 = W8A8-dynamic weights (models/quant.py): what lets a "
+        "7-8B model fit one 16 GB chip.  Default: the --dtype weights",
+    )
+    p_run.add_argument(
+        "--warmup",
+        action=argparse.BooleanOptionalAction,
+        default=None,
+        help="compile every reachable device program before serving "
+        "(engine.warmup).  Default: on for in=http, off otherwise",
     )
     p_run.add_argument(
         "--spec-decode",

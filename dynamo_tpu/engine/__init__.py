@@ -80,6 +80,8 @@ def build_tpu_engine(args):
         checkpoint_path=getattr(args, "checkpoint", None),
         attn_impl=getattr(args, "attn_impl", "auto"),
         decode_kernel=getattr(args, "decode_kernel", "auto"),
+        prefill_kernel=getattr(args, "prefill_kernel", "auto"),
+        weight_quant=getattr(args, "weight_quant", None),
         host_cache_bytes=(getattr(args, "host_cache_mb", 0) or 0) << 20,
         disk_cache_bytes=(getattr(args, "disk_cache_mb", 0) or 0) << 20,
         disk_cache_dir=getattr(args, "disk_cache_dir", None),

@@ -1,14 +1,21 @@
 """Persistent XLA compilation cache.
 
-Round-3 measurement: warming every reachable program costs ~140s of XLA
-compiles on every engine start, so each worker restart / elastic scale-up
-served nothing for ~2.3 minutes.  The reference's engines inherit vLLM's
-torch.compile/CUDA-graph caches; the JAX equivalent is the persistent
-compilation cache keyed by (HLO, compile options, backend version) — with it
-wired, a restarted worker's warmup replays from disk in seconds.
+Warming every reachable program is minutes of XLA compiles on a cold engine
+start (not measured on this machine before PR 21's chip_smoke.py run), so a
+restarted worker should replay its warmup from disk.  JAX's persistent
+compilation cache does that; the directory is part of the cache key, so it
+must never move.  The rule:
 
-Enabled by default at ``~/.cache/dynamo_tpu/xla`` (override with
-DYN_XLA_CACHE_DIR; set it empty to disable).
+- ``JAX_COMPILATION_CACHE_DIR`` set: JAX's own handling of it stands and
+  this module sets no directory.
+- unset, accelerator backend: ONE fixed path inside the checkout
+  (``<repo>/.xla_cache``, git-ignored) — never under ``~``, a temp name, a
+  pid or a time.
+- unset, CPU backend: no persistent cache.  XLA:CPU AOT entries embed the
+  compile machine's CPU feature set and can fail (or SIGILL) when loaded
+  under a different feature detection — observed between a serving process
+  and hermetic child processes on the SAME host — and CPU compiles are
+  cheap.  Setting the variable opts the CPU in.
 """
 
 from __future__ import annotations
@@ -17,54 +24,55 @@ import logging
 import os
 from typing import Optional
 
+from .. import REPO_ROOT
+
 logger = logging.getLogger(__name__)
 
-_configured: Optional[str] = None
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+CHECKOUT_CACHE_DIR = os.path.join(REPO_ROOT, ".xla_cache")
 
 
-def setup_compilation_cache(path: Optional[str] = None) -> Optional[str]:
-    """Point JAX's persistent compilation cache at ``path`` (idempotent).
+# Persistent-cache hits and misses of this process, counted from JAX's own
+# monitoring events (reported on /metrics; chip_smoke.py reads them to show
+# that a second start really replayed from disk).
+cache_events = {"hits": 0, "misses": 0}
+_listening = False
 
-    ``None`` resolves DYN_XLA_CACHE_DIR, falling back to the default cache
-    dir; an empty string disables.  Returns the active cache dir or None.
-    """
-    global _configured
-    # An explicit path — argument or env var — is an opt-in that overrides
-    # the CPU-backend default-off below.
-    explicit = path is not None or bool(os.environ.get("DYN_XLA_CACHE_DIR"))
-    if path is None:
-        path = os.environ.get(
-            "DYN_XLA_CACHE_DIR",
-            os.path.join(
-                os.path.expanduser("~"), ".cache", "dynamo_tpu", "xla"
-            ),
-        )
-    if not path:
-        return None
+
+def _on_event(event: str, **_kw) -> None:
+    if event == "/jax/compilation_cache/cache_hits":
+        cache_events["hits"] += 1
+    elif event == "/jax/compilation_cache/cache_misses":
+        cache_events["misses"] += 1
+
+
+def setup_compilation_cache() -> Optional[str]:
+    """Apply the rule above (idempotent); returns the active cache
+    directory or None."""
+    global _listening
     import jax
 
-    backend = jax.default_backend()
-    if backend == "cpu" and not explicit:
-        # XLA:CPU AOT cache entries embed the compile machine's CPU feature
-        # set and can fail (or SIGILL) when loaded under a different feature
-        # detection — observed between the serving process and hermetic
-        # child processes on the SAME host.  CPU compiles are cheap; the
-        # restart-warmup win this cache exists for is the accelerator path.
-        # Explicitly setting DYN_XLA_CACHE_DIR opts CPU back in.
-        return None
-    path = os.path.join(path, backend)  # one cache per backend
-    if _configured == path:
-        return path
-    try:
+    if not _listening:
+        jax.monitoring.register_event_listener(_on_event)
+        _listening = True
+
+    path = os.environ.get(CACHE_ENV)
+    if not path:
+        if jax.default_backend() == "cpu":
+            return None
+        path = CHECKOUT_CACHE_DIR
         os.makedirs(path, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", path)
-        # Cache everything: the whole point is restart-time warmup, and the
-        # warmup set is dozens of programs of wildly varying compile cost.
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        _configured = path
-        logger.info("persistent XLA compilation cache at %s", path)
-        return path
-    except Exception:  # cache is an optimization; never block serving
-        logger.exception("failed to enable XLA compilation cache")
-        return None
+    # Cache everything: the whole point is restart-time warmup, and the
+    # warmup set is dozens of programs of wildly varying compile cost.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    logger.info("persistent XLA compilation cache at %s", path)
+    return path
+
+
+def cache_entries(path: Optional[str]) -> int:
+    """Files currently in the cache directory (0 when there is none)."""
+    if not path or not os.path.isdir(path):
+        return 0
+    return sum(1 for _ in os.scandir(path))

@@ -642,6 +642,53 @@ class EngineDispatchMetrics:
             lines.append(
                 f'{ns}_prefill_kernel_info{{kernel="{escape_label(pkern)}"}} 1'
             )
+        # What the engine runs on and what warmup cost (engine.
+        # device_summary) — OUTSIDE the _dispatch ns, like the stall counter.
+        dev = s.get("device")
+        if dev:
+            en = f"{prefix}_engine"
+            labels = ",".join(
+                f'{k}="{escape_label(str(dev[k]))}"'
+                for k in (
+                    "jax", "libtpu", "platform", "device_kind",
+                    "device_count", "model", "num_layers", "weight_quant",
+                    "cache_dtype", "attn_impl", "hasher",
+                    "compile_cache_dir",
+                )
+            )
+            lines.append(f"# HELP {en}_info Versions, device, model and "
+                         "attention backend of the serving process")
+            lines.append(f"# TYPE {en}_info gauge")
+            lines.append(f"{en}_info{{{labels}}} 1")
+            lines.append(f"# HELP {en}_warmup_seconds Wall of engine warmup")
+            lines.append(f"# TYPE {en}_warmup_seconds gauge")
+            lines.append(f"{en}_warmup_seconds {dev['warmup_s']}")
+            lines.append(f"# HELP {en}_compiled_programs Compiled programs "
+                         "per jitted entry (must not grow after warmup)")
+            lines.append(f"# TYPE {en}_compiled_programs gauge")
+            for fn, n in sorted(dev["compile_counts"].items()):
+                lines.append(
+                    f'{en}_compiled_programs{{fn="{escape_label(fn)}"}} {n}'
+                )
+            lines.append(f"# HELP {en}_compile_cache_entries Files in the "
+                         "persistent compilation cache directory")
+            lines.append(f"# TYPE {en}_compile_cache_entries gauge")
+            lines.append(
+                f"{en}_compile_cache_entries {dev['compile_cache_entries']}"
+            )
+            for key in ("compile_cache_hits", "compile_cache_misses"):
+                lines.append(f"# HELP {en}_{key} Persistent compilation "
+                             "cache events in this process")
+                lines.append(f"# TYPE {en}_{key} gauge")
+                lines.append(f"{en}_{key} {dev[key]}")
+            for key in ("hbm_bytes_in_use", "hbm_bytes_limit"):
+                lines.append(f"# HELP {en}_{key} Device memory_stats() "
+                             "per local device (0 where not reported)")
+                lines.append(f"# TYPE {en}_{key} gauge")
+                for i, v in enumerate(dev[key]):
+                    lines.append(
+                        f'{en}_{key}{{device="{escape_label(str(i))}"}} {v}'
+                    )
         # Prefill-chunk latency summary (engine.prefill_summary): cumulative
         # _sum/_count are true counters; the quantiles come from the
         # bounded per-chunk trace window (gauges in counter clothing, same

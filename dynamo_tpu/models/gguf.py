@@ -252,11 +252,11 @@ def load_params_gguf(config, path: str, dtype: Any = None) -> Dict[str, Any]:
 
     for name, info in g.tensors.items():
         if name == "token_embd.weight":
-            params["embed"] = jnp.asarray(g.tensor(name), dt)
+            params["embed"] = np.asarray(g.tensor(name), dt)
         elif name == "output_norm.weight":
-            params["final_norm"] = jnp.asarray(g.tensor(name), dt)
+            params["final_norm"] = np.asarray(g.tensor(name), dt)
         elif name == "output.weight":
-            params["lm_head"] = jnp.asarray(g.tensor(name).T, dt)
+            params["lm_head"] = np.asarray(g.tensor(name).T, dt)
         elif name.startswith("blk."):
             idx_str, sub = name[len("blk."):].split(".", 1)
             mapped = _GGUF_LAYER_MAP.get(sub)
@@ -271,7 +271,7 @@ def load_params_gguf(config, path: str, dtype: Any = None) -> Dict[str, Any]:
         missing = [i for i, s in enumerate(slabs) if s is None]
         if missing:
             raise ValueError(f"gguf missing {ours} for layers {missing}")
-        params["layers"][ours] = jnp.asarray(np.stack(slabs), dt)
+        params["layers"][ours] = np.asarray(np.stack(slabs), dt)
     if "embed" not in params:
         raise ValueError("gguf missing token_embd.weight")
     if "lm_head" not in params and not config.tie_word_embeddings:

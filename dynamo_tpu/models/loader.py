@@ -7,8 +7,10 @@ the stacked-[L, ...] tree that models/llama.py consumes (torch [out, in]
 linears transpose to [in, out] matmul layout).
 
 Memory notes: tensors stream from safetensors one at a time; per-layer
-tensors accumulate as numpy then stack.  Sharded (multi-host) loading applies
-the param shardings at device_put time via parallel.shard_tree.
+tensors accumulate as numpy then stack.  The tree this returns is STAGED ON
+THE HOST (numpy arrays; the GGUF int8 branch: CPU-device arrays) — no
+accelerator holds a whole tensor.  The engine places it: shard by shard via
+parallel.shard_tree with a mesh, onto the one device without.
 """
 
 from __future__ import annotations
@@ -88,8 +90,7 @@ def load_params(
 
         cpu = jax.devices("cpu")[0]
         with jax.default_device(cpu):
-            params = quantize_params(load_params_gguf(config, path, dtype))
-        return jax.tree_util.tree_map(jax.device_put, params)
+            return quantize_params(load_params_gguf(config, path, dtype))
 
     from .quant import _LAYER_QUANT_AXES, _TOP_QUANT_AXES, quantize_array_np
 
@@ -114,16 +115,16 @@ def load_params(
     def put_top(name: str, value: np.ndarray) -> None:
         if quant and name in _TOP_QUANT_AXES:
             q, s = quantize_array_np(value, _TOP_QUANT_AXES[name])
-            params[name] = jnp.asarray(q)
-            params[name + "_scale"] = jnp.asarray(s)
+            params[name] = np.asarray(q)
+            params[name + "_scale"] = np.asarray(s)
         else:
-            params[name] = jnp.asarray(value, dt)
+            params[name] = np.asarray(value, dt)
 
     for key, tensor in _iter_safetensors(path):
         if key == "model.embed_tokens.weight":
             put_top("embed", tensor)
         elif key == "model.norm.weight":
-            params["final_norm"] = jnp.asarray(tensor, dt)
+            params["final_norm"] = np.asarray(tensor, dt)
         elif key == "lm_head.weight":
             put_top("lm_head", tensor.T)
         elif key.startswith("model.layers."):
@@ -164,12 +165,12 @@ def load_params(
             raise ValueError(f"checkpoint missing {name} for layers {missing}")
         stacked = np.stack(tensors)
         if name in per_scale:
-            params["layers"][name] = jnp.asarray(stacked)  # int8 as-is
-            params["layers"][name + "_scale"] = jnp.asarray(
+            params["layers"][name] = np.asarray(stacked)  # int8 as-is
+            params["layers"][name + "_scale"] = np.asarray(
                 np.stack(per_scale[name])
             )
         else:
-            params["layers"][name] = jnp.asarray(stacked, dt)
+            params["layers"][name] = np.asarray(stacked, dt)
 
     for name, grid in per_expert.items():
         missing = [
@@ -179,12 +180,12 @@ def load_params(
             raise ValueError(f"checkpoint missing {name} for (layer, expert) {missing[:8]}")
         stacked = np.stack([np.stack(row) for row in grid])
         if name in per_expert_scale:
-            params["layers"][name] = jnp.asarray(stacked)  # int8 as-is
-            params["layers"][name + "_scale"] = jnp.asarray(
+            params["layers"][name] = np.asarray(stacked)  # int8 as-is
+            params["layers"][name + "_scale"] = np.asarray(
                 np.stack([np.stack(row) for row in per_expert_scale[name]])
             )
         else:
-            params["layers"][name] = jnp.asarray(stacked, dt)
+            params["layers"][name] = np.asarray(stacked, dt)
 
     if config.is_moe:
         # Fail at load, not at first forward's KeyError (a dense checkpoint

@@ -26,7 +26,9 @@ from typing import List, Optional, Tuple
 
 logger = logging.getLogger(__name__)
 
-_NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "native")
+from . import REPO_ROOT
+
+_NATIVE_DIR = os.path.join(REPO_ROOT, "native")
 # DYN_NATIVE_LIB overrides the library (e.g. the `make sanitize` ASan build).
 _SO_PATH = os.environ.get(
     "DYN_NATIVE_LIB",
@@ -55,8 +57,8 @@ def _build() -> bool:
 
 def _build_and_load() -> None:
     global _lib, _load_failed
-    if not os.path.exists(_SO_PATH):
-        if "DYN_NATIVE_LIB" in os.environ:
+    if "DYN_NATIVE_LIB" in os.environ:
+        if not os.path.exists(_SO_PATH):
             # An explicit override must never silently fall back to the
             # pure-Python path (e.g. a sanitizer run that tests nothing) —
             # and auto-build only knows the default target.
@@ -64,9 +66,12 @@ def _build_and_load() -> None:
                 f"DYN_NATIVE_LIB={_SO_PATH} does not exist; build it first "
                 "(e.g. `make -C native sanitize`)"
             )
-        if not _build():
-            _load_failed = True
-            return
+    elif not _build():
+        # `make` every time, never "use the .so as found": the build dir is
+        # git-ignored, so a library left by an older checkout would
+        # otherwise serve stale code (make is a no-op when up to date).
+        _load_failed = True
+        return
     _load()
 
 
@@ -141,6 +146,12 @@ def _load() -> None:
 def available() -> bool:
     """True once the library is built+loaded (blocks for the build)."""
     return get_lib(wait=True) is not None
+
+
+def hasher() -> str:
+    """Which block hasher serves this process — reported on /metrics and
+    by chip_smoke.py, so the Python path never stands in unnoticed."""
+    return "native" if available() else "python"
 
 
 def hash_blocks(

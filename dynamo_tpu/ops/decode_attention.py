@@ -5,10 +5,10 @@ only CASTS quantized (int8/fp8) KV pages up to the query dtype and never
 applies ``kv_scale`` in-kernel, so the model folds dequant algebraically
 around the call (q pre-scaled, output post-scaled — models/llama.py) and
 the decode step's dominant HBM stream still rides a generic mixed
-prefill/decode kernel.  BENCH_r05 put full-model decode at 54.89% MFU with
-a ~12 ms/step non-bandwidth residual; this kernel attacks exactly that
-residual for the one shape the fused decode program dispatches — ONE query
-token per row, identity row map (``ragged_decode_attention``):
+prefill/decode kernel.  This kernel is specialised for the one shape the
+fused decode program dispatches — ONE query token per row, identity row map
+(``ragged_decode_attention``); whether it beats the stock kernel is not
+measured on this machine:
 
 1. **Fused dequant**: int8/fp8 KV pages are DMA'd quantized and scaled by
    ``kv_scale`` in VMEM right before the QK/AV dots — the KV stream is
@@ -28,8 +28,9 @@ token per row, identity row map (``ragged_decode_attention``):
 
 Contract: identical inputs/outputs to ``ragged_decode_attention``'s XLA
 fallback (the bit-exactness oracle) — [S, H, D] out, zeros for rows past
-``num_seqs``.  Interpret mode (CPU) runs the same kernel for tier-1 parity
-gates; compiled mode is TPU-only.  Selection: DYN_DECODE_KERNEL /
+``num_seqs``.  The wrapper compiles for the chip unless a caller asks for
+the Pallas interpreter (``interpret=True``, or ``DYN_PALLAS_INTERPRET=1``
+— the CPU test path, ops/ragged_attention.py pallas_interpret).  Selection: DYN_DECODE_KERNEL /
 EngineConfig.decode_kernel (ops/ragged_attention.py resolve_decode_kernel).
 """
 
@@ -44,6 +45,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .ragged_attention import pallas_interpret
 
 logger = logging.getLogger(__name__)
 
@@ -61,9 +64,14 @@ _ACTIVE_KEY: Optional[str] = None
 
 
 def default_table_path() -> str:
+    """``DYN_DECODE_TUNE_TABLE``, else ONE path inside the checkout
+    (``<repo>/decode_tune.json`` — absent until tools/tune_decode.py is run
+    on the chip and its table committed, so the built-in defaults serve).
+    Nothing under ``~`` is read."""
+    from .. import REPO_ROOT
+
     return os.environ.get(
-        "DYN_DECODE_TUNE_TABLE",
-        os.path.expanduser("~/.cache/dynamo_tpu/decode_tune.json"),
+        "DYN_DECODE_TUNE_TABLE", os.path.join(REPO_ROOT, "decode_tune.json")
     )
 
 
@@ -369,11 +377,7 @@ def fused_decode_attention(
     split_pages = pl.cdiv(PP, splits)
     splits = pl.cdiv(PP, split_pages)  # drop now-empty tail splits
 
-    if interpret is None:
-        from .ragged_attention import on_tpu
-
-        interpret = not on_tpu()
-
+    interpret = pallas_interpret() if interpret is None else interpret
     kernel = _make_kernel(
         sm_scale=sm_scale,
         num_kv=KV,
@@ -395,7 +399,7 @@ def fused_decode_attention(
             pl.BlockSpec(
                 (1, H, D), lambda s, j, *_: (s, 0, 0), memory_space=pltpu.VMEM
             ),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # pages stay in HBM
+            pl.BlockSpec(memory_space=pl.ANY),  # pages stay in HBM
             pl.BlockSpec(memory_space=pltpu.SMEM),  # kv_scale
         ],
         out_specs=(
@@ -428,12 +432,13 @@ def fused_decode_attention(
             jax.ShapeDtypeStruct((S, splits, H, 1), jnp.float32),
             jax.ShapeDtypeStruct((S, splits, H, 1), jnp.float32),
         ),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # Same headroom as the stock path: the default 16MB scoped
             # budget is a compiler default, not the hardware ceiling.
             vmem_limit_bytes=64 << 20,
         ),
         interpret=interpret,
+        name="fused_decode_attention",
     )(
         jnp.asarray(kv_lens, jnp.int32),
         jnp.asarray(page_indices, jnp.int32),

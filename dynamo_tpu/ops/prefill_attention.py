@@ -37,8 +37,9 @@ therefore never be marked ``parallel``.
 
 Contract: identical inputs/outputs to ``ragged_attention``'s XLA fallback
 (the byte-identity oracle) — [T, H, D] out, zeros for padding tokens at or
-past ``cu_q_lens[num_seqs]``.  Interpret mode (CPU) runs the same kernel
-for tier-1 parity gates; compiled mode is TPU-only.  Selection:
+past ``cu_q_lens[num_seqs]``.  The wrapper compiles for the chip unless a
+caller asks for the Pallas interpreter (``interpret=True``, or
+``DYN_PALLAS_INTERPRET=1`` — the CPU test path).  Selection:
 DYN_PREFILL_KERNEL / EngineConfig.prefill_kernel
 (ops/ragged_attention.py resolve_prefill_kernel).
 """
@@ -56,6 +57,10 @@ from jax.experimental.pallas import tpu as pltpu
 # (tools/tune_decode.py sweeps both kernels' families into one entry per
 # engine geometry) under their own keys, resolved env > table > default.
 from .decode_attention import NEG_INF, pages_per_vmem_budget, resolve_hint
+from .ragged_attention import pallas_interpret
+
+SUBLANES, LANES = 8, 128  # one f32 vreg tile on the chip
+MAX_BLOCK_CTX = 512  # context positions per compute block (see _default_ppcb)
 
 
 def _default_ppcb(page_size: int, kv2: int, head_dim: int, itemsize: int) -> int:
@@ -63,7 +68,15 @@ def _default_ppcb(page_size: int, kv2: int, head_dim: int, itemsize: int) -> int
     4MB) at the PAGE dtype's width — quantized pages land in scratch
     quantized, so int8 packs ~2x the bf16 block."""
     budget = resolve_hint("DYN_PREFILL_NKV_MB", "prefill_nkv_mb", 4) << 20
-    return pages_per_vmem_budget(budget, page_size, kv2, head_dim, itemsize)
+    # The page bytes are not the only VMEM cost of a compute block: its
+    # f32 dequantized copy and the [QB*Gp, C] logits scale with the
+    # block's context positions C.  Past C = 512 the chip's compiler ran
+    # out of VMEM at llama-3.1-8b widths with int8 pages (compile for a
+    # described v5e, PR 21), so C is capped there.
+    return min(
+        pages_per_vmem_budget(budget, page_size, kv2, head_dim, itemsize),
+        max(1, MAX_BLOCK_CTX // page_size),
+    )
 
 
 def _make_kernel(
@@ -78,7 +91,9 @@ def _make_kernel(
     ppcb: int,
     q_block: int,
 ):
-    """Build the kernel body for a static geometry.
+    """Build the kernel body for a static geometry.  ``group`` is the
+    PADDED q-heads-per-kv-head count (a sublane-tile multiple, see the
+    wrapper), so every in-kernel reshape is tile-aligned.
 
     Grid (S, J): program (s, j) computes ALL of row ``s``'s query blocks
     against KV split ``j`` (pages [j*split_pages, (j+1)*split_pages)) and
@@ -87,7 +102,6 @@ def _make_kernel(
     """
     C = ppcb * page_size  # context positions per compute block
     QB = q_block
-    H = num_kv * group
 
     def kernel(
         # scalar prefetch (SMEM)
@@ -96,21 +110,19 @@ def _make_kernel(
         cu_q_lens_ref,  # [S+1] int32
         num_seqs_ref,  # [1] int32
         # operands
-        q_hbm_ref,  # [Tpad, H, D] HBM/ANY — DMA'd per q-block
-        pages_ref,  # [P, ps, 2KV, D] HBM/ANY — DMA'd per compute block
+        q_hbm_ref,  # [Tpad, KV, Gp, D] f32 HBM — DMA'd per q-block
+        pages_ref,  # [P, ps, 2KV, D] HBM — DMA'd per compute block
         scale_ref,  # [1, 1] f32 SMEM — kv_scale (traced OK)
-        # outputs (HBM/ANY — DMA'd per q-block)
-        o_ref,  # [J, Tpad, H, D] f32 — unnormalized sum(p·V)
-        m_ref,  # [J, Tpad, H, 1] f32 — split max
-        l_ref,  # [J, Tpad, H, 1] f32 — split sum(exp)
+        # outputs (HBM — DMA'd per q-block)
+        o_ref,  # [J, Tpad, KV, Gp, D] f32 — unnormalized sum(p·V)
+        ml_ref,  # [J, Tpad, KV, Gp, LANES] f32 — lane 0 split max, lane 1 sum(exp)
         # scratch
-        q_buf,  # [QB, H, D] q dtype
+        q_buf,  # [QB, KV, Gp, D] f32
         kv_buf,  # [2, ppcb, ps, 2KV, D] pages dtype
-        o_sc,  # [QB, H, D] f32
-        m_sc,  # [QB, H, 1] f32
-        l_sc,  # [QB, H, 1] f32
+        o_sc,  # [QB, KV, Gp, D] f32
+        ml_sc,  # [QB, KV, Gp, LANES] f32
         kv_sems,  # DMA semaphores (2,) — double-buffered page stream
-        io_sems,  # DMA semaphores (4,) — q in + o/m/l out
+        io_sems,  # DMA semaphores (3,) — q in + o/ml out
     ):
         s = pl.program_id(0)
         j = pl.program_id(1)
@@ -199,9 +211,7 @@ def _make_kernel(
                         k_h = kvf[:, 2 * h, :]  # [C, D]
                         v_h = kvf[:, 2 * h + 1, :]
                         qf = (
-                            q_buf[:, h * group : (h + 1) * group, :]
-                            .reshape(QB * group, head_dim)
-                            .astype(jnp.float32)
+                            q_buf[:, h].reshape(QB * group, head_dim)
                             * sm_scale
                         )
                         logits = jax.lax.dot_general(
@@ -246,16 +256,19 @@ def _make_kernel(
                 # An empty split runs zero trips: the init carry IS the
                 # neutral partial (o=0, m=NEG_INF, l=0).
                 final = jax.lax.fori_loop(0, nblocks, block_step, tuple(init))
+                lane = jax.lax.broadcasted_iota(
+                    jnp.int32, (QB * group, LANES), 1
+                )
                 for h in range(num_kv):
-                    m_sc[:, h * group : (h + 1) * group, :] = final[
-                        3 * h
-                    ].reshape(QB, group, 1)
-                    l_sc[:, h * group : (h + 1) * group, :] = final[
-                        3 * h + 1
-                    ].reshape(QB, group, 1)
-                    o_sc[:, h * group : (h + 1) * group, :] = final[
-                        3 * h + 2
-                    ].reshape(QB, group, head_dim)
+                    # m and l ride ONE lane-dense slab (lane 0 / lane 1):
+                    # a [.., 1]-wide partial is not a shape the chip's
+                    # DMA engine can slice.
+                    ml_sc[:, h] = jnp.where(
+                        lane == 0, final[3 * h], final[3 * h + 1]
+                    ).reshape(QB, group, LANES)
+                    o_sc[:, h] = final[3 * h + 2].reshape(
+                        QB, group, head_dim
+                    )
                 # Write the block's partials back at the token offset.  The
                 # tail block spills up to QB-1 tokens into the next row's
                 # region — overwritten by that row's own (later) program;
@@ -265,10 +278,7 @@ def _make_kernel(
                         o_sc, o_ref.at[j, pl.ds(tok0, QB)], io_sems.at[1]
                     ),
                     pltpu.make_async_copy(
-                        m_sc, m_ref.at[j, pl.ds(tok0, QB)], io_sems.at[2]
-                    ),
-                    pltpu.make_async_copy(
-                        l_sc, l_ref.at[j, pl.ds(tok0, QB)], io_sems.at[3]
+                        ml_sc, ml_ref.at[j, pl.ds(tok0, QB)], io_sems.at[2]
                     ),
                 )
                 for w in writes:
@@ -331,15 +341,20 @@ def fused_prefill_attention(
     split_pages = pl.cdiv(PP, splits)
     splits = pl.cdiv(PP, split_pages)  # drop now-empty tail splits
 
-    if interpret is None:
-        from .ragged_attention import on_tpu
-
-        interpret = not on_tpu()
-
+    interpret = pallas_interpret() if interpret is None else interpret
+    # Layout the chip's DMA engine can slice at a dynamic TOKEN offset: the
+    # token axis leads (untiled) and the two tiled minor dims are whole,
+    # aligned tiles — q as f32 [Tpad, KV, Gp, D] with the q-heads of one
+    # KV head padded to the f32 sublane tile (Gp = 8 for qwen's G=7 and
+    # llama's G=4; the pad rows are zero queries, sliced off below).  The
+    # original [T, H, D] bf16 / [.., H, 1] layouts only ever ran in
+    # interpret mode: Mosaic refuses a 28-of-32 sublane slice and a
+    # 1-of-128 lane slice.
+    Gp = -(-G // SUBLANES) * SUBLANES
     kernel = _make_kernel(
         sm_scale=sm_scale,
         num_kv=KV,
-        group=G,
+        group=Gp,
         head_dim=D,
         page_size=ps,
         pages_per_seq=PP,
@@ -354,48 +369,45 @@ def fused_prefill_attention(
     # the run, and the LAST row's tail write spills here instead of out of
     # bounds.  Sliced back off after the combine.
     Tpad = T + QB
-    q_pad = jnp.concatenate(
-        [q, jnp.zeros((QB, H, D), q.dtype)], axis=0
+    q_pad = jnp.pad(
+        q.astype(jnp.float32).reshape(T, KV, G, D),
+        ((0, QB), (0, 0), (0, Gp - G), (0, 0)),
     )
 
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(S, splits),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),  # q stays in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),  # pages stay in HBM
+            hbm,  # q: DMA'd per q-block
+            hbm,  # pages: DMA'd per compute block
             pl.BlockSpec(memory_space=pltpu.SMEM),  # kv_scale
         ],
-        out_specs=(
-            pl.BlockSpec(memory_space=pltpu.ANY),  # o partials
-            pl.BlockSpec(memory_space=pltpu.ANY),  # m partials
-            pl.BlockSpec(memory_space=pltpu.ANY),  # l partials
-        ),
+        out_specs=(hbm, hbm),  # o / ml partials
         scratch_shapes=[
-            pltpu.VMEM((QB, H, D), q.dtype),
+            pltpu.VMEM((QB, KV, Gp, D), jnp.float32),
             pltpu.VMEM((2, ppcb, ps, KV2, D), pages.dtype),
-            pltpu.VMEM((QB, H, D), jnp.float32),
-            pltpu.VMEM((QB, H, 1), jnp.float32),
-            pltpu.VMEM((QB, H, 1), jnp.float32),
+            pltpu.VMEM((QB, KV, Gp, D), jnp.float32),
+            pltpu.VMEM((QB, KV, Gp, LANES), jnp.float32),
             pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((4,)),
+            pltpu.SemaphoreType.DMA((3,)),
         ],
     )
     cu = jnp.asarray(cu_q_lens, jnp.int32)
     num = jnp.asarray(num_seqs, jnp.int32)
-    o_part, m_part, l_part = pl.pallas_call(
+    o_part, ml_part = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=(
-            jax.ShapeDtypeStruct((splits, Tpad, H, D), jnp.float32),
-            jax.ShapeDtypeStruct((splits, Tpad, H, 1), jnp.float32),
-            jax.ShapeDtypeStruct((splits, Tpad, H, 1), jnp.float32),
+            jax.ShapeDtypeStruct((splits, Tpad, KV, Gp, D), jnp.float32),
+            jax.ShapeDtypeStruct((splits, Tpad, KV, Gp, LANES), jnp.float32),
         ),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # Same headroom as the decode kernel / stock path.
             vmem_limit_bytes=64 << 20,
         ),
         interpret=interpret,
+        name="fused_prefill_attention",
     )(
         jnp.asarray(kv_lens, jnp.int32),
         jnp.asarray(page_indices, jnp.int32),
@@ -405,15 +417,17 @@ def fused_prefill_attention(
         pages,
         scale_arr,
     )
+    # Drop the token / group padding and restore [J, T, H(, D)].
+    o_part = o_part[:, :T, :, :G].reshape(splits, T, H, D)
+    m = ml_part[:, :T, :, :G, 0].reshape(splits, T, H)
+    l = ml_part[:, :T, :, :G, 1].reshape(splits, T, H)
     # Flash-style LSE combine over the split axis.  Neutral partials
     # (o=0, m=NEG_INF, l=0) from empty splits vanish here.
-    m = m_part[..., 0]  # [J, Tpad, H]
-    l = l_part[..., 0]
-    m_max = jnp.max(m, axis=0)  # [Tpad, H]
-    alpha = jnp.exp(m - m_max[None])  # [J, Tpad, H]
+    m_max = jnp.max(m, axis=0)  # [T, H]
+    alpha = jnp.exp(m - m_max[None])  # [J, T, H]
     l_tot = jnp.sum(alpha * l, axis=0)
-    o_tot = jnp.sum(alpha[..., None] * o_part, axis=0)  # [Tpad, H, D]
-    out = (o_tot / (l_tot[..., None] + 1e-30))[:T]
+    o_tot = jnp.sum(alpha[..., None] * o_part, axis=0)  # [T, H, D]
+    out = o_tot / (l_tot[..., None] + 1e-30)
     # Padding tokens (at/past cu_q_lens[num_seqs]) were never written by an
     # active row: zero them to match the XLA oracle's padding contract.
     valid = jnp.arange(T, dtype=jnp.int32) < cu[num[0]]

@@ -8,14 +8,18 @@ which is what keeps XLA recompiles rare (the round-1 design had separate
 prefill/decode programs per (batch, seq-len) bucket pair and still hit
 cold shapes in production mixes).
 
-Two implementations behind one contract:
-- TPU: ``jax.experimental.pallas.ops.tpu.ragged_paged_attention`` — the
+Implementations behind one contract (which serves is resolved ONCE, at
+engine init, and reported on /metrics — no path here falls back to another):
+- the repo's own Pallas kernels (ops/decode_attention.py,
+  ops/prefill_attention.py): what ``auto`` picks on a TPU backend;
+- ``stock``: ``jax.experimental.pallas.ops.tpu.ragged_paged_attention`` — the
   vLLM-TPU kernel (multi-page async-copy DMA, heads-block grid, online
-  softmax in VMEM).  This is the measured-fastest decode AND prefill path
-  and never materialises O(T · window) logits in HBM.
-- XLA fallback (CPU tests / virtual meshes): static-shape gather + masked
-  softmax.  Memory O(T · window · kv_heads · head_dim) — fine for the tiny
-  test shapes, deliberately not used on real hardware.
+  softmax in VMEM) on ``impl="tpu"``;
+- ``xla``: static-shape gather + masked softmax, the oracle the tests and
+  chip_smoke.py compare against.  Memory O(T · window · kv_heads ·
+  head_dim) — fine for test shapes and toy head_dims, deliberately not
+  used at real widths.
+Which is fastest on the current machine: not measured.
 
 Cache layout per layer (kernel contract): ``[num_pages, page_size,
 2 * kv_heads, head_dim]`` with K at even combined-head indices and V at odd.
@@ -33,13 +37,18 @@ NEG_INF = -1e30
 
 
 def on_tpu() -> bool:
-    """True when default execution actually lands on a TPU — accounts for a
-    jax_default_device override (tests pin CPU while a TPU plugin is still
-    registered as the default backend)."""
-    if jax.default_backend() != "tpu":
-        return False
-    dev = jax.config.jax_default_device
-    return dev is None or getattr(dev, "platform", None) == "tpu"
+    return jax.default_backend() == "tpu"
+
+
+def pallas_interpret() -> bool:
+    """Whether the repo's own Pallas kernels run under the Pallas
+    interpreter when the caller passes no ``interpret=``.  It is something
+    a caller ASKS for — ``DYN_PALLAS_INTERPRET=1``, which tests/conftest.py
+    sets for the CPU test path — never something inferred from the
+    backend: on the serving path the kernels compile or raise."""
+    import os
+
+    return os.environ.get("DYN_PALLAS_INTERPRET", "0") not in ("", "0")
 
 
 def resolve_decode_kernel(value: str = "auto", attn_impl: str = "auto") -> str:
@@ -47,8 +56,8 @@ def resolve_decode_kernel(value: str = "auto", attn_impl: str = "auto") -> str:
 
     Order: explicit config value > ``DYN_DECODE_KERNEL`` env > auto.
     - ``pallas_fused``: our fused-dequant split-KV kernel
-      (ops/decode_attention.py) — compiled on TPU, interpret-mode on CPU
-      (the tier-1 parity gates run exactly the device kernel logic).
+      (ops/decode_attention.py) — compiled for the chip; under the Pallas
+      interpreter only when asked (pallas_interpret).
     - ``stock``: the pre-existing path — the jax pallas
       ragged_paged_attention kernel on TPU, XLA gather fallback elsewhere.
     - ``xla``: force the XLA fallback everywhere (the bit-exactness
@@ -94,9 +103,8 @@ def resolve_prefill_kernel(value: str = "auto", attn_impl: str = "auto") -> str:
 
     Order: explicit config value > ``DYN_PREFILL_KERNEL`` env > auto.
     - ``pallas``: our chunked paged prefill kernel with in-kernel dequant
-      and KV splits (ops/prefill_attention.py) — compiled on TPU,
-      interpret-mode on CPU (the tier-1 parity gates run exactly the
-      device kernel logic).
+      and KV splits (ops/prefill_attention.py) — compiled for the chip;
+      under the Pallas interpreter only when asked (pallas_interpret).
     - ``stock``: the pre-existing path — the jax pallas
       ragged_paged_attention kernel on TPU, XLA gather fallback elsewhere.
     - ``xla``: force the XLA fallback everywhere (the byte-identity
@@ -179,9 +187,8 @@ def _decode_block_hints(pages: jnp.ndarray, page_indices: jnp.ndarray):
     """Pallas block/grid hints for decode-shaped dispatches (every row one
     query token).  The kernel's default KV block spans all of pages_per_seq;
     at long context its double-buffered VMEM scratch exceeds the 16MB scoped
-    limit, and decode steps measured 2x faster with explicit 16-query blocks
-    + a ~4MB-budget KV block (18-layer chain at batch 256: 14.2 -> 7.9ms on
-    v5e).  Tunable for hardware sweeps: DYN_DECODE_NQ query block,
+    limit; explicit 16-query blocks + a ~4MB-budget KV block are an earlier
+    round's choice (not measured on this machine).  Tunable for hardware sweeps: DYN_DECODE_NQ query block,
     DYN_DECODE_NKV_MB KV block budget — each resolved env var > tuned-table
     entry installed at engine init (tools/tune_decode.py) > the defaults
     above, through the ONE precedence implementation (resolve_hint)."""
@@ -224,7 +231,7 @@ def ragged_decode_attention(
     - "pallas_fused": our fused-dequant split-KV decode kernel
       (ops/decode_attention.py) — ``kv_scale`` (static OR traced) is
       applied IN-KERNEL, so quantized pages stream from HBM once at
-      1 byte/value.  Interpret-mode on CPU, compiled on TPU.
+      1 byte/value.
     - "stock": the pre-existing routing — the jax pallas kernel with the
       decode-tuned block hints on ``impl == "tpu"``, XLA fallback
       otherwise.
@@ -236,34 +243,18 @@ def ragged_decode_attention(
     if kernel == "pallas_fused":
         from .decode_attention import fused_decode_attention
 
-        try:
-            return fused_decode_attention(
-                q,
-                pages,
-                kv_lens,
-                page_indices,
-                num_seqs,
-                sm_scale=sm_scale,
-                kv_scale=kv_scale,
-            )
-        except Exception as e:  # trace-time rejection (see ragged_attention)
-            # Only COMPILED toy shapes (sub-lane-width heads on a real
-            # TPU) may fall back.  Interpret mode has no legitimate
-            # rejection path, and a silent fallback there would leave
-            # every decode_kernel reporting surface (bench JSON, CI churn
-            # assertion, /metrics info gauge) claiming pallas_fused while
-            # stock served — the attribution error BENCH_r06 exists to
-            # avoid.  Real serving geometries stay loud everywhere.
-            if pages.shape[3] >= 128 or not on_tpu():
-                raise
-            import logging
-
-            logging.getLogger(__name__).warning(
-                "fused decode kernel rejected toy shapes q=%s pages=%s "
-                "(%s); using the stock path",
-                q.shape, pages.shape, e,
-            )
-            kernel = "stock"
+        # Compiles or raises: a fallback here would leave every
+        # decode_kernel reporting surface (bench JSON, /metrics info
+        # gauge) claiming pallas_fused while another path served.
+        return fused_decode_attention(
+            q,
+            pages,
+            kv_lens,
+            page_indices,
+            num_seqs,
+            sm_scale=sm_scale,
+            kv_scale=kv_scale,
+        )
     if kernel == "xla":
         impl = "xla"
     elif kernel != "stock":
@@ -279,32 +270,20 @@ def ragged_decode_attention(
         # Unit scale for quantized pages without an explicit one — see the
         # matching comment in ragged_attention.
         unit = 1.0 if pages.dtype.itemsize == 1 and kv_scale is None else kv_scale
-        try:
-            return ragged_paged_attention(
-                q,
-                pages,
-                kv_lens,
-                page_indices,
-                cu,
-                num_seqs,
-                sm_scale=sm_scale,
-                num_queries_per_block=nq,
-                num_kv_pages_per_block=nkv,
-                vmem_limit_bytes=64 << 20,
-                k_scale=unit,
-                v_scale=unit,
-            )
-        except Exception as e:  # trace-time rejection (see ragged_attention)
-            if pages.shape[3] >= 128:
-                raise
-            import logging
-
-            logging.getLogger(__name__).warning(
-                "pallas ragged kernel rejected toy decode shapes q=%s "
-                "pages=%s (%s); using the XLA fallback",
-                q.shape, pages.shape, e,
-            )
-            impl = "xla"
+        return ragged_paged_attention(
+            q,
+            pages,
+            kv_lens,
+            page_indices,
+            cu,
+            num_seqs,
+            sm_scale=sm_scale,
+            num_queries_per_block=nq,
+            num_kv_pages_per_block=nkv,
+            vmem_limit_bytes=64 << 20,
+            k_scale=unit,
+            v_scale=unit,
+        )
     if impl != "xla":
         raise ValueError(f"unknown ragged attention impl {impl!r}")
 
@@ -381,7 +360,7 @@ def ragged_attention(
     - "pallas": our chunked paged prefill kernel
       (ops/prefill_attention.py) — ``kv_scale`` (static OR traced) is
       applied IN-KERNEL and the prior prefix streams straight from the
-      paged blocks.  Interpret-mode on CPU, compiled on TPU.
+      paged blocks.
     - "stock": the pre-existing routing below (jax pallas kernel on
       ``impl == "tpu"``, XLA fallback otherwise).
     - "xla": force the XLA fallback (the byte-identity oracle).
@@ -401,34 +380,17 @@ def ragged_attention(
     if prefill_kernel == "pallas":
         from .prefill_attention import fused_prefill_attention
 
-        try:
-            return fused_prefill_attention(
-                q,
-                pages,
-                kv_lens,
-                page_indices,
-                cu_q_lens,
-                num_seqs,
-                sm_scale=sm_scale,
-                kv_scale=kv_scale,
-            )
-        except Exception as e:  # trace-time rejection (see below)
-            # Same fallback policy as the fused decode kernel: only
-            # COMPILED toy shapes (sub-lane-width heads on a real TPU) may
-            # fall back.  Interpret mode has no legitimate rejection path —
-            # a silent fallback there would leave every prefill_kernel
-            # reporting surface (bench JSON, CI gate, /metrics info gauge)
-            # claiming pallas while stock served.
-            if pages.shape[3] >= 128 or not on_tpu():
-                raise
-            import logging
-
-            logging.getLogger(__name__).warning(
-                "fused prefill kernel rejected toy shapes q=%s pages=%s "
-                "(%s); using the stock path",
-                q.shape, pages.shape, e,
-            )
-            prefill_kernel = "stock"
+        # Compiles or raises (see ragged_decode_attention).
+        return fused_prefill_attention(
+            q,
+            pages,
+            kv_lens,
+            page_indices,
+            cu_q_lens,
+            num_seqs,
+            sm_scale=sm_scale,
+            kv_scale=kv_scale,
+        )
     if prefill_kernel == "xla":
         impl = "xla"
     elif prefill_kernel != "stock":
@@ -441,10 +403,9 @@ def ragged_attention(
         # Block sizing: the kernel replaces BOTH block params with its tuned
         # table whenever EITHER is None — a partial override is silently
         # discarded.  Prefill and mixed shapes run the kernel's tuned table
-        # (59-83% MFU measured) under the raised vmem limit; decode shapes
-        # never reach here (routed to ragged_decode_attention above, which
-        # passes the measured-best decode hints).
-        hd = pages.shape[3]
+        # under the raised vmem limit; decode shapes never reach here
+        # (routed to ragged_decode_attention above, which passes the
+        # repo's decode hints).
         nkv = nq = None
         # Quantized (1-byte) pages: real scaling is folded around this call
         # by the model (q pre-scaled, output post-scaled — models/llama.py),
@@ -452,41 +413,23 @@ def ragged_attention(
         # `if k_scale is not None` branch — so a unit scale must be passed
         # or raw quantized values feed the MXU dot and tracing rejects.
         unit = 1.0 if pages.dtype.itemsize == 1 and kv_scale is None else kv_scale
-        try:
-            return ragged_paged_attention(
-                q,
-                pages,
-                kv_lens,
-                page_indices,
-                cu_q_lens,
-                num_seqs,
-                sm_scale=sm_scale,
-                num_queries_per_block=nq,
-                num_kv_pages_per_block=nkv,
-                # The default 16MB scoped-vmem budget is a compiler default,
-                # not the hardware ceiling; long-context shapes need headroom
-                # (vLLM's TPU backend raises it the same way).
-                vmem_limit_bytes=64 << 20,
-                k_scale=unit,
-                v_scale=unit,
-            )
-        except Exception as e:  # trace-time rejection
-            # The kernel enforces its own contract during tracing.  Only
-            # TOY geometries (sub-lane-width heads: tests/debug models) may
-            # silently fall back to the XLA path — there its O(T·window)
-            # materialization is small.  A rejection at a real serving
-            # geometry (head_dim >= 128) is a kernel/JAX fault that must be
-            # LOUD, not a silent 10x memory/latency downgrade.
-            if hd >= 128:
-                raise
-            import logging
-
-            logging.getLogger(__name__).warning(
-                "pallas ragged kernel rejected toy shapes q=%s pages=%s "
-                "(%s); using the XLA fallback",
-                q.shape, pages.shape, e,
-            )
-            impl = "xla"
+        return ragged_paged_attention(
+            q,
+            pages,
+            kv_lens,
+            page_indices,
+            cu_q_lens,
+            num_seqs,
+            sm_scale=sm_scale,
+            num_queries_per_block=nq,
+            num_kv_pages_per_block=nkv,
+            # The default 16MB scoped-vmem budget is a compiler default,
+            # not the hardware ceiling; long-context shapes need headroom
+            # (vLLM's TPU backend raises it the same way).
+            vmem_limit_bytes=64 << 20,
+            k_scale=unit,
+            v_scale=unit,
+        )
     if impl != "xla":
         raise ValueError(f"unknown ragged attention impl {impl!r}")
 

@@ -48,18 +48,16 @@ def make_mesh(
 ) -> Mesh:
     n = cfg.num_devices
     if devices is None:
+        # The default backend's devices and no other: on the CPU backend
+        # these are the virtual devices tests and dry runs ask for
+        # (--xla_force_host_platform_device_count); a TPU backend with too
+        # few chips raises rather than quietly meshing CPU devices.
         devices = jax.devices()
-        if len(devices) < n:
-            # Virtual CPU mesh fallback (tests / dry-runs use
-            # --xla_force_host_platform_device_count; SURVEY.md §4).
-            try:
-                cpus = jax.devices("cpu")
-            except RuntimeError:
-                cpus = []
-            if len(cpus) >= n:
-                devices = cpus
     if len(devices) < n:
-        raise ValueError(f"need {n} devices for {cfg}, have {len(devices)}")
+        raise ValueError(
+            f"need {n} {jax.default_backend()} devices for {cfg}, "
+            f"have {len(devices)}"
+        )
     # sp adjacent to tp: K/V ring hops between sp neighbors stay one ICI
     # hop for standard torus topologies.
     grid = np.array(devices[:n]).reshape(cfg.dp, cfg.ep, cfg.sp, cfg.tp)

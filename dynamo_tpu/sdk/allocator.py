@@ -15,6 +15,24 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
 
+def count_chips() -> int:
+    """Chips on this host, counted WITHOUT touching JAX: the supervisor
+    that allocates must never hold a chip its workers need.  Order:
+    ``DYN_TPU_CHIPS``, else the TPU device files (``/dev/accel*`` on v4/v5
+    hosts, ``/dev/vfio/<n>`` where the runtime uses vfio)."""
+    env = os.environ.get("DYN_TPU_CHIPS", "").strip()
+    if env:
+        return int(env)
+    import glob
+
+    accel = glob.glob("/dev/accel[0-9]*")
+    if accel:
+        return len(accel)
+    return sum(
+        1 for p in glob.glob("/dev/vfio/*") if os.path.basename(p).isdigit()
+    )
+
+
 @dataclass
 class Allocation:
     env: Dict[str, str] = field(default_factory=dict)
@@ -26,16 +44,7 @@ class TpuAllocator:
 
     def __init__(self, total_chips: Optional[int] = None):
         if total_chips is None:
-            total_chips = int(os.environ.get("DYN_TPU_CHIPS", "0") or 0)
-            if total_chips == 0:
-                try:
-                    import jax
-
-                    total_chips = sum(
-                        1 for d in jax.devices() if d.platform == "tpu"
-                    )
-                except Exception:
-                    total_chips = 0
+            total_chips = count_chips()
         self.total_chips = total_chips
         self._next = 0
 

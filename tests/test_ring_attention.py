@@ -238,6 +238,15 @@ def test_sp_prefill_prefix_survives_pool_flood():
         # The pinned prefix survived the flood: admission saw the sp-sealed
         # blocks as cache hits instead of recomputing everything.
         assert engine.kv.matched_blocks >= 12, engine.kv.matched_blocks
+        # The finish item reaches the consumer BEFORE the row retires: the
+        # continuous decode pipeline (PR 10) frees a finished row's slot
+        # and blocks only once every chunk dispatched while it was live
+        # has passed the write barrier.  Wait for the loop to go idle
+        # (bounded), then check nothing leaked.
+        for _ in range(200):
+            if engine.scheduler.num_running == 0:
+                break
+            await asyncio.sleep(0.01)
         assert engine.scheduler.num_running == 0
         # Pin fully released after admission: nothing leaks.
         await asyncio.sleep(0)

@@ -1,0 +1,164 @@
+"""Compile the serving path's attention kernels for a DESCRIBED TPU v5e.
+
+No chip is attached here: the chip's compiler (libtpu) is installed and
+compiles for ``topologies.get_topology_desc("tpu", "v5e:2x2")``.  What it
+refuses here it refuses on the chip — a slice not aligned to the tiling, more
+VMEM than a kernel may use — and interpret mode can show neither.  A compile
+that passes is not a chip run (``chip_smoke.py`` is).
+
+The topology is described inside a module-scoped fixture and nowhere else:
+one process at a time may load libtpu, so the call must not run at import, in
+a ``skipif``/``parametrize`` argument or in conftest.py, and these tests stay
+in this ONE file (another file could land on another xdist worker, whose
+fixture would skip in silence).  Compiles run in the test's own process with
+the persistent compilation cache off around them.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from dynamo_tpu.ops.decode_attention import fused_decode_attention
+from dynamo_tpu.ops.prefill_attention import fused_prefill_attention
+from dynamo_tpu.ops.ragged_attention import ragged_decode_attention
+
+# (q heads, kv heads) per shard; head_dim 128 and page size 16 throughout.
+GEOMETRY = {
+    "qwen2.5-7b": (28, 4),
+    "llama-3.1-8b": (32, 8),
+    "llama-3.1-8b-tp4": (8, 2),
+}
+D, PS = 128, 16
+LAYERS_X_PAGES = 28 * 2048  # the engine's layer-merged page slab
+CTX = 4096  # --max-model-len of the smoke's deployment
+ROWS = 64
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no libtpu / lock held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def sds(topo):
+    """ShapeDtypeStruct factory placed on one described chip."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip
+    )
+
+
+@pytest.fixture()
+def no_persistent_cache():
+    # A compile for a described chip is written to the persistent cache but
+    # cannot be read back without a chip (JAX warns and recompiles).
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile(fn, *shapes) -> None:
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    # Compiled by Mosaic for the chip, not interpreted.
+    assert "tpu_custom_call" in text
+
+
+def _decode_shapes(sds, model, page_dtype):
+    H, KV = GEOMETRY[model]
+    PP = CTX // PS
+    return (
+        sds((ROWS, H, D), jnp.bfloat16),
+        sds((LAYERS_X_PAGES, PS, 2 * KV, D), jnp.dtype(page_dtype)),
+        sds((ROWS,), jnp.int32),
+        sds((ROWS, PP), jnp.int32),
+        sds((1,), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("page_dtype", ["int8", "bfloat16"])
+@pytest.mark.parametrize("model", list(GEOMETRY))
+def test_fused_decode_kernel_compiles(
+    sds, no_persistent_cache, model, page_dtype
+):
+    """The kernel ``auto`` picks for decode on a TPU, with a TRACED
+    kv_scale (the per-layer calibration vector's element)."""
+
+    def fn(q, pages, kv_lens, tables, num, scale):
+        return fused_decode_attention(
+            q, pages, kv_lens, tables, num, sm_scale=D**-0.5,
+            kv_scale=scale, interpret=False,
+        )
+
+    _compile(fn, *_decode_shapes(sds, model, page_dtype), sds((), jnp.float32))
+
+
+@pytest.mark.parametrize("page_dtype", ["int8", "bfloat16"])
+@pytest.mark.parametrize("model", list(GEOMETRY))
+def test_prefill_kernel_compiles(sds, no_persistent_cache, model, page_dtype):
+    """The kernel ``auto`` picks for prefill on a TPU: a 2048-token chunk
+    against a 4096-token paged context."""
+    H, KV = GEOMETRY[model]
+    T, PP = 2048, CTX // PS
+
+    def fn(q, pages, kv_lens, tables, cu, num, scale):
+        return fused_prefill_attention(
+            q, pages, kv_lens, tables, cu, num, sm_scale=D**-0.5,
+            kv_scale=scale, interpret=False,
+        )
+
+    _compile(
+        fn,
+        sds((T, H, D), jnp.bfloat16),
+        sds((LAYERS_X_PAGES, PS, 2 * KV, D), jnp.dtype(page_dtype)),
+        sds((ROWS,), jnp.int32),
+        sds((ROWS, PP), jnp.int32),
+        sds((ROWS + 1,), jnp.int32),
+        sds((1,), jnp.int32),
+        sds((), jnp.float32),
+    )
+
+
+@pytest.mark.parametrize("page_dtype", ["int8", "bfloat16"])
+@pytest.mark.parametrize("model", list(GEOMETRY))
+def test_stock_decode_with_repo_hints_compiles(
+    sds, no_persistent_cache, model, page_dtype
+):
+    """``decode_kernel=stock``: the jax ragged_paged_attention kernel under
+    the repo's decode block hints (ops/ragged_attention.py
+    _decode_block_hints)."""
+
+    def fn(q, pages, kv_lens, tables, num):
+        return ragged_decode_attention(
+            q, pages, kv_lens, tables, num, sm_scale=D**-0.5, impl="tpu",
+            kernel="stock",
+        )
+
+    _compile(fn, *_decode_shapes(sds, model, page_dtype))
+
+
+def test_one_kv_head_per_shard_is_refused_with_a_sentence():
+    """qwen2.5-7b at tp=4 with int8 pages leaves one KV head per shard,
+    which no kernel here compiles for: the engine says so at validation
+    (before allocating anything), not inside Mosaic."""
+    from dynamo_tpu.engine.config import EngineConfig
+    from dynamo_tpu.engine.engine import TpuEngine
+
+    cfg = EngineConfig(
+        model="qwen2.5-7b", tp=4, cache_dtype="int8", kv_scale=0.05,
+        weight_quant="int8", attn_impl="tpu",
+    )
+    with pytest.raises(ValueError, match="KV head.* per shard"):
+        TpuEngine(cfg)

@@ -64,7 +64,7 @@ env JAX_PLATFORMS=cpu python -m pytest tests/test_kv_integrity.py -q -m integrit
 echo "== prefix-reuse smoke (BENCH_PREFIX=1: tiers off/host/disk/pull;"
 echo "   bars: >=90% prefill skipped on 2nd occurrence, pull serves a"
 echo "   never-computed prefix, byte-identical streams, stable compiles) =="
-env JAX_PLATFORMS=cpu BENCH_PREFIX=1 python bench.py > /tmp/_prefix_smoke.json
+env JAX_PLATFORMS=cpu BENCH_PREFIX=1 python bench.py --cpu-smoke > /tmp/_prefix_smoke.json
 python - <<'PYEOF'
 import json
 r = json.loads(open("/tmp/_prefix_smoke.json").read().strip().splitlines()[-1])
@@ -99,8 +99,8 @@ echo "== continuous-decode churn smoke (CPU bench: staggered finishes +"
 echo "   late arrivals, FUSED decode kernel; bars: fewer rebuilds than"
 echo "   forced-rebuild control, exact streams, zero new compiles,"
 echo "   pallas_fused actually served the run, dispatch metrics parseable) =="
-env JAX_PLATFORMS=cpu DYN_DECODE_KERNEL=pallas_fused BENCH_CHURN=1 \
-  python bench.py > /tmp/_churn_smoke.json
+env JAX_PLATFORMS=cpu DYN_PALLAS_INTERPRET=1 DYN_DECODE_KERNEL=pallas_fused BENCH_CHURN=1 \
+  python bench.py --cpu-smoke > /tmp/_churn_smoke.json
 python - <<'PYEOF'
 import json, math
 r = json.loads(open("/tmp/_churn_smoke.json").read().strip().splitlines()[-1])

@@ -17,8 +17,8 @@ top-1 agreement on DECISIVE positions (reference top-2 margin > 3x the
 observed max logit error — random-init logits are near-ties, so raw
 agreement under-reports; decisive agreement is the honest gate).
 
-Writes benchmarks/results/r5_quant_quality.json; render_results.py
-renders the RESULTS.md table from it.  Run on CPU:
+Writes benchmarks/results/r5_quant_quality.json (a CPU product: quality
+numbers, no timings).  Run on CPU:
     JAX_PLATFORMS=cpu python tools/quant_quality.py
 """
 

@@ -20,16 +20,17 @@ when no entry matches.  Two knob families:
   geometry (every row one ``--prefill-chunk`` tail against a full-chain
   paged prefix).
 
-On CPU the fused kernel runs in interpret mode, so absolute timings are
-meaningless — the sweep is a smoke (it still exercises every combo and
-the table write path); run on the v5e for numbers of record.  Resolution
+On a CPU the kernels run only under the Pallas interpreter, and only when
+asked (``DYN_PALLAS_INTERPRET=1``): timings are then meaningless — the sweep
+is a smoke of every combo and the table write path; run on the v5e for
+numbers of record.  Resolution
 order stays: explicit env var > tuned table > default, so a sweep never
 overrides an operator's pin.
 
 Example:
     python -m tools.tune_decode --model llama-3.1-8b --batch 256 \
         --page-size 32 --pages-per-seq 64 --cache-dtype int8 \
-        --out ~/.cache/dynamo_tpu/decode_tune.json
+        --out decode_tune.json
 """
 
 from __future__ import annotations
@@ -279,7 +280,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="prefill pages-per-compute-block candidates")
     ap.add_argument("--out", default=None,
                     help="table path (default: DYN_DECODE_TUNE_TABLE or "
-                         "~/.cache/dynamo_tpu/decode_tune.json)")
+                         "decode_tune.json at the repo root)")
     args = ap.parse_args(argv)
 
     from dynamo_tpu.ops.decode_attention import default_table_path, hint_key
