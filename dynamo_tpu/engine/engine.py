@@ -37,6 +37,7 @@ from typing import Any, AsyncIterator, Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..llm.kv_router.protocols import ForwardPassMetrics, KvCacheEvent
 from ..llm.protocols import FinishReason, LLMEngineOutput, PreprocessedRequest
@@ -1221,8 +1222,8 @@ class TpuEngine(
         if trace is not None:
             from ..runtime.tracing import SeqTrace
 
-            # Anchors queue-wait (scheduler._record_admission) and prefill
-            # (first-token accept, pipeline._trace_first_token) spans.
+            # Marks the row for the queue-wait (scheduler._record_admission)
+            # and prefill (pipeline._trace_first_token) spans.
             seq.trace = SeqTrace(trace)
         if automaton is not None:
             seq.grammar = automaton
@@ -1266,6 +1267,13 @@ class TpuEngine(
         self._contexts[request.id] = request.ctx
         self.scheduler.add(seq)
         self._wake.set()
+        # Hop account (docs/tracing.md): a colocated edge reads the queue
+        # entry off the in-process context.  A negative value was put there
+        # upstream (disagg remote prefill): this sequence's account is void.
+        if request.ctx.t_enqueue < 0.0:
+            seq.t_first_chunk = -1.0
+        else:
+            request.ctx.t_enqueue = seq.enqueue_t
         # Server-side seed resolution (llm/qos satellite): UNSEEDED sampled
         # requests get their engine-assigned seed stamped onto the first
         # stream item, so the routed client's _StreamGuard can build a
@@ -1617,7 +1625,8 @@ class TpuEngine(
                 logger.exception("deferred fetch failed")
                 self._fail_all()
                 return
-            plan = self.scheduler.schedule()
+            with TraceAnnotation("engine.schedule"):
+                plan = self.scheduler.schedule()
             self._note_prefill_requeues()
             for seq in self.scheduler.take_rejected():
                 self._finish(seq, FinishReason.ERROR)

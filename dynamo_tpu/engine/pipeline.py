@@ -16,12 +16,13 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 logger = logging.getLogger(__name__)
 
 from collections import deque
 
-from ..llm.metrics import tenancy_metrics
+from ..llm.metrics import request_hop_metrics, tenancy_metrics
 from ..llm.protocols import FinishReason, LLMEngineOutput
 from ..ops.sampling import SamplingParams
 from .scheduler import RowSlots, SequenceState, StepPlan
@@ -255,15 +256,27 @@ class DecodePipelineMixin:
             await self._harvest_pending()  # free: task already complete
 
         def run():
-            out, self.cache = step(self.params, self.cache, rb_d, samp_d)
-            if need_tokens:
-                # Start the D2H now; the accept is deferred to a harvest
-                # point so the round trip overlaps later dispatches.
-                self._start_d2h(out, need_lp)
+            with TraceAnnotation("engine.dispatch:unified"):
+                out, self.cache = step(self.params, self.cache, rb_d, samp_d)
+                if need_tokens:
+                    # Start the D2H now; the accept is deferred to a harvest
+                    # point so the round trip overlaps later dispatches.
+                    self._start_d2h(out, need_lp)
             return out
 
         await self._pace()
         t0 = time.perf_counter()
+        # Hop account, one comparison per row per dispatch: a row still in
+        # its prompt is stamped before its first chunk's dispatch, and the
+        # rows whose FINAL prompt token this step carries are kept — their
+        # first token is what the fetch below brings to the host.
+        first_rows: Optional[List[SequenceState]] = None
+        for seq, start, n in plan.items:
+            if seq.t_last_chunk == 0.0:
+                if seq.t_first_chunk == 0.0:
+                    seq.t_first_chunk = t0
+                if start + n >= len(seq.prompt):
+                    first_rows = (first_rows or []) + [seq]
         async with self._device_lock:
             # Publish INSIDE the device lock: broadcast order must equal
             # device enqueue order or followers replay a different program
@@ -298,6 +311,9 @@ class DecodePipelineMixin:
         if prefill_tokens > 0:
             self._note_prefill_chunk(wall, prefill_tokens)
 
+        if first_rows:
+            for seq in first_rows:
+                seq.t_last_chunk = t0 + wall
         pending_rows: List[Tuple[SequenceState, int]] = []
         for i, (seq, start, n) in enumerate(plan.items):
             if seq.finished:
@@ -315,7 +331,9 @@ class DecodePipelineMixin:
                 seq.awaiting_fetch = True
                 pending_rows.append((seq, i))
         if pending_rows:
-            self._stash_fetch("first", out, need_lp, pending_rows)
+            self._stash_fetch(
+                "first", out, need_lp, pending_rows, first_rows=first_rows
+            )
 
     async def _pace(self) -> None:
         """Await the injectable test pace hook (engine.py pace_hook)
@@ -391,14 +409,33 @@ class DecodePipelineMixin:
             )
         return np.asarray(out.tokens), None, None, None
 
-    def _stash_fetch(self, kind: str, out, need_lp: bool, *meta) -> None:
+    @staticmethod
+    def _fetch_first(out, need_lp: bool, first_rows: List[SequenceState]):
+        """``_fetch_outs`` for a step that completed prompts: stamps the
+        moment the sampled tokens are on the host (hop account
+        ``t_fetch_done``) — here on the fetch thread, where the copy ends,
+        so the event loop's latency stays out of the device half.  One
+        clock read per fetch; only rows awaiting their FIRST token."""
+        res = DecodePipelineMixin._fetch_outs(out, need_lp)
+        now = request_hop_metrics.now()
+        for seq in first_rows:
+            seq.t_fetch_done = now
+        return res
+
+    def _stash_fetch(self, kind: str, out, need_lp: bool, *meta,
+                     first_rows=None) -> None:
         """Park a dispatched step's token fetch: the np.asarray runs on a
         worker thread STARTING NOW (the D2H was already initiated with
         copy_to_host_async), and the loop applies the result at a harvest
         point once the task completes — the device round trip never blocks
         dispatching."""
+        fetch = (
+            (self._fetch_first, out, need_lp, first_rows)
+            if first_rows
+            else (self._fetch_outs, out, need_lp)
+        )
         task = asyncio.get_running_loop().create_task(
-            asyncio.to_thread(self._fetch_outs, out, need_lp)
+            asyncio.to_thread(*fetch)
         )
         self._pending_fetches.append((kind, task, *meta))
 
@@ -411,58 +448,73 @@ class DecodePipelineMixin:
 
             await self._pace()
             t0 = time.perf_counter()
-            sampled, logp, top_ids, top_lp = await self._await_device(
-                task, f"{kind}_fetch", len(entry[2])
-            )
+            with TraceAnnotation(f"engine.harvest:{kind}"):
+                sampled, logp, top_ids, top_lp = await self._await_device(
+                    task, f"{kind}_fetch", len(entry[2])
+                )
+            t1 = time.perf_counter()
             self.step_trace.append(
-                (
-                    f"{kind}_harvest",
-                    time.perf_counter() - t0,
-                    len(entry[2]),
-                    0,
-                )
+                (f"{kind}_harvest", t1 - t0, len(entry[2]), 0)
             )
-            if kind == "first":
-                for seq, i in entry[2]:
-                    seq.awaiting_fetch = False
-                    if seq.finished:
-                        continue  # cancelled while the token was in flight
-                    self._accept_token(
-                        seq,
-                        int(sampled[i]),
-                        logprobs=self._lp_info(seq, i, logp, top_ids, top_lp),
-                    )
-            elif kind == "spec":  # speculative verification (engine/spec.py)
-                self._harvest_spec(entry, sampled, logp, top_ids, top_lp)
-            else:  # burst
-                members, pos0 = entry[2], entry[3]
-                chained = entry[4] if len(entry) > 4 else False
-                finished: List[SequenceState] = []
-                self._accept_chunk(
-                    members, pos0, sampled, logp, top_ids, top_lp, finished
+            with TraceAnnotation("engine.emit"):
+                self._apply_harvest(
+                    kind, entry, sampled, logp, top_ids, top_lp, t1
                 )
-                if chained:
-                    # A chained burst chunk for these rows is still in
-                    # flight (_decode_burst's pipelined shape): keep them
-                    # parked — freeze_sequence's quiescence poll must see
-                    # the in-flight tokens — and defer removals to the
-                    # final chunk's harvest, so no member's blocks are
-                    # freed while a dispatch that writes them is in flight.
-                    for seq in members:
-                        if not seq.finished:
-                            seq.awaiting_fetch = True
-                else:
-                    # Sweep by flag, not the local ``finished`` list: a row
-                    # that stopped in the FIRST chunk of a chained burst is
-                    # skipped by this chunk's accept and must still be
-                    # removed here.
-                    for seq in members:
-                        if seq.finished and any(
-                            s is seq for s in self.scheduler.running
-                        ):
-                            self.scheduler.remove(seq)
             if not all_pending:
                 break
+
+    def _apply_harvest(
+        self, kind: str, entry, sampled, logp, top_ids, top_lp, t1: float
+    ) -> None:
+        """Accept one harvested fetch's tokens (``t1``: when the loop had
+        them) and hand them to the streams."""
+        if kind == "first":
+            for seq, i in entry[2]:
+                seq.awaiting_fetch = False
+                if seq.finished:
+                    continue  # cancelled while the token was in flight
+                if seq.t_first_token == 0.0:
+                    # Hop account: the first token's accept — also
+                    # handed to a colocated edge on the in-process
+                    # context object (never on the stream item).
+                    seq.t_first_token = t1
+                    c = self._contexts.get(seq.request_id)
+                    if c is not None:
+                        c.t_first_token = t1
+                self._accept_token(
+                    seq,
+                    int(sampled[i]),
+                    logprobs=self._lp_info(seq, i, logp, top_ids, top_lp),
+                )
+        elif kind == "spec":  # speculative verification (engine/spec.py)
+            self._harvest_spec(entry, sampled, logp, top_ids, top_lp)
+        else:  # burst
+            members, pos0 = entry[2], entry[3]
+            chained = entry[4] if len(entry) > 4 else False
+            finished: List[SequenceState] = []
+            self._accept_chunk(
+                members, pos0, sampled, logp, top_ids, top_lp, finished
+            )
+            if chained:
+                # A chained burst chunk for these rows is still in
+                # flight (_decode_burst's pipelined shape): keep them
+                # parked — freeze_sequence's quiescence poll must see
+                # the in-flight tokens — and defer removals to the
+                # final chunk's harvest, so no member's blocks are
+                # freed while a dispatch that writes them is in flight.
+                for seq in members:
+                    if not seq.finished:
+                        seq.awaiting_fetch = True
+            else:
+                # Sweep by flag, not the local ``finished`` list: a row
+                # that stopped in the FIRST chunk of a chained burst is
+                # skipped by this chunk's accept and must still be
+                # removed here.
+                for seq in members:
+                    if seq.finished and any(
+                        s is seq for s in self.scheduler.running
+                    ):
+                        self.scheduler.remove(seq)
 
     async def _decode_pipeline(self, members: List[SequenceState]) -> bool:
         """Continuous fused decode: multi-step dispatches with the token
@@ -806,9 +858,10 @@ class DecodePipelineMixin:
                 d_args = (pos0, tables, limits, samp)
 
             def run(args=d_args, tok_in=c_tok, st=c_steps, ct=c_counts):
-                outs, last, steps_f, counts_f, self.cache = multi(
-                    self.params, self.cache, tok_in, st, ct, *args
-                )
+                with TraceAnnotation("engine.dispatch:decode"):
+                    outs, last, steps_f, counts_f, self.cache = multi(
+                        self.params, self.cache, tok_in, st, ct, *args
+                    )
                 return outs, (last, steps_f, counts_f)
 
             await self._pace()
@@ -879,7 +932,8 @@ class DecodePipelineMixin:
                 and samp is not None
                 and in_flight_now < depth
             ):
-                pos0 = plan_chunk()
+                with TraceAnnotation("engine.schedule"):
+                    pos0 = plan_chunk()
                 if pos0 is None:
                     break
                 await dispatch_chunk(pos0)
@@ -900,9 +954,10 @@ class DecodePipelineMixin:
 
             if fetch_task is not None:
                 await self._pace()
-                sampled, logp, top_ids, top_lp = await self._await_device(
-                    fetch_task, "decode_wait", slots.num_active
-                )
+                with TraceAnnotation("engine.harvest:decode"):
+                    sampled, logp, top_ids, top_lp = await self._await_device(
+                        fetch_task, "decode_wait", slots.num_active
+                    )
                 wait_wall = time.perf_counter() - wait_t0
                 self.decode_busy_s += wait_wall
                 self.step_trace.append(
@@ -916,9 +971,10 @@ class DecodePipelineMixin:
                         slots.num_active * T,
                     )
                 )
-                self._accept_chunk(
-                    slots.rows, pos0_c, sampled, logp, top_ids, top_lp, []
-                )
+                with TraceAnnotation("engine.emit"):
+                    self._accept_chunk(
+                        slots.rows, pos0_c, sampled, logp, top_ids, top_lp, []
+                    )
                 harvested = cid
                 if not rebuild and self._spec_session_probe(
                     [s for _, s in slots.active()]
@@ -1027,14 +1083,16 @@ class DecodePipelineMixin:
         multi = self._multi_fn
 
         def run():
-            outs, last, steps_f, counts_f, self.cache = multi(
-                self.params, self.cache, c_tok, c_steps, samp.counts, *d_args
-            )
-            # Async D2H + deferred accept: the burst's tokens are only
-            # needed at the next harvest point (its rows are parked), so
-            # the round trip overlaps the following prefill chunks instead
-            # of stalling behind the device queue.
-            self._start_d2h(outs, need_lp)
+            with TraceAnnotation("engine.dispatch:burst"):
+                outs, last, steps_f, counts_f, self.cache = multi(
+                    self.params, self.cache, c_tok, c_steps, samp.counts,
+                    *d_args
+                )
+                # Async D2H + deferred accept: the burst's tokens are only
+                # needed at the next harvest point (its rows are parked),
+                # so the round trip overlaps the following prefill chunks
+                # instead of stalling behind the device queue.
+                self._start_d2h(outs, need_lp)
             return outs, (last, steps_f, counts_f)
 
         await self._pace()
@@ -1065,10 +1123,11 @@ class DecodePipelineMixin:
             d_args_b = (pos0b, tables, limits, samp)
 
         def run_b():
-            outs, last, steps_f, counts_f, self.cache = multi(
-                self.params, self.cache, *carry, *d_args_b
-            )
-            self._start_d2h(outs, need_lp)
+            with TraceAnnotation("engine.dispatch:burst"):
+                outs, last, steps_f, counts_f, self.cache = multi(
+                    self.params, self.cache, *carry, *d_args_b
+                )
+                self._start_d2h(outs, need_lp)
             return outs
 
         await self._pace()
@@ -1283,7 +1342,7 @@ class DecodePipelineMixin:
         now = time.perf_counter()
         trace_collector.record(
             st.ctx, "engine.prefill", "engine",
-            st.t_admit or st.t_enqueue, now,
+            seq.t_admit or seq.enqueue_t, now,
             attrs={
                 "prompt_tokens": len(seq.prompt),
                 "cached_tokens": seq.num_cached_prompt,
@@ -1296,10 +1355,14 @@ class DecodePipelineMixin:
         dispatch — the ISSUE 15 granularity contract: decode records at
         chunk (dispatch) granularity only, never per token.  Untraced rows
         cost one attr check per chunk; rows whose first token hasn't
-        landed yet are skipped (their wall belongs to engine.prefill)."""
+        landed yet are skipped (their wall belongs to engine.prefill).
+        Every row's first fused dispatch also latches its ``t_join`` stamp
+        (hop account): one more comparison per row per dispatch."""
         for _i, seq in rows:
             if seq is None:
                 continue
+            if seq.t_join == 0.0:
+                seq.t_join = t0  # hop account: first fused dispatch of the row
             st = seq.trace
             if st is None or not st.first_done:
                 continue
@@ -1386,6 +1449,39 @@ class DecodePipelineMixin:
             return FinishReason.LENGTH
         return None
 
+    def _fold_hops(self, seq: SequenceState) -> None:
+        """The engine's fold of the hop account, once per sequence at its
+        end: neighbouring stamps become sums on ``/metrics`` and, for a
+        sampled request, the spans of the same intervals.  A sequence that
+        was resumed, migrated in or prefilled again after a preemption
+        (its prompt no longer the original) is incomplete by definition."""
+        seq.hops_folded = True
+        fresh = len(seq.prompt) == seq.orig_prompt_len
+        ok = request_hop_metrics.fold_engine(
+            seq.enqueue_t if fresh else 0.0, seq.t_admit, seq.t_first_chunk,
+            seq.t_last_chunk, seq.t_fetch_done, seq.t_first_token, seq.t_join,
+        )
+        st = seq.trace
+        if st is None or not ok:
+            return
+        from ..runtime.tracing import _wall_ms
+        from ..runtime.tracing import collector as trace_collector
+
+        trace_collector.record(
+            st.ctx, "engine.prefill_wait", "engine",
+            seq.t_admit, seq.t_first_chunk,
+        )
+        trace_collector.record(
+            st.ctx, "engine.prefill_run", "engine",
+            seq.t_first_chunk, seq.t_last_chunk,
+        )
+        trace_collector.record(
+            st.ctx, "engine.first_fetch", "engine",
+            seq.t_last_chunk, seq.t_first_token,
+            events=[{"name": "fetch_done",
+                     "t_ms": round(_wall_ms(seq.t_fetch_done), 3)}],
+        )
+
     def _finish(self, seq: SequenceState, reason: FinishReason) -> None:
         # Drop the adapter-slot pin BEFORE the queue check: every finish
         # path funnels here (including cancelled/error streams whose queue
@@ -1397,6 +1493,8 @@ class DecodePipelineMixin:
         ):
             seq.adapter_released = True
             self._lora_registry.release(seq.adapter)
+        if not seq.hops_folded:
+            self._fold_hops(seq)
         queue = self._queues.get(seq.request_id)
         if queue is None:
             return

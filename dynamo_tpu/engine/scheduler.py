@@ -90,6 +90,20 @@ class SequenceState:
     # newcomer waiting out a fused pure-decode session (r5 stall
     # diagnosis); admission_waits records it per request.
     enqueue_t: float = 0.0
+    # --- hop account (llm/metrics.py RequestHopMetrics; docs/tracing.md) ---
+    # time.perf_counter stamps, 0.0 = not taken, first write wins, each set
+    # once per request and folded into sums at pipeline._finish:
+    # admission; before the dispatch of the first step carrying prompt
+    # tokens of the row; after the dispatch of its final prompt token; the
+    # first token on the host (fetch thread); its accept at a harvest
+    # point; the first fused decode dispatch that carries the row.
+    t_admit: float = 0.0
+    t_first_chunk: float = 0.0
+    t_last_chunk: float = 0.0
+    t_fetch_done: float = 0.0
+    t_first_token: float = 0.0
+    t_join: float = 0.0
+    hops_folded: bool = False
     # --- speculative decoding (engine/spec.py) ---
     # Per-request opt-out (sampling_options.spec_decode=false via nvext).
     spec_enabled: bool = True
@@ -539,11 +553,12 @@ class Scheduler:
         now = time.perf_counter()
         if seq.enqueue_t:
             self.admission_waits.append(now - seq.enqueue_t)
+        if seq.t_admit == 0.0:
+            seq.t_admit = now
         st = seq.trace
         if st is not None:
             from ..runtime.tracing import collector as trace_collector
 
-            st.t_admit = now
             trace_collector.record(
                 st.ctx, "engine.queue_wait", "engine",
                 seq.enqueue_t or now, now,

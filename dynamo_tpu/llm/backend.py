@@ -18,6 +18,7 @@ from typing import Any, AsyncIterator, Dict, List, Optional, Tuple
 
 from ..runtime.engine import AsyncEngine, Context, ResponseStream
 from ..runtime.pipeline import Operator
+from .metrics import request_hop_metrics
 from .protocols import FinishReason, PreprocessedRequest, StopConditions
 from .tokenizer import BaseTokenizer
 
@@ -132,10 +133,17 @@ class Backend(Operator):
         prompt_tokens = len(pre.token_ids)
         completion_tokens = 0
         finished = False
+        # Hop account (docs/tracing.md): stamp the arrival of the first
+        # engine item that carries a token, BEFORE the detokenizer can hold
+        # its text back.  One local test per item, one clock read a request.
+        unstamped = True
         try:
             async for out in stream:
                 if finished:
                     break
+                if unstamped and out.get("token_ids"):
+                    unstamped = False
+                    request.ctx.t_edge_item = request_hop_metrics.now()
                 engine_finish = out.get("finish_reason")
                 emit_text = ""
                 finish: Optional[FinishReason] = None

@@ -172,6 +172,10 @@ class DisaggDecodeWorker(AsyncEngine):
                 deadline=getattr(request.ctx, "deadline", None),
                 annotations=pre.annotations,
             )
+            # The prompt was computed elsewhere: the engine's TTFT hop
+            # account of this request would describe a suffix (the engine
+            # counts it incomplete; docs/tracing.md).
+            request.ctx.t_enqueue = -1.0
         else:
             self.local_prefills += 1
         return await self.engine.generate(request)

@@ -289,6 +289,7 @@ class HttpService:
             kv_tier_metrics,
             migration_metrics,
             objstore_metrics,
+            request_hop_metrics,
             spec_metrics,
             tenancy_metrics,
         )
@@ -307,6 +308,7 @@ class HttpService:
             + health_metrics.render(self._metrics_prefix).encode()
             + qos_metrics.render(self._metrics_prefix).encode()
             + engine_dispatch_metrics.render(self._metrics_prefix).encode()
+            + request_hop_metrics.render(self._metrics_prefix).encode()
             + kv_tier_metrics.render(self._metrics_prefix).encode()
             + kv_integrity_metrics.render(self._metrics_prefix).encode()
             + objstore_metrics.render(self._metrics_prefix).encode()
@@ -540,6 +542,7 @@ class HttpService:
         deadline_s = _requested_deadline(request, body, self.default_deadline_s)
         if deadline_s is not None:
             ctx.ctx.deadline = Deadline.after(deadline_s)
+        ert.ctx = ctx.ctx
         if ert.tc is not None:
             # Downstream propagation: the preprocessor stamps this onto
             # ``annotations.trace``; the service transport ships it in the
@@ -681,8 +684,10 @@ class HttpService:
                         b"event: annotation\n" + sse_encode(chunk["__annotations__"])
                     )
                     continue
-                guard.on_token()
                 await resp.write(sse_encode(chunk))
+                # AFTER the write: the first call latches t_edge_sent, the
+                # end of the hop account's server-side TTFT.
+                guard.on_token()
             await resp.write(SSE_DONE)
         except (ConnectionResetError, asyncio.CancelledError):  # dynalint: disable=DYN003
             # Client went away: aiohttp cancels this handler on disconnect.

@@ -697,7 +697,8 @@ class EngineDispatchMetrics:
         pf = s.get("prefill", {})
         if pf:
             pn = f"{prefix}_prefill_chunk_seconds"
-            lines.append(f"# HELP {pn} Prefill chunk dispatch wall time")
+            lines.append(f"# HELP {pn} Wall of ENQUEUEING a prefill chunk's "
+                         "asynchronous dispatch (not the chunk's device time)")
             lines.append(f"# TYPE {pn} summary")
             for q, key in (("0.5", "p50_ms"), ("0.99", "p99_ms")):
                 lines.append(
@@ -717,6 +718,118 @@ class EngineDispatchMetrics:
 
 
 engine_dispatch_metrics = EngineDispatchMetrics()
+
+
+class RequestHopMetrics:
+    """The always-on per-request TTFT/TPOT hop account (docs/tracing.md):
+    sums and counts of the intervals between the O(1) stamps a request
+    collects on its way through the edge and the engine.  Two folds per
+    request — ``fold_engine`` (engine/pipeline.py ``_finish``) and
+    ``fold_edge`` (llm/trace_service.py ``EdgeRequestTrace.finish``) — add
+    plain floats to a fixed table; names and text exist only in
+    ``render``.  Module-level singleton appended to ``/metrics`` (same
+    pattern as ``spec_metrics``): ``{prefix}_request_hop_seconds_sum`` /
+    ``_count`` per ``hop`` and ``{prefix}_request_hop_incomplete_total``
+    per ``side``.  A stamp is 0.0 until taken; a request that ends with
+    an engine stamp missing or out of order (cancelled before its first
+    token, error, preempted and prefilled again, resumed or migrated
+    stream, remote prefill) counts as incomplete and adds to no hop."""
+
+    # In request order; ``edge_pre`` .. ``edge_emit`` sum to ``server_ttft``.
+    HOPS = (
+        "edge_pre", "queue_wait", "prefill_wait", "prefill_run",
+        "first_fetch_device", "first_fetch_harvest", "edge_handoff",
+        "edge_emit", "server_ttft", "join_wait",
+    )
+    (EDGE_PRE, QUEUE_WAIT, PREFILL_WAIT, PREFILL_RUN, FIRST_FETCH_DEVICE,
+     FIRST_FETCH_HARVEST, EDGE_HANDOFF, EDGE_EMIT, SERVER_TTFT,
+     JOIN_WAIT) = range(len(HOPS))
+
+    def __init__(self):
+        self.sums = [0.0] * len(self.HOPS)
+        self.counts = [0] * len(self.HOPS)
+        self.incomplete_engine = 0
+        self.incomplete_edge = 0
+
+    def reset(self) -> None:
+        self.__init__()
+
+    # The one clock of the account, the span plane's (runtime/tracing.py).
+    now = staticmethod(time.perf_counter)
+
+    def fold_engine(self, t_enqueue: float, t_admit: float,
+                    t_first_chunk: float, t_last_chunk: float,
+                    t_fetch_done: float, t_first_token: float,
+                    t_join: float) -> bool:
+        """One finished sequence's engine hops; False = incomplete."""
+        if not (0.0 < t_enqueue <= t_admit <= t_first_chunk <= t_last_chunk
+                <= t_fetch_done <= t_first_token):
+            self.incomplete_engine += 1
+            return False
+        s, c = self.sums, self.counts
+        s[self.QUEUE_WAIT] += t_admit - t_enqueue
+        s[self.PREFILL_WAIT] += t_first_chunk - t_admit
+        s[self.PREFILL_RUN] += t_last_chunk - t_first_chunk
+        s[self.FIRST_FETCH_DEVICE] += t_fetch_done - t_last_chunk
+        s[self.FIRST_FETCH_HARVEST] += t_first_token - t_fetch_done
+        for i in range(self.QUEUE_WAIT, self.FIRST_FETCH_HARVEST + 1):
+            c[i] += 1
+        if t_join >= t_first_token:  # 0.0: never rode a fused dispatch
+            s[self.JOIN_WAIT] += t_join - t_first_token
+            c[self.JOIN_WAIT] += 1
+        return True
+
+    def fold_edge(self, t_edge: float, t_enqueue: float,
+                  t_first_token: float, t_edge_item: float,
+                  t_edge_sent: float) -> bool:
+        """One finished request's edge hops.  The two hops that cross into
+        the engine need its stamps on the shared in-process context; an
+        edge in front of a remote engine reports ``edge_emit`` and
+        ``server_ttft`` alone.  False = incomplete: no engine item with a
+        token reached the Backend operator, or no event was written."""
+        if not 0.0 < t_edge_item <= t_edge_sent:
+            self.incomplete_edge += 1
+            return False
+        s, c = self.sums, self.counts
+        s[self.SERVER_TTFT] += t_edge_sent - t_edge
+        c[self.SERVER_TTFT] += 1
+        s[self.EDGE_EMIT] += t_edge_sent - t_edge_item
+        c[self.EDGE_EMIT] += 1
+        if t_edge <= t_enqueue <= t_first_token <= t_edge_item:
+            s[self.EDGE_PRE] += t_enqueue - t_edge
+            c[self.EDGE_PRE] += 1
+            s[self.EDGE_HANDOFF] += t_edge_item - t_first_token
+            c[self.EDGE_HANDOFF] += 1
+        return True
+
+    def render(self, prefix: str = "dynamo_tpu") -> str:
+        ns = f"{prefix}_request_hop"
+        lines = [
+            f"# HELP {ns}_seconds_sum Seconds requests spent in each hop "
+            "of the TTFT/TPOT account (mean = growth of sum over growth "
+            "of count)",
+            f"# TYPE {ns}_seconds_sum counter",
+        ]
+        for hop, v in zip(self.HOPS, self.sums):
+            lines.append(
+                f'{ns}_seconds_sum{{hop="{escape_label(hop)}"}} {v}')
+        lines.append(f"# HELP {ns}_seconds_count Requests folded into "
+                     "each hop of the account")
+        lines.append(f"# TYPE {ns}_seconds_count counter")
+        for hop, n in zip(self.HOPS, self.counts):
+            lines.append(
+                f'{ns}_seconds_count{{hop="{escape_label(hop)}"}} {n}')
+        lines.append(f"# HELP {ns}_incomplete_total Requests that ended "
+                     "with a stamp missing or out of order (added to no hop)")
+        lines.append(f"# TYPE {ns}_incomplete_total counter")
+        lines.append(
+            f'{ns}_incomplete_total{{side="engine"}} {self.incomplete_engine}')
+        lines.append(
+            f'{ns}_incomplete_total{{side="edge"}} {self.incomplete_edge}')
+        return "\n".join(lines) + "\n"
+
+
+request_hop_metrics = RequestHopMetrics()
 
 
 class KvTierMetrics:

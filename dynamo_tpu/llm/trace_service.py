@@ -29,6 +29,7 @@ from ..runtime.tracing import (
     span,
     tracing_metrics,
 )
+from .metrics import request_hop_metrics
 
 logger = logging.getLogger(__name__)
 
@@ -345,7 +346,8 @@ class EdgeRequestTrace:
     error / SLO-violating request after the fact."""
 
     __slots__ = ("sampler", "tc", "t0", "model", "endpoint", "_admit_t0",
-                 "_admit_t1", "_first_token_t", "_events", "_finished")
+                 "_admit_t1", "_first_token_t", "_events", "_finished",
+                 "ctx")
 
     def __init__(self, sampler: Optional[TraceSampler], headers, body):
         self.sampler = sampler
@@ -360,6 +362,11 @@ class EdgeRequestTrace:
         self._first_token_t: Optional[float] = None
         self._events: List[Dict[str, Any]] = []
         self._finished = False
+        # The request's AsyncEngineContext once an engine was asked
+        # (http_service._admitted_openai): the in-process object a
+        # colocated engine and the Backend operator leave their hop-account
+        # stamps on.  None = the request never reached an engine.
+        self.ctx = None
 
     @property
     def active(self) -> bool:
@@ -383,8 +390,10 @@ class EdgeRequestTrace:
         self._events.append(ev)
 
     def on_first_token(self) -> None:
+        """Latch ``t_edge_sent``: the stream handler calls this AFTER the
+        first event's ``resp.write`` returned (unary: at the first chunk)."""
         if self._first_token_t is None:
-            self._first_token_t = time.perf_counter()
+            self._first_token_t = request_hop_metrics.now()
             self.event("first_token")
 
     @property
@@ -399,6 +408,18 @@ class EdgeRequestTrace:
         if self._finished:
             return
         self._finished = True
+        ctx, t_sent = self.ctx, self._first_token_t or 0.0
+        # The edge's fold of the always-on hop account (the engine's is
+        # pipeline._finish); a request an engine refused had no first token
+        # to wait for and is no part of it.
+        folded = (
+            ctx is not None
+            and status != "rejected"
+            and request_hop_metrics.fold_edge(
+                self.t0, ctx.t_enqueue, ctx.t_first_token, ctx.t_edge_item,
+                t_sent,
+            )
+        )
         tc = self.tc
         if tc is None:
             # NOT "rejected": shedding is deliberate and high-volume by
@@ -422,6 +443,12 @@ class EdgeRequestTrace:
                 self._admit_t0,
                 self._admit_t1 if self._admit_t1 is not None else end,
             )
+        if folded:
+            # The account's two edge hops as spans, from the same stamps.
+            if 0.0 < ctx.t_first_token <= ctx.t_edge_item:
+                collector.record(tc, "edge.handoff", "edge",
+                                 ctx.t_first_token, ctx.t_edge_item)
+            collector.record(tc, "edge.emit", "edge", ctx.t_edge_item, t_sent)
         attrs: Dict[str, Any] = {"status": status}
         if model or self.model:
             attrs["model"] = model or self.model

@@ -37,7 +37,7 @@ class AsyncEngineContext:
 
     __slots__ = (
         "_id", "_stopped", "_killed", "_children", "_stop_event", "deadline",
-        "trace",
+        "trace", "t_enqueue", "t_first_token", "t_edge_item",
     )
 
     def __init__(self, id: Optional[str] = None, deadline=None, trace=None):
@@ -55,6 +55,15 @@ class AsyncEngineContext:
         # (``trace`` request-header key) and read by every instrumented hop
         # (runtime/tracing.py).  None = untraced — the zero-cost path.
         self.trace = trace
+        # Hop-account stamps (time.perf_counter, 0.0 = not taken; first
+        # write wins) that cross between the edge and an engine in the SAME
+        # process — this object is the one both hold (docs/tracing.md):
+        # the engine writes its queue entry and first-token accept, the
+        # Backend operator the arrival of the first engine item that
+        # carries a token.  Never on the wire; a remote engine's stay 0.0.
+        self.t_enqueue = 0.0
+        self.t_first_token = 0.0
+        self.t_edge_item = 0.0
 
     @property
     def id(self) -> str:

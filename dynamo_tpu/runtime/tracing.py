@@ -373,18 +373,15 @@ def span(
 
 class SeqTrace:
     """Engine-side per-sequence trace state (SequenceState.trace): the
-    context plus the timing anchors the queue-wait/prefill spans need and
-    the first-token latch.  Never serialized itself — the snapshot ships
-    only ``ctx.to_dict()``."""
+    context plus the first-token latch.  The timing anchors of the
+    queue-wait/prefill spans are the sequence's own hop-account stamps
+    (``enqueue_t``, ``t_admit``), taken for every request anyway.  Never
+    serialized itself — the snapshot ships only ``ctx.to_dict()``."""
 
-    __slots__ = ("ctx", "t_enqueue", "t_admit", "first_done")
+    __slots__ = ("ctx", "first_done")
 
-    def __init__(self, ctx: TraceContext, t_enqueue: Optional[float] = None):
+    def __init__(self, ctx: TraceContext):
         self.ctx = ctx
-        self.t_enqueue = (
-            time.perf_counter() if t_enqueue is None else t_enqueue
-        )
-        self.t_admit: Optional[float] = None
         self.first_done = False
 
 

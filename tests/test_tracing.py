@@ -522,12 +522,55 @@ def test_engine_byte_identical_and_zero_new_compiles_with_tracing():
             assert 1 <= len(chunks) < 24
             assert all(c["attrs"]["steps"] >= 1 for c in chunks)
 
+            # The hop account's stamps as spans (ISSUE 25), same trace.
+            assert {"engine.prefill_wait", "engine.prefill_run",
+                    "engine.first_fetch"} <= set(names)
+
             # Tracing OFF on the same engine records nothing at all.
             got2 = _tokens(
                 await collect(await eng.generate(Context(dict(req))))
             )
             assert got2 == want and len(collector) == 0
         finally:
+            await eng.close()
+
+    asyncio.run(main())
+
+
+def test_engine_byte_identical_and_zero_new_compiles_with_the_hop_account(
+    monkeypatch,
+):
+    """The always-on hop account (ISSUE 25) under the same gate: with its
+    clock and its fold taken away the same seeded request streams the same
+    bytes from the same compiled programs as with them — the account is
+    floats on host objects and nothing else."""
+    from dynamo_tpu.engine import EngineConfig
+    from dynamo_tpu.engine.engine import TpuEngine
+    from dynamo_tpu.llm.metrics import RequestHopMetrics, request_hop_metrics
+
+    async def main():
+        eng = TpuEngine(EngineConfig(**CFG))
+        try:
+            req = _req(list(range(1, 18)), max_tokens=24, seed=77)
+            request_hop_metrics.reset()
+            want = _tokens(await collect(await eng.generate(Context(dict(req)))))
+            again = _tokens(await collect(await eng.generate(Context(dict(req)))))
+            assert again == want and len(want) == 24
+            queue_wait = RequestHopMetrics.QUEUE_WAIT
+            assert request_hop_metrics.counts[queue_wait] == 2  # it was on
+            counts = dict(eng.compile_counts())
+
+            monkeypatch.setattr(request_hop_metrics, "now", lambda: 0.0)
+            monkeypatch.setattr(
+                request_hop_metrics, "fold_engine", lambda *a: False
+            )
+            got = _tokens(await collect(await eng.generate(Context(dict(req)))))
+            assert got == want  # byte-identical without the account
+            assert eng.compile_counts() == counts  # and no new program
+            assert request_hop_metrics.counts[queue_wait] == 2
+            assert len(collector) == 0  # unsampled: the span ring stays empty
+        finally:
+            request_hop_metrics.reset()
             await eng.close()
 
     asyncio.run(main())

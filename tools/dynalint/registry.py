@@ -362,6 +362,15 @@ SNAPSHOT_EXEMPT = {
     "frozen": "migration-local flag",
     "finished": "finished sequences are not migrated",
     "enqueue_t": "per-queue latency bookkeeping",
+    # Hop-account stamps (docs/tracing.md): this engine's clock and this
+    # engine's hops; a resumed sequence counts as incomplete on the target.
+    "t_admit": "hop-account stamp of the source engine",
+    "t_first_chunk": "hop-account stamp of the source engine",
+    "t_last_chunk": "hop-account stamp of the source engine",
+    "t_fetch_done": "hop-account stamp of the source engine",
+    "t_first_token": "hop-account stamp of the source engine",
+    "t_join": "hop-account stamp of the source engine",
+    "hops_folded": "source-side fold idempotency flag",
     # Tenancy handles resolved per engine:
     "adapter_slot": "target resolves its own resident slot",
     "adapter_released": "source-side release idempotency flag",
