@@ -549,9 +549,9 @@ class TpuEngine(
                     token_ids=tok,
                     positions=posc,
                     slot_mapping=slot,
-                    # Padding rows attend over 1 garbage token (never 0 —
-                    # keeps the kernel's per-row loop well-defined).
-                    kv_lens=jnp.where(active, jnp.minimum(pos + 1, limits), 1),
+                    # Padding rows have no context (0): a decode kernel returns
+                    # them zeros and the fused one does no work for them.
+                    kv_lens=jnp.where(active, jnp.minimum(pos + 1, limits), 0),
                     page_indices=tables,
                     cu_q_lens=cu,
                     num_seqs=num,

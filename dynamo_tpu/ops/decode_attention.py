@@ -16,15 +16,20 @@ measured on this machine:
    The scale is an SMEM scalar operand, so per-layer TRACED calibration
    scales work natively (the stock kernel's k_scale/v_scale must be static
    floats, which is why dequant lived outside it).
-2. **Split-KV grid** (Flash-Decoding, Dao et al. 2023): long KV chains
-   split across grid programs, each producing an unnormalized partial
-   (o, m, l); a log-sum-exp combine reduces the splits.  At decode's
-   q_len=1 shapes one program per row leaves the chip idle — the split
-   axis restores parallel work.
+2. **Work follows the live pages**: a row's program walks
+   ``cdiv(live pages, ppcb)`` compute blocks of a few hundred positions
+   and starts ONE page copy per page the row has — a short row, and a
+   padding row (``kv_lens`` 0), cost what they hold, not what
+   ``pages_per_seq`` could hold.
 3. **Double-buffered page fetch**: pages DMA HBM→VMEM via
-   ``make_async_copy`` two compute-blocks deep, so the (bandwidth-bound)
-   page stream overlaps the QK/AV compute (PagedAttention page tables,
-   vLLM SOSP 2023 — the repo's existing paged layout).
+   ``make_async_copy`` two compute-blocks deep; with several blocks a row,
+   block ``b+1``'s copies are in flight during block ``b``'s dots
+   (PagedAttention page tables, vLLM SOSP 2023 — the repo's paged layout).
+4. **Split-KV grid** (Flash-Decoding, Dao et al. 2023) behind
+   ``num_kv_splits``: each split writes an unnormalized partial (o, m, l)
+   and a log-sum-exp combine reduces them.  Off by default (one split):
+   the call declares no parallel grid axis, so splits run one after
+   another on the chip's one TensorCore and buy nothing (docs/decode_kernel.md).
 
 Contract: identical inputs/outputs to ``ragged_decode_attention``'s XLA
 fallback (the bit-exactness oracle) — [S, H, D] out, zeros for rows past
@@ -166,11 +171,22 @@ def pages_per_vmem_budget(
     )
 
 
+MAX_BLOCK_CTX = 512  # context positions per compute block (see _default_ppcb)
+
+
 def _default_ppcb(page_size: int, kv2: int, head_dim: int, itemsize: int) -> int:
-    """Fused-kernel pages per compute block from the DYN_DECODE_NKV_MB
-    budget (default 4MB) at the page dtype's width."""
+    """Fused-kernel pages per compute block: ``MAX_BLOCK_CTX`` positions,
+    or fewer where the DYN_DECODE_NKV_MB budget (default 4MB, at the page
+    dtype's width) holds fewer.  A block is the unit a row's work is
+    counted in — its trip count is ``cdiv(live pages, ppcb)`` — so it is
+    kept to a few hundred positions: a 600-token row then costs two
+    blocks, not the 2048 positions the VMEM budget alone would allow
+    (the sweep behind the number: PERF.md section 6, PR 26)."""
     budget = resolve_hint("DYN_DECODE_NKV_MB", "nkv_mb", 4) << 20
-    return pages_per_vmem_budget(budget, page_size, kv2, head_dim, itemsize)
+    return min(
+        pages_per_vmem_budget(budget, page_size, kv2, head_dim, itemsize),
+        max(1, MAX_BLOCK_CTX // page_size),
+    )
 
 
 # ------------------------------------------------------------------ kernel
@@ -191,7 +207,9 @@ def _make_kernel(
 
     Grid (S, J): program (s, j) computes row ``s``'s attention over KV
     split ``j`` (pages [j*split_pages, (j+1)*split_pages)) and writes an
-    UNNORMALIZED partial (o, m, l) — combined host-side by LSE.
+    UNNORMALIZED partial (o, m, l) — combined host-side by LSE.  Its
+    block loop and its page copies are bounded by the pages the row HAS
+    in that split, never by ``split_pages``.
     """
     C = ppcb * page_size  # context positions per compute block
 
@@ -214,11 +232,23 @@ def _make_kernel(
     ):
         s = pl.program_id(0)
         j = pl.program_id(1)
+
+        # Pages a short row never copies keep whatever the scratch held,
+        # and the masked softmax weight 0 times a NaN is a NaN: start the
+        # call from zeros.  Later rows then find earlier rows' pages
+        # there — finite like the pool.  (Grid programs run in order on
+        # one core: no dimension_semantics below.)
+        @pl.when((s == 0) & (j == 0))
+        def _():
+            kv_buf[...] = jnp.zeros(kv_buf.shape, kv_buf.dtype)
+
         kv_len = kv_lens_ref[s]
         base_page = j * split_pages
         # Pages this split actually covers (tail splits truncate; rows
         # shorter than the split's base contribute nothing).
-        row_pages = pl.cdiv(kv_len, page_size)
+        # (Capped at the table's width: every page id read below is a
+        # table entry, whatever kv_lens claims.)
+        row_pages = jnp.minimum(pl.cdiv(kv_len, page_size), pages_per_seq)
         pages_here = jnp.clip(row_pages - base_page, 0, split_pages)
         # The split's coverage END, not just kv_len: the last compute
         # block of a split can reach past split_pages (ppcb granularity),
@@ -235,14 +265,18 @@ def _make_kernel(
         l_ref[0, 0] = jnp.zeros((num_kv * group, 1), jnp.float32)
 
         def fetch(block, slot, start):
-            # One DMA per page: page ids are arbitrary (PagedAttention
-            # indirection), so the block's pages can't ride one stride.
-            # wait() recreates the descriptor — standard Pallas pattern;
-            # the semaphore accounts per-copy.
-            for t in range(ppcb):
-                idx = base_page + block * ppcb + t
-                idx = jnp.clip(idx, 0, pages_per_seq - 1)
-                pid = page_indices_ref[s, idx]
+            # One DMA per LIVE page of the block: page ids are arbitrary
+            # (PagedAttention indirection), so pages can't ride one
+            # stride, and the row's last block stops at the row's last
+            # page — what lies past it is neither copied nor waited for
+            # (the position mask below never reads it).  wait() recreates
+            # the descriptor — standard Pallas pattern; the semaphore
+            # accounts per-copy.
+            first = block * ppcb
+            live = jnp.minimum(ppcb, pages_here - first)
+
+            def one(t, carry=None):
+                pid = page_indices_ref[s, base_page + first + t]
                 dma = pltpu.make_async_copy(
                     pages_ref.at[pid], kv_buf.at[slot, t], sems.at[slot]
                 )
@@ -250,6 +284,20 @@ def _make_kernel(
                     dma.start()
                 else:
                     dma.wait()
+                return carry
+
+            # A full block (every block of a row but its last) is
+            # straight-line code: the scalar core issues its copies back
+            # to back, which a counted loop's branch a page does not allow
+            # (sweep: PERF.md section 6, PR 26).
+            @pl.when(live == ppcb)
+            def _():
+                for t in range(ppcb):
+                    one(t)
+
+            @pl.when(live < ppcb)
+            def _():
+                jax.lax.fori_loop(0, live, one, 0)
 
         @pl.when(active)
         def _():
@@ -266,6 +314,13 @@ def _make_kernel(
 
                 fetch(b, slot, start=False)
                 buf = kv_buf[slot].reshape(C, 2 * num_kv, head_dim)
+                # Heads to the front ONCE a block, while the values are
+                # still page-dtype wide: slicing head h out of
+                # [C, 2KV, D] gathers one sublane from each of C tiles,
+                # and 2KV such slices were most of a block's time (sweep:
+                # PERF.md section 6, PR 26).  Same values into the same
+                # dots — the output is bit-identical.
+                buf = jnp.transpose(buf, (1, 0, 2))  # [2KV, C, D]
                 # Fused dequant: the ONLY f32 materialization of this KV
                 # block is here in VMEM, one compute block at a time.
                 kvf = buf.astype(jnp.float32) * scale
@@ -276,8 +331,8 @@ def _make_kernel(
                 out = []
                 for h in range(num_kv):
                     m_h, l_h, acc_h = carry[3 * h], carry[3 * h + 1], carry[3 * h + 2]
-                    k_h = kvf[:, 2 * h, :]  # [C, D]
-                    v_h = kvf[:, 2 * h + 1, :]
+                    k_h = kvf[2 * h]  # [C, D]
+                    v_h = kvf[2 * h + 1]
                     qf = (
                         q_ref[0, h * group : (h + 1) * group, :].astype(
                             jnp.float32
@@ -348,15 +403,16 @@ def fused_decode_attention(
     pages_per_block: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
-    """Host wrapper: fused-dequant split-KV decode attention + LSE combine.
+    """Host wrapper: fused-dequant decode attention + LSE split combine.
 
     Knobs (env > tuned table > default; tools/tune_decode.py sweeps them):
-    - ``DYN_DECODE_SPLITS`` / splits: KV-split grid width (0 = auto:
-      enough splits to cover pages_per_seq at one compute block each,
-      capped at 8).
+    - ``DYN_DECODE_SPLITS`` / splits: KV-split grid width (0 = auto: 1 —
+      one program a row walks all the row's blocks; the grid runs in
+      order on one TensorCore, so a split is a second serial program and
+      a combine, and an empty one still takes its turn).
     - ``DYN_DECODE_FUSED_PPCB`` / ppcb: pages per compute block (default
-      from the DYN_DECODE_NKV_MB VMEM budget at the PAGE dtype's width —
-      int8 pages pack ~2x the bf16 block).
+      ``MAX_BLOCK_CTX`` positions, fewer where the DYN_DECODE_NKV_MB VMEM
+      budget at the PAGE dtype's width holds fewer).
     """
     S, H, D = q.shape
     P, ps, KV2, _ = pages.shape
@@ -371,8 +427,8 @@ def fused_decode_attention(
     )
     ppcb = max(1, min(ppcb, PP))
     splits = num_kv_splits or resolve_hint("DYN_DECODE_SPLITS", "splits", 0)
-    if splits <= 0:  # auto: one compute block per split, at most 8 splits
-        splits = max(1, min(8, pl.cdiv(PP, ppcb)))
+    if splits <= 0:  # auto
+        splits = 1
     splits = min(splits, pl.cdiv(PP, ppcb))
     split_pages = pl.cdiv(PP, splits)
     splits = pl.cdiv(PP, split_pages)  # drop now-empty tail splits

@@ -267,10 +267,16 @@ def ragged_decode_attention(
         nq, nkv = _decode_block_hints(pages, page_indices)
         # One token per row: cumulative query lengths are the identity.
         cu = jnp.arange(S + 1, dtype=jnp.int32)
+        # The stock kernel's per-row loop needs a context of at least one
+        # token; a row without one (the fused decode program's padding
+        # rows) attends over one garbage token and is zeroed below, which
+        # is what the other two implementations return for it.
+        has_ctx = jnp.asarray(kv_lens) > 0
+        kv_lens = jnp.maximum(kv_lens, 1)
         # Unit scale for quantized pages without an explicit one — see the
         # matching comment in ragged_attention.
         unit = 1.0 if pages.dtype.itemsize == 1 and kv_scale is None else kv_scale
-        return ragged_paged_attention(
+        out = ragged_paged_attention(
             q,
             pages,
             kv_lens,
@@ -284,6 +290,7 @@ def ragged_decode_attention(
             k_scale=unit,
             v_scale=unit,
         )
+        return jnp.where(has_ctx[:, None, None], out, 0)
     if impl != "xla":
         raise ValueError(f"unknown ragged attention impl {impl!r}")
 

@@ -74,11 +74,19 @@ GEOMETRIES = [
     (5, 8, 4, 1, 4, 16, [32, 0, 5, 17, 2], 5, jnp.int8, 0.05),  # int8 + 0-len
     (2, 5, 2, 2, 1, 8, [9, 10], 2, jnp.float32, 2.5),  # fp32 with scale
     (3, 4, 4, 2, 2, 8, [16, 16, 16], 3, jnp.int8, 0.1),  # full chains
+    # Work follows the live pages (ISSUE 26).  Slots are sparse, not
+    # compacted: empty rows sit BETWEEN live ones.  At ppcb 2 a block is 8
+    # positions: 13 is no multiple of it, 8 is exactly one block, 1 is one
+    # token, 32 fills PP; at ppcb 3 the block does not divide PP either.
+    (6, 8, 4, 2, 2, 16, [13, 0, 8, 1, 0, 32], 6, jnp.float32, None),
+    (6, 8, 4, 1, 4, 16, [13, 0, 8, 1, 0, 32], 6, jnp.int8, 0.05),
 ]
 
 
 @pytest.mark.parametrize("geom", GEOMETRIES, ids=lambda g: f"S{g[0]}PP{g[1]}")
-@pytest.mark.parametrize("splits,ppcb", [(1, 1), (2, 2), (3, 1), (4, 2)])
+@pytest.mark.parametrize(
+    "splits,ppcb", [(1, 1), (2, 2), (3, 1), (4, 2), (1, 2), (1, 3), (2, 3)]
+)
 def test_fused_kernel_parity_vs_xla_oracle(geom, splits, ppcb):
     S, PP, ps, KV, G, D, lens, nv, dt, scale = geom
     q, pages, kv_lens, tables, num = _case(0, S, PP, ps, KV, G, D, lens, nv,
@@ -100,6 +108,114 @@ def test_fused_kernel_parity_vs_xla_oracle(geom, splits, ppcb):
     for i in range(S):
         if i >= nv or int(kv_lens[i]) == 0:
             np.testing.assert_array_equal(np.asarray(got)[i], 0.0)
+
+
+class _CountingTpu:
+    """``pltpu`` with ``make_async_copy`` wrapped so each start and wait
+    bumps a host counter — interpret mode runs the callbacks, the chip's
+    build never sees this."""
+
+    def __init__(self, real, counts):
+        self._real, self._counts = real, counts
+
+    def __getattr__(self, name):
+        return getattr(self._real, name)
+
+    def make_async_copy(self, src, dst, sem):
+        dma, counts = self._real.make_async_copy(src, dst, sem), self._counts
+
+        def bump(key):
+            jax.debug.callback(lambda: counts.__setitem__(key, counts[key] + 1))
+
+        class Counted:
+            def start(self):
+                bump("started")
+                dma.start()
+
+            def wait(self):
+                bump("waited")
+                dma.wait()
+
+        return Counted()
+
+
+@pytest.mark.parametrize("dtype,scale", [(jnp.float32, None), (jnp.int8, 0.05)],
+                         ids=["f32", "int8"])
+@pytest.mark.parametrize("splits,ppcb", [(1, 1), (1, 2), (1, 3), (2, 2), (1, 8)])
+def test_fused_kernel_copies_only_live_pages(monkeypatch, splits, ppcb,
+                                             dtype, scale):
+    """The property ISSUE 26 exists for, counted not timed: the kernel
+    starts one page copy for each page a row HAS — none for a padding row
+    (kv_lens 0, or past num_seqs), none past a row's last page in its last
+    block — and waits for exactly those."""
+    from dynamo_tpu.ops import decode_attention as da
+
+    S, PP, ps, KV, G, D = 7, 8, 4, 2, 2, 16
+    lens = [13, 0, 8, 1, 0, 32, 20]
+    nvalid = 6  # row 6 holds 20 tokens but lies past num_seqs
+    q, pages, kv_lens, tables, num = _case(
+        3, S, PP, ps, KV, G, D, lens, nvalid, dtype, scale
+    )
+    counts = {"started": 0, "waited": 0}
+    monkeypatch.setattr(da, "pltpu", _CountingTpu(da.pltpu, counts))
+    got = fused_decode_attention(
+        q, pages, kv_lens, tables, num, sm_scale=D**-0.5, kv_scale=scale,
+        num_kv_splits=splits, pages_per_block=ppcb, interpret=True,
+    )
+    jax.block_until_ready(got)
+    jax.effects_barrier()
+    live_pages = sum(-(-n // ps) for n in lens[:nvalid])
+    assert counts == {"started": live_pages, "waited": live_pages}
+
+
+def test_fused_kernel_default_blocks_follow_page_shape(monkeypatch):
+    """The built-in defaults: a compute block of MAX_BLOCK_CTX positions
+    whatever the page size (fewer where the VMEM budget holds fewer), and
+    one split."""
+    from dynamo_tpu.ops import decode_attention as da
+
+    for name in ("DYN_DECODE_NKV_MB", "DYN_DECODE_FUSED_PPCB", "DYN_DECODE_SPLITS"):
+        monkeypatch.delenv(name, raising=False)
+    clear_tuned_hints()
+    for ps in (16, 32, 128):
+        assert da._default_ppcb(ps, 8, 128, 1) * ps == da.MAX_BLOCK_CTX
+    # bf16 pages of 8 KV heads (llama-3.1-8b): the 4MB double-buffered
+    # budget holds exactly a full block; at 16 KV heads, or under a 1MB
+    # budget, it holds fewer pages and wins.
+    assert da._default_ppcb(16, 16, 128, 2) * 16 == da.MAX_BLOCK_CTX
+    assert da._default_ppcb(16, 32, 128, 2) == 16
+    monkeypatch.setenv("DYN_DECODE_NKV_MB", "1")
+    assert da._default_ppcb(16, 16, 128, 2) == 8
+
+
+def test_stock_branch_floors_context_and_zeroes_padding_rows(monkeypatch):
+    """The floor of one token lives where it is needed — the stock
+    kernel's call — and a row without context comes back zero from it as
+    from the other two implementations."""
+    import sys
+    import types
+
+    seen = {}
+
+    def fake_kernel(q, pages, kv_lens, page_indices, cu, num, **kw):
+        seen["kv_lens"] = np.asarray(kv_lens)
+        return jnp.ones_like(q)
+
+    name = "jax.experimental.pallas.ops.tpu.ragged_paged_attention"
+    fake = types.ModuleType(name)
+    fake.ragged_paged_attention = fake_kernel
+    monkeypatch.setitem(sys.modules, name, fake)
+    S, PP, ps, KV, G, D = 4, 6, 4, 2, 2, 16
+    q, pages, kv_lens, tables, num = _case(
+        2, S, PP, ps, KV, G, D, [9, 0, 24, 0], 4
+    )
+    out = ragged_decode_attention(
+        q, pages, kv_lens, tables, num, sm_scale=D**-0.5, impl="tpu",
+        kernel="stock",
+    )
+    assert seen["kv_lens"].tolist() == [9, 1, 24, 1]
+    assert np.asarray(out)[[0, 2]].min() == 1.0
+    np.testing.assert_array_equal(np.asarray(out)[[1, 3]], 0.0)
 
 
 def test_fused_kernel_traced_scale_under_jit():

@@ -70,21 +70,31 @@ def no_persistent_cache():
     cc.reset_cache()
 
 
-def _compile(fn, *shapes) -> None:
+def _compile(fn, *shapes) -> str:
     text = jax.jit(fn).lower(*shapes).compile().as_text()
     # Compiled by Mosaic for the chip, not interpreted.
     assert "tpu_custom_call" in text
+    return text
 
 
-def _decode_shapes(sds, model, page_dtype):
+def _decode_shapes(sds, model, page_dtype, rows=ROWS, pages=LAYERS_X_PAGES):
     H, KV = GEOMETRY[model]
     PP = CTX // PS
     return (
-        sds((ROWS, H, D), jnp.bfloat16),
-        sds((LAYERS_X_PAGES, PS, 2 * KV, D), jnp.dtype(page_dtype)),
-        sds((ROWS,), jnp.int32),
-        sds((ROWS, PP), jnp.int32),
+        sds((rows, H, D), jnp.bfloat16),
+        sds((pages, PS, 2 * KV, D), jnp.dtype(page_dtype)),
+        sds((rows,), jnp.int32),
+        sds((rows, PP), jnp.int32),
         sds((1,), jnp.int32),
+    )
+
+
+def _fused_decode(q, pages, kv_lens, tables, num, scale):
+    """The kernel ``auto`` picks for decode on a TPU, built-in defaults,
+    with a TRACED kv_scale (the per-layer calibration vector's element)."""
+    return fused_decode_attention(
+        q, pages, kv_lens, tables, num, sm_scale=D**-0.5,
+        kv_scale=scale, interpret=False,
     )
 
 
@@ -93,16 +103,27 @@ def _decode_shapes(sds, model, page_dtype):
 def test_fused_decode_kernel_compiles(
     sds, no_persistent_cache, model, page_dtype
 ):
-    """The kernel ``auto`` picks for decode on a TPU, with a TRACED
-    kv_scale (the per-layer calibration vector's element)."""
+    _compile(
+        _fused_decode, *_decode_shapes(sds, model, page_dtype),
+        sds((), jnp.float32),
+    )
 
-    def fn(q, pages, kv_lens, tables, num, scale):
-        return fused_decode_attention(
-            q, pages, kv_lens, tables, num, sm_scale=D**-0.5,
-            kv_scale=scale, interpret=False,
-        )
 
-    _compile(fn, *_decode_shapes(sds, model, page_dtype), sds((), jnp.float32))
+def test_fused_decode_kernel_compiles_at_the_cells_shape(
+    sds, no_persistent_cache
+):
+    """The benchmark cells' decode shape (chipbench/configs/qwen2.5-7b.json):
+    32 rows, 256 pages a row, 12288 int8 pages a layer.  With the built-in
+    defaults the call has ONE split, and keeps the name the trace readers
+    find it by."""
+    H, _ = GEOMETRY["qwen2.5-7b"]
+    text = _compile(
+        _fused_decode,
+        *_decode_shapes(sds, "qwen2.5-7b", "int8", rows=32, pages=28 * 12288),
+        sds((), jnp.float32),
+    )
+    assert "fused_decode_attention" in text
+    assert f"f32[32,1,{H},{D}]" in text
 
 
 @pytest.mark.parametrize("page_dtype", ["int8", "bfloat16"])
