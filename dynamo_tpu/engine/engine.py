@@ -42,12 +42,11 @@ from jax.profiler import TraceAnnotation
 from ..llm.kv_router.protocols import ForwardPassMetrics, KvCacheEvent
 from ..llm.protocols import FinishReason, LLMEngineOutput, PreprocessedRequest
 from ..models.config import ModelConfig, get_config
-from ..models.llama import PagedKVCache, RaggedBatch, forward_ragged, init_params
+from ..models.family import RaggedBatch, family_of
 from ..ops.sampling import SamplingParams, sample_tokens
 from ..parallel.mesh import (
     MeshConfig,
     make_mesh,
-    pages_pspec,
     param_pspecs,
     shard_tree,
     sharding_tree,
@@ -98,6 +97,15 @@ class TpuEngine(
                 f"{self.model_config.num_kv_heads} (KV pages shard by head)"
             )
         model_config = self.model_config
+        # Everything the engine needs of the model: drawn by model_type, so
+        # no site below names a family (models/family.py).
+        fam = self.family = family_of(model_config)
+        fam.check(model_config, cfg)
+        # The family's own host-side account of a dispatch (lengths the
+        # scheduler holds; no device sync), or None.
+        self._count_dispatch = (
+            partial(fam.count_dispatch, model_config) if fam.count_dispatch else None
+        )
         attn_impl = cfg.attn_impl
         if attn_impl == "auto":
             from ..ops.ragged_attention import on_tpu
@@ -387,9 +395,9 @@ class TpuEngine(
                 if cfg.weight_quant:
                     # Direct int8 init — full-depth random bf16 would OOM
                     # the chip before it could be quantized.
-                    from ..models.quant import init_params_quantized as init
+                    init = fam.init_params_quantized
                 else:
-                    init = init_params
+                    init = fam.init_params
                 init = partial(init, self.model_config)
                 key = jax.random.PRNGKey(cfg.seed)
                 if self.mesh is None:
@@ -405,19 +413,16 @@ class TpuEngine(
                     )(key)
         else:
             if cfg.weight_quant:
-                from ..models.quant import quantize_params
-
-                params = quantize_params(params)  # no-op if already quantized
+                params = fam.quantize_params(params)  # no-op if already quantized
             if self.mesh is not None:
                 params = _place(params)
         if (
             cfg.fuse_projections
+            and fam.fuse_projections is not None
             and not self.model_config.is_moe
             and self.mesh is None  # single-shard only (see fuse_projections)
         ):
-            from ..models.quant import fuse_projections
-
-            params = fuse_projections(params)
+            params = fam.fuse_projections(params)
         if cfg.lora.enable:
             # Fixed-shape multi-LoRA device banks (llm/tenancy/lora.py):
             # R resident slots × rank-r A/B factors per attention
@@ -440,7 +445,7 @@ class TpuEngine(
             ).items():
                 params["layers"][name] = jnp.asarray(leaf, dt)
         make_cache = partial(
-            PagedKVCache.create,
+            fam.create_cache,
             self.model_config,
             cfg.num_blocks,
             cfg.block_size,
@@ -453,7 +458,7 @@ class TpuEngine(
                 make_cache,
                 out_shardings=sharding_tree(
                     jax.eval_shape(make_cache),
-                    PagedKVCache(pages_pspec()),
+                    fam.cache_pspec(),
                     self.mesh,
                 ),
             )()
@@ -498,7 +503,7 @@ class TpuEngine(
         self._lora_rank = lora_rank
 
         def _step(params, cache, rb, samp):
-            logits, cache = forward_ragged(
+            logits, cache, aux = fam.forward(
                 params, model_config, rb, cache, attn_impl=attn_impl,
                 mesh=mesh, kv_scale=kv_scale, lora_rank=lora_rank,
                 prefill_kernel=prefill_kernel,
@@ -517,6 +522,8 @@ class TpuEngine(
                 samp.mask_words,
                 samp.any_mask,
             )
+            if aux is not None:
+                out = out._replace(aux=aux)
             return out, cache
 
         T_steps = cfg.decode_steps
@@ -559,7 +566,7 @@ class TpuEngine(
                     # (llm/tenancy multi-LoRA) are the per-token slots.
                     adapter_slots=samp.adapter_slots,
                 )
-                logits, cache = forward_ragged(
+                logits, cache, aux = fam.forward(
                     params, model_config, rb, cache, attn_impl=attn_impl,
                     mesh=mesh, kv_scale=kv_scale, decode=True,
                     decode_kernel=decode_kernel, lora_rank=lora_rank,
@@ -578,6 +585,8 @@ class TpuEngine(
                     samp.mask_words,
                     samp.any_mask,
                 )
+                if aux is not None:
+                    out = out._replace(aux=aux)
                 nxt = out.tokens
                 counts = counts.at[jnp.arange(S), nxt].add(
                     active.astype(counts.dtype)
@@ -601,32 +610,24 @@ class TpuEngine(
             # counts_f) is the ON-DEVICE carry the next dispatch chains to.
             return outs, last, steps_f, counts_f, cache
 
-        def _gather(cache, page_ids):
-            # Batched block gather for host offload; OOB padding ids clamp
-            # (their slices are ignored at store time).
-            return cache.pages[:, page_ids]
-
-        def _inject(cache, page_ids, new_pages):
-            # Donated in-place page scatter for KV imports; padding ids are
-            # out of range and dropped, so callers can bucket the page count
-            # to bound recompiles.
-            # Same quantization as the ragged write path (shared helper) —
-            # injected/sp-prefilled blocks must never diverge numerically
-            # from normal-prefill blocks under the same hashes.
-            from ..ops.ragged_attention import quantize_for_cache
-
-            pages = cache.pages.at[:, page_ids].set(
-                quantize_for_cache(new_pages, cache.pages.dtype), mode="drop"
-            )
-            return PagedKVCache(pages)
+        # Batched block gather (host offload) and donated in-place page
+        # scatter (KV imports; callers bucket the page count to bound
+        # recompiles): the family owns the layout.  A family whose blocks
+        # cannot be moved yet has neither, and the planes that need them
+        # refuse at their entry (_require_block_moves).
+        _gather, _inject = fam.gather_pages, fam.inject_pages
 
         donate = (1,)
-        if self.mesh is None:
+        if _inject is None:
+            self._step_fn = jax.jit(_step, donate_argnums=donate)
+            self._multi_fn = jax.jit(_multi, donate_argnums=donate)
+            self._inject_fn = self._gather_fn = None
+        elif self.mesh is None:
             self._step_fn = jax.jit(_step, donate_argnums=donate)
             self._multi_fn = jax.jit(_multi, donate_argnums=donate)
             self._inject_fn = jax.jit(_inject, donate_argnums=(0,))
         else:
-            cache_sh = sharding_tree(cache, PagedKVCache(pages_pspec()), self.mesh)
+            cache_sh = sharding_tree(cache, fam.cache_pspec(), self.mesh)
             self._step_fn = jax.jit(
                 _step, donate_argnums=donate, out_shardings=(None, cache_sh)
             )
@@ -638,13 +639,12 @@ class TpuEngine(
             self._inject_fn = jax.jit(
                 _inject, donate_argnums=(0,), out_shardings=cache_sh
             )
-        self._gather_fn = jax.jit(_gather)  # host offload (no donation)
+        if _gather is not None:
+            self._gather_fn = jax.jit(_gather)  # host offload (no donation)
 
         if cfg.sp > 1:
-            from ..models.llama import forward_sp_prefill
-
             def _sp(params, toks, valid):
-                return forward_sp_prefill(
+                return fam.forward_sp_prefill(
                     params, model_config, toks, valid, mesh
                 )
 
@@ -701,9 +701,10 @@ class TpuEngine(
         # Probe length bounded so nb (+1 slack) fits a single row's table.
         T = min(128, (cfg.max_blocks_per_seq - 1) * cfg.block_size)
         nb = (T + cfg.block_size - 1) // cfg.block_size + 1
-        probe = PagedKVCache.create(mc, nb, cfg.block_size, dtype=jnp.bfloat16)
+        fam = self.family
+        probe = fam.create_cache(mc, nb, cfg.block_size, dtype=jnp.bfloat16)
         if self.mesh is not None:
-            probe = shard_tree(probe, PagedKVCache(pages_pspec()), self.mesh)
+            probe = shard_tree(probe, fam.cache_pspec(), self.mesh)
         toks = ((np.arange(T) * 2654435761) % mc.vocab_size).astype(np.int32)
         pos = np.arange(T, dtype=np.int32)
         S = cfg.max_batch
@@ -724,9 +725,9 @@ class TpuEngine(
             num_seqs=np.asarray([1], np.int32),
         )
         _, probe = jax.jit(
-            lambda p, c: forward_ragged(
+            lambda p, c: fam.forward(
                 p, mc, rb, c, attn_impl="xla", mesh=self.mesh
-            )
+            )[:2]
         )(params, probe)
         # [L, nb, ps, 2KV, hd] → per-layer max |value| over everything else.
         maxabs = np.asarray(
@@ -845,15 +846,30 @@ class TpuEngine(
         else:
             raise ValueError(f"unknown mirror step kind {kind!r}")
 
+    def _require_block_moves(self, what: str) -> None:
+        """Planes that move whole KV blocks (export/import, migration, host
+        and disk tiers) need the family's gather/inject; a latent cache has
+        none yet, and mis-sizing a block silently is worse than refusing."""
+        if self._inject_fn is None:
+            raise ValueError(
+                f"{what} is not supported for model_type "
+                f"{self.model_config.model_type} ({self.cfg.model}): its cache "
+                f"({self.device_summary()['cache_kinds']} bytes a token a layer) is "
+                "not K-plus-V pages; serve it without --host-cache-mb/"
+                "--disk-cache-mb, KV export/import, prefix pulls and live migration"
+            )
+
     # ---------------------------------------------------------------- warmup
     def compile_counts(self) -> Dict[str, int]:
         """Compiled-program count per jitted entry (cache sizes).  The bench
         asserts these do not grow inside its timed window."""
-        return {
+        counts = {
             "step": self._step_fn._cache_size(),
             "multi": self._multi_fn._cache_size(),
-            "inject": self._inject_fn._cache_size(),
         }
+        if self._inject_fn is not None:
+            counts["inject"] = self._inject_fn._cache_size()
+        return counts
 
     def device_summary(self) -> Dict[str, Any]:
         """What this process runs on and what warmup cost, as the serving
@@ -885,6 +901,11 @@ class TpuEngine(
                 "weight_quant": self.cfg.weight_quant or "none",
                 "cache_dtype": str(self.cfg.cache_dtype),
                 "attn_impl": self.attn_impl,
+                # Each page array of the cache and its bytes a token a layer.
+                "cache_kinds": ",".join(
+                    f"{k}:{v}" for k, v in
+                    self.family.cache_kinds(self.model_config, self.cache).items()
+                ),
                 "decode_kernel": self.decode_kernel,
                 "prefill_kernel": self.prefill_kernel,
                 "hasher": native.hasher(),
@@ -1545,7 +1566,10 @@ class TpuEngine(
 
     def block_nbytes(self) -> int:
         """Host-side bytes of one KV block in the stored representation."""
-        return int(self.cache.pages.nbytes // max(1, self.cfg.num_blocks))
+        return int(
+            sum(a.nbytes for a in jax.tree_util.tree_leaves(self.cache))
+            // max(1, self.cfg.num_blocks)
+        )
 
     def kv_tier_summary(self) -> Dict[str, Any]:
         """Per-tier bytes/blocks gauges for /metrics (llm/metrics.py

@@ -142,6 +142,7 @@ class MigrationMixin:
         the quiescent SequenceState, or None if the sequence is
         gone/finished or quiescence didn't land in ``timeout`` (the flag
         is cleared again — the sequence keeps decoding)."""
+        self._require_block_moves("live sequence migration")
         seq = self.find_sequence(request_id)
         if seq is None or seq.finished:
             return None

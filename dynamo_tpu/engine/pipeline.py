@@ -48,6 +48,8 @@ class DecodePipelineMixin:
         step (no ``except AttributeError``: a renamed SampleOut field must
         fail loudly, not turn every fetch into a synchronous round trip)."""
         out.tokens.copy_to_host_async()
+        if out.aux is not None:
+            out.aux.copy_to_host_async()
         if need_lp:
             out.logprob.copy_to_host_async()
             out.top_ids.copy_to_host_async()
@@ -213,6 +215,10 @@ class DecodePipelineMixin:
             at += n
             cu[i + 1] = at
         cu[len(items) + 1 :] = at
+        if self._count_dispatch:
+            self._count_dispatch(
+                "unified", [st for _, st, _ in items], [n for _, _, n in items]
+            )
         return RaggedBatch(
             token_ids=tok,
             positions=pos,
@@ -395,11 +401,14 @@ class DecodePipelineMixin:
         """Wrap a device-op thread in a Task so _await_device can watch it."""
         return asyncio.get_running_loop().create_task(asyncio.to_thread(fn))
 
-    @staticmethod
-    def _fetch_outs(out, need_lp: bool):
+    def _fetch_outs(self, out, need_lp: bool):
         """Materialize a step's sampled outputs on host (ONE definition of
         the SampleOut fetch shape — the stash path and the fused pipeline
         both use it, so a payload change cannot silently diverge them)."""
+        if out.aux is not None:
+            # The family's small int32 account, [n] or [steps, n]: it rides
+            # the fetch that brings the tokens home.
+            self.family.count_aux(np.asarray(out.aux))
         if need_lp:
             return (
                 np.asarray(out.tokens),
@@ -409,14 +418,13 @@ class DecodePipelineMixin:
             )
         return np.asarray(out.tokens), None, None, None
 
-    @staticmethod
-    def _fetch_first(out, need_lp: bool, first_rows: List[SequenceState]):
+    def _fetch_first(self, out, need_lp: bool, first_rows: List[SequenceState]):
         """``_fetch_outs`` for a step that completed prompts: stamps the
         moment the sampled tokens are on the host (hop account
         ``t_fetch_done``) — here on the fetch thread, where the copy ends,
         so the event loop's latency stays out of the device half.  One
         clock read per fetch; only rows awaiting their FIRST token."""
-        res = DecodePipelineMixin._fetch_outs(out, need_lp)
+        res = self._fetch_outs(out, need_lp)
         now = request_hop_metrics.now()
         for seq in first_rows:
             seq.t_fetch_done = now
@@ -838,6 +846,10 @@ class DecodePipelineMixin:
         async def dispatch_chunk(pos0: np.ndarray) -> None:
             nonlocal carry, chunk_id, dispatched_any
             first = carry is None
+            if self._count_dispatch:
+                self._count_dispatch(
+                    "decode", pos0, np.where(pos0 >= 0, cfg.decode_steps, 0)
+                )
             n_active = slots.num_active
             pub_payload = (
                 tok0 if first else None,  # None → follower's own carry
@@ -1080,6 +1092,8 @@ class DecodePipelineMixin:
             d_args = self._prep((pos0, tables, limits, samp))
         else:
             d_args = (pos0, tables, limits, samp)
+        if self._count_dispatch:
+            self._count_dispatch("decode_burst", pos0, np.where(pos0 >= 0, T, 0))
         multi = self._multi_fn
 
         def run():
@@ -1121,6 +1135,8 @@ class DecodePipelineMixin:
             d_args_b = self._prep((pos0b, tables, limits, samp))
         else:
             d_args_b = (pos0b, tables, limits, samp)
+        if self._count_dispatch:
+            self._count_dispatch("decode_burst", pos0b, np.where(pos0b >= 0, T, 0))
 
         def run_b():
             with TraceAnnotation("engine.dispatch:burst"):

@@ -68,6 +68,7 @@ class KvTransferMixin:
         passed chain is asserted equal to a fresh recompute — a stale or
         wrongly-salted chain must fail loudly, not seal wrong bytes.
         """
+        self._require_block_moves("KV export (disaggregated prefill, prefix pulls, migration)")
         from ..tokens import hash_token_blocks
 
         if jax.process_count() > 1:
@@ -148,6 +149,7 @@ class KvTransferMixin:
         very next generate() for these tokens admits with a prefix hit — no
         special remote-prefill state in the scheduler.
         """
+        self._require_block_moves("KV import")
         from ..tokens import hash_token_blocks
 
         start = int(payload.get("start_block", 0))
@@ -320,6 +322,7 @@ class KvTransferMixin:
         """Seal ``n`` transferred blocks whose pages are ALREADY on device
         (the ICI/device_put fast path — no host staging).  ``pages_dev`` is
         [L, pad, ps, 2KV, hd] with the first n slots valid."""
+        self._require_block_moves("device-to-device KV import")
         from ..tokens import hash_token_blocks
 
         if jax.process_count() > 1:
@@ -392,6 +395,8 @@ async def transfer_blocks_device(
     copy; across chips of a shared slice the put rides ICI — the reference's
     NIXL/GPUDirect block path (SURVEY §2.6) for same-slice deployments.
     Returns tokens covered (the longest resident prefix run)."""
+    src._require_block_moves("device-to-device KV transfer")
+    dst._require_block_moves("device-to-device KV transfer")
     from ..tokens import hash_token_blocks
 
     if jax.process_count() > 1:

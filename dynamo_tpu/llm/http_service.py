@@ -285,6 +285,7 @@ class HttpService:
         from .metrics import (
             bulk_metrics,
             engine_dispatch_metrics,
+            sparse_model_metrics,
             kv_integrity_metrics,
             kv_tier_metrics,
             migration_metrics,
@@ -308,6 +309,7 @@ class HttpService:
             + health_metrics.render(self._metrics_prefix).encode()
             + qos_metrics.render(self._metrics_prefix).encode()
             + engine_dispatch_metrics.render(self._metrics_prefix).encode()
+            + sparse_model_metrics.render(self._metrics_prefix).encode()
             + request_hop_metrics.render(self._metrics_prefix).encode()
             + kv_tier_metrics.render(self._metrics_prefix).encode()
             + kv_integrity_metrics.render(self._metrics_prefix).encode()

@@ -652,7 +652,7 @@ class EngineDispatchMetrics:
                 for k in (
                     "jax", "libtpu", "platform", "device_kind",
                     "device_count", "model", "num_layers", "weight_quant",
-                    "cache_dtype", "attn_impl", "hasher",
+                    "cache_dtype", "cache_kinds", "attn_impl", "hasher",
                     "compile_cache_dir",
                 )
             )
@@ -718,6 +718,74 @@ class EngineDispatchMetrics:
 
 
 engine_dispatch_metrics = EngineDispatchMetrics()
+
+
+class SparseModelMetrics:
+    """Counters of a model with a learned sparse selector and held experts
+    (models/deepseek_v32.py; docs/tracing.md).  The selector's account is
+    host arithmetic on lengths the scheduler holds (``add_dsa``); the expert
+    account rides home with the sampled tokens (``add_moe``).  The engine
+    reaches both through its model family only (models/family.py
+    ``count_dispatch`` / ``count_aux``).  Renders nothing until a model that
+    has them ran."""
+
+    def __init__(self):
+        self.dsa: Dict[str, list] = {}  # dispatch kind -> [context, selected]
+        self.moe_local_pairs = 0
+        self.moe_routed_tokens = 0
+
+    def reset(self) -> None:
+        self.__init__()
+
+    def add_dsa(self, kind: str, topk: int, starts, ns) -> None:
+        """Add a dispatch's query tokens to the selector's account: the token
+        at position t has t + 1 context positions and keeps min(topk, t + 1).
+        ``starts[i]``, ``ns[i]``: first position and token count of row i."""
+        k = topk
+        acc = self.dsa.setdefault(kind, [0, 0])
+        for start, n in zip(starts, ns):
+            start, n = int(start), int(n)
+            if n <= 0 or start < 0:
+                continue
+            acc[0] += n * start + n * (n + 1) // 2
+            full = max(0, min(n, start + n - k + 1)) if start + n >= k else 0
+            part = n - full  # tokens with t + 1 < k keep all t + 1
+            acc[1] += full * k + part * start + part * (part + 1) // 2
+
+    def add_moe(self, aux) -> None:
+        """``aux``: int array [..., 2] of (pairs on held experts, tokens routed)."""
+        a = aux.reshape(-1, 2).sum(axis=0)
+        self.moe_local_pairs += int(a[0])
+        self.moe_routed_tokens += int(a[1])
+
+    def render(self, prefix: str = "dynamo_tpu") -> str:
+        if not self.dsa and not self.moe_routed_tokens:
+            return ""
+        lines = []
+        for i, (name, help_) in enumerate((
+            ("dsa_context_positions_total",
+             "Positions s <= t summed over the query tokens dispatched"),
+            ("dsa_selected_positions_total",
+             "min(index_topk, t + 1) summed over the query tokens dispatched"),
+        )):
+            lines.append(f"# HELP {prefix}_{name} {help_}")
+            lines.append(f"# TYPE {prefix}_{name} counter")
+            for kind, v in sorted(self.dsa.items()):
+                lines.append(f'{prefix}_{name}{{kind="{escape_label(kind)}"}} {v[i]}')
+        for name, help_, v in (
+            ("moe_local_pairs_total",
+             "Routed (token, expert) pairs that landed on experts held here",
+             self.moe_local_pairs),
+            ("moe_routed_tokens_total",
+             "Tokens routed, counted once per expert layer", self.moe_routed_tokens),
+        ):
+            lines.append(f"# HELP {prefix}_{name} {help_}")
+            lines.append(f"# TYPE {prefix}_{name} counter")
+            lines.append(f"{prefix}_{name} {v}")
+        return "\n".join(lines) + "\n"
+
+
+sparse_model_metrics = SparseModelMetrics()
 
 
 class RequestHopMetrics:

@@ -43,6 +43,29 @@ class ModelConfig:
     eos_token_ids: tuple = ()
     # Qwen2-style attention: q/k/v projections carry biases.
     qkv_bias: bool = False
+    # The model family (models/family.py picks init, cache and forward from
+    # it): "llama" covers the dense GQA block and the Mixtral-style MoE.
+    model_type: str = "llama"
+    # deepseek_v32: latent attention (MLA), the learned sparse selector
+    # (DSA) and the sigmoid gate; all 0 for every other family.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    first_k_dense_replace: int = 0
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    # num_experts counts the routed experts HELD here (ids ep_rank *
+    # num_experts ...); router_experts is the router's published width.
+    router_experts: int = 0
+    ep_size: int = 1
+    ep_rank: int = 0
 
     @property
     def is_moe(self) -> bool:
@@ -61,7 +84,10 @@ class ModelConfig:
 
     @classmethod
     def from_hf_config(cls, cfg: Dict[str, Any], name: str = "") -> "ModelConfig":
-        """Convert a HuggingFace ``config.json`` dict (llama/mixtral style)."""
+        """Convert a HuggingFace ``config.json`` dict (llama/mixtral style,
+        or ``model_type: deepseek_v32``)."""
+        if cfg.get("model_type") == "deepseek_v32":
+            return cls._from_deepseek_v32(cfg, name)
         num_heads = cfg["num_attention_heads"]
         head_dim = cfg.get("head_dim") or cfg["hidden_size"] // num_heads
         # Qwen2 checkpoints carry q/k/v biases but don't always write an
@@ -93,6 +119,66 @@ class ModelConfig:
             else 0,
             eos_token_ids=tuple(eos),
             qkv_bias=qkv_bias,
+        )
+
+    @classmethod
+    def _from_deepseek_v32(cls, cfg: Dict[str, Any], name: str) -> "ModelConfig":
+        """DeepSeek-V3.2-Exp's keys.  ``n_routed_experts`` counts the experts
+        held here; a file cut to one chip's share states the published
+        router width beside it (``n_routed_experts_published``) with
+        ``ep_size``/``ep_rank``; the uncut config holds them all."""
+        held = cfg["n_routed_experts"]
+        ep_size = cfg.get("ep_size", 1)
+        total = cfg.get("n_routed_experts_published", held * ep_size)
+        if held * ep_size != total:
+            raise ValueError(
+                f"n_routed_experts {held} x ep_size {ep_size} is not the "
+                f"router's width {total}"
+            )
+        ep_rank = cfg.get("ep_rank", 0)
+        if not 0 <= ep_rank < ep_size:
+            raise ValueError(f"ep_rank {ep_rank} outside ep_size {ep_size}")
+        if total % cfg["n_group"] or cfg["topk_group"] > cfg["n_group"]:
+            raise ValueError("n_group must divide the router's width")
+        eos = cfg.get("eos_token_id", ())
+        if isinstance(eos, int):
+            eos = (eos,)
+        return cls(
+            name=name or cfg.get("_name_or_path", "hf-model"),
+            model_type="deepseek_v32",
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            num_layers=cfg["num_hidden_layers"],
+            num_heads=cfg["num_attention_heads"],
+            num_kv_heads=cfg.get("num_key_value_heads", cfg["num_attention_heads"]),
+            head_dim=cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"],
+            intermediate_size=cfg["intermediate_size"],
+            rope_theta=cfg.get("rope_theta", 10000.0),
+            rope_scaling=cfg.get("rope_scaling"),
+            rms_norm_eps=cfg.get("rms_norm_eps", 1e-6),
+            max_position=cfg.get("max_position_embeddings", 163840),
+            tie_word_embeddings=cfg.get("tie_word_embeddings", False),
+            num_experts=held,
+            num_experts_per_token=cfg["num_experts_per_tok"],
+            num_shared_experts=cfg.get("n_shared_experts", 0),
+            moe_intermediate_size=cfg["moe_intermediate_size"],
+            eos_token_ids=tuple(eos),
+            q_lora_rank=cfg["q_lora_rank"],
+            kv_lora_rank=cfg["kv_lora_rank"],
+            qk_nope_head_dim=cfg["qk_nope_head_dim"],
+            qk_rope_head_dim=cfg["qk_rope_head_dim"],
+            v_head_dim=cfg["v_head_dim"],
+            index_n_heads=cfg["index_n_heads"],
+            index_head_dim=cfg["index_head_dim"],
+            index_topk=cfg["index_topk"],
+            first_k_dense_replace=cfg["first_k_dense_replace"],
+            n_group=cfg["n_group"],
+            topk_group=cfg["topk_group"],
+            routed_scaling_factor=cfg.get("routed_scaling_factor", 1.0),
+            norm_topk_prob=cfg.get("norm_topk_prob", True),
+            router_experts=total,
+            ep_size=ep_size,
+            ep_rank=ep_rank,
         )
 
     @classmethod

@@ -1,0 +1,407 @@
+"""DeepSeek-V3.2-Exp block: latent attention (MLA) under the learned sparse
+selector (DSA), a sigmoid gate with group-limited choice, one shared expert
+and the routed experts this chip HOLDS (docs/deepseek_v32.md has the
+equations; models/reference/deepseek_v32.py is the plain float32 reference).
+
+Beside models/llama.py and sharing its ``linear``, ``rms_norm``,
+``embed_lookup``, ``lm_logits`` and the dispatch of models/moe.py.  The cache
+is two page arrays under ONE page table (``LatentKVCache``): the engine's
+block manager, prefix cache and eviction see page ids only and are untouched.
+
+Expert parallelism without the exchange: ``config.num_experts`` experts are
+held (global ids ``ep_rank * num_experts`` onwards), the router scores and
+chooses over all ``config.router_experts``, and this chip adds the part of
+the result its own experts give, plus the shared expert.  That partial result
+goes on to the next layer, as in the reference.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, NamedTuple, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..ops.rope import (
+    apply_rope,
+    apply_rope_interleaved,
+    rope_frequencies,
+    yarn_mscale,
+)
+from ..ops.sparse_mla import sparse_decode_attention, sparse_prefill_attention
+from .config import ModelConfig
+from .llama import RaggedBatch, embed_lookup, linear, lm_logits, mlp, rms_norm
+from .moe import expert_dispatch
+
+Params = Dict[str, Any]
+
+# Leaves stored in int8 with a scale over the CONTRACTED axis (the value is
+# that axis in the stacked layout); every other leaf stays in the activation
+# dtype: w_uk / w_uv (absorbed into q and the output, 33.6 MB a layer),
+# the router and its bias, the selector's idx_wk and idx_wproj, the norms.
+QUANT_AXES = {
+    "layers": {"wq_a": 1, "wq_b": 1, "wkv_a": 1, "wo": 1, "idx_wq_b": 1},
+    "dense": {"w_gate": 1, "w_up": 1, "w_down": 1},
+    "moe": {"moe_gate": 2, "moe_up": 2, "moe_down": 2,
+            "shared_gate": 1, "shared_up": 1, "shared_down": 1},
+    "top": {"embed": 1, "lm_head": 0},
+}
+
+
+def latent_width(config: ModelConfig) -> int:
+    """Stored width of a latent entry: kv_lora_rank + qk_rope_head_dim (576)
+    rounded up to whole 128-lane tiles (640), the tail zero.  The TPU pads a
+    minor dimension of 576 to 640 in memory anyway, or else picks a layout
+    with the PAGE axis minor, which no gather can use: the compiler then
+    copied the whole 3.6 GB array into and out of every step (compile
+    rehearsal, PR 28)."""
+    return -(-(config.kv_lora_rank + config.qk_rope_head_dim) // 128) * 128
+
+
+class LatentKVCache(NamedTuple):
+    """``latent`` [L, P, ps, latent_width]: the normed latent c_t and the
+    rope key k^R_t, K and V at once (then zero padding to whole lanes).
+    ``index`` [L, P, ps, index_head_dim]: the selector's key k^I_t.  One page
+    id names the same 16 tokens in both."""
+
+    latent: jnp.ndarray
+    index: jnp.ndarray
+
+    @classmethod
+    def create(cls, config: ModelConfig, num_pages: int, page_size: int,
+               dtype=jnp.bfloat16) -> "LatentKVCache":
+        L = config.num_layers
+        return cls(
+            latent=jnp.zeros((L, num_pages, page_size, latent_width(config)), dtype),
+            index=jnp.zeros((L, num_pages, page_size, config.index_head_dim), dtype),
+        )
+
+
+def leaf_shapes(config: ModelConfig) -> Dict[str, Dict[str, tuple]]:
+    """Every leaf's shape, by group: the one statement of the layout."""
+    c = config
+    D, H, L, V = c.hidden_size, c.num_heads, c.num_layers, c.vocab_size
+    Rq, Rkv, dn, dr, dv = (c.q_lora_rank, c.kv_lora_rank, c.qk_nope_head_dim,
+                           c.qk_rope_head_dim, c.v_head_dim)
+    Hi, di = c.index_n_heads, c.index_head_dim
+    Ld = min(c.first_k_dense_replace, L)
+    Lm, E, Et = L - Ld, c.num_experts, c.router_experts
+    F, Fm = c.intermediate_size, c.moe_intermediate_size
+    Fs = Fm * max(1, c.num_shared_experts)
+    return {
+        "top": {"embed": (V, D), "lm_head": (D, V), "final_norm": (D,)},
+        "layers": {
+            "attn_norm": (L, D), "wq_a": (L, D, Rq), "q_norm": (L, Rq),
+            "wq_b": (L, Rq, H * (dn + dr)), "wkv_a": (L, D, Rkv + dr),
+            "kv_norm": (L, Rkv), "w_uk": (L, H, Rkv, dn), "w_uv": (L, H, Rkv, dv),
+            "wo": (L, H * dv, D), "idx_wq_b": (L, Rq, Hi * di), "idx_wk": (L, D, di),
+            "idx_k_norm_w": (L, di), "idx_k_norm_b": (L, di), "idx_wproj": (L, D, Hi),
+            "mlp_norm": (L, D),
+        },
+        "dense": {"w_gate": (Ld, D, F), "w_up": (Ld, D, F), "w_down": (Ld, F, D)},
+        "moe": {
+            "router": (Lm, D, Et), "router_bias": (Lm, Et),
+            "moe_gate": (Lm, E, D, Fm), "moe_up": (Lm, E, D, Fm), "moe_down": (Lm, E, Fm, D),
+            "shared_gate": (Lm, D, Fs), "shared_up": (Lm, D, Fs), "shared_down": (Lm, Fs, D),
+        },
+    }
+
+
+_ONES = ("attn_norm", "q_norm", "kv_norm", "idx_k_norm_w", "mlp_norm", "final_norm")
+_S0 = np.float32(0.02 / 73.0)  # uniform int8 has std ~73: N(0, 0.02)-like weights
+
+
+def _draw(config: ModelConfig, key: jax.Array, quant: bool) -> Params:
+    """Every leaf from ``key``, each straight into its stored type.  Traced
+    under ONE jit (``init_params*``): no eager temporaries, so the 1.2 GB
+    expert leaves and the 5.6 GB whole fit beside the pages."""
+    dt = jnp.dtype(config.dtype)
+    out: Params = {}
+    n = 0
+    for group, leaves in leaf_shapes(config).items():
+        dst = out if group == "top" else out.setdefault(group, {})
+        for name, shape in leaves.items():
+            k = jax.random.fold_in(key, n)
+            n += 1
+            if name in _ONES:
+                dst[name] = jnp.ones(shape, dt)
+            elif name == "idx_k_norm_b":
+                dst[name] = jnp.zeros(shape, dt)
+            elif name == "router_bias":
+                # Small and nonzero, so that "the bias steers the choice
+                # only" is visible to a test; f32 as the release keeps it.
+                dst[name] = jax.random.normal(k, shape, jnp.float32) * 0.01
+            elif quant and name in QUANT_AXES[group]:
+                dst[name] = jax.random.randint(k, shape, -127, 128, dtype=jnp.int8)
+                axis = QUANT_AXES[group][name]
+                dst[name + "_scale"] = jnp.full(shape[:axis] + shape[axis + 1:], _S0, jnp.float32)
+            else:
+                dst[name] = (jax.random.normal(k, shape, jnp.float32) * 0.02).astype(dt)
+    return out
+
+
+def init_params(config: ModelConfig, key: jax.Array) -> Params:
+    return jax.jit(lambda k: _draw(config, k, False))(key)
+
+
+def init_params_quantized(config: ModelConfig, key: jax.Array) -> Params:
+    return jax.jit(lambda k: _draw(config, k, True))(key)
+
+
+def _groups(params: Params):
+    yield "top", params
+    for g in ("layers", "dense", "moe"):
+        yield g, params[g]
+
+
+def quantize_params(params: Params) -> Params:
+    """int8 leaves with their scales from a float tree (no-op when done)."""
+    from .quant import _quantize_jnp
+
+    if "embed_scale" in params:
+        return params
+    out: Params = {}
+    for group, leaves in _groups(params):
+        dst = out if group == "top" else out.setdefault(group, {})
+        for name, leaf in leaves.items():
+            if isinstance(leaf, dict):
+                continue
+            axis = QUANT_AXES[group].get(name)
+            if axis is None:
+                dst[name] = leaf
+            else:
+                dst[name], dst[name + "_scale"] = _quantize_jnp(leaf, axis)
+    return out
+
+
+def dequantize_params(params: Params, dtype="float32") -> Params:
+    """The float tree a quantized one stands for (the reference's weights)."""
+    out: Params = {}
+    for group, leaves in _groups(params):
+        dst = out if group == "top" else out.setdefault(group, {})
+        for name, leaf in leaves.items():
+            if isinstance(leaf, dict) or name.endswith("_scale"):
+                continue
+            s = leaves.get(name + "_scale")
+            if s is None:
+                dst[name] = leaf
+            else:
+                axis = QUANT_AXES[group][name]
+                dst[name] = (leaf.astype(jnp.float32) * jnp.expand_dims(s, axis)).astype(dtype)
+    return out
+
+
+def held_experts(config: ModelConfig) -> range:
+    lo = config.ep_rank * config.num_experts
+    return range(lo, lo + config.num_experts)
+
+
+def gate(x: jnp.ndarray, lp: Params, config: ModelConfig):
+    """(chosen ids [T, K], weights [T, K] f32) over ALL the router's experts:
+    sigmoid scores; the bias enters the choice only; the best ``topk_group``
+    of ``n_group`` groups by the sum of each group's two largest; top-K of
+    what is left; weights normalised over the chosen and scaled."""
+    T = x.shape[0]
+    Et, G, K = config.router_experts, config.n_group, config.num_experts_per_token
+    s = jax.nn.sigmoid((x @ lp["router"]).astype(jnp.float32))  # [T, Et]
+    biased = s + lp["router_bias"].astype(jnp.float32)
+    group_score = jnp.sum(jax.lax.top_k(biased.reshape(T, G, Et // G), 2)[0], axis=-1)
+    keep = jax.lax.top_k(group_score, config.topk_group)[1]  # [T, topk_group]
+    group_ok = jnp.any(keep[:, :, None] == jnp.arange(G)[None, None, :], axis=1)  # [T, G]
+    allowed = jnp.repeat(group_ok, Et // G, axis=-1)
+    chosen = jax.lax.top_k(jnp.where(allowed, biased, -jnp.inf), K)[1]
+    w = jnp.take_along_axis(s, chosen, axis=-1)
+    if config.norm_topk_prob:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return chosen, w * config.routed_scaling_factor
+
+
+def moe_block(x: jnp.ndarray, lp: Params, config: ModelConfig):
+    """Shared expert + the routed experts chosen AND held.  Returns (y [T, D],
+    how many (token, expert) pairs landed on held experts [] int32)."""
+    chosen, w = gate(x, lp, config)
+    lo = config.ep_rank * config.num_experts
+    local = chosen - lo
+    here = (local >= 0) & (local < config.num_experts)
+    # A held expert is routed T * K / router_experts rows on average (16 of a
+    # 512-token step): tables of T rows each (dropless by construction) made
+    # every expert compute 32 times its share, 34 of the step's 108 ms (chip
+    # run, PR 28).  So: tables of a quarter of T, and the full tables only in
+    # a step where some expert really is routed more.  Both are exact.
+    T, E = x.shape[0], config.num_experts
+    small = max(8, T // 4)
+    if small >= T:
+        routed = expert_dispatch(x, local, w, lp, E, T, valid=here)
+    else:
+        load = jnp.sum(here[:, :, None] & (local[:, :, None] == jnp.arange(E)), axis=(0, 1))
+        routed = jax.lax.cond(
+            jnp.max(load) <= small,
+            lambda: expert_dispatch(x, local, w, lp, E, small, valid=here),
+            lambda: expert_dispatch(x, local, w, lp, E, T, valid=here),
+        )
+    shared = mlp(x, {"w_" + k[len("shared_"):]: v for k, v in lp.items()
+                     if k.startswith("shared_")})
+    return routed + shared, here
+
+
+def _rope_head(x, positions, inv_freq, dr: int):
+    """Half-split rope on the first ``dr`` dims of the selector's q or k."""
+    return jnp.concatenate([apply_rope(x[..., :dr], positions, inv_freq), x[..., dr:]], axis=-1)
+
+
+def _layer_norm(x, w, b, eps=1e-6):
+    xf = x.astype(jnp.float32)
+    mu = jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean((xf - mu) ** 2, axis=-1, keepdims=True)
+    return ((xf - mu) * jax.lax.rsqrt(var + eps)).astype(x.dtype) * w + b
+
+
+def _write(pages, rows, slots):
+    """Scatter ``rows`` [T, d] into flat slots of ``pages`` [NP, ps, d];
+    slot -1 (padding) is dropped."""
+    NP, ps, d = pages.shape
+    at = jnp.where(slots < 0, NP * ps, slots)
+    return pages.reshape(NP * ps, d).at[at].set(rows.astype(pages.dtype), mode="drop").reshape(
+        NP, ps, d)
+
+
+def forward_ragged(
+    params: Params,
+    config: ModelConfig,
+    rb: RaggedBatch,
+    cache: LatentKVCache,
+    *,
+    decode: bool = False,
+    return_selection: bool = False,
+    block_q: int = 64,
+    block_k: int = 1024,
+    **_llama_only,  # attn_impl, kernels, kv_scale, mesh, lora_rank: family.py checks them
+) -> Tuple[jnp.ndarray, LatentKVCache, Any]:
+    """The unified step of models/llama.py for this family: returns (logits
+    [S, V] of each row's last token, the updated cache, aux).  ``aux`` is
+    [2] int32: (routed pairs that landed on held experts, tokens routed), over
+    the step's real tokens and all MoE layers; with ``return_selection`` it
+    is instead the list of S_t per layer (decode: positions [S, k]; else a
+    mask [T, PP * ps])."""
+    c = config
+    rb = jax.tree_util.tree_map(jnp.asarray, rb)  # host arrays when not under jit
+    (T,) = rb.token_ids.shape
+    H, dn, dr, dv, Rkv = c.num_heads, c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim, c.kv_lora_rank
+    Hi, di, eps = c.index_n_heads, c.index_head_dim, c.rms_norm_eps
+    inv_freq = rope_frequencies(dr, c.rope_theta, c.rope_scaling)
+    sm_scale = (dn + dr) ** -0.5 * yarn_mscale(c.rope_scaling) ** 2
+    L, P_layer, ps = cache.latent.shape[:3]
+    Ld = min(c.first_k_dense_replace, L)
+    pos = rb.positions
+    real = rb.slot_mapping >= 0  # [T] padding tokens carry slot -1
+    S = rb.kv_lens.shape[0]
+    q_lens = rb.cu_q_lens[1:] - rb.cu_q_lens[:-1]
+    first = jnp.clip(rb.cu_q_lens[:-1], 0, T - 1)  # a single-token row's token
+    single = (q_lens == 1) & (jnp.arange(S) < rb.num_seqs[0])
+
+    def attention(x, lp, l, lat, idx):
+        cq = rms_norm(linear(x, lp, "wq_a"), lp["q_norm"], eps)
+        q = linear(cq, lp, "wq_b").reshape(T, H, dn + dr)
+        q_rope = apply_rope_interleaved(q[..., dn:], pos, inv_freq)
+        kv = linear(x, lp, "wkv_a")
+        k_rope = apply_rope_interleaved(kv[:, None, Rkv:], pos, inv_freq)[:, 0]
+        tail = lat.shape[-1] - Rkv - dr  # zero lanes up to the stored width
+        entry = jnp.concatenate(
+            [rms_norm(kv[:, :Rkv], lp["kv_norm"], eps), k_rope, jnp.zeros((T, tail), kv.dtype)],
+            axis=-1)
+        # Absorbed form: q~ = W^UK^T q^N scores the cached latent directly.
+        q_abs = jnp.concatenate(
+            [jnp.einsum("thn,hcn->thc", q[..., :dn], lp["w_uk"]), q_rope,
+             jnp.zeros((T, H, tail), q.dtype)], axis=-1)
+        qi = _rope_head(linear(cq, lp, "idx_wq_b").reshape(T, Hi, di), pos, inv_freq, dr)
+        ki = _layer_norm(x @ lp["idx_wk"], lp["idx_k_norm_w"], lp["idx_k_norm_b"])
+        ki = _rope_head(ki[:, None, :], pos, inv_freq, dr)[:, 0]
+        wi = (x @ lp["idx_wproj"]).astype(jnp.float32) * (Hi**-0.5 * di**-0.5)
+        slots = jnp.where(rb.slot_mapping < 0, -1, rb.slot_mapping + l * (P_layer * ps))
+        lat, idx = _write(lat, entry, slots), _write(idx, ki, slots)
+        tables = rb.page_indices + l * P_layer
+        kw = dict(topk=c.index_topk, sm_scale=sm_scale, rank_v=Rkv)
+
+        def one_token_rows(_):
+            o, sel = sparse_decode_attention(
+                q_abs[first], qi[first], wi[first], lat, idx, pos[first],
+                jnp.where(single, rb.kv_lens, 0), tables, **kw)
+            return o, sel
+
+        if decode:
+            o_lat, sel = one_token_rows(None)
+        else:
+            res = sparse_prefill_attention(
+                q_abs, qi, wi, lat, idx, pos, rb.kv_lens, tables, rb.cu_q_lens, rb.num_seqs,
+                block_q=block_q, block_k=block_k, return_mask=return_selection, **kw)
+            o_lat, sel = res if return_selection else (res, None)
+            # Decode rows riding a mixed step: the one-query path, only when
+            # the step has any.
+            k_sel = min(c.index_topk, rb.page_indices.shape[1] * ps)
+            o1, sel1 = jax.lax.cond(
+                jnp.any(single), one_token_rows,
+                lambda _: (jnp.zeros((S, H, Rkv), o_lat.dtype), jnp.full((S, k_sel), -1, jnp.int32)),
+                None)
+            at = jnp.where(single, first, T)
+            o_lat = o_lat.at[at].set(o1, mode="drop")
+            if return_selection:
+                hot = jnp.zeros((S, sel.shape[1] + 1), bool).at[
+                    jnp.arange(S)[:, None], jnp.where(sel1 < 0, sel.shape[1], sel1)].set(True)
+                sel = sel.at[at].set(hot[:, :-1], mode="drop")
+        o = jnp.einsum("thc,hcv->thv", o_lat, lp["w_uv"]).reshape(T, H * dv)
+        return linear(o, lp, "wo"), lat, idx, sel
+
+    def layer(h, lat, idx, lp, l, is_moe: bool):
+        a, lat, idx, sel = attention(rms_norm(h, lp["attn_norm"], eps), lp, l, lat, idx)
+        h = h + a
+        x = rms_norm(h, lp["mlp_norm"], eps)
+        if is_moe:
+            y, here = moe_block(x, lp, c)
+            pairs = jnp.sum(here & real[:, None], dtype=jnp.int32)
+        else:
+            y, pairs = mlp(x, lp), jnp.zeros((), jnp.int32)
+        return h + y, lat, idx, pairs, sel
+
+    def at_layer(group: str, i):
+        return jax.tree_util.tree_map(lambda a: a[i], params[group])
+
+    h = embed_lookup(params, rb.token_ids, jnp.dtype(c.dtype))
+    lat = cache.latent.reshape((L * P_layer,) + cache.latent.shape[2:])
+    idx = cache.index.reshape((L * P_layer,) + cache.index.shape[2:])
+    pairs = jnp.zeros((), jnp.int32)
+    sels = []
+    for l in range(Ld):  # the leading dense layers: few, so unrolled
+        h, lat, idx, _, sel = layer(h, lat, idx, {**at_layer("layers", l), **at_layer("dense", l)},
+                                    l, False)
+        sels.append(sel)
+    if decode or return_selection:
+        # Static layer indices: XLA prefetches layer l+1's weights during
+        # layer l (see models/llama.py on the fused decode program).
+        for l in range(Ld, L):
+            h, lat, idx, p, sel = layer(
+                h, lat, idx, {**at_layer("layers", l), **at_layer("moe", l - Ld)}, l, True)
+            pairs += p
+            sels.append(sel)
+    elif L > Ld:
+        # The scan carries only the layer's number and indexes the stacked
+        # leaves itself: slicing params["layers"][Ld:] for ``xs`` copied 1.3 GB
+        # of attention weights in every step (slice s8[5,16384,7168], first
+        # chip profile of PR 28).
+        def body(carry, l):
+            h, lat, idx, pairs = carry
+            lp = {**at_layer("layers", l), **at_layer("moe", l - Ld)}
+            h, lat, idx, p, _ = layer(h, lat, idx, lp, l, True)
+            return (h, lat, idx, pairs + p), None
+
+        (h, lat, idx, pairs), _ = jax.lax.scan(
+            body, (h, lat, idx, pairs), jnp.arange(Ld, L, dtype=jnp.int32))
+
+    h = rms_norm(h, params["final_norm"], eps)
+    rows = jnp.clip(rb.cu_q_lens[1:] - 1, 0, T - 1)
+    logits = lm_logits(params, h[rows])
+    new_cache = LatentKVCache(lat.reshape(cache.latent.shape), idx.reshape(cache.index.shape))
+    if return_selection:
+        return logits, new_cache, sels
+    tokens = jnp.sum(real, dtype=jnp.int32) * (L - Ld)
+    return logits, new_cache, jnp.stack([pairs, tokens])

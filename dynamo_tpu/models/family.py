@@ -1,0 +1,158 @@
+"""One seam between the engine and the model families.
+
+``family_of(config)`` returns what engine/engine.py needs of a model and
+nothing else: how to draw or quantize its parameters, how to build its page
+cache, its unified forward, and the few cache operations whose layout a family
+owns.  The engine names no family; a new one is a module beside models/llama.py
+and one entry here, chosen by ``ModelConfig.model_type``.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Callable, NamedTuple, Optional
+
+from .config import ModelConfig
+from .llama import RaggedBatch  # noqa: F401 — the engine's step input, family-neutral
+
+
+class ModelFamily(NamedTuple):
+    name: str
+    init_params: Callable  # (config, key) -> params, activation dtype
+    init_params_quantized: Callable  # (config, key) -> params, int8 leaves + scales
+    quantize_params: Callable  # params -> params (no-op when already quantized)
+    # params -> params with projections fused (single shard only), or None
+    fuse_projections: Optional[Callable]
+    create_cache: Callable  # (config, num_pages, page_size, dtype=) -> cache pytree
+    # (params, config, rb, cache, **step options) -> (logits, cache, aux);
+    # aux is None or a small int32 array that rides home with the sampled tokens.
+    forward: Callable
+    # PartitionSpec trees for a device mesh; None = single shard only.
+    cache_pspec: Optional[Callable]
+    # (cache, page_ids) -> pages and (cache, page_ids, pages) -> cache, for
+    # the planes that move whole blocks (offload, transfer, migration); None =
+    # this family's blocks cannot be moved yet and those planes are refused.
+    gather_pages: Optional[Callable]
+    inject_pages: Optional[Callable]
+    forward_sp_prefill: Optional[Callable]
+    # Names and bytes a token a layer of each page array, for /metrics.
+    cache_kinds: Callable  # (config, cache) -> {name: bytes}
+    # (config, engine config) -> None; raises ValueError naming the flag for
+    # every engine option this family does not support.
+    check: Callable
+    # Host-side accounts a family keeps of its own mechanisms, or None:
+    # (config, dispatch kind, first positions, token counts of the rows) at
+    # every dispatch, from lengths the scheduler holds; and (aux as numpy)
+    # for what ``forward`` sent home.
+    count_dispatch: Optional[Callable] = None
+    count_aux: Optional[Callable] = None
+
+
+def _llama() -> ModelFamily:
+    from . import llama, quant
+
+    def forward(params, config, rb, cache, **kw):
+        logits, cache = llama.forward_ragged(params, config, rb, cache, **kw)
+        return logits, cache, None
+
+    def gather(cache, page_ids):
+        # OOB padding ids clamp (their slices are ignored at store time).
+        return cache.pages[:, page_ids]
+
+    def inject(cache, page_ids, new_pages):
+        # Same quantization as the ragged write path (shared helper):
+        # injected blocks must never diverge numerically from
+        # normal-prefill blocks under the same hashes.  Padding ids are out
+        # of range and dropped.
+        from ..ops.ragged_attention import quantize_for_cache
+
+        pages = cache.pages.at[:, page_ids].set(
+            quantize_for_cache(new_pages, cache.pages.dtype), mode="drop"
+        )
+        return llama.PagedKVCache(pages)
+
+    def cache_pspec():
+        from ..parallel.mesh import pages_pspec
+
+        return llama.PagedKVCache(pages_pspec())
+
+    def kinds(config, cache):
+        return {"kv": 2 * config.num_kv_heads * config.head_dim * cache.pages.dtype.itemsize}
+
+    return ModelFamily(
+        name="llama",
+        init_params=llama.init_params,
+        init_params_quantized=quant.init_params_quantized,
+        quantize_params=quant.quantize_params,
+        fuse_projections=quant.fuse_projections,
+        create_cache=llama.PagedKVCache.create,
+        forward=forward,
+        cache_pspec=cache_pspec,
+        gather_pages=gather,
+        inject_pages=inject,
+        forward_sp_prefill=llama.forward_sp_prefill,
+        cache_kinds=kinds,
+        check=lambda config, cfg: None,
+    )
+
+
+def _deepseek_v32() -> ModelFamily:
+    import jax.numpy as jnp
+
+    from ..llm.metrics import sparse_model_metrics
+    from . import deepseek_v32 as ds
+
+    def kinds(config, cache):
+        return {"latent": cache.latent.shape[-1] * cache.latent.dtype.itemsize,
+                "index": cache.index.shape[-1] * cache.index.dtype.itemsize}
+
+    def check(config: ModelConfig, cfg: Any) -> None:
+        """The engine options this family cannot serve yet, each refused by
+        its flag: a latent block is not K plus V, so nothing that sizes or
+        moves blocks as ``pages`` may run on it silently."""
+        bad = []
+        if cfg.tp * cfg.dp * cfg.ep * cfg.sp != 1:
+            bad.append("--tp/--dp/--ep/--sp > 1 (no PartitionSpecs for the latent cache; the "
+                       "configuration's ep_size/ep_rank say which experts this chip holds)")
+        if jnp.dtype(cfg.cache_dtype).itemsize == 1:
+            bad.append("--kv-cache-dtype int8/fp8 (latent and indexer pages are bfloat16 or wider)")
+        if cfg.host_cache_bytes or cfg.disk_cache_bytes or cfg.object_store_bytes:
+            bad.append("--host-cache-mb/--disk-cache-mb/--object-store-mb (the tiers store "
+                       "K-plus-V blocks)")
+        if cfg.spec_decode.enable:
+            bad.append("--spec-decode (verification rows of several tokens are not wired)")
+        if cfg.lora.enable:
+            bad.append("--lora (no adapter banks for the latent projections)")
+        if bad:
+            raise ValueError(
+                f"model_type deepseek_v32 ({config.name}) does not support: " + "; ".join(bad))
+
+    return ModelFamily(
+        name="deepseek_v32",
+        init_params=ds.init_params,
+        init_params_quantized=ds.init_params_quantized,
+        quantize_params=ds.quantize_params,
+        fuse_projections=None,
+        create_cache=ds.LatentKVCache.create,
+        forward=ds.forward_ragged,
+        cache_pspec=None,
+        gather_pages=None,
+        inject_pages=None,
+        forward_sp_prefill=None,
+        cache_kinds=kinds,
+        check=check,
+        count_dispatch=lambda config, kind, starts, ns: sparse_model_metrics.add_dsa(
+            kind, config.index_topk, starts, ns),
+        count_aux=sparse_model_metrics.add_moe,
+    )
+
+
+_FAMILIES = {"llama": _llama, "deepseek_v32": _deepseek_v32}
+
+
+def family_of(config: ModelConfig) -> ModelFamily:
+    try:
+        return _FAMILIES[config.model_type]()
+    except KeyError:
+        raise ValueError(
+            f"model_type {config.model_type!r} has no family; known: {sorted(_FAMILIES)}"
+        ) from None

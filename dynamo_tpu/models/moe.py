@@ -61,13 +61,34 @@ def moe_mlp(
     weights, chosen = jax.lax.top_k(router_logits, K)  # [T, K]
     weights = jax.nn.softmax(weights, axis=-1)  # renormalise over chosen
 
+    yt = expert_dispatch(xt, chosen, weights, lp, E, capacity)
+    return yt.reshape(B, Sq, D)
+
+
+def expert_dispatch(
+    xt: jnp.ndarray,  # [T, D]
+    chosen: jnp.ndarray,  # [T, K] expert id of each assignment, LOCAL to lp's E experts
+    weights: jnp.ndarray,  # [T, K] f32 combine weights
+    lp: Dict[str, jnp.ndarray],  # moe_gate / moe_up / moe_down [E, ...] (+ scales)
+    E: int,
+    capacity: int,
+    valid: jnp.ndarray | None = None,  # [T, K] False = not an expert held here: skipped
+) -> jnp.ndarray:
+    """sum_k weights[t, k] * FFN_{chosen[t, k]}(xt[t]) through per-expert
+    token-index tables [E, C].  With ``valid`` (expert parallelism: the
+    router chose over more experts than ``lp`` holds) the assignments that
+    land elsewhere add nothing."""
+    T, D = xt.shape
+    K = chosen.shape[1]
     # Queue position of each (t, k) assignment within its expert.
     flat_e = chosen.reshape(T * K)  # expert id per assignment
+    if valid is not None:
+        flat_e = jnp.where(valid.reshape(T * K), flat_e, E)  # E: no such expert
     flat_t = jnp.repeat(jnp.arange(T, dtype=jnp.int32), K)  # token per assignment
     flat_w = weights.reshape(T * K)
-    onehot_e = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)  # [T*K, E]
-    pos = (jnp.cumsum(onehot_e, axis=0) - 1)[jnp.arange(T * K), flat_e]  # [T*K]
-    overflow = pos >= capacity
+    onehot_e = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)  # [T*K, E]; all zero for id E
+    pos = (jnp.cumsum(onehot_e, axis=0) - 1)[jnp.arange(T * K), jnp.minimum(flat_e, E - 1)]
+    overflow = (pos >= capacity) | (flat_e >= E)
     pos_safe = jnp.where(overflow, capacity, pos)  # OOB rows dropped by scatter
 
     # dispatch_idx[e, c] = source token index (T = padding row).
@@ -82,7 +103,7 @@ def moe_mlp(
     xe = x_pad[dispatch_idx]  # [E, C, D]
     gate = jax.nn.silu(
         expert_linear(xe, lp, "moe_gate", jnp.float32)
-    ).astype(x.dtype)
+    ).astype(xt.dtype)
     up = expert_linear(xe, lp, "moe_up")
     ye = expert_linear(gate * up, lp, "moe_down")  # [E, C, D]
 
@@ -90,4 +111,4 @@ def moe_mlp(
     ye_w = ye.astype(jnp.float32) * gate_w[..., None]
     yt = jnp.zeros((T + 1, D), jnp.float32)
     yt = yt.at[dispatch_idx.reshape(-1)].add(ye_w.reshape(-1, D), mode="drop")
-    return yt[:T].astype(x.dtype).reshape(B, Sq, D)
+    return yt[:T].astype(xt.dtype)

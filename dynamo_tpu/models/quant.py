@@ -206,7 +206,19 @@ def init_params_quantized(config, key) -> Dict[str, Any]:
     """Random-init a quantized tree DIRECTLY in int8 — full-depth 8B bf16
     random-init would not fit single-chip HBM, which is the point of
     quantizing.  Distribution mimics init_params' N(0, 0.02): uniform int8
-    (std ~73) with a constant scale of 0.02/73 per output channel."""
+    (std ~73) with a constant scale of 0.02/73 per output channel.
+
+    ONE jitted call draws every leaf from ``key``, each straight into its
+    stored type: drawn eagerly, every ``randint`` first materialised its
+    uint32 bits, four times the leaf (the 10.4 GiB of temporaries that
+    dropped the Mixtral cell in PR 23).  The values are those the eager
+    draw gave: same keys, same ops."""
+    import jax
+
+    return jax.jit(lambda k: _draw_quantized(config, k))(key)
+
+
+def _draw_quantized(config, key) -> Dict[str, Any]:
     import jax
     import jax.numpy as jnp
 

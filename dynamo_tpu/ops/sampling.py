@@ -41,6 +41,10 @@ class SampleOut(NamedTuple):
     logprob: jnp.ndarray  # [B] f32 — raw log p(sampled token)
     top_ids: jnp.ndarray  # [B, TOPK_LOGPROBS] int32
     top_logprobs: jnp.ndarray  # [B, TOPK_LOGPROBS] f32
+    # A small int32 array some model families send home with the sampled
+    # tokens (models/family.py ``forward``); None for the others, and a None
+    # leaf adds nothing to a jitted program.
+    aux: object = None
 
 
 class SamplingParams(NamedTuple):

@@ -14,6 +14,7 @@ Services:
 
 from __future__ import annotations
 
+import asyncio
 from typing import Any, AsyncIterator, Dict
 
 from dynamo_tpu.engine import EngineConfig
@@ -49,7 +50,10 @@ class TpuWorker:
             max_model_len=int(self.config.get("max_model_len", 1024)),
             tp=int(self.config.get("tp", 1)),
         )
-        self.engine = TpuEngine(cfg)
+        # Off the event loop: building the engine compiles its initialisers
+        # (seconds on a busy host), and a loop blocked past the hub's 10 s
+        # lease TTL loses the lease before register_model can use it.
+        self.engine = await asyncio.to_thread(TpuEngine, cfg)
         component = self.runtime.namespace("examples").component("TpuWorker")
         self.engine.set_event_callback(
             KvEventPublisher(component, self.runtime.worker_id)

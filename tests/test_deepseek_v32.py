@@ -1,0 +1,435 @@
+"""DeepSeek-V3.2-Exp (models/deepseek_v32.py, ops/sparse_mla.py) against the
+plain float32 reference (models/reference/deepseek_v32.py) on seeded random
+weights at a small size on the CPU, in float32 under "highest" matmuls.
+
+Tolerances.  LOGITS 2e-5 of the largest reference logit: both sides are
+float32 and differ in summation order only (absorbed against materialised
+K/V, running against whole softmax, dispatch tables against a dense sum over
+experts); measured 2e-7.  A wrong selection, rope pairing or gate moves
+logits by 1e-2 or more at this size.  SELECTIONS are compared exactly: the
+scores are float32 on both sides and the test inputs sit far from ties,
+except where a tie is forced.
+"""
+
+import asyncio
+import json
+import math
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dynamo_tpu.models import deepseek_v32 as ds
+from dynamo_tpu.models.config import ModelConfig, register_config
+from dynamo_tpu.models.family import RaggedBatch, family_of
+from dynamo_tpu.models.reference import deepseek_v32 as ref
+from dynamo_tpu.ops import rope, sparse_mla
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+LOGIT_TOL = 2e-5
+
+HF = {
+    "model_type": "deepseek_v32", "vocab_size": 128, "hidden_size": 64, "num_hidden_layers": 3,
+    "num_attention_heads": 4, "num_key_value_heads": 4, "intermediate_size": 96,
+    "q_lora_rank": 32, "kv_lora_rank": 32, "qk_nope_head_dim": 16, "qk_rope_head_dim": 8,
+    "v_head_dim": 16, "index_n_heads": 4, "index_head_dim": 16, "index_topk": 8,
+    "first_k_dense_replace": 1, "n_group": 4, "topk_group": 2, "num_experts_per_tok": 2,
+    "n_routed_experts": 4, "n_routed_experts_published": 16, "ep_size": 4, "ep_rank": 1,
+    "n_shared_experts": 1, "moe_intermediate_size": 32, "routed_scaling_factor": 2.5,
+    "norm_topk_prob": True, "rms_norm_eps": 1e-6, "rope_theta": 10000,
+    "rope_scaling": {"beta_fast": 32, "beta_slow": 1, "factor": 40, "mscale": 1,
+                     "mscale_all_dim": 1, "original_max_position_embeddings": 16, "type": "yarn"},
+    "max_position_embeddings": 1024,
+}
+PS, PP, NPAGES, S = 4, 12, 40, 4  # page size, pages a row, pages, rows
+
+
+@pytest.fixture(scope="module", autouse=True)
+def highest():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+@pytest.fixture(scope="module")
+def model():
+    cfg = ModelConfig.from_hf_config(HF, name="dsv32-test").with_overrides(dtype="float32")
+    params = ds.init_params(cfg, jax.random.PRNGKey(0))
+    toks = np.random.RandomState(0).randint(0, HF["vocab_size"], size=40).astype(np.int32)
+    logits, masks = ref.forward(params, HF, toks)
+    return cfg, params, toks, np.asarray(logits), [np.asarray(m) for m in masks]
+
+
+def batch(toks, table, start, n, width, decode=False):
+    """One row's tokens [start, start + n) as a RaggedBatch of ``width``."""
+    tok, pos = np.zeros(width, np.int32), np.zeros(width, np.int32)
+    slots = np.full(width, -1, np.int32)
+    p = np.arange(start, start + n)
+    tok[:n], pos[:n] = toks[start:start + n], p
+    slots[:n] = table[p // PS] * PS + p % PS
+    tables = np.zeros((S, PP), np.int32)
+    tables[0] = table
+    kv = np.zeros(S, np.int32)
+    kv[0] = start + n
+    if decode:
+        cu, num = np.arange(S + 1, dtype=np.int32), S
+    else:
+        cu, num = np.zeros(S + 1, np.int32), 1
+        cu[1:] = n
+    return RaggedBatch(tok, pos, slots, kv, tables, cu, np.asarray([num], np.int32))
+
+
+def close(a, b):
+    return float(np.max(np.abs(np.asarray(a) - b)) / np.max(np.abs(b)))
+
+
+# ------------------------------------------------------------- (a) logits
+@pytest.mark.parametrize("sealed_prefix", [False, True], ids=["cold", "sealed-prefix"])
+def test_chunked_prefill_then_decode_matches_the_reference(model, sealed_prefix):
+    """Chunks of 16 through the paged latent and indexer caches, then decode
+    (the fused program's path and a one-token row riding a mixed step), with
+    index_topk 8 far under the context of 40.  ``sealed-prefix``: the first
+    16 tokens were computed by ANOTHER request into pages this one shares."""
+    cfg, params, toks, want, _ = model
+    cache = ds.LatentKVCache.create(cfg, NPAGES, PS, dtype=jnp.float32)
+    table = np.arange(5, 5 + PP).astype(np.int32)
+    kw = dict(block_q=8, block_k=8)
+    start = 0
+    if sealed_prefix:
+        other = table.copy()
+        other[4:] = np.arange(30, 30 + PP - 4)  # shares the first 4 pages only
+        _, cache, _ = ds.forward_ragged(params, cfg, batch(toks, other, 0, 16, 16), cache, **kw)
+        start = 16
+    else:
+        lg, cache, _ = ds.forward_ragged(params, cfg, batch(toks, table, 0, 16, 16), cache, **kw)
+        assert close(lg[0], want[15]) < LOGIT_TOL
+        start = 16
+    lg, cache, aux = ds.forward_ragged(params, cfg, batch(toks, table, start, 13, 16), cache, **kw)
+    assert close(lg[0], want[28]) < LOGIT_TOL
+    assert int(aux[1]) == 13 * 2  # real tokens x MoE layers; padding is not counted
+    for t in range(29, 40):
+        decode = t % 2 == 0
+        lg, cache, _ = ds.forward_ragged(
+            params, cfg, batch(toks, table, t, 1, S if decode else 16, decode), cache,
+            decode=decode, **kw)
+        assert close(lg[0], want[t]) < LOGIT_TOL, (t, decode)
+
+
+# ---------------------------------------------------------- (b) selection
+def test_selection_equals_the_references(model):
+    """S_t of every layer, for t + 1 < index_topk (keeps all) and beyond, from
+    a prefill chunk (mask) and from decode rows (positions)."""
+    cfg, params, toks, _, masks = model
+    cache = ds.LatentKVCache.create(cfg, NPAGES, PS, dtype=jnp.float32)
+    table = np.arange(3, 3 + PP).astype(np.int32)
+    _, cache, sels = ds.forward_ragged(params, cfg, batch(toks, table, 0, 30, 32), cache,
+                                       return_selection=True, block_q=8, block_k=16)
+    for l, sel in enumerate(sels):
+        got = np.asarray(sel)[:30, :40]
+        assert (got == masks[l][:30]).all(), l
+        assert got[3].sum() == 4 and got[20].sum() == HF["index_topk"]
+    _, cache, sels = ds.forward_ragged(params, cfg, batch(toks, table, 30, 1, S, True), cache,
+                                       decode=True, return_selection=True)
+    for l, sel in enumerate(sels):
+        assert sorted(np.asarray(sel)[0].tolist()) == np.flatnonzero(masks[l][30]).tolist(), l
+
+
+@pytest.mark.parametrize("k", [1, 3, 5, 16])
+def test_select_mask_is_exact_top_k_with_ties_to_the_lowest_index(k):
+    """Against a stable sort, on rows with a forced tie across the k-th
+    place, all-equal rows, negative zeros and rows with fewer than k live."""
+    inf = np.inf
+    rows = np.array([
+        [0.5, 0.2, 0.5, 0.9, 0.2, 0.2, -inf, 0.2, 0.1, 0.2],
+        [1.0] * 10,
+        [-0.0, 0.0, -1.0, 0.0, -0.0, 2.0, -inf, -inf, 0.0, -3.0],
+        [-inf, -inf, 0.3, -inf, -inf, -inf, 0.3, -inf, -inf, -inf],
+        [-inf] * 10,
+    ], np.float32)
+    got = np.asarray(sparse_mla.select_mask(jnp.asarray(rows) + 0.0, k))
+    for r, row in enumerate(rows):
+        order = np.argsort(-row, kind="stable")[:k]
+        want = np.zeros(10, bool)
+        want[order] = True
+        want &= row > -inf
+        assert (got[r] == want).all(), (r, got[r], want)
+    # The decode path's top_k breaks ties the same way.
+    vals, idx = jax.lax.top_k(jnp.asarray(rows[0]), min(k, 10))
+    live = np.asarray(idx)[np.asarray(vals) > -inf]
+    assert sorted(live.tolist()) == np.flatnonzero(got[0]).tolist()
+
+
+def test_a_forced_tie_selects_the_lowest_positions_in_both_paths(model):
+    """Zero selector weights make every score 0: S_t must be the first
+    index_topk positions, as the reference's stable sort gives."""
+    cfg, params, toks, _, _ = model
+    tied = dict(params, layers=dict(params["layers"],
+                                    idx_wproj=jnp.zeros_like(params["layers"]["idx_wproj"])))
+    _, masks = ref.forward(tied, HF, toks[:24])
+    cache = ds.LatentKVCache.create(cfg, NPAGES, PS, dtype=jnp.float32)
+    table = np.arange(PP).astype(np.int32)
+    _, cache, sels = ds.forward_ragged(tied, cfg, batch(toks, table, 0, 23, 32), cache,
+                                       return_selection=True, block_q=8, block_k=8)
+    assert (np.asarray(sels[1])[:23, :24] == np.asarray(masks[1])[:23]).all()
+    assert np.flatnonzero(np.asarray(sels[1])[22]).tolist() == list(range(8))
+    _, _, sels = ds.forward_ragged(tied, cfg, batch(toks, table, 23, 1, S, True), cache,
+                                   decode=True, return_selection=True)
+    assert sorted(np.asarray(sels[1])[0].tolist()) == list(range(8))
+
+
+# ---------------------------------------------------------------- (c) gate
+def test_gate_group_limited_choice_bias_for_choice_only_weights_scaled(model):
+    cfg, params, _, _, _ = model
+    lp = {k: v[0] for k, v in params["moe"].items()}
+    x = jax.random.normal(jax.random.PRNGKey(3), (64, 64), jnp.float32)
+    # A large bias on expert 5 forces it to be chosen everywhere ...
+    lp["router_bias"] = lp["router_bias"].at[5].set(10.0)
+    chosen, w = ds.gate(x, lp, cfg)
+    rc, rw = ref.gate(lp, HF, x)
+    assert (np.sort(np.asarray(chosen), -1) == np.sort(np.asarray(rc), -1)).all()
+    assert np.allclose(np.sort(np.asarray(w), -1), np.sort(np.asarray(rw), -1), atol=1e-6)
+    chosen, w = np.asarray(chosen), np.asarray(w)
+    assert (chosen == 5).any(axis=1).all()
+    # ... but its WEIGHT is its sigmoid score, not score + bias.
+    s = np.asarray(jax.nn.sigmoid(x @ lp["router"]))
+    picked = np.take_along_axis(s, chosen, axis=1)
+    assert np.allclose(w, 2.5 * picked / picked.sum(1, keepdims=True), atol=1e-6)
+    assert np.allclose(w.sum(1), 2.5, atol=1e-5)  # normalised, then scaled
+    # Group limit: the chosen experts lie in at most topk_group of the n_group groups,
+    # and those are the groups with the largest sum of their two best biased scores.
+    biased = s + np.asarray(lp["router_bias"])
+    gs = np.sort(biased.reshape(64, 4, 4), -1)[..., -2:].sum(-1)
+    best = np.argsort(-gs, axis=1)[:, :2]
+    for t in range(64):
+        assert set(chosen[t] // 4) <= set(best[t])
+
+
+# ------------------------------------------------- (d) the share test (s. 4)
+def test_all_shares_add_up_to_the_uncut_layer(model):
+    """model-configs section 4: over ALL ep_size shares of the experts, the
+    routed parts summed and the shared expert counted once equal the uncut
+    reference's whole layer.  Tolerance 1e-5 of the largest output: float32
+    sums in another order."""
+    cfg, _, _, _, _ = model
+    full_hf = dict(HF, n_routed_experts=16, ep_size=1, ep_rank=0)
+    full_cfg = ModelConfig.from_hf_config(full_hf, name="dsv32-full").with_overrides(dtype="float32")
+    full = ds.init_params(full_cfg, jax.random.PRNGKey(7))
+    lp_full = {k: v[0] for k, v in full["moe"].items()}
+    x = jax.random.normal(jax.random.PRNGKey(4), (48, 64), jnp.float32)
+    whole = np.asarray(ref.moe(lp_full, full_hf, x, list(range(16))))
+    shared = np.asarray(ref.ffn(x, lp_full["shared_gate"], lp_full["shared_up"], lp_full["shared_down"]))
+    total, pairs = np.zeros_like(whole), 0
+    for rank in range(4):
+        share_cfg = cfg.with_overrides(ep_rank=rank)
+        lp = dict(lp_full)
+        for name in ("moe_gate", "moe_up", "moe_down"):
+            lp[name] = lp_full[name][rank * 4:(rank + 1) * 4]
+        y, here = ds.moe_block(x, lp, share_cfg)
+        assert list(ds.held_experts(share_cfg)) == list(range(rank * 4, rank * 4 + 4))
+        # the reference given the same share says the same
+        assert close(y, np.asarray(ref.moe(lp, dict(HF, ep_rank=rank), x,
+                                           ref.held_experts(dict(HF, ep_rank=rank))))) < 1e-5
+        total += np.asarray(y) - shared
+        pairs += int(np.asarray(here).sum())
+    assert close(total + shared, whole) < 1e-5
+    assert pairs == 48 * HF["num_experts_per_tok"]  # every routed pair landed on one share
+
+
+# ---------------------------------------------------------------- (e) rope
+def test_yarn_frequencies_and_both_pairings_by_hand():
+    sc = {"type": "yarn", "factor": 40, "beta_fast": 32, "beta_slow": 1, "mscale": 1,
+          "mscale_all_dim": 1, "original_max_position_embeddings": 4096}
+    inv = np.asarray(rope.rope_frequencies(64, 10000.0, sc))
+    base = 10000.0 ** (-np.arange(0, 64, 2) / 64)
+    # correction dims: 64 ln(4096 / (b 2 pi)) / (2 ln 10000): b=32 -> 10.47 (floor 10), b=1 -> 22.5 (ceil 23)
+    assert math.floor(64 * math.log(4096 / (32 * 2 * math.pi)) / (2 * math.log(10000))) == 10
+    assert math.ceil(64 * math.log(4096 / (2 * math.pi)) / (2 * math.log(10000))) == 23
+    assert np.allclose(inv[:11], base[:11], rtol=1e-6)  # fast dims keep their frequency
+    assert np.allclose(inv[23:], base[23:] / 40, rtol=1e-6)  # slow dims are divided by factor
+    ramp = (16 - 10) / (23 - 10)  # dim 16 is blended linearly
+    assert np.isclose(inv[16], base[16] / 40 * ramp + base[16] * (1 - ramp), rtol=1e-6)
+    assert np.allclose(inv, np.asarray(ref.yarn_inv_freq(64, {"rope_theta": 10000.0, "rope_scaling": sc})),
+                       rtol=1e-6)
+    assert np.isclose(rope.yarn_mscale(sc), 0.1 * math.log(40) + 1) and np.isclose(
+        ref.softmax_scale({"qk_nope_head_dim": 128, "qk_rope_head_dim": 64, "rope_scaling": sc}),
+        192 ** -0.5 * 1.3688879 ** 2, rtol=1e-6)
+    # Pairings, by hand: x = (1, 0, 0, 0), position 1, angles (a0, a1).
+    x = jnp.asarray([[[1.0, 0.0, 0.0, 0.0]]])
+    f = jnp.asarray([0.5, 0.25])
+    pos = jnp.asarray([1])
+    inter = np.asarray(rope.apply_rope_interleaved(x, pos, f))[0, 0]
+    half = np.asarray(rope.apply_rope(x, pos, f))[0, 0]
+    assert np.allclose(inter, [math.cos(0.5), math.sin(0.5), 0, 0], atol=1e-6)  # pair (x0, x1)
+    assert np.allclose(half, [math.cos(0.5), 0, math.sin(0.5), 0], atol=1e-6)  # pair (x0, x2)
+    assert np.allclose(inter, np.asarray(ref.rope_interleaved(x[0], pos, f))[0], atol=1e-6)
+    assert np.allclose(half, np.asarray(ref.rope_half(x[0], pos, f))[0], atol=1e-6)
+
+
+# ------------------------------------------------------- (f) from_hf_config
+def test_from_hf_config_reads_the_catalog_row_and_the_cut_file():
+    catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
+    if os.path.exists(catalog):
+        with open(catalog) as f:
+            row = next(json.loads(l) for l in f if '"DeepSeek-V3.2-Exp"' in l)
+        published = row["config"]
+    else:  # the keys the catalog row holds, as this PR read them
+        published = None
+    with open(os.path.join(ROOT, "chipbench/configs/deepseek-v3.2-exp-6l-ep16.json")) as f:
+        body = json.load(f)
+    if published is not None:
+        full = ModelConfig.from_hf_config(published, name="full")
+        assert (full.model_type, full.num_layers, full.num_experts, full.router_experts) == (
+            "deepseek_v32", 61, 256, 256)
+        assert (full.q_lora_rank, full.kv_lora_rank, full.qk_nope_head_dim, full.qk_rope_head_dim,
+                full.v_head_dim, full.index_n_heads, full.index_head_dim, full.index_topk) == (
+            1536, 512, 128, 64, 128, 64, 128, 2048)
+        assert (full.n_group, full.topk_group, full.num_experts_per_token,
+                full.routed_scaling_factor, full.first_k_dense_replace) == (8, 4, 8, 2.5, 3)
+        # every published number is in the cut file unchanged, unless `reduced` names its key
+        for key, value in published.items():
+            if key not in body["reduced"]:
+                assert body[key] == value, key
+    cut = ModelConfig.from_hf_config(body, name="cut")
+    assert (cut.num_layers, cut.first_k_dense_replace, cut.num_experts, cut.router_experts,
+            cut.ep_size, cut.ep_rank, cut.vocab_size) == (6, 1, 16, 256, 16, 0, 16160)
+    assert list(ds.held_experts(cut)) == list(range(16))
+    shapes = ds.leaf_shapes(cut)
+    n = sum(int(np.prod(s)) for g in shapes.values() for s in g.values())
+    assert n == 5_587_117_824  # the 5.59 G parameters of the issue's arithmetic
+    assert ds.latent_width(cut) == 640 and family_of(cut).name == "deepseek_v32"
+    with pytest.raises(ValueError, match="router's width"):
+        ModelConfig.from_hf_config(dict(body, ep_size=8), name="bad")
+
+
+# ------------------------------------------- (g) block-moving planes refuse
+ENGINE = dict(block_size=4, num_blocks=64, max_batch=4, max_model_len=64, prefill_chunk=16,
+              dtype="float32", decode_steps=2)
+
+
+@pytest.fixture(scope="module")
+def engine():
+    from dynamo_tpu.engine import EngineConfig
+    from dynamo_tpu.engine.engine import TpuEngine
+
+    register_config(ModelConfig.from_hf_config(HF, name="dsv32-engine"))
+    # Closed by the one test that runs its loop (the serving test, inside
+    # that loop); the others only call methods that never start it.
+    return TpuEngine(EngineConfig(model="dsv32-engine", **ENGINE))
+
+
+@pytest.mark.parametrize("flag,kw", [
+    ("--host-cache-mb", dict(host_cache_bytes=1 << 20)),
+    ("--kv-cache-dtype", dict(cache_dtype="int8")),
+    ("--tp", dict(tp=2)),
+    ("--spec-decode", dict(spec_decode={"enable": True})),
+    ("--lora", dict(lora={"enable": True})),
+])
+def test_unsupported_engine_options_are_refused_by_flag(flag, kw):
+    from dynamo_tpu.engine import EngineConfig
+    from dynamo_tpu.engine.engine import TpuEngine
+
+    register_config(ModelConfig.from_hf_config(HF, name="dsv32-engine"))
+    with pytest.raises(ValueError, match=flag):
+        TpuEngine(EngineConfig(model="dsv32-engine", **ENGINE, **kw))
+
+
+def test_a_latent_block_is_sized_from_the_cache_and_never_moved_as_k_plus_v(engine):
+    from dynamo_tpu.engine.transfer import transfer_blocks_device
+
+    L, ps = 3, ENGINE["block_size"]
+    width = ds.latent_width(engine.model_config) + HF["index_head_dim"]
+    assert engine.block_nbytes() == L * ps * width * 4  # float32 pages here
+    assert engine.device_summary()["cache_kinds"] == "latent:512,index:64"
+    assert "inject" not in engine.compile_counts()
+
+    async def main():
+        for call in (engine.export_prompt_blocks([1] * 8),
+                     engine.inject_blocks([1] * 8, {}),
+                     engine.inject_blocks_from_device([1] * 8, None, 1),
+                     engine.freeze_sequence("nobody")):
+            with pytest.raises(ValueError, match="not K-plus-V pages"):
+                await call
+        with pytest.raises(ValueError, match="not K-plus-V pages"):
+            await transfer_blocks_device(engine, engine, [1] * 8)
+
+    asyncio.run(main())
+
+
+def test_the_engine_serves_it_with_prefix_reuse_and_counts(engine):
+    """Through TpuEngine's normal path (scheduler, block manager, prefix
+    cache, unified step, fused decode): greedy tokens equal the reference's
+    argmax over its own continuation, a second request reuses the first one's
+    sealed pages, and the selector and expert accounts grow."""
+    from dynamo_tpu.llm.metrics import sparse_model_metrics
+    from dynamo_tpu.llm.protocols import PreprocessedRequest, SamplingOptions, StopConditions
+    from dynamo_tpu.runtime.engine import Context, collect
+
+    async def gen(tokens, n):
+        req = PreprocessedRequest(
+            token_ids=list(tokens), stop_conditions=StopConditions(max_tokens=n, ignore_eos=True),
+            sampling_options=SamplingOptions()).to_dict()
+        out = await collect(await engine.generate(Context(req)))
+        return [t for item in out for t in item["token_ids"]]
+
+    async def main():
+        sparse_model_metrics.reset()
+        rs = np.random.RandomState(5)
+        doc = rs.randint(16, 128, 24).tolist()
+        first = await gen(doc + rs.randint(16, 128, 5).tolist(), 4)
+        hits0 = engine.kv.hit_rate
+        prompt = doc + rs.randint(16, 128, 7).tolist()
+        got = await gen(prompt, 6)
+        assert engine.kv.hit_rate > hits0 and len(first) == 4
+        params = ds.dequantize_params(engine.params) if "embed_scale" in engine.params else engine.params
+        seq = list(prompt)
+        for tok in got:  # teacher-forced: each token is the reference's argmax at its position
+            logits, _ = ref.forward(params, HF, np.asarray(seq, np.int32))
+            assert int(np.argmax(np.asarray(logits[-1]))) == tok
+            seq.append(tok)
+        dsa = sparse_model_metrics.dsa
+        assert set(dsa) >= {"unified"} and all(0 < v[1] <= v[0] for v in dsa.values())
+        assert sum(v[1] for v in dsa.values()) < sum(v[0] for v in dsa.values())  # selection is live
+        assert 0 < sparse_model_metrics.moe_local_pairs <= 2 * sparse_model_metrics.moe_routed_tokens
+        text = sparse_model_metrics.render()
+        for name in ("dsa_context_positions_total", "dsa_selected_positions_total",
+                     "moe_local_pairs_total", "moe_routed_tokens_total"):
+            assert f"dynamo_tpu_{name}" in text
+        await engine.close()
+
+    asyncio.run(main())
+
+
+def test_dsa_account_arithmetic(engine):
+    from dynamo_tpu.llm.metrics import sparse_model_metrics
+
+    sparse_model_metrics.reset()
+    k = HF["index_topk"]  # 8
+    engine._count_dispatch("unit", [0, 20, 5, -1], [4, 3, 6, 1])
+    want_ctx = sum(t + 1 for t in range(4)) + sum(t + 1 for t in range(20, 23)) + sum(
+        t + 1 for t in range(5, 11))
+    want_sel = sum(min(k, t + 1) for t in list(range(4)) + list(range(20, 23)) + list(range(5, 11)))
+    assert sparse_model_metrics.dsa["unit"] == [want_ctx, want_sel]
+    sparse_model_metrics.reset()
+    assert sparse_model_metrics.render() == ""
+
+
+def test_quantized_draw_and_dequantize_round_trip():
+    cfg = ModelConfig.from_hf_config(HF, name="q")
+    q = ds.init_params_quantized(cfg, jax.random.PRNGKey(1))
+    assert q["moe"]["moe_gate"].dtype == jnp.int8 and q["layers"]["w_uk"].dtype == jnp.bfloat16
+    assert q["moe"]["moe_gate_scale"].shape == (2, 4, 32) and q["lm_head_scale"].shape == (128,)
+    again = ds.init_params_quantized(cfg, jax.random.PRNGKey(1))
+    assert (np.asarray(q["layers"]["wo"]) == np.asarray(again["layers"]["wo"])).all()
+    f = ds.dequantize_params(q)
+    assert f["moe"]["moe_gate"].dtype == jnp.float32 and "moe_gate_scale" not in f["moe"]
+    back = ds.dequantize_params(ds.quantize_params(f))  # within half a step of each channel
+    w, w2 = np.asarray(f["moe"]["moe_gate"]), np.asarray(back["moe"]["moe_gate"])
+    assert np.abs(w - w2).max() <= 0.5 * np.abs(w).max() / 127 + 1e-9
+    assert ds.quantize_params(q) is q
+
+
+def test_the_benchmarks_copy_of_the_reference_is_the_reference():
+    with open(os.path.join(ROOT, "dynamo_tpu/models/reference/deepseek_v32.py")) as a, open(
+            os.path.join(ROOT, "chipbench/reference/deepseek_v32.py")) as b:
+        assert a.read() == b.read()

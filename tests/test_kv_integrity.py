@@ -264,14 +264,27 @@ def test_corruption_plane_matrix_disk_and_host(tmp_path):
         ]
         c0 = kv_integrity_metrics.corrupt_total["disk"]
         events.clear()
-        again = await _generate(engine, prompt, seed=3, temperature=0.9)
-        assert again == control  # recompute fallback is byte-identical
-        assert kv_integrity_metrics.corrupt_total["disk"] == c0 + 1
-        assert engine.integrity.banned(h)  # negative-cached (TTL)
-        # the corrupt block AND its chained descendants left every tier
-        for d in [h, *descendants]:
-            assert not engine.disk_kv.contains(d)
-            assert not engine.host_kv.contains(d)
+        # Hold the write-behind pump over the recompute and the check: the
+        # recompute seals the blocks again, and a pump cycle that lands before
+        # the check stores their CLEAN copies (it did in a quarter of the
+        # runs), which would hide whether the corrupt ones left the tiers.
+        real_drain = engine.drain_offload
+
+        async def held(max_blocks: int = 64) -> int:
+            return 0
+
+        engine.drain_offload = held
+        try:
+            again = await _generate(engine, prompt, seed=3, temperature=0.9)
+            assert again == control  # recompute fallback is byte-identical
+            assert kv_integrity_metrics.corrupt_total["disk"] == c0 + 1
+            assert engine.integrity.banned(h)  # negative-cached (TTL)
+            # the corrupt block AND its chained descendants left every tier
+            for d in [h, *descendants]:
+                assert not engine.disk_kv.contains(d)
+                assert not engine.host_kv.contains(d)
+        finally:
+            engine.drain_offload = real_drain
         removed = {
             hh
             for e in events
@@ -284,8 +297,14 @@ def test_corruption_plane_matrix_disk_and_host(tmp_path):
         prompt2 = list(range(200, 212))
         control2 = await _generate(engine, prompt2, seed=5, temperature=0.9)
         engine.host_kv.capacity_bytes = 64 << 20
-        await _settle_offload(engine, 1)
         blocks2 = hash_token_blocks(prompt2, BS)
+        # Until one of THIS prompt's blocks is on the host tier: the tier
+        # already holds others, and a pump cycle may be mid-commit.
+        for _ in range(200):
+            await engine.drain_offload()
+            if any(engine.host_kv.contains(tb.sequence_hash) for tb in blocks2):
+                break
+            await asyncio.sleep(0.01)
         host_resident = [
             tb.sequence_hash
             for tb in blocks2

@@ -183,3 +183,51 @@ def test_one_kv_head_per_shard_is_refused_with_a_sentence():
     )
     with pytest.raises(ValueError, match="KV head.* per shard"):
         TpuEngine(cfg)
+
+
+@pytest.mark.parametrize("decode", [False, True], ids=["unified-512", "decode-16"])
+def test_deepseek_v32_step_compiles_at_the_cells_shapes_without_copying_pages(
+    topo, no_persistent_cache, decode
+):
+    """chipbench/configs/deepseek-v3.2-exp-6l-ep16.json whole: 5.7 GB of int8
+    weights, 32768 latent and indexer pages, a 512-token chunk (or 16 decode
+    rows) against 576 pages a row.  The step's temporaries must stay far
+    under the page arrays' 4.8 GB: with a latent width of 576 the compiler
+    chose a page-minor layout and copied the whole array into and out of
+    every step (4.1 GB of temporaries; PR 28's rehearsal); 640 lanes
+    (``latent_width``) cured it."""
+    import json
+    import os
+
+    from dynamo_tpu.models import deepseek_v32 as ds
+    from dynamo_tpu.models.config import ModelConfig
+    from dynamo_tpu.models.family import RaggedBatch, family_of
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench/configs/deepseek-v3.2-exp-6l-ep16.json")) as f:
+        body = json.load(f)
+    serve = body["serve"]
+    mc = ModelConfig.from_hf_config(body, name="dsv32-compile")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(lambda k: ds._draw(mc, k, True), jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(
+        lambda: ds.LatentKVCache.create(mc, serve["num_blocks"], serve["block_size"])))
+    S, PP = serve["max_batch"], serve["max_model_len"] // serve["block_size"]
+    T = S if decode else serve["prefill_chunk"]
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)  # noqa: E731
+    rb = RaggedBatch(i32(T), i32(T), i32(T), i32(S), i32(S, PP), i32(S + 1), i32(1))
+    fam = family_of(mc)
+    compiled = jax.jit(
+        lambda p, c, rb: fam.forward(p, mc, rb, c, decode=decode), donate_argnums=1
+    ).lower(params, cache, rb).compile()
+    mem = compiled.memory_analysis()
+    pages = sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(cache))
+    assert pages == 32768 * 16 * (640 + 128) * 2 * 6
+    assert mem.alias_size_in_bytes >= pages  # the step updates the pages in place
+    assert mem.temp_size_in_bytes < 2.5e9, mem
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14e9, mem
