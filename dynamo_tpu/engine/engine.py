@@ -306,6 +306,12 @@ class TpuEngine(
         self.pipeline_rebuilds = 0       # sessions drained by a rebuild event
         self.continuous_admissions = 0   # sequences admitted in-loop
         self.continuous_retired = 0      # rows retired in-loop (no drain)
+        # The first token's path through an iteration (docs/
+        # decode_pipeline.md): first-token fetches applied on landing or at
+        # a harvest point, and prompt steps enqueued ahead of or behind a
+        # fused chunk of the same iteration.  One increment a prompt step.
+        self.first_harvest = {"landed": 0, "iteration": 0}
+        self.prompt_step_order = {"ahead": 0, "behind": 0}
         self.pipeline_wall_s = 0.0       # cumulative fused-session wall
         # Device-busy wall accumulated INSIDE fused sessions (decode
         # dispatch/wait + interleaved admission-prefill steps).  Unbounded
@@ -1900,6 +1906,8 @@ class TpuEngine(
         self.pipeline_rebuilds = 0
         self.continuous_admissions = 0
         self.continuous_retired = 0
+        self.first_harvest = dict.fromkeys(self.first_harvest, 0)
+        self.prompt_step_order = dict.fromkeys(self.prompt_step_order, 0)
         self.pipeline_wall_s = 0.0
         self.decode_busy_s = 0.0
         self.decode_stalls = 0
@@ -1938,6 +1946,8 @@ class TpuEngine(
                 "rebuilds": self.pipeline_rebuilds,
                 "continuous_admissions": self.continuous_admissions,
                 "continuous_retired": self.continuous_retired,
+                "first_harvest": dict(self.first_harvest),
+                "prompt_step": dict(self.prompt_step_order),
                 "wall_s": round(wall, 4),
                 "host_gap_frac": round(gap, 4),
                 # Stall-watchdog surface (DYN_DECODE_STALL_S): the health

@@ -368,14 +368,26 @@ class DecodePipelineMixin:
         dispatch-order invariants).  Dispatch awaits are covered too: a
         wedge can just as well surface one await earlier, blocking the
         ``to_thread(run)`` handoff with no fetch outstanding."""
-        thr = self._stall_threshold_s
-        if thr <= 0:
+        if self._stall_threshold_s <= 0:
             return await task
+        await self._wait_first({task}, kind, rows)
+        return task.result()
+
+    async def _wait_first(self, tasks, kind: str, rows: int) -> None:
+        """Return once ANY of ``tasks`` is done, under the decode-stall
+        watchdog of ``_await_device``.  The fused loop waits for a chunk's
+        fetch and the oldest first-token fetch at once, so a new row's token
+        is applied when it lands and not an iteration later."""
+        thr = self._stall_threshold_s
         waited = 0.0
         while True:
-            done, _ = await asyncio.wait({task}, timeout=thr)
+            done, _ = await asyncio.wait(
+                tasks,
+                timeout=thr if thr > 0 else None,
+                return_when=asyncio.FIRST_COMPLETED,
+            )
             if done:
-                return task.result()
+                return
             first = waited == 0.0
             waited += thr
             if first:
@@ -447,12 +459,19 @@ class DecodePipelineMixin:
         )
         self._pending_fetches.append((kind, task, *meta))
 
-    async def _harvest_pending(self, all_pending: bool = False) -> None:
+    async def _harvest_pending(
+        self, all_pending: bool = False, at: str = "iteration"
+    ) -> None:
         """Apply deferred fetches in dispatch order.  Harvests the oldest
-        entry (awaiting its background task), or everything outstanding."""
+        entry (awaiting its background task), or everything outstanding.
+        ``at`` says for the ``first_harvest`` counter whether the caller
+        waited on the fetch itself ("landed") or looked at a harvest
+        point of its iteration."""
         while self._pending_fetches:
             entry = self._pending_fetches.pop(0)
             kind, task = entry[0], entry[1]
+            if kind == "first":
+                self.first_harvest[at] += 1
 
             await self._pace()
             t0 = time.perf_counter()
@@ -543,11 +562,17 @@ class DecodePipelineMixin:
           in-flight chunks only, never an exit to the scheduler and the
           mixed-phase single-step regime.
         - **Double-buffered dispatch**: the oldest chunk's token fetch runs
-          in a worker thread while the next chunk's host-side planning
-          (slot ensure, table rows), the admission prefill dispatch and
-          completed first-token harvests all proceed — the host never
-          plans on the critical path (``decode_wait`` measures device
-          compute, not host work).
+          in a worker thread while the admission prefill dispatch, the
+          next chunk's host-side planning (slot ensure, table rows) and
+          its dispatch proceed, in that order — the host never plans on
+          the critical path (``decode_wait`` measures device compute, not
+          host work).
+        - **The first token's path**: a prompt step is enqueued AHEAD of
+          the iteration's top-up chunk, and a first-token fetch is applied
+          when it lands (the wait for the chunk's fetch watches both), so
+          work for a new row is neither ordered behind nor noticed after
+          work for old rows that was issued later
+          (docs/decode_pipeline.md has the iteration's order).
 
         ``want_rebuild`` fires only for genuinely incompatible changes:
         engine close, a frozen (mid-migration) row, a waiting head the
@@ -592,6 +617,7 @@ class DecodePipelineMixin:
         inflight: deque = deque()  # (outs, pos0, chunk_id, need_lp)
         chunk_id = 0   # monotone dispatch counter — the write-barrier clock
         harvested = 0  # highest chunk id applied so far
+        iter_chunk0 = 0  # chunk_id when the current iteration began
         # (seq, slot, barrier, remove): remove=False parks a FROZEN row out
         # of the session (migration quiescence) without releasing it from
         # the scheduler — the row stays resident, just unplanned.
@@ -795,6 +821,9 @@ class DecodePipelineMixin:
                 budget -= chunk
             if not items:
                 return False
+            self.prompt_step_order[
+                "behind" if chunk_id > iter_chunk0 else "ahead"
+            ] += 1
             # Counted as in-session DEVICE work for host_gap_frac: an
             # admitted prompt's prefill dispatches run inside the session
             # wall, and excluding them would read as a host-side gap
@@ -904,6 +933,7 @@ class DecodePipelineMixin:
             pos_disp[:] = np.where(pos_disp >= 0, pos_disp + T, pos_disp)
 
         while True:
+            iter_chunk0 = chunk_id
             if sweep_retire() and not continuous:
                 rebuild = True
             flush_retired()
@@ -915,8 +945,8 @@ class DecodePipelineMixin:
                 merge_ready()
 
             # Pop the oldest chunk and start its fetch FIRST: everything
-            # below — next-chunk planning + dispatch, admission, the
-            # interleaved prefill, completed first-token harvests —
+            # below — admission, the interleaved prefill, next-chunk
+            # planning + dispatch, completed first-token harvests —
             # overlaps the D2H running in the fetch thread.
             fetch_task = None
             if inflight:
@@ -925,6 +955,26 @@ class DecodePipelineMixin:
                 fetch_task = asyncio.get_running_loop().create_task(
                     asyncio.to_thread(self._fetch_outs, outs, lp)
                 )
+
+            # Prompt steps go to the device BEFORE this iteration's top-up
+            # chunk: the device queue reads C_k, P_k, C_k+1 and a prompt's
+            # last chunk (its first token) does not wait behind a fused
+            # chunk dispatched microseconds before it.  The chunk already
+            # in flight keeps the device fed while the host builds the
+            # step; the device order between the two is free (disjoint
+            # rows and blocks).  A pure-decode iteration does nothing here.
+            progressed = False
+            if not rebuild:
+                admit()
+                if await prefill_step():
+                    dispatched_any = True
+                    progressed = True
+            # First tokens that landed while the loop was busy apply here,
+            # still ahead of the top-up: a row that is ``ready`` holds it.
+            while self._pending_fetches and self._pending_fetches[0][1].done():
+                await self._harvest_pending()
+                progressed = True
+            promote_ready()
 
             # Top up the dispatch window.  With anyone waiting to join
             # (queued, prefilling, or merge-pending), cap the in-flight
@@ -937,7 +987,6 @@ class DecodePipelineMixin:
                 else cfg.pipeline_depth
             )
             in_flight_now = len(inflight) + (1 if fetch_task is not None else 0)
-            progressed = False
             while (
                 not rebuild
                 and not ready
@@ -953,20 +1002,24 @@ class DecodePipelineMixin:
                 progressed = True
                 if want_rebuild():
                     rebuild = True
-            if not rebuild:
-                admit()
-                if await prefill_step():
-                    dispatched_any = True
-                    progressed = True
-            # Completed deferred fetches (admitted rows' first tokens)
-            # apply for free while the oldest chunk is still in flight.
-            while self._pending_fetches and self._pending_fetches[0][1].done():
-                await self._harvest_pending()
-                progressed = True
 
             if fetch_task is not None:
                 await self._pace()
                 with TraceAnnotation("engine.harvest:decode"):
+                    # A first token that lands while the chunk computes is
+                    # applied NOW, not at the next iteration's harvest
+                    # point: its stream gets it at once, and its row is in
+                    # ``ready`` before the next top-up.
+                    while self._pending_fetches and not fetch_task.done():
+                        first_task = self._pending_fetches[0][1]
+                        await self._wait_first(
+                            {fetch_task, first_task},
+                            "decode_wait",
+                            slots.num_active,
+                        )
+                        if first_task.done():
+                            await self._harvest_pending(at="landed")
+                            promote_ready()
                     sampled, logp, top_ids, top_lp = await self._await_device(
                         fetch_task, "decode_wait", slots.num_active
                     )
@@ -999,7 +1052,7 @@ class DecodePipelineMixin:
                 if self._pending_fetches:
                     # Nothing dispatchable until a first-token fetch lands:
                     # block on the oldest instead of spinning.
-                    await self._harvest_pending()
+                    await self._harvest_pending(at="landed")
                 else:
                     promote_ready()
                     if ready and not rebuild:

@@ -618,6 +618,24 @@ class EngineDispatchMetrics:
         emit("host_gap_frac", "gauge",
              "Fraction of fused-session wall not covered by decode "
              "dispatch/wait device work", pipe.get("host_gap_frac", 0.0))
+        # The first token's path through a session's iteration (engine/
+        # pipeline.py _decode_pipeline; docs/decode_pipeline.md).  OUTSIDE
+        # the _dispatch ns, like the stall counter below.
+        for name, label, help_ in (
+            ("first_harvest", "at",
+             "First-token fetches applied when they landed (during the "
+             "wait for a fused chunk) or at an iteration's harvest point"),
+            ("prompt_step", "order",
+             "In-session prompt steps enqueued ahead of or behind a fused "
+             "chunk of the same iteration"),
+        ):
+            lines.append(f"# HELP {prefix}_pipeline_{name}_total {help_}")
+            lines.append(f"# TYPE {prefix}_pipeline_{name}_total counter")
+            for value, n in (pipe.get(name) or {}).items():
+                lines.append(
+                    f'{prefix}_pipeline_{name}_total'
+                    f'{{{label}="{escape_label(value)}"}} {n}'
+                )
         # Decode-stall watchdog (decode_stall_s / DYN_DECODE_STALL_S;
         # engine/pipeline.py _await_device).  OUTSIDE the _dispatch ns —
         # the alert rule keys on this exact name.

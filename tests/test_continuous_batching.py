@@ -105,6 +105,7 @@ def _run_modes(temperature, spec=None):
                     "rebuilds": engine.pipeline_rebuilds,
                     "admissions": engine.continuous_admissions,
                     "retired": engine.continuous_retired,
+                    "prompt_steps": dict(engine.prompt_step_order),
                 },
             )
         finally:
@@ -124,6 +125,21 @@ def test_continuous_vs_rebuild_exact_streams_seeded_temp09():
     assert stats["admissions"] >= 1, stats
     assert stats["retired"] >= 1, stats
     assert stats["rebuilds"] == 0, stats
+
+
+@pytest.mark.parametrize("spec", [None, {"enable": True, "k": 4}],
+                         ids=["spec-off", "spec-on"])
+def test_exact_streams_seeded_rows_join_ahead_of_the_top_up(spec):
+    """The byte-identity gate under the iteration's order of ISSUE 29:
+    seeded temperature-0.8 rows join mid-session, their prompt steps are
+    enqueued AHEAD of the iteration's top-up chunk (never behind one), and
+    not a token differs from the drain-rebuild control, speculation on and
+    off."""
+    on, off, stats = _run_modes(temperature=0.8, spec=spec)
+    assert on == off, "the prompt step's place in the iteration changed a stream"
+    assert stats["admissions"] >= 1, stats
+    assert stats["prompt_steps"]["ahead"] >= 1, stats  # rows share steps
+    assert stats["prompt_steps"]["behind"] == 0, stats
 
 
 def test_continuous_vs_rebuild_exact_streams_greedy_spec_on():
@@ -208,6 +224,167 @@ def test_freeze_quiesces_continuous_pipeline_and_resumes_exact():
     got_a, got_b = asyncio.run(frozen_run())
     assert got_a == ctrl_a
     assert got_b == ctrl_b
+
+
+async def _session(engine, live: bool):
+    """Wait until a fused session is live, or until the last one has torn
+    down (a stream ends a few iterations before its session does, and a
+    request sent in between is admitted into the OLD session)."""
+    for _ in range(4000):
+        if bool(engine._pipeline_members) == live:
+            return
+        await asyncio.sleep(0.002)
+    raise AssertionError(f"no fused session {'began' if live else 'ended'}")
+
+
+def _record_enqueues(engine):
+    """Recording stand-ins for the two device programs and the chunk
+    accept: ``log`` reads ``P`` (a unified step enqueued), ``C`` (a fused
+    chunk enqueued) and ``|`` (a fused chunk accepted: the end of the
+    iteration that awaited it), in the loop's own order."""
+    log = []
+    step, multi, accept = engine._step_fn, engine._multi_fn, engine._accept_chunk
+
+    def step_fn(*a, **k):
+        log.append("P")
+        return step(*a, **k)
+
+    def multi_fn(*a, **k):
+        log.append("C")
+        return multi(*a, **k)
+
+    def accept_chunk(*a, **k):
+        log.append("|")
+        return accept(*a, **k)
+
+    # compile_counts() (dispatch_summary's device block) reads the jit cache.
+    step_fn._cache_size, multi_fn._cache_size = step._cache_size, multi._cache_size
+    engine._step_fn, engine._multi_fn = step_fn, multi_fn
+    engine._accept_chunk = accept_chunk
+    return log
+
+
+def test_prompt_step_is_enqueued_ahead_of_the_top_up_chunk():
+    """ISSUE 29 (a).  An iteration with an admitted prompt AND room in the
+    fused window enqueues the prompt step first (device queue ``C_k, P_k,
+    C_k+1``, not ``C_k, P_k-1, C_k+1, P_k``); an iteration with nobody
+    prefilling enqueues what it always did: one chunk, the window's top-up."""
+
+    async def main():
+        engine = TpuEngine(EngineConfig(**CFG))
+        try:
+            log = _record_enqueues(engine)
+            # Alone: prefill outside the session, then pure decode.
+            await _one(engine, 0, 33, 0.0)
+            await _session(engine, live=False)
+            alone = "".join(log)
+            assert engine.prompt_step_order == {"ahead": 0, "behind": 0}
+            del log[:]
+            # A long row keeps the session alive; a 40-token prompt (three
+            # chunks of 16) joins it mid-session.
+            long_row = asyncio.create_task(_one(engine, 1, 64, 0.0))
+            await _session(engine, live=True)
+            req = _req(_prompt(2, n=40), max_tokens=6, seed=3)
+            await collect(await engine.generate(Context(req)))
+            await long_row
+            return alone, "".join(log), dict(engine.prompt_step_order)
+        finally:
+            await engine.close()
+
+    alone, joined, order = asyncio.run(main())
+    # Pure decode, as before the change: the prompt's one step, the window
+    # filled to pipeline_depth 2, then exactly one chunk an iteration until
+    # no row can use another (8 chunks carry the 32 tokens after the first).
+    assert alone == "P" + "CC|" + "C|" * 6 + "|", alone
+    iterations = [it for it in joined.split("|") if "P" in it and "C" in it]
+    assert iterations, joined
+    for it in iterations:
+        assert it.index("P") < it.index("C"), (it, joined)
+    assert order["behind"] == 0 and order["ahead"] >= 3, order
+
+
+def test_first_token_is_applied_when_it_lands_not_an_iteration_later():
+    """ISSUE 29 (b).  While a fused chunk's fetch is held, the first-token
+    fetch of a row admitted in that iteration completes: the loop applies
+    it at once (first token out, ``first_harvest{at="landed"}``), BEFORE the
+    held chunk is accepted, and the row then joins the chain and finishes
+    with the stream it has when served alone."""
+    import threading
+
+    from dynamo_tpu.llm.metrics import engine_dispatch_metrics
+
+    async def main():
+        engine = TpuEngine(EngineConfig(**CFG))
+        try:
+            alone = await _one(engine, 2, 9, 0.7)
+            await _session(engine, live=False)
+            log = _record_enqueues(engine)
+            release = threading.Event()
+            fetch_outs, apply = engine._fetch_outs, engine._apply_harvest
+
+            def apply_harvest(kind, *a):
+                log.append(kind)
+                return apply(kind, *a)
+
+            engine._apply_harvest = apply_harvest
+            long_row = asyncio.create_task(_one(engine, 1, 96, 0.7))
+            await _session(engine, live=True)
+            # Park the loop at its next device op, queue the newcomer, let
+            # go: the iteration that admits its one-chunk prompt (or, when
+            # the window was empty, the next) has its chunk's fetch held.
+            gate = asyncio.Event()
+            engine.pace_hook = gate.wait
+            admitted_before = engine.continuous_admissions
+
+            def held_fetch(out, need_lp):
+                # A fused chunk's [steps, rows], popped in the iteration that
+                # admitted the newcomer (admit() runs before the fetch thread
+                # starts) or later: by count, not by the clock.
+                if (
+                    out.tokens.ndim == 2
+                    and engine.continuous_admissions > admitted_before
+                    and not release.is_set()
+                ):
+                    log.append("held")
+                    release.wait(timeout=60)
+                return fetch_outs(out, need_lp)
+
+            engine._fetch_outs = held_fetch
+            req = _req(_prompt(2), max_tokens=9, seed=3, temperature=0.7)
+            stream = await engine.generate(Context(req))
+            for _ in range(4000):
+                if engine.scheduler.num_waiting:
+                    break
+                await asyncio.sleep(0.002)
+            assert engine.scheduler.num_waiting == 1
+            del log[:]
+            engine.pace_hook = None
+            gate.set()
+            first = await asyncio.wait_for(stream.__anext__(), timeout=30)
+            seen = list(log)
+            landed = dict(engine.first_harvest)
+            engine_dispatch_metrics.set_source(engine.dispatch_summary)
+            text = engine_dispatch_metrics.render()
+            release.set()
+            rest = [it async for it in stream]
+            await long_row
+            toks = [t for it in [first] + rest for t in it["token_ids"]]
+            return alone, toks, seen, landed, text
+        finally:
+            engine_dispatch_metrics.reset()
+            release.set()
+            await engine.close()
+
+    alone, toks, seen, landed, text = asyncio.run(main())
+    assert toks == alone
+    held, first = seen.index("held"), seen.index("first")
+    assert held < first, seen
+    assert "|" not in seen[held:], seen  # the held chunk is not accepted yet
+    assert landed["landed"] >= 1, landed
+    assert 'dynamo_tpu_pipeline_first_harvest_total{at="landed"} ' in text
+    assert 'dynamo_tpu_pipeline_first_harvest_total{at="iteration"} ' in text
+    assert 'dynamo_tpu_pipeline_prompt_step_total{order="ahead"} ' in text
+    assert 'dynamo_tpu_pipeline_prompt_step_total{order="behind"} 0' in text
 
 
 def test_zero_new_compiles_in_loop_admission():

@@ -124,6 +124,44 @@ async def test_the_account_closes_and_every_count_is_the_requests_served():
     assert len(collector) == 0  # nothing sampled: the span ring stays empty
 
 
+async def test_the_account_closes_when_first_tokens_are_applied_on_landing(monkeypatch):
+    """A long answer keeps one fused session alive while short prompts join
+    it; the loop applies a first token when its fetch lands (ISSUE 29), so
+    ``t_first_token`` may come moments after ``t_fetch_done`` and never
+    before it: no fold is out of order, no hop negative, the eight still
+    sum to ``server_ttft``."""
+    folds = []
+    fold = request_hop_metrics.fold_engine
+
+    def fold_engine(*stamps):
+        folds.append(stamps)
+        return fold(*stamps)
+
+    monkeypatch.setattr(request_hop_metrics, "fold_engine", fold_engine)
+    n = 0
+    async with Served() as s:
+        long_answer = asyncio.ensure_future(s.complete(0, max_tokens=400))
+        while not long_answer.done():
+            await s.complete(n + 1, max_tokens=2)
+            n += 1
+        await long_answer
+        landed = s.engine.first_harvest["landed"]
+        text = await s.metrics()
+    assert landed >= 1, (s.engine.first_harvest, n)
+    assert len(folds) == n + 1
+    for stamps in folds:
+        t_last_chunk, t_fetch_done, t_first_token = stamps[3:6]
+        assert 0.0 < t_last_chunk <= t_fetch_done <= t_first_token, stamps
+    sums = _series(text, "dynamo_tpu_request_hop_seconds_sum")
+    counts = _series(text, "dynamo_tpu_request_hop_seconds_count")
+    assert all(counts[H.HOPS[i]] == n + 1 for i in TTFT_HOPS), counts
+    assert all(sums[h] >= 0.0 for h in H.HOPS), sums
+    parts = sum(sums[H.HOPS[i]] for i in TTFT_HOPS)
+    assert abs(parts - sums["server_ttft"]) < 1e-6 * (n + 1)
+    assert _series(text, "dynamo_tpu_request_hop_incomplete_total") == {
+        "engine": 0.0, "edge": 0.0}
+
+
 def test_fold_arithmetic_on_hand_written_stamps():
     m = RequestHopMetrics()
     assert m.fold_engine(1.0, 1.5, 1.75, 2.0, 2.5, 2.625, 3.0)
