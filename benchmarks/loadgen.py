@@ -548,9 +548,6 @@ async def _self_host(args):
         # (how large: not measured on this machine).
         prefill_chunk=int(os.environ.get("LOADGEN_PREFILL_CHUNK", "2048")),
         decode_steps=int(os.environ.get("LOADGEN_DECODE_STEPS", "16")),
-        prefill_chunks_per_burst=int(
-            os.environ.get("LOADGEN_CHUNKS_PER_BURST", "24")
-        ),
         pipeline_depth=4,
         dtype="float32" if backend == "cpu" else "bfloat16",
         weight_quant=quant,

@@ -69,7 +69,8 @@ class SpecDecodeConfig:
     # are scanned (0 = unlimited).  Bounds per-proposal cost at long
     # contexts; recent history is where templated repetition lives.
     lookback: int = 2048
-    # Engagement bar vs the fused pipeline (pure-decode plans): speculate
+    # Engagement bar vs the fused pipeline (plans that would otherwise run
+    # as a fused session, ``StepPlan.session``): speculate
     # when the expected committed tokens per round trip reach
     # ``pipeline_margin * n_decode * decode_steps``.  A verification step
     # streams the weights ONCE for all its rows where a fused chunk
@@ -339,13 +340,6 @@ class EngineConfig:
     # (the disagg degraded-mode shape; the request is never lost).
     kv_pull_max_bytes: int = 64 << 20
     kv_pull_timeout_s: float = 5.0
-    # Mixed-phase cadence: while prompts are prefilling, decode rows are
-    # excluded from the (fetch-free) prefill steps and advance via a fused
-    # decode_steps burst once every this many prefill chunks — balancing
-    # prefill throughput against decode stall (engine.py _run_loop).
-    # The value 24 comes from an earlier round's sweep whose records are
-    # gone; not measured on this machine.
-    prefill_chunks_per_burst: int = 24
     # Draft-free speculative decoding section (SpecDecodeConfig; accepts a
     # dict / bool from layered configs).  Engine-level default; requests
     # opt out per call via sampling_options.spec_decode=false (nvext).

@@ -54,7 +54,7 @@ from ..parallel.mesh import (
 from ..runtime.engine import AsyncEngine, Context, ResponseStream
 from .config import EngineConfig
 from .kv_manager import KvBlockManager
-from .scheduler import Scheduler, SequenceState, StepPlan
+from .scheduler import Scheduler, SequenceState
 
 logger = logging.getLogger(__name__)
 
@@ -268,24 +268,16 @@ class TpuEngine(
         # Largest observed gap between engine-loop iterations (stall
         # attribution; reset by clearing alongside step_trace readers).
         self.loop_gap_max = 0.0
-        # Mixed-phase cadence: prefill chunks run since the last decode
-        # burst (see _run_loop).
-        self._chunks_since_burst = 0
-        # Preemption/migration requeues of mid-prefill sequences observed
-        # via the scheduler counter; a requeue resets the cadence so the
-        # NEXT mixed phase does not inherit a stale chunk count and burst
-        # immediately (_note_prefill_requeues).
-        self._prefill_requeues_seen = 0
         # Prefill-chunk accounting (pipeline._run_unified): cumulative
         # chunk count / wall / prompt tokens plus a bounded per-chunk wall
         # trace for the latency quantiles on /metrics
-        # (dynamo_tpu_prefill_chunk_seconds) and in the bench JSON.
+        # (dynamo_tpu_prefill_chunk_seconds).
         self.prefill_chunks = 0
         self.prefill_wall_s = 0.0
         self.prefill_tokens = 0
         self._prefill_chunk_trace: deque = deque(maxlen=4096)
         # Deferred token fetches (FIFO).  Prompt-completing unified steps
-        # AND mixed-phase decode bursts start their token D2H
+        # and speculative verification steps start their token D2H
         # asynchronously, park their rows (awaiting_fetch), and keep the
         # loop dispatching; accepts happen at harvest points once the
         # round trip has overlapped with real work (what a blocking
@@ -300,8 +292,7 @@ class TpuEngine(
         # Continuous-batching pipeline health (engine/pipeline.py): how
         # often fused sessions start/drain, and how much membership churn
         # the in-loop paths absorbed without a drain.  Exported on /metrics
-        # as dynamo_tpu_engine_dispatch_* (llm/metrics.py) and folded into
-        # the bench JSON.
+        # as dynamo_tpu_engine_dispatch_* (llm/metrics.py).
         self.pipeline_sessions = 0       # _decode_pipeline runs begun
         self.pipeline_rebuilds = 0       # sessions drained by a rebuild event
         self.continuous_admissions = 0   # sequences admitted in-loop
@@ -867,8 +858,9 @@ class TpuEngine(
 
     # ---------------------------------------------------------------- warmup
     def compile_counts(self) -> Dict[str, int]:
-        """Compiled-program count per jitted entry (cache sizes).  The bench
-        asserts these do not grow inside its timed window."""
+        """Compiled-program count per jitted entry (cache sizes).  The
+        benchmark (``dynamo_tpu_engine_compiled_programs``) and the tests
+        assert these do not grow after ``warmup()``."""
         counts = {
             "step": self._step_fn._cache_size(),
             "multi": self._multi_fn._cache_size(),
@@ -1657,7 +1649,6 @@ class TpuEngine(
                 return
             with TraceAnnotation("engine.schedule"):
                 plan = self.scheduler.schedule()
-            self._note_prefill_requeues()
             for seq in self.scheduler.take_rejected():
                 self._finish(seq, FinishReason.ERROR)
             if plan is None:
@@ -1701,90 +1692,27 @@ class TpuEngine(
                 if drafts:
                     await self._run_spec_unified(plan, drafts)
                     did_work = True
-                if (
-                    not did_work
-                    and plan.pure_decode
-                    and self.cfg.decode_steps > 1
-                ):
+                if not did_work and plan.session:
                     if self._pending_fetches:
                         # Parked rows must not sit out a whole fused
                         # pipeline run — fold them in first.
                         await self._harvest_pending(all_pending=True)
                         continue
-                    # Leaving the mixed regime: a stale chunk count must not
-                    # trigger an immediate burst in the NEXT mixed phase.
-                    self._chunks_since_burst = 0
+                    # The plan's decode rows start the session; its prompts
+                    # and the waiting queue are picked up inside it.
                     did_work = await self._decode_pipeline(
-                        [seq for seq, _, _ in plan.items]
+                        [
+                            seq
+                            for seq, start, _ in plan.items
+                            if start >= len(seq.prompt)
+                        ]
                     )
-                if not did_work and self.cfg.decode_steps > 1:
-                    # Mixed phase (prefill + decode in one plan): running
-                    # decode rows inside the unified step gives them ONE
-                    # token per dispatch+fetch round trip — with prefill
-                    # almost always active under continuous arrivals, that
-                    # made conc 16 SLOWER than conc 8 (r4 ladder).  Instead:
-                    # fetch-free prefill-only steps at device rate, and
-                    # every cfg.prefill_chunks_per_burst of them one fused
-                    # burst advancing every decode row decode_steps tokens
-                    # for a single round trip.  (Bursting after EVERY chunk
-                    # was tried first and throttled prefill ~3x: 8 requests'
-                    # first wave alone is ~47 chunks.)
-                    decode_items = [
-                        it for it in plan.items if it[1] >= len(it[0].prompt)
-                    ]
-                    prefill_items = [
-                        it for it in plan.items if it[1] < len(it[0].prompt)
-                    ]
-                    # Grammar-constrained decode rows (llm/tenancy) never
-                    # burst — their logit mask advances host-side per
-                    # token — so they ride the unified prefill steps
-                    # instead (one token per step, mask rebuilt each time)
-                    # while unconstrained rows keep the fused-burst cadence.
-                    burstable = [
-                        it for it in decode_items if it[0].grammar is None
-                    ]
-                    step_extra = [
-                        it
-                        for it in decode_items
-                        if it[0].grammar is not None
-                    ]
-                    # Without prefill in the plan this branch would starve
-                    # the burstable rows (only the periodic burst advances
-                    # them): fall through to the plain unified step instead,
-                    # which gives EVERY row one token per round trip.
-                    if burstable and prefill_items:
-                        await self._run_unified(
-                            StepPlan(prefill_items + step_extra)
-                        )
-                        self._chunks_since_burst += 1
-                        if (
-                            self._chunks_since_burst
-                            >= self.cfg.prefill_chunks_per_burst
-                        ):
-                            self._chunks_since_burst = 0
-                            # Replan against freezes/finishes that landed
-                            # DURING the awaited prefill step: a frozen
-                            # (mid-migration) row advanced here would emit
-                            # tokens its cutover snapshot lacks.
-                            burst_items = [
-                                it
-                                for it in burstable
-                                if not it[0].finished and not it[0].frozen
-                            ]
-                            if burst_items and not await self._decode_burst(
-                                [s for s, _, _ in burst_items]
-                            ):
-                                # No KV headroom for a whole burst: the
-                                # 1-token slots are already allocated.
-                                self.step_trace.append(
-                                    ("burst_fallback", 0.0, len(burst_items), 0)
-                                )
-                                await self._run_unified(StepPlan(burst_items))
-                        did_work = True
                 if not did_work:
-                    # Not enough KV headroom for a fused window (or not a
-                    # pure-decode state): single unified step still advances
-                    # every sequence one token, and finishes free blocks.
+                    # Not a session's plan (prompts only, decode_steps 1, a
+                    # grammar-constrained row resident), or the session
+                    # dispatched nothing for want of KV headroom for a
+                    # fused window: one unified step advances every row of
+                    # the plan, and finishes free blocks.
                     await self._run_unified(plan)
             except asyncio.CancelledError:
                 raise
@@ -1840,23 +1768,10 @@ class TpuEngine(
 
 
 
-    def _note_prefill_requeues(self) -> None:
-        """Reset the mixed-phase chunk cadence when a mid-prefill sequence
-        was requeued since the last scheduling pass (preemption folds the
-        partial prompt back into waiting; migration retires it).  The
-        requeued sequence restarts its chunk sequence from zero, so a
-        chunk count carried over from BEFORE the requeue would trigger the
-        first decode burst of the next mixed phase too early and skew its
-        cadence (ISSUE 19 satellite)."""
-        reqs = getattr(self.scheduler, "prefill_requeues", 0)
-        if reqs != self._prefill_requeues_seen:
-            self._prefill_requeues_seen = reqs
-            self._chunks_since_burst = 0
-
     def _note_prefill_chunk(self, wall_s: float, tokens: int) -> None:
         """Account one prefill chunk (called by pipeline._run_unified for
         every unified step that advanced prompt tokens): cumulative
-        counters feed the bench MFU math, the bounded trace feeds the
+        counters for rates, the bounded trace for the
         dynamo_tpu_prefill_chunk_seconds quantiles."""
         self.prefill_chunks += 1
         self.prefill_wall_s += wall_s
@@ -1896,34 +1811,13 @@ class TpuEngine(
             }
         return out
 
-    def reset_dispatch_stats(self) -> None:
-        """Zero the dispatch trace AND the session counters together (the
-        bench's timed window): mixing warm-pass counters with timed-window
-        wall time would make rebuilds-per-session vs wall_s internally
-        inconsistent in BENCH_r*.json."""
-        self.step_trace.clear()
-        self.pipeline_sessions = 0
-        self.pipeline_rebuilds = 0
-        self.continuous_admissions = 0
-        self.continuous_retired = 0
-        self.first_harvest = dict.fromkeys(self.first_harvest, 0)
-        self.prompt_step_order = dict.fromkeys(self.prompt_step_order, 0)
-        self.pipeline_wall_s = 0.0
-        self.decode_busy_s = 0.0
-        self.decode_stalls = 0
-        self.last_stall = None
-        self.prefill_chunks = 0
-        self.prefill_wall_s = 0.0
-        self.prefill_tokens = 0
-        self._prefill_chunk_trace.clear()
-
     def dispatch_summary(self) -> Dict[str, Any]:
         """Machine-readable decode-pipeline health: the per-kind dispatch
         trace (step_summary — over the BOUNDED trace window, so its counts
         and percentiles are gauges, not counters) plus session/rebuild/
         churn counters and the fused-loop host-gap fraction — what the
-        planner and bench read off ``/metrics`` (llm/metrics.py
-        engine_dispatch_metrics) instead of parsing bench stderr.
+        planner and the benchmark read off ``/metrics`` (llm/metrics.py
+        engine_dispatch_metrics).
 
         ``host_gap_frac`` is scoped to fused decode sessions: the fraction
         of pipeline wall NOT covered by in-session device work (decode

@@ -186,11 +186,6 @@ class MigrationMixin:
         """
         seq = self.find_sequence(request_id)
         if seq is not None:
-            # A sequence migrated out mid-prefill leaves the mixed phase:
-            # its chunk count must not carry into the cadence of whoever
-            # prefills next (same invariant as preemption requeue).
-            if seq.in_prefill:
-                self._chunks_since_burst = 0
             seq.finished = True
             seq.frozen = False
             # Cutover bypasses pipeline._finish, so the adapter-slot ref

@@ -1,5 +1,5 @@
 """Fused decode pipeline: unified ragged steps, multi-step decode chains,
-deferred token fetches/harvest, mixed-phase bursts, and token acceptance.
+deferred token fetches/harvest, and token acceptance.
 
 Split out of engine.py as a pure move (r5; VERDICT r4 weak #7) — these are
 TpuEngine methods, combined via mixin inheritance.  See engine.py for the
@@ -35,13 +35,6 @@ class DecodePipelineMixin:
     # Numpy fast path for per-chunk token acceptance (_accept_chunk); tests
     # flip this off to prove equivalence against the scalar loop.
     _vectorized_accept = True
-    # Continuous batching in the fused decode loop: retire finished rows and
-    # admit waiting sequences between chunk dispatches instead of draining
-    # the whole pipeline on every membership change.  Tests and the churn
-    # bench flip this off to run the legacy drain-on-any-change behaviour
-    # as the exact-stream control (both modes are token-identical; only the
-    # scheduling shape differs).
-    _continuous_decode = True
 
     def _start_d2h(self, out, need_lp: bool) -> None:
         """Start the sampled-output device→host copies for a dispatched
@@ -308,7 +301,7 @@ class DecodePipelineMixin:
         # counts as one chunk (mixed plans attribute the whole dispatch
         # wall — the prefill rows dominate it by construction of the
         # chunked scheduler).  Feeds the per-chunk latency quantiles on
-        # /metrics and the prefill-MFU breakdown in bench.py.
+        # /metrics.
         prefill_tokens = sum(
             min(n, len(seq.prompt) - start)
             for seq, start, n in plan.items
@@ -513,35 +506,8 @@ class DecodePipelineMixin:
                     int(sampled[i]),
                     logprobs=self._lp_info(seq, i, logp, top_ids, top_lp),
                 )
-        elif kind == "spec":  # speculative verification (engine/spec.py)
+        else:  # "spec": speculative verification (engine/spec.py)
             self._harvest_spec(entry, sampled, logp, top_ids, top_lp)
-        else:  # burst
-            members, pos0 = entry[2], entry[3]
-            chained = entry[4] if len(entry) > 4 else False
-            finished: List[SequenceState] = []
-            self._accept_chunk(
-                members, pos0, sampled, logp, top_ids, top_lp, finished
-            )
-            if chained:
-                # A chained burst chunk for these rows is still in
-                # flight (_decode_burst's pipelined shape): keep them
-                # parked — freeze_sequence's quiescence poll must see
-                # the in-flight tokens — and defer removals to the
-                # final chunk's harvest, so no member's blocks are
-                # freed while a dispatch that writes them is in flight.
-                for seq in members:
-                    if not seq.finished:
-                        seq.awaiting_fetch = True
-            else:
-                # Sweep by flag, not the local ``finished`` list: a row
-                # that stopped in the FIRST chunk of a chained burst is
-                # skipped by this chunk's accept and must still be
-                # removed here.
-                for seq in members:
-                    if seq.finished and any(
-                        s is seq for s in self.scheduler.running
-                    ):
-                        self.scheduler.remove(seq)
 
     async def _decode_pipeline(self, members: List[SequenceState]) -> bool:
         """Continuous fused decode: multi-step dispatches with the token
@@ -559,8 +525,9 @@ class DecodePipelineMixin:
           ordinary unified steps INTERLEAVED between fused chunks (the
           fused cadence never stops), and once the first token lands they
           join the chain at the next chain-break merge — a drain of
-          in-flight chunks only, never an exit to the scheduler and the
-          mixed-phase single-step regime.
+          in-flight chunks only, never an exit to the scheduler.  Rows the
+          scheduler admitted before the session began, still in their
+          prompts, are hosted the same way (``rejoin_strays``).
         - **Double-buffered dispatch**: the oldest chunk's token fetch runs
           in a worker thread while the admission prefill dispatch, the
           next chunk's host-side planning (slot ensure, table rows) and
@@ -581,20 +548,17 @@ class DecodePipelineMixin:
 
         Exactness: samples depend only on (seed, rng-step, committed
         prefix), and a chain-break merge re-seeds the device carry with
-        exactly the values it already holds — so continuous and
-        drain-rebuild scheduling produce byte-identical streams at any
-        temperature (tests/test_continuous_batching.py gates it, spec
-        on/off; ``_continuous_decode = False`` is the legacy control).
+        exactly the values it already holds — so a request under churn
+        gets the stream it gets when served alone, at any temperature
+        (tests/test_continuous_batching.py gates it, spec on/off).
 
         Invariant: no member's KV blocks are freed while any dispatch that
         writes them is in flight — retirement defers the release to the
-        per-row write barrier (the legacy path deferred ALL finishes to
-        the full drain).
+        per-row write barrier.
         """
         cfg = self.cfg
         bs = cfg.block_size
         S, T = cfg.max_batch, cfg.decode_steps
-        continuous = self._continuous_decode
         # Visible to freeze_sequence (engine/migrate.py) BEFORE the first
         # suspension point; maintained as membership changes below.
         self._pipeline_members = {s.request_id for s in members}
@@ -659,14 +623,12 @@ class DecodePipelineMixin:
             need_lp = bool(samp.need_logprobs)
             carry = None  # next dispatch re-seeds (tok, steps, counts)
 
-        def sweep_retire() -> int:
-            """Retire finished (and, in continuous mode, client-cancelled
-            and migration-frozen) rows: excluded from future dispatches
-            NOW; slot (+ blocks, unless frozen) released once the write
-            barrier passes."""
-            m = 0
+        def sweep_retire() -> None:
+            """Retire finished, client-cancelled and migration-frozen
+            rows: excluded from future dispatches NOW; slot (+ blocks,
+            unless frozen) released once the write barrier passes."""
             for i, seq in slots.active():
-                if continuous and not seq.finished:
+                if not seq.finished:
                     c = self._contexts.get(seq.request_id)
                     if c is not None and c.is_stopped:
                         # In-loop cancellation IS retirement — the stream
@@ -677,10 +639,8 @@ class DecodePipelineMixin:
                     slots.retire(i)
                     pos_disp[i] = -1
                     retired.append((seq, i, chunk_id, True))
-                    if continuous:
-                        self.continuous_retired += 1
-                    m += 1
-                elif continuous and seq.frozen:
+                    self.continuous_retired += 1
+                elif seq.frozen:
                     # Migration freeze: park the row OUT of the session.
                     # Its slot goes None, so any not-yet-harvested chunk
                     # tokens for the row are DROPPED at accept (recomputed
@@ -688,13 +648,10 @@ class DecodePipelineMixin:
                     # snapshot frontier equal to the emitted stream; the
                     # barrier hands quiescence to freeze_sequence via the
                     # _pipeline_members discard — the session keeps fusing
-                    # for everyone else.  Legacy mode drains instead
-                    # (want_rebuild).
+                    # for everyone else.
                     slots.retire(i)
                     pos_disp[i] = -1
                     retired.append((seq, i, chunk_id, False))
-                    m += 1
-            return m
 
         def flush_retired() -> None:
             """Release retirements whose write barrier has passed: every
@@ -709,12 +666,12 @@ class DecodePipelineMixin:
                 slots.free(i)
 
         def rejoin_strays() -> None:
-            """Running decode rows OUTSIDE the session rejoin at the next
-            chain break — a migration rollback's unfreeze is the one way a
-            planned row falls out of membership, and with long-lived
-            continuous sessions it would otherwise starve until the
-            session ends (legacy sessions rebuilt constantly, so schedule()
-            picked such rows up within a few chunks)."""
+            """Running rows OUTSIDE the session come in: a row the
+            scheduler admitted before the session began and whose prompt
+            is still computing goes on prefilling here, and a decode row
+            joins at the next chain break (a migration rollback's
+            unfreeze is how a member falls out of membership; it would
+            otherwise starve until the session ends)."""
             nonlocal rebuild
             known = (
                 slots.num_active
@@ -744,7 +701,7 @@ class DecodePipelineMixin:
                     rebuild = True
                     continue
                 if seq.in_prefill:
-                    prefilling.append(seq)  # froze mid-prefill: resume it
+                    prefilling.append(seq)
                 else:
                     ready.append(seq)
                 self._pipeline_members.add(seq.request_id)
@@ -758,37 +715,15 @@ class DecodePipelineMixin:
                 # A freeze landing in the join window (rare): drain — the
                 # joining row has no slot to park out of.
                 return True
-            if not continuous:
-                # Legacy static membership: ANY change drains the session —
-                # a frozen member (quiescence needs the full drain), an
-                # admissible waiting head, a finish, or a cancellation.
-                # Waiting requests only force a rebuild when one could
-                # actually be ADMITTED (free slot + blocks) — at
-                # oversubscription the queue is never empty, and gating on
-                # num_waiting alone kept the fused pipeline permanently
-                # disabled (round-3 saturation collapse).
-                return (
-                    any(s.frozen for _, s in slots.active())
-                    or
-                    self.scheduler.admission_ready()
-                    or any(s.finished for _, s in slots.active())
-                    or any(
-                        (c := self._contexts.get(s.request_id)) is not None
-                        and c.is_stopped
-                        for _, s in slots.active()
-                    )
-                )
-            # Continuous: only a head the fused loop cannot host (grammar-
-            # constrained — its mask advances host-side per token) still
-            # needs the full scheduler rebuild.
+            # Only a head the fused loop cannot host (grammar-constrained —
+            # its mask advances host-side per token) still needs the full
+            # scheduler rebuild.
             return (
                 self.scheduler.admission_ready()
                 and not self.scheduler.waiting_head_compatible()
             )
 
         def admit() -> None:
-            if not continuous or rebuild:
-                return
             room = slots.capacity_left - len(prefilling) - len(ready)
             if room <= 0 or not self.scheduler.admission_ready():
                 return
@@ -934,10 +869,9 @@ class DecodePipelineMixin:
 
         while True:
             iter_chunk0 = chunk_id
-            if sweep_retire() and not continuous:
-                rebuild = True
+            sweep_retire()
             flush_retired()
-            if continuous and not rebuild:
+            if not rebuild:
                 rejoin_strays()
             if want_rebuild():
                 rebuild = True
@@ -1038,7 +972,7 @@ class DecodePipelineMixin:
                 )
                 with TraceAnnotation("engine.emit"):
                     self._accept_chunk(
-                        slots.rows, pos0_c, sampled, logp, top_ids, top_lp, []
+                        slots.rows, pos0_c, sampled, logp, top_ids, top_lp
                     )
                 harvested = cid
                 if not rebuild and self._spec_session_probe(
@@ -1075,147 +1009,6 @@ class DecodePipelineMixin:
         if rebuild:
             self.pipeline_rebuilds += 1
         return dispatched_any
-
-    async def _decode_burst(self, members: List[SequenceState]) -> bool:
-        """Fused multi-step dispatch(es) for ``members`` (all decoding),
-        used in mixed phases where prefill rows keep the full pipeline from
-        engaging.  Pipelined shape (ISSUE 11): when KV headroom covers TWO
-        chunks and some row can still use the second, a second dispatch is
-        CHAINED off the first's on-device token carry — two in-flight
-        chunks (2 × decode_steps tokens per row) for the same host-side
-        planning cost, matching the full pipeline's double-buffered shape.
-        Same discard semantics as the pipeline: tokens past a row's
-        stop/limit are dropped host-side.  Returns False (dispatching
-        nothing) when KV headroom for even one full burst is missing."""
-        cfg = self.cfg
-        bs = cfg.block_size
-        S, T = cfg.max_batch, cfg.decode_steps
-        n = len(members)
-        tok0 = np.zeros((S,), np.int32)
-        pos0 = np.full((S,), -1, np.int32)
-        tables = np.zeros((S, cfg.max_blocks_per_seq), np.int32)
-        limits = np.zeros((S,), np.int32)
-        chain = True  # headroom for a second chained chunk on every row?
-        for i, seq in enumerate(members):
-            if seq.finished or seq.frozen:
-                return False  # membership changed under us: replan
-            if seq.grammar is not None:
-                # Constrained rows never burst: their mask advances
-                # host-side per accepted token (callers route them to
-                # unified steps — this is the safety net).
-                return False
-            if not self.scheduler._ensure_slot(seq, lookahead=T):
-                return False
-            # Second-chunk headroom is best-effort: blocks the 2T ensure
-            # allocates stay with the row either way (used by later steps).
-            if chain and not self.scheduler._ensure_slot(seq, lookahead=2 * T):
-                chain = False
-            all_toks = seq.prompt + seq.output
-            tok0[i] = all_toks[seq.num_computed]
-            pos0[i] = seq.num_computed
-            self._tables_row(tables, i, seq)
-            limits[i] = min(
-                len(seq.block_ids) * bs, cfg.max_blocks_per_seq * bs
-            )
-        # A second chunk no row can still use is pure waste (all its tokens
-        # would be discarded host-side): chain only when some member's
-        # budget reaches past the first chunk's frontier.
-        if chain:
-            chain = self._any_useful_rows(
-                members, np.where(pos0 >= 0, pos0 + T, pos0)
-            )
-        # Park BEFORE the first suspension point (see _run_unified):
-        # quiescence pollers must count the burst's in-flight tokens from
-        # the moment this coroutine can yield, not from when the dispatch
-        # returns.
-        for seq in members:
-            seq.awaiting_fetch = True
-        while self._pending_fetches and self._pending_fetches[0][1].done():
-            await self._harvest_pending()  # free: task already complete
-        samp = self._sampling_arrays(members)
-        need_lp = bool(samp.need_logprobs)
-        samp_np = (
-            jax.tree_util.tree_map(np.asarray, samp)
-            if self._publisher is not None
-            else None
-        )
-        c_tok, c_steps = tok0, samp.steps
-        if self._rep_sharding is not None:
-            c_tok, c_steps = self._prep((c_tok, c_steps))
-            d_args = self._prep((pos0, tables, limits, samp))
-        else:
-            d_args = (pos0, tables, limits, samp)
-        if self._count_dispatch:
-            self._count_dispatch("decode_burst", pos0, np.where(pos0 >= 0, T, 0))
-        multi = self._multi_fn
-
-        def run():
-            with TraceAnnotation("engine.dispatch:burst"):
-                outs, last, steps_f, counts_f, self.cache = multi(
-                    self.params, self.cache, c_tok, c_steps, samp.counts,
-                    *d_args
-                )
-                # Async D2H + deferred accept: the burst's tokens are only
-                # needed at the next harvest point (its rows are parked),
-                # so the round trip overlaps the following prefill chunks
-                # instead of stalling behind the device queue.
-                self._start_d2h(outs, need_lp)
-            return outs, (last, steps_f, counts_f)
-
-        await self._pace()
-        t0 = time.perf_counter()
-        async with self._device_lock:
-            if self._publisher is not None:
-                await self._publisher.publish(
-                    "multi",
-                    (tok0, pos0, tables.copy(), limits, samp_np),
-                )
-            outs, carry = await self._await_device(
-                self._device_task(run), "burst_dispatch", n
-            )
-        t1 = time.perf_counter()
-        self.step_trace.append(("decode_burst", t1 - t0, n, n * T))
-        self._trace_decode_chunk(enumerate(members), t0, t1, T)
-        self._stash_fetch("burst", outs, need_lp, members, pos0, chain)
-        if not chain:
-            return True
-
-        # Chained second chunk: the carry (token, rng step, penalty counts)
-        # stays ON DEVICE — warmup pre-compiles this exact device-carry
-        # variant, so no new program is reachable here.
-        pos0b = np.where(pos0 >= 0, pos0 + T, pos0)
-        if self._rep_sharding is not None:
-            d_args_b = self._prep((pos0b, tables, limits, samp))
-        else:
-            d_args_b = (pos0b, tables, limits, samp)
-        if self._count_dispatch:
-            self._count_dispatch("decode_burst", pos0b, np.where(pos0b >= 0, T, 0))
-
-        def run_b():
-            with TraceAnnotation("engine.dispatch:burst"):
-                outs, last, steps_f, counts_f, self.cache = multi(
-                    self.params, self.cache, *carry, *d_args_b
-                )
-                self._start_d2h(outs, need_lp)
-            return outs
-
-        await self._pace()
-        t0 = time.perf_counter()
-        async with self._device_lock:
-            if self._publisher is not None:
-                # tok None → follower chains its own mirror carry.
-                await self._publisher.publish(
-                    "multi",
-                    (None, pos0b, tables.copy(), limits, samp_np),
-                )
-            outs_b = await self._await_device(
-                self._device_task(run_b), "burst_dispatch", n
-            )
-        t1 = time.perf_counter()
-        self.step_trace.append(("decode_burst", t1 - t0, n, n * T))
-        self._trace_decode_chunk(enumerate(members), t0, t1, T)
-        self._stash_fetch("burst", outs_b, need_lp, members, pos0b, False)
-        return True
 
     def _any_useful_rows(
         self, members: List[Optional[SequenceState]], pos_disp: np.ndarray
@@ -1256,7 +1049,6 @@ class DecodePipelineMixin:
         logp,
         top_ids,
         top_lp,
-        finished: List[SequenceState],
     ) -> None:
         """Apply one fused chunk's sampled tokens to ``members``.
 
@@ -1281,7 +1073,7 @@ class DecodePipelineMixin:
                 continue  # stopped/hit the allocation wall in a prior chunk
             if not self._vectorized_accept or seq.logprobs is not None:
                 self._accept_chunk_row_scalar(
-                    seq, i, p0, sampled, logp, top_ids, top_lp, finished
+                    seq, i, p0, sampled, logp, top_ids, top_lp
                 )
                 continue
             n_cap = min(T, len(seq.block_ids) * bs - p0)
@@ -1337,7 +1129,6 @@ class DecodePipelineMixin:
                 queue.put_nowait(LLMEngineOutput.tokens(emit))
             if reason is not None:
                 seq.finished = True
-                finished.append(seq)
                 self._finish(seq, reason)
 
     def _accept_chunk_row_scalar(
@@ -1349,7 +1140,6 @@ class DecodePipelineMixin:
         logp,
         top_ids,
         top_lp,
-        finished: List[SequenceState],
     ) -> None:
         """Reference per-token accept loop for one row (logprob payloads
         are per token; also the oracle the vectorized path is tested
@@ -1378,7 +1168,6 @@ class DecodePipelineMixin:
                 ),
             )
             if seq.finished:
-                finished.append(seq)
                 break
 
     def _lp_info(

@@ -500,13 +500,14 @@ class StepPlan:
     """One unified device step: per-row (state, start, n_tokens).
 
     Decode rows have n_tokens == 1; prefill rows carry their next prompt
-    chunk.  ``pure_decode`` marks a steady state (every running sequence is
-    decoding, nothing waiting) where the engine can switch to the fused
-    multi-step decode pipeline instead of single unified steps.
+    chunk.  ``session`` says how the engine loop runs the plan: True, as a
+    fused decode session (``_decode_pipeline``: the plan's decode rows are
+    its first members, its prompts prefill inside it); False, as this one
+    unified step.
     """
 
     items: List[Tuple[SequenceState, int, int]]
-    pure_decode: bool = False
+    session: bool = False
 
 
 class Scheduler:
@@ -521,13 +522,6 @@ class Scheduler:
         self.running: List[SequenceState] = []
         self.rejected: List[SequenceState] = []  # can never fit; engine fails them
         self.preempted = 0  # cumulative, for metrics
-        # Cumulative mid-prefill requeues (preemption of a sequence whose
-        # prompt was only partially computed).  The engine compares this
-        # against its last-seen value each scheduling pass and resets the
-        # mixed-phase chunk cadence (_chunks_since_burst): the requeued
-        # sequence restarts chunking from zero, so a stale count would
-        # skew the first decode burst after re-admission.
-        self.prefill_requeues = 0
         # Queue->admission latencies (s), bounded; loadgen reads per level.
         self.admission_waits: Deque[float] = deque(maxlen=16384)
 
@@ -641,6 +635,7 @@ class Scheduler:
             # (config.max_step_tokens), so a full decode batch must never
             # starve prompt chunks — with max_batch > prefill_chunk it
             # would permanently block admission at saturation.
+        n_decode = len(items)
 
         # Prefill continuations (chunked prefill of already-running prompts).
         for seq in self.running:
@@ -651,17 +646,12 @@ class Scheduler:
                 items.append((seq, seq.num_computed, chunk))
                 budget -= chunk
 
-        # Admit newcomers while slots + blocks + budget allow.  Track
-        # whether the waiting head is BLOCKED (slots/blocks full): waiting
-        # requests that cannot land must not hold the fused decode pipeline
-        # off — that inverts throughput exactly at saturation (conc 32 below
-        # conc 16 in round 3), when the queue is never empty.
-        admission_blocked = (
-            bool(self.waiting) and len(self.running) >= self.cfg.max_batch
-        )
+        # Admit newcomers while slots + blocks + budget allow.  A head that
+        # cannot land (slots or blocks full, frozen mid-migration) stops
+        # admission for this pass and nothing else: what runs the plan is
+        # decided below from what the plan holds.
         while budget > 0 and self.waiting and len(items) < self.cfg.max_batch:
             if len(self.running) >= self.cfg.max_batch:
-                admission_blocked = True
                 break
             seq = self.waiting[0]
             if seq.frozen:
@@ -669,7 +659,6 @@ class Scheduler:
                 # admitted and recomputed — a sampled token the snapshot
                 # lacks would reach the client twice after the splice.
                 # Freezes are sub-second; treat the head as blocked.
-                admission_blocked = True
                 break
             if not self._try_admit(seq):
                 own_pins = len(seq.pin_ids or [])
@@ -689,7 +678,6 @@ class Scheduler:
                     self._release_pin(seq)
                     self.rejected.append(seq)
                     continue
-                admission_blocked = True
                 break
             self.waiting.popleft()
             self.running.append(seq)
@@ -702,29 +690,28 @@ class Scheduler:
 
         if not items:
             return None
-        pure = (
-            (not self.waiting or admission_blocked)
-            and all(n == 1 for _, _, n in items)
-            and not any(
-                s.in_prefill and not s.frozen for s in self.running
-            )
-            # Grammar-constrained rows bar the fused multi-step programs:
-            # their token mask advances host-side per accepted token, and a
-            # fused chunk feeds sampled tokens forward ON DEVICE.  The
-            # engine's mixed-phase path still bursts the unconstrained rows
-            # (engine.py _run_loop).
+        # A plan with a decode row runs as a fused session, which hosts the
+        # plan's prompts and the waiting queue itself (rejoin_strays and
+        # admit in engine/pipeline.py).  A resident grammar-constrained row
+        # bars it: its token mask advances host-side per accepted token,
+        # and a fused chunk feeds sampled tokens forward ON DEVICE, so
+        # every row then takes one token a unified step.
+        session = (
+            self.cfg.decode_steps > 1
+            and n_decode > 0
             and not any(
                 s.grammar is not None and not s.finished and not s.frozen
                 for s in self.running
             )
         )
-        return StepPlan(items, pure_decode=pure)
+        return StepPlan(items, session=session)
 
     def admission_ready(self) -> bool:
         """Non-destructive check: would the waiting head admit right now?
         The fused decode pipeline polls this between chunks — it keeps
-        fusing while admission is impossible (slots/blocks full) and drains
-        for a rebuild the moment a newcomer could actually land."""
+        fusing while admission is impossible (slots/blocks full) and admits
+        the head in-loop the moment it could actually land (or drains, for
+        a head it cannot host: ``waiting_head_compatible``)."""
         if not self.waiting:
             return False
         if len(self.running) >= self.cfg.max_batch:
@@ -855,11 +842,6 @@ class Scheduler:
         self.running.remove(seq)
         self.kv.free_sequence(seq.block_ids)
         seq.block_ids = []
-        # Mid-prefill must be detected BEFORE the fold below: folding sets
-        # num_computed = 0, after which EVERY preempted sequence looks
-        # mid-prefill.
-        if seq.in_prefill:
-            self.prefill_requeues += 1
         # Fold generated tokens into the prompt so recompute resumes exactly.
         seq.prompt = seq.prompt + seq.output
         seq.output = []

@@ -229,20 +229,20 @@ class SpecDecodeMixin:
         """Engagement bar vs the fused pipeline: a verification step
         streams the weights once where a fused chunk streams them
         ``decode_steps`` times, so speculation wins well below raw
-        tokens-per-round-trip parity (pipeline_margin)."""
+        tokens-per-round-trip parity (pipeline_margin).  Both callers
+        ask only where a session is the alternative (decode_steps > 1)."""
         cfg = self.cfg
-        if cfg.decode_steps <= 1:
-            return True
         bar = cfg.spec_decode.pipeline_margin * n_decode * cfg.decode_steps
         return expected >= bar
 
     def _spec_propose(self, plan: StepPlan) -> Dict[str, List[int]]:
         """Drafts for this plan's decode rows: {request_id: tokens}.
 
-        Each draft token is one extra row of the unified step; for
-        pure-decode plans speculation must also beat the fused pipeline
-        (_spec_engaged), else stand down — the adaptive controller keeps
-        dead proposers from dragging live batches."""
+        Each draft token is one extra row of the unified step; where the
+        plan would otherwise run as a fused session (``plan.session``)
+        speculation must also beat the fused pipeline (_spec_engaged), else
+        stand down — the adaptive controller keeps dead proposers from
+        dragging live batches."""
         cfg = self.cfg
         decode_items = [
             (seq, start)
@@ -257,7 +257,7 @@ class SpecDecodeMixin:
         cands = self._spec_collect(decode_items, rows_free)
         if not cands:
             return {}
-        if plan.pure_decode:
+        if plan.session:
             # Engagement BEFORE allocation: standing down must not have
             # paid _ensure_slot evictions (which can LRU-evict sealed
             # prefix-cache blocks) for drafts that never run.

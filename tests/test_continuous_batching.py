@@ -3,9 +3,10 @@
 The load-bearing property is EXACT-STREAM EQUIVALENCE: in-loop
 admission/retirement is a SCHEDULING change, never a token change — the
 seeded sampler keys on (seed, output-index) over the committed prefix, so
-the continuous pipeline and the legacy drain-on-any-change control
-(``_continuous_decode = False``) must produce byte-identical streams at
-any temperature, spec on or off.  Also covered: migration freeze
+a request under churn must get, byte for byte, the stream it gets when a
+fresh engine serves the same seeded requests one at a time (the serial
+reference: no session ever holds two rows, speculation off), at any
+temperature, spec on or off.  Also covered: migration freeze
 quiescence while the session keeps fusing for other rows (the
 ``_pipeline_members`` accounting under dynamic membership), the
 zero-new-compiles gate (in-loop admission reaches no program warmup did
@@ -18,6 +19,8 @@ of which engine computed them (same config/seed ⇒ same weights).
 """
 
 import asyncio
+import functools
+import os
 
 import pytest
 
@@ -61,6 +64,13 @@ def _prompt(i, n=12):
     return [(i * 7919 + j * 104729) % 251 + 1 for j in range(n)]
 
 
+async def _gen(engine, i, n, osl, temperature):
+    """Request ``i`` with an ``n``-token prompt: its token stream."""
+    req = _req(_prompt(i, n), max_tokens=osl, seed=i + 1, temperature=temperature)
+    items = await collect(await engine.generate(Context(req)))
+    return [t for it in items for t in it["token_ids"]]
+
+
 async def _one(engine, i, osl, temperature, late=False):
     if late:
         # Land INSIDE a live fused session: the whole point of the churn
@@ -69,57 +79,74 @@ async def _one(engine, i, osl, temperature, late=False):
             if engine._pipeline_members:
                 break
             await asyncio.sleep(0.002)
-    req = _req(_prompt(i), max_tokens=osl, seed=i + 1, temperature=temperature)
-    items = await collect(await engine.generate(Context(req)))
-    return [t for it in items for t in it["token_ids"]]
+    return await _gen(engine, i, 12, osl, temperature)
+
+
+def _late(i, n):
+    return i >= (n + 1) // 2
+
+
+def _osl(i, n):
+    return (5 + 3 * (i % 3)) if _late(i, n) else (24 + 8 * (i % 2))
 
 
 async def _churn(engine, temperature, n=8):
     """Staggered finishes + late arrivals: first wave keeps the session
     alive while short rows retire; back half arrives mid-session."""
-    jobs = []
-    for i in range(n):
-        late = i >= (n + 1) // 2
-        osl = (24 + 8 * (i % 2)) if not late else (5 + 3 * (i % 3))
-        jobs.append(_one(engine, i, osl, temperature, late=late))
-    return await asyncio.gather(*jobs)
+    return await asyncio.gather(*[
+        _one(engine, i, _osl(i, n), temperature, late=_late(i, n))
+        for i in range(n)
+    ])
+
+
+@functools.lru_cache(maxsize=None)
+def _serial(reqs, temperature, over=()):
+    """The control: seeded requests ``(i, prompt tokens, max_tokens)``, one
+    at a time, on a fresh engine without speculation (``over``: items of
+    EngineConfig overrides).  Cached: the tests of this file run in one
+    process and share a reference a temperature."""
+
+    async def main():
+        engine = TpuEngine(EngineConfig(**dict(CFG, **dict(over))))
+        try:
+            return [await _gen(engine, *r, temperature) for r in reqs]
+        finally:
+            await engine.close()
+
+    return asyncio.run(main())
+
+
+CHURN = tuple((i, 12, _osl(i, 8)) for i in range(8))
 
 
 def _run_modes(temperature, spec=None):
-    """Same churn trace on a continuous engine and a forced-rebuild
-    control; returns (streams_on, streams_off, engine_stats)."""
+    """The churn trace on one engine against the serial reference; returns
+    (churn_streams, serial_streams, engine_stats)."""
 
-    results = {}
-
-    async def mode(continuous: bool):
+    async def churn():
         cfg = dict(CFG)
         if spec is not None:
             cfg["spec_decode"] = spec
         engine = TpuEngine(EngineConfig(**cfg))
-        engine._continuous_decode = continuous
         try:
             streams = await _churn(engine, temperature)
-            results[continuous] = (
-                streams,
-                {
-                    "rebuilds": engine.pipeline_rebuilds,
-                    "admissions": engine.continuous_admissions,
-                    "retired": engine.continuous_retired,
-                    "prompt_steps": dict(engine.prompt_step_order),
-                },
-            )
+            return streams, {
+                "rebuilds": engine.pipeline_rebuilds,
+                "admissions": engine.continuous_admissions,
+                "retired": engine.continuous_retired,
+                "prompt_steps": dict(engine.prompt_step_order),
+            }
         finally:
             await engine.close()
 
-    for continuous in (True, False):
-        asyncio.run(mode(continuous))
-    return results[True][0], results[False][0], results[True][1]
+    streams, stats = asyncio.run(churn())
+    return streams, _serial(CHURN, temperature), stats
 
 
-def test_continuous_vs_rebuild_exact_streams_seeded_temp09():
+def test_churn_vs_serial_exact_streams_seeded_temp09():
     """Mid-pipeline retirement + admission at temperature 0.9 with seeds:
-    byte-identical streams vs the forced-rebuild control, and the
-    continuous engine actually exercised the in-loop paths."""
+    byte-identical streams vs the serial reference, and the engine
+    actually exercised the in-loop paths."""
     on, off, stats = _run_modes(temperature=0.9)
     assert on == off, "continuous batching changed seeded streams"
     assert stats["admissions"] >= 1, stats
@@ -133,7 +160,7 @@ def test_exact_streams_seeded_rows_join_ahead_of_the_top_up(spec):
     """The byte-identity gate under the iteration's order of ISSUE 29:
     seeded temperature-0.8 rows join mid-session, their prompt steps are
     enqueued AHEAD of the iteration's top-up chunk (never behind one), and
-    not a token differs from the drain-rebuild control, speculation on and
+    not a token differs from the serial reference, speculation on and
     off."""
     on, off, stats = _run_modes(temperature=0.8, spec=spec)
     assert on == off, "the prompt step's place in the iteration changed a stream"
@@ -142,12 +169,255 @@ def test_exact_streams_seeded_rows_join_ahead_of_the_top_up(spec):
     assert stats["prompt_steps"]["behind"] == 0, stats
 
 
-def test_continuous_vs_rebuild_exact_streams_greedy_spec_on():
+def test_churn_vs_serial_exact_streams_greedy_spec_on():
     """Greedy + speculative decoding enabled: spec-session probes and
     in-loop membership changes compose without changing a single token."""
     on, off, stats = _run_modes(temperature=0.0, spec={"enable": True, "k": 4})
     assert on == off, "continuous batching changed greedy/spec streams"
     assert stats["retired"] >= 1, stats
+
+
+# ------------------------------------------- a mixed plan runs as a session
+
+
+def _lockstep(engine):
+    """Hold every device op until the token fetches issued before it have
+    landed: a round trip is then one step long whatever the machine (on
+    the CPU the host enqueues a dozen steps in the time one fetch takes),
+    so which step first sees a row's token is a count, not a race."""
+
+    async def land_fetches():
+        tasks = [entry[1] for entry in engine._pending_fetches]
+        if tasks:
+            await asyncio.wait(tasks)
+
+    engine.pace_hook = land_fetches
+
+
+async def _all_at_once(engine, reqs, temperature):
+    """Prompts of unlike length into an idle engine: the short one is
+    decoding while the long ones still have chunks to compute."""
+    return await asyncio.gather(*[_gen(engine, *r, temperature) for r in reqs])
+
+
+async def _after_kv_drain(engine, reqs, temperature):
+    """Two rows decode in one session; then every free block is taken
+    hostage, so the next fused chunk finds no KV headroom and the session
+    drains.  Unified steps carry the rows until one cannot get a slot for
+    a single token and ``schedule()`` preempts the younger; the hostages
+    come back at that preemption (another tenant's blocks freed), so the
+    same ``schedule()`` call re-admits the victim: the plan after the
+    drain holds a decode row AND a prompt."""
+    sched, kv = engine.scheduler, engine.kv
+    hostages = []
+    preempt = sched._preempt
+
+    def preempt_then_release(seq):
+        preempt(seq)
+        kv.free_sequence(hostages)
+        del hostages[:]
+
+    sched._preempt = preempt_then_release
+    tasks = [asyncio.create_task(_gen(engine, *r, temperature)) for r in reqs]
+    for _ in range(4000):
+        if len(engine._pipeline_members) == len(reqs) and all(
+            s.num_output_tokens >= 2 for s in sched.running
+        ):
+            break
+        await asyncio.sleep(0.002)
+    assert len(engine._pipeline_members) == len(reqs), "no shared session"
+    while (bid := kv.allocate_block()) is not None:
+        hostages.append(bid)
+    streams = [await t for t in tasks]
+    assert sched.preempted >= 1 and not hostages, "the pool never ran dry"
+    assert engine.pipeline_rebuilds >= 1
+    return streams
+
+
+async def _waiting_at_session_start(engine, reqs, temperature):
+    """Four requests at once into an idle engine: one prompt chunk of
+    budget a step leaves the later ones WAITING, and admissible, when the
+    first decode row appears and the session starts."""
+    streams = await _all_at_once(engine, reqs, temperature)
+    assert engine.continuous_admissions >= 1, "nobody waited at session start"
+    return streams
+
+
+# name -> (driver, requests (i, prompt tokens, max_tokens), EngineConfig
+# overrides, most sessions a run may take)
+MIXED_PLANS = {
+    "unlike-prompts-into-an-idle-engine": (
+        _all_at_once, ((0, 5, 40), (1, 150, 6)), {}, 2,
+    ),
+    # Between the drain and the preemption each plan is tried as a session
+    # that finds no headroom and gives up, once a token until a row needs a
+    # block: a few, never one a token of the run.
+    "after-a-kv-exhaustion-drain": (
+        _after_kv_drain, ((0, 12, 56), (1, 12, 56)), {"num_blocks": 64}, 8,
+    ),
+    "waiting-queue-admissible-at-session-start": (
+        _waiting_at_session_start,
+        ((0, 5, 40), (1, 40, 8), (2, 40, 8), (3, 40, 8)), {}, 2,
+    ),
+}
+
+
+@pytest.mark.parametrize(
+    "temperature,spec",
+    [(0.9, None), (0.0, {"enable": True, "k": 4})],
+    ids=["seeded-temp09", "greedy-spec-on"],
+)
+@pytest.mark.parametrize("name", list(MIXED_PLANS))
+def test_mixed_plan_runs_as_a_session(name, temperature, spec):
+    """A plan that holds a decode row AND a prompt runs as ONE fused
+    session that hosts the prompt (``rejoin_strays`` / ``admit``), however
+    the plan came about; no other cadence exists.  Streams equal the serial
+    reference, and nothing compiles after ``warmup()``."""
+    driver, reqs, over, most_sessions = MIXED_PLANS[name]
+
+    async def main():
+        cfg = dict(CFG, **over)
+        if spec is not None:
+            cfg["spec_decode"] = spec
+        engine = TpuEngine(EngineConfig(**cfg))
+        try:
+            compiled = await asyncio.to_thread(engine.warmup)
+            _lockstep(engine)
+            streams = await driver(engine, reqs, temperature)
+            assert engine.compile_counts() == compiled
+            return streams, engine.dispatch_summary()["pipeline"], {
+                k for k, *_ in engine.step_trace
+            }
+        finally:
+            await engine.close()
+
+    streams, pipe, kinds = asyncio.run(main())
+    assert list(streams) == _serial(reqs, temperature, tuple(over.items()))
+    hosted = pipe["continuous_admissions"] + sum(pipe["prompt_step"].values())
+    assert hosted >= 1, f"no session hosted a prompt: {pipe}"
+    assert 1 <= pipe["sessions"] <= most_sessions, pipe
+    assert "decode_dispatch" in kinds, kinds
+    assert not any("burst" in k for k in kinds), kinds
+
+
+def test_grammar_row_keeps_plans_on_unified_steps():
+    """While a grammar-constrained row is resident no plan runs as a
+    session (a fused chunk feeds sampled tokens forward on the device; the
+    row's mask advances on the host): EVERY resident row then takes one
+    token a round trip through unified steps, beside whatever prompt is
+    prefilling.  In lockstep a row sits out the one step its token is in
+    flight for, so the plain row ends its 4 tokens within a dozen steps,
+    long before the long prompt's 25 chunks are through: by the count of
+    steps, not by the clock."""
+    from dynamo_tpu.llm.tenancy.grammar import GrammarCompiler
+    from dynamo_tpu.llm.tokenizer import ByteTokenizer
+
+    grammar = GrammarCompiler(ByteTokenizer()).compile(
+        {"kind": "regex", "pattern": "[a-z]{48}"}
+    ).to_dict()
+
+    async def main():
+        engine = TpuEngine(EngineConfig(**dict(CFG, prefill_chunk=8)))
+        try:
+            plain_alone = await _gen(engine, 1, 12, 4, 0.9)
+            await _session(engine, live=False)
+            engine.step_trace.clear()
+            sessions0 = engine.pipeline_sessions
+            _lockstep(engine)
+            order = []
+
+            async def constrained():
+                req = PreprocessedRequest(
+                    token_ids=_prompt(0),
+                    stop_conditions=StopConditions(max_tokens=64),
+                    sampling_options=SamplingOptions(temperature=0.9, seed=5),
+                    grammar=grammar,
+                ).to_dict()
+                items = await collect(await engine.generate(Context(req)))
+                order.append("grammar-done")
+                return [t for it in items for t in it["token_ids"]]
+
+            async def plain():
+                toks = await _gen(engine, 1, 12, 4, 0.9)
+                order.append("plain-done")
+                return toks
+
+            async def long_prompt():
+                req = _req(_prompt(2, 200), max_tokens=4, seed=3)
+                stream = await engine.generate(Context(req))
+                async for _ in stream:
+                    if "long-first-token" not in order:
+                        order.append("long-first-token")
+
+            g, p, _ = await asyncio.gather(constrained(), plain(), long_prompt())
+            kinds = {k for k, *_ in engine.step_trace}
+            return g, p, plain_alone, order, kinds, engine.pipeline_sessions - sessions0
+        finally:
+            await engine.close()
+
+    g, p, plain_alone, order, kinds, sessions = asyncio.run(main())
+    assert len(g) == 48 and all(ord("a") <= t <= ord("z") for t in g), g
+    assert p == plain_alone
+    assert order == ["plain-done", "long-first-token", "grammar-done"], order
+    # The constrained row outlives both others: no plan of this run could
+    # be a session.
+    assert sessions == 0 and "decode_dispatch" not in kinds, (sessions, kinds)
+
+
+def test_spec_engagement_bar_applies_whenever_a_session_is_the_alternative():
+    """``_spec_propose`` holds drafts to the fused pipeline's bar exactly
+    when the plan would otherwise run as a session — with a prompt in the
+    plan too — and not where the alternative is a unified step (a grammar
+    row resident).  The bar is out of reach here (margin 4: 16 tokens a
+    round trip from one row with at most 4 drafts)."""
+    from dynamo_tpu.llm.metrics import spec_metrics
+
+    async def main():
+        cfg = dict(CFG, spec_decode={"enable": True, "k": 4, "pipeline_margin": 4.0})
+        engine = TpuEngine(EngineConfig(**cfg))
+        try:
+            sched, kv = engine.scheduler, engine.kv
+
+            def mk(rid, prompt, output=()):
+                seq = SequenceState(
+                    request_id=rid,
+                    prompt=list(prompt),
+                    block_seq=TokenBlockSequence(block_size=cfg["block_size"]),
+                )
+                seq.output = list(output)
+                return seq
+
+            # A decoding row whose history repeats: the n-gram proposer
+            # drafts its continuation.
+            row = mk("row", [1, 2, 3] * 4, output=[1])
+            row.num_computed = len(row.prompt)
+            row.block_ids = [kv.allocate_block() for _ in range(5)]
+            sched.running.append(row)
+            sched.add(mk("prompt", range(10, 30)))
+
+            plan = sched.schedule()
+            assert sorted(n for _, _, n in plan.items) == [1, 16]
+            assert plan.session  # a decode row and a prompt: a session's plan
+            fallbacks = spec_metrics.fallback_total
+            assert engine._spec_propose(plan) == {}
+            assert spec_metrics.fallback_total == fallbacks + 1
+
+            # The same rows beside a resident grammar row: a unified step
+            # is all the plan can be, so any draft is worth its rows.
+            constrained = mk("constrained", [4, 5, 6, 7], output=[8])
+            constrained.num_computed = 4
+            constrained.grammar = object()
+            constrained.block_ids = [kv.allocate_block() for _ in range(2)]
+            sched.running.append(constrained)
+            plan = sched.schedule()
+            assert not plan.session
+            drafts = engine._spec_propose(plan)
+            assert drafts.get("row"), drafts
+            assert spec_metrics.fallback_total == fallbacks + 1
+        finally:
+            await engine.close()
+
+    asyncio.run(main())
 
 
 def test_freeze_quiesces_continuous_pipeline_and_resumes_exact():
@@ -390,8 +660,7 @@ def test_first_token_is_applied_when_it_lands_not_an_iteration_later():
 def test_zero_new_compiles_in_loop_admission():
     """Warmup covers every program the continuous pipeline can reach: a
     churn trace with in-loop admission/retirement (chain-break merges,
-    interleaved prefill steps, chained bursts) must not add a single jit
-    cache entry."""
+    interleaved prefill steps) must not add a single jit cache entry."""
 
     async def main():
         engine = TpuEngine(EngineConfig(**CFG))
@@ -413,9 +682,9 @@ def test_zero_new_compiles_in_loop_admission():
 
 def test_dispatch_metrics_exported():
     """engine.dispatch_summary → engine_dispatch_metrics: the pipeline
-    health the planner/bench read off /metrics instead of parsing bench
-    stdout — per-kind counts/percentiles plus the continuous-batching
-    session counters and host-gap fraction."""
+    health the planner and the benchmark read off /metrics — per-kind
+    counts/percentiles plus the continuous-batching session counters and
+    host-gap fraction."""
     from dynamo_tpu.llm.metrics import engine_dispatch_metrics
 
     async def main():
@@ -426,6 +695,11 @@ def test_dispatch_metrics_exported():
             s = engine.dispatch_summary()
             assert s["pipeline"]["sessions"] >= 1
             assert 0.0 <= s["pipeline"]["host_gap_frac"] <= 1.0
+            # tools/ci.sh runs this file under DYN_DECODE_KERNEL=pallas_fused:
+            # the kernel that was asked for is the one that served the churn.
+            asked = os.environ.get("DYN_DECODE_KERNEL", "").strip()
+            if asked and asked != "auto":
+                assert s["decode_kernel"] == asked, s["decode_kernel"]
             assert "decode_dispatch" in s["kinds"]
             text = engine_dispatch_metrics.render()
             assert (

@@ -304,11 +304,12 @@ def test_scheduler_never_preempts_already_scheduled_rows():
     assert b in sched.waiting and sched.preempted == 1
 
 
-def test_scheduler_pure_decode_with_blocked_waiting():
-    """VERDICT r3 weak #1: a waiting request that CANNOT be admitted (slots
-    full) must not disable the fused decode path — at oversubscription the
-    queue is never empty, and gating pure_decode on it collapsed throughput
-    (conc 32 below conc 16)."""
+def test_scheduler_session_plan_whatever_waits():
+    """A plan with a decode row runs as a fused session whether the
+    waiting queue is empty, blocked (slots full: at oversubscription it is
+    never empty, and gating the fused path on it collapsed throughput,
+    conc 32 below conc 16) or admissible (the session hosts the newcomer's
+    prompt itself)."""
     from dynamo_tpu.engine.scheduler import Scheduler, SequenceState
     from dynamo_tpu.tokens import TokenBlockSequence
 
@@ -345,15 +346,22 @@ def test_scheduler_pure_decode_with_blocked_waiting():
 
     plan = sched.schedule()
     assert plan is not None
-    assert plan.pure_decode, "blocked waiting must not break pure decode"
+    assert plan.session, "blocked waiting must not break the fused path"
     assert not sched.admission_ready()
 
-    # A slot frees up → admission becomes possible → pipeline must rebuild.
+    # A slot frees up → the newcomer's prompt chunk is in the plan, beside
+    # the decode row: still a session's plan.
     sched.remove(sched.running[0])
     assert sched.admission_ready()
     plan2 = sched.schedule()
-    assert not plan2.pure_decode  # newcomer's prefill chunk is in the plan
+    assert sorted(n for _, _, n in plan2.items) == [1, 3]
+    assert plan2.session
     assert waiter in sched.running
+
+    # Prompts only (the last decode row gone): one unified step.
+    sched.remove(sched.running[0])
+    plan3 = sched.schedule()
+    assert [n for _, _, n in plan3.items] == [3] and not plan3.session
 
 
 def test_engine_fused_decode_engages_at_oversubscription():
@@ -386,7 +394,7 @@ def test_engine_fused_decode_engages_at_oversubscription():
 
 def test_scheduler_decode_rows_do_not_consume_prefill_budget():
     """Review r4: with max_batch > prefill_chunk, a full decode batch must
-    neither disable pure_decode nor starve admission — decode rows ride the
+    neither disable the fused path nor starve admission — decode rows ride the
     unified step's own capacity (max_step_tokens = prefill_chunk +
     max_batch), they don't spend the prompt-chunk budget."""
     from dynamo_tpu.engine.scheduler import Scheduler, SequenceState
@@ -430,7 +438,7 @@ def test_scheduler_decode_rows_do_not_consume_prefill_budget():
     assert waiter in sched.running
     kinds = sorted(n for _, _, n in plan.items)
     assert kinds == [1, 1, 1, 1, 1, 1, 3]
-    assert not plan.pure_decode
+    assert plan.session
 
     # With all slots decoding and one waiting, the batch must stay fused.
     sched.waiting.clear()
@@ -441,24 +449,19 @@ def test_scheduler_decode_rows_do_not_consume_prefill_budget():
         block_seq=TokenBlockSequence(block_size=4),
     ))
     plan2 = sched.schedule()
-    assert plan2.pure_decode
+    assert plan2.session
     assert waiter2 in sched.waiting
 
 
 def test_engine_mixed_phase_burst_matches_serial():
-    """While one request decodes and another prefills a long prompt, decode
-    advances via fused bursts (decode_burst dispatches) — and the tokens
-    must match serial execution exactly (burst cadence is a scheduling
-    change, never a numerics change).
-
-    Runs with ``_continuous_decode = False``: under continuous batching the
-    late long prompt is admitted INTO the fused session (its prefill
-    interleaves with fused chunks — tests/test_continuous_batching.py), so
-    the mixed-phase burst regime this test covers only engages on the
-    legacy path and in genuinely mixed plans (e.g. grammar rows)."""
+    """A long prompt arriving beside a decoding row is hosted by the fused
+    session — admitted in-loop or, when the scheduler admitted it first,
+    picked up still prefilling — and both streams match serial execution
+    exactly (where a prompt's chunks run is a scheduling change, never a
+    numerics change)."""
 
     async def main():
-        from dynamo_tpu.runtime.engine import Context, collect
+        from dynamo_tpu.runtime.engine import Context
 
         cfg = dict(CFG)
         cfg.update(
@@ -466,7 +469,6 @@ def test_engine_mixed_phase_burst_matches_serial():
             prefill_chunk=8,
             decode_steps=4,
             pipeline_depth=2,
-            prefill_chunks_per_burst=2,
             max_model_len=256,
             num_blocks=256,
         )
@@ -479,44 +481,38 @@ def test_engine_mixed_phase_burst_matches_serial():
         await engine.close()
 
         engine2 = TpuEngine(EngineConfig(**cfg))
-        engine2._continuous_decode = False  # legacy mixed-phase control
-
-        async def run_a():
-            return await _generate(engine2, short, max_tokens=40)
-
-        async def run_b():
-            # Let A reach steady decode before B's prefill starts.
-            stream_a = await engine2.generate(Context(_req(short, 40)))
-            it = stream_a.__aiter__()
-            first = await it.__anext__()
-            toks_a = list(first["token_ids"])
-            out_b = await _generate(engine2, long_prompt, max_tokens=6)
-            async for item in it:
-                toks_a.extend(item.get("token_ids", ()))
-            return toks_a, out_b
-
-        toks_a, (toks_b, _) = await run_b()
+        # Let A reach steady decode before B's prefill starts.
+        stream_a = await engine2.generate(Context(_req(short, 40)))
+        it = stream_a.__aiter__()
+        first = await it.__anext__()
+        toks_a = list(first["token_ids"])
+        toks_b, _ = await _generate(engine2, long_prompt, max_tokens=6)
+        async for item in it:
+            toks_a.extend(item.get("token_ids", ()))
         assert toks_a == serial_a
         assert toks_b == serial_b
+        pipe = engine2.dispatch_summary()["pipeline"]
+        hosted = pipe["continuous_admissions"] + sum(pipe["prompt_step"].values())
+        assert hosted >= 1, f"the session did not host the prompt: {pipe}"
         kinds = {k for k, *_ in engine2.step_trace}
-        assert "decode_burst" in kinds, f"no burst dispatched: {kinds}"
+        assert "decode_dispatch" in kinds, kinds
+        assert not any("burst" in k for k in kinds), kinds
         await engine2.close()
 
     asyncio.run(main())
 
 
 def test_engine_burst_headroom_fallback():
-    """When KV headroom for a full burst is missing, the engine must fall
-    back to the unified step (decode still advances one token) instead of
-    stalling decode rows."""
+    """When KV headroom for a fused window is missing, a session dispatches
+    nothing and the engine falls through to the unified step (every row
+    still advances one token a step) instead of stalling decode rows."""
 
     async def main():
         cfg = dict(CFG)
         cfg.update(
             max_batch=2,
             prefill_chunk=8,
-            decode_steps=64,  # a full burst wants 64 lookahead slots
-            prefill_chunks_per_burst=1,
+            decode_steps=64,  # a fused chunk wants 64 lookahead slots
             num_blocks=18,  # tiny pool: lookahead can't allocate
             max_model_len=64,
         )
@@ -526,6 +522,14 @@ def test_engine_burst_headroom_fallback():
             _generate(engine, list(range(5, 37)), max_tokens=6),
         )
         assert [len(r[0]) for r in results] == [10, 6]
+        # While both rows are resident no fused chunk fits the pool: those
+        # sessions drain at once for want of KV (``rebuilds``) and each is
+        # followed by a unified step that fetches its rows' tokens.
+        pipe = engine.dispatch_summary()["pipeline"]
+        assert pipe["rebuilds"] >= 1, pipe
+        kinds = [k for k, *_ in engine.step_trace]
+        assert kinds.count("unified_fetch") > pipe["rebuilds"], (kinds, pipe)
+        assert not any("burst" in k for k in kinds), kinds
         await engine.close()
 
     asyncio.run(main())

@@ -198,6 +198,49 @@ def test_demoted_prefix_restores_from_disk_byte_identical(tmp_path):
     asyncio.run(main())
 
 
+@pytest.mark.parametrize("tier", ["host", "disk"])
+def test_second_occurrence_skips_its_prefill_and_compiles_nothing(tmp_path, tier):
+    """The prefix-reuse ladder's bars (they were a benchmark mode's): a
+    prompt whose blocks were pushed out of HBM comes back from the host
+    tier, or from disk through a two-block host window, skipping at least
+    nine tenths of its complete-block prefill, with the stream it had the
+    first time, and nothing compiles after warmup and one priming pass
+    (which reaches the restore paths warmup does not)."""
+
+    async def main():
+        engine = TpuEngine(_cfg(tmp_path if tier == "disk" else None))
+        await asyncio.to_thread(engine.warmup)
+
+        async def occurrence(prompt, floods):
+            first = await _generate(engine, prompt)
+            await _settle_offload(engine, 3)
+            if tier == "disk":
+                engine.host_kv.capacity_bytes = 2 * engine.block_nbytes()
+            await _flood(engine, floods)
+            blocks = hash_token_blocks(prompt, BS)
+            assert len(engine.kv.match_prefix(blocks)) < 3, "test needs eviction"
+            looked, matched = engine.kv.lookup_blocks, engine.kv.matched_blocks
+            again = await _generate(engine, prompt)
+            assert again == first
+            return (
+                engine.kv.matched_blocks - matched,
+                engine.kv.lookup_blocks - looked,
+            )
+
+        await occurrence(list(range(1, 13)), (20, 40, 60, 80, 100, 120))
+        compiled = engine.compile_counts()
+        skipped, total = await occurrence(
+            list(range(130, 142)), (150, 170, 190, 210, 230, 250)
+        )
+        assert total >= 3 and skipped >= 0.9 * total, (skipped, total)
+        assert engine.compile_counts() == compiled
+        if tier == "disk":
+            assert engine.disk_kv.promoted_blocks > 0
+        await engine.close()
+
+    asyncio.run(main())
+
+
 def test_salt_isolation_holds_on_the_disk_tier(tmp_path):
     """Fifth row of the PR 6 tier-isolation matrix (sealing, host tier,
     transfer plane, router — now disk): a tenant's demoted blocks are

@@ -13,10 +13,9 @@ The prefill sibling of test_decode_kernel.py, all CPU-runnable:
    token stream must be byte-identical across chunkings, across
    DYN_PREFILL_KERNEL modes, and vs single-shot prefill — with zero new
    compiles after warmup.
-3. **Mixed-phase cadence** — with the kernel enabled (interpret mode) the
-   chunk/burst cadence still runs decode bursts, and the
-   ``_chunks_since_burst`` counter resets on preemption/migration requeue
-   of a mid-prefill sequence (the ISSUE 19 cadence fix).
+3. **Prompts inside a session** — with the kernel enabled (interpret
+   mode) a long prompt's chunks interleave with fused decode chunks inside
+   one session, by counted events.
 4. **Selector / tuner / metrics plumbing** — resolve_prefill_kernel
    semantics, tuned-table prefill keys, the prefill-chunk summary on
    ``/metrics``.
@@ -333,160 +332,52 @@ def test_stock_kernel_matches_across_chunkings():
     assert a["kv_bytes"] == b["kv_bytes"]
 
 
-# ------------------------------------------------- mixed-phase cadence
+# --------------------------------------------- prompts inside a session
 
 
 def test_mixed_phase_cadence_with_kernel_enabled():
-    """CPU smoke for the acceptance bar: with DYN_PREFILL_KERNEL=pallas in
-    interpret mode, long prompts + concurrent decodes still run the
-    chunk/burst cadence (decode bursts interleave with prefill chunks) and
-    the prefill-chunk summary surfaces on dispatch_summary."""
+    """With the Pallas prefill kernel in interpret mode, a long prompt that
+    arrives beside a decoding row prefills INSIDE the fused session: its
+    chunks are enqueued ahead of the iterations' fused chunks
+    (``prompt_step_order``), fused chunks were dispatched, and the
+    prefill-chunk summary surfaces on dispatch_summary.  Counted events
+    only: no cadence, no wall time."""
     from dynamo_tpu.engine import EngineConfig
     from dynamo_tpu.engine.engine import TpuEngine
     from dynamo_tpu.runtime.engine import Context, collect
 
     async def go():
         cfg = EngineConfig(
-            **dict(
-                CFG,
-                prefill_chunk=4,
-                prefill_kernel="pallas",
-                prefill_chunks_per_burst=2,
-                decode_steps=4,
-            )
+            **dict(CFG, prefill_chunk=4, prefill_kernel="pallas", decode_steps=4)
         )
         engine = TpuEngine(cfg)
         engine.warmup()
         try:
 
-            async def one(i, n):
+            async def one(i, n, max_tokens):
                 items = await collect(
                     await engine.generate(
-                        Context(_req(_prompt(i, n), max_tokens=8))
+                        Context(_req(_prompt(i, n), max_tokens=max_tokens))
                     )
                 )
                 return [t for it in items for t in it["token_ids"]]
 
-            streams = await asyncio.gather(one(1, 6), one(2, 24))
-            assert all(len(s) == 8 for s in streams)
+            # A 6-token prompt decodes after two chunks of 4; the 40-token
+            # prompt beside it needs ten: the short row's first token has
+            # eight device steps to reach the host before the long prompt
+            # could end.
+            streams = await asyncio.gather(one(1, 6, 24), one(2, 40, 8))
+            assert [len(s) for s in streams] == [24, 8]
             kinds = {k for k, *_ in engine.step_trace}
-            assert "decode_burst" in kinds, (
-                f"no decode burst ran in the mixed phase (kinds={kinds})"
-            )
+            assert "decode_dispatch" in kinds, kinds
+            assert not any("burst" in k for k in kinds), kinds
             summary = engine.dispatch_summary()
+            assert summary["pipeline"]["prompt_step"]["ahead"] >= 1, summary
+            assert summary["pipeline"]["prompt_step"]["behind"] == 0, summary
             assert summary["prefill_kernel"] == "pallas"
-            assert summary["prefill"]["chunks"] == engine.prefill_chunks > 0
-            assert summary["prefill"]["prompt_tokens"] >= 30
+            assert summary["prefill"]["chunks"] == engine.prefill_chunks >= 11
+            assert summary["prefill"]["prompt_tokens"] >= 46
             assert summary["prefill"]["wall_s"] > 0
-        finally:
-            await engine.close()
-
-    asyncio.run(go())
-
-
-def test_chunk_cadence_resets_on_prefill_requeue():
-    """The ISSUE 19 cadence fix: a mid-prefill preemption requeue bumps
-    scheduler.prefill_requeues (checked BEFORE the prompt fold, which
-    zeroes num_computed and would make every victim look mid-prefill),
-    and the engine resets _chunks_since_burst when it observes one."""
-    from dynamo_tpu.engine import EngineConfig
-    from dynamo_tpu.engine.kv_manager import KvBlockManager
-    from dynamo_tpu.engine.scheduler import (
-        Scheduler,
-        SequenceState,
-        TokenBlockSequence,
-    )
-
-    cfg = EngineConfig(**CFG, prefill_chunk=4)
-    kv = KvBlockManager(cfg.num_blocks, cfg.block_size)
-    sched = Scheduler(cfg, kv)
-
-    def running_seq(rid, prompt_len, computed, out_tokens):
-        seq = SequenceState(
-            request_id=rid,
-            prompt=_prompt(7, prompt_len),
-            block_seq=TokenBlockSequence(block_size=cfg.block_size),
-            orig_prompt_len=prompt_len,
-        )
-        seq.num_computed = computed
-        seq.output = list(range(out_tokens))
-        sched.running.append(seq)
-        return seq
-
-    # Decode-phase victim (prompt fully computed): NOT a prefill requeue —
-    # even though the fold rewinds num_computed to 0.
-    decode_victim = running_seq("d", 8, 8, 2)
-    sched._preempt(decode_victim)
-    assert sched.preempted == 1
-    assert sched.prefill_requeues == 0
-    assert decode_victim.num_computed == 0  # fold happened
-
-    # Mid-prefill victim: counted.
-    prefill_victim = running_seq("p", 12, 6, 0)
-    sched._preempt(prefill_victim)
-    assert sched.preempted == 2
-    assert sched.prefill_requeues == 1
-
-    # Engine-side observation resets the cadence counter exactly when the
-    # scheduler counter moves — use the real helper against a stub.
-    from dynamo_tpu.engine.engine import TpuEngine
-
-    class _Eng:
-        _note_prefill_requeues = TpuEngine._note_prefill_requeues
-
-    eng = _Eng()
-    eng.scheduler = sched
-    eng._prefill_requeues_seen = 0
-    eng._chunks_since_burst = 7
-    eng._note_prefill_requeues()
-    assert eng._chunks_since_burst == 0
-    assert eng._prefill_requeues_seen == 1
-    # No new requeue: the counter is left alone.
-    eng._chunks_since_burst = 5
-    eng._note_prefill_requeues()
-    assert eng._chunks_since_burst == 5
-
-
-def test_chunk_cadence_resets_on_migration_cutover():
-    """finish_migrated of a mid-prefill sequence leaves the mixed phase:
-    the chunk count must not leak into the next prefill's cadence."""
-    from dynamo_tpu.engine import EngineConfig
-    from dynamo_tpu.engine.engine import TpuEngine
-    from dynamo_tpu.runtime.engine import Context
-
-    async def go():
-        engine = TpuEngine(EngineConfig(**CFG, prefill_chunk=4))
-        engine.warmup()
-        try:
-            # Hold the engine loop after the FIRST prefill chunk so the
-            # sequence is deterministically mid-prefill at cutover.
-            orig = engine._run_unified
-            gate = asyncio.Event()
-            calls = {"n": 0}
-
-            async def held(plan):
-                await orig(plan)
-                calls["n"] += 1
-                if calls["n"] == 1:
-                    await gate.wait()
-
-            engine._run_unified = held
-            stream = await engine.generate(
-                Context(_req(_prompt(5, 24), max_tokens=4))
-            )
-            for _ in range(2000):
-                if calls["n"]:
-                    break
-                await asyncio.sleep(0.01)
-            assert calls["n"], "first prefill chunk never ran"
-            (seq,) = engine.scheduler.running
-            assert seq.in_prefill and seq.num_computed > 0
-            engine._chunks_since_burst = 9
-            engine.finish_migrated(seq.request_id, item=None)
-            assert engine._chunks_since_burst == 0
-            gate.set()
-            async for _ in stream:
-                break
         finally:
             await engine.close()
 

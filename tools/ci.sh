@@ -51,7 +51,8 @@ env JAX_PLATFORMS=cpu python -m pytest tests/test_qos.py -q -m chaos \
   -p no:cacheprovider -p no:xdist -p no:randomly
 
 echo "== kv-tiering suite (disk tier, tier events, discounted scoring,"
-echo "   cross-worker pull exactness) =="
+echo "   cross-worker pull exactness; prefix reuse: >=90% of a second"
+echo "   occurrence's prefill skipped from host and disk, stable compiles) =="
 env JAX_PLATFORMS=cpu python -m pytest tests/test_kv_tiering.py -q -m tiering \
   -p no:cacheprovider -p no:xdist -p no:randomly
 
@@ -60,26 +61,6 @@ echo "   corruption plane matrix, descendant drop, negative cache,"
 echo "   byte-identical recompute, donor quarantine) =="
 env JAX_PLATFORMS=cpu python -m pytest tests/test_kv_integrity.py -q -m integrity \
   -p no:cacheprovider -p no:xdist -p no:randomly
-
-echo "== prefix-reuse smoke (BENCH_PREFIX=1: tiers off/host/disk/pull;"
-echo "   bars: >=90% prefill skipped on 2nd occurrence, pull serves a"
-echo "   never-computed prefix, byte-identical streams, stable compiles) =="
-env JAX_PLATFORMS=cpu BENCH_PREFIX=1 python bench.py --cpu-smoke > /tmp/_prefix_smoke.json
-python - <<'PYEOF'
-import json
-r = json.loads(open("/tmp/_prefix_smoke.json").read().strip().splitlines()[-1])
-assert r["metric"] == "prefix_reuse_skip_frac", r
-assert r["identical"] is True, "tiered streams diverged from control"
-assert r["compile_stable"] is True, "tier paths compiled after warmup"
-assert r["modes"]["host"]["skip_frac"] >= 0.9, r["modes"]["host"]
-assert r["modes"]["disk"]["skip_frac"] >= 0.9, r["modes"]["disk"]
-assert r["pull_served_blocks"] >= 1, "cross-worker pull never served blocks"
-assert r["modes"]["off"]["skip_frac"] < 0.5, (
-    "control mode reused prefixes — the smoke lost its eviction pressure")
-print(f"prefix smoke ok: skip host={r['modes']['host']['skip_frac']} "
-      f"disk={r['modes']['disk']['skip_frac']} "
-      f"pull_blocks={r['pull_served_blocks']}")
-PYEOF
 
 echo "== fused decode kernel parity (interpret-mode pallas vs XLA oracle"
 echo "   on ragged int8/fp32 page tables; ops/decode_attention.py) =="
@@ -95,34 +76,13 @@ env JAX_PLATFORMS=cpu python -m pytest tests/test_prefill_kernel.py -q \
   -k "parity or traced_scale or routed or resolve or byte_identity or metric" \
   -p no:cacheprovider -p no:xdist -p no:randomly
 
-echo "== continuous-decode churn smoke (CPU bench: staggered finishes +"
-echo "   late arrivals, FUSED decode kernel; bars: fewer rebuilds than"
-echo "   forced-rebuild control, exact streams, zero new compiles,"
-echo "   pallas_fused actually served the run, dispatch metrics parseable) =="
-env JAX_PLATFORMS=cpu DYN_PALLAS_INTERPRET=1 DYN_DECODE_KERNEL=pallas_fused BENCH_CHURN=1 \
-  python bench.py --cpu-smoke > /tmp/_churn_smoke.json
-python - <<'PYEOF'
-import json, math
-r = json.loads(open("/tmp/_churn_smoke.json").read().strip().splitlines()[-1])
-assert r["metric"] == "continuous_decode_rebuilds", r
-assert r["decode_kernel"] == "pallas_fused", (
-    f"churn smoke did not run on the fused kernel: {r['decode_kernel']}")
-# The hot-path guards: continuous batching must absorb the churn the
-# forced-rebuild control drains for, without compiling anything new, and
-# the dispatch summary the planner/bench consume must be well-formed.
-assert r["rebuilds"]["continuous"] < r["rebuilds"]["forced"], r["rebuilds"]
-assert r["compile_counts_stable"] is True, "compile count grew under churn"
-assert r["continuous_admissions"] >= 1, "no in-loop admission exercised"
-assert r["continuous_retired"] >= 1, "no in-loop retirement exercised"
-g = r["host_gap_frac"]
-assert isinstance(g, float) and math.isfinite(g) and 0.0 <= g <= 1.0, g
-d = r["dispatch"]["decode_dispatch"]
-assert d["dispatches"] >= 1 and math.isfinite(d["p99_ms"]), d
-print(f"churn smoke ok: kernel={r['decode_kernel']} "
-      f"rebuilds {r['rebuilds']} "
-      f"admissions={r['continuous_admissions']} "
-      f"retired={r['continuous_retired']} host_gap={g}")
-PYEOF
+echo "== continuous-decode churn (staggered finishes + late arrivals on the"
+echo "   FUSED decode kernel: exact streams against serial, no rebuild under"
+echo "   churn, zero new compiles, pallas_fused served the run, dispatch"
+echo "   summary well-formed; tests/test_continuous_batching.py) =="
+env JAX_PLATFORMS=cpu DYN_PALLAS_INTERPRET=1 DYN_DECODE_KERNEL=pallas_fused \
+  python -m pytest tests/test_continuous_batching.py -q \
+  -p no:cacheprovider -p no:xdist -p no:randomly
 
 echo "== tracing suite (span plane: propagation across disagg/pull/"
 echo "   migration, sampling, aggregator, byte-identity + zero-compile"
