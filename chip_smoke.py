@@ -661,11 +661,12 @@ def child_parity(rehearse: bool) -> None:
 # weights dequantised to bfloat16 with no activation rounding (above the
 # stated W8A8: shows how much of the error is W8A8's).  Limits and the
 # readings they come from: PERF.md section 6.
-DSV32 = {"config": "chipbench/configs/deepseek-v3.2-exp-6l-ep16.json",
+DSV32 = {"config": "chipbench/configs/deepseek-v3.2-exp-6l-ep16.json", "reference": "deepseek_v32",
+         "control": "int8_pages",  # the control that must fail a limit
          "doc": 7680, "prompt": 8192, "decode": 32, "short": 96, "num_blocks": 2048,
          "q_block": 256}
-DSV32_REHEARSAL = dict(DSV32, doc=448, prompt=512, decode=8, short=12, num_blocks=256,
-                       q_block=128)
+_PARITY_REHEARSAL = dict(doc=448, prompt=512, decode=8, short=12, num_blocks=256, q_block=128)
+DSV32_REHEARSAL = dict(DSV32, **_PARITY_REHEARSAL)
 # Readings on the chip (PR 28, seeds 29 / 30; 33 positions, context 8224, largest reference logit 8.2 / 7.9), the
 # system first, then the same tokens over int8 pages:
 #   rms_err_forced_selection   0.080 / 0.097   |  0.164 / 0.180   limit 0.13: between, a third of room on each side
@@ -690,9 +691,53 @@ DSV32_LIMITS = {
 }
 
 
+# `--child parity-kimi-k2`: chipbench/configs/kimi-k2-6l-ep32.json at its
+# published widths under agent-shared's shapes, the same rows and programs as
+# above (row A: a 12288-token context in sealed pages, a 512-token turn, 32
+# decoded tokens: context 12832; B on the same pages, C short), against ONE
+# float32 pass of dynamo_tpu/models/reference/kimi_k2.py (decompressed
+# per-head attention with a whole causal softmax, where the system's decode is
+# absorbed and its chunks blocked) on the same dequantised weights.  Without a
+# selector nothing is selected: comparison (3) and the forced pass do not
+# exist.  Limits and their readings: PERF.md section 6 (PR 32).
+KIMI_K2 = {"config": "chipbench/configs/kimi-k2-6l-ep32.json", "reference": "kimi_k2",
+           "control": "coarse_activations",
+           "doc": 12288, "prompt": 12800, "decode": 32, "short": 96, "num_blocks": 1024,
+           "q_block": 256}
+KIMI_K2_REHEARSAL = dict(KIMI_K2, **_PARITY_REHEARSAL)
+# Readings on the chip (PR 32, seeds 28 / 29 / 30; 33 positions, context 12832, largest reference logit 8.6 / 8.2 /
+# 10.0), the system first, then the same tokens with W8A8's activations one step coarser (6 bits and a sign):
+#   rms_err_whole_context   0.064 / 0.060 / 0.065   |  0.141 / 0.146 / 0.148   limit 0.10: between, half of room above the
+#       system and a third under the control: THE limit that separates
+#   engine_link             0.057 / 0.016 / 0.033   |  0.110 / 0.140 / 0.086   limit 0.08: two programs of one model (a
+#       scan against an unrolled loop); it moves 3.5 times from seed to seed, so it bounds more than it separates
+#   rel_err_whole_context   0.110 / 0.115 / 0.095   |  0.203 / 0.188 / 0.139   limit 0.16: a maximum over 676k logits; bounds
+# Every latent page int8 (the other family's control) reads 0.074 / 0.070 / 0.064 here, inside the system's own spread:
+# a query attends to 12.8k entries and their roundings average out, where the other family's selector amplifies them
+# into another S_t.  The bfloat16-weights control reads 0.024 / 0.039 / 0.024: most of the system's error is W8A8's.
+KIMI_K2_LIMITS = {
+    "rms_err_whole_context": (0.10, "max"),
+    "engine_link": (0.08, "max"),
+    "rel_err_whole_context": (0.16, "max"),
+}
+
+
 def child_parity_dsv32(rehearse: bool) -> None:
+    _parity_latent(rehearse, "dsv32", DSV32_REHEARSAL if rehearse else DSV32, DSV32_LIMITS)
+
+
+def child_parity_kimi_k2(rehearse: bool) -> None:
+    _parity_latent(rehearse, "kimi_k2", KIMI_K2_REHEARSAL if rehearse else KIMI_K2, KIMI_K2_LIMITS)
+
+
+def _parity_latent(rehearse: bool, tag: str, par: dict, limits: dict) -> None:
+    """The latent family on the chip against its float32 reference, with the
+    selector (``parity-dsv32``) or without one (``parity-kimi-k2``: S_t is the
+    whole context, so nothing is selected, forced or overlapped, and ONE
+    reference pass serves)."""
     t0 = time.time()
     dev = child_device(rehearse)
+    import importlib
     import types
 
     import jax
@@ -704,10 +749,9 @@ def child_parity_dsv32(rehearse: bool) -> None:
     from dynamo_tpu.models import deepseek_v32 as ds
     from dynamo_tpu.models.config import ModelConfig, register_config
     from dynamo_tpu.models.family import RaggedBatch
-    from dynamo_tpu.models.reference import deepseek_v32 as ref
 
-    par = DSV32_REHEARSAL if rehearse else DSV32
-    # Another seed draws other weights and another sequence.
+    ref = importlib.import_module("dynamo_tpu.models.reference." + par["reference"])
+    # Another seed draws other weights and another sequence (both children).
     seed = int(os.environ.get("DSV32_PARITY_SEED", "28"))
     with open(os.path.join(HERE, par["config"])) as f:
         body = json.load(f)
@@ -719,7 +763,7 @@ def child_parity_dsv32(rehearse: bool) -> None:
         hf = {k: v for k, v in body.items() if k not in (
             "name", "source", "serve", "chips", "reduced", "assumed", "stands_for",
             "rehearsal", "notes")}
-    mc = register_config(ModelConfig.from_hf_config(hf, name="parity-dsv32"))
+    mc = register_config(ModelConfig.from_hf_config(hf, name="parity-" + tag))
     cfg = EngineConfig(
         model=mc.name, block_size=serve["block_size"], num_blocks=par["num_blocks"],
         max_batch=serve["max_batch"], max_model_len=serve["max_model_len"],
@@ -728,7 +772,8 @@ def child_parity_dsv32(rehearse: bool) -> None:
         weight_quant=serve["weight_quant"], seed=20260900 + seed)
     engine = TpuEngine(cfg)
     mc, fam = engine.model_config, engine.family
-    emit("dsv32_engine", t0, **dev, hbm=(jax.local_devices()[0].memory_stats() or {}).get(
+    selector = mc.index_topk > 0
+    emit(f"{tag}_engine", t0, **dev, hbm=(jax.local_devices()[0].memory_stats() or {}).get(
         "bytes_in_use"))
 
     bs, S, PP, chunk = cfg.block_size, cfg.max_batch, cfg.max_blocks_per_seq, cfg.prefill_chunk
@@ -792,11 +837,11 @@ def child_parity_dsv32(rehearse: bool) -> None:
 
     def mask_rows(sels, n):
         """S_t of the step's first n tokens (row A's) as masks over A's positions."""
-        return [np.asarray(m[:n, :T]) for m in sels]
+        return [np.asarray(m[:n, :T]) for m in sels] if selector else []
 
     def hot_rows(sels):
         out = []
-        for sel in sels:  # decode: positions [S, k], -1 where fewer exist
+        for sel in sels if selector else []:  # decode: positions [S, k], -1 where fewer exist
             s0 = np.asarray(sel[0])
             m = np.zeros((1, T), bool)
             m[0, s0[s0 >= 0]] = True
@@ -881,14 +926,14 @@ def child_parity_dsv32(rehearse: bool) -> None:
         return {"doc": with_doc, "A": [[] for _ in range(L)]}
 
     def whole(masks):
-        return [np.concatenate(m) for m in masks["A"]]  # [T, T] per layer
+        return [np.concatenate(m) for m in masks["A"]] if selector else None  # [T, T] per layer
 
     # ---- the system as configured
     t1 = time.time()
     masks = new_masks(True)
     sys_logits, cache = system(engine.params, engine.cache, masks, True)
     sys_masks = whole(masks)
-    emit("dsv32_system", t1, positions=len(sys_logits), rows=len(rows),
+    emit(f"{tag}_system", t1, positions=len(sys_logits), rows=len(rows),
          engine_tokens=link["tokens"], engine_argmax_agree=link["agree"])
 
     # ---- control 1: every page rounded to int8 (one scale a token and part),
@@ -914,6 +959,32 @@ def child_parity_dsv32(rehearse: bool) -> None:
         logits, c, sels = fwd(p, c, rb, dec)
         return logits, round_pages(c), sels
 
+    # ---- control 1b (where ``par`` names it as the control that must fail):
+    # W8A8's activations one step coarser, 6 bits and a sign where the
+    # configuration states 7 (every second int8 level), in the check's jit
+    # over the question and the answer; the document's pages stay the engine's.
+    controls = {}
+    if par["control"] == "coarse_activations":
+        from dynamo_tpu.ops import quant_matmul
+
+        t1 = time.time()
+        rows_int8 = quant_matmul.quantize_rows
+
+        def rows_int7(x):
+            xq, scale = rows_int8(x)
+            return (jnp.round(xq.astype(jnp.float32) / 2.0) * 2.0).astype(xq.dtype), scale
+
+        fwd_coarse = jax.jit(
+            lambda p, c, rb, dec: fam.forward(p, mc, rb, c, decode=dec, return_selection=True),
+            static_argnums=3, donate_argnums=1)
+        quant_matmul.quantize_rows = rows_int7  # read when ``fwd_coarse`` traces
+        try:
+            controls["coarse_activations"], cache = system(
+                engine.params, cache, new_masks(False), False, fwd_coarse)
+        finally:
+            quant_matmul.quantize_rows = rows_int8
+        emit(f"{tag}_coarse_activations", t1)
+
     t1 = time.time()
     # The pages as the engine's programs left them, A's question and answer
     # included: the control recomputes those entries from the rounded document.
@@ -921,7 +992,8 @@ def child_parity_dsv32(rehearse: bool) -> None:
     low_logits, low_cache = system(engine.params, round_pages(cache), low_masks, False,
                                    fwd_rounded)
     del low_cache
-    emit("dsv32_int8_pages", t1)
+    controls["int8_pages"] = low_logits
+    emit(f"{tag}_int8_pages", t1)
 
     # ---- the engine leaves the chip; its weights stay on the host
     host_params = jax.tree_util.tree_map(np.asarray, engine.params)
@@ -960,7 +1032,7 @@ def child_parity_dsv32(rehearse: bool) -> None:
     float_logits, fcache = system(fparams, fcache, fmasks, False)
     float_masks = whole(fmasks)
     del fparams, fcache
-    emit("dsv32_float_weights", t1)
+    emit(f"{tag}_float_weights", t1)
     engine = None
 
     # ---- the reference, layer by layer, on the dequantised weights
@@ -993,9 +1065,12 @@ def child_parity_dsv32(rehearse: bool) -> None:
             sels = []
             for l in range(L):
                 lp = layer_f32(l)
-                sel = None if forced is None else jnp.asarray(forced[l])
-                h, m = ref.layer(lp, hf, h, pos, held, sel, par["q_block"])
-                sels.append(np.asarray(m)[compare])
+                if selector:
+                    sel = None if forced is None else jnp.asarray(forced[l])
+                    h, m = ref.layer(lp, hf, h, pos, held, sel, par["q_block"])
+                    sels.append(np.asarray(m)[compare])
+                else:
+                    h = ref.layer(lp, hf, h, pos, held, par["q_block"])
                 del lp
             h = ref.rms_norm(h[compare], jnp.asarray(host_params["final_norm"], jnp.float32),
                              hf["rms_norm_eps"])
@@ -1017,61 +1092,72 @@ def child_parity_dsv32(rehearse: bool) -> None:
             o.append(both / np.maximum(ref_sels[l].sum(axis=1), 1))
         return np.stack(o)
 
+    # The reference passes the system is read against: with a selector one
+    # under the reference's own S_t and one with the system's S_t forced;
+    # without one, S_t is the whole context and ONE pass serves.
     ref_own, ref_sels = reference(None)
-    emit("dsv32_reference_own", t1)
-    t1 = time.time()
-    ref_forced, _ = reference(sys_masks)
-    emit("dsv32_reference_forced", t1)
-    overlap = overlap_of(ref_sels, sys_masks)
+    emit(f"{tag}_reference_own", t1)
+    if selector:
+        t1 = time.time()
+        ref_forced, _ = reference(sys_masks)
+        emit(f"{tag}_reference_forced", t1)
+        refs = {"own_selection": ref_own, "forced_selection": ref_forced}
+    else:
+        ref_forced = ref_own
+        refs = {"whole_context": ref_own}
     ref_max = float(np.max(np.abs(ref_forced)))
-    # The engine's top-20 log-probabilities against the int8-page logits: the
-    # link's reading one precision down.
-    low_lp = log_softmax(low_logits)
-    link_low = max(float(np.abs(np.asarray(lps, np.float64) - low_lp[j][ids]).max())
-                   for j, (ids, lps) in enumerate(a_top))
     out = {
-        "rel_err_own_selection": rel_err(sys_logits, ref_own),
-        "rel_err_forced_selection": rel_err(sys_logits, ref_forced),
-        "rms_err_own_selection": rms_err(sys_logits, ref_own),
-        "rms_err_forced_selection": rms_err(sys_logits, ref_forced),
-        "selection_overlap_mean": float(overlap.mean()),
-        "selection_overlap_min": float(overlap.min()),
         "engine_link": link["abs"] / ref_max,
         "engine_link_nats": link["abs"],
         "engine_tokens": link["tokens"], "engine_argmax_agree": link["agree"],
-        "int8_pages_rel_err_forced_selection": rel_err(low_logits, ref_forced),
-        "int8_pages_rel_err_own_selection": rel_err(low_logits, ref_own),
-        "int8_pages_rms_err_forced_selection": rms_err(low_logits, ref_forced),
-        "int8_pages_rms_err_own_selection": rms_err(low_logits, ref_own),
-        "int8_pages_engine_link": link_low / ref_max,
-        "int8_pages_selection_overlap_mean": float(overlap_of(ref_sels, whole(low_masks)).mean()),
-        "int8_pages_vs_system_rel": rel_err(low_logits, sys_logits),
-        "int8_pages_vs_system_rms": rms_err(low_logits, sys_logits),
         "argmax_agree": int((sys_logits.argmax(-1) == ref_own.argmax(-1)).sum()),
         "ref_max_abs_logit": ref_max,
         "positions": int(len(compare)), "context": int(T), "seed": seed,
-        "limits": {k: v[0] for k, v in DSV32_LIMITS.items()},
+        "limits": {k: v[0] for k, v in limits.items()},
     }
-    t1 = time.time()
-    ref_float_forced, _ = reference(float_masks)
-    emit("dsv32_reference_forced_float", t1)
-    out.update({
-        "float_weights_rel_err_own_selection": rel_err(float_logits, ref_own),
-        "float_weights_rel_err_forced_selection": rel_err(float_logits, ref_float_forced),
-        "float_weights_rms_err_own_selection": rms_err(float_logits, ref_own),
-        "float_weights_rms_err_forced_selection": rms_err(float_logits, ref_float_forced),
-        "float_weights_selection_overlap_mean": float(overlap_of(ref_sels, float_masks).mean()),
-    })
-    emit("dsv32_parity", t0, **out)
+    for name, r in refs.items():
+        out.update({f"rel_err_{name}": rel_err(sys_logits, r),
+                    f"rms_err_{name}": rms_err(sys_logits, r)})
+    for control, logits in controls.items():
+        # The engine's top-20 log-probabilities against the control's logits:
+        # the link's reading one precision down.
+        lp = log_softmax(logits)
+        out.update({
+            f"{control}_engine_link": max(
+                float(np.abs(np.asarray(lps, np.float64) - lp[j][ids]).max())
+                for j, (ids, lps) in enumerate(a_top)) / ref_max,
+            f"{control}_vs_system_rel": rel_err(logits, sys_logits),
+            f"{control}_vs_system_rms": rms_err(logits, sys_logits)})
+        for name, r in refs.items():
+            out.update({f"{control}_rel_err_{name}": rel_err(logits, r),
+                        f"{control}_rms_err_{name}": rms_err(logits, r)})
+    if selector:
+        overlap = overlap_of(ref_sels, sys_masks)
+        out.update({
+            "selection_overlap_mean": float(overlap.mean()),
+            "selection_overlap_min": float(overlap.min()),
+            "int8_pages_selection_overlap_mean": float(
+                overlap_of(ref_sels, whole(low_masks)).mean()),
+            "float_weights_selection_overlap_mean": float(
+                overlap_of(ref_sels, float_masks).mean()),
+        })
+        t1 = time.time()
+        refs["forced_selection"], _ = reference(float_masks)  # the float control's own S_t
+        emit(f"{tag}_reference_forced_float", t1)
+    for name, r in refs.items():
+        out.update({f"float_weights_rel_err_{name}": rel_err(float_logits, r),
+                    f"float_weights_rms_err_{name}": rms_err(float_logits, r)})
+    emit(f"{tag}_parity", t0, **out)
     if not rehearse:
         within = lambda v, limit, kind: v <= limit if kind == "max" else v >= limit
-        for name, (limit, kind) in DSV32_LIMITS.items():
+        for name, (limit, kind) in limits.items():
             if not within(out[name], limit, kind):
-                fail(f"dsv32: {name} {out[name]} against its limit {limit} ({kind})")
-        low = {n: lk for n, lk in DSV32_LIMITS.items() if "int8_pages_" + n in out}
-        if all(within(out["int8_pages_" + n], *lk) for n, lk in low.items()):
-            fail(f"dsv32: int8 pages pass every limit they are read against ({sorted(low)}): "
-                 "the limits are too loose")
+                fail(f"{tag}: {name} {out[name]} against its limit {limit} ({kind})")
+        control = par["control"]
+        low = {n: lk for n, lk in limits.items() if f"{control}_{n}" in out}
+        if all(within(out[f"{control}_{n}"], *lk) for n, lk in low.items()):
+            fail(f"{tag}: the control {control} passes every limit it is read against "
+                 f"({sorted(low)}): the limits are too loose")
     print(json.dumps(dev), flush=True)
 
 
@@ -1333,6 +1419,7 @@ def main() -> None:
         sys.path.insert(0, HERE)
         {"parity": child_parity,
          "parity-dsv32": child_parity_dsv32,
+         "parity-kimi-k2": child_parity_kimi_k2,
          "tp1": lambda r: child_tp1(r, args.ref),
          "tp4": lambda r: child_tp4(r, args.ref)}[args.child](args.rehearse_cpu)
         return

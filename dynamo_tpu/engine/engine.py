@@ -1835,6 +1835,9 @@ class TpuEngine(
             "prefill_kernel": self.prefill_kernel,
             "device": self.device_summary(),
             "prefill": self.prefill_summary(),
+            # The family's own accounts (selector or whole-context latent
+            # attention, held experts), or None: models/family.py ``counts``.
+            "model": self.family.counts() if self.family.counts else None,
             "pipeline": {
                 "sessions": self.pipeline_sessions,
                 "rebuilds": self.pipeline_rebuilds,

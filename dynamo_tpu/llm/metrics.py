@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import time
 from collections import deque
-from typing import Deque, Dict, Optional, Tuple
+from typing import Any, Deque, Dict, Optional, Tuple
 
 from prometheus_client import (
     CollectorRegistry,
@@ -739,16 +739,18 @@ engine_dispatch_metrics = EngineDispatchMetrics()
 
 
 class SparseModelMetrics:
-    """Counters of a model with a learned sparse selector and held experts
-    (models/deepseek_v32.py; docs/tracing.md).  The selector's account is
-    host arithmetic on lengths the scheduler holds (``add_dsa``); the expert
-    account rides home with the sampled tokens (``add_moe``).  The engine
-    reaches both through its model family only (models/family.py
-    ``count_dispatch`` / ``count_aux``).  Renders nothing until a model that
-    has them ran."""
+    """Counters of the latent family with its held experts
+    (models/deepseek_v32.py; docs/tracing.md).  The attention's account is
+    host arithmetic on lengths the scheduler holds: the selector's where the
+    model has one (``add_dsa``), else that of attention over the whole
+    context (``add_mla``); the expert account rides home with the sampled
+    tokens (``add_moe``).  The engine reaches them through its model family
+    only (models/family.py ``count_dispatch`` / ``count_aux`` / ``counts``).
+    Renders nothing until a model that has them ran."""
 
     def __init__(self):
         self.dsa: Dict[str, list] = {}  # dispatch kind -> [context, selected]
+        self.mla: Dict[str, list] = {}  # dispatch kind -> [attended, query tokens]
         self.moe_local_pairs = 0
         self.moe_routed_tokens = 0
 
@@ -770,6 +772,25 @@ class SparseModelMetrics:
             part = n - full  # tokens with t + 1 < k keep all t + 1
             acc[1] += full * k + part * start + part * (part + 1) // 2
 
+    def add_mla(self, kind: str, starts, ns) -> None:
+        """Add a dispatch's query tokens to the account of latent attention
+        WITHOUT a selector: the token at position t attends to all t + 1
+        positions its row holds.  Arguments as ``add_dsa``."""
+        acc = self.mla.setdefault(kind, [0, 0])
+        for start, n in zip(starts, ns):
+            start, n = int(start), int(n)
+            if n <= 0 or start < 0:
+                continue
+            acc[0] += n * start + n * (n + 1) // 2
+            acc[1] += n
+
+    def summary(self) -> Dict[str, Any]:
+        """The accounts as ``dispatch_summary()["model"]``."""
+        return {"dsa": {k: list(v) for k, v in self.dsa.items()},
+                "mla": {k: list(v) for k, v in self.mla.items()},
+                "moe_local_pairs": self.moe_local_pairs,
+                "moe_routed_tokens": self.moe_routed_tokens}
+
     def add_moe(self, aux) -> None:
         """``aux``: int array [..., 2] of (pairs on held experts, tokens routed)."""
         a = aux.reshape(-1, 2).sum(axis=0)
@@ -777,19 +798,29 @@ class SparseModelMetrics:
         self.moe_routed_tokens += int(a[1])
 
     def render(self, prefix: str = "dynamo_tpu") -> str:
-        if not self.dsa and not self.moe_routed_tokens:
+        if not self.dsa and not self.mla and not self.moe_routed_tokens:
             return ""
         lines = []
-        for i, (name, help_) in enumerate((
-            ("dsa_context_positions_total",
-             "Positions s <= t summed over the query tokens dispatched"),
-            ("dsa_selected_positions_total",
-             "min(index_topk, t + 1) summed over the query tokens dispatched"),
-        )):
-            lines.append(f"# HELP {prefix}_{name} {help_}")
-            lines.append(f"# TYPE {prefix}_{name} counter")
-            for kind, v in sorted(self.dsa.items()):
-                lines.append(f'{prefix}_{name}{{kind="{escape_label(kind)}"}} {v[i]}')
+        for acc, series in (
+            (self.dsa, (
+                ("dsa_context_positions_total",
+                 "Positions s <= t summed over the query tokens dispatched"),
+                ("dsa_selected_positions_total",
+                 "min(index_topk, t + 1) summed over the query tokens dispatched"))),
+            (self.mla, (
+                ("mla_attended_positions_total",
+                 "Positions s <= t a query token attends to (no selector), summed over the "
+                 "query tokens dispatched"),
+                ("mla_query_tokens_total",
+                 "Query tokens dispatched to latent attention without a selector"))),
+        ):
+            if not acc:
+                continue
+            for i, (name, help_) in enumerate(series):
+                lines.append(f"# HELP {prefix}_{name} {help_}")
+                lines.append(f"# TYPE {prefix}_{name} counter")
+                for kind, v in sorted(acc.items()):
+                    lines.append(f'{prefix}_{name}{{kind="{escape_label(kind)}"}} {v[i]}')
         for name, help_, v in (
             ("moe_local_pairs_total",
              "Routed (token, expert) pairs that landed on experts held here",

@@ -19,6 +19,11 @@ from dataclasses import dataclass, field, replace
 from typing import Any, Dict, Optional
 
 
+# model_types of the latent family (models/deepseek_v32.py): DeepSeek-V3's
+# block; deepseek_v32 adds the learned sparse selector.
+LATENT_MODEL_TYPES = ("deepseek_v32", "deepseek_v3", "kimi_k2")
+
+
 @dataclass(frozen=True)
 class ModelConfig:
     name: str
@@ -46,8 +51,10 @@ class ModelConfig:
     # The model family (models/family.py picks init, cache and forward from
     # it): "llama" covers the dense GQA block and the Mixtral-style MoE.
     model_type: str = "llama"
-    # deepseek_v32: latent attention (MLA), the learned sparse selector
-    # (DSA) and the sigmoid gate; all 0 for every other family.
+    # The latent family (deepseek_v32, and without a selector deepseek_v3 and
+    # kimi_k2): latent attention (MLA), the sigmoid gate and, where
+    # index_topk > 0, the learned sparse selector (DSA); all 0 for every
+    # other family.
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -85,9 +92,9 @@ class ModelConfig:
     @classmethod
     def from_hf_config(cls, cfg: Dict[str, Any], name: str = "") -> "ModelConfig":
         """Convert a HuggingFace ``config.json`` dict (llama/mixtral style,
-        or ``model_type: deepseek_v32``)."""
-        if cfg.get("model_type") == "deepseek_v32":
-            return cls._from_deepseek_v32(cfg, name)
+        or one of ``LATENT_MODEL_TYPES``)."""
+        if cfg.get("model_type") in LATENT_MODEL_TYPES:
+            return cls._from_latent(cfg, name)
         num_heads = cfg["num_attention_heads"]
         head_dim = cfg.get("head_dim") or cfg["hidden_size"] // num_heads
         # Qwen2 checkpoints carry q/k/v biases but don't always write an
@@ -122,11 +129,13 @@ class ModelConfig:
         )
 
     @classmethod
-    def _from_deepseek_v32(cls, cfg: Dict[str, Any], name: str) -> "ModelConfig":
-        """DeepSeek-V3.2-Exp's keys.  ``n_routed_experts`` counts the experts
-        held here; a file cut to one chip's share states the published
-        router width beside it (``n_routed_experts_published``) with
-        ``ep_size``/``ep_rank``; the uncut config holds them all."""
+    def _from_latent(cls, cfg: Dict[str, Any], name: str) -> "ModelConfig":
+        """DeepSeek-V3's keys; with ``index_*`` (DeepSeek-V3.2-Exp) the
+        selector's, without them ``index_topk`` 0: no selector.
+        ``n_routed_experts`` counts the experts held here; a file cut to one
+        chip's share states the published router width beside it
+        (``n_routed_experts_published``) with ``ep_size``/``ep_rank``; the
+        uncut config holds them all."""
         held = cfg["n_routed_experts"]
         ep_size = cfg.get("ep_size", 1)
         total = cfg.get("n_routed_experts_published", held * ep_size)
@@ -138,14 +147,15 @@ class ModelConfig:
         ep_rank = cfg.get("ep_rank", 0)
         if not 0 <= ep_rank < ep_size:
             raise ValueError(f"ep_rank {ep_rank} outside ep_size {ep_size}")
-        if total % cfg["n_group"] or cfg["topk_group"] > cfg["n_group"]:
+        n_group, topk_group = cfg.get("n_group", 1), cfg.get("topk_group", 1)
+        if total % n_group or topk_group > n_group:
             raise ValueError("n_group must divide the router's width")
         eos = cfg.get("eos_token_id", ())
         if isinstance(eos, int):
             eos = (eos,)
         return cls(
             name=name or cfg.get("_name_or_path", "hf-model"),
-            model_type="deepseek_v32",
+            model_type=cfg["model_type"],
             vocab_size=cfg["vocab_size"],
             hidden_size=cfg["hidden_size"],
             num_layers=cfg["num_hidden_layers"],
@@ -168,12 +178,12 @@ class ModelConfig:
             qk_nope_head_dim=cfg["qk_nope_head_dim"],
             qk_rope_head_dim=cfg["qk_rope_head_dim"],
             v_head_dim=cfg["v_head_dim"],
-            index_n_heads=cfg["index_n_heads"],
-            index_head_dim=cfg["index_head_dim"],
-            index_topk=cfg["index_topk"],
+            index_n_heads=cfg.get("index_n_heads", 0),
+            index_head_dim=cfg.get("index_head_dim", 0),
+            index_topk=cfg.get("index_topk", 0),
             first_k_dense_replace=cfg["first_k_dense_replace"],
-            n_group=cfg["n_group"],
-            topk_group=cfg["topk_group"],
+            n_group=n_group,
+            topk_group=topk_group,
             routed_scaling_factor=cfg.get("routed_scaling_factor", 1.0),
             norm_topk_prob=cfg.get("norm_topk_prob", True),
             router_experts=total,

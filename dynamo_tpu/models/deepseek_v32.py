@@ -1,12 +1,18 @@
-"""DeepSeek-V3.2-Exp block: latent attention (MLA) under the learned sparse
-selector (DSA), a sigmoid gate with group-limited choice, one shared expert
-and the routed experts this chip HOLDS (docs/deepseek_v32.md has the
-equations; models/reference/deepseek_v32.py is the plain float32 reference).
+"""The latent family, DeepSeek-V3's block and its descendants: latent
+attention (MLA), a sigmoid gate with group-limited choice, one shared expert
+and the routed experts this chip HOLDS; with ``index_topk`` > 0 the learned
+sparse selector (DSA) of DeepSeek-V3.2-Exp decides which positions a query
+attends to, with ``index_topk`` 0 (``model_type`` ``kimi_k2``,
+``deepseek_v3``) there is NO selector: no ``idx_*`` leaf, no indexer page, no
+score, and a query attends to its row's whole context (ops/dense_mla.py).
+docs/deepseek_v32.md has the equations; models/reference/deepseek_v32.py and
+models/reference/kimi_k2.py are the plain float32 references.
 
 Beside models/llama.py and sharing its ``linear``, ``rms_norm``,
 ``embed_lookup``, ``lm_logits`` and the dispatch of models/moe.py.  The cache
-is two page arrays under ONE page table (``LatentKVCache``): the engine's
-block manager, prefix cache and eviction see page ids only and are untouched.
+is one or two page arrays under ONE page table (``LatentKVCache``): the
+engine's block manager, prefix cache and eviction see page ids only and are
+untouched.
 
 Expert parallelism without the exchange: ``config.num_experts`` experts are
 held (global ids ``ep_rank * num_experts`` onwards), the router scores and
@@ -17,7 +23,7 @@ goes on to the next layer, as in the reference.
 
 from __future__ import annotations
 
-from typing import Any, Dict, NamedTuple, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +35,7 @@ from ..ops.rope import (
     rope_frequencies,
     yarn_mscale,
 )
+from ..ops.dense_mla import dense_decode_attention, dense_prefill_attention
 from ..ops.sparse_mla import fused_sparse_decode_attention, sparse_prefill_attention
 from .config import ModelConfig
 from .llama import RaggedBatch, embed_lookup, linear, lm_logits, mlp, rms_norm
@@ -62,11 +69,11 @@ def latent_width(config: ModelConfig) -> int:
 class LatentKVCache(NamedTuple):
     """``latent`` [L, P, ps, latent_width]: the normed latent c_t and the
     rope key k^R_t, K and V at once (then zero padding to whole lanes).
-    ``index`` [L, P, ps, index_head_dim]: the selector's key k^I_t.  One page
-    id names the same 16 tokens in both."""
+    ``index`` [L, P, ps, index_head_dim]: the selector's key k^I_t; None for a
+    model without a selector.  One page id names the same 16 tokens in both."""
 
     latent: jnp.ndarray
-    index: jnp.ndarray
+    index: Optional[jnp.ndarray]
 
     @classmethod
     def create(cls, config: ModelConfig, num_pages: int, page_size: int,
@@ -74,7 +81,8 @@ class LatentKVCache(NamedTuple):
         L = config.num_layers
         return cls(
             latent=jnp.zeros((L, num_pages, page_size, latent_width(config)), dtype),
-            index=jnp.zeros((L, num_pages, page_size, config.index_head_dim), dtype),
+            index=jnp.zeros((L, num_pages, page_size, config.index_head_dim), dtype)
+            if config.index_topk else None,
         )
 
 
@@ -89,15 +97,15 @@ def leaf_shapes(config: ModelConfig) -> Dict[str, Dict[str, tuple]]:
     Lm, E, Et = L - Ld, c.num_experts, c.router_experts
     F, Fm = c.intermediate_size, c.moe_intermediate_size
     Fs = Fm * max(1, c.num_shared_experts)
+    selector = {"idx_wq_b": (L, Rq, Hi * di), "idx_wk": (L, D, di), "idx_k_norm_w": (L, di),
+                "idx_k_norm_b": (L, di), "idx_wproj": (L, D, Hi)} if c.index_topk else {}
     return {
         "top": {"embed": (V, D), "lm_head": (D, V), "final_norm": (D,)},
         "layers": {
             "attn_norm": (L, D), "wq_a": (L, D, Rq), "q_norm": (L, Rq),
             "wq_b": (L, Rq, H * (dn + dr)), "wkv_a": (L, D, Rkv + dr),
             "kv_norm": (L, Rkv), "w_uk": (L, H, Rkv, dn), "w_uv": (L, H, Rkv, dv),
-            "wo": (L, H * dv, D), "idx_wq_b": (L, Rq, Hi * di), "idx_wk": (L, D, di),
-            "idx_k_norm_w": (L, di), "idx_k_norm_b": (L, di), "idx_wproj": (L, D, Hi),
-            "mlp_norm": (L, D),
+            "wo": (L, H * dv, D), **selector, "mlp_norm": (L, D),
         },
         "dense": {"w_gate": (Ld, D, F), "w_up": (Ld, D, F), "w_down": (Ld, F, D)},
         "moe": {
@@ -200,17 +208,19 @@ def held_experts(config: ModelConfig) -> range:
 def gate(x: jnp.ndarray, lp: Params, config: ModelConfig):
     """(chosen ids [T, K], weights [T, K] f32) over ALL the router's experts:
     sigmoid scores; the bias enters the choice only; the best ``topk_group``
-    of ``n_group`` groups by the sum of each group's two largest; top-K of
-    what is left; weights normalised over the chosen and scaled."""
+    of ``n_group`` groups by the sum of each group's two largest (one group:
+    nothing to limit); top-K of what is left; weights normalised over the
+    chosen and scaled."""
     T = x.shape[0]
     Et, G, K = config.router_experts, config.n_group, config.num_experts_per_token
     s = jax.nn.sigmoid((x @ lp["router"]).astype(jnp.float32))  # [T, Et]
     biased = s + lp["router_bias"].astype(jnp.float32)
-    group_score = jnp.sum(jax.lax.top_k(biased.reshape(T, G, Et // G), 2)[0], axis=-1)
-    keep = jax.lax.top_k(group_score, config.topk_group)[1]  # [T, topk_group]
-    group_ok = jnp.any(keep[:, :, None] == jnp.arange(G)[None, None, :], axis=1)  # [T, G]
-    allowed = jnp.repeat(group_ok, Et // G, axis=-1)
-    chosen = jax.lax.top_k(jnp.where(allowed, biased, -jnp.inf), K)[1]
+    if G > 1:
+        group_score = jnp.sum(jax.lax.top_k(biased.reshape(T, G, Et // G), 2)[0], axis=-1)
+        keep = jax.lax.top_k(group_score, config.topk_group)[1]  # [T, topk_group]
+        group_ok = jnp.any(keep[:, :, None] == jnp.arange(G)[None, None, :], axis=1)  # [T, G]
+        biased = jnp.where(jnp.repeat(group_ok, Et // G, axis=-1), biased, -jnp.inf)
+    chosen = jax.lax.top_k(biased, K)[1]
     w = jnp.take_along_axis(s, chosen, axis=-1)
     if config.norm_topk_prob:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
@@ -274,7 +284,7 @@ def forward_ragged(
     *,
     decode: bool = False,
     return_selection: bool = False,
-    block_q: int = 64,
+    block_q: Optional[int] = None,  # each attention path has its own default
     block_k: int = 1024,
     **_llama_only,  # attn_impl, kernels, kv_scale, mesh, lora_rank: family.py checks them
 ) -> Tuple[jnp.ndarray, LatentKVCache, Any]:
@@ -283,12 +293,14 @@ def forward_ragged(
     [2] int32: (routed pairs that landed on held experts, tokens routed), over
     the step's real tokens and all MoE layers; with ``return_selection`` it
     is instead the list of S_t per layer (decode: positions [S, k]; else a
-    mask [T, PP * ps])."""
+    mask [T, PP * ps]; None for a model without a selector, whose S_t is
+    every position up to t)."""
     c = config
     rb = jax.tree_util.tree_map(jnp.asarray, rb)  # host arrays when not under jit
     (T,) = rb.token_ids.shape
     H, dn, dr, dv, Rkv = c.num_heads, c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim, c.kv_lora_rank
     Hi, di, eps = c.index_n_heads, c.index_head_dim, c.rms_norm_eps
+    selector = c.index_topk > 0
     inv_freq = rope_frequencies(dr, c.rope_theta, c.rope_scaling)
     sm_scale = (dn + dr) ** -0.5 * yarn_mscale(c.rope_scaling) ** 2
     L, P_layer, ps = cache.latent.shape[:3]
@@ -310,17 +322,23 @@ def forward_ragged(
         entry = jnp.concatenate(
             [rms_norm(kv[:, :Rkv], lp["kv_norm"], eps), k_rope, jnp.zeros((T, tail), kv.dtype)],
             axis=-1)
+        slots = jnp.where(rb.slot_mapping < 0, -1, rb.slot_mapping + l * (P_layer * ps))
+        tables = rb.page_indices + l * P_layer
+
         # Absorbed form: q~ = W^UK^T q^N scores the cached latent directly.
-        q_abs = jnp.concatenate(
-            [jnp.einsum("thn,hcn->thc", q[..., :dn], lp["w_uk"]), q_rope,
-             jnp.zeros((T, H, tail), q.dtype)], axis=-1)
+        def absorbed(rows=slice(None)):
+            return jnp.concatenate(
+                [jnp.einsum("thn,hcn->thc", q[rows, :, :dn], lp["w_uk"]), q_rope[rows],
+                 jnp.zeros(q_rope[rows].shape[:2] + (tail,), q.dtype)], axis=-1)
+
+        if not selector:
+            return dense_attention(absorbed, q, q_rope, entry, lp, lat, slots, tables)
+        q_abs = absorbed()
         qi = _rope_head(linear(cq, lp, "idx_wq_b").reshape(T, Hi, di), pos, inv_freq, dr)
         ki = _layer_norm(x @ lp["idx_wk"], lp["idx_k_norm_w"], lp["idx_k_norm_b"])
         ki = _rope_head(ki[:, None, :], pos, inv_freq, dr)[:, 0]
         wi = (x @ lp["idx_wproj"]).astype(jnp.float32) * (Hi**-0.5 * di**-0.5)
-        slots = jnp.where(rb.slot_mapping < 0, -1, rb.slot_mapping + l * (P_layer * ps))
         lat, idx = _write(lat, entry, slots), _write(idx, ki, slots)
-        tables = rb.page_indices + l * P_layer
         kw = dict(topk=c.index_topk, sm_scale=sm_scale, rank_v=Rkv)
 
         def one_token_rows(_):
@@ -335,7 +353,7 @@ def forward_ragged(
         else:
             res = sparse_prefill_attention(
                 q_abs, qi, wi, lat, idx, pos, rb.kv_lens, tables, rb.cu_q_lens, rb.num_seqs,
-                block_q=block_q, block_k=block_k, return_mask=return_selection, **kw)
+                block_q=block_q or 64, block_k=block_k, return_mask=return_selection, **kw)
             o_lat, sel = res if return_selection else (res, None)
             # Decode rows riding a mixed step: the one-query path, only when
             # the step has any.
@@ -354,6 +372,30 @@ def forward_ragged(
         o = jnp.einsum("thc,hcv->thv", o_lat, lp["w_uv"]).reshape(T, H * dv)
         return linear(o, lp, "wo"), lat, idx, sel
 
+    def dense_attention(absorbed, q, q_rope, entry, lp, lat, slots, tables):
+        """No selector: a one-token row attends to its whole context in the
+        absorbed form (the kernel), a prompt chunk in the decompressed form,
+        and a chunk's output needs no W^UV: it is per head already."""
+        lat = _write(lat, entry, slots)
+        kw = dict(sm_scale=sm_scale, rank_v=Rkv)
+
+        def one_token_rows(_):
+            o_lat = dense_decode_attention(
+                absorbed(first), lat, jnp.where(single, rb.kv_lens, 0), tables, **kw)
+            return jnp.einsum("shc,hcv->shv", o_lat, lp["w_uv"])  # [S, H, dv]
+
+        if decode:
+            o = one_token_rows(None)
+        else:
+            o = dense_prefill_attention(
+                jnp.concatenate([q[..., :dn], q_rope], axis=-1), lat, lp["w_uk"], lp["w_uv"],
+                pos, rb.kv_lens, tables, rb.cu_q_lens, rb.num_seqs, sm_scale=sm_scale,
+                block_q=block_q or 128, block_k=block_k)
+            o1 = jax.lax.cond(jnp.any(single), one_token_rows,
+                              lambda _: jnp.zeros((S, H, dv), o.dtype), None)
+            o = o.at[jnp.where(single, first, T)].set(o1, mode="drop")
+        return linear(o.reshape(T, H * dv), lp, "wo"), lat, None, None  # no indexer pages, no S_t
+
     def layer(h, lat, idx, lp, l, is_moe: bool):
         a, lat, idx, sel = attention(rms_norm(h, lp["attn_norm"], eps), lp, l, lat, idx)
         h = h + a
@@ -370,7 +412,7 @@ def forward_ragged(
 
     h = embed_lookup(params, rb.token_ids, jnp.dtype(c.dtype))
     lat = cache.latent.reshape((L * P_layer,) + cache.latent.shape[2:])
-    idx = cache.index.reshape((L * P_layer,) + cache.index.shape[2:])
+    idx = cache.index.reshape((L * P_layer,) + cache.index.shape[2:]) if selector else None
     pairs = jnp.zeros((), jnp.int32)
     sels = []
     for l in range(Ld):  # the leading dense layers: few, so unrolled
@@ -402,7 +444,8 @@ def forward_ragged(
     h = rms_norm(h, params["final_norm"], eps)
     rows = jnp.clip(rb.cu_q_lens[1:] - 1, 0, T - 1)
     logits = lm_logits(params, h[rows])
-    new_cache = LatentKVCache(lat.reshape(cache.latent.shape), idx.reshape(cache.index.shape))
+    new_cache = LatentKVCache(lat.reshape(cache.latent.shape),
+                              idx.reshape(cache.index.shape) if selector else None)
     if return_selection:
         return logits, new_cache, sels
     tokens = jnp.sum(real, dtype=jnp.int32) * (L - Ld)

@@ -11,7 +11,7 @@ from __future__ import annotations
 
 from typing import Any, Callable, NamedTuple, Optional
 
-from .config import ModelConfig
+from .config import LATENT_MODEL_TYPES, ModelConfig
 from .llama import RaggedBatch  # noqa: F401 — the engine's step input, family-neutral
 
 
@@ -45,6 +45,8 @@ class ModelFamily(NamedTuple):
     # for what ``forward`` sent home.
     count_dispatch: Optional[Callable] = None
     count_aux: Optional[Callable] = None
+    # () -> those accounts as a dict, for ``dispatch_summary()``.
+    counts: Optional[Callable] = None
 
 
 def _llama() -> ModelFamily:
@@ -95,15 +97,23 @@ def _llama() -> ModelFamily:
     )
 
 
-def _deepseek_v32() -> ModelFamily:
+def _latent() -> ModelFamily:
+    """models/deepseek_v32.py: with the selector (``index_topk`` > 0) or
+    without it, by the configuration."""
     import jax.numpy as jnp
 
     from ..llm.metrics import sparse_model_metrics
     from . import deepseek_v32 as ds
 
     def kinds(config, cache):
-        return {"latent": cache.latent.shape[-1] * cache.latent.dtype.itemsize,
-                "index": cache.index.shape[-1] * cache.index.dtype.itemsize}
+        return {name: pages.shape[-1] * pages.dtype.itemsize
+                for name, pages in cache._asdict().items() if pages is not None}
+
+    def count_dispatch(config, kind, starts, ns):
+        if config.index_topk:
+            sparse_model_metrics.add_dsa(kind, config.index_topk, starts, ns)
+        else:
+            sparse_model_metrics.add_mla(kind, starts, ns)
 
     def check(config: ModelConfig, cfg: Any) -> None:
         """The engine options this family cannot serve yet, each refused by
@@ -124,10 +134,11 @@ def _deepseek_v32() -> ModelFamily:
             bad.append("--lora (no adapter banks for the latent projections)")
         if bad:
             raise ValueError(
-                f"model_type deepseek_v32 ({config.name}) does not support: " + "; ".join(bad))
+                f"model_type {config.model_type} ({config.name}) does not support: "
+                + "; ".join(bad))
 
     return ModelFamily(
-        name="deepseek_v32",
+        name="latent",
         init_params=ds.init_params,
         init_params_quantized=ds.init_params_quantized,
         quantize_params=ds.quantize_params,
@@ -140,13 +151,13 @@ def _deepseek_v32() -> ModelFamily:
         forward_sp_prefill=None,
         cache_kinds=kinds,
         check=check,
-        count_dispatch=lambda config, kind, starts, ns: sparse_model_metrics.add_dsa(
-            kind, config.index_topk, starts, ns),
+        count_dispatch=count_dispatch,
         count_aux=sparse_model_metrics.add_moe,
+        counts=sparse_model_metrics.summary,
     )
 
 
-_FAMILIES = {"llama": _llama, "deepseek_v32": _deepseek_v32}
+_FAMILIES = {"llama": _llama, **{t: _latent for t in LATENT_MODEL_TYPES}}
 
 
 def family_of(config: ModelConfig) -> ModelFamily:

@@ -159,14 +159,12 @@ def _decode_kernel(
     tables_ref,  # [S, PP] int32
     # operands
     q_ref,  # [1, H, Dk] VMEM (row s)
-    mask_ref,  # [1, nkb, C] int32 VMEM (row s): S_t over the row's positions
-    lat_ref,  # [NP, ps, Dk] HBM, copied page by page
-    # output
-    o_ref,  # [1, H, Rkv]
-    # scratch
-    buf,  # [2, ppb, ps, Dk] pages' dtype
-    sems,  # DMA semaphores (2,)
-    *,
+    # then: mask_ref [1, nkb, C] int32 VMEM (row s), S_t over the row's
+    # positions, ONLY with ``masked``; lat_ref [NP, ps, Dk] HBM, copied page
+    # by page; the output o_ref [1, H, Rkv]; the scratch buf [2, ppb, ps, Dk]
+    # in the pages' dtype and sems, DMA semaphores (2,)
+    *refs,
+    masked: bool,
     sm_scale: float,
     rank_v: int,
     page_size: int,
@@ -175,7 +173,11 @@ def _decode_kernel(
 ):
     """Program s is row s: ``sparse_prefill_attention::attend_block`` for one
     query, over the blocks the row has.  (Grid programs run in order on one
-    core: no dimension_semantics.)"""
+    core: no dimension_semantics.)  Without ``masked`` there is no selector:
+    the query attends to every position below ``kv_len``, and no mask
+    operand exists."""
+    mask_ref = refs[0] if masked else None
+    lat_ref, o_ref, buf, sems = refs[1:] if masked else refs
     s = pl.program_id(0)
     H, Dk = q_ref.shape[1:]
     C = ppb * page_size
@@ -236,7 +238,10 @@ def _decode_kernel(
             lat = buf[slot].reshape(C, Dk)  # K and V at once
             sc = jax.lax.dot_general(q, lat, (((1,), (1,)), ((), ())),
                                      preferred_element_type=jnp.float32)  # [H, C]
-            mk = mask_ref[0, pl.ds(b, 1), :] > 0  # [1, C]
+            if masked:
+                mk = mask_ref[0, pl.ds(b, 1), :] > 0  # [1, C]
+            else:
+                mk = b * C + jax.lax.broadcasted_iota(jnp.int32, (1, C), 1) < kv_len
             sc = jnp.where(mk, sc * sm_scale, NEG)
             m_new = jnp.maximum(m, jnp.max(sc, axis=1, keepdims=True))
             p = jnp.where(mk, jnp.exp(sc - m_new), 0.0)
@@ -254,7 +259,55 @@ def _decode_kernel(
         o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("sm_scale", "rank_v", "block_k", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("sm_scale", "rank_v", "block_k", "interpret", "name"))
+def latent_decode_attention(q_abs, mask, lat_pages, kv_lens, tables, *, sm_scale, rank_v,
+                            block_k, interpret, name=SCOPES["decode"]):
+    """The kernel's call, with S_t's ``mask`` or, for a model without a
+    selector (ops/dense_mla.py), ``None``: then no mask operand is built.
+
+    Jitted, so that a process traces the kernel once and a program lowers it
+    once, whatever its layers: the unrolled copies take 1.1 s a call site to
+    trace, 23 s of a warm start over the engine's programs (my chip run,
+    PR 31)."""
+    S, H, Dk = q_abs.shape
+    PP = tables.shape[1]
+    ps = lat_pages.shape[1]
+    ppb = max(1, min(block_k // ps, PP))  # pages per key block
+    C = ppb * ps
+    nkb = -(-PP // ppb)
+    masked = mask is not None
+    kernel = functools.partial(
+        _decode_kernel, masked=masked, sm_scale=sm_scale, rank_v=rank_v, page_size=ps,
+        pages_per_seq=PP, ppb=ppb)
+    operands, in_specs = [q_abs], [
+        pl.BlockSpec((1, H, Dk), lambda s, *_: (s, 0, 0), memory_space=pltpu.VMEM)]
+    if masked:
+        operands.append(jnp.pad(mask.astype(jnp.int32),
+                                ((0, 0), (0, nkb * C - PP * ps))).reshape(S, nkb, C))
+        in_specs.append(
+            pl.BlockSpec((1, nkb, C), lambda s, *_: (s, 0, 0), memory_space=pltpu.VMEM))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(S,),
+        in_specs=in_specs + [pl.BlockSpec(memory_space=pl.ANY)],  # pages stay in HBM
+        out_specs=pl.BlockSpec((1, H, rank_v), lambda s, *_: (s, 0, 0),
+                               memory_space=pltpu.VMEM),
+        scratch_shapes=[
+            pltpu.VMEM((2, ppb, ps, Dk), lat_pages.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, H, rank_v), q_abs.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=64 << 20),
+        interpret=interpret,
+        name=name,
+    )(kv_lens.astype(jnp.int32), tables.astype(jnp.int32), *operands, lat_pages)
+
+
 def masked_decode_attention(
     q_abs: jnp.ndarray,  # [S, H, Rkv + dr]
     mask: jnp.ndarray,  # [S, PP * ps] bool: S_t over the row's logical positions
@@ -269,45 +322,9 @@ def masked_decode_attention(
 ) -> jnp.ndarray:
     """The kernel: one query a row attends to the row's positions that
     ``mask`` keeps.  [S, H, Rkv]; zeros for a row with ``kv_len`` 0.  ``mask``
-    must be False at and beyond ``kv_len``.
-
-    Jitted, so that a process traces the kernel once and a program lowers it
-    once, whatever its layers: the unrolled copies take 1.1 s a call site to
-    trace, 23 s of a warm start over the engine's programs (my chip run,
-    PR 31)."""
-    S, H, Dk = q_abs.shape
-    PP = tables.shape[1]
-    ps = lat_pages.shape[1]
-    ppb = max(1, min(block_k // ps, PP))  # pages per key block
-    C = ppb * ps
-    nkb = -(-PP // ppb)
-    mask3 = jnp.pad(mask.astype(jnp.int32), ((0, 0), (0, nkb * C - PP * ps))).reshape(S, nkb, C)
-    kernel = functools.partial(
-        _decode_kernel, sm_scale=sm_scale, rank_v=rank_v, page_size=ps, pages_per_seq=PP,
-        ppb=ppb)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(S,),
-        in_specs=[
-            pl.BlockSpec((1, H, Dk), lambda s, *_: (s, 0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, nkb, C), lambda s, *_: (s, 0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pl.ANY),  # pages stay in HBM
-        ],
-        out_specs=pl.BlockSpec((1, H, rank_v), lambda s, *_: (s, 0, 0),
-                               memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.VMEM((2, ppb, ps, Dk), lat_pages.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, H, rank_v), q_abs.dtype),
-        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=64 << 20),
-        interpret=interpret,
-        name=SCOPES["decode"],
-    )(kv_lens.astype(jnp.int32), tables.astype(jnp.int32), q_abs, mask3, lat_pages)
+    must be False at and beyond ``kv_len``."""
+    return latent_decode_attention(q_abs, mask, lat_pages, kv_lens, tables, sm_scale=sm_scale,
+                                   rank_v=rank_v, block_k=block_k, interpret=interpret)
 
 
 def fused_sparse_decode_attention(

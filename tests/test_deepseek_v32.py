@@ -421,7 +421,7 @@ def test_from_hf_config_reads_the_catalog_row_and_the_cut_file():
     shapes = ds.leaf_shapes(cut)
     n = sum(int(np.prod(s)) for g in shapes.values() for s in g.values())
     assert n == 5_587_117_824  # the 5.59 G parameters of the issue's arithmetic
-    assert ds.latent_width(cut) == 640 and family_of(cut).name == "deepseek_v32"
+    assert ds.latent_width(cut) == 640 and family_of(cut).name == "latent"
     with pytest.raises(ValueError, match="router's width"):
         ModelConfig.from_hf_config(dict(body, ep_size=8), name="bad")
 

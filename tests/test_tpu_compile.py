@@ -188,13 +188,12 @@ def test_one_kv_head_per_shard_is_refused_with_a_sentence():
 _DSV32_PAGES = 32768 * 16 * (640 + 128) * 2 * 6  # latent and indexer pages, six layers
 
 
-@pytest.fixture(scope="module")
-def dsv32_step(topo):
-    """``compiled(decode)``: the whole step of chipbench/configs/
-    deepseek-v3.2-exp-6l-ep16.json for a described v5e, each program
-    compiled once for the tests below (which turn the persistent cache off
-    around it: ``no_persistent_cache``).  The one-query kernel goes through
-    Mosaic as on the chip: conftest's interpreter switch is off."""
+def _latent_step(topo, config_file: str, pages_bytes: int):
+    """``compiled(decode)``: the whole step of a latent-family configuration
+    file for a described v5e, each program compiled once for the tests that
+    share it (which turn the persistent cache off around it:
+    ``no_persistent_cache``).  The one-query kernel goes through Mosaic as on
+    the chip: conftest's interpreter switch is off."""
     import functools
     import json
     import os
@@ -204,10 +203,10 @@ def dsv32_step(topo):
     from dynamo_tpu.models.family import RaggedBatch, family_of
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "chipbench/configs/deepseek-v3.2-exp-6l-ep16.json")) as f:
+    with open(os.path.join(root, config_file)) as f:
         body = json.load(f)
     serve = body["serve"]
-    mc = ModelConfig.from_hf_config(body, name="dsv32-compile")
+    mc = ModelConfig.from_hf_config(body, name=body["name"] + "-compile")
     one_chip = SingleDeviceSharding(topo.devices[0])
 
     def on_chip(tree):
@@ -220,7 +219,7 @@ def dsv32_step(topo):
         cache = on_chip(jax.eval_shape(
             lambda: ds.LatentKVCache.create(mc, serve["num_blocks"], serve["block_size"])))
         assert sum(a.size * a.dtype.itemsize
-                   for a in jax.tree_util.tree_leaves(cache)) == _DSV32_PAGES
+                   for a in jax.tree_util.tree_leaves(cache)) == pages_bytes
         S, PP = serve["max_batch"], serve["max_model_len"] // serve["block_size"]
         T = S if decode else serve["prefill_chunk"]
         i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)  # noqa: E731
@@ -233,6 +232,19 @@ def dsv32_step(topo):
             ).lower(params, cache, rb).compile()
 
     return compiled
+
+
+@pytest.fixture(scope="module")
+def dsv32_step(topo):
+    return _latent_step(topo, "chipbench/configs/deepseek-v3.2-exp-6l-ep16.json", _DSV32_PAGES)
+
+
+_KIMI_PAGES = 32768 * 16 * 640 * 2 * 6  # latent pages alone, six layers
+
+
+@pytest.fixture(scope="module")
+def kimi_step(topo):
+    return _latent_step(topo, "chipbench/configs/kimi-k2-6l-ep32.json", _KIMI_PAGES)
 
 
 @pytest.mark.parametrize("decode", [False, True], ids=["unified-512", "decode-16"])
@@ -270,3 +282,83 @@ def test_deepseek_v32_decode_program_attends_in_the_kernel_and_gathers_nothing(
     assert "[32768,640]" not in text and "s32[32768]" not in text
     layer_latent = 32768 * 16 * 640 * 2
     assert compiled.memory_analysis().temp_size_in_bytes < layer_latent, compiled.memory_analysis()
+
+
+def _custom_calls(text: str) -> list:
+    return [ln for ln in text.splitlines() if "tpu_custom_call" in ln and " custom-call(" in ln]
+
+
+@pytest.mark.parametrize("decode", [False, True], ids=["unified-512", "decode-16"])
+def test_kimi_k2_step_compiles_at_the_cells_shapes_without_copying_pages(
+    kimi_step, no_persistent_cache, decode
+):
+    """chipbench/configs/kimi-k2-6l-ep32.json whole: 4.24 GB of weights, 32768
+    latent pages and no others, a 512-token chunk (or 16 decode rows) against
+    832 pages a row.  The step updates the pages in place and its temporaries
+    stay under ONE layer's pages (a copy of the page array into or out of a
+    step, or a layout change before the kernel, would be at least that)."""
+    compiled = kimi_step(decode)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _KIMI_PAGES
+    assert mem.temp_size_in_bytes < _KIMI_PAGES // 6, mem
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 10e9, mem
+    # The one-query kernel is in both programs (decode rows ride a prompt
+    # step), once for the unrolled dense layer and once in the scanned layers.
+    calls = _custom_calls(compiled.as_text())
+    assert calls and all("mla_dense_decode_attention" in ln for ln in calls), calls
+
+
+@pytest.mark.parametrize("decode", [False, True], ids=["unified-512", "decode-16"])
+def test_kimi_k2_programs_sort_and_gather_nothing_of_the_context(
+    kimi_step, no_persistent_cache, decode
+):
+    """Without a selector nothing is scored, sorted or selected: no sort over
+    a row's 13312 positions (the router's top_k sorts [T, 384] and the
+    dispatch tables a few thousand pairs), no gather of ``num_blocks x 16``
+    slots nor of a row's whole context (16 rows x 832 pages), and no mask
+    operand of a row's positions."""
+    text = kimi_step(decode).as_text()
+    for ln in text.splitlines():
+        if " sort(" in ln:
+            assert "13312" not in ln.split(" sort(")[0] and "524288" not in ln, ln
+    for shape in ("[524288,640]", "s32[524288]", "[16,832,16,640]", "[13312,16,640]",
+                  "[16,13312]", "[512,13312]"):
+        assert shape not in text, shape
+
+
+
+def test_kimi_k2_prefill_attention_metric_matches_the_scopes_ops_and_no_others(
+    kimi_step, no_persistent_cache
+):
+    """``mla_dense_prefill_attn_time_share`` matches XLA's op names (the
+    harness keeps an op's name and shape, not its scope).  In the 512-token
+    program compiled for a described v5e every op the pattern matches lies
+    under ``mla_dense_prefill_attention`` (or is the compiler's own layout
+    copy of a decompressed key block, which carries no scope), and the
+    stages that cost the time are matched: another block size or compiler
+    fails HERE, not as a metric that silently reads 0."""
+    import json
+    import os
+    import re
+
+    from chipbench.trace_reduce import short_name
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "chipbench/layer_metrics/mla_dense_prefill_attn_time_share.json")) as f:
+        pattern = re.compile(json.load(f)["args"]["pattern"])
+    matched, fused = set(), False
+    for ln in kimi_step(False).as_text().splitlines():
+        if ln.endswith("{") and " -> " in ln:  # a computation's head
+            fused = "fused" in ln.split(" ", 1)[0]
+        if fused or " = " not in ln:
+            continue
+        name = short_name(ln.strip().removeprefix("ROOT "))
+        if not pattern.search(name):
+            continue
+        matched.add(name)
+        assert "mla_dense_prefill_attention" in ln or (
+            name == "copy bf16[64,1024,128]" and "copy(%convolution_bitcast_fusion" in ln), ln
+    assert {"convolution_bitcast_fusion bf16[64,1024,128]", "fusion f32[64,128,1024]",
+            "fusion f32[64,128,128]", "fusion f32[64,128]", "fusion bf16[64,16,640]",
+            "dynamic_update_slice f32[64,640,128]"} <= matched, matched
