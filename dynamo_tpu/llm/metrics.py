@@ -753,6 +753,8 @@ class SparseModelMetrics:
         self.mla: Dict[str, list] = {}  # dispatch kind -> [attended, query tokens]
         self.moe_local_pairs = 0
         self.moe_routed_tokens = 0
+        self.moe_experts_read = 0
+        self.moe_experts_held = 0
 
     def reset(self) -> None:
         self.__init__()
@@ -789,13 +791,19 @@ class SparseModelMetrics:
         return {"dsa": {k: list(v) for k, v in self.dsa.items()},
                 "mla": {k: list(v) for k, v in self.mla.items()},
                 "moe_local_pairs": self.moe_local_pairs,
-                "moe_routed_tokens": self.moe_routed_tokens}
+                "moe_routed_tokens": self.moe_routed_tokens,
+                "moe_experts_read": self.moe_experts_read,
+                "moe_experts_held": self.moe_experts_held}
 
     def add_moe(self, aux) -> None:
-        """``aux``: int array [..., 2] of (pairs on held experts, tokens routed)."""
-        a = aux.reshape(-1, 2).sum(axis=0)
+        """``aux``: int array [..., 4] of (pairs on held experts, tokens
+        routed, held experts with such a pair, experts held), each summed
+        over a step's expert layers."""
+        a = aux.reshape(-1, 4).sum(axis=0)
         self.moe_local_pairs += int(a[0])
         self.moe_routed_tokens += int(a[1])
+        self.moe_experts_read += int(a[2])
+        self.moe_experts_held += int(a[3])
 
     def render(self, prefix: str = "dynamo_tpu") -> str:
         if not self.dsa and not self.mla and not self.moe_routed_tokens:
@@ -827,6 +835,12 @@ class SparseModelMetrics:
              self.moe_local_pairs),
             ("moe_routed_tokens_total",
              "Tokens routed, counted once per expert layer", self.moe_routed_tokens),
+            ("moe_experts_read_total",
+             "Held experts with a landed pair of a real token: the experts whose weights a "
+             "step reads, summed over expert layers and steps", self.moe_experts_read),
+            ("moe_experts_held_total",
+             "Experts held, summed over the same expert layers and steps",
+             self.moe_experts_held),
         ):
             lines.append(f"# HELP {prefix}_{name} {help_}")
             lines.append(f"# TYPE {prefix}_{name} counter")

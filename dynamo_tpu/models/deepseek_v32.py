@@ -227,32 +227,26 @@ def gate(x: jnp.ndarray, lp: Params, config: ModelConfig):
     return chosen, w * config.routed_scaling_factor
 
 
-def moe_block(x: jnp.ndarray, lp: Params, config: ModelConfig):
+EXPERT_LEAVES = tuple(n + tail for n in ("moe_gate", "moe_up", "moe_down") for tail in ("", "_scale"))
+
+
+def moe_block(x: jnp.ndarray, lp: Params, config: ModelConfig, real=None, layer=None):
     """Shared expert + the routed experts chosen AND held.  Returns (y [T, D],
-    how many (token, expert) pairs landed on held experts [] int32)."""
+    how many (token, expert) pairs of REAL tokens landed on each held expert
+    [E] int32).  ``real`` [T] bool: False for a padding token, whose pairs are
+    no pairs (None: every token is real).  With ``layer`` the expert leaves
+    of ``lp`` are the stacked ones [L, E, ...] and it picks the layer: the
+    grouped matmul reads a block of the stacked leaf in place, where a slice
+    of a layer would be a copy of all its experts."""
     chosen, w = gate(x, lp, config)
-    lo = config.ep_rank * config.num_experts
-    local = chosen - lo
+    local = chosen - config.ep_rank * config.num_experts
     here = (local >= 0) & (local < config.num_experts)
-    # A held expert is routed T * K / router_experts rows on average (16 of a
-    # 512-token step): tables of T rows each (dropless by construction) made
-    # every expert compute 32 times its share, 34 of the step's 108 ms (chip
-    # run, PR 28).  So: tables of a quarter of T, and the full tables only in
-    # a step where some expert really is routed more.  Both are exact.
-    T, E = x.shape[0], config.num_experts
-    small = max(8, T // 4)
-    if small >= T:
-        routed = expert_dispatch(x, local, w, lp, E, T, valid=here)
-    else:
-        load = jnp.sum(here[:, :, None] & (local[:, :, None] == jnp.arange(E)), axis=(0, 1))
-        routed = jax.lax.cond(
-            jnp.max(load) <= small,
-            lambda: expert_dispatch(x, local, w, lp, E, small, valid=here),
-            lambda: expert_dispatch(x, local, w, lp, E, T, valid=here),
-        )
+    if real is not None:
+        here &= real[:, None]
+    routed, load = expert_dispatch(x, local, w, lp, config.num_experts, valid=here, layer=layer)
     shared = mlp(x, {"w_" + k[len("shared_"):]: v for k, v in lp.items()
                      if k.startswith("shared_")})
-    return routed + shared, here
+    return routed + shared, load
 
 
 def _rope_head(x, positions, inv_freq, dr: int):
@@ -290,8 +284,9 @@ def forward_ragged(
 ) -> Tuple[jnp.ndarray, LatentKVCache, Any]:
     """The unified step of models/llama.py for this family: returns (logits
     [S, V] of each row's last token, the updated cache, aux).  ``aux`` is
-    [2] int32: (routed pairs that landed on held experts, tokens routed), over
-    the step's real tokens and all MoE layers; with ``return_selection`` it
+    [4] int32: (routed pairs that landed on held experts, tokens routed, held
+    experts with such a pair: the experts READ, experts held), over the
+    step's real tokens and all MoE layers; with ``return_selection`` it
     is instead the list of S_t per layer (decode: positions [S, k]; else a
     mask [T, PP * ps]; None for a model without a selector, whose S_t is
     every position up to t)."""
@@ -401,19 +396,20 @@ def forward_ragged(
         h = h + a
         x = rms_norm(h, lp["mlp_norm"], eps)
         if is_moe:
-            y, here = moe_block(x, lp, c)
-            pairs = jnp.sum(here & real[:, None], dtype=jnp.int32)
+            y, load = moe_block(x, lp, c, real, layer=l - Ld)
+            counts = jnp.stack([jnp.sum(load), jnp.sum(load > 0, dtype=jnp.int32)])
         else:
-            y, pairs = mlp(x, lp), jnp.zeros((), jnp.int32)
-        return h + y, lat, idx, pairs, sel
+            y, counts = mlp(x, lp), jnp.zeros((2,), jnp.int32)
+        return h + y, lat, idx, counts, sel
 
     def at_layer(group: str, i):
-        return jax.tree_util.tree_map(lambda a: a[i], params[group])
+        """Layer i's leaves; the expert leaves whole (``moe_block``)."""
+        return {k: a if k in EXPERT_LEAVES else a[i] for k, a in params[group].items()}
 
     h = embed_lookup(params, rb.token_ids, jnp.dtype(c.dtype))
     lat = cache.latent.reshape((L * P_layer,) + cache.latent.shape[2:])
     idx = cache.index.reshape((L * P_layer,) + cache.index.shape[2:]) if selector else None
-    pairs = jnp.zeros((), jnp.int32)
+    counts = jnp.zeros((2,), jnp.int32)  # pairs landed, experts read
     sels = []
     for l in range(Ld):  # the leading dense layers: few, so unrolled
         h, lat, idx, _, sel = layer(h, lat, idx, {**at_layer("layers", l), **at_layer("dense", l)},
@@ -425,7 +421,7 @@ def forward_ragged(
         for l in range(Ld, L):
             h, lat, idx, p, sel = layer(
                 h, lat, idx, {**at_layer("layers", l), **at_layer("moe", l - Ld)}, l, True)
-            pairs += p
+            counts += p
             sels.append(sel)
     elif L > Ld:
         # The scan carries only the layer's number and indexes the stacked
@@ -433,13 +429,13 @@ def forward_ragged(
         # of attention weights in every step (slice s8[5,16384,7168], first
         # chip profile of PR 28).
         def body(carry, l):
-            h, lat, idx, pairs = carry
+            h, lat, idx, counts = carry
             lp = {**at_layer("layers", l), **at_layer("moe", l - Ld)}
             h, lat, idx, p, _ = layer(h, lat, idx, lp, l, True)
-            return (h, lat, idx, pairs + p), None
+            return (h, lat, idx, counts + p), None
 
-        (h, lat, idx, pairs), _ = jax.lax.scan(
-            body, (h, lat, idx, pairs), jnp.arange(Ld, L, dtype=jnp.int32))
+        (h, lat, idx, counts), _ = jax.lax.scan(
+            body, (h, lat, idx, counts), jnp.arange(Ld, L, dtype=jnp.int32))
 
     h = rms_norm(h, params["final_norm"], eps)
     rows = jnp.clip(rb.cu_q_lens[1:] - 1, 0, T - 1)
@@ -449,4 +445,5 @@ def forward_ragged(
     if return_selection:
         return logits, new_cache, sels
     tokens = jnp.sum(real, dtype=jnp.int32) * (L - Ld)
-    return logits, new_cache, jnp.stack([pairs, tokens])
+    held = jnp.asarray(c.num_experts * (L - Ld), jnp.int32)
+    return logits, new_cache, jnp.stack([counts[0], tokens, counts[1], held])

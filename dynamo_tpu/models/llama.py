@@ -406,7 +406,7 @@ def forward_ragged(
         h = h + o
         x = rms_norm(h, lp["mlp_norm"], config.rms_norm_eps)
         if config.is_moe:
-            h = h + moe_mlp(x[None], lp, config)[0]
+            h = h + moe_mlp(x[None], lp, config, mesh)[0]
         else:
             h = h + mlp(x, lp)
         return (h, pages), None
@@ -498,7 +498,7 @@ def forward_sp_prefill(
         h = h + linear(attn.reshape(Tg, H * hd), lp, "wo")
         x = rms_norm(h, lp["mlp_norm"], config.rms_norm_eps)
         if config.is_moe:
-            h = h + moe_mlp(x[None], lp, config)[0]
+            h = h + moe_mlp(x[None], lp, config, mesh)[0]
         else:
             h = h + mlp(x, lp)
         # pages layout rows: K at even combined-head indices, V at odd

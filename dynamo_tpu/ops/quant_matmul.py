@@ -22,11 +22,16 @@ import jax
 import jax.numpy as jnp
 
 
-def quantize_rows(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+def quantize_rows(x: jnp.ndarray, across=()) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Dynamic symmetric per-row int8: returns (x_q int8, row_scale f32
-    [..., 1]).  Rows of zeros get scale 1e-9 and quantize to zeros."""
+    [..., 1]).  Rows of zeros get scale 1e-9 and quantize to zeros.
+    ``across``: the mesh axes a row is split over where each shard holds a
+    slice of it (models/moe.py under ``tp``); the scale is the whole row's."""
     xf = x.astype(jnp.float32)
-    ax = jnp.maximum(jnp.max(jnp.abs(xf), axis=-1, keepdims=True) / 127.0, 1e-9)
+    amax = jnp.max(jnp.abs(xf), axis=-1, keepdims=True)
+    if across:
+        amax = jax.lax.pmax(amax, across)
+    ax = jnp.maximum(amax / 127.0, 1e-9)
     xq = jnp.clip(jnp.round(xf / ax), -127, 127).astype(jnp.int8)
     return xq, ax
 
@@ -45,8 +50,10 @@ def qdot(x: jnp.ndarray, w_q: jnp.ndarray, scale: jnp.ndarray, out_dtype=None):
 
 
 def qdot_batched(x: jnp.ndarray, w_q: jnp.ndarray, scale: jnp.ndarray, out_dtype=None):
-    """Batched variant for MoE experts: x [E, C, K] float, w_q [E, K, N]
-    int8, scale [E, N] f32 → [E, C, N] (einsum "eck,ekn->ecn")."""
+    """Batched variant over experts' tables: x [E, C, K] float, w_q [E, K, N]
+    int8, scale [E, N] f32 → [E, C, N] (einsum "eck,ekn->ecn").  The serving
+    path groups rows by expert instead (ops/grouped_matmul.py, the same
+    contract a row); this is what the tests hold that to."""
     xq, ax = quantize_rows(x)
     acc = jax.lax.dot_general(
         xq, w_q, (((2,), (1,)), ((0,), (0,))),
@@ -54,15 +61,3 @@ def qdot_batched(x: jnp.ndarray, w_q: jnp.ndarray, scale: jnp.ndarray, out_dtype
     )
     out = acc.astype(jnp.float32) * ax * scale[:, None, :]
     return out.astype(out_dtype or x.dtype)
-
-
-def expert_linear(x: jnp.ndarray, lp, name: str, out_dtype=None):
-    """Per-expert ``einsum("ecd,edf->ecf", x, lp[name])`` dispatching on the
-    quant scale leaf — the batched sibling of models.llama.linear, so the
-    MoE and dense forwards share one quantization contract."""
-    w = lp[name]
-    s = lp.get(name + "_scale")
-    if s is None:
-        r = jnp.einsum("ecd,edf->ecf", x, w)
-        return r.astype(out_dtype) if out_dtype is not None else r
-    return qdot_batched(x, w, s, out_dtype=out_dtype)

@@ -515,9 +515,13 @@ def test_the_engine_serves_it_with_prefix_reuse_and_counts(engine):
         assert set(dsa) >= {"unified"} and all(0 < v[1] <= v[0] for v in dsa.values())
         assert sum(v[1] for v in dsa.values()) < sum(v[0] for v in dsa.values())  # selection is live
         assert 0 < sparse_model_metrics.moe_local_pairs <= 2 * sparse_model_metrics.moe_routed_tokens
+        # an expert is read for a landed pair, and only for one
+        assert 0 < sparse_model_metrics.moe_experts_read <= min(
+            sparse_model_metrics.moe_local_pairs, sparse_model_metrics.moe_experts_held)
         text = sparse_model_metrics.render()
         for name in ("dsa_context_positions_total", "dsa_selected_positions_total",
-                     "moe_local_pairs_total", "moe_routed_tokens_total"):
+                     "moe_local_pairs_total", "moe_routed_tokens_total",
+                     "moe_experts_read_total", "moe_experts_held_total"):
             assert f"dynamo_tpu_{name}" in text
         await engine.close()
 
@@ -536,6 +540,49 @@ def test_dsa_account_arithmetic(engine):
     assert sparse_model_metrics.dsa["unit"] == [want_ctx, want_sel]
     sparse_model_metrics.reset()
     assert sparse_model_metrics.render() == ""
+
+
+@pytest.mark.parametrize("shape", [(4,), (4, 4), (2, 3, 4)], ids=["a-step", "a-fused-chunk", "stacked"])
+def test_expert_account_arithmetic(shape):
+    """``add_moe`` with the wider ``aux`` (pairs, tokens, experts read,
+    experts held), whatever leads its last axis; ``summary``, ``render`` and
+    ``reset`` carry the two new counters beside the old."""
+    from dynamo_tpu.llm.metrics import SparseModelMetrics
+
+    m = SparseModelMetrics()
+    assert m.render() == ""
+    aux = np.arange(int(np.prod(shape)), dtype=np.int32).reshape(shape)
+    m.add_moe(aux)
+    m.add_moe(aux)
+    want = 2 * aux.reshape(-1, 4).sum(axis=0)
+    assert [m.moe_local_pairs, m.moe_routed_tokens, m.moe_experts_read, m.moe_experts_held] == list(want)
+    summary = m.summary()
+    assert (summary["moe_experts_read"], summary["moe_experts_held"]) == (want[2], want[3])
+    assert (summary["moe_local_pairs"], summary["moe_routed_tokens"]) == (want[0], want[1])
+    text = m.render()
+    for name, v in zip(("moe_local_pairs_total", "moe_routed_tokens_total",
+                        "moe_experts_read_total", "moe_experts_held_total"), want):
+        assert f"\ndynamo_tpu_{name} {v}\n" in text and f"# TYPE dynamo_tpu_{name} counter" in text
+    m.reset()
+    assert m.render() == "" and m.summary()["moe_experts_read"] == 0 == m.moe_experts_held
+
+
+def test_the_forward_counts_the_experts_read_on_the_device(model):
+    """``aux`` of one step: experts read = held experts with a landed pair of
+    a real token, per expert layer; held = experts x expert layers; a padding
+    token's pairs read nothing."""
+    cfg, params, toks, _, _ = model
+    cache = ds.LatentKVCache.create(cfg, NPAGES, PS, dtype=jnp.float32)
+    table = np.arange(3, 3 + PP).astype(np.int32)
+    layers = cfg.num_layers - cfg.first_k_dense_replace
+    reads = []
+    for n_real in (16, 1, 0):
+        _, _, aux = ds.forward_ragged(params, cfg, batch(toks, table, 0, n_real, 16), cache)
+        pairs, tokens, read, held = (int(v) for v in np.asarray(aux))
+        assert held == cfg.num_experts * layers and tokens == n_real * layers
+        assert read <= min(pairs, held) and (read > 0) == (pairs > 0)
+        reads.append(read)
+    assert reads[0] > reads[2] == 0  # 16 tokens land pairs; 16 padding tokens read nothing
 
 
 def test_quantized_draw_and_dequantize_round_trip():

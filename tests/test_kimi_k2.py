@@ -418,9 +418,12 @@ def test_the_engine_serves_it_with_prefix_reuse_and_counts(engine):
         assert not sparse_model_metrics.dsa and set(mla) >= {"unified"}
         assert all(v[0] >= v[1] > 0 for v in mla.values())
         assert 0 < sparse_model_metrics.moe_local_pairs <= 2 * sparse_model_metrics.moe_routed_tokens
+        assert 0 < sparse_model_metrics.moe_experts_read <= min(
+            sparse_model_metrics.moe_local_pairs, sparse_model_metrics.moe_experts_held)
         text = sparse_model_metrics.render()
         for name in ("mla_attended_positions_total", "mla_query_tokens_total",
-                     "moe_local_pairs_total", "moe_routed_tokens_total"):
+                     "moe_local_pairs_total", "moe_routed_tokens_total",
+                     "moe_experts_read_total", "moe_experts_held_total"):
             assert f"dynamo_tpu_{name}" in text
         assert "dsa_" not in text
         counts = engine.dispatch_summary()["model"]

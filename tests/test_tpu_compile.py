@@ -192,8 +192,9 @@ def _latent_step(topo, config_file: str, pages_bytes: int):
     """``compiled(decode)``: the whole step of a latent-family configuration
     file for a described v5e, each program compiled once for the tests that
     share it (which turn the persistent cache off around it:
-    ``no_persistent_cache``).  The one-query kernel goes through Mosaic as on
-    the chip: conftest's interpreter switch is off."""
+    ``no_persistent_cache``).  The one-query kernel and the grouped expert
+    matmul go through Mosaic as on the chip: conftest's interpreter switch is
+    off, and nothing is patched."""
     import functools
     import json
     import os
@@ -303,9 +304,11 @@ def test_kimi_k2_step_compiles_at_the_cells_shapes_without_copying_pages(
     assert mem.temp_size_in_bytes < _KIMI_PAGES // 6, mem
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 10e9, mem
     # The one-query kernel is in both programs (decode rows ride a prompt
-    # step), once for the unrolled dense layer and once in the scanned layers.
+    # step), once for the unrolled dense layer and once in the scanned layers;
+    # the only other kernel is the grouped expert matmul.
     calls = _custom_calls(compiled.as_text())
-    assert calls and all("mla_dense_decode_attention" in ln for ln in calls), calls
+    assert [ln for ln in calls if "mla_dense_decode_attention" in ln], calls
+    assert all("mla_dense_decode_attention" in ln or "moe_grouped_matmul" in ln for ln in calls), calls
 
 
 @pytest.mark.parametrize("decode", [False, True], ids=["unified-512", "decode-16"])
@@ -325,6 +328,96 @@ def test_kimi_k2_programs_sort_and_gather_nothing_of_the_context(
                   "[16,13312]", "[512,13312]"):
         assert shape not in text, shape
 
+
+
+@pytest.mark.parametrize("model,E", [("kimi", 12), ("dsv32", 16)])
+def test_latent_decode_programs_read_the_held_experts_through_the_grouped_matmul_alone(
+    kimi_step, dsv32_step, no_persistent_cache, model, E
+):
+    """The decode program at the cell's shapes: the routed experts go through
+    the Pallas call ``moe_grouped_matmul`` (gate with up, and down: two calls
+    an expert layer, five layers), whose operand is the STACKED leaf as the
+    program was given it; nothing else touches an expert leaf, whole or a
+    layer of it (a dot or a fusion over ``s8[E,7168,2048]`` would read every
+    held expert; a slice of a layer before the call would copy them), and no
+    ``conditional`` is left of the two table sizes."""
+    text = (kimi_step if model == "kimi" else dsv32_step)(True).as_text()
+    calls = [ln for ln in _custom_calls(text) if "moe_grouped_matmul" in ln]
+    assert len(calls) == 10, calls
+    leaf = (f"s8[5,{E},7168,2048]", f"s8[5,{E},2048,7168]")
+    layer = (f"s8[{E},7168,2048]", f"s8[{E},2048,7168]", f"s8[1,{E},7168,2048]",
+             f"s8[1,{E},2048,7168]")
+    assert all(any(shape in ln for shape in leaf) for ln in calls), calls
+    entry = text[text.index("ENTRY "):]
+    for ln in entry.splitlines():
+        if " = " not in ln or " parameter(" in ln or "moe_grouped_matmul" in ln:
+            continue
+        assert not any(shape in ln for shape in leaf + layer), ln
+    assert not any(shape in text for shape in layer)
+    assert " conditional(" not in text
+
+
+@pytest.mark.parametrize("model", ["kimi", "dsv32"])
+def test_latent_prompt_programs_group_the_experts_rows_in_the_kernel_too(
+    kimi_step, dsv32_step, no_persistent_cache, model
+):
+    """The 512-token program: the same two calls, in the loop over chunks of
+    row tiles inside the scan over layers, on the stacked leaves (passed
+    through the loops, never sliced to a layer)."""
+    text = (kimi_step if model == "kimi" else dsv32_step)(False).as_text()
+    calls = [ln for ln in _custom_calls(text) if "moe_grouped_matmul" in ln]
+    assert len(calls) == 2 and all("s8[5," in ln for ln in calls), calls
+    E = 12 if model == "kimi" else 16
+    assert not any(shape in text for shape in (f"s8[{E},7168,2048]", f"s8[{E},2048,7168]"))
+
+
+@pytest.mark.parametrize("quant", [True, False], ids=["int8", "bfloat16"])
+def test_sharded_experts_stay_where_they_are_under_the_grouped_matmul(
+    topo, no_persistent_cache, monkeypatch, quant
+):
+    """The llama family's ``moe_mlp`` on the described 2x2 v5e, its leaves
+    placed as parallel/mesh.py places them (E over ``ep``, the intermediate
+    width over ``tp``): each chip's ``moe_grouped_matmul`` gets ITS shard of
+    the leaves, no collective moves an expert leaf (a Pallas call left to
+    GSPMD would have had them all gathered to every chip), and what crosses
+    chips is the tokens' partial sums (and, int8, a row scale)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from dynamo_tpu.models import moe
+    from dynamo_tpu.models.config import get_config
+    from dynamo_tpu.parallel import param_pspecs
+
+    monkeypatch.setenv("DYN_PALLAS_INTERPRET", "0")
+    E, K, D, F, T = 8, 2, 1024, 2048, 64
+    cfg = get_config("debug-tiny-moe").with_overrides(
+        hidden_size=D, intermediate_size=F, num_experts=E, num_experts_per_token=K)
+    mesh = Mesh(np.array(topo.devices).reshape(1, 2, 1, 2), ("dp", "ep", "sp", "tp"))
+    specs = param_pspecs(cfg)["layers"]
+    wdt = jnp.int8 if quant else jnp.bfloat16
+
+    def leaf(name, shape, dtype):  # one layer's: the specs' leading L stripped
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, P(*specs[name][1:])))
+
+    lp = {"router": leaf("router", (D, E), jnp.bfloat16),
+          "moe_gate": leaf("moe_gate", (E, D, F), wdt), "moe_up": leaf("moe_up", (E, D, F), wdt),
+          "moe_down": leaf("moe_down", (E, F, D), wdt)}
+    if quant:
+        lp.update({"moe_gate_scale": leaf("moe_gate_scale", (E, F), jnp.float32),
+                   "moe_up_scale": leaf("moe_up_scale", (E, F), jnp.float32),
+                   "moe_down_scale": leaf("moe_down_scale", (E, D), jnp.float32)})
+    x = jax.ShapeDtypeStruct((1, T, D), jnp.bfloat16, sharding=NamedSharding(mesh, P()))
+    text = jax.jit(lambda x, lp: moe.moe_mlp(x, lp, cfg, mesh)).lower(x, lp).compile().as_text()
+    calls = [ln for ln in _custom_calls(text) if "moe_grouped_matmul" in ln]
+    t = "s8" if quant else "bf16"
+    assert calls and all(f"{t}[1,{E // 2},{D},{F // 2}]" in ln or f"{t}[1,{E // 2},{F // 2},{D}]" in ln
+                         for ln in calls), calls
+    moved = [ln for ln in text.splitlines()
+             if any(op in ln for op in (" all-gather(", " all-to-all(", " collective-permute("))
+             and f"{t}[" in ln and (f",{D}," in ln or f",{F // 2}," in ln or f",{F}," in ln)]
+    assert not moved, moved
+    assert f"{t}[{E},{D},{F}]" not in text and f"{t}[1,{E},{D},{F}]" not in text
+    assert " all-reduce(" in text
 
 
 def test_kimi_k2_prefill_attention_metric_matches_the_scopes_ops_and_no_others(
