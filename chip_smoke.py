@@ -1161,6 +1161,327 @@ def _parity_latent(rehearse: bool, tag: str, par: dict, limits: dict) -> None:
     print(json.dumps(dev), flush=True)
 
 
+# ------------------------------------------------------------ LFM2 parity
+# `--child parity-lfm2`: chipbench/configs/lfm2-8b-a1b.json at its published
+# widths and full depth under assist-shared's shapes, against the float32
+# reference (dynamo_tpu/models/reference/lfm2_moe.py) on the same dequantised
+# weights, computed layer by layer in query blocks.
+#
+# What runs.  (1) The ENGINE'S OWN programs decide every token: row A
+# prefills a 3000-token prompt COLD in 512-token chunks (`engine._step_fn`),
+# row B the same prompt behind a 2048-token PREFIX HIT (its table names A's
+# first 128 pages: its convolutions start from the 128th page's entry), then
+# both decode 64 tokens side by side in 16 fused chunks (`engine._multi_fn`,
+# the sampled token fed back on the device).  B's tokens and top-20
+# log-probabilities must EQUAL A's (`hit_vs_cold`: the same programs over the
+# same values; the benchmark's probe compares exactly those two paths).
+# (2) The check's own jit of the same forward with the engine's options,
+# teacher-forced on A's tokens into fresh pages, gives what the engine's
+# programs do not return: whole logits, at every chunk's end and every decode
+# step; the engine's top-20 log-probabilities are read against them
+# (`engine_link`).  (3) Those logits against the reference at the same 70
+# positions, as the root-mean-square and the maximum over all logits.
+# Control, teacher-forced on the SAME tokens, must fail: the page entry
+# dropped (zeros read) wherever a run starts on a page boundary: every
+# chunk's first token and every sixteenth decode step lose u_{t-1}, u_{t-2}
+# in 18 layers.
+# (4) The same comparison over ONE PERIOD of the layer pattern (the first
+# ``shallow_layers`` 4 layers: convolution, convolution, attention,
+# convolution; both dense feed-forwards and two expert layers of all 32
+# experts) at the published widths, the same weights, tokens, pages and
+# programs' options: ``shallow_*``.  Seeded random blocks are each as large
+# as the stream they add to, so 24 of them amplify every rounding (and every
+# expert choice a rounding flips) until the whole model's logits read 0.4
+# against float32 where a wrong position reads 1.2; one period is where an
+# arithmetic fault in a kind of layer or in a kernel shows against W8A8's own
+# noise, so its limits are the tight ones.
+# Limits and the readings they come from: PERF.md section 6.
+LFM2 = {"config": "chipbench/configs/lfm2-8b-a1b.json", "prefix": 2048, "prompt": 3000,
+        "decode": 64, "num_blocks": 1024, "q_block": 512, "shallow_layers": 4}
+LFM2_REHEARSAL = dict(LFM2, prefix=128, prompt=200, decode=8, num_blocks=128, q_block=64)
+# The readings of each comparison; the control (page entries dropped) reads the
+# same names behind "dropped_state_" and must pass one limit of each at least
+# (it passes every one of them on every seed).
+LFM2_WHOLE = ("rms_err", "rel_err", "rms_err_worst_position", "engine_link")
+LFM2_ONE_PERIOD = ("shallow_rms_err", "shallow_rel_err", "shallow_rms_err_worst_position")
+# Each limit lies between the largest the system read and the least the control
+# read on the chip over seeds 28 / 29 / 30 (PR 36; the table is in PERF.md
+# section 6), near their geometric mean: system | control.
+LFM2_LIMITS = {  # the most each may read
+    "rms_err": 0.47,  # 0.393-0.415 | 0.522-0.544: four or five positions of 70 lose their state
+    "rel_err": 0.72,  # 0.456-0.567 | 0.926-1.057: the largest single logit error
+    "rms_err_worst_position": 0.85,  # 0.584-0.619 | 1.188-1.198: a position whose state was dropped
+    "engine_link": 0.52,  # 0.238-0.372 | 0.738-0.841: the engine's own top-20 against these logits
+    "shallow_rms_err": 0.17,  # 0.088-0.094 | 0.319-0.327: W8A8 and bfloat16 over four layers
+    "shallow_rel_err": 0.35,  # 0.151-0.153 | 0.816-0.951
+    "shallow_rms_err_worst_position": 0.42,  # 0.169-0.183 | 1.031-1.098
+    "hit_vs_cold": 0.0,  # the same programs over the same values: equal to the bit
+}
+
+
+def child_parity_lfm2(rehearse: bool) -> None:
+    t0 = time.time()
+    dev = child_device(rehearse)
+    import types
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dynamo_tpu.engine.config import EngineConfig
+    from dynamo_tpu.engine.engine import TpuEngine
+    from dynamo_tpu.models import lfm2
+    from dynamo_tpu.models.config import ModelConfig, register_config
+    from dynamo_tpu.models.family import RaggedBatch
+    from dynamo_tpu.models.reference import lfm2_moe as ref
+
+    par = LFM2_REHEARSAL if rehearse else LFM2
+    seed = int(os.environ.get("DSV32_PARITY_SEED", "28"))
+    with open(os.path.join(HERE, par["config"])) as f:
+        body = json.load(f)
+    serve = dict(body["serve"])
+    if rehearse:
+        hf = dict(body["rehearsal"]["model"])
+        serve.update(body["rehearsal"]["serve"])
+    else:
+        hf = {k: v for k, v in body.items() if k not in (
+            "name", "source", "serve", "chips", "reduced", "assumed", "stands_for",
+            "rehearsal", "notes")}
+    mc = register_config(ModelConfig.from_hf_config(hf, name="parity-lfm2"))
+    kv_scale = serve.get("kv_scale", 1.0)
+    cfg = EngineConfig(
+        model=mc.name, block_size=serve["block_size"], num_blocks=par["num_blocks"],
+        max_batch=serve["max_batch"], max_model_len=serve["max_model_len"],
+        prefill_chunk=serve["prefill_chunk"], decode_steps=serve["decode_steps"],
+        dtype=serve["dtype"], cache_dtype=serve["kv_cache_dtype"],
+        kv_scale=kv_scale if kv_scale == "auto" else float(kv_scale),
+        weight_quant=serve["weight_quant"], seed=20260900 + seed)
+    engine = TpuEngine(cfg)
+    mc, fam = engine.model_config, engine.family
+    emit("lfm2_engine", t0, **dev, attn_impl=engine.attn_impl, decode_kernel=engine.decode_kernel,
+         prefill_kernel=engine.prefill_kernel,
+         hbm=(jax.local_devices()[0].memory_stats() or {}).get("bytes_in_use"))
+
+    bs, S, PP, chunk = cfg.block_size, cfg.max_batch, cfg.max_blocks_per_seq, cfg.prefill_chunk
+    steps = cfg.decode_steps
+    n_prefix, n_prompt, n_dec = par["prefix"], par["prompt"], par["decode"]
+    assert n_prefix % chunk == 0 and n_dec % steps == 0 and n_prefix % bs == 0
+    T = n_prompt + n_dec
+    own = -(-T // bs) + 1  # pages a row needs
+    assert 4 * own <= cfg.num_blocks and own <= PP
+    rng = np.random.default_rng(seed)
+    tokens = np.zeros((T + 1,), np.int32)
+    tokens[:n_prompt] = rng.integers(16, mc.vocab_size, n_prompt)
+
+    def table_of(first, shared=0):
+        t = np.zeros((PP,), np.int32)
+        t[:shared] = np.arange(shared)  # row A's pages
+        t[shared:own] = first + np.arange(own - shared)
+        return t
+
+    tab_a, tab_b = table_of(0), table_of(own, n_prefix // bs)
+    tab_c, tab_d = table_of(2 * own), table_of(3 * own)
+    samp = engine._sampling_arrays([])._replace(need_logprobs=np.asarray(True))
+    chunks = lambda a: [(s, min(chunk, n_prompt - s)) for s in range(a, n_prompt, chunk)]
+
+    def prefill_batch(table, start, n):
+        seq = types.SimpleNamespace(prompt=[int(t) for t in tokens[:start + n]], output=[],
+                                    block_ids=[int(x) for x in table], adapter_slot=-1)
+        return engine._build_ragged([(seq, start, n)])
+
+    # ---- (1) the engine's own programs: A cold, B behind the hit, both decode
+    t1 = time.time()
+    params, cache = engine.params, engine.cache
+    top = {"A": {}, "B": {}}  # position -> (token, top ids, their log-probabilities)
+    for name, table, start in (("A", tab_a, 0), ("B", tab_b, n_prefix)):
+        for a, n in chunks(start):
+            out, cache = engine._step_fn(params, cache, prefill_batch(table, a, n), samp)
+            top[name][a + n - 1] = (int(np.asarray(out.tokens)[0]), np.asarray(out.top_ids)[0],
+                                    np.asarray(out.top_logprobs)[0])
+    tokens[n_prompt] = top["A"][n_prompt - 1][0]
+    pos0 = np.full((S,), -1, np.int32)
+    tables, limits = np.zeros((S, PP), np.int32), np.zeros((S,), np.int32)
+    tok0 = np.zeros((S,), np.int32)
+    for i, (name, table) in enumerate((("A", tab_a), ("B", tab_b))):
+        pos0[i], tables[i], limits[i] = n_prompt, table, own * bs
+        tok0[i] = top[name][n_prompt - 1][0]
+    carry = (tok0, samp.steps, samp.counts)
+    for d in range(n_dec // steps):
+        outs, last, steps_f, counts_f, cache = engine._multi_fn(
+            params, cache, *carry, pos0 + np.where(pos0 >= 0, d * steps, 0), tables, limits, samp)
+        carry = (last, steps_f, counts_f)
+        toks, ids, lps = (np.asarray(x) for x in (outs.tokens, outs.top_ids, outs.top_logprobs))
+        for k in range(steps):
+            p = n_prompt + d * steps + k
+            tokens[p + 1] = toks[k, 0]
+            top["A"][p] = (int(toks[k, 0]), ids[k, 0], lps[k, 0])
+            top["B"][p] = (int(toks[k, 1]), ids[k, 1], lps[k, 1])
+    shared = sorted(set(top["A"]) & set(top["B"]))
+    hit_vs_cold = max(float(np.abs(top["A"][p][2] - top["B"][p][2]).max()) for p in shared)
+    hit_same = sum(int(top["A"][p][0] == top["B"][p][0]
+                       and np.array_equal(top["A"][p][1], top["B"][p][1])) for p in shared)
+    emit("lfm2_engine_programs", t1, positions=len(shared), hit_same_tokens_and_top20=hit_same,
+         hit_vs_cold_nats=hit_vs_cold)
+
+    # ---- (2) whole logits by the check's jit, teacher-forced on A's tokens
+    def forward_of(config):
+        return jax.jit(
+            lambda p, c, rb, dec, drop: fam.forward(
+                p, config, rb, c, decode=dec, attn_impl=engine.attn_impl,
+                kv_scale=engine.kv_scale, decode_kernel=engine.decode_kernel,
+                prefill_kernel=engine.prefill_kernel, drop_state_at_page_boundary=drop)[:2],
+            static_argnums=(3, 4), donate_argnums=1)
+
+    def decode_batch(table, p):
+        t, ps_, kv = (np.zeros((S,), np.int32) for _ in range(3))
+        sl = np.full((S,), -1, np.int32)
+        tb = np.zeros((S, PP), np.int32)
+        t[0], ps_[0], kv[0], tb[0] = tokens[p], p, p + 1, table
+        sl[0] = int(table[p // bs]) * bs + p % bs
+        return RaggedBatch(t, ps_, sl, kv, tb, np.arange(S + 1, dtype=np.int32),
+                           np.asarray([S], np.int32))
+
+    def system(fwd, params, cache, table, drop):
+        """Logits [compared positions, V] of one cold pass into ``table``."""
+        out = []
+        for a, n in chunks(0):
+            logits, cache = fwd(params, cache, prefill_batch(table, a, n), False, drop)
+            out.append(np.asarray(logits, np.float32)[0])
+        for p in range(n_prompt, T):
+            logits, cache = fwd(params, cache, decode_batch(table, p), True, drop)
+            out.append(np.asarray(logits, np.float32)[0])
+        return np.stack(out), cache
+
+    compare = np.asarray([a + n - 1 for a, n in chunks(0)] + list(range(n_prompt, T)))
+    fwd = forward_of(mc)
+    t1 = time.time()
+    sys_logits, cache = system(fwd, params, cache, tab_c, False)
+    emit("lfm2_system", t1, positions=len(compare))
+    t1 = time.time()
+    ctl_logits, cache = system(fwd, params, cache, tab_d, True)
+    emit("lfm2_dropped_state", t1)
+
+    # ---- (4) one period of the pattern: the leading layers of the same weights
+    t1 = time.time()
+    n_sh = par["shallow_layers"]
+    mc_sh = mc.with_overrides(num_layers=n_sh, layer_types=mc.layer_types[:n_sh])
+    kept = dict(zip(("conv", "attn", "dense", "moe"), lfm2.layer_counts(mc_sh)), layers=n_sh)
+    params_sh = {g: {k: a[:kept[g]] for k, a in v.items()} if g in kept else v
+                 for g, v in params.items()}
+    cache_sh = fam.create_cache(mc_sh, cfg.num_blocks, bs, dtype=cache.pages.dtype)
+    fwd_sh = forward_of(mc_sh)
+    sys_sh, cache_sh = system(fwd_sh, params_sh, cache_sh, tab_c, False)
+    ctl_sh, cache_sh = system(fwd_sh, params_sh, cache_sh, tab_d, True)
+    emit("lfm2_shallow", t1, layers=n_sh, kinds=list(mc_sh.layer_types))
+
+    # ---- the engine leaves the chip; its weights stay on the host
+    host_params = jax.tree_util.tree_map(np.asarray, engine.params)
+    del cache, params, cache_sh, params_sh
+    engine.params = engine.cache = None
+    engine = None
+
+    # ---- (3) the reference, layer by layer, on the dequantised weights
+    t1 = time.time()
+
+    def f32_leaf(group, name, i=None):
+        leaves = host_params if group == "top" else host_params[group]
+        w = leaves[name] if i is None else leaves[name][i]
+        w = jnp.asarray(w, jnp.float32)
+        if name + "_scale" in leaves:
+            sc = leaves[name + "_scale"] if i is None else leaves[name + "_scale"][i]
+            axis = lfm2.QUANT_AXES[group][name] - (0 if i is None else 1)
+            w = w * jnp.expand_dims(jnp.asarray(sc), axis)
+        return w
+
+    def layer_f32(l):
+        kinds = mc.layer_types
+        mixer = "conv" if kinds[l] == "conv" else "attn"
+        i = sum(k == kinds[l] for k in kinds[:l])
+        Ld = mc.first_k_dense_replace
+        group, j = ("dense", l) if l < Ld else ("moe", l - Ld)
+        lp = {}
+        for g, at in (("layers", l), (mixer, i), (group, j)):
+            for name in host_params[g]:
+                if not name.endswith("_scale"):
+                    lp[name] = f32_leaf(g, name, at)
+        return lp
+
+    with jax.default_matmul_precision("highest"):
+        embed = f32_leaf("top", "embed")
+        final_norm = jnp.asarray(host_params["final_norm"], jnp.float32)
+        logits_of = lambda h: np.asarray(
+            ref.rms_norm(h[compare], final_norm, hf.get("norm_eps", 1e-5)) @ embed.T)
+        pos = jnp.arange(T, dtype=jnp.int32)
+        h = embed[jnp.asarray(tokens[:T])]
+        held = list(range(mc.num_experts))
+        for l, kind in enumerate(mc.layer_types):
+            h = ref.layer(layer_f32(l), hf, h, pos, kind, held, par["q_block"])
+            if l == n_sh - 1:
+                ref_sh = logits_of(h)
+        ref_logits = logits_of(h)
+    emit("lfm2_reference", t1)
+
+    def rel_err(a, b):
+        return float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
+
+    def rms_err(a, b):
+        return float(np.sqrt(np.mean((a - b) ** 2) / np.mean(b ** 2)))
+
+    def worst_position(a, b):
+        """The largest root-mean-square error of ONE position's logits."""
+        return float(np.max(np.sqrt(np.mean((a - b) ** 2, axis=-1) / np.mean(b ** 2, axis=-1))))
+
+    def log_softmax(x):
+        x = np.asarray(x, np.float64)
+        return x - x.max(-1, keepdims=True) - np.log(np.exp(x - x.max(-1, keepdims=True)).sum(
+            -1, keepdims=True))
+
+    ref_max = float(np.max(np.abs(ref_logits)))
+
+    def link(logits):
+        """The engine's top-20 log-probabilities of row A against ``logits``."""
+        lp = log_softmax(logits)
+        return max(float(np.abs(np.asarray(top["A"][int(p)][2], np.float64)
+                                - lp[j][top["A"][int(p)][1]]).max())
+                   for j, p in enumerate(compare)) / ref_max
+
+    def readings(tag, got, want):
+        return {tag + "rms_err": rms_err(got, want), tag + "rel_err": rel_err(got, want),
+                tag + "rms_err_worst_position": worst_position(got, want)}
+
+    n_pre = len(chunks(0))
+    out = {
+        **readings("", sys_logits, ref_logits), "engine_link": link(sys_logits),
+        **readings("shallow_", sys_sh, ref_sh),
+        "hit_vs_cold": hit_vs_cold / ref_max, "hit_same_tokens_and_top20": hit_same,
+        "hit_positions": len(shared),
+        "rms_err_prefill": rms_err(sys_logits[:n_pre], ref_logits[:n_pre]),
+        "rms_err_decode": rms_err(sys_logits[n_pre:], ref_logits[n_pre:]),
+        "argmax_agree": int((sys_logits.argmax(-1) == ref_logits.argmax(-1)).sum()),
+        "shallow_argmax_agree": int((sys_sh.argmax(-1) == ref_sh.argmax(-1)).sum()),
+        **readings("dropped_state_", ctl_logits, ref_logits),
+        "dropped_state_engine_link": link(ctl_logits),
+        **readings("dropped_state_shallow_", ctl_sh, ref_sh),
+        "ref_max_abs_logit": ref_max, "shallow_ref_max_abs_logit": float(np.max(np.abs(ref_sh))),
+        "positions": int(len(compare)), "context": int(T), "seed": seed,
+        "limits": LFM2_LIMITS,
+    }
+    emit("lfm2_parity", t0, **out)
+    if not rehearse:
+        over = [f"{n} {out[n]} against its limit {limit}"
+                for n, limit in LFM2_LIMITS.items() if out[n] > limit]
+        if over:
+            fail("lfm2: " + "; ".join(over))
+        if hit_same != len(shared):
+            fail(f"lfm2: the hit's tokens or top-20 differ from the cold prefill's at "
+                 f"{len(shared) - hit_same} of {len(shared)} positions")
+        for what, names in (("whole model's", LFM2_WHOLE), ("one period's", LFM2_ONE_PERIOD)):
+            if not any(out["dropped_state_" + n] > LFM2_LIMITS[n] for n in names):
+                fail(f"lfm2: the control (page entries dropped) passes every one of the "
+                     f"{what} limits: too loose")
+    print(json.dumps(dev), flush=True)
+
+
 def _tp_engine(cfg: dict, tp: int, kv_scale, layers: int = 0):
     """The comparison's engine; ``layers`` > 0 cuts DEPTH only (widths stay
     the published ones) for the shallow, tightly-toleranced comparison."""
@@ -1420,6 +1741,7 @@ def main() -> None:
         {"parity": child_parity,
          "parity-dsv32": child_parity_dsv32,
          "parity-kimi-k2": child_parity_kimi_k2,
+         "parity-lfm2": child_parity_lfm2,
          "tp1": lambda r: child_tp1(r, args.ref),
          "tp4": lambda r: child_tp4(r, args.ref)}[args.child](args.rehearse_cpu)
         return

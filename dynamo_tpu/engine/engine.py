@@ -114,11 +114,8 @@ class TpuEngine(
             # debug models) takes the XLA path — resolved HERE, from the
             # model's own shape, so every reporting surface names the path
             # that really serves (there is no fallback further down).
-            attn_impl = (
-                "tpu"
-                if on_tpu() and model_config.head_dim % 128 == 0
-                else "xla"
-            )
+            lanes = fam.attn_lanes(model_config) if fam.attn_lanes else model_config.head_dim
+            attn_impl = "tpu" if on_tpu() and lanes % 128 == 0 else "xla"
         self.attn_impl = attn_impl
         # Kernel selectors (config > DYN_DECODE_KERNEL/DYN_PREFILL_KERNEL
         # env > auto), resolved and validated BEFORE anything is allocated.
@@ -162,7 +159,10 @@ class TpuEngine(
             event_callback=event_callback,
             enable_prefix_caching=cfg.enable_prefix_caching,
         )
-        self.scheduler = Scheduler(cfg, self.kv)
+        self.scheduler = Scheduler(
+            cfg, self.kv,
+            full_hit_recompute=cfg.block_size if fam.state_per_page else 1,
+        )
         # Draft-free speculative decoding (engine/spec.py): None = off.
         self._spec_ctl = (
             AcceptanceController(cfg.spec_decode)

@@ -511,9 +511,14 @@ class StepPlan:
 
 
 class Scheduler:
-    def __init__(self, cfg: EngineConfig, kv: KvBlockManager):
+    def __init__(self, cfg: EngineConfig, kv: KvBlockManager, full_hit_recompute: int = 1):
         self.cfg = cfg
         self.kv = kv
+        # Tokens a fully cached prompt computes again for its logits: the
+        # last one, or the whole last block for a family whose state is held
+        # by PAGE (models/family.py ``state_per_page``): its sealed entry
+        # ends AT the last token and cannot restart one position earlier.
+        self.full_hit_recompute = full_hit_recompute
         self.waiting: WfqQueue = WfqQueue(
             tenant_weights=cfg.qos.tenant_weights,
             default_weight=cfg.qos.default_weight,
@@ -814,7 +819,7 @@ class Scheduler:
         # A fully-cached prompt must still recompute its last token to get
         # logits for sampling the first output token.
         if cached_tokens >= len(seq.prompt):
-            cached_tokens = len(seq.prompt) - 1
+            cached_tokens = len(seq.prompt) - self.full_hit_recompute
         seq.num_computed = cached_tokens
         seq.num_cached_prompt = cached_tokens
         seq.num_sealed_blocks = cached_tokens // self.cfg.block_size

@@ -755,6 +755,8 @@ class SparseModelMetrics:
         self.moe_routed_tokens = 0
         self.moe_experts_read = 0
         self.moe_experts_held = 0
+        self.conv_row_starts = {"zero": 0, "tail": 0}  # prompt-chunk rows by their start
+        self.conv_tokens = 0
 
     def reset(self) -> None:
         self.__init__()
@@ -786,10 +788,28 @@ class SparseModelMetrics:
             acc[0] += n * start + n * (n + 1) // 2
             acc[1] += n
 
+    def add_conv(self, kind: str, starts, ns) -> None:
+        """Add a dispatch to the account of the short-convolution layers
+        (models/lfm2.py): its tokens, and its PROMPT-CHUNK rows by where
+        their first token's convolution started: from zeros (position 0) or
+        from a page's entry (a prefix hit or a later chunk).  A row of a
+        ``unified`` dispatch is a prompt chunk if it has several tokens or
+        starts at 0: a single token further on is, by the lengths the host
+        holds, a decode row riding the step.  Arguments as ``add_dsa``."""
+        for start, n in zip(starts, ns):
+            start, n = int(start), int(n)
+            if n <= 0 or start < 0:
+                continue
+            self.conv_tokens += n
+            if kind == "unified" and (n > 1 or start == 0):
+                self.conv_row_starts["zero" if start == 0 else "tail"] += 1
+
     def summary(self) -> Dict[str, Any]:
         """The accounts as ``dispatch_summary()["model"]``."""
         return {"dsa": {k: list(v) for k, v in self.dsa.items()},
                 "mla": {k: list(v) for k, v in self.mla.items()},
+                "conv_row_starts": dict(self.conv_row_starts),
+                "conv_tokens": self.conv_tokens,
                 "moe_local_pairs": self.moe_local_pairs,
                 "moe_routed_tokens": self.moe_routed_tokens,
                 "moe_experts_read": self.moe_experts_read,
@@ -809,6 +829,18 @@ class SparseModelMetrics:
         if not self.dsa and not self.mla and not self.moe_routed_tokens:
             return ""
         lines = []
+        if self.conv_tokens:
+            name = f"{prefix}_conv_row_starts_total"
+            lines += [f"# HELP {name} Prompt-chunk rows dispatched to the short-convolution "
+                      "layers, by whether their first token started from zeros (position 0) "
+                      "or from a page's entry (a prefix hit or a later chunk)",
+                      f"# TYPE {name} counter"]
+            lines += [f'{name}{{state="{escape_label(k)}"}} {v}'
+                      for k, v in self.conv_row_starts.items()]
+            name = f"{prefix}_conv_tokens_total"
+            lines += [f"# HELP {name} Tokens dispatched through the short-convolution layers "
+                      "(a fused decode chunk counts its steps)",
+                      f"# TYPE {name} counter", f"{name} {self.conv_tokens}"]
         for acc, series in (
             (self.dsa, (
                 ("dsa_context_positions_total",

@@ -22,6 +22,11 @@ from typing import Any, Dict, Optional
 # model_types of the latent family (models/deepseek_v32.py): DeepSeek-V3's
 # block; deepseek_v32 adds the learned sparse selector.
 LATENT_MODEL_TYPES = ("deepseek_v32", "deepseek_v3", "kimi_k2")
+# model_types of the dense GQA block of models/llama.py (an absent key too).
+LLAMA_MODEL_TYPES = ("llama", "qwen2", "mistral", "mixtral")
+# The hybrid family (models/lfm2.py): gated short convolutions among GQA
+# attention layers, dense then sparse feed-forwards.
+HYBRID_MODEL_TYPES = ("lfm2_moe",)
 
 
 @dataclass(frozen=True)
@@ -73,6 +78,14 @@ class ModelConfig:
     router_experts: int = 0
     ep_size: int = 1
     ep_rank: int = 0
+    # Added to the sum of the chosen scores before the gate's weights are
+    # normalised by it (lfm2_moe: 1e-6); 0 adds nothing to the program.
+    gate_norm_eps: float = 0.0
+    # The hybrid family (lfm2_moe): each layer's mixer, "conv" or
+    # "full_attention", and the short convolution's length (its state is the
+    # conv_L_cache - 1 positions before a token); () and 0 elsewhere.
+    layer_types: tuple = ()
+    conv_L_cache: int = 0
 
     @property
     def is_moe(self) -> bool:
@@ -91,10 +104,21 @@ class ModelConfig:
 
     @classmethod
     def from_hf_config(cls, cfg: Dict[str, Any], name: str = "") -> "ModelConfig":
-        """Convert a HuggingFace ``config.json`` dict (llama/mixtral style,
-        or one of ``LATENT_MODEL_TYPES``)."""
-        if cfg.get("model_type") in LATENT_MODEL_TYPES:
+        """Convert a HuggingFace ``config.json`` dict: llama/mixtral style
+        (``LLAMA_MODEL_TYPES`` or no ``model_type``), one of
+        ``LATENT_MODEL_TYPES`` or of ``HYBRID_MODEL_TYPES``.  Any other
+        ``model_type`` is refused by name: its keys read as a dense llama
+        would be another model under its name."""
+        model_type = cfg.get("model_type")
+        if model_type in LATENT_MODEL_TYPES:
             return cls._from_latent(cfg, name)
+        if model_type in HYBRID_MODEL_TYPES:
+            return cls._from_hybrid(cfg, name)
+        if model_type is not None and model_type not in LLAMA_MODEL_TYPES:
+            raise ValueError(
+                f"model_type {model_type!r} is not supported; known: "
+                f"{sorted(LLAMA_MODEL_TYPES + LATENT_MODEL_TYPES + HYBRID_MODEL_TYPES)}"
+            )
         num_heads = cfg["num_attention_heads"]
         head_dim = cfg.get("head_dim") or cfg["hidden_size"] // num_heads
         # Qwen2 checkpoints carry q/k/v biases but don't always write an
@@ -189,6 +213,63 @@ class ModelConfig:
             router_experts=total,
             ep_size=ep_size,
             ep_rank=ep_rank,
+        )
+
+    @classmethod
+    def _from_hybrid(cls, cfg: Dict[str, Any], name: str) -> "ModelConfig":
+        """``lfm2_moe``'s keys (docs/lfm2.md).  ``num_experts`` counts the
+        experts held here, as ``_from_latent`` reads ``n_routed_experts``:
+        the published file holds them all (``ep_size`` 1)."""
+        L = cfg["num_hidden_layers"]
+        layer_types = tuple(cfg["layer_types"])
+        if len(layer_types) != L or set(layer_types) - {"conv", "full_attention"}:
+            raise ValueError(
+                f"layer_types must name {L} layers, each 'conv' or 'full_attention'")
+        if cfg.get("conv_bias", False):
+            raise ValueError("conv_bias true is not supported (the release has none)")
+        if cfg.get("conv_L_cache", 3) < 2:
+            raise ValueError("conv_L_cache must be at least 2")
+        if not cfg.get("use_expert_bias", True):
+            raise ValueError("use_expert_bias false is not supported (the release has it)")
+        held = cfg["num_experts"]
+        ep_size = cfg.get("ep_size", 1)
+        total = cfg.get("num_experts_published", held * ep_size)
+        ep_rank = cfg.get("ep_rank", 0)
+        if held * ep_size != total or not 0 <= ep_rank < ep_size:
+            raise ValueError(
+                f"num_experts {held} x ep_size {ep_size} (ep_rank {ep_rank}) is not the "
+                f"router's width {total}")
+        num_heads = cfg["num_attention_heads"]
+        eos = cfg.get("eos_token_id", ())
+        if isinstance(eos, int):
+            eos = (eos,)
+        return cls(
+            name=name or cfg.get("_name_or_path", "hf-model"),
+            model_type=cfg["model_type"],
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            num_layers=L,
+            num_heads=num_heads,
+            num_kv_heads=cfg.get("num_key_value_heads", num_heads),
+            head_dim=cfg.get("head_dim") or cfg["hidden_size"] // num_heads,
+            intermediate_size=cfg["intermediate_size"],
+            rope_theta=cfg.get("rope_theta", 1000000.0),
+            rms_norm_eps=cfg.get("norm_eps", 1e-5),
+            max_position=cfg.get("max_position_embeddings", 128000),
+            tie_word_embeddings=cfg.get("tie_word_embeddings", True),
+            num_experts=held,
+            num_experts_per_token=cfg["num_experts_per_tok"],
+            moe_intermediate_size=cfg["moe_intermediate_size"],
+            eos_token_ids=tuple(eos),
+            first_k_dense_replace=cfg.get("num_dense_layers", 0),
+            routed_scaling_factor=cfg.get("routed_scaling_factor", 1.0),
+            norm_topk_prob=cfg.get("norm_topk_prob", True),
+            router_experts=total,
+            ep_size=ep_size,
+            ep_rank=ep_rank,
+            gate_norm_eps=1e-6,
+            layer_types=layer_types,
+            conv_L_cache=cfg.get("conv_L_cache", 3),
         )
 
     @classmethod

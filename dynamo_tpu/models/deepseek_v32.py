@@ -120,19 +120,25 @@ _ONES = ("attn_norm", "q_norm", "kv_norm", "idx_k_norm_w", "mlp_norm", "final_no
 _S0 = np.float32(0.02 / 73.0)  # uniform int8 has std ~73: N(0, 0.02)-like weights
 
 
-def _draw(config: ModelConfig, key: jax.Array, quant: bool) -> Params:
+def _draw(config: ModelConfig, key: jax.Array, quant: bool, shapes=None,
+          quant_axes=None, ones=None) -> Params:
     """Every leaf from ``key``, each straight into its stored type.  Traced
     under ONE jit (``init_params*``): no eager temporaries, so the 1.2 GB
-    expert leaves and the 5.6 GB whole fit beside the pages."""
+    expert leaves and the 5.6 GB whole fit beside the pages.  ``shapes``,
+    ``quant_axes``, ``ones``: another family's layout in this one's form
+    (models/lfm2.py); this family's own by default."""
     dt = jnp.dtype(config.dtype)
+    shapes = leaf_shapes(config) if shapes is None else shapes
+    axes = QUANT_AXES if quant_axes is None else quant_axes
+    ones = _ONES if ones is None else ones
     out: Params = {}
     n = 0
-    for group, leaves in leaf_shapes(config).items():
+    for group, leaves in shapes.items():
         dst = out if group == "top" else out.setdefault(group, {})
         for name, shape in leaves.items():
             k = jax.random.fold_in(key, n)
             n += 1
-            if name in _ONES:
+            if name in ones:
                 dst[name] = jnp.ones(shape, dt)
             elif name == "idx_k_norm_b":
                 dst[name] = jnp.zeros(shape, dt)
@@ -140,9 +146,9 @@ def _draw(config: ModelConfig, key: jax.Array, quant: bool) -> Params:
                 # Small and nonzero, so that "the bias steers the choice
                 # only" is visible to a test; f32 as the release keeps it.
                 dst[name] = jax.random.normal(k, shape, jnp.float32) * 0.01
-            elif quant and name in QUANT_AXES[group]:
+            elif quant and name in axes[group]:
                 dst[name] = jax.random.randint(k, shape, -127, 128, dtype=jnp.int8)
-                axis = QUANT_AXES[group][name]
+                axis = axes[group][name]
                 dst[name + "_scale"] = jnp.full(shape[:axis] + shape[axis + 1:], _S0, jnp.float32)
             else:
                 dst[name] = (jax.random.normal(k, shape, jnp.float32) * 0.02).astype(dt)
@@ -158,12 +164,14 @@ def init_params_quantized(config: ModelConfig, key: jax.Array) -> Params:
 
 
 def _groups(params: Params):
+    """("top", the tree itself) and then each group of stacked leaves."""
     yield "top", params
-    for g in ("layers", "dense", "moe"):
-        yield g, params[g]
+    for g, leaves in params.items():
+        if isinstance(leaves, dict):
+            yield g, leaves
 
 
-def quantize_params(params: Params) -> Params:
+def quantize_params(params: Params, quant_axes=QUANT_AXES) -> Params:
     """int8 leaves with their scales from a float tree (no-op when done)."""
     from .quant import _quantize_jnp
 
@@ -175,7 +183,7 @@ def quantize_params(params: Params) -> Params:
         for name, leaf in leaves.items():
             if isinstance(leaf, dict):
                 continue
-            axis = QUANT_AXES[group].get(name)
+            axis = quant_axes[group].get(name)
             if axis is None:
                 dst[name] = leaf
             else:
@@ -183,7 +191,7 @@ def quantize_params(params: Params) -> Params:
     return out
 
 
-def dequantize_params(params: Params, dtype="float32") -> Params:
+def dequantize_params(params: Params, dtype="float32", quant_axes=QUANT_AXES) -> Params:
     """The float tree a quantized one stands for (the reference's weights)."""
     out: Params = {}
     for group, leaves in _groups(params):
@@ -195,7 +203,7 @@ def dequantize_params(params: Params, dtype="float32") -> Params:
             if s is None:
                 dst[name] = leaf
             else:
-                axis = QUANT_AXES[group][name]
+                axis = quant_axes[group][name]
                 dst[name] = (leaf.astype(jnp.float32) * jnp.expand_dims(s, axis)).astype(dtype)
     return out
 
@@ -223,7 +231,10 @@ def gate(x: jnp.ndarray, lp: Params, config: ModelConfig):
     chosen = jax.lax.top_k(biased, K)[1]
     w = jnp.take_along_axis(s, chosen, axis=-1)
     if config.norm_topk_prob:
-        w = w / jnp.sum(w, axis=-1, keepdims=True)
+        total = jnp.sum(w, axis=-1, keepdims=True)
+        if config.gate_norm_eps:  # static: a model without it gets no op for it
+            total = total + config.gate_norm_eps
+        w = w / total
     return chosen, w * config.routed_scaling_factor
 
 

@@ -11,7 +11,7 @@ from __future__ import annotations
 
 from typing import Any, Callable, NamedTuple, Optional
 
-from .config import LATENT_MODEL_TYPES, ModelConfig
+from .config import HYBRID_MODEL_TYPES, LATENT_MODEL_TYPES, ModelConfig
 from .llama import RaggedBatch  # noqa: F401 — the engine's step input, family-neutral
 
 
@@ -47,6 +47,13 @@ class ModelFamily(NamedTuple):
     count_aux: Optional[Callable] = None
     # () -> those accounts as a dict, for ``dispatch_summary()``.
     counts: Optional[Callable] = None
+    # (config) -> the head width the attention kernels see, where it is not
+    # ``config.head_dim`` (heads packed into one lane tile); None = head_dim.
+    attn_lanes: Optional[Callable] = None
+    # The cache holds state by PAGE that a position inside the page cannot
+    # be resumed from: a prompt that is a whole number of cached blocks gives
+    # its last block back (engine/scheduler.py ``full_hit_recompute``).
+    state_per_page: bool = False
 
 
 def _llama() -> ModelFamily:
@@ -125,17 +132,7 @@ def _latent() -> ModelFamily:
                        "configuration's ep_size/ep_rank say which experts this chip holds)")
         if jnp.dtype(cfg.cache_dtype).itemsize == 1:
             bad.append("--kv-cache-dtype int8/fp8 (latent and indexer pages are bfloat16 or wider)")
-        if cfg.host_cache_bytes or cfg.disk_cache_bytes or cfg.object_store_bytes:
-            bad.append("--host-cache-mb/--disk-cache-mb/--object-store-mb (the tiers store "
-                       "K-plus-V blocks)")
-        if cfg.spec_decode.enable:
-            bad.append("--spec-decode (verification rows of several tokens are not wired)")
-        if cfg.lora.enable:
-            bad.append("--lora (no adapter banks for the latent projections)")
-        if bad:
-            raise ValueError(
-                f"model_type {config.model_type} ({config.name}) does not support: "
-                + "; ".join(bad))
+        _refuse(config, cfg, bad + _unmovable_blocks(cfg))
 
     return ModelFamily(
         name="latent",
@@ -157,7 +154,71 @@ def _latent() -> ModelFamily:
     )
 
 
-_FAMILIES = {"llama": _llama, **{t: _latent for t in LATENT_MODEL_TYPES}}
+def _refuse(config: ModelConfig, cfg: Any, bad: list) -> None:
+    if bad:
+        raise ValueError(
+            f"model_type {config.model_type} ({config.name}) does not support: "
+            + "; ".join(bad))
+
+
+def _unmovable_blocks(cfg: Any) -> list:
+    """The engine options no family without ``gather_pages`` / ``inject_pages``
+    and PartitionSpecs can serve, each by its flag."""
+    bad = []
+    if cfg.host_cache_bytes or cfg.disk_cache_bytes or cfg.object_store_bytes:
+        bad.append("--host-cache-mb/--disk-cache-mb/--object-store-mb (the tiers store "
+                   "K-plus-V blocks)")
+    if cfg.spec_decode.enable:
+        bad.append("--spec-decode (verification rows of several tokens are not wired)")
+    if cfg.lora.enable:
+        bad.append("--lora (no adapter banks for this family's projections)")
+    return bad
+
+
+def _hybrid() -> ModelFamily:
+    """models/lfm2.py: gated short convolutions whose state lives in the page
+    cache beside the attention layers' K/V."""
+    from ..llm.metrics import sparse_model_metrics
+    from . import lfm2
+
+    def kinds(config, cache):
+        """K/V bytes a token an attention layer, and the convolution state's
+        bytes a PAGE a convolution layer (it does not grow inside a page)."""
+        return {"kv": 2 * config.num_kv_heads * config.head_dim * cache.pages.dtype.itemsize,
+                "conv_page": cache.conv.shape[2] * cache.conv.shape[3] * cache.conv.dtype.itemsize}
+
+    def check(config: ModelConfig, cfg: Any) -> None:
+        bad = []
+        if cfg.tp * cfg.dp * cfg.ep * cfg.sp != 1:
+            bad.append("--tp/--dp/--ep/--sp > 1 (no PartitionSpecs for the state pages; the "
+                       "configuration's ep_size/ep_rank say which experts this chip holds)")
+        _refuse(config, cfg, bad + _unmovable_blocks(cfg))
+
+    return ModelFamily(
+        name="hybrid",
+        init_params=lfm2.init_params,
+        init_params_quantized=lfm2.init_params_quantized,
+        quantize_params=lfm2.quantize_params,
+        fuse_projections=None,
+        create_cache=lfm2.HybridCache.create,
+        forward=lfm2.forward_ragged,
+        cache_pspec=None,
+        gather_pages=None,
+        inject_pages=None,
+        forward_sp_prefill=None,
+        cache_kinds=kinds,
+        check=check,
+        count_dispatch=lambda config, kind, starts, ns: sparse_model_metrics.add_conv(
+            kind, starts, ns),
+        count_aux=sparse_model_metrics.add_moe,
+        counts=sparse_model_metrics.summary,
+        attn_lanes=lfm2.attn_lanes,
+        state_per_page=True,
+    )
+
+
+_FAMILIES = {"llama": _llama, **{t: _latent for t in LATENT_MODEL_TYPES},
+             **{t: _hybrid for t in HYBRID_MODEL_TYPES}}
 
 
 def family_of(config: ModelConfig) -> ModelFamily:

@@ -1,0 +1,344 @@
+"""The hybrid family, LFM2's block (``model_type`` ``lfm2_moe``): a layer's
+mixer is a gated short convolution or GQA attention with QK-norm, its
+feed-forward a dense SwiGLU (the leading ``first_k_dense_replace`` layers)
+or sigmoid-gated experts of which this chip holds ``num_experts``.
+docs/lfm2.md has the equations; models/reference/lfm2_moe.py is the plain
+float32 reference.
+
+Beside models/llama.py and models/deepseek_v32.py, sharing ``linear``,
+``rms_norm``, ``mlp``, ``embed_lookup``, ``lm_logits``, the attention ops of
+the dense family, the latent family's ``gate`` and the dispatch of
+models/moe.py.  The layers are unlike, so the loop over them is unrolled with
+static indices in both programs, each kind's leaves stacked over its own
+layers (``conv`` [Lc, ...], ``attn`` [La, ...], ``dense``, ``moe``).
+
+Two kinds of state under ONE page table (``HybridCache``): K/V pages for the
+attention layers, and for the convolution layers one ENTRY a page,
+``conv[l, p] = (u_{t-K+1}, ..., u_t)`` with ``t`` the LAST position written
+into page ``p`` and K = ``conv_L_cache`` - 1: what a sequence needs to go on
+from position ``t + 1``.  A token at position t reads the entry of the page
+that holds t - 1 (zeros at t = 0), the tokens of one run read their
+predecessors in the run, and only a run's last token in each page writes.  A
+sealed block's entry is a function of the prefix alone, so a prefix hit, a
+later chunk, a decode step and a resume after preemption are one case, and
+the engine's block manager, prefix cache and eviction see page ids only.
+
+The layout's one edge: a prompt that is a whole number of cached blocks
+must compute its last token again for the logits, and that token's
+predecessors' ``u`` the sealed page no longer holds (its entry ends AT that
+token).  Such a hit gives its last block back (``ModelFamily.state_per_page``
+-> engine/scheduler.py ``full_hit_recompute``): sixteen tokens computed
+again from the block before, against a third more state in every page.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, NamedTuple, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ..ops.ragged_attention import ragged_attention, write_kv_ragged
+from ..ops.rope import apply_rope, rope_frequencies
+from . import deepseek_v32 as latent
+from .config import ModelConfig
+from .llama import RaggedBatch, embed_lookup, linear, lm_logits, mlp, rms_norm
+from .moe import expert_dispatch
+
+Params = Dict[str, Any]
+
+# int8 leaves and the CONTRACTED axis their scale spans (as the latent
+# family's table); the taps, the router and its bias and the norms stay in the
+# activation dtype.
+QUANT_AXES = {
+    "layers": {},
+    "conv": {"in_proj": 1, "out_proj": 1},
+    "attn": {"wqkv": 1, "wo": 1},
+    "dense": {"w_gate": 1, "w_up": 1, "w_down": 1},
+    "moe": {"moe_gate": 2, "moe_up": 2, "moe_down": 2},
+    "top": {"embed": 1, "lm_head": 0},
+}
+_ONES = ("op_norm", "ffn_norm", "q_norm", "k_norm", "final_norm")
+EXPERT_LEAVES = latent.EXPERT_LEAVES
+
+
+def head_pack(config: ModelConfig) -> int:
+    """K/V heads that share one 128-lane row of a page.  The attention
+    kernels slice pages by whole lane tiles, and a head of 64 does not lower
+    (Mosaic: "slice shape must be aligned to tiling (128)"), so two heads lie
+    side by side in a row: the kernels see ``num_kv_heads / pack`` heads of
+    ``pack * head_dim`` lanes, a query padded with zeros where its
+    neighbour's keys lie, and its own half of the output.  The zeros add
+    nothing to a score: the mathematics is the unpacked one."""
+    hd, KV = config.head_dim, config.num_kv_heads
+    pack = 128 // hd if hd < 128 and 128 % hd == 0 else 1
+    return pack if KV % pack == 0 else 1
+
+
+def attn_lanes(config: ModelConfig) -> int:
+    """The head width the attention kernels see (``ModelFamily.attn_lanes``)."""
+    return config.head_dim * head_pack(config)
+
+
+def layer_counts(config: ModelConfig) -> Tuple[int, int, int, int]:
+    """(convolution, attention, dense, expert) layers."""
+    Lc = sum(t == "conv" for t in config.layer_types)
+    Ld = min(config.first_k_dense_replace, config.num_layers)
+    return Lc, config.num_layers - Lc, Ld, config.num_layers - Ld
+
+
+class HybridCache(NamedTuple):
+    """``pages`` [La, P, ps, 2 * KV / pack, pack * head_dim]: the attention
+    layers' K/V in the dense family's layout (K rows even, V rows odd), the
+    page dtype the engine was asked for.  ``conv`` [Lc, P, K, D]: the
+    convolution layers' entry of each page, in the ACTIVATION dtype whatever
+    the K/V pages' (what a hit reads is then what a cold run computed)."""
+
+    pages: jnp.ndarray
+    conv: jnp.ndarray
+
+    @classmethod
+    def create(cls, config: ModelConfig, num_pages: int, page_size: int,
+               dtype=jnp.bfloat16) -> "HybridCache":
+        Lc, La, _, _ = layer_counts(config)
+        pack = head_pack(config)
+        return cls(
+            pages=jnp.zeros((La, num_pages, page_size, 2 * config.num_kv_heads // pack,
+                             pack * config.head_dim), dtype),
+            conv=jnp.zeros((Lc, num_pages, config.conv_L_cache - 1, config.hidden_size),
+                           jnp.dtype(config.dtype)),
+        )
+
+
+def leaf_shapes(config: ModelConfig) -> Dict[str, Dict[str, tuple]]:
+    """Every leaf's shape, by group: the one statement of the layout."""
+    c = config
+    D, H, KV, hd, L, V = (c.hidden_size, c.num_heads, c.num_kv_heads, c.head_dim,
+                          c.num_layers, c.vocab_size)
+    Lc, La, Ld, Lm = layer_counts(c)
+    E, Et, F, Fm = c.num_experts, c.router_experts, c.intermediate_size, c.moe_intermediate_size
+    top = {"embed": (V, D), "final_norm": (D,)}
+    if not c.tie_word_embeddings:
+        top["lm_head"] = (D, V)
+    return {
+        "top": top,
+        "layers": {"op_norm": (L, D), "ffn_norm": (L, D)},
+        # in_proj's columns: B, C, x (three parts of D); taps[k] multiplies u_{t-K+k}.
+        "conv": {"in_proj": (Lc, D, 3 * D), "taps": (Lc, c.conv_L_cache, D),
+                 "out_proj": (Lc, D, D)},
+        # wqkv's columns: q (H heads), k, v (KV heads each).
+        "attn": {"wqkv": (La, D, (H + 2 * KV) * hd), "q_norm": (La, hd), "k_norm": (La, hd),
+                 "wo": (La, H * hd, D)},
+        "dense": {"w_gate": (Ld, D, F), "w_up": (Ld, D, F), "w_down": (Ld, F, D)},
+        "moe": {"router": (Lm, D, Et), "router_bias": (Lm, Et),
+                "moe_gate": (Lm, E, D, Fm), "moe_up": (Lm, E, D, Fm), "moe_down": (Lm, E, Fm, D)},
+    }
+
+
+def _draw(config: ModelConfig, key: jax.Array, quant: bool) -> Params:
+    return latent._draw(config, key, quant, leaf_shapes(config), QUANT_AXES, _ONES)
+
+
+def init_params(config: ModelConfig, key: jax.Array) -> Params:
+    return jax.jit(lambda k: _draw(config, k, False))(key)
+
+
+def init_params_quantized(config: ModelConfig, key: jax.Array) -> Params:
+    return jax.jit(lambda k: _draw(config, k, True))(key)
+
+
+def quantize_params(params: Params) -> Params:
+    return latent.quantize_params(params, QUANT_AXES)
+
+
+def dequantize_params(params: Params, dtype="float32") -> Params:
+    return latent.dequantize_params(params, dtype, QUANT_AXES)
+
+
+def _rounded(v: jnp.ndarray, dtype) -> jnp.ndarray:
+    """``v`` (float32) rounded to ``dtype``, SAID and not left to a cast that
+    XLA keeps or drops by what it fuses it with (models/moe.py
+    ``_quantize_gated``): the page entry must hold what the run itself used."""
+    fi = jnp.finfo(dtype)
+    return jax.lax.reduce_precision(v, fi.nexp, fi.nmant).astype(dtype)
+
+
+def moe_block(x, lp: Params, config: ModelConfig, real, layer):
+    """The routed experts chosen AND held (no shared expert): (y [T, D],
+    pairs of real tokens landed on each held expert [E])."""
+    chosen, w = latent.gate(x, lp, config)
+    local = chosen - config.ep_rank * config.num_experts
+    here = (local >= 0) & (local < config.num_experts) & real[:, None]
+    return expert_dispatch(x, local, w, lp, config.num_experts, valid=here, layer=layer)
+
+
+def forward_ragged(
+    params: Params,
+    config: ModelConfig,
+    rb: RaggedBatch,
+    cache: HybridCache,
+    *,
+    attn_impl: str = "xla",
+    kv_scale=None,  # None, a float, or the attention layers' calibrated [La]
+    decode: bool = False,
+    decode_kernel: str = "stock",
+    prefill_kernel: str = "stock",
+    drop_state_at_page_boundary: bool = False,  # chip_smoke.py's control, never the engine
+    **_other_families,  # mesh, lora_rank: family.py's check refuses what they stand for
+) -> Tuple[jnp.ndarray, HybridCache, Any]:
+    """The unified step of models/llama.py for this family: (logits [S, V] of
+    each row's last token, the updated cache, aux [4] int32 as the latent
+    family's: routed pairs landed on held experts, tokens routed, held experts
+    read, experts held, over the step's real tokens and expert layers)."""
+    c = config
+    rb = jax.tree_util.tree_map(jnp.asarray, rb)  # host arrays when not under jit
+    (T,) = rb.token_ids.shape
+    S = rb.kv_lens.shape[0]
+    D, H, KV, hd, eps = c.hidden_size, c.num_heads, c.num_kv_heads, c.head_dim, c.rms_norm_eps
+    dt = jnp.dtype(c.dtype)
+    Lc, La, Ld, Lm = layer_counts(c)
+    K = c.conv_L_cache - 1
+    pack, G = head_pack(c), H // KV
+    inv_freq = rope_frequencies(hd, c.rope_theta, None)
+    P_layer, ps = cache.pages.shape[1:3]
+    pos = rb.positions
+    real = rb.slot_mapping >= 0  # [T] padding tokens carry slot -1
+    ks_vec = None if kv_scale is None else jnp.asarray(kv_scale, jnp.float32).reshape(-1)
+    # The fused kernels take the scale themselves (models/llama.py).
+    fused_dequant = decode_kernel == "pallas_fused" if decode else prefill_kernel == "pallas"
+
+    # ---- the rows' places in the convolution state, shared by its layers.
+    live_row = (jnp.arange(S) < rb.num_seqs[0]) & (rb.cu_q_lens[1:] > rb.cu_q_lens[:-1])
+    first = jnp.clip(rb.cu_q_lens[:-1], 0, T - 1)  # a row's first token
+    t0 = pos[first]
+    has_tail = live_row & (t0 > 0)
+    if drop_state_at_page_boundary:
+        has_tail &= t0 % ps != 0
+    tail_page = rb.page_indices[jnp.arange(S), jnp.maximum(t0 - 1, 0) // ps]
+    # [Lc, S, K, D]: read before any layer writes (a row may write the page it reads).
+    tails = jnp.where(has_tail[None, :, None, None], cache.conv[:, tail_page], 0)
+    if decode:  # every row is one token, the last of its page so far
+        write_page = jnp.where(real, rb.slot_mapping // ps, P_layer)
+    else:
+        first_at = jnp.where(live_row, rb.cu_q_lens[:-1], T)  # out of range: dropped
+        is_first = jnp.zeros((T,), bool).at[first_at].set(True, mode="drop")
+        last_at = jnp.where(live_row, rb.cu_q_lens[1:] - 1, T)
+        ends_run = jnp.zeros((T,), bool).at[last_at].set(True, mode="drop")
+        writes = real & (ends_run | (pos % ps == ps - 1))
+        # A run of n tokens ends at most n // ps + 1 pages.
+        writers = jnp.nonzero(writes, size=min(T, T // ps + S), fill_value=T)[0]
+        write_page = jnp.where(writers < T, rb.slot_mapping[jnp.minimum(writers, T - 1)] // ps,
+                               P_layer)
+
+    def short_conv(x, lp, tail):
+        """h += W_out (C * conv(B * x)): returns (the mixer's output, the
+        entries [writers, K, D] its writing tokens leave)."""
+        bcx = linear(x, lp, "in_proj")
+        with jax.named_scope("short_conv"):
+            b, gate_c, xin = jnp.split(bcx, 3, axis=-1)
+            u = _rounded(b.astype(jnp.float32) * xin.astype(jnp.float32), dt)  # [T, D]
+            # prev[k - 1] = u_{t-k}: from the run, or from the tail at its start.
+            if decode:
+                prev = [tail[:, K - k] for k in range(1, K + 1)]
+            else:
+                at_first = jnp.zeros((T, K, D), dt).at[first_at].set(tail, mode="drop")
+                prev, p = [], u
+                for k in range(1, K + 1):
+                    p = jnp.where(is_first[:, None], at_first[:, K - k],
+                                  jnp.concatenate([jnp.zeros((1, D), dt), p[:-1]], axis=0))
+                    prev.append(p)
+            taps = lp["taps"].astype(jnp.float32)  # [K + 1, D]
+            v = taps[K] * u.astype(jnp.float32)
+            for k in range(1, K + 1):
+                v = v + taps[K - k] * prev[k - 1].astype(jnp.float32)
+            y = (gate_c.astype(jnp.float32) * v).astype(dt)
+            window = jnp.stack(prev[:K - 1][::-1] + [u], axis=1)  # [T, K, D]: u_{t-K+1} .. u_t
+            entry = window if decode else window[jnp.minimum(writers, T - 1)]
+        return linear(y, lp, "out_proj"), entry
+
+    def attention(x, lp, a, pages):
+        q, k, v = jnp.split(linear(x, lp, "wqkv"), [H * hd, (H + KV) * hd], axis=-1)
+        q = rms_norm(q.reshape(T, H, hd), lp["q_norm"], eps)
+        k = rms_norm(k.reshape(T, KV, hd), lp["k_norm"], eps)
+        q, k = apply_rope(q, pos, inv_freq), apply_rope(k, pos, inv_freq)
+        if pack > 1:
+            # KV head g lies in half g % pack of row g // pack; its G queries
+            # carry zeros in the other halves.
+            q = (q.reshape(T, KV // pack, pack, G, 1, hd)
+                 * jnp.eye(pack, dtype=dt).reshape(1, 1, pack, 1, pack, 1)
+                 ).reshape(T, H, pack * hd)
+        k = k.reshape(T, KV // pack, pack * hd)
+        v = v.reshape(T, KV // pack, pack * hd)
+        s_a = None if ks_vec is None else ks_vec[jnp.minimum(a, ks_vec.shape[0] - 1)]
+        slots = jnp.where(real, rb.slot_mapping + a * (P_layer * ps), -1)
+        pages = write_kv_ragged(pages, k, v, slots, kv_scale=s_a)
+        fold = s_a is not None and not fused_dequant
+        if fold:  # models/llama.py: the scale folded around the call
+            q = (q.astype(jnp.float32) * s_a).astype(q.dtype)
+        o = ragged_attention(
+            q, pages, rb.kv_lens, rb.page_indices + a * P_layer, rb.cu_q_lens, rb.num_seqs,
+            sm_scale=hd**-0.5, impl=attn_impl, decode=decode, decode_kernel=decode_kernel,
+            prefill_kernel=prefill_kernel, kv_scale=s_a if fused_dequant else None)
+        if fold:
+            o = (o.astype(jnp.float32) * s_a).astype(o.dtype)
+        if pack > 1:
+            o = o.reshape(T, KV // pack, pack, G, pack, hd)
+            o = jnp.stack([o[:, :, i, :, i] for i in range(pack)], axis=2)
+        return linear(o.reshape(T, H * hd), lp, "wo"), pages
+
+    def at_layer(group: str, i) -> Params:
+        """Layer i's leaves; the expert leaves whole (``expert_dispatch``
+        reads a block of the stacked leaf in place)."""
+        return {k: a if k in EXPERT_LEAVES else a[i] for k, a in params[group].items()}
+
+    # Each kind of block is traced and lowered ONCE a program, as a function
+    # of its layer's number, and called once a layer: tracing 24 unrolled
+    # layers a program made a start with a warm compile cache 253 s where
+    # this makes it 119 (chip runs, PR 36).  XLA inlines the calls and folds
+    # each constant number into static slices: the compiled program is the
+    # unrolled one (no call, no dynamic slice: compile for a described v5e).
+    @jax.jit
+    def conv_block(x, i, tail):
+        return short_conv(x, at_layer("conv", i), tail)
+
+    @jax.jit
+    def attn_block(x, a, pages):
+        return attention(x, at_layer("attn", a), a, pages)
+
+    @jax.jit
+    def moe_layer(x, j):
+        return moe_block(x, at_layer("moe", j), c, real, j)
+
+    h = embed_lookup(params, rb.token_ids, dt)
+    pages = cache.pages.reshape((La * P_layer,) + cache.pages.shape[2:])
+    entries = []
+    pairs = jnp.zeros((), jnp.int32)
+    read = jnp.zeros((), jnp.int32)
+    ci = ai = 0
+    for l, kind in enumerate(c.layer_types):  # constant layer numbers: see models/llama.py on decode
+        x = rms_norm(h, params["layers"]["op_norm"][l], eps)
+        if kind == "conv":
+            y, entry = conv_block(x, jnp.int32(ci), tails[ci])
+            entries.append(entry)
+            ci += 1
+        else:
+            y, pages = attn_block(x, jnp.int32(ai), pages)
+            ai += 1
+        h = h + y
+        x = rms_norm(h, params["layers"]["ffn_norm"][l], eps)
+        if l < Ld:
+            h = h + mlp(x, at_layer("dense", l))
+        else:
+            y, load = moe_layer(x, jnp.int32(l - Ld))
+            h = h + y
+            pairs += jnp.sum(load)
+            read += jnp.sum(load > 0, dtype=jnp.int32)
+
+    with jax.named_scope("short_conv"):
+        conv = cache.conv.at[:, write_page].set(jnp.stack(entries), mode="drop")
+    h = rms_norm(h, params["final_norm"], eps)
+    rows = jnp.clip(rb.cu_q_lens[1:] - 1, 0, T - 1)
+    logits = lm_logits(params, h[rows])
+    aux = jnp.stack([pairs, jnp.sum(real, dtype=jnp.int32) * Lm, read,
+                     jnp.asarray(c.num_experts * Lm, jnp.int32)])
+    return logits, HybridCache(pages.reshape(cache.pages.shape), conv), aux

@@ -28,6 +28,9 @@ GEOMETRY = {
     "qwen2.5-7b": (28, 4),
     "llama-3.1-8b": (32, 8),
     "llama-3.1-8b-tp4": (8, 2),
+    # 32 query and 8 K/V heads of 64, two K/V heads a 128-lane row
+    # (models/lfm2.py ``head_pack``): what the kernels see of lfm2-8b-a1b.
+    "lfm2-8b-a1b-packed": (32, 4),
 }
 D, PS = 128, 16
 LAYERS_X_PAGES = 28 * 2048  # the engine's layer-merged page slab
@@ -188,18 +191,18 @@ def test_one_kv_head_per_shard_is_refused_with_a_sentence():
 _DSV32_PAGES = 32768 * 16 * (640 + 128) * 2 * 6  # latent and indexer pages, six layers
 
 
-def _latent_step(topo, config_file: str, pages_bytes: int):
-    """``compiled(decode)``: the whole step of a latent-family configuration
-    file for a described v5e, each program compiled once for the tests that
-    share it (which turn the persistent cache off around it:
-    ``no_persistent_cache``).  The one-query kernel and the grouped expert
-    matmul go through Mosaic as on the chip: conftest's interpreter switch is
-    off, and nothing is patched."""
+def _family_step(topo, config_file: str, pages_bytes: int, traced=None, **static_kw):
+    """``compiled(decode)``: the whole step of a configuration file of the
+    latent or the hybrid family for a described v5e, each program compiled
+    once for the tests that share it (which turn the persistent cache off
+    around it: ``no_persistent_cache``).  The kernels go through Mosaic as on
+    the chip: conftest's interpreter switch is off, and nothing is patched.
+    ``static_kw`` and ``traced`` (name -> (shape, dtype)): the engine's options
+    on the chip, where the family's defaults are not those."""
     import functools
     import json
     import os
 
-    from dynamo_tpu.models import deepseek_v32 as ds
     from dynamo_tpu.models.config import ModelConfig
     from dynamo_tpu.models.family import RaggedBatch, family_of
 
@@ -214,30 +217,36 @@ def _latent_step(topo, config_file: str, pages_bytes: int):
         return jax.tree_util.tree_map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
 
+    fam = family_of(mc)
+
     @functools.lru_cache(maxsize=None)
     def compiled(decode: bool):
-        params = on_chip(jax.eval_shape(lambda k: ds._draw(mc, k, True), jax.random.PRNGKey(0)))
-        cache = on_chip(jax.eval_shape(
-            lambda: ds.LatentKVCache.create(mc, serve["num_blocks"], serve["block_size"])))
+        params = on_chip(jax.eval_shape(
+            lambda k: fam.init_params_quantized(mc, k), jax.random.PRNGKey(0)))
+        cache = on_chip(jax.eval_shape(lambda: fam.create_cache(
+            mc, serve["num_blocks"], serve["block_size"],
+            dtype=jnp.dtype(serve["kv_cache_dtype"]))))
         assert sum(a.size * a.dtype.itemsize
                    for a in jax.tree_util.tree_leaves(cache)) == pages_bytes
         S, PP = serve["max_batch"], serve["max_model_len"] // serve["block_size"]
         T = S if decode else serve["prefill_chunk"]
         i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)  # noqa: E731
         rb = RaggedBatch(i32(T), i32(T), i32(T), i32(S), i32(S, PP), i32(S + 1), i32(1))
-        fam = family_of(mc)
+        traced_args = {k: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                       for k, (shape, dtype) in (traced or {}).items()}
         with pytest.MonkeyPatch.context() as mp:
             mp.setenv("DYN_PALLAS_INTERPRET", "0")
             return jax.jit(
-                lambda p, c, rb: fam.forward(p, mc, rb, c, decode=decode), donate_argnums=1
-            ).lower(params, cache, rb).compile()
+                lambda p, c, rb, tr: fam.forward(p, mc, rb, c, decode=decode, **static_kw, **tr),
+                donate_argnums=1,
+            ).lower(params, cache, rb, traced_args).compile()
 
     return compiled
 
 
 @pytest.fixture(scope="module")
 def dsv32_step(topo):
-    return _latent_step(topo, "chipbench/configs/deepseek-v3.2-exp-6l-ep16.json", _DSV32_PAGES)
+    return _family_step(topo, "chipbench/configs/deepseek-v3.2-exp-6l-ep16.json", _DSV32_PAGES)
 
 
 _KIMI_PAGES = 32768 * 16 * 640 * 2 * 6  # latent pages alone, six layers
@@ -245,7 +254,101 @@ _KIMI_PAGES = 32768 * 16 * 640 * 2 * 6  # latent pages alone, six layers
 
 @pytest.fixture(scope="module")
 def kimi_step(topo):
-    return _latent_step(topo, "chipbench/configs/kimi-k2-6l-ep32.json", _KIMI_PAGES)
+    return _family_step(topo, "chipbench/configs/kimi-k2-6l-ep32.json", _KIMI_PAGES)
+
+
+_LFM2_KV_PAGES = 16384 * 16 * 2 * 8 * 64 * 6  # int8 K/V of the six attention layers
+_LFM2_STATE_PAGES = 16384 * 2 * 2048 * 2 * 18  # one bfloat16 entry a page, eighteen layers
+
+
+@pytest.fixture(scope="module")
+def lfm2_step(topo):
+    """chipbench/configs/lfm2-8b-a1b.json whole, with the options the engine
+    resolves on a TPU: both Pallas attention kernels and the calibrated
+    per-layer K/V scale (traced)."""
+    return _family_step(
+        topo, "chipbench/configs/lfm2-8b-a1b.json", _LFM2_KV_PAGES + _LFM2_STATE_PAGES,
+        traced={"kv_scale": ((6,), jnp.float32)}, attn_impl="tpu",
+        decode_kernel="pallas_fused", prefill_kernel="pallas")
+
+
+@pytest.mark.parametrize("decode", [False, True], ids=["unified-512", "decode-32"])
+def test_lfm2_step_compiles_at_the_cells_shapes_without_copying_either_page_array(
+    lfm2_step, no_persistent_cache, decode
+):
+    """chipbench/configs/lfm2-8b-a1b.json whole: 8.36 GB of weights (all 24
+    layers, all 32 experts of 22 layers, the whole vocabulary), 16384 pages of
+    K/V (head size 64, two heads a lane tile) and of convolution state, a
+    512-token chunk (or 32 decode rows) against 256 pages a row.  Both page
+    arrays are updated in place and the step's temporaries stay far under the
+    smaller of them (a copy into or out of a step would be at least that).
+    Attention goes through the two Pallas kernels of the dense family, once a
+    layer, and the experts through the grouped matmul: no other kernel."""
+    compiled = lfm2_step(decode)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _LFM2_KV_PAGES + _LFM2_STATE_PAGES
+    assert mem.temp_size_in_bytes < 0.4e9 < _LFM2_KV_PAGES, mem
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13.5e9, mem
+    calls = _custom_calls(compiled.as_text())
+    attn = "fused_decode_attention" if decode else "fused_prefill_attention"
+    assert len([ln for ln in calls if attn in ln]) == 6, calls
+    assert len([ln for ln in calls if "moe_grouped_matmul" in ln]) == 44, calls
+    assert all(attn in ln or "moe_grouped_matmul" in ln for ln in calls), calls
+    # the K/V pages as the kernels see them: 8 rows of 128 lanes a token
+    assert "s8[98304,16,8,128]" in compiled.as_text()
+
+
+def test_lfm2_decode_program_reads_the_experts_through_the_grouped_matmul_alone(
+    lfm2_step, no_persistent_cache
+):
+    """All 32 experts of a layer are held: nothing but the kernel may touch
+    an expert leaf, whole or a layer of it (see the latent family's test)."""
+    text = lfm2_step(True).as_text()
+    leaf = ("s8[22,32,2048,1792]", "s8[22,32,1792,2048]")
+    layer = ("s8[32,2048,1792]", "s8[32,1792,2048]", "s8[1,32,2048,1792]", "s8[1,32,1792,2048]")
+    calls = [ln for ln in _custom_calls(text) if "moe_grouped_matmul" in ln]
+    assert all(any(shape in ln for shape in leaf) for ln in calls), calls
+    entry = text[text.index("ENTRY "):]
+    for ln in entry.splitlines():
+        if " = " not in ln or " parameter(" in ln or "moe_grouped_matmul" in ln:
+            continue
+        assert not any(shape in ln for shape in leaf + layer), ln
+    assert not any(shape in text for shape in layer)
+
+
+@pytest.mark.parametrize("decode", [False, True], ids=["unified-512", "decode-32"])
+def test_lfm2_short_conv_metric_matches_the_scopes_ops_and_no_others(
+    lfm2_step, no_persistent_cache, decode
+):
+    """``short_conv_time_share`` matches XLA's op names (the harness keeps an
+    op's name and shape, not its scope).  In both programs compiled for a
+    described v5e every op the pattern matches lies under the scope
+    ``short_conv``, and the ops that move the page entries are matched:
+    another compiler or shape fails HERE, not as a metric that reads 0."""
+    import json
+    import os
+    import re
+
+    from chipbench.trace_reduce import short_name
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench/layer_metrics/short_conv_time_share.json")) as f:
+        pattern = re.compile(json.load(f)["args"]["pattern"])
+    matched, fused = set(), False
+    for ln in lfm2_step(decode).as_text().splitlines():
+        if ln.endswith("{") and " -> " in ln:  # a computation's head
+            fused = "fused" in ln.split(" ", 1)[0]
+        if fused or " = " not in ln:
+            continue
+        name = short_name(ln.strip().removeprefix("ROOT "))
+        if pattern.search(name):
+            matched.add(name)
+            assert "short_conv" in ln, ln
+    want = {"fusion bf16[18,16384,2,2048]"} | (
+        {"copy bf16[32,18,2,2048]", "slice bf16[32,1,2048]"} if decode else
+        {"copy bf16[64,18,2,2048]", "fusion bf16[512,1,2048]", "fusion bf16[64,2,2048]",
+         "reduce-precision_convert_fusion bf16[512,2048]"})
+    assert want <= matched, matched
 
 
 @pytest.mark.parametrize("decode", [False, True], ids=["unified-512", "decode-16"])
