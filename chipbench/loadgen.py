@@ -30,6 +30,7 @@ import aiohttp  # noqa: E402
 from chipbench import stats, traffic  # noqa: E402
 
 DRAIN_CAP_S = 120.0
+PROBE_TOP = 20  # the most alternatives the server gives (llm/openai.py)
 
 
 def say(event: str, **fields) -> None:
@@ -39,18 +40,22 @@ def say(event: str, **fields) -> None:
 def blank_record(req: dict, t_ref: float, t_sent: float, error=None) -> dict:
     return {"ok": False, "t_ref": t_ref, "t_sent": t_sent, "t_first": None, "t_last": None,
             "n_tokens": 0, "event_times": [], "short": False, "prompt_len": req["prompt_len"],
-            "max_tokens": req["max_tokens"], "text": "", "error": error}
+            "max_tokens": req["max_tokens"], "text": "", "error": error, "logprobs": []}
 
 
 async def stream_request(session, url: str, model: str, req: dict, clock0: float,
-                         t_ref: float) -> dict:
+                         t_ref: float, logprobs: int | None = None) -> dict:
     """One streamed /v1/completions call.  Times are seconds since ``clock0``
-    (perf_counter); ``t_ref`` is the time its latency counts from."""
+    (perf_counter); ``t_ref`` is the time its latency counts from.  With
+    ``logprobs`` the record keeps, per generated position the wire reports,
+    the log-probabilities it gave (``position_values``)."""
     payload = {
         "model": model, "prompt": req["prompt"], "stream": True,
         "max_tokens": req["max_tokens"], "temperature": 0.0,
         "nvext": {"ignore_eos": True},
     }
+    if logprobs is not None:
+        payload["logprobs"] = logprobs
     rec = blank_record(req, t_ref, time.perf_counter() - clock0)
     times, parts, usage_n, done = rec["event_times"], [], None, False
     try:
@@ -82,6 +87,8 @@ async def stream_request(session, url: str, model: str, req: dict, clock0: float
                     if "text" in choice or choice.get("finish_reason"):
                         times.append(now)
                         parts.append(choice.get("text") or "")
+                    if choice.get("logprobs"):
+                        rec["logprobs"] += position_values(choice["logprobs"])
                 if done:
                     break
     except asyncio.CancelledError:
@@ -104,6 +111,27 @@ async def stream_request(session, url: str, model: str, req: dict, clock0: float
         rec["error"] = (f"short: done={done} usage={usage_n} events={len(times)} "
                         f"of {req['max_tokens']}")
     return rec
+
+
+def position_values(lp: dict) -> list:
+    """Per position of one chunk's ``logprobs``, the values the wire gave,
+    largest first: the chosen token's and the alternatives'.  VALUES, because
+    the wire names a token by its glyph and glyphs collide: ``top_logprobs``
+    is a dict keyed by glyph, so of the ids that share a glyph (with the byte
+    tokenizer every id from 128 up) it keeps the last, the lowest of them."""
+    chosen = lp.get("token_logprobs") or []
+    tops = lp.get("top_logprobs") or [{}] * len(chosen)
+    return [sorted({c, *(top or {}).values()}, reverse=True) for c, top in zip(chosen, tops)]
+
+
+def values_gap(a: list, b: list):
+    """The widest gap between two positions' values of equal rank.  None:
+    nothing to compare, or the two sides gave another COUNT of values (an id
+    with a glyph of its own among one side's alternatives only): they are not
+    the same distribution, whatever their ends say."""
+    if not a or len(a) != len(b):
+        return None
+    return max(abs(x - y) for x, y in zip(a, b))
 
 
 async def run_phase(session, url: str, model: str, phase: dict, seconds: float) -> dict:
@@ -136,8 +164,8 @@ async def run_phase(session, url: str, model: str, phase: dict, seconds: float) 
                     return
                 i = next(cursor)
                 req = reqs[i % len(reqs)]
-                if i >= len(reqs):  # the system outran the pool: new prompt, same sizes
-                    req = dict(req, prompt=[t ^ 1 for t in req["prompt"]])
+                if i >= len(reqs):  # the system outran the pool: lap 1, 2, ...
+                    req = traffic.renewed(req, i // len(reqs))
                 t = asyncio.ensure_future(
                     stream_request(session, url, model, req, clock0, now))
                 tasks.append(t)
@@ -167,6 +195,7 @@ async def run_phase(session, url: str, model: str, phase: dict, seconds: float) 
         "in_flight_at_end": in_flight_at_end,
         "late_s": late,
         "pool": len(reqs),
+        "wrapped": max(0, len(tasks) - len(reqs)),
     }
 
 
@@ -188,7 +217,7 @@ def phase_report(out: dict, seconds: float) -> dict:
         "prompt_tokens_total": sum(r["prompt_len"] for r in recs),
         "in_flight_at_end": out["in_flight_at_end"],
         "t_drained": out["t_drained"],
-        "pool": out["pool"],
+        "pool": out["pool"], "wrapped": out["wrapped"],
         "generator_late_ms": {
             "n": len(late),
             "p50": late[len(late) // 2] * 1e3 if late else 0.0,
@@ -208,15 +237,68 @@ async def scrape(session, url: str) -> str:
         return await resp.text()
 
 
-async def probe(session, url: str, model: str, job: dict) -> dict:
+async def probe(session, url: str, model: str, job: dict, flip_last: bool = False) -> dict:
+    """The probe prompt alone on the server, greedy, with the wire's
+    log-probabilities.  ``flip_last`` changes its last token (the control)."""
     p = job["probe"]
     req = traffic.build_requests(
         {"prompt": {"dist": "fixed", "value": p["prompt_len"]},
          "output": {"dist": "fixed", "value": p["max_tokens"]}},
         1, p["seed"], job["vocab"], salt=7)[0]
-    rec = await stream_request(session, url, model, req, time.perf_counter(), 0.0)
+    if flip_last:
+        req["prompt"][-1] ^= 1
+    rec = await stream_request(session, url, model, req, time.perf_counter(), 0.0,
+                               logprobs=PROBE_TOP)
     return {"ok": rec["ok"], "text": rec["text"], "error": rec["error"],
-            "seconds": rec["t_last"]}
+            "seconds": rec["t_last"], "values": rec["logprobs"]}
+
+
+def runs_gap(a: dict, b: dict):
+    """The widest gap between two probe runs over every generated position;
+    None where a run failed or reported no values, or where the two differ in
+    their count of positions or of values at a position."""
+    va, vb = a["values"], b["values"]
+    if not (a["ok"] and b["ok"] and va) or len(va) != len(vb):
+        return None
+    gaps = [values_gap(x, y) for x, y in zip(va, vb)]
+    return None if None in gaps else max(gaps)
+
+
+def probe_verdict(before: list, after: list, limits: dict, control: dict | None = None) -> dict:
+    """``before`` and ``after`` are two runs each of the probe, on either side
+    of the window: (cold, hit) and (either, hit).  The probe's hit begins on a
+    prefill-chunk boundary, so the hit's one prompt step IS the cold prefill's
+    last: one program over the same values, and every run decodes from the
+    same pages.  Identical means, at EVERY generated position, (i) hit against
+    hit, one from each side of the window, within ``hit_gap`` and (ii) cold
+    against hit, before the window, within ``cold_gap``.  The first run after
+    the window may find any part of the prompt still cached (a part that ends
+    inside a chunk is another chunking, so another rounding): its gap is
+    reported and judges nothing, as the served text does, in which one glyph
+    stands for most ids."""
+    runs = before + after
+
+    def judge(cold_run: dict, hit_before: dict, hit_after: dict) -> dict:
+        hit, cold = runs_gap(hit_before, hit_after), runs_gap(cold_run, hit_before)
+        ok = (all(r["ok"] for r in runs) and hit is not None and cold is not None
+              and hit <= limits["hit_gap"] and cold <= limits["cold_gap"])
+        return {"identical": ok, "hit_gap": hit, "cold_gap": cold}
+
+    out = judge(before[0], before[1], after[1])
+    out.update({
+        "limits": limits,
+        "after_first_gap": runs_gap(after[0], after[1]),
+        "positions": [len(r["values"]) for r in runs],
+        "values_per_position": sorted({len(v) for v in before[1]["values"]}),
+        "text_identical": all(r["ok"] for r in runs) and len({r["text"] for r in runs}) == 1,
+        "seconds": [r["seconds"] for r in runs],
+        "errors": [r["error"] for r in runs if r["error"]],
+    })
+    if control is not None:
+        # The hit with its last prompt token changed, in the place of the hit
+        # before the window: both comparisons read it.
+        out["control"] = dict(judge(before[0], control, after[1]), ok=control["ok"])
+    return out
 
 
 async def run_cell(job: dict, session) -> dict:
@@ -228,21 +310,21 @@ async def run_cell(job: dict, session) -> dict:
     window = traffic.build_phase(mix, params, seed, seconds, vocab, salt=202)
     say("warm_start")
     warm_out = phase_report(await run_phase(session, url, model, warm, warm_s), warm_s)
-    probe_before = await probe(session, url, model, job)
+    before = [await probe(session, url, model, job) for _ in range(2)]  # cold, then a hit
     metrics_before = await scrape(session, url)
     say("window_start")
     out = await run_phase(session, url, model, window, seconds)
     say("window_end", t_end=out["t_end"])
     metrics_after = await scrape(session, url)
-    probe_after = await probe(session, url, model, job)
+    after = [await probe(session, url, model, job) for _ in range(2)]  # either, then a hit
+    control = (await probe(session, url, model, job, flip_last=True)
+               if job["probe"].get("control") else None)
     report = phase_report(out, seconds)
     for k in ("ttft_s", "tpot_s", "requests"):
         warm_out.pop(k)
     return {
         "window": report, "warm": warm_out,
-        "probe": {"before": probe_before, "after": probe_after,
-                  "identical": probe_before["ok"] and probe_after["ok"]
-                  and probe_before["text"] == probe_after["text"]},
+        "probe": probe_verdict(before, after, job["probe"]["limits"], control),
         "metrics_before": metrics_before, "metrics_after": metrics_after,
     }
 

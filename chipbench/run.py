@@ -47,7 +47,16 @@ from chipbench import loader, promtext, stats, trace_reduce  # noqa: E402
 READY_TIMEOUT_S = 1100.0
 WARM_SECONDS = 5.0
 WARM_MAX_OUTPUT = 32
-PROBE = {"prompt_len": 700, "max_tokens": 32, "seed": 20260927}
+# The probe (loadgen.probe_verdict): `chunks` whole prefill chunks and a tail
+# shorter than a page, so that its prefix hit (the prompt's whole pages) begins
+# ON a chunk boundary and the hit's one prompt step is the cold prefill's last
+# step: one program over the same values.  Cold against hit and hit against
+# hit are then exact comparisons, in every cell, whatever its pages' type: the
+# limit is 0 nats (README; the readings: PERF.md section 2).  A hit that begins
+# inside a chunk is another chunking of the same prompt and reads 0.02-0.16
+# nats at the first position, as much as another prompt does.
+PROBE = {"chunks": 2, "tail_tokens": 8, "max_tokens": 32, "seed": 20260927,
+         "limits": {"hit_gap": 0.0, "cold_gap": 0.0}}
 TRACE_SECONDS = 3.0
 # Keys of a configuration file that are the harness's; every other top-level
 # key is the model's HF-style config.json and goes to --model-config.
@@ -84,6 +93,20 @@ def serve_argv(config: dict, model_path: str, port: int, rehearse: bool) -> list
     for flag, value in serve_of(config, rehearse).items():
         argv += ["--" + flag.replace("_", "-"), str(value)]
     return argv
+
+
+def probe_of(serve: dict, cell: str) -> dict:
+    """The probe's sizes under a cell's serving flags (see PROBE)."""
+    chunk, page = int(serve["prefill_chunk"]), int(serve.get("block_size", 16))
+    prompt_len = PROBE["chunks"] * chunk + PROBE["tail_tokens"]
+    if chunk % page or not 0 < PROBE["tail_tokens"] < page \
+            or prompt_len + PROBE["max_tokens"] > int(serve["max_model_len"]):
+        raise loader.BenchmarkError(
+            f"{cell}: a probe of {PROBE['chunks']} chunks of {chunk} tokens and "
+            f"{PROBE['tail_tokens']} more does not hit on a chunk boundary with pages of {page}, "
+            f"or does not fit max_model_len {serve['max_model_len']}")
+    return {"prompt_len": prompt_len, "max_tokens": PROBE["max_tokens"], "seed": PROBE["seed"],
+            "limits": dict(PROBE["limits"])}
 
 
 def build_native() -> None:
@@ -180,17 +203,18 @@ class Run:
         raise RuntimeError(f"the server was not ready after {READY_TIMEOUT_S:.0f}s")
 
     def job(self, results_path: str) -> dict:
-        mix, probe = dict(self.cell["mix"]), dict(PROBE)
+        mix, params = dict(self.cell["mix"]), self.cell["params"]
         if self.rehearse:
             from chipbench import traffic
 
             scale = self.config["rehearsal"]["length_scale"]
             mix["prompt"] = traffic.scale_dist(mix["prompt"], scale)
             mix["output"] = traffic.scale_dist(mix["output"], scale)
-            probe.update(prompt_len=70, max_tokens=8)
+        probe = probe_of(self.serve_flags, self.cell["name"])
+        probe["control"] = bool(self.args.probe_control)
         return {
             "mode": "run", "url": self.url(), "model": self.config["name"],
-            "mix": mix, "params": self.cell["params"], "seed": self.args.seed,
+            "mix": mix, "params": params, "seed": self.args.seed,
             "seconds": self.args.seconds, "warm_seconds": WARM_SECONDS,
             "warm_max_output": WARM_MAX_OUTPUT, "vocab": self.model["vocab_size"],
             "probe": probe, "results_path": results_path,
@@ -281,9 +305,18 @@ class Run:
             "before": promtext.value(before, "dynamo_tpu_engine_compiled_programs"),
             "after": promtext.value(after, "dynamo_tpu_engine_compiled_programs"),
         }
+        probe = res["probe"]
+        compared = {
+            "short_answers": {"value": window["short"], "limit": 0},
+            "probe_hit_gap": {"value": probe["hit_gap"], "limit": probe["limits"]["hit_gap"]},
+            "probe_cold_gap": {"value": probe["cold_gap"], "limit": probe["limits"]["cold_gap"]},
+            "programs_compiled_in_window": {
+                "value": None if None in programs.values()
+                else programs["after"] - programs["before"], "limit": 0},
+        }
         checks = {
             "no_short_answers": window["short"] == 0,
-            "probe_identical": res["probe"]["identical"],
+            "probe_identical": probe["identical"],
             "no_compile_in_window": programs["before"] is not None
             and programs["before"] == programs["after"],
             "device_in_peaks": bool(self.peaks) and not self.rehearse,
@@ -339,7 +372,7 @@ class Run:
             "samples": {"completed_in_window": window["n_completed"],
                         "with_tpot": window["n_tpot"],
                         "in_flight_at_end": window["in_flight_at_end"],
-                        "pool": window["pool"]},
+                        "pool": window["pool"], "wrapped": window["wrapped"]},
             "generator_late_ms": window["generator_late_ms"],
             "errors": window["errors"], "short_tails": notes,
             # What the traced run saw end to end (3 s of it under the profiler):
@@ -355,10 +388,16 @@ class Run:
                 "compiled_programs": programs,
             },
             "drain_s": window["t_drained"] - self.args.seconds,
-            "probe_s": [res["probe"]["before"]["seconds"], res["probe"]["after"]["seconds"]],
+            # Reported, never judged: the text (one glyph stands for most
+            # ids), the first run after the window against its hit, the control.
+            "probe_text_identical": probe["text_identical"],
+            "probe": {k: probe.get(k) for k in ("after_first_gap", "positions", "seconds",
+                                                "values_per_position", "errors", "control")},
             "warm": {k: res["warm"][k] for k in ("attempted", "failed")},
             "hbm_bytes_in_use": mem[0].get("bytes_in_use"),
             "engine": promtext.labels_of(after, "dynamo_tpu_engine_info"),
+            # Last: every number `correct` compared, beside its limit.
+            "compared": compared,
         })
         return line
 
@@ -370,6 +409,9 @@ def parse_args(argv=None):
     p.add_argument("--seconds", type=float, required=True)
     p.add_argument("--trace", type=int, choices=(0, 1), default=0)
     p.add_argument("--rehearse-cpu", action="store_true")
+    p.add_argument("--probe-control", type=int, choices=(0, 1), default=0,
+                   help="also send the probe with its last prompt token changed and report "
+                        "what the comparison says of it (never part of a check's run)")
     return p.parse_args(argv)
 
 
@@ -416,6 +458,10 @@ def main(argv=None) -> int:
         sys.stdout.flush()
         print(json.dumps(line), flush=True)
     log(f"exit {rc}")
+    if rc == 0:
+        for name, c in line["compared"].items():
+            print(f"compared {name}: {c['value']} (limit {c['limit']})", file=sys.stderr)
+        sys.stderr.flush()
     return rc
 
 

@@ -105,10 +105,12 @@ def _tokens(rng: random.Random, n: int, vocab: int) -> list:
 
 
 def build_requests(mix: dict, n: int, seed: int, vocab: int, salt: int = 0) -> list:
-    """n requests ``{"prompt": [ids], "max_tokens": k, "prompt_len": m}``:
-    sizes and their order from the schedule, tokens from ``seed``.  ``salt``
-    separates the phases of one run (warm traffic, window) so that they share
-    neither order nor prompt."""
+    """n requests ``{"prompt": [ids], "max_tokens": k, "prompt_len": m,
+    "shared_len": h}``: sizes and their order from the schedule, tokens from
+    ``seed``; the first ``shared_len`` tokens are the request's group's shared
+    prefix (0 when the mix shares nothing).  ``salt`` separates the phases of
+    one run (warm traffic, window) so that they share neither order nor
+    prompt."""
     order = schedule_rng(mix, salt)
     p_lens = shuffled(lengths(mix["prompt"], n), order)
     o_lens = shuffled(lengths(mix["output"], n), order)
@@ -125,20 +127,33 @@ def build_requests(mix: dict, n: int, seed: int, vocab: int, salt: int = 0) -> l
     out = []
     for i, (pl, ol) in enumerate(zip(p_lens, o_lens)):
         body = random.Random(rng.getrandbits(48))
-        if prefixes:
-            head = prefixes[i % len(prefixes)][:pl]
-            prompt = head + _tokens(body, pl - len(head), vocab)
-        else:
-            prompt = _tokens(body, pl, vocab)
-        out.append({"prompt": prompt, "max_tokens": ol, "prompt_len": pl})
+        head = prefixes[i % len(prefixes)][:pl] if prefixes else []
+        prompt = head + _tokens(body, pl - len(head), vocab)
+        out.append({"prompt": prompt, "max_tokens": ol, "prompt_len": pl,
+                    "shared_len": len(head)})
     return out
+
+
+def renewed(req: dict, lap: int = 1) -> dict:
+    """The request a closed loop sends on its ``lap``-th pass beyond its pool:
+    the same sizes, the shared prefix kept, every token of its own part changed,
+    and changed otherwise on every lap (``t ^ lap``: lap 1 is ``t ^ 1``), so
+    that a later lap does not send an earlier one's prompts again as
+    whole-prompt hits.  A new turn on a context the replica holds; where
+    nothing is shared, a new prompt.  The flips 1 to 15 stay among the 16 ids
+    of a token's aligned group, above ``TOKEN_LO``; the 16th lap, which no
+    cell comes near, begins them again."""
+    flip = 1 + (lap - 1) % (TOKEN_LO - 1)
+    k, prompt = req.get("shared_len", 0), req["prompt"]
+    return dict(req, prompt=prompt[:k] + [t ^ flip for t in prompt[k:]])
 
 
 def build_phase(mix: dict, params: dict, seed: int, seconds: float, vocab: int,
                 salt: int = 0, max_output: int | None = None) -> dict:
     """One phase of traffic: the requests, and for an open loop their due
     times.  A closed loop gets ``params['pool_per_s'] * seconds`` requests to
-    draw from in order (wrapping, should the system outrun the pool)."""
+    draw from in order (wrapping, should the system outrun the pool: see
+    ``renewed``)."""
     loop = mix["loop"]
     if loop == "open":
         due = arrival_times(mix, float(params["rate_rps"]), seconds,

@@ -113,3 +113,43 @@ def test_the_cell_reports_the_roofline_and_the_idle_share():
 def test_every_new_metric_says_what_it_reads(name):
     spec = loader.read_json(loader.data_file("layer_metrics", name))
     assert len(spec["about"]) > 80
+
+
+# The names docs/tracing.md lists for the PREFILL side of the two XLA-stage
+# metrics (its rows "selector, device side" and "sparse latent attention,
+# device side", as of PR 36), the decode-side names a saved trace of PR 37
+# still holds (kept), and the ones no program has held since PR 31 (dropped).
+SELECT_PREFILL = ["fusion f32[64,1024]", "reduce-window s32[64,72,128]",
+                  "convert_reduce_fusion s32[64]", "slice_reduce_fusion s32[64,72]",
+                  "fusion bf16[64,16,128]"]
+SPARSE_PREFILL = ["fusion f32[64,128]", "fusion f32[64,128,512]", "fusion bf16[64,16,640]",
+                  "constant_dynamic-slice_fusion bf16[64,128,640]", "broadcast f32[64,128,512]"]
+PATTERN_CASES = (
+    [("dsa_select_time_share", n, True)
+     for n in SELECT_PREFILL + ["fusion bf16[9216,16,128]", "fusion f32[16,9216]"]]
+    + [("dsa_select_time_share", n, False) for n in ("sort f32[16,9216]", "fusion u32[16,9216]")]
+    + [("mla_sparse_attn_time_share", n, True) for n in SPARSE_PREFILL + ["fusion bf16[16,128,512]"]]
+    + [("mla_sparse_attn_time_share", n, False)
+       for n in ("fusion bf16[32768,640]", "fusion s32[32768]", "fusion f32[16,128,2048]",
+                 "mla_sparse_decode_attention bf16[16,128,512]")])
+
+
+@pytest.mark.parametrize("metric,op,listed", PATTERN_CASES)
+def test_the_pruned_patterns_read_what_runs_and_nothing_dropped(metric, op, listed):
+    spec = loader.read_json(loader.data_file("layer_metrics", metric))
+    events = [(op, 0, 7), ("fusion f32[16]", 10, 5)]
+    assert tr.sum_matching_ns(events, spec["args"]["pattern"]) == (7 if listed else 0)
+    docs = os.path.join(ROOT, "docs", "tracing.md")
+    if listed and os.path.exists(docs):
+        assert f"`{op}`" in open(docs).read()
+
+
+@pytest.mark.parametrize("name,stale", [
+    ("mla_dense_decode_step_roofline", "reads every held expert"),
+    ("mla_dsa_decode_step_roofline", "reads every held expert"),
+    ("moe_local_pairs_per_token", "the 16 experts held here"),
+    ("moe_held_experts_read_share", "100% means the mechanism idles")])
+def test_the_reworded_metrics_no_longer_say_what_is_false(name, stale):
+    spec = loader.read_json(loader.data_file("layer_metrics", name))
+    assert stale not in spec["about"] and len(spec["about"]) > 80
+    assert "bench.py l." not in loader.read_json(os.path.join(ROOT, "chipbench", "peaks.json"))["source"]

@@ -119,3 +119,105 @@ def test_rehearsal_walks_the_whole_flow_and_is_never_a_result(cell, trace):
     else:
         assert line["metrics"]["setup_s"]["value"] > 0 and "ttft_ms_p50" in line["metrics"]
         assert line["generator_late_ms"]["n"] == line["attempted"]
+
+
+# ------------------------------------------------ the rest of a run, no chip
+# The harness's look for a chip is skipped and the server is a fake one; the
+# generator's run of a cell and Run.report are the real ones.  A sound server
+# reads correct; each fault planted under the timed path reads not correct.
+FAULTS = {
+    "sound": ({}, None),
+    "an answer altered where it is produced: the hit after the window is another":
+        ({"nudge": {4: 1e-3}}, "probe_identical"),
+    "cold prefill and the prefix hit part": ({"nudge": {1: 0.5}}, "probe_identical"),
+    "cold prefill and the prefix hit part by a rounding":
+        ({"nudge": {1: 1e-5}}, "probe_identical"),
+    "answers a token short": ({"short_by": 1}, "no_short_answers"),
+    "a program compiled inside the window": ({"programs_step": 1}, "no_compile_in_window"),
+}
+
+
+@pytest.mark.parametrize("fault", list(FAULTS))
+async def test_report_reads_correct_only_on_a_sound_server(fault):
+    import argparse
+    import time
+
+    import aiohttp
+
+    from chipbench import loadgen
+    from test_chipbench_loadgen import FakeServer, _nudged
+
+    server, failing = FAULTS[fault]
+    server = dict(server)
+    if "nudge" in server:
+        server["top_values"] = _nudged(server.pop("nudge"))
+    cell = loader.load_cell("kimi-k2-6l-ep32.agent-shared")
+    args = argparse.Namespace(workload=cell["name"], seed=3000000001, seconds=0.6, trace=0,
+                              rehearse_cpu=False, probe_control=0)
+    r = harness.Run(args, cell)
+    try:
+        r.peaks, r.device = {"hbm_bytes_per_s": 1.0}, {"platform": "tpu", "kind": "fake", "count": 1}
+        mix = {"loop": "closed", "prompt": {"dist": "uniform", "min": 30, "max": 40},
+               "output": {"dist": "uniform", "min": 8, "max": 12},
+               "sharing": {"kind": "shared_prefix", "groups": 2, "prefix_len": 16}}
+        async with FakeServer(gap_s=0.002, **server) as srv, aiohttp.ClientSession() as s:
+            job = dict(r.job("unused"), url=srv.url, mix=mix, vocab=300, warm_seconds=0.2,
+                       params={"clients": 3, "pool_per_s": 10.0})
+            assert job["probe"]["prompt_len"] == 2 * 512 + 8  # two chunks and a tail
+            job["probe"].update(prompt_len=20, max_tokens=8)
+            res = await loadgen.run_cell(job, s)
+        line = r.report(res, {"window_start": time.time()}, {}, time.time())
+    finally:
+        shutil.rmtree(r.tmp, ignore_errors=True)
+    assert CONTRACT_KEYS <= set(line) and list(line)[-1] == "compared"
+    assert line["correct"] is (failing is None), (fault, line["checks"], line["compared"])
+    failed = [k for k, v in line["checks"].items() if not v]
+    # An answer cut short fails more than its own check: nothing completes.
+    assert failed == [failing] if failing in ("probe_identical", "no_compile_in_window") \
+        else failing in failed if failing else not failed
+    c = line["compared"]
+    assert set(c) == {"short_answers", "probe_hit_gap", "probe_cold_gap",
+                      "programs_compiled_in_window"}
+    assert all(set(v) == {"value", "limit"} for v in c.values())
+    assert c["probe_cold_gap"]["limit"] == harness.PROBE["limits"]["cold_gap"] == 0.0
+    assert c["probe_hit_gap"]["limit"] == harness.PROBE["limits"]["hit_gap"] == 0.0
+    assert line["samples"]["wrapped"] == line["attempted"] - line["samples"]["pool"] > 0
+    assert line["probe_text_identical"] is (failing != "no_short_answers")
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in loader.read_json(
+    os.path.join(ROOT, "BENCHMARK.json"))["workloads"]])
+@pytest.mark.parametrize("rehearse", [False, True])
+def test_every_cells_probe_hits_on_a_chunk_boundary_under_exact_limits(cell, rehearse):
+    """The relation that makes cold against hit an exact comparison, for every
+    cell that is there or comes: whole chunks and a tail inside one page, so the
+    hit (the prompt's whole pages) begins where the cold prefill's last step does."""
+    import argparse
+
+    loaded = loader.load_cell(cell)
+    args = argparse.Namespace(workload=cell, seed=1, seconds=1.0, trace=0, rehearse_cpu=rehearse,
+                              probe_control=1)
+    r = harness.Run(args, loaded)
+    try:
+        probe = r.job("unused")["probe"]
+    finally:
+        shutil.rmtree(r.tmp, ignore_errors=True)
+    serve = harness.serve_of(loaded["config"], rehearse)
+    chunk, page = serve["prefill_chunk"], serve.get("block_size", 16)
+    n = probe["prompt_len"]
+    hit = (n - 1) // page * page  # whole pages; a whole-pages prompt gives its last one back
+    assert hit > 0 and hit % chunk == 0 and 0 < n - hit < page
+    assert n + probe["max_tokens"] <= serve["max_model_len"]
+    assert probe["limits"] == {"hit_gap": 0.0, "cold_gap": 0.0} and probe["control"] is True
+    assert "probe_cold_gap" not in loaded["params"], "the limit is the harness's, not a cell's"
+
+
+@pytest.mark.parametrize("serve,why", [
+    ({"prefill_chunk": 500, "max_model_len": 4096}, "a chunk that is no whole number of pages"),
+    ({"prefill_chunk": 512, "block_size": 8, "max_model_len": 4096}, "a tail as long as a page"),
+    ({"prefill_chunk": 512, "max_model_len": 1056}, "a context the probe does not fit"),
+])
+def test_a_probe_that_cannot_hit_on_a_chunk_boundary_is_refused(serve, why):
+    with pytest.raises(loader.BenchmarkError, match="chunk boundary"):
+        harness.probe_of(serve, "some.cell")
+    assert harness.probe_of({"prefill_chunk": 512, "max_model_len": 1064}, "c")["prompt_len"] == 1032

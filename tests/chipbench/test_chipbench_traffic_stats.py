@@ -206,3 +206,38 @@ def test_the_generator_side_imports_neither_jax_nor_the_program():
     )
     p = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True, timeout=120)
     assert p.returncode == 0, p.stderr
+
+
+# ------------------------------------------------- what a wrapped request keeps
+@pytest.mark.parametrize("prefix_len,lo,hi", [(64, 80, 120), (64, 40, 120), (200, 80, 120)])
+def test_shared_len_is_the_shorter_of_prefix_and_prompt(prefix_len, lo, hi):
+    mix = dict(_mix("prefill-closed"), prompt={"dist": "uniform", "min": lo, "max": hi},
+               sharing={"kind": "shared_prefix", "groups": 2, "prefix_len": prefix_len})
+    reqs = traffic.build_requests(mix, 16, 3, 50000)
+    assert all(r["shared_len"] == min(prefix_len, r["prompt_len"]) for r in reqs)
+    assert len({r["shared_len"] == r["prompt_len"] for r in reqs}) == (2 if lo < prefix_len < hi else 1)
+    by_group = {i % 2: r for i, r in enumerate(reqs)}
+    for i, r in enumerate(reqs):
+        k = min(r["shared_len"], by_group[i % 2]["shared_len"])
+        assert r["prompt"][:k] == by_group[i % 2]["prompt"][:k]
+
+
+@pytest.mark.parametrize("name", MIXES)
+def test_a_renewed_request_keeps_its_sizes_and_what_its_mix_shares(name):
+    mix = _mix(name)
+    shares = mix.get("sharing", {"kind": "none"})["kind"] == "shared_prefix"
+    for r in traffic.build_requests(mix, 6, 3000000001, 50000, salt=202):
+        k = r["shared_len"]
+        assert k == (min(mix["sharing"]["prefix_len"], r["prompt_len"]) if shares else 0)
+        new = traffic.renewed(r)
+        assert {key: new[key] for key in new if key != "prompt"} == \
+            {key: r[key] for key in r if key != "prompt"}
+        assert new["prompt"][:k] == r["prompt"][:k] and len(new["prompt"]) == r["prompt_len"]
+        assert all(a != b and a >= traffic.TOKEN_LO for a, b in zip(new["prompt"][k:], r["prompt"][k:]))
+        assert k < r["prompt_len"], "every committed mix leaves a request a part of its own"
+        # A second lap is new traffic too: it repeats neither the pool nor the first lap.
+        again = traffic.renewed(r, 2)
+        assert again["prompt"][:k] == r["prompt"][:k]
+        assert all(len({a, b, c}) == 3 and c >= traffic.TOKEN_LO
+                   for a, b, c in zip(r["prompt"][k:], new["prompt"][k:], again["prompt"][k:]))
+    assert traffic.renewed(r, 15) != traffic.renewed(r, 1) == traffic.renewed(r, 16)
