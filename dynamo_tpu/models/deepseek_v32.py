@@ -289,7 +289,9 @@ def forward_ragged(
     *,
     decode: bool = False,
     return_selection: bool = False,
-    block_q: Optional[int] = None,  # each attention path has its own default
+    # Blocks of the selector's prompt loop (ops/sparse_mla.py) alone; tests make
+    # them small.  The dense path's kernel has its own constants (ops/dense_mla.py).
+    block_q: int = 64,
     block_k: int = 1024,
     **_llama_only,  # attn_impl, kernels, kv_scale, mesh, lora_rank: family.py checks them
 ) -> Tuple[jnp.ndarray, LatentKVCache, Any]:
@@ -359,7 +361,7 @@ def forward_ragged(
         else:
             res = sparse_prefill_attention(
                 q_abs, qi, wi, lat, idx, pos, rb.kv_lens, tables, rb.cu_q_lens, rb.num_seqs,
-                block_q=block_q or 64, block_k=block_k, return_mask=return_selection, **kw)
+                block_q=block_q, block_k=block_k, return_mask=return_selection, **kw)
             o_lat, sel = res if return_selection else (res, None)
             # Decode rows riding a mixed step: the one-query path, only when
             # the step has any.
@@ -395,8 +397,7 @@ def forward_ragged(
         else:
             o = dense_prefill_attention(
                 jnp.concatenate([q[..., :dn], q_rope], axis=-1), lat, lp["w_uk"], lp["w_uv"],
-                pos, rb.kv_lens, tables, rb.cu_q_lens, rb.num_seqs, sm_scale=sm_scale,
-                block_q=block_q or 128, block_k=block_k)
+                rb.kv_lens, tables, rb.cu_q_lens, rb.num_seqs, sm_scale=sm_scale)
             o1 = jax.lax.cond(jnp.any(single), one_token_rows,
                               lambda _: jnp.zeros((S, H, dv), o.dtype), None)
             o = o.at[jnp.where(single, first, T)].set(o1, mode="drop")

@@ -3,7 +3,7 @@ latent family without a selector (``index_topk`` 0: DeepSeek-V3, Kimi-K2),
 over the same paged latent cache as ops/sparse_mla.py.
 
 Two forms of the same mathematics (docs/deepseek_v32.md has the operation
-counts), chosen by how many queries share a key:
+counts), chosen by how many queries share a key, each ONE Pallas call:
 
 - ``dense_decode_attention``: rows of ONE query token.  ABSORBED form: the
   query carries W^UK, the cached 576-value entry is K and V at once.  The
@@ -12,19 +12,27 @@ counts), chosen by how many queries share a key:
   operand.  The call is named ``mla_dense_decode_attention``.
 - ``dense_prefill_attention``: rows of MANY query tokens (prompt chunks with
   a cached past).  DECOMPRESSED form: a key block's entries become per-head
-  keys [W^UK_h c_j ; k^R_j] and values W^UV_h c_j ONCE, and every query block
-  of the row's chunk attends to them (one causal pass, a running softmax):
+  keys [W^UK_h c_j ; k^R_j] and values W^UV_h c_j ONCE, and every query of
+  the row's chunk attends to them (one causal pass, a running softmax):
   2 N H (192 + 128) operations a query plus 2 N H 512 x 256 a chunk, where
-  absorbed costs 2 N H (576 + 512) a query.  A device loop over (row, key
-  block, query block) whose work follows ``kv_lens`` and the rows' query
-  counts, not ``max_model_len``.  Plain XLA under the scope
-  ``mla_dense_prefill_attention`` (a Pallas kernel would take that name).
+  absorbed costs 2 N H (576 + 512) a query.  The call is named
+  ``mla_dense_prefill_attention``: a program holds ``PREFILL_HEADS`` heads of
+  the step's tokens and walks each prompt row's live pages through the page
+  table, two key blocks deep; the running softmax (max, sum, float32 output)
+  is VMEM scratch from a row's first key block to its last and the output is
+  written once.  Work follows ``kv_lens`` and the rows' query counts, not
+  ``max_model_len`` (docs/kimi_k2.md has the grid, what is resident and the
+  block sizes with their measurements).
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import sparse_mla
 from .ragged_attention import pallas_interpret
@@ -53,85 +61,256 @@ def dense_decode_attention(
             name=SCOPES["decode"])
 
 
+# The prompt-chunk kernel's sizes, chosen by its time alone at the cell's
+# shape (64 heads, 512 queries against 13k cached positions in 16-token pages
+# of 640 lanes; docs/kimi_k2.md has the table).  Positions a key block (its
+# pages are copied, then decompressed once for the program's heads); query
+# tokens a tile of the inner loop; heads a program; and the tokens of a step
+# a program keeps resident (queries, state and output of its heads): a larger
+# step (``--prefill-chunk`` plus ``--max-batch`` over 1024; no cell of the
+# benchmark has one) is walked in blocks of that many, and a row that lies
+# across two of them is decompressed for each.
+PREFILL_BLOCK_K = 1024
+PREFILL_BLOCK_Q = 256
+PREFILL_HEADS = 16
+PREFILL_STEP_TOKENS = 1024
+
+
+def _prefill_kernel(
+    # scalar prefetch (SMEM)
+    kv_lens_ref,  # [S] int32
+    tables_ref,  # [S, PP] int32
+    cu_ref,  # [S + 1] int32
+    num_ref,  # [1] int32
+    # operands
+    q_ref,  # [Hg, TB, dn + tail] VMEM: the program's heads of the step's tokens, zero lanes last
+    wuk_ref,  # [Hg, Rkv, dn] VMEM
+    wuv_ref,  # [Hg, Rkv, dv] VMEM
+    lat_ref,  # [NP, ps, Rkv + tail] HBM, copied page by page
+    o_ref,  # [TB, Hg * dv] VMEM
+    # scratch
+    buf,  # [2, ppb, ps, Rkv + tail] in the pages' dtype
+    kbuf,  # [Hg, C, dn + tail]: a key block's keys, [W^UK_h c | k^R | zero lanes]
+    vbuf,  # [Hg, C, dv]: its values
+    m_ref,  # [Hg, TB, 1] f32
+    l_ref,  # [Hg, TB, 1] f32
+    acc_ref,  # [Hg, TB, dv] f32
+    sems,  # DMA semaphores (2,)
+    *,
+    sm_scale: float,
+    page_size: int,
+    pages_per_seq: int,
+    ppb: int,
+    tq: int,
+):
+    """Program (g, b): heads [g Hg, (g + 1) Hg) of the step's tokens [b TB,
+    (b + 1) TB).  For every row of more than one query token that has tokens
+    there, the row's live key blocks in order; a block's pages are copied
+    while the block before it is computed, decompressed once, and every query
+    tile of the row attends to it.  (Grid programs run in order on one core:
+    no dimension_semantics.)"""
+    Hg, TB, _ = q_ref.shape
+    dn, dv = wuk_ref.shape[2], wuv_ref.shape[2]
+    rank = wuk_ref.shape[1]
+    C = ppb * page_size
+    base = pl.program_id(1) * TB
+    cdt = kbuf.dtype
+
+    # As in ops/sparse_mla.py::_decode_kernel: a short last block copies only
+    # the pages the row has, what the buffer held before stays, and a masked
+    # weight of 0 times a NaN is a NaN.  So the call starts from zeros.
+    @pl.when((pl.program_id(0) == 0) & (pl.program_id(1) == 0))
+    def _():
+        buf[...] = jnp.zeros(buf.shape, buf.dtype)
+
+    m_ref[...] = jnp.full(m_ref.shape, NEG, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def row(r, carry):
+        t0, t1, kv_len = cu_ref[r], cu_ref[r + 1], kv_lens_ref[r]
+        lo, hi = jnp.maximum(t0, base) - base, jnp.minimum(t1, base + TB) - base
+        pages = jnp.minimum(pl.cdiv(kv_len, page_size), pages_per_seq)
+        nblocks = pl.cdiv(pages, ppb)
+        first_pos = kv_len - (t1 - t0)  # the position of the row's first query
+
+        def fetch(block, slot, start):
+            first = block * ppb
+            live = jnp.minimum(ppb, pages - first)
+
+            def one(t, carry):
+                dma = pltpu.make_async_copy(
+                    lat_ref.at[tables_ref[r, first + t]], buf.at[slot, t], sems.at[slot])
+                if start:
+                    dma.start()
+                else:
+                    dma.wait()
+                return carry
+
+            # A loop, not straight-line code as in the one-query kernel: a
+            # block's copies are issued under 50 us of matmuls, and unrolled
+            # they cost a second of tracing and lowering a program.
+            jax.lax.fori_loop(0, live, one, 0)
+
+        def attend(ts, kb, masked: bool):
+            """Every head of the program: the query tile at ``ts`` against key
+            block kb.  ``masked``: the tile holds tokens of other rows, or the
+            block reaches the chunk's first position (the causal comparison)."""
+            rows = pl.ds(ts, tq)
+
+            def head(h, carry):
+                s = jax.lax.dot_general(q_ref[h, rows, :], kbuf[h], (((1,), (1,)), ((), ())),
+                                        preferred_element_type=jnp.float32) * sm_scale  # [tq, C]
+                if masked:
+                    tok = ts + jax.lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
+                    kpos = kb * C + jax.lax.broadcasted_iota(jnp.int32, (1, C), 1)
+                    live = (kpos <= first_pos + (tok + base - t0)) & (tok >= lo) & (tok < hi)
+                    s = jnp.where(live, s, NEG)
+                m_old = m_ref[h, rows, :]
+                m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                if masked:
+                    # A query with nothing live here (another row's, or all
+                    # keys in its future) must leave its state as it is.
+                    p = jnp.where(live, p, 0.0)
+                alpha = jnp.exp(m_old - m_new)
+                l_ref[h, rows, :] = l_ref[h, rows, :] * alpha + jnp.sum(p, axis=1, keepdims=True)
+                acc_ref[h, rows, :] = acc_ref[h, rows, :] * alpha + jax.lax.dot_general(
+                    p.astype(cdt), vbuf[h], (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                m_ref[h, rows, :] = m_new
+                return carry
+
+            jax.lax.fori_loop(0, Hg, head, 0)
+
+        def key_block(kb, carry):
+            slot = jax.lax.rem(kb, 2)
+
+            @pl.when(kb + 1 < nblocks)
+            def _():
+                fetch(kb + 1, jax.lax.rem(kb + 1, 2), start=True)
+
+            fetch(kb, slot, start=False)
+            lat = buf[slot].reshape(C, buf.shape[-1]).astype(cdt)
+            c = lat[:, :rank]
+
+            def decompress(h, carry):
+                # Rounded to the compute type as the einsum's result was.
+                kbuf[h, :, :dn] = jnp.dot(
+                    c, wuk_ref[h].astype(cdt), preferred_element_type=jnp.float32).astype(cdt)
+                kbuf[h, :, dn:] = lat[:, rank:]
+                vbuf[h] = jnp.dot(
+                    c, wuv_ref[h].astype(cdt), preferred_element_type=jnp.float32).astype(cdt)
+                return carry
+
+            jax.lax.fori_loop(0, Hg, decompress, 0)
+            past = (kb + 1) * C <= first_pos + 1  # every key at or below every query of the row
+
+            def tile(qt, carry):
+                ts = pl.multiple_of(qt * tq, tq)
+                own = (ts >= lo) & (ts + tq <= hi)  # the row's tokens only
+                plain = past & own
+                last_pos = first_pos + jnp.minimum(ts + tq, hi) - 1 + base - t0
+
+                pl.when(plain)(lambda: attend(ts, kb, False))
+                # A block wholly in the tile's future is skipped.
+                pl.when(jnp.logical_not(plain) & (kb * C <= last_pos))(lambda: attend(ts, kb, True))
+                return carry
+
+            jax.lax.fori_loop(lo // tq, pl.cdiv(hi, tq), tile, 0)
+            return carry
+
+        # Rows of one query token or none (the one-query kernel's), rows
+        # with no token in this block: nothing copied, nothing computed.
+        @pl.when((t1 - t0 > 1) & (hi > lo) & (kv_len > 0))
+        def _():
+            fetch(0, 0, start=True)
+            jax.lax.fori_loop(0, nblocks, key_block, 0)
+
+        return carry
+
+    jax.lax.fori_loop(0, num_ref[0], row, 0)
+    for h in range(Hg):  # static: a head is a lane range of the output
+        o_ref[:, h * dv:(h + 1) * dv] = (
+            acc_ref[h] / jnp.maximum(l_ref[h], 1e-30)).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "sm_scale", "block_k", "block_q", "heads", "step_tokens", "interpret"))
+def _prefill_call(q, lat_pages, w_uk, w_uv, kv_lens, tables, cu_q_lens, num_seqs, *,
+                  sm_scale, block_k, block_q, heads, step_tokens, interpret):
+    """The kernel's call.  Jitted, so that a process traces the kernel once
+    and a program lowers it once, whatever its layers (PERF.md section 6,
+    PR 31)."""
+    T, H, Dq = q.shape
+    Rkv, dn = w_uk.shape[1:]
+    dv = w_uv.shape[2]
+    PP = tables.shape[1]
+    ps, W = lat_pages.shape[1:]
+    tail = W - Rkv  # k^R and the zero lanes up to the stored width
+    cdt = jnp.result_type(q.dtype, lat_pages.dtype, w_uk.dtype)
+    Hg = next(n for n in range(min(heads, H), 0, -1) if H % n == 0)
+    ppb = max(1, min(block_k // ps, PP))  # pages per key block
+    C = ppb * ps
+    tq = min(block_q, -(-T // 16) * 16)
+    TB = min(step_tokens, -(-T // tq) * tq)
+    Tp = -(-T // TB) * TB
+    # Heads lead (a head of the program is a leading index); the query's lanes
+    # are padded with zeros to the key's: [q^N | q^R | 0] against [W^UK c | k^R | 0].
+    q_h = jnp.pad(q.astype(cdt), ((0, Tp - T), (0, 0), (0, dn + tail - Dq))).transpose(1, 0, 2)
+    kernel = functools.partial(_prefill_kernel, sm_scale=sm_scale, page_size=ps,
+                               pages_per_seq=PP, ppb=ppb, tq=tq)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(H // Hg, Tp // TB),
+        in_specs=[
+            pl.BlockSpec((Hg, TB, dn + tail), lambda g, b, *_: (g, b, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((Hg, Rkv, dn), lambda g, b, *_: (g, 0, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((Hg, Rkv, dv), lambda g, b, *_: (g, 0, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec(memory_space=pl.ANY),  # pages stay in HBM
+        ],
+        out_specs=pl.BlockSpec((TB, Hg * dv), lambda g, b, *_: (b, g), memory_space=pltpu.VMEM),
+        scratch_shapes=[
+            pltpu.VMEM((2, ppb, ps, W), lat_pages.dtype),
+            pltpu.VMEM((Hg, C, dn + tail), cdt),
+            pltpu.VMEM((Hg, C, dv), cdt),
+            pltpu.VMEM((Hg, TB, 1), jnp.float32),
+            pltpu.VMEM((Hg, TB, 1), jnp.float32),
+            pltpu.VMEM((Hg, TB, dv), jnp.float32),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((Tp, H * dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=96 << 20),
+        interpret=interpret,
+        name=SCOPES["prefill"],
+    )(kv_lens.astype(jnp.int32), tables.astype(jnp.int32), cu_q_lens.astype(jnp.int32),
+      num_seqs.astype(jnp.int32), q_h, w_uk, w_uv, lat_pages)
+    return out[:T].reshape(T, H, dv)
+
+
 def dense_prefill_attention(
     q: jnp.ndarray,  # [T, H, dn + dr]: per-head queries, rope applied to the last dr
     lat_pages: jnp.ndarray,  # [NP, ps, >= Rkv + dr]: [c | k^R | zero lanes]
     w_uk: jnp.ndarray,  # [H, Rkv, dn]
     w_uv: jnp.ndarray,  # [H, Rkv, dv]
-    positions: jnp.ndarray,  # [T]
     kv_lens: jnp.ndarray,  # [S]
     tables: jnp.ndarray,  # [S, PP]
     cu_q_lens: jnp.ndarray,  # [S + 1]
     num_seqs: jnp.ndarray,  # [1]
     *,
     sm_scale: float,
-    block_q: int = 128,
-    block_k: int = 1024,
 ) -> jnp.ndarray:
     """Rows of more than one query token (single-token rows are left at
-    zero: ``dense_decode_attention`` serves them).  Returns [T, H, dv]."""
-    T, H, Dq = q.shape
-    Rkv, dn = w_uk.shape[1:]
-    dv = w_uv.shape[2]
-    dr = Dq - dn
-    S, PP = tables.shape
-    ps = lat_pages.shape[1]
-    ppk = max(1, min(block_k // ps, PP))  # pages per key block
-    bk = ppk * ps
-    nkb_max = -(-PP // ppk)
-    Bq = min(block_q, T)
-    tables_kb = jnp.pad(tables, ((0, 0), (0, nkb_max * ppk - PP))).reshape(S, nkb_max, ppk)
-    # Heads lead: the per-head products are batched matmuls with no transpose.
-    q_h = jnp.pad(q, ((0, Bq), (0, 0), (0, 0))).transpose(1, 0, 2)  # [H, T + Bq, Dq]
-    pos_p = jnp.pad(positions, (0, Bq))
-    q_lens = cu_q_lens[1:] - cu_q_lens[:-1]
-    many = (q_lens > 1) & (jnp.arange(S, dtype=jnp.int32) < num_seqs[0])
-
-    def row(r, state):
-        t0, nq, kvl = cu_q_lens[r], q_lens[r], kv_lens[r]
-        pages_r = tables_kb[r]  # [nkb_max, ppk]
-
-        def key_block(kb, state):
-            lat = lat_pages[pages_r[kb]].reshape(bk, -1)
-            c, k_rope = lat[:, :Rkv], lat[:, Rkv:Rkv + dr]
-            # Decompressed ONCE a key block, for all of the row's queries.
-            k_nope = jnp.einsum("sc,hcn->hsn", c, w_uk)  # [H, bk, dn]
-            v = jnp.einsum("sc,hcv->hsv", c, w_uv)  # [H, bk, dv]
-            kpos = kb * bk + jnp.arange(bk, dtype=jnp.int32)
-
-            def query_block(qb, state):
-                m, l, acc = state
-                a = t0 + qb * Bq
-                qq = jax.lax.dynamic_slice_in_dim(q_h, a, Bq, axis=1)  # [H, Bq, Dq]
-                qpos = jax.lax.dynamic_slice_in_dim(pos_p, a, Bq)
-                q_ok = a + jnp.arange(Bq, dtype=jnp.int32) < t0 + nq
-                live = ((kpos[None, :] <= qpos[:, None]) & (kpos[None, :] < kvl)
-                        & q_ok[:, None])[None]  # [1, Bq, bk]
-                sc = (jnp.einsum("hqn,hsn->hqs", qq[..., :dn], k_nope,
-                                 preferred_element_type=jnp.float32)
-                      + jnp.einsum("hqr,sr->hqs", qq[..., dn:], k_rope,
-                                   preferred_element_type=jnp.float32))
-                sc = jnp.where(live, sc * sm_scale, NEG)
-                m_old = jax.lax.dynamic_slice_in_dim(m, a, Bq, axis=1)  # [H, Bq]
-                m_new = jnp.maximum(m_old, jnp.max(sc, axis=-1))
-                # x live: a query with nothing live here (another row's, or
-                # all keys in its future) must leave its state as it is.
-                p = jnp.exp(sc - m_new[..., None]) * live
-                alpha = jnp.exp(m_old - m_new)
-                l_new = jax.lax.dynamic_slice_in_dim(l, a, Bq, axis=1) * alpha + jnp.sum(p, axis=-1)
-                pv = jnp.einsum("hqs,hsv->hqv", p.astype(v.dtype), v,
-                                preferred_element_type=jnp.float32)
-                acc_new = jax.lax.dynamic_slice_in_dim(acc, a, Bq, axis=1) * alpha[..., None] + pv
-                upd = jax.lax.dynamic_update_slice_in_dim
-                return upd(m, m_new, a, axis=1), upd(l, l_new, a, axis=1), upd(acc, acc_new, a, axis=1)
-
-            return jax.lax.fori_loop(0, -(-nq // Bq), query_block, state)
-
-        return jax.lax.fori_loop(0, jnp.where(many[r], -(-kvl // bk), 0), key_block, state)
-
+    zero: ``dense_decode_attention`` serves them); a row's queries are its
+    last positions (``kv_len`` minus its query count onwards).  Returns
+    [T, H, dv].  Compiles for the chip or raises; under the Pallas
+    interpreter only where ``DYN_PALLAS_INTERPRET`` asks."""
     with jax.named_scope(SCOPES["prefill"]):
-        state = (jnp.full((H, T + Bq), NEG, jnp.float32), jnp.zeros((H, T + Bq), jnp.float32),
-                 jnp.zeros((H, T + Bq, dv), jnp.float32))
-        _, l, acc = jax.lax.fori_loop(0, S, row, state)
-        out = acc / jnp.maximum(l, 1e-30)[..., None]
-        return out[:, :T].transpose(1, 0, 2).astype(q.dtype)
+        return _prefill_call(
+            q, lat_pages, w_uk, w_uv, kv_lens, tables, cu_q_lens, num_seqs, sm_scale=sm_scale,
+            block_k=PREFILL_BLOCK_K, block_q=PREFILL_BLOCK_Q, heads=PREFILL_HEADS,
+            step_tokens=PREFILL_STEP_TOKENS, interpret=pallas_interpret())

@@ -57,6 +57,17 @@ def highest():
         yield
 
 
+@pytest.fixture(autouse=True)
+def small_prefill_blocks(monkeypatch):
+    """The prompt-chunk kernel's sizes at this file's shapes: key blocks of 2
+    pages, query tiles of 8 tokens, 2 of the 4 heads a program, 16 tokens of a
+    step resident: several of each a row, and rows that lie across two."""
+    monkeypatch.setattr(dense_mla, "PREFILL_BLOCK_K", 8)
+    monkeypatch.setattr(dense_mla, "PREFILL_BLOCK_Q", 8)
+    monkeypatch.setattr(dense_mla, "PREFILL_HEADS", 2)
+    monkeypatch.setattr(dense_mla, "PREFILL_STEP_TOKENS", 16)
+
+
 @pytest.fixture(scope="module")
 def model():
     cfg = ModelConfig.from_hf_config(HF, name="kimi-test").with_overrides(dtype="float32")
@@ -91,24 +102,23 @@ def close(a, b):
 # ------------------------------------------------------------- (a) logits
 @pytest.mark.parametrize("sealed_prefix", [False, True], ids=["cold", "sealed-prefix"])
 def test_chunked_prefill_then_decode_matches_the_reference(model, sealed_prefix):
-    """Chunks of 16 through the paged latent cache (query blocks of 8, key
-    blocks of 8: several of each a row), then decode (the fused program's
-    path and a one-token row riding a mixed step).  ``sealed-prefix``: the
-    first 16 tokens were computed by ANOTHER request into pages this one
-    shares."""
+    """Chunks of 16 through the paged latent cache (the kernel's query tiles
+    of 8 and key blocks of 8: several of each a row), then decode (the fused
+    program's path and a one-token row riding a mixed step).
+    ``sealed-prefix``: the first 16 tokens were computed by ANOTHER request
+    into pages this one shares."""
     cfg, params, toks, want = model
     cache = ds.LatentKVCache.create(cfg, NPAGES, PS, dtype=jnp.float32)
     assert cache.index is None
     table = np.arange(5, 5 + PP).astype(np.int32)
-    kw = dict(block_q=8, block_k=8)
     if sealed_prefix:
         other = table.copy()
         other[4:] = np.arange(30, 30 + PP - 4)  # shares the first 4 pages only
-        _, cache, _ = ds.forward_ragged(params, cfg, batch(toks, other, 0, 16, 16), cache, **kw)
+        _, cache, _ = ds.forward_ragged(params, cfg, batch(toks, other, 0, 16, 16), cache)
     else:
-        lg, cache, _ = ds.forward_ragged(params, cfg, batch(toks, table, 0, 16, 16), cache, **kw)
+        lg, cache, _ = ds.forward_ragged(params, cfg, batch(toks, table, 0, 16, 16), cache)
         assert close(lg[0], want[15]) < LOGIT_TOL
-    lg, cache, aux = ds.forward_ragged(params, cfg, batch(toks, table, 16, 13, 16), cache, **kw)
+    lg, cache, aux = ds.forward_ragged(params, cfg, batch(toks, table, 16, 13, 16), cache)
     assert close(lg[0], want[28]) < LOGIT_TOL
     assert int(aux[1]) == 13 * 2  # real tokens x MoE layers; padding is not counted
     assert cache.index is None
@@ -116,7 +126,7 @@ def test_chunked_prefill_then_decode_matches_the_reference(model, sealed_prefix)
         decode = t % 2 == 0
         lg, cache, _ = ds.forward_ragged(
             params, cfg, batch(toks, table, t, 1, S if decode else 16, decode), cache,
-            decode=decode, **kw)
+            decode=decode)
         assert close(lg[0], want[t]) < LOGIT_TOL, (t, decode)
 
 
@@ -128,9 +138,8 @@ def test_two_prompt_rows_and_a_decode_row_share_a_step(model):
     cache = ds.LatentKVCache.create(cfg, NPAGES, PS, dtype=jnp.float32)
     ta, tb = np.arange(2, 2 + PP).astype(np.int32), np.arange(39, 39 - PP, -1).astype(np.int32)
     tc = np.arange(14, 14 + PP).astype(np.int32)
-    kw = dict(block_q=4, block_k=8)
-    _, cache, _ = ds.forward_ragged(params, cfg, batch(toks, ta, 0, 29, 32), cache, **kw)
-    _, cache, _ = ds.forward_ragged(params, cfg, batch(toks, tb, 0, 12, 16), cache, **kw)
+    _, cache, _ = ds.forward_ragged(params, cfg, batch(toks, ta, 0, 29, 32), cache)
+    _, cache, _ = ds.forward_ragged(params, cfg, batch(toks, tb, 0, 12, 16), cache)
     tok, pos = np.zeros(16, np.int32), np.zeros(16, np.int32)
     slots = np.full(16, -1, np.int32)
     tok[0], pos[0], slots[0] = toks[29], 29, ta[29 // PS] * PS + 29 % PS
@@ -142,7 +151,7 @@ def test_two_prompt_rows_and_a_decode_row_share_a_step(model):
     tables[0], tables[1], tables[2] = ta, tb, tc
     rb = RaggedBatch(tok, pos, slots, np.array([30, 20, 5, 0], np.int32), tables,
                      np.array([0, 1, 9, 14, 14], np.int32), np.asarray([3], np.int32))
-    lg, _, _ = ds.forward_ragged(params, cfg, rb, cache, **kw)
+    lg, _, _ = ds.forward_ragged(params, cfg, rb, cache)
     assert close(lg[0], want[29]) < LOGIT_TOL and close(lg[1], want[19]) < LOGIT_TOL
     assert close(lg[2], want[4]) < LOGIT_TOL
 
@@ -261,6 +270,103 @@ def test_the_dense_kernel_is_the_masked_kernel_under_the_full_mask(monkeypatch):
     assert (np.asarray(a) == np.asarray(b)).all()
 
 
+# ------------------------------- (c') the prompt-chunk kernel, decompressed
+CHUNKS = {  # query tokens a row | kv_len a row; the sizes are ``small_prefill_blocks``'s
+    "past-off-the-key-block": ([11], [37]),  # a chunk after 26 cached positions: no multiple of the key block (8)
+    "two-prompt-rows-and-a-decode-row": ([9, 1, 21], [30, 17, 48]),
+    "shorter-than-a-query-tile": ([3], [29]),
+    "cold-first-chunk": ([40], [40]),  # kv_len = its own queries: pure causal; lies across resident blocks of 16
+    # The kernel's second grid axis: ONE row behind a past, its tokens in three resident blocks of 16, each of which
+    # decompresses the row's key blocks for itself.
+    "one-row-across-two-token-blocks": ([40], [47]),
+}
+
+
+def _chunk_step(case, dtype, nan_elsewhere=False):
+    """A step's prompt chunks over a shared page pool: 4 heads of 16 + 8 /
+    16, rank 32, pages of 4 in 128 lanes, 12 pages a row.  ``nan_elsewhere``:
+    every page that is no row's LIVE page (beyond ``kv_len``, unused table
+    entries, rows past ``num_seqs``) holds NaN."""
+    rs = np.random.RandomState(5)
+    H, dn, dr, dv, rank, W, NP = 4, 16, 8, 16, 32, 128, 64
+    f = lambda *shape: jnp.asarray(rs.standard_normal(shape), jnp.float32).astype(dtype)  # noqa: E731
+    lat = f(NP, PS, W).at[:, :, rank + dr:].set(0)
+    w_uk, w_uv = f(H, rank, dn) * 0.3, f(H, rank, dv) * 0.3
+    q_lens, kv = CHUNKS[case]
+    n = len(q_lens)
+    T = -(-sum(q_lens) // 16) * 16
+    cu = np.full(S + 1, sum(q_lens), np.int32)
+    cu[: n + 1] = np.concatenate([[0], np.cumsum(q_lens)])
+    kv_lens = np.zeros(S, np.int32)
+    kv_lens[:n] = kv
+    tables = np.stack([rs.permutation(NP)[:PP] for _ in range(S)]).astype(np.int32)
+    if nan_elsewhere:
+        live = np.zeros(NP, bool)
+        for r in range(n):
+            live[tables[r, : -(-kv[r] // PS)]] = True
+        lat = jnp.where(jnp.asarray(live)[:, None, None], lat, jnp.nan)
+    return f(T, H, dn + dr), lat, w_uk, w_uv, kv_lens, tables, cu, n
+
+
+def _plain_chunk_attention(q, lat, w_uk, w_uv, kv_lens, tables, cu, n, sm_scale):
+    """Float32, a whole softmax a query over the row's decompressed context
+    (per-head keys [W^UK c ; k^R], values W^UV c); rows of one token at zero."""
+    q, lat, w_uk, w_uv = (np.asarray(a, np.float32) for a in (q, lat, w_uk, w_uv))
+    (H, rank, dn), dr = w_uk.shape, q.shape[2] - w_uk.shape[2]
+    out = np.zeros(q.shape[:2] + (w_uv.shape[2],), np.float32)
+    for r in range(n):
+        t0, nq = cu[r], cu[r + 1] - cu[r]
+        if nq <= 1:
+            continue
+        ctx = lat[tables[r]].reshape(-1, lat.shape[-1])[: kv_lens[r]]
+        c, k_rope = ctx[:, :rank], ctx[:, rank:rank + dr]
+        k = np.concatenate([np.einsum("sc,hcn->hsn", c, w_uk),
+                            np.broadcast_to(k_rope, (H,) + k_rope.shape)], axis=-1)
+        v = np.einsum("sc,hcv->hsv", c, w_uv)
+        for i in range(nq):
+            upto = kv_lens[r] - nq + i + 1
+            sc = np.einsum("hd,hsd->hs", q[t0 + i], k[:, :upto]) * sm_scale
+            p = np.exp(sc - sc.max(-1, keepdims=True))
+            out[t0 + i] = np.einsum("hs,hsv->hv", p / p.sum(-1, keepdims=True), v[:, :upto])
+    return out
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5), ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("case", list(CHUNKS))
+def test_the_dense_prefill_kernel_equals_plain_attention(case, dtype, tol):
+    """``dense_prefill_attention`` (the Pallas kernel under the interpreter)
+    against plain float32 attention over each row's decompressed context.  A
+    row of one token (the decode row), padding tokens and rows past
+    ``num_seqs`` are left at zero."""
+    q, lat, w_uk, w_uv, kv_lens, tables, cu, n = _chunk_step(case, jnp.dtype(dtype))
+    got = dense_mla.dense_prefill_attention(
+        q, lat, w_uk, w_uv, jnp.asarray(kv_lens), jnp.asarray(tables), jnp.asarray(cu),
+        jnp.asarray([n], jnp.int32), sm_scale=0.2)
+    want = _plain_chunk_attention(q, lat, w_uk, w_uv, kv_lens, tables, cu, n, 0.2)
+    assert got.dtype == q.dtype and got.shape == q.shape[:2] + (16,)
+    got = np.asarray(got, np.float32)
+    assert np.abs(got - want).max() <= tol * np.abs(want).max()
+    served = np.zeros(q.shape[0], bool)
+    for r in range(n):
+        served[cu[r]:cu[r + 1]] = cu[r + 1] - cu[r] > 1
+    assert (got[~served] == 0).all() and np.abs(got[served]).min(axis=(1, 2)).min() > 0
+
+
+def test_the_dense_prefill_kernel_reads_live_pages_alone():
+    """Pages beyond a row's ``kv_len``, unused table entries and the tables
+    of rows past ``num_seqs`` hold NaN: nothing of them is copied, and the
+    result is the plain one."""
+    q, lat, w_uk, w_uv, kv_lens, tables, cu, n = _chunk_step(
+        "two-prompt-rows-and-a-decode-row", jnp.float32, nan_elsewhere=True)
+    assert np.isnan(np.asarray(lat)).any()
+    got = np.asarray(dense_mla.dense_prefill_attention(
+        q, lat, w_uk, w_uv, jnp.asarray(kv_lens), jnp.asarray(tables), jnp.asarray(cu),
+        jnp.asarray([n], jnp.int32), sm_scale=0.2))
+    assert np.isfinite(got).all()
+    want = _plain_chunk_attention(q, jnp.nan_to_num(lat), w_uk, w_uv, kv_lens, tables, cu, n, 0.2)
+    assert np.abs(got - want).max() <= 1e-5 * np.abs(want).max()
+
+
 # ----------------------------------------------- (d) the two references tie
 def test_the_selectors_reference_with_topk_over_the_context_is_this_reference(model):
     """The ``deepseek_v32`` reference with ``index_topk`` at least the
@@ -282,8 +388,7 @@ def test_the_selectors_reference_with_topk_over_the_context_is_this_reference(mo
                                      block_q=8, block_k=8)
     kcfg = ModelConfig.from_hf_config(HF, name="tie-k").with_overrides(dtype="float32")
     lg_dense, _, _ = ds.forward_ragged(params, kcfg, batch(toks, table, 0, 30, 32),
-                                       ds.LatentKVCache.create(kcfg, NPAGES, PS, dtype=jnp.float32),
-                                       block_q=8, block_k=8)
+                                       ds.LatentKVCache.create(kcfg, NPAGES, PS, dtype=jnp.float32))
     assert close(lg_sel, np.asarray(lg_dense)) < LOGIT_TOL
 
 

@@ -355,10 +355,15 @@ async def test_migrate_once_and_twice_exact_stream():
         gate = _CopyGate(a.mig)
         assert await a.mig.migrate_out(ctx2.id, b.target)
         gate.release()
-        # Wait until B owns the resumed sequence and has advanced it.
+        # Wait until B owns the resumed sequence and has advanced it past what
+        # the client held when the wait began.  (Compared with the client's
+        # GROWING count the condition held only in the instants between B
+        # applying a fused chunk and the client receiving it; under six
+        # workers the polls missed them all until the sequence had finished.)
+        seen = len(_tokens(items2))
         await _wait_for(
             lambda: (s := b.engine.find_sequence(ctx2.id)) is not None
-            and s.num_output_tokens >= len(_tokens(items2)) + 2
+            and s.num_output_tokens >= seen + 2
         )
         gate = _CopyGate(b.mig)
         assert await b.mig.migrate_out(ctx2.id, c.target)

@@ -344,7 +344,7 @@ def test_mixed_phase_cadence_with_kernel_enabled():
     only: no cadence, no wall time."""
     from dynamo_tpu.engine import EngineConfig
     from dynamo_tpu.engine.engine import TpuEngine
-    from dynamo_tpu.runtime.engine import Context, collect
+    from dynamo_tpu.runtime.engine import Context
 
     async def go():
         cfg = EngineConfig(
@@ -354,20 +354,27 @@ def test_mixed_phase_cadence_with_kernel_enabled():
         engine.warmup()
         try:
 
-            async def one(i, n, max_tokens):
-                items = await collect(
-                    await engine.generate(
-                        Context(_req(_prompt(i, n), max_tokens=max_tokens))
-                    )
-                )
-                return [t for it in items for t in it["token_ids"]]
+            async def one(i, n, max_tokens, started=None):
+                tokens = []
+                async for it in await engine.generate(
+                    Context(_req(_prompt(i, n), max_tokens=max_tokens))
+                ):
+                    tokens += it["token_ids"]
+                    if started is not None:
+                        started.set()
+                return tokens
 
-            # A 6-token prompt decodes after two chunks of 4; the 40-token
-            # prompt beside it needs ten: the short row's first token has
-            # eight device steps to reach the host before the long prompt
-            # could end.
-            streams = await asyncio.gather(one(1, 6, 24), one(2, 40, 8))
-            assert [len(s) for s in streams] == [24, 8]
+            # The long prompt is sent when the short row's FIRST token has
+            # reached this loop, so the row is decoding (eleven fused chunks
+            # to go) when the prompt's ten chunks arrive: an order of events,
+            # where two requests sent together raced the first token's way to
+            # the host against ten prompt steps (it lost once under six
+            # workers: no prompt step ran inside a session).
+            started = asyncio.Event()
+            short = asyncio.create_task(one(1, 6, 48, started))
+            await started.wait()
+            streams = [await one(2, 40, 8), await short][::-1]
+            assert [len(s) for s in streams] == [48, 8]
             kinds = {k for k, *_ in engine.step_trace}
             assert "decode_dispatch" in kinds, kinds
             assert not any("burst" in k for k in kinds), kinds
