@@ -325,7 +325,7 @@ class Run:
         mem = [d.memory_stats() or {} for d in jax.local_devices()]
         peak = max(m.get("peak_bytes_in_use", 0) for m in mem)
         device = dict(self.device, memory_peak_bytes=int(peak))
-        notes, seen, breakdown = {}, {}, None
+        notes, seen, breakdown, host_trace = {}, {}, None, None
         for m in self.cell["end_to_end"]:
             spec = loader.read_json(loader.data_file("end_to_end", m["name"]))
             value, note = evaluate_end_to_end(spec, window, setup_s)
@@ -337,12 +337,14 @@ class Run:
             metrics, trace = {}, None
             if tracing.get("dir"):
                 path = trace_reduce.find_xplane(tracing["dir"])
+                t_load = time.time()
                 # The CPU has no device plane: a rehearsal walks the code on host lines.
-                planes, extent = (
+                planes, extent, host = (
                     trace_reduce.load_xplane(path, re.compile(r"^/host:CPU$"), lines=None)
                     if self.rehearse else trace_reduce.load_xplane(path))
+                t_reduce = time.time()
                 trace = trace_reduce.DeviceTrace(planes, tracing["t_start_s"],
-                                                 tracing["t_stop_s"], extent)
+                                                 tracing["t_stop_s"], extent, host)
                 device.update(busy_s=trace.busy_s, window_s=trace.window_s)
                 breakdown = trace.breakdown()
                 checks["device_ran"] = trace.busy_s > 0 or self.rehearse
@@ -353,6 +355,14 @@ class Run:
                 value = loader.load_reader(spec["reader"]).read(ctx, **spec.get("args", {}))
                 if value is not None:
                     metrics[spec["name"]] = {"value": value, "unit": spec["unit"]}
+            if trace is not None:
+                # For the reader: what the trace's file cost to load and to
+                # reduce (after the window, in no timed metric), how much of
+                # the host's side was kept, and what the clock check saw.
+                host_trace = {"file_bytes": os.path.getsize(path), "load_s": t_reduce - t_load,
+                              "reduce_s": time.time() - t_reduce,
+                              "annotations": len(host["annotations"]),
+                              "launches": len(host["launches"]), "clock": trace.clock()}
         else:
             metrics = seen
             checks["every_metric_read"] = len(metrics) == len(self.cell["end_to_end"])
@@ -378,6 +388,7 @@ class Run:
             # What the traced run saw end to end (3 s of it under the profiler):
             # beside the per-layer metrics, never in place of an untraced run.
             "end_to_end_seen": {k: v["value"] for k, v in seen.items()},
+            "host_trace": host_trace,
             "output_tokens_per_s": window["output_tokens_per_s"],
             "setup": {
                 "ready_s": t_ready - T_PROCESS_START,

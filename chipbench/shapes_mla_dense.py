@@ -61,3 +61,34 @@ def kernel_call_need_s(model: dict, serve: dict, held_tokens: float, peaks: dict
     and of its operations over the bf16 peak."""
     return max(held_tokens * entry_bytes(model, serve) / peaks["hbm_bytes_per_s"],
                held_tokens * attention_flops_per_position(model) / peaks["bf16_flops"])
+
+
+def prefill_query_flops_per_position(model: dict) -> int:
+    """Multiply-adds x 2 of ONE prompt query against ONE position in ONE
+    layer in the decompressed form: H scores over the key's 128 + 64 lanes, H
+    values over 128."""
+    return 2 * model["num_attention_heads"] * (
+        model["qk_nope_head_dim"] + model["qk_rope_head_dim"] + model["v_head_dim"])
+
+
+def decompress_flops_per_position(model: dict) -> int:
+    """Multiply-adds x 2 that turn ONE position's latent into its per-head
+    keys and values (W^UK and W^UV) in ONE layer."""
+    return 2 * model["num_attention_heads"] * model["kv_lora_rank"] * (
+        model["qk_nope_head_dim"] + model["v_head_dim"])
+
+
+def prefill_request_flops(model: dict, serve: dict, prompt_len: int, hit: int) -> float:
+    """Least operations of ONE layer's prompt attention for a request whose
+    first ``hit`` positions are a prefix hit, counted from below: the query at
+    position p attends to the p + 1 positions up to itself (a hit's positions
+    are attended to, their queries not computed), and the row's context is
+    decompressed once a chunk of ``prefill_chunk`` tokens, the fewest calls
+    that can hold the ``prompt_len - hit`` computed tokens."""
+    hit = max(0, min(int(hit), int(prompt_len) - 1))
+    attended = (prompt_len * (prompt_len + 1) - hit * (hit + 1)) // 2
+    chunk = int(serve["prefill_chunk"])
+    decompressed = sum(min(end, prompt_len)
+                       for end in range(hit + chunk, prompt_len + chunk, chunk))
+    return (attended * prefill_query_flops_per_position(model)
+            + decompressed * decompress_flops_per_position(model))

@@ -65,11 +65,34 @@ def test_the_new_metrics_are_this_cell_s_alone_and_the_shared_ones_are_appended(
                   "deepseek-v3.2-exp-6l-ep16.longdoc-shared", "kimi-k2-6l-ep32.agent-shared"):
         theirs = [m["name"] for m in loader.load_cell(other)["per_layer"]]
         assert not set(NEW) & set(theirs)
-    bench = loader.load_benchmark()
-    assert [m["name"] for m in bench["per_layer"]][-3:] == NEW  # new entries at the end
-    assert bench["workloads"][-1]["name"] == CELL and bench["configs"][-1]["name"] == "lfm2-8b-a1b"
+    _holds_this_pr_s_entries(loader.load_benchmark())
+
+
+def _holds_this_pr_s_entries(bench: dict) -> None:
+    """PR 36's three metrics stand together and in order, its cell and its
+    configuration are listed: wherever they stand, since every later PR appends."""
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index(NEW[0])
+    assert names[at:at + 3] == NEW
+    assert CELL in [w["name"] for w in bench["workloads"]]
+    assert "lfm2-8b-a1b" in [c["name"] for c in bench["configs"]]
     for m in bench["end_to_end"]:
         assert CELL not in m.get("workloads", [])
+
+
+def test_entries_appended_by_later_prs_do_not_move_this_pr_s():
+    """A copy of the benchmark with one more per-layer entry, one more cell and
+    one more configuration at the END of their lists, as the builder's contract
+    has every later PR add them."""
+    bench = loader.load_benchmark()
+    later = dict(bench,
+                 per_layer=bench["per_layer"] + [dict(bench["per_layer"][0], name="a_later_metric")],
+                 workloads=bench["workloads"] + [dict(bench["workloads"][0], name="a-later.cell")],
+                 configs=bench["configs"] + [dict(bench["configs"][0], name="a-later-config")])
+    _holds_this_pr_s_entries(later)
+    moved = dict(bench, per_layer=[m for m in bench["per_layer"] if m["name"] != NEW[1]])
+    with pytest.raises(AssertionError):
+        _holds_this_pr_s_entries(moved)
 
 
 @pytest.mark.parametrize("name", NEW)
