@@ -729,7 +729,6 @@ async def main() -> None:
             print(f"loadgen: conc={conc} n={n} ...", file=sys.stderr)
             if engine is not None:
                 engine.step_trace.clear()
-                engine.loop_gap_max = 0.0
                 engine.scheduler.admission_waits.clear()
                 compiles_before = engine.compile_counts()
             row = await _sweep_level(url, args.model, conc, n, args.isl,
@@ -744,14 +743,12 @@ async def main() -> None:
                     for k, v in compiles_before.items()
                     if engine.compile_counts().get(k, 0) != v
                 }
-                # Engine-side stall attribution.  loop_gap_max: the longest
-                # single scheduler-loop iteration (≈ one fused pure-decode
-                # SESSION — expected to be seconds at saturation).
-                # admission waits: queue→admission latency per request; the
-                # TTFT tail is p99(admission) + prefill + first burst, so an
-                # outlier WITHOUT a matching admission wait is outside the
-                # engine (network/client).
-                row["engine_loop_gap_max_ms"] = round(engine.loop_gap_max * 1e3, 1)
+                # Engine-side stall attribution.  admission waits:
+                # queue→admission latency per request; the TTFT tail is
+                # p99(admission) + prefill + first burst, so an outlier
+                # WITHOUT a matching admission wait is outside the engine
+                # (network/client).  (A stall of the loop itself: the
+                # _bucket rows of dynamo_tpu_engine_loop_phase_seconds.)
                 aw = sorted(engine.scheduler.admission_waits)
                 row["admission_wait_p50_ms"] = round(
                     _pct(aw, 0.5) * 1e3, 1

@@ -37,7 +37,6 @@ from typing import Any, AsyncIterator, Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.profiler import TraceAnnotation
 
 from ..llm.kv_router.protocols import ForwardPassMetrics, KvCacheEvent
 from ..llm.protocols import FinishReason, LLMEngineOutput, PreprocessedRequest
@@ -63,6 +62,7 @@ logger = logging.getLogger(__name__)
 
 from .migrate import MigrationMixin
 from .offload import HostOffloadMixin
+from .phases import PhaseAccount
 from .pipeline import _FINISHED, DecodePipelineMixin
 from .spec import AcceptanceController, SpecDecodeMixin
 from .transfer import KvTransferMixin, _scales_close, transfer_blocks_device  # noqa: F401 — compat re-export
@@ -265,9 +265,9 @@ class TpuEngine(
         # pipeline records dispatch and fetch separately since they
         # overlap.  Bounded: a long-lived server must not grow it forever.
         self.step_trace: deque = deque(maxlen=65536)
-        # Largest observed gap between engine-loop iterations (stall
-        # attribution; reset by clearing alongside step_trace readers).
-        self.loop_gap_max = 0.0
+        # The loop's account of its own time, always on (engine/phases.py).
+        self.phases = PhaseAccount()
+        self._phase = self.phases.phase
         # Prefill-chunk accounting (pipeline._run_unified): cumulative
         # chunk count / wall / prompt tokens plus a bounded per-chunk wall
         # trace for the latency quantiles on /metrics
@@ -304,12 +304,12 @@ class TpuEngine(
         self.first_harvest = {"landed": 0, "iteration": 0}
         self.prompt_step_order = {"ahead": 0, "behind": 0}
         self.pipeline_wall_s = 0.0       # cumulative fused-session wall
-        # Device-busy wall accumulated INSIDE fused sessions (decode
-        # dispatch/wait + interleaved admission-prefill steps).  Unbounded
-        # like pipeline_wall_s — host_gap_frac must never be derived from
-        # the BOUNDED step_trace, whose eviction after 65k entries would
-        # drift the ratio toward 1.0 on a long-lived server.
-        self.decode_busy_s = 0.0
+        # Of pipeline_wall_s, the part the loop spent in a harvest:* phase
+        # (nothing of its own to do but wait for the device): what
+        # host_gap_frac is made from.  Unbounded like pipeline_wall_s — never
+        # derived from the BOUNDED step_trace, whose eviction after 65k
+        # entries would drift the ratio on a long-lived server.
+        self.pipeline_waited_s = 0.0
         # Decode-stall watchdog (r5 diagnosed a ~3-minute decode_wait hang
         # with NO engine-side detector): a token fetch / device dispatch
         # that exceeds the threshold trips a loud log with the recent
@@ -1609,28 +1609,11 @@ class TpuEngine(
             )
 
     async def _run_loop(self) -> None:
-        last_beat = time.perf_counter()
+        # Where this loop does a session's work it is in the session's
+        # phases (engine/phases.py); its idle wait for a request is in none.
         while not self._closed:
-            # Heartbeat: one iteration = one scheduling decision.  A
-            # multi-second gap here localizes tail-latency stalls to the
-            # ENGINE side (device dispatch, harvest, GC) vs the network /
-            # client — the r4 ladder artifacts carried ~8s TTFT outliers
-            # with no compile and no attribution (VERDICT r4 weak #1).
-            now = time.perf_counter()
-            gap = now - last_beat
-            last_beat = now
-            if gap > self.loop_gap_max:
-                self.loop_gap_max = gap
-            if gap > 5.0:
-                # One iteration can legitimately span a whole fused
-                # pure-decode session (seconds at saturation); beyond that
-                # it smells like a genuine stall (device hiccup, GC, host
-                # pause) — surface it.
-                logger.warning(
-                    "engine loop iteration spanned %.2fs "
-                    "(long fused-decode session or stall)", gap
-                )
-            self._cancel_stopped()
+            with self._phase("retire"):
+                self._cancel_stopped()
             try:
                 while (
                     self._pending_fetches
@@ -1647,10 +1630,10 @@ class TpuEngine(
                 logger.exception("deferred fetch failed")
                 self._fail_all()
                 return
-            with TraceAnnotation("engine.schedule"):
+            with self._phase("schedule"):
                 plan = self.scheduler.schedule()
-            for seq in self.scheduler.take_rejected():
-                self._finish(seq, FinishReason.ERROR)
+                for seq in self.scheduler.take_rejected():
+                    self._finish(seq, FinishReason.ERROR)
             if plan is None:
                 if self._pending_fetches:
                     try:
@@ -1666,16 +1649,13 @@ class TpuEngine(
                     # e.g. decode just preempted everyone back to waiting:
                     # retry admission immediately (terminates: each pass
                     # admits or rejects at least one waiting sequence).
-                    await asyncio.sleep(0)
+                    with self._phase("yield"):
+                        await asyncio.sleep(0)
                     continue
                 # Idle: running is empty (running sequences always yield
-                # work), so sleep until a new request arrives.  Idle time
-                # is NOT a stall: re-arm the heartbeat or the first
-                # request after a lull reads the whole idle period as an
-                # engine-side gap.
+                # work), so sleep until a new request arrives.
                 self._wake.clear()
                 await self._wake.wait()
-                last_beat = time.perf_counter()
                 continue
             try:
                 did_work = False
@@ -1720,8 +1700,9 @@ class TpuEngine(
                 logger.exception("engine step failed")
                 self._fail_all()
                 return
-            self._steps += 1
-            await asyncio.sleep(0)  # let ingress/egress run between steps
+            with self._phase("yield"):
+                self._steps += 1
+                await asyncio.sleep(0)  # let ingress/egress run between steps
 
     def _cancel_stopped(self) -> None:
         for seq in list(self.scheduler.running) + list(self.scheduler.waiting):
@@ -1820,14 +1801,20 @@ class TpuEngine(
         engine_dispatch_metrics).
 
         ``host_gap_frac`` is scoped to fused decode sessions: the fraction
-        of pipeline wall NOT covered by in-session device work (decode
-        dispatch/wait + the interleaved admission-prefill steps) — the
-        host-side planning/accept share the continuous pipeline exists to
-        shrink.  Both terms accumulate unbounded (never derived from the
-        bounded trace).  0.0 when no session has run."""
+        of pipeline wall in which the loop was NOT in a ``harvest:*`` phase
+        (engine/phases.py), that is, doing work of its own (retiring,
+        merging, admitting, building and enqueueing steps, emitting tokens,
+        yielding to the HTTP side) instead of waiting for the device — the
+        host-side share the continuous pipeline exists to shrink.  Both
+        terms accumulate unbounded (never derived from the bounded trace)
+        and a second of a session is counted once.  0.0 when no session has
+        run.  ``phases`` is the account itself: histogram rows of the loop's
+        tiling phases and of the worker threads' device calls."""
         wall = self.pipeline_wall_s
         gap = (
-            max(0.0, wall - self.decode_busy_s) / wall if wall > 0 else 0.0
+            min(1.0, max(0.0, wall - self.pipeline_waited_s) / wall)
+            if wall > 0
+            else 0.0
         )
         return {
             "kinds": self.step_summary(),
@@ -1853,6 +1840,7 @@ class TpuEngine(
                 "stalls": self.decode_stalls,
                 "last_stall": self.last_stall,
             },
+            "phases": self.phases.summary(),
         }
 
 

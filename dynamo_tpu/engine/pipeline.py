@@ -16,7 +16,6 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.profiler import TraceAnnotation
 
 logger = logging.getLogger(__name__)
 
@@ -224,115 +223,117 @@ class DecodePipelineMixin:
         )
 
     async def _run_unified(self, plan: StepPlan) -> None:
-        rb = self._build_ragged(plan.items)
-        samp = self._sampling_arrays([s for s, _, _ in plan.items])
-        need_lp = bool(samp.need_logprobs)
-        # A step whose every row stays mid-prefill produces sampled tokens
-        # nobody consumes — skip the device→host fetch entirely and let the
-        # next chunk's dispatch queue behind this one: it saves a sync
-        # per chunk (how much: not measured on this machine).
-        need_tokens = any(
-            start + n >= len(seq.prompt) for seq, start, n in plan.items
-        )
-        if self._rep_sharding is not None:
-            rb_d, samp_d = self._prep((rb, samp))
-        else:
-            rb_d, samp_d = rb, samp
-        step = self._step_fn
-        # Park rows BEFORE the first suspension point, not after the
-        # dispatch: from here to the harvest this coroutine yields, and
-        # anything polling quiescence (freeze_sequence, engine/migrate.py)
-        # must see these rows as having a token en route — marking after
-        # the await left a window where a migration snapshot missed the
-        # in-flight token and the client received it twice.  (Rows of OLD
-        # pending fetches are disjoint from this plan's rows — the
-        # scheduler never plans a parked row — so the harvests below can't
-        # clear these marks early.)
-        for seq, start, n in plan.items:
-            if not seq.finished and start + n >= len(seq.prompt):
-                seq.awaiting_fetch = True
+        with self._phase("prompt_build"):
+            rb = self._build_ragged(plan.items)
+            samp = self._sampling_arrays([s for s, _, _ in plan.items])
+            need_lp = bool(samp.need_logprobs)
+            # A step whose every row stays mid-prefill produces sampled tokens
+            # nobody consumes — skip the device→host fetch entirely and let the
+            # next chunk's dispatch queue behind this one: it saves a sync
+            # per chunk (how much: not measured on this machine).
+            need_tokens = any(
+                start + n >= len(seq.prompt) for seq, start, n in plan.items
+            )
+            if self._rep_sharding is not None:
+                rb_d, samp_d = self._prep((rb, samp))
+            else:
+                rb_d, samp_d = rb, samp
+            step = self._step_fn
+            # Park rows BEFORE the first suspension point, not after the
+            # dispatch: from here to the harvest this coroutine yields, and
+            # anything polling quiescence (freeze_sequence, engine/migrate.py)
+            # must see these rows as having a token en route — marking after
+            # the await left a window where a migration snapshot missed the
+            # in-flight token and the client received it twice.  (Rows of OLD
+            # pending fetches are disjoint from this plan's rows — the
+            # scheduler never plans a parked row — so the harvests below can't
+            # clear these marks early.)
+            for seq, start, n in plan.items:
+                if not seq.finished and start + n >= len(seq.prompt):
+                    seq.awaiting_fetch = True
+
+            def run():
+                with self._phase("dispatch:unified"):
+                    out, self.cache = step(self.params, self.cache, rb_d, samp_d)
+                    if need_tokens:
+                        # Start the D2H now; the accept is deferred to a harvest
+                        # point so the round trip overlaps later dispatches.
+                        self._start_d2h(out, need_lp)
+                return out
+
         while self._pending_fetches and self._pending_fetches[0][1].done():
             await self._harvest_pending()  # free: task already complete
-
-        def run():
-            with TraceAnnotation("engine.dispatch:unified"):
-                out, self.cache = step(self.params, self.cache, rb_d, samp_d)
-                if need_tokens:
-                    # Start the D2H now; the accept is deferred to a harvest
-                    # point so the round trip overlaps later dispatches.
-                    self._start_d2h(out, need_lp)
-            return out
-
-        await self._pace()
-        t0 = time.perf_counter()
-        # Hop account, one comparison per row per dispatch: a row still in
-        # its prompt is stamped before its first chunk's dispatch, and the
-        # rows whose FINAL prompt token this step carries are kept — their
-        # first token is what the fetch below brings to the host.
-        first_rows: Optional[List[SequenceState]] = None
-        for seq, start, n in plan.items:
-            if seq.t_last_chunk == 0.0:
-                if seq.t_first_chunk == 0.0:
-                    seq.t_first_chunk = t0
-                if start + n >= len(seq.prompt):
-                    first_rows = (first_rows or []) + [seq]
-        async with self._device_lock:
-            # Publish INSIDE the device lock: broadcast order must equal
-            # device enqueue order or followers replay a different program
-            # sequence than the leader ran (SPMD divergence).
-            if self._publisher is not None:
-                await self._publisher.publish(
-                    "unified",
-                    (rb, jax.tree_util.tree_map(np.asarray, samp)),
+        with self._phase("enqueue:unified"):
+            await self._pace()
+            t0 = time.perf_counter()
+            # Hop account, one comparison per row per dispatch: a row still in
+            # its prompt is stamped before its first chunk's dispatch, and the
+            # rows whose FINAL prompt token this step carries are kept — their
+            # first token is what the fetch below brings to the host.
+            first_rows: Optional[List[SequenceState]] = None
+            for seq, start, n in plan.items:
+                if seq.t_last_chunk == 0.0:
+                    if seq.t_first_chunk == 0.0:
+                        seq.t_first_chunk = t0
+                    if start + n >= len(seq.prompt):
+                        first_rows = (first_rows or []) + [seq]
+            async with self._device_lock:
+                # Publish INSIDE the device lock: broadcast order must equal
+                # device enqueue order or followers replay a different program
+                # sequence than the leader ran (SPMD divergence).
+                if self._publisher is not None:
+                    await self._publisher.publish(
+                        "unified",
+                        (rb, jax.tree_util.tree_map(np.asarray, samp)),
+                    )
+                out = await self._await_device(
+                    self._device_task(run), "unified_dispatch", len(plan.items)
                 )
-            out = await self._await_device(
-                self._device_task(run), "unified_dispatch", len(plan.items)
+            wall = time.perf_counter() - t0
+            self.step_trace.append(
+                (
+                    "unified_fetch" if need_tokens else "unified",
+                    wall,
+                    len(plan.items),
+                    len(rb.token_ids),
+                )
             )
-        wall = time.perf_counter() - t0
-        self.step_trace.append(
-            (
-                "unified_fetch" if need_tokens else "unified",
-                wall,
-                len(plan.items),
-                len(rb.token_ids),
+            # Prefill-chunk accounting: any step that advanced prompt tokens
+            # counts as one chunk (mixed plans attribute the whole dispatch
+            # wall — the prefill rows dominate it by construction of the
+            # chunked scheduler).  Feeds the per-chunk latency quantiles on
+            # /metrics.
+            prefill_tokens = sum(
+                min(n, len(seq.prompt) - start)
+                for seq, start, n in plan.items
+                if start < len(seq.prompt)
             )
-        )
-        # Prefill-chunk accounting: any step that advanced prompt tokens
-        # counts as one chunk (mixed plans attribute the whole dispatch
-        # wall — the prefill rows dominate it by construction of the
-        # chunked scheduler).  Feeds the per-chunk latency quantiles on
-        # /metrics.
-        prefill_tokens = sum(
-            min(n, len(seq.prompt) - start)
-            for seq, start, n in plan.items
-            if start < len(seq.prompt)
-        )
-        if prefill_tokens > 0:
-            self._note_prefill_chunk(wall, prefill_tokens)
+            if prefill_tokens > 0:
+                self._note_prefill_chunk(wall, prefill_tokens)
 
-        if first_rows:
-            for seq in first_rows:
-                seq.t_last_chunk = t0 + wall
-        pending_rows: List[Tuple[SequenceState, int]] = []
-        for i, (seq, start, n) in enumerate(plan.items):
-            if seq.finished:
-                seq.awaiting_fetch = False  # pre-marked above; never parked
-                continue
-            if start >= len(seq.prompt):
-                # Decode row: the fed token joins the hash stream.
-                seq.block_seq.append((seq.prompt + seq.output)[start])
-            seq.num_computed = start + n
-            self._seal_completed_blocks(seq)
-            if not seq.in_prefill:
-                # This row's sampled token is in flight (pre-marked before
-                # the dispatch); park the row until a harvest point applies
-                # it.
-                seq.awaiting_fetch = True
-                pending_rows.append((seq, i))
-        if pending_rows:
-            self._stash_fetch(
-                "first", out, need_lp, pending_rows, first_rows=first_rows
-            )
+            if first_rows:
+                for seq in first_rows:
+                    seq.t_last_chunk = t0 + wall
+            pending_rows: List[Tuple[SequenceState, int]] = []
+            for i, (seq, start, n) in enumerate(plan.items):
+                if seq.finished:
+                    seq.awaiting_fetch = False  # pre-marked above; never parked
+                    continue
+                if start >= len(seq.prompt):
+                    # Decode row: the fed token joins the hash stream.
+                    seq.block_seq.append((seq.prompt + seq.output)[start])
+                seq.num_computed = start + n
+                self._seal_completed_blocks(seq)
+                if not seq.in_prefill:
+                    # This row's sampled token is in flight (pre-marked before
+                    # the dispatch); park the row until a harvest point applies
+                    # it.
+                    seq.awaiting_fetch = True
+                    pending_rows.append((seq, i))
+            if pending_rows:
+                self._stash_fetch(
+                    "first", out, need_lp, pending_rows, first_rows=first_rows
+                )
 
     async def _pace(self) -> None:
         """Await the injectable test pace hook (engine.py pace_hook)
@@ -435,6 +436,13 @@ class DecodePipelineMixin:
             seq.t_fetch_done = now
         return res
 
+    def _timed_fetch(self, call: str, fetch, *args):
+        """Run a token fetch on its worker thread inside its device-call
+        phase (``fetch:*``, engine/phases.py): the copy to the host, apart
+        from the event loop getting round to the finished task."""
+        with self._phase(call):
+            return fetch(*args)
+
     def _stash_fetch(self, kind: str, out, need_lp: bool, *meta,
                      first_rows=None) -> None:
         """Park a dispatched step's token fetch: the np.asarray runs on a
@@ -448,7 +456,7 @@ class DecodePipelineMixin:
             else (self._fetch_outs, out, need_lp)
         )
         task = asyncio.get_running_loop().create_task(
-            asyncio.to_thread(*fetch)
+            asyncio.to_thread(self._timed_fetch, f"fetch:{kind}", *fetch)
         )
         self._pending_fetches.append((kind, task, *meta))
 
@@ -463,20 +471,19 @@ class DecodePipelineMixin:
         while self._pending_fetches:
             entry = self._pending_fetches.pop(0)
             kind, task = entry[0], entry[1]
-            if kind == "first":
-                self.first_harvest[at] += 1
-
-            await self._pace()
-            t0 = time.perf_counter()
-            with TraceAnnotation(f"engine.harvest:{kind}"):
+            with self._phase(f"harvest:{kind}"):
+                if kind == "first":
+                    self.first_harvest[at] += 1
+                await self._pace()
+                t0 = time.perf_counter()
                 sampled, logp, top_ids, top_lp = await self._await_device(
                     task, f"{kind}_fetch", len(entry[2])
                 )
-            t1 = time.perf_counter()
-            self.step_trace.append(
-                (f"{kind}_harvest", t1 - t0, len(entry[2]), 0)
-            )
-            with TraceAnnotation("engine.emit"):
+            with self._phase("emit"):
+                t1 = time.perf_counter()
+                self.step_trace.append(
+                    (f"{kind}_harvest", t1 - t0, len(entry[2]), 0)
+                )
                 self._apply_harvest(
                     kind, entry, sampled, logp, top_ids, top_lp, t1
                 )
@@ -564,6 +571,7 @@ class DecodePipelineMixin:
         self._pipeline_members = {s.request_id for s in members}
         self.pipeline_sessions += 1
         session_t0 = time.perf_counter()
+        waited0 = self.phases.waited_s()
         multi = self._multi_fn
 
         tok0 = np.zeros((S,), np.int32)
@@ -734,11 +742,11 @@ class DecodePipelineMixin:
                 self.continuous_admissions += 1
                 prefilling.append(seq)
 
-        async def prefill_step() -> bool:
-            """One unified step advancing every in-loop-admitted prompt by
-            a chunk (ordinary _run_unified: chunked prefill, deferred
-            first-token fetch, block sealing).  Fused chunks around it
-            touch disjoint rows and blocks."""
+        def prompt_rows() -> List[Tuple[SequenceState, int, int]]:
+            """The rows of one unified step advancing every in-loop-admitted
+            prompt by a chunk (ordinary _run_unified: chunked prefill,
+            deferred first-token fetch, block sealing), or none.  Fused
+            chunks around it touch disjoint rows and blocks."""
             budget = cfg.prefill_chunk
             items: List[Tuple[SequenceState, int, int]] = []
             for seq in prefilling:
@@ -754,19 +762,11 @@ class DecodePipelineMixin:
                 chunk = min(budget, len(seq.prompt) - seq.num_computed)
                 items.append((seq, seq.num_computed, chunk))
                 budget -= chunk
-            if not items:
-                return False
-            self.prompt_step_order[
-                "behind" if chunk_id > iter_chunk0 else "ahead"
-            ] += 1
-            # Counted as in-session DEVICE work for host_gap_frac: an
-            # admitted prompt's prefill dispatches run inside the session
-            # wall, and excluding them would read as a host-side gap
-            # exactly when in-loop admission is active.
-            t0 = time.perf_counter()
-            await self._run_unified(StepPlan(items))
-            self.decode_busy_s += time.perf_counter() - t0
-            return True
+            if items:
+                self.prompt_step_order[
+                    "behind" if chunk_id > iter_chunk0 else "ahead"
+                ] += 1
+            return items
 
         def promote_ready() -> None:
             for seq in list(prefilling):
@@ -807,207 +807,245 @@ class DecodePipelineMixin:
                 return None
             return pos_disp.copy()
 
+        def plan_top_up(in_flight_now: int, depth: int) -> Optional[np.ndarray]:
+            """The dispatch window's next chunk, or None: the window is
+            full, the chain must break first (a pending merge, a rebuild),
+            or no row can use one."""
+            if rebuild or ready or samp is None or in_flight_now >= depth:
+                return None
+            return plan_chunk()
+
         async def dispatch_chunk(pos0: np.ndarray) -> None:
-            nonlocal carry, chunk_id, dispatched_any
-            first = carry is None
-            if self._count_dispatch:
-                self._count_dispatch(
-                    "decode", pos0, np.where(pos0 >= 0, cfg.decode_steps, 0)
-                )
-            n_active = slots.num_active
-            pub_payload = (
-                tok0 if first else None,  # None → follower's own carry
-                pos0,
-                tables.copy(),
-                limits.copy(),
-                samp_np,
-            )
-            if first:
-                c_tok, c_steps, c_counts = tok0, samp.steps, samp.counts
-                if self._rep_sharding is not None:
-                    c_tok, c_steps = self._prep((c_tok, c_steps))
-            else:
-                c_tok, c_steps, c_counts = carry
-            if self._rep_sharding is not None:
-                d_args = self._prep((pos0, tables.copy(), limits.copy(), samp))
-            else:
-                d_args = (pos0, tables, limits, samp)
-
-            def run(args=d_args, tok_in=c_tok, st=c_steps, ct=c_counts):
-                with TraceAnnotation("engine.dispatch:decode"):
-                    outs, last, steps_f, counts_f, self.cache = multi(
-                        self.params, self.cache, tok_in, st, ct, *args
+            nonlocal carry, chunk_id, dispatched_any, progressed, rebuild
+            with self._phase("enqueue:decode"):
+                first = carry is None
+                if self._count_dispatch:
+                    self._count_dispatch(
+                        "decode", pos0, np.where(pos0 >= 0, cfg.decode_steps, 0)
                     )
-                return outs, (last, steps_f, counts_f)
-
-            await self._pace()
-            t0 = time.perf_counter()
-            async with self._device_lock:
-                # Broadcast order must equal device enqueue order (see
-                # _run_unified) — publish under the device lock.
-                if self._publisher is not None:
-                    await self._publisher.publish("multi", pub_payload)
-                outs, new_carry = await self._await_device(
-                    self._device_task(run), "decode_dispatch", n_active
+                n_active = slots.num_active
+                pub_payload = (
+                    tok0 if first else None,  # None → follower's own carry
+                    pos0,
+                    tables.copy(),
+                    limits.copy(),
+                    samp_np,
                 )
-            carry = new_carry
-            t1 = time.perf_counter()
-            wall = t1 - t0
-            self.decode_busy_s += wall  # unbounded host-gap accounting
-            self.step_trace.append(
-                ("decode_dispatch", wall, n_active, n_active * T)
-            )
-            self._trace_decode_chunk(slots.active(), t0, t1, T)
-            # Start the D2H copy NOW: it proceeds in the background while
-            # later chunks compute, so the wait below pays ~zero round trip
-            # instead of compute + full link latency.
-            self._start_d2h(outs, need_lp)
-            chunk_id += 1
-            inflight.append((outs, pos0, chunk_id, need_lp))
-            dispatched_any = True
-            pos_disp[:] = np.where(pos_disp >= 0, pos_disp + T, pos_disp)
+                if first:
+                    c_tok, c_steps, c_counts = tok0, samp.steps, samp.counts
+                    if self._rep_sharding is not None:
+                        c_tok, c_steps = self._prep((c_tok, c_steps))
+                else:
+                    c_tok, c_steps, c_counts = carry
+                if self._rep_sharding is not None:
+                    d_args = self._prep((pos0, tables.copy(), limits.copy(), samp))
+                else:
+                    d_args = (pos0, tables, limits, samp)
 
+                def run(args=d_args, tok_in=c_tok, st=c_steps, ct=c_counts):
+                    with self._phase("dispatch:decode"):
+                        outs, last, steps_f, counts_f, self.cache = multi(
+                            self.params, self.cache, tok_in, st, ct, *args
+                        )
+                    return outs, (last, steps_f, counts_f)
+
+                await self._pace()
+                t0 = time.perf_counter()
+                async with self._device_lock:
+                    # Broadcast order must equal device enqueue order (see
+                    # _run_unified) — publish under the device lock.
+                    if self._publisher is not None:
+                        await self._publisher.publish("multi", pub_payload)
+                    outs, new_carry = await self._await_device(
+                        self._device_task(run), "decode_dispatch", n_active
+                    )
+                carry = new_carry
+                t1 = time.perf_counter()
+                wall = t1 - t0
+                self.step_trace.append(
+                    ("decode_dispatch", wall, n_active, n_active * T)
+                )
+                self._trace_decode_chunk(slots.active(), t0, t1, T)
+                # Start the D2H copy NOW: it proceeds in the background while
+                # later chunks compute, so the wait below pays ~zero round trip
+                # instead of compute + full link latency.
+                self._start_d2h(outs, need_lp)
+                chunk_id += 1
+                inflight.append((outs, pos0, chunk_id, need_lp))
+                dispatched_any = True
+                pos_disp[:] = np.where(pos_disp >= 0, pos_disp + T, pos_disp)
+                progressed = True
+                if want_rebuild():
+                    rebuild = True
+
+        progressed = False
         while True:
-            iter_chunk0 = chunk_id
-            sweep_retire()
-            flush_retired()
-            if not rebuild:
-                rejoin_strays()
-            if want_rebuild():
-                rebuild = True
+            with self._phase("retire"):
+                iter_chunk0 = chunk_id
+                sweep_retire()
+                flush_retired()
+                if not rebuild:
+                    rejoin_strays()
+                if want_rebuild():
+                    rebuild = True
             if ready and not inflight and not rebuild:
-                merge_ready()
+                with self._phase("merge"):
+                    merge_ready()
 
-            # Pop the oldest chunk and start its fetch FIRST: everything
-            # below — admission, the interleaved prefill, next-chunk
-            # planning + dispatch, completed first-token harvests —
-            # overlaps the D2H running in the fetch thread.
-            fetch_task = None
-            if inflight:
-                outs, pos0_c, cid, lp = inflight.popleft()
-                wait_t0 = time.perf_counter()
-                fetch_task = asyncio.get_running_loop().create_task(
-                    asyncio.to_thread(self._fetch_outs, outs, lp)
-                )
+            with self._phase("admit"):
+                # Pop the oldest chunk and start its fetch FIRST: everything
+                # below — admission, the interleaved prefill, next-chunk
+                # planning + dispatch, completed first-token harvests —
+                # overlaps the D2H running in the fetch thread.
+                # landed: None until the wait for the chunk begins, then
+                # whether a first-token fetch landed during it.
+                fetch_task = landed = None
+                if inflight:
+                    outs, pos0_c, cid, lp = inflight.popleft()
+                    wait_t0 = time.perf_counter()
+                    fetch_task = asyncio.get_running_loop().create_task(
+                        asyncio.to_thread(
+                            self._timed_fetch, "fetch:decode",
+                            self._fetch_outs, outs, lp,
+                        )
+                    )
 
-            # Prompt steps go to the device BEFORE this iteration's top-up
-            # chunk: the device queue reads C_k, P_k, C_k+1 and a prompt's
-            # last chunk (its first token) does not wait behind a fused
-            # chunk dispatched microseconds before it.  The chunk already
-            # in flight keeps the device fed while the host builds the
-            # step; the device order between the two is free (disjoint
-            # rows and blocks).  A pure-decode iteration does nothing here.
-            progressed = False
-            if not rebuild:
-                admit()
-                if await prefill_step():
-                    dispatched_any = True
-                    progressed = True
+                # Prompt steps go to the device BEFORE this iteration's
+                # top-up chunk: the device queue reads C_k, P_k, C_k+1 and a
+                # prompt's last chunk (its first token) does not wait behind
+                # a fused chunk dispatched microseconds before it.  The
+                # chunk already in flight keeps the device fed while the
+                # host builds the step; the device order between the two is
+                # free (disjoint rows and blocks).  A pure-decode iteration
+                # does nothing here.
+                progressed = False
+                items: List[Tuple[SequenceState, int, int]] = []
+                if not rebuild:
+                    admit()
+                    items = prompt_rows()
+                if items:
+                    dispatched_any = progressed = True
+            if items:
+                await self._run_unified(StepPlan(items))
             # First tokens that landed while the loop was busy apply here,
             # still ahead of the top-up: a row that is ``ready`` holds it.
             while self._pending_fetches and self._pending_fetches[0][1].done():
                 await self._harvest_pending()
                 progressed = True
-            promote_ready()
 
             # Top up the dispatch window.  With anyone waiting to join
             # (queued, prefilling, or merge-pending), cap the in-flight
             # depth at 2 — enough to overlap fetch with compute — so the
             # drain a join must wait for stays bounded.  A pending merge
             # holds fused dispatch entirely: the chain must break first.
-            depth = (
-                min(cfg.pipeline_depth, 2)
-                if (self.scheduler.num_waiting or prefilling or ready)
-                else cfg.pipeline_depth
-            )
-            in_flight_now = len(inflight) + (1 if fetch_task is not None else 0)
-            while (
-                not rebuild
-                and not ready
-                and samp is not None
-                and in_flight_now < depth
-            ):
-                with TraceAnnotation("engine.schedule"):
-                    pos0 = plan_chunk()
-                if pos0 is None:
-                    break
+            with self._phase("schedule"):
+                promote_ready()
+                depth = (
+                    min(cfg.pipeline_depth, 2)
+                    if (self.scheduler.num_waiting or prefilling or ready)
+                    else cfg.pipeline_depth
+                )
+                in_flight_now = len(inflight) + (
+                    1 if fetch_task is not None else 0
+                )
+                pos0 = plan_top_up(in_flight_now, depth)
+            while pos0 is not None:
                 await dispatch_chunk(pos0)
-                in_flight_now += 1
-                progressed = True
-                if want_rebuild():
-                    rebuild = True
+                with self._phase("schedule"):
+                    in_flight_now += 1
+                    pos0 = plan_top_up(in_flight_now, depth)
 
             if fetch_task is not None:
-                await self._pace()
-                with TraceAnnotation("engine.harvest:decode"):
-                    # A first token that lands while the chunk computes is
-                    # applied NOW, not at the next iteration's harvest
-                    # point: its stream gets it at once, and its row is in
-                    # ``ready`` before the next top-up.
-                    while self._pending_fetches and not fetch_task.done():
-                        first_task = self._pending_fetches[0][1]
-                        await self._wait_first(
-                            {fetch_task, first_task},
-                            "decode_wait",
-                            slots.num_active,
-                        )
-                        if first_task.done():
-                            await self._harvest_pending(at="landed")
+                while True:
+                    with self._phase("harvest:decode"):
+                        if landed is None:
+                            await self._pace()
+                        else:
                             promote_ready()
-                    sampled, logp, top_ids, top_lp = await self._await_device(
-                        fetch_task, "decode_wait", slots.num_active
+                        # A first token that lands while the chunk computes
+                        # is applied NOW, not at the next iteration's
+                        # harvest point: its stream gets it at once, and its
+                        # row is in ``ready`` before the next top-up.  The
+                        # wait is left for that fetch's own phases
+                        # (harvest:first, emit) and taken up again.
+                        landed = False
+                        while (
+                            self._pending_fetches
+                            and not fetch_task.done()
+                            and not landed
+                        ):
+                            first_task = self._pending_fetches[0][1]
+                            await self._wait_first(
+                                {fetch_task, first_task},
+                                "decode_wait",
+                                slots.num_active,
+                            )
+                            landed = first_task.done()
+                        if not landed:
+                            sampled, logp, top_ids, top_lp = (
+                                await self._await_device(
+                                    fetch_task, "decode_wait", slots.num_active
+                                )
+                            )
+                            break
+                    await self._harvest_pending(at="landed")
+                with self._phase("emit"):
+                    wait_wall = time.perf_counter() - wait_t0
+                    self.step_trace.append(
+                        # "wait" not "fetch": the D2H copy started at
+                        # dispatch, so this wall (from the chunk's pop at
+                        # the iteration's top) is dominated by the chunk's
+                        # device compute.
+                        (
+                            "decode_wait",
+                            wait_wall,
+                            slots.num_active,
+                            slots.num_active * T,
+                        )
                     )
-                wait_wall = time.perf_counter() - wait_t0
-                self.decode_busy_s += wait_wall
-                self.step_trace.append(
-                    # "wait" not "fetch": the D2H copy started at dispatch,
-                    # so this wall is dominated by the chunk's device
-                    # compute.
-                    (
-                        "decode_wait",
-                        wait_wall,
-                        slots.num_active,
-                        slots.num_active * T,
-                    )
-                )
-                with TraceAnnotation("engine.emit"):
                     self._accept_chunk(
                         slots.rows, pos0_c, sampled, logp, top_ids, top_lp
                     )
-                harvested = cid
-                if not rebuild and self._spec_session_probe(
-                    [s for _, s in slots.active()]
-                ):
-                    # Output grew repetitive enough that in-step speculation
-                    # now beats the fused chunks: drain and let schedule()
-                    # re-propose for real (engine/spec.py).
-                    rebuild = True
+                    harvested = cid
+                    if not rebuild and self._spec_session_probe(
+                        [s for _, s in slots.active()]
+                    ):
+                        # Output grew repetitive enough that in-step
+                        # speculation now beats the fused chunks: drain and
+                        # let schedule() re-propose for real (engine/spec.py).
+                        rebuild = True
             elif not progressed:
                 if self._pending_fetches:
                     # Nothing dispatchable until a first-token fetch lands:
                     # block on the oldest instead of spinning.
                     await self._harvest_pending(at="landed")
                 else:
-                    promote_ready()
-                    if ready and not rebuild:
-                        continue  # late joiners: merge next iteration
-                    # Nothing in flight, nothing to dispatch, nothing
-                    # pending: drained for a rebuild, or every member
-                    # finished — the session is over.
+                    with self._phase("retire"):
+                        promote_ready()
+                        if ready and not rebuild:
+                            continue  # late joiners: merge next iteration
+                        # Nothing in flight, nothing to dispatch, nothing
+                        # pending: drained for a rebuild, or every member
+                        # finished — the session is over.
+                        break
+            with self._phase("yield"):
+                promote_ready()
+                if rebuild and not inflight:
                     break
-            promote_ready()
-            if rebuild and not inflight:
-                break
-            await asyncio.sleep(0)  # let ingress/egress run between chunks
+                # Let ingress/egress run between chunks: whatever the HTTP
+                # side and the other coroutines do with the thread.
+                await asyncio.sleep(0)
 
-        # Drained: every dispatched chunk was harvested, so every write
-        # barrier has passed — release whatever retirement is pending.
-        sweep_retire()
-        flush_retired()
-        self._pipeline_members = set()
+        with self._phase("retire"):
+            # Drained: every dispatched chunk was harvested, so every write
+            # barrier has passed — release whatever retirement is pending.
+            sweep_retire()
+            flush_retired()
+            self._pipeline_members = set()
+            if rebuild:
+                self.pipeline_rebuilds += 1
         self.pipeline_wall_s += time.perf_counter() - session_t0
-        if rebuild:
-            self.pipeline_rebuilds += 1
+        self.pipeline_waited_s += self.phases.waited_s() - waited0
         return dispatched_any
 
     def _any_useful_rows(

@@ -616,8 +616,41 @@ class EngineDispatchMetrics:
              "Cumulative fused-session wall time",
              pipe.get("wall_s", 0.0))
         emit("host_gap_frac", "gauge",
-             "Fraction of fused-session wall not covered by decode "
-             "dispatch/wait device work", pipe.get("host_gap_frac", 0.0))
+             "Fraction of fused-session wall in which the loop was in no "
+             "harvest:* phase (doing work of its own, not waiting for the "
+             "device)", pipe.get("host_gap_frac", 0.0))
+        # The loop's account of its own time (engine/phases.py; the table
+        # of phases: docs/tracing.md).  Two histograms, kept apart: the
+        # loop's phases tile its time, the worker threads' calls lie inside
+        # them.  OUTSIDE the _dispatch ns, like the counters below.
+        acct = s.get("phases") or {}
+        les = [repr(le) for le in acct.get("le", ())] + ["+Inf"]
+        for table, name, label, help_ in (
+            ("loop", "loop_phase", "phase",
+             "Engine loop time by phase; over a fused session the phases "
+             "sum to its wall"),
+            ("calls", "device_call", "call",
+             "Worker-thread calls inside the loop's phases: the jitted "
+             "call that enqueues a program, the token fetch's copy"),
+        ):
+            hn = f"{prefix}_engine_{name}_seconds"
+            lines.append(f"# HELP {hn} {help_}")
+            lines.append(f"# TYPE {hn} histogram")
+            for value, row in (acct.get(table) or {}).items():
+                seen = 0
+                for le, n in zip(les, row["buckets"]):
+                    seen += n
+                    lines.append(
+                        f'{hn}_bucket{{{label}="{escape_label(value)}",'
+                        f'le="{escape_label(le)}"}} {seen}'
+                    )
+                lines.append(
+                    f'{hn}_sum{{{label}="{escape_label(value)}"}} {row["sum"]}'
+                )
+                lines.append(
+                    f'{hn}_count{{{label}="{escape_label(value)}"}} '
+                    f'{row["count"]}'
+                )
         # The first token's path through a session's iteration (engine/
         # pipeline.py _decode_pipeline; docs/decode_pipeline.md).  OUTSIDE
         # the _dispatch ns, like the stall counter below.

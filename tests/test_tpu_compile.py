@@ -537,9 +537,11 @@ def test_kimi_k2_prompt_program_attends_in_the_prefill_kernel_and_keeps_no_state
     ``breakdown.device_ops``; the stages of the XLA loop it replaced are in no
     program (the float32 state written back after every block, a key block
     decompressed by XLA and its layout copy); and the pattern of
-    ``mla_dense_prefill_attn_time_share`` (the benchmark's file: XLA's names
-    for that loop) matches nothing, so the metric reads 0 until a
-    ``benchmark`` issue retires it (PERF.md section 7)."""
+    ``mla_dense_prefill_kernel_time_share`` (the benchmark's file, PR 40)
+    matches that call's name and no other op of the program, so the metric
+    times the kernel and nothing else.  (``mla_dense_prefill_attn_time_share``
+    lists XLA's names for the deleted loop and waits for a ``benchmark`` issue
+    to retire it: PERF.md section 7.)"""
     import json
     import os
     import re
@@ -548,8 +550,8 @@ def test_kimi_k2_prompt_program_attends_in_the_prefill_kernel_and_keeps_no_state
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(
-            root, "chipbench/layer_metrics/mla_dense_prefill_attn_time_share.json")) as f:
-        stale_pattern = re.compile(json.load(f)["args"]["pattern"])
+            root, "chipbench/layer_metrics/mla_dense_prefill_kernel_time_share.json")) as f:
+        pattern = re.compile(json.load(f)["args"]["pattern"])
     text = kimi_step(False).as_text()
     calls = [ln for ln in _custom_calls(text) if "mla_dense_prefill_attention" in ln]
     assert len(calls) == 2, calls
@@ -566,8 +568,8 @@ def test_kimi_k2_prompt_program_attends_in_the_prefill_kernel_and_keeps_no_state
                  "fusion f32[64,128,1024]", "fusion f32[64,128,128]", "fusion f32[64,128]"):
         assert gone not in names, gone
     assert not any("[64,1024,128]" in n or "[64,640,128]" in n for n in names), names
-    stale = {n for n in names if stale_pattern.search(n)}
-    assert not stale, stale
+    assert {n for n in names if pattern.search(n)} == kernel
+    assert all(pattern.search(short_name(ln.strip().removeprefix("ROOT "))) for ln in calls), calls
 
 
 def test_the_dense_prefill_kernel_compiles_for_a_step_of_two_token_blocks(
