@@ -209,7 +209,7 @@ class DecodePipelineMixin:
         cu[len(items) + 1 :] = at
         if self._count_dispatch:
             self._count_dispatch(
-                "unified", [st for _, st, _ in items], [n for _, _, n in items]
+                "unified", [st for _, st, _ in items], [n for _, _, n in items], T
             )
         return RaggedBatch(
             token_ids=tok,

@@ -783,6 +783,7 @@ class SparseModelMetrics:
 
     def __init__(self):
         self.dsa: Dict[str, list] = {}  # dispatch kind -> [context, selected]
+        self.dsa_prefill_tokens: Dict[str, int] = {}  # attention form -> query tokens
         self.mla: Dict[str, list] = {}  # dispatch kind -> [attended, query tokens]
         self.moe_local_pairs = 0
         self.moe_routed_tokens = 0
@@ -794,16 +795,23 @@ class SparseModelMetrics:
     def reset(self) -> None:
         self.__init__()
 
-    def add_dsa(self, kind: str, topk: int, starts, ns) -> None:
+    def add_dsa(self, kind: str, topk: int, starts, ns, prefill_form: Optional[str] = None) -> None:
         """Add a dispatch's query tokens to the selector's account: the token
         at position t has t + 1 context positions and keeps min(topk, t + 1).
-        ``starts[i]``, ``ns[i]``: first position and token count of row i."""
+        ``starts[i]``, ``ns[i]``: first position and token count of row i.
+        ``prefill_form``: the form in which the dispatched PROMPT program
+        attends (ops/sparse_mla.py ``prefill_form``); the tokens of its rows
+        of more than one token (the others are the one-query kernel's) are
+        counted under it."""
         k = topk
         acc = self.dsa.setdefault(kind, [0, 0])
         for start, n in zip(starts, ns):
             start, n = int(start), int(n)
             if n <= 0 or start < 0:
                 continue
+            if prefill_form is not None and n > 1:
+                self.dsa_prefill_tokens[prefill_form] = (
+                    self.dsa_prefill_tokens.get(prefill_form, 0) + n)
             acc[0] += n * start + n * (n + 1) // 2
             full = max(0, min(n, start + n - k + 1)) if start + n >= k else 0
             part = n - full  # tokens with t + 1 < k keep all t + 1
@@ -840,6 +848,7 @@ class SparseModelMetrics:
     def summary(self) -> Dict[str, Any]:
         """The accounts as ``dispatch_summary()["model"]``."""
         return {"dsa": {k: list(v) for k, v in self.dsa.items()},
+                "dsa_prefill_tokens": dict(self.dsa_prefill_tokens),
                 "mla": {k: list(v) for k, v in self.mla.items()},
                 "conv_row_starts": dict(self.conv_row_starts),
                 "conv_tokens": self.conv_tokens,
@@ -894,6 +903,15 @@ class SparseModelMetrics:
                 lines.append(f"# TYPE {prefix}_{name} counter")
                 for kind, v in sorted(acc.items()):
                     lines.append(f'{prefix}_{name}{{kind="{escape_label(kind)}"}} {v[i]}')
+        if self.dsa_prefill_tokens:
+            name = f"{prefix}_dsa_prefill_query_tokens_total"
+            lines += [f"# HELP {name} Query tokens of prompt-chunk rows (more than one token) "
+                      "dispatched to sparse latent attention, by the form the dispatched "
+                      "program attends in: decompressed (the Pallas call "
+                      "mla_sparse_prefill_attention) or absorbed (the XLA loop)",
+                      f"# TYPE {name} counter"]
+            lines += [f'{name}{{form="{escape_label(k)}"}} {v}'
+                      for k, v in sorted(self.dsa_prefill_tokens.items())]
         for name, help_, v in (
             ("moe_local_pairs_total",
              "Routed (token, expert) pairs that landed on experts held here",

@@ -35,8 +35,18 @@ from ..ops.rope import (
     rope_frequencies,
     yarn_mscale,
 )
-from ..ops.dense_mla import dense_decode_attention, dense_prefill_attention
-from ..ops.sparse_mla import fused_sparse_decode_attention, sparse_prefill_attention
+from ..ops.dense_mla import (
+    dense_decode_attention,
+    dense_prefill_attention,
+    latent_prefill_attention,
+)
+from ..ops.sparse_mla import (
+    SCOPES as SPARSE_SCOPES,
+    fused_sparse_decode_attention,
+    prefill_form,
+    sparse_prefill_attention,
+    sparse_prefill_selection,
+)
 from .config import ModelConfig
 from .llama import RaggedBatch, embed_lookup, linear, lm_logits, mlp, rms_norm
 from .moe import expert_dispatch
@@ -341,44 +351,65 @@ def forward_ragged(
 
         if not selector:
             return dense_attention(absorbed, q, q_rope, entry, lp, lat, slots, tables)
-        q_abs = absorbed()
+        # A prompt program attends in the form its token count pays for least
+        # (ops/sparse_mla.py ``prefill_form``): the absorbed XLA loop, or S_t
+        # as a mask and the decompressed kernel, whose output is per head.
+        kernel = not decode and prefill_form(T) == "decompressed"
+        q_abs = None if kernel else absorbed()
         qi = _rope_head(linear(cq, lp, "idx_wq_b").reshape(T, Hi, di), pos, inv_freq, dr)
         ki = _layer_norm(x @ lp["idx_wk"], lp["idx_k_norm_w"], lp["idx_k_norm_b"])
         ki = _rope_head(ki[:, None, :], pos, inv_freq, dr)[:, 0]
         wi = (x @ lp["idx_wproj"]).astype(jnp.float32) * (Hi**-0.5 * di**-0.5)
         lat, idx = _write(lat, entry, slots), _write(idx, ki, slots)
         kw = dict(topk=c.index_topk, sm_scale=sm_scale, rank_v=Rkv)
+        per_head = lambda o_lat: jnp.einsum("thc,hcv->thv", o_lat, lp["w_uv"])  # noqa: E731
 
         def one_token_rows(_):
             # (o [S, H, Rkv], S_t as positions [S, k] or, unasked, None)
             return fused_sparse_decode_attention(
-                q_abs[first], qi[first], wi[first], lat, idx, pos[first],
-                jnp.where(single, rb.kv_lens, 0), tables,
+                absorbed(first) if kernel else q_abs[first], qi[first], wi[first], lat, idx,
+                pos[first], jnp.where(single, rb.kv_lens, 0), tables,
                 return_selection=return_selection, **kw)
 
         if decode:
             o_lat, sel = one_token_rows(None)
+            return linear(per_head(o_lat).reshape(T, H * dv), lp, "wo"), lat, idx, sel
+        if kernel:
+            sel = sparse_prefill_selection(
+                qi, wi, idx, pos, rb.kv_lens, tables, rb.cu_q_lens, rb.num_seqs,
+                topk=c.index_topk, block_q=block_q, block_k=block_k)
+            o = latent_prefill_attention(
+                jnp.concatenate([q[..., :dn], q_rope], axis=-1), sel, lat, lp["w_uk"], lp["w_uv"],
+                rb.kv_lens, tables, rb.cu_q_lens, rb.num_seqs, sm_scale=sm_scale,
+                name=SPARSE_SCOPES["prefill"])  # [T, H, dv]
+            sel = sel if return_selection else None
         else:
             res = sparse_prefill_attention(
                 q_abs, qi, wi, lat, idx, pos, rb.kv_lens, tables, rb.cu_q_lens, rb.num_seqs,
                 block_q=block_q, block_k=block_k, return_mask=return_selection, **kw)
-            o_lat, sel = res if return_selection else (res, None)
-            # Decode rows riding a mixed step: the one-query path, only when
-            # the step has any.
-            k_sel = min(c.index_topk, rb.page_indices.shape[1] * ps)
-            o1, sel1 = jax.lax.cond(
-                jnp.any(single), one_token_rows,
-                lambda _: (jnp.zeros((S, H, Rkv), o_lat.dtype),
-                           jnp.full((S, k_sel), -1, jnp.int32) if return_selection else None),
-                None)
-            at = jnp.where(single, first, T)
-            o_lat = o_lat.at[at].set(o1, mode="drop")
-            if return_selection:
-                hot = jnp.zeros((S, sel.shape[1] + 1), bool).at[
-                    jnp.arange(S)[:, None], jnp.where(sel1 < 0, sel.shape[1], sel1)].set(True)
-                sel = sel.at[at].set(hot[:, :-1], mode="drop")
-        o = jnp.einsum("thc,hcv->thv", o_lat, lp["w_uv"]).reshape(T, H * dv)
-        return linear(o, lp, "wo"), lat, idx, sel
+            o, sel = res if return_selection else (res, None)  # [T, H, Rkv]
+        # Decode rows riding a mixed step: the one-query path, only when the
+        # step has any; its output joins the chunks' in the chunks' form.
+        k_sel = min(c.index_topk, rb.page_indices.shape[1] * ps)
+
+        def riding_rows(_):
+            o1, sel1 = one_token_rows(None)
+            return (per_head(o1) if kernel else o1), sel1
+
+        o1, sel1 = jax.lax.cond(
+            jnp.any(single), riding_rows,
+            lambda _: (jnp.zeros((S,) + o.shape[1:], o.dtype),
+                       jnp.full((S, k_sel), -1, jnp.int32) if return_selection else None),
+            None)
+        at = jnp.where(single, first, T)
+        o = o.at[at].set(o1, mode="drop")
+        if return_selection:
+            hot = jnp.zeros((S, sel.shape[1] + 1), bool).at[
+                jnp.arange(S)[:, None], jnp.where(sel1 < 0, sel.shape[1], sel1)].set(True)
+            sel = sel.at[at].set(hot[:, :-1], mode="drop")
+        if not kernel:
+            o = per_head(o)
+        return linear(o.reshape(T, H * dv), lp, "wo"), lat, idx, sel
 
     def dense_attention(absorbed, q, q_rope, entry, lp, lat, slots, tables):
         """No selector: a one-token row attends to its whole context in the

@@ -110,15 +110,17 @@ def _latent() -> ModelFamily:
     import jax.numpy as jnp
 
     from ..llm.metrics import sparse_model_metrics
+    from ..ops import sparse_mla
     from . import deepseek_v32 as ds
 
     def kinds(config, cache):
         return {name: pages.shape[-1] * pages.dtype.itemsize
                 for name, pages in cache._asdict().items() if pages is not None}
 
-    def count_dispatch(config, kind, starts, ns):
+    def count_dispatch(config, kind, starts, ns, step_tokens=None):
         if config.index_topk:
-            sparse_model_metrics.add_dsa(kind, config.index_topk, starts, ns)
+            form = sparse_mla.prefill_form(step_tokens) if kind == "unified" else None
+            sparse_model_metrics.add_dsa(kind, config.index_topk, starts, ns, prefill_form=form)
         else:
             sparse_model_metrics.add_mla(kind, starts, ns)
 
@@ -208,8 +210,8 @@ def _hybrid() -> ModelFamily:
         forward_sp_prefill=None,
         cache_kinds=kinds,
         check=check,
-        count_dispatch=lambda config, kind, starts, ns: sparse_model_metrics.add_conv(
-            kind, starts, ns),
+        count_dispatch=lambda config, kind, starts, ns, step_tokens=None: (
+            sparse_model_metrics.add_conv(kind, starts, ns)),
         count_aux=sparse_model_metrics.add_moe,
         counts=sparse_model_metrics.summary,
         attn_lanes=lfm2.attn_lanes,

@@ -23,6 +23,14 @@ counts), chosen by how many queries share a key, each ONE Pallas call:
   written once.  Work follows ``kv_lens`` and the rows' query counts, not
   ``max_model_len`` (docs/kimi_k2.md has the grid, what is resident and the
   block sizes with their measurements).
+- ``latent_prefill_attention``: the same kernel for the model WITH a selector
+  (ops/sparse_mla.py, prompt programs of ``PREFILL_KERNEL_TOKENS`` tokens or
+  more): S_t arrives as a mask of the step's tokens, one more operand that
+  stays in HBM; its (program's tokens x key block) slab is copied beside the
+  block's pages and a query attends to what it keeps and to nothing else.
+  Whether the operand exists is a static property of the call: without it
+  the traced kernel is the dense one, op for op.  The call is then named
+  ``mla_sparse_prefill_attention`` (docs/deepseek_v32.md).
 """
 
 from __future__ import annotations
@@ -74,6 +82,9 @@ PREFILL_BLOCK_K = 1024
 PREFILL_BLOCK_Q = 256
 PREFILL_HEADS = 16
 PREFILL_STEP_TOKENS = 1024
+# The bias of a (query, key) pair that S_t does not keep: below the running
+# maximum's floor NEG (``_prefill_kernel::attend``).
+SKIPPED = 2 * NEG
 
 
 def _prefill_kernel(
@@ -87,16 +98,19 @@ def _prefill_kernel(
     wuk_ref,  # [Hg, Rkv, dn] VMEM
     wuv_ref,  # [Hg, Rkv, dv] VMEM
     lat_ref,  # [NP, ps, Rkv + tail] HBM, copied page by page
-    o_ref,  # [TB, Hg * dv] VMEM
-    # scratch
-    buf,  # [2, ppb, ps, Rkv + tail] in the pages' dtype
-    kbuf,  # [Hg, C, dn + tail]: a key block's keys, [W^UK_h c | k^R | zero lanes]
-    vbuf,  # [Hg, C, dv]: its values
-    m_ref,  # [Hg, TB, 1] f32
-    l_ref,  # [Hg, TB, 1] f32
-    acc_ref,  # [Hg, TB, dv] f32
-    sems,  # DMA semaphores (2,)
-    *,
+    # then, ONLY with ``selected``: sel_ref [Tp, nkb * C] int8 HBM, S_t over
+    # the step's tokens and their rows' positions; the output o_ref [TB, Hg *
+    # dv] VMEM; the scratch:
+    #   buf [2, ppb, ps, Rkv + tail] in the pages' dtype
+    #   kbuf [Hg, C, dn + tail]: a key block's keys, [W^UK_h c | k^R | zero lanes]
+    #   vbuf [Hg, C, dv]: its values
+    #   m_ref, l_ref [Hg, TB, 1] f32; acc_ref [Hg, TB, dv] f32
+    #   sems, DMA semaphores (2,)
+    # and ONLY with ``selected``: sbuf [2, TB, C] int8 (S_t of the program's
+    # tokens over a key block, copied beside the block's pages), bias_ref
+    # [tq, C] f32 and ssems, DMA semaphores (2,)
+    *refs,
+    selected: bool,
     sm_scale: float,
     page_size: int,
     pages_per_seq: int,
@@ -108,7 +122,14 @@ def _prefill_kernel(
     there, the row's live key blocks in order; a block's pages are copied
     while the block before it is computed, decompressed once, and every query
     tile of the row attends to it.  (Grid programs run in order on one core:
-    no dimension_semantics.)"""
+    no dimension_semantics.)  Without ``selected`` there is no selector: a
+    query attends to every position up to its own, and no mask operand
+    exists.  With it a query attends to what S_t keeps of them and to nothing
+    else (S_t holds the causal bound and ``kv_len`` already)."""
+    if selected:
+        sel_ref, o_ref, buf, kbuf, vbuf, m_ref, l_ref, acc_ref, sems, sbuf, bias_ref, ssems = refs
+    else:
+        o_ref, buf, kbuf, vbuf, m_ref, l_ref, acc_ref, sems = refs
     Hg, TB, _ = q_ref.shape
     dn, dv = wuk_ref.shape[2], wuv_ref.shape[2]
     rank = wuk_ref.shape[1]
@@ -151,17 +172,38 @@ def _prefill_kernel(
             # block's copies are issued under 50 us of matmuls, and unrolled
             # they cost a second of tracing and lowering a program.
             jax.lax.fori_loop(0, live, one, 0)
+            if selected:
+                dma = pltpu.make_async_copy(
+                    sel_ref.at[pl.ds(base, TB), pl.ds(pl.multiple_of(block * C, C), C)],
+                    sbuf.at[slot], ssems.at[slot])
+                if start:
+                    dma.start()
+                else:
+                    dma.wait()
 
         def attend(ts, kb, masked: bool):
             """Every head of the program: the query tile at ``ts`` against key
             block kb.  ``masked``: the tile holds tokens of other rows, or the
-            block reaches the chunk's first position (the causal comparison)."""
+            block reaches the chunk's first position (the causal comparison);
+            with ``selected`` every pair is a masked one, by S_t."""
             rows = pl.ds(ts, tq)
+            if selected:
+                # Once for the program's heads: 0 where the query is the
+                # row's and S_t keeps the key, else SKIPPED, which lies below
+                # the state's floor NEG: exp(SKIPPED - m) is 0 even for a
+                # query that has seen nothing yet, so it leaves its state as
+                # it is with no second select a head.
+                tok = ts + jax.lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
+                kept = sbuf[jax.lax.rem(kb, 2), rows, :].astype(jnp.int32) != 0
+                keep = kept & (tok >= lo) & (tok < hi)
+                bias_ref[...] = jnp.where(keep, 0.0, SKIPPED)
 
             def head(h, carry):
                 s = jax.lax.dot_general(q_ref[h, rows, :], kbuf[h], (((1,), (1,)), ((), ())),
                                         preferred_element_type=jnp.float32) * sm_scale  # [tq, C]
-                if masked:
+                if selected:
+                    s = s + bias_ref[...]
+                elif masked:
                     tok = ts + jax.lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
                     kpos = kb * C + jax.lax.broadcasted_iota(jnp.int32, (1, C), 1)
                     live = (kpos <= first_pos + (tok + base - t0)) & (tok >= lo) & (tok < hi)
@@ -169,7 +211,7 @@ def _prefill_kernel(
                 m_old = m_ref[h, rows, :]
                 m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
                 p = jnp.exp(s - m_new)
-                if masked:
+                if masked and not selected:
                     # A query with nothing live here (another row's, or all
                     # keys in its future) must leave its state as it is.
                     p = jnp.where(live, p, 0.0)
@@ -212,9 +254,13 @@ def _prefill_kernel(
                 plain = past & own
                 last_pos = first_pos + jnp.minimum(ts + tq, hi) - 1 + base - t0
 
-                pl.when(plain)(lambda: attend(ts, kb, False))
                 # A block wholly in the tile's future is skipped.
-                pl.when(jnp.logical_not(plain) & (kb * C <= last_pos))(lambda: attend(ts, kb, True))
+                if selected:
+                    pl.when(kb * C <= last_pos)(lambda: attend(ts, kb, True))
+                else:
+                    pl.when(plain)(lambda: attend(ts, kb, False))
+                    pl.when(jnp.logical_not(plain) & (kb * C <= last_pos))(
+                        lambda: attend(ts, kb, True))
                 return carry
 
             jax.lax.fori_loop(lo // tq, pl.cdiv(hi, tq), tile, 0)
@@ -236,12 +282,14 @@ def _prefill_kernel(
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "sm_scale", "block_k", "block_q", "heads", "step_tokens", "interpret"))
-def _prefill_call(q, lat_pages, w_uk, w_uv, kv_lens, tables, cu_q_lens, num_seqs, *,
-                  sm_scale, block_k, block_q, heads, step_tokens, interpret):
-    """The kernel's call.  Jitted, so that a process traces the kernel once
-    and a program lowers it once, whatever its layers (PERF.md section 6,
-    PR 31)."""
+    "sm_scale", "block_k", "block_q", "heads", "step_tokens", "interpret", "name"))
+def _prefill_call(q, selection, lat_pages, w_uk, w_uv, kv_lens, tables, cu_q_lens, num_seqs, *,
+                  sm_scale, block_k, block_q, heads, step_tokens, interpret, name):
+    """The kernel's call, with S_t as ``selection`` [T, PP * ps] bool over
+    the step's tokens and their rows' positions or, for a model without a
+    selector, ``None``: then no mask operand is built and the traced kernel
+    is the dense one.  Jitted, so that a process traces the kernel once and a
+    program lowers it once, whatever its layers (PERF.md section 6, PR 31)."""
     T, H, Dq = q.shape
     Rkv, dn = w_uk.shape[1:]
     dv = w_uv.shape[2]
@@ -255,30 +303,40 @@ def _prefill_call(q, lat_pages, w_uk, w_uv, kv_lens, tables, cu_q_lens, num_seqs
     tq = min(block_q, -(-T // 16) * 16)
     TB = min(step_tokens, -(-T // tq) * tq)
     Tp = -(-T // TB) * TB
+    selected = selection is not None
     # Heads lead (a head of the program is a leading index); the query's lanes
     # are padded with zeros to the key's: [q^N | q^R | 0] against [W^UK c | k^R | 0].
     q_h = jnp.pad(q.astype(cdt), ((0, Tp - T), (0, 0), (0, dn + tail - Dq))).transpose(1, 0, 2)
-    kernel = functools.partial(_prefill_kernel, sm_scale=sm_scale, page_size=ps,
-                               pages_per_seq=PP, ppb=ppb, tq=tq)
+    kernel = functools.partial(_prefill_kernel, selected=selected, sm_scale=sm_scale,
+                               page_size=ps, pages_per_seq=PP, ppb=ppb, tq=tq)
+    operands, in_specs, scratch = [q_h, w_uk, w_uv, lat_pages], [
+        pl.BlockSpec((Hg, TB, dn + tail), lambda g, b, *_: (g, b, 0), memory_space=pltpu.VMEM),
+        pl.BlockSpec((Hg, Rkv, dn), lambda g, b, *_: (g, 0, 0), memory_space=pltpu.VMEM),
+        pl.BlockSpec((Hg, Rkv, dv), lambda g, b, *_: (g, 0, 0), memory_space=pltpu.VMEM),
+        pl.BlockSpec(memory_space=pl.ANY),  # pages stay in HBM
+    ], [
+        pltpu.VMEM((2, ppb, ps, W), lat_pages.dtype),
+        pltpu.VMEM((Hg, C, dn + tail), cdt),
+        pltpu.VMEM((Hg, C, dv), cdt),
+        pltpu.VMEM((Hg, TB, 1), jnp.float32),
+        pltpu.VMEM((Hg, TB, 1), jnp.float32),
+        pltpu.VMEM((Hg, TB, dv), jnp.float32),
+        pltpu.SemaphoreType.DMA((2,)),
+    ]
+    if selected:
+        # One byte a (token, position), whole key blocks wide: the (program's
+        # tokens x key block) slab is then one strided copy.  It stays in HBM.
+        operands.append(jnp.pad(selection.astype(jnp.int8),
+                                ((0, Tp - T), (0, -(-PP // ppb) * C - PP * ps))))
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+        scratch += [pltpu.VMEM((2, TB, C), jnp.int8), pltpu.VMEM((tq, C), jnp.float32),
+                    pltpu.SemaphoreType.DMA((2,))]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(H // Hg, Tp // TB),
-        in_specs=[
-            pl.BlockSpec((Hg, TB, dn + tail), lambda g, b, *_: (g, b, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((Hg, Rkv, dn), lambda g, b, *_: (g, 0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((Hg, Rkv, dv), lambda g, b, *_: (g, 0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pl.ANY),  # pages stay in HBM
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((TB, Hg * dv), lambda g, b, *_: (b, g), memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.VMEM((2, ppb, ps, W), lat_pages.dtype),
-            pltpu.VMEM((Hg, C, dn + tail), cdt),
-            pltpu.VMEM((Hg, C, dv), cdt),
-            pltpu.VMEM((Hg, TB, 1), jnp.float32),
-            pltpu.VMEM((Hg, TB, 1), jnp.float32),
-            pltpu.VMEM((Hg, TB, dv), jnp.float32),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
+        scratch_shapes=scratch,
     )
     out = pl.pallas_call(
         kernel,
@@ -286,9 +344,9 @@ def _prefill_call(q, lat_pages, w_uk, w_uv, kv_lens, tables, cu_q_lens, num_seqs
         out_shape=jax.ShapeDtypeStruct((Tp, H * dv), q.dtype),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=96 << 20),
         interpret=interpret,
-        name=SCOPES["prefill"],
+        name=name,
     )(kv_lens.astype(jnp.int32), tables.astype(jnp.int32), cu_q_lens.astype(jnp.int32),
-      num_seqs.astype(jnp.int32), q_h, w_uk, w_uv, lat_pages)
+      num_seqs.astype(jnp.int32), *operands)
     return out[:T].reshape(T, H, dv)
 
 
@@ -309,8 +367,20 @@ def dense_prefill_attention(
     last positions (``kv_len`` minus its query count onwards).  Returns
     [T, H, dv].  Compiles for the chip or raises; under the Pallas
     interpreter only where ``DYN_PALLAS_INTERPRET`` asks."""
-    with jax.named_scope(SCOPES["prefill"]):
+    return latent_prefill_attention(q, None, lat_pages, w_uk, w_uv, kv_lens, tables, cu_q_lens,
+                                    num_seqs, sm_scale=sm_scale, name=SCOPES["prefill"])
+
+
+def latent_prefill_attention(q, selection, lat_pages, w_uk, w_uv, kv_lens, tables, cu_q_lens,
+                             num_seqs, *, sm_scale: float, name: str) -> jnp.ndarray:
+    """``dense_prefill_attention`` (its arguments, its result) under S_t:
+    ``selection`` [T, PP * ps] bool says which positions of its row a
+    step's token attends to (``ops/sparse_mla.py::sparse_prefill_selection``;
+    False beyond the token's own position and ``kv_len``), or None: every
+    position up to its own.  ``name``: the call's, and its scope's."""
+    with jax.named_scope(name):
         return _prefill_call(
-            q, lat_pages, w_uk, w_uv, kv_lens, tables, cu_q_lens, num_seqs, sm_scale=sm_scale,
-            block_k=PREFILL_BLOCK_K, block_q=PREFILL_BLOCK_Q, heads=PREFILL_HEADS,
-            step_tokens=PREFILL_STEP_TOKENS, interpret=pallas_interpret())
+            q, selection, lat_pages, w_uk, w_uv, kv_lens, tables, cu_q_lens, num_seqs,
+            sm_scale=sm_scale, block_k=PREFILL_BLOCK_K, block_q=PREFILL_BLOCK_Q,
+            heads=PREFILL_HEADS, step_tokens=PREFILL_STEP_TOKENS, interpret=pallas_interpret(),
+            name=name)

@@ -1,25 +1,40 @@
-"""DeepSeek sparse attention (DSA) over a paged latent cache, in plain XLA.
+"""DeepSeek sparse attention (DSA) over a paged latent cache: the selector in
+plain XLA, attention over S_t in XLA or in a Pallas kernel.
 
 Two page arrays share one page table: latent pages ``[NP, ps, Rkv + dr]``
 (MLA's normed latent and the one rope key: K and V at once) and indexer pages
 ``[NP, ps, di]`` (the selector's key).  For every query token t the selector
 scores the row's live positions, I[t, s] = sum_j w[t, j] relu(q^I[t, j] .
 k^I[s]), keeps S_t = the min(topk, t + 1) positions of largest score (equal
-scores: lowest s), and attention in absorbed form runs over S_t only.
+scores: lowest s), and attention runs over S_t only.
 
-- ``sparse_prefill_attention``: rows of MANY query tokens (prefill chunks with
-  a cached past).  A device loop over (row, block of ``block_q`` queries)
-  walks the row's live key blocks twice: once for the scores, then, S_t being
-  a threshold on them, once more for attention under that MASK with a running
-  softmax.  Work follows the row's live context, not ``max_model_len``.
-- ``fused_sparse_decode_attention``: rows of ONE query token, what the model
-  runs.  Scores over the row's pages (XLA), S_t as the same threshold MASK
-  (``select_mask``), and attention in ONE Pallas kernel
-  (``mla_sparse_decode_attention``) that walks each live row's live pages
-  under that mask: page tables and ``kv_lens`` prefetched as scalars, pages
-  copied HBM -> VMEM two key blocks deep, a running softmax.  A row with
-  ``kv_len`` 0 gets no fetch and no block; a live row walks
-  ``cdiv(live pages, pages a block)`` blocks.  Nothing is gathered.
+Rows of MANY query tokens (prompt chunks with a cached past): a device loop
+over (row, block of ``block_q`` queries) walks the row's live key blocks for
+the selector's scores; S_t is a threshold on them (``select_mask``).  Work
+follows the row's live context, not ``max_model_len``.  Then, by the compiled
+program's token count (``prefill_form``, ``PREFILL_KERNEL_TOKENS``):
+
+- ``sparse_prefill_attention``, small programs: the ABSORBED form in the same
+  loop, which walks the key blocks once more for attention under that MASK
+  with a running softmax whose float32 state XLA carries through HBM.  Also
+  the reference the tests hold the kernel below to.
+- ``sparse_prefill_selection``, the others: the loop ends with S_t, written
+  as a mask of the step's tokens, and attention is ONE Pallas call named
+  ``mla_sparse_prefill_attention`` in the DECOMPRESSED form under that mask
+  (``ops/dense_mla.py::latent_prefill_attention``: the dense family's
+  prompt-chunk kernel with the mask as one more operand): 2 N H (320 Tq + 512
+  x 256) operations a row of Tq queries where absorbed costs 2 N H 1088 Tq.
+
+Rows of ONE query token:
+
+- ``fused_sparse_decode_attention``, what the model runs.  Scores over the
+  row's pages (XLA), S_t as the same threshold MASK (``select_mask``), and
+  attention in ONE Pallas kernel (``mla_sparse_decode_attention``) that walks
+  each live row's live pages under that mask: page tables and ``kv_lens``
+  prefetched as scalars, pages copied HBM -> VMEM two key blocks deep, a
+  running softmax.  A row with ``kv_len`` 0 gets no fetch and no block; a
+  live row walks ``cdiv(live pages, pages a block)`` blocks.  Nothing is
+  gathered.
 - ``sparse_decode_attention``: the same in plain XLA (``lax.top_k`` gives S_t
   as positions, attention over the GATHERED entries): the reference the
   tests hold the kernel to, as the XLA attention path is for the dense
@@ -27,7 +42,7 @@ scores: lowest s), and attention in absorbed form runs over S_t only.
 
 All give exactly S_t's result.  The stages still in XLA get XLA's op names
 (docs/tracing.md lists them at the benchmark's shapes), each under a
-``jax.named_scope`` of ``SCOPES``; the kernel's op is named after its scope.
+``jax.named_scope`` of ``SCOPES``; a kernel's op is named after its scope.
 """
 
 from __future__ import annotations
@@ -360,32 +375,38 @@ def fused_sparse_decode_attention(
     return out, sel
 
 
-def sparse_prefill_attention(
-    q_abs: jnp.ndarray,  # [T, H, Rkv + dr]
-    qi: jnp.ndarray,  # [T, Hi, di]
-    wi: jnp.ndarray,  # [T, Hi] f32
-    lat_pages: jnp.ndarray,  # [NP, ps, Rkv + dr]
-    idx_pages: jnp.ndarray,  # [NP, ps, di]
-    positions: jnp.ndarray,  # [T]
-    kv_lens: jnp.ndarray,  # [S]
-    tables: jnp.ndarray,  # [S, PP]
-    cu_q_lens: jnp.ndarray,  # [S + 1]
-    num_seqs: jnp.ndarray,  # [1]
-    *,
-    topk: int,
-    sm_scale: float,
-    rank_v: int,
-    block_q: int = 64,
-    block_k: int = 1024,
-    return_mask: bool = False,
-):
-    """Rows of more than one query token (single-token rows are left at
-    zero: ``sparse_decode_attention`` serves them).  Returns [T, H, Rkv], and
-    with ``return_mask`` also S_t as a mask [T, PP * ps] over the row's
-    logical positions (tests and the parity run read it)."""
-    T, H, Dk = q_abs.shape
+# Prompt programs of at least this many tokens attend in the DECOMPRESSED form
+# (S_t as a mask, then the Pallas call ``mla_sparse_prefill_attention``),
+# smaller ones in the absorbed form (the XLA loop).  By operations the forms
+# meet at 171 queries a row (docs/deepseek_v32.md); the programs are compiled
+# for powers of two, and by the attention stage's time alone the forms cross
+# between 128 and 256: one row of Tq queries whose context ends at 8192
+# positions, 128 heads, a layer's call, ms on the host's clock over 20 calls
+# (my chip run, PR 42; the selector's two stages, 0.27 / 0.48 / 0.93 / 1.82
+# ms, run before either and are left out):
+#   Tq            64     128    256    512    300 + 212 (two rows)
+#   absorbed      1.21   2.42   4.75   9.65   10.76
+#   decompressed  2.43   2.82   3.38   5.05   8.28
+PREFILL_KERNEL_TOKENS = 256
+
+
+def prefill_form(step_tokens: int) -> str:
+    """The form in which a prompt program compiled for ``step_tokens`` tokens
+    attends: what the model traces and what the dispatch counter counts by."""
+    return "decompressed" if step_tokens >= PREFILL_KERNEL_TOKENS else "absorbed"
+
+
+def _prompt_blocks(q_abs, qi, wi, lat_pages, idx_pages, positions, kv_lens, tables, cu_q_lens,
+                   num_seqs, *, topk, sm_scale, rank_v, block_q, block_k, want_mask):
+    """The device loop over (row, block of ``block_q`` queries) of the rows
+    of more than one query token: the selector's scores over the row's live
+    key blocks, S_t as ``select_mask``'s threshold on them and, with
+    ``q_abs``, absorbed attention under that mask with a running softmax
+    (``q_abs`` None: the selector alone).  Returns (out [T, H, Rkv] or None,
+    S_t as a mask [T, PP * ps] over the rows' logical positions or None)."""
+    T = qi.shape[0]
     S, PP = tables.shape
-    ps = lat_pages.shape[1]
+    ps = idx_pages.shape[1]
     ppk = max(1, min(block_k // ps, PP))  # pages per key block
     bk = ppk * ps
     nkb_max = -(-PP // ppk)
@@ -393,7 +414,11 @@ def sparse_prefill_attention(
     Bq = block_q
     tables_kb = jnp.pad(tables, ((0, 0), (0, nkb_max * ppk - PP))).reshape(S, nkb_max, ppk)
     pad = lambda a: jnp.pad(a, ((0, Bq),) + ((0, 0),) * (a.ndim - 1))
-    q_abs_p, qi_p, wi_p, pos_p = pad(q_abs), pad(qi), pad(wi), pad(positions)
+    qi_p, wi_p, pos_p = pad(qi), pad(wi), pad(positions)
+    attend = q_abs is not None
+    if attend:
+        H, Dk = q_abs.shape[1:]
+        q_abs_p = pad(q_abs)
 
     q_lens = cu_q_lens[1:] - cu_q_lens[:-1]
     rows = jnp.arange(S, dtype=jnp.int32)
@@ -406,7 +431,6 @@ def sparse_prefill_attention(
         r = jnp.searchsorted(ends, i, side="right").astype(jnp.int32)
         t0 = cu_q_lens[r] + (i - (ends[r] - nblk[r])) * Bq
         q_ok = t0 + jnp.arange(Bq, dtype=jnp.int32) < cu_q_lens[r + 1]
-        qa = jax.lax.dynamic_slice_in_dim(q_abs_p, t0, Bq)
         qib = jax.lax.dynamic_slice_in_dim(qi_p, t0, Bq)
         wib = jax.lax.dynamic_slice_in_dim(wi_p, t0, Bq)
         qpos = jax.lax.dynamic_slice_in_dim(pos_p, t0, Bq)
@@ -427,38 +451,83 @@ def sparse_prefill_attention(
         with jax.named_scope(SCOPES["select"]):
             chosen = select_mask(scores, k_sel)  # [Bq, N]
 
-        def attend_block(kb, st):
-            m, l, acc = st
-            lat = lat_pages[pages_r[kb]].reshape(bk, Dk)
-            sc = jnp.einsum("qhd,sd->qhs", qa, lat, preferred_element_type=jnp.float32)
-            mk = jax.lax.dynamic_slice_in_dim(chosen, kb * bk, bk, axis=1)[:, None, :]
-            sc = jnp.where(mk, sc * sm_scale, NEG)
-            m_new = jnp.maximum(m, jnp.max(sc, axis=-1))
-            p = jnp.exp(sc - m_new[..., None]) * mk
-            alpha = jnp.exp(m - m_new)
-            l = l * alpha + jnp.sum(p, axis=-1)
-            pv = jnp.einsum("qhs,sc->qhc", p.astype(lat.dtype), lat[:, :rank_v],
-                            preferred_element_type=jnp.float32)
-            return m_new, l, acc * alpha[..., None] + pv
+        if attend:
+            qa = jax.lax.dynamic_slice_in_dim(q_abs_p, t0, Bq)
 
-        with jax.named_scope(SCOPES["prefill"]):
-            m0 = jnp.full((Bq, H), NEG, jnp.float32)
-            _, l, acc = jax.lax.fori_loop(
-                0, nkb, attend_block,
-                (m0, jnp.zeros((Bq, H), jnp.float32), jnp.zeros((Bq, H, rank_v), jnp.float32)))
-            res = (acc / jnp.maximum(l, 1e-30)[..., None]).astype(out.dtype)
-        old = jax.lax.dynamic_slice_in_dim(out, t0, Bq)
-        out = jax.lax.dynamic_update_slice_in_dim(
-            out, jnp.where(q_ok[:, None, None], res, old), t0, axis=0)
-        if return_mask:
+            def attend_block(kb, st):
+                m, l, acc = st
+                lat = lat_pages[pages_r[kb]].reshape(bk, Dk)
+                sc = jnp.einsum("qhd,sd->qhs", qa, lat, preferred_element_type=jnp.float32)
+                mk = jax.lax.dynamic_slice_in_dim(chosen, kb * bk, bk, axis=1)[:, None, :]
+                sc = jnp.where(mk, sc * sm_scale, NEG)
+                m_new = jnp.maximum(m, jnp.max(sc, axis=-1))
+                p = jnp.exp(sc - m_new[..., None]) * mk
+                alpha = jnp.exp(m - m_new)
+                l = l * alpha + jnp.sum(p, axis=-1)
+                pv = jnp.einsum("qhs,sc->qhc", p.astype(lat.dtype), lat[:, :rank_v],
+                                preferred_element_type=jnp.float32)
+                return m_new, l, acc * alpha[..., None] + pv
+
+            with jax.named_scope(SCOPES["prefill"]):
+                m0 = jnp.full((Bq, H), NEG, jnp.float32)
+                _, l, acc = jax.lax.fori_loop(
+                    0, nkb, attend_block,
+                    (m0, jnp.zeros((Bq, H), jnp.float32), jnp.zeros((Bq, H, rank_v), jnp.float32)))
+                res = (acc / jnp.maximum(l, 1e-30)[..., None]).astype(out.dtype)
+            old = jax.lax.dynamic_slice_in_dim(out, t0, Bq)
+            out = jax.lax.dynamic_update_slice_in_dim(
+                out, jnp.where(q_ok[:, None, None], res, old), t0, axis=0)
+        if want_mask:
             old_m = jax.lax.dynamic_slice_in_dim(masks, t0, Bq)
             masks = jax.lax.dynamic_update_slice_in_dim(
                 masks, jnp.where(q_ok[:, None], chosen, old_m), t0, axis=0)
         return out, masks
 
-    out0 = jnp.zeros((T + Bq, H, rank_v), q_abs.dtype)
-    masks0 = jnp.zeros((T + Bq, N) if return_mask else (1, 1), dtype=jnp.bool_)
+    out0 = jnp.zeros((T + Bq, H, rank_v), q_abs.dtype) if attend else jnp.zeros((1,), jnp.bool_)
+    masks0 = jnp.zeros((T + Bq, N) if want_mask else (1, 1), dtype=jnp.bool_)
     out, masks = jax.lax.fori_loop(0, ends[-1], block, (out0, masks0))
-    if return_mask:
-        return out[:T], masks[:T, : PP * ps]
-    return out[:T]
+    return out[:T] if attend else None, masks[:T, : PP * ps] if want_mask else None
+
+
+def sparse_prefill_attention(
+    q_abs: jnp.ndarray,  # [T, H, Rkv + dr]
+    qi: jnp.ndarray,  # [T, Hi, di]
+    wi: jnp.ndarray,  # [T, Hi] f32
+    lat_pages: jnp.ndarray,  # [NP, ps, Rkv + dr]
+    idx_pages: jnp.ndarray,  # [NP, ps, di]
+    positions: jnp.ndarray,  # [T]
+    kv_lens: jnp.ndarray,  # [S]
+    tables: jnp.ndarray,  # [S, PP]
+    cu_q_lens: jnp.ndarray,  # [S + 1]
+    num_seqs: jnp.ndarray,  # [1]
+    *,
+    topk: int,
+    sm_scale: float,
+    rank_v: int,
+    block_q: int = 64,
+    block_k: int = 1024,
+    return_mask: bool = False,
+):
+    """Rows of more than one query token in the ABSORBED form, in plain XLA
+    (single-token rows are left at zero: ``sparse_decode_attention`` serves
+    them).  Returns [T, H, Rkv], and with ``return_mask`` also S_t as a mask
+    [T, PP * ps] over the row's logical positions (tests and the parity run
+    read it)."""
+    out, mask = _prompt_blocks(
+        q_abs, qi, wi, lat_pages, idx_pages, positions, kv_lens, tables, cu_q_lens, num_seqs,
+        topk=topk, sm_scale=sm_scale, rank_v=rank_v, block_q=block_q, block_k=block_k,
+        want_mask=return_mask)
+    return (out, mask) if return_mask else out
+
+
+def sparse_prefill_selection(qi, wi, idx_pages, positions, kv_lens, tables, cu_q_lens, num_seqs,
+                             *, topk: int, block_q: int = 64, block_k: int = 1024):
+    """S_t of every token of the rows of more than one query token, as a mask
+    [T, PP * ps] over its row's logical positions (False everywhere for the
+    other tokens): ``sparse_prefill_attention``'s, by the same two stages,
+    with no attention behind it.  What the DECOMPRESSED form attends under
+    (``ops/dense_mla.py::latent_prefill_attention``)."""
+    return _prompt_blocks(
+        None, qi, wi, None, idx_pages, positions, kv_lens, tables, cu_q_lens, num_seqs,
+        topk=topk, sm_scale=None, rank_v=None, block_q=block_q, block_k=block_k,
+        want_mask=True)[1]

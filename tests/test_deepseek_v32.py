@@ -25,7 +25,7 @@ from dynamo_tpu.models import deepseek_v32 as ds
 from dynamo_tpu.models.config import ModelConfig, register_config
 from dynamo_tpu.models.family import RaggedBatch, family_of
 from dynamo_tpu.models.reference import deepseek_v32 as ref
-from dynamo_tpu.ops import rope, sparse_mla
+from dynamo_tpu.ops import dense_mla, rope, sparse_mla
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LOGIT_TOL = 2e-5
@@ -50,6 +50,20 @@ PS, PP, NPAGES, S = 4, 12, 40, 4  # page size, pages a row, pages, rows
 def highest():
     with jax.default_matmul_precision("highest"):
         yield
+
+
+@pytest.fixture(autouse=True)
+def few_enough_mappings():
+    """A program XLA compiled for the CPU stays mapped while a jit cache
+    holds it, and this file's eager steps compile thousands of them: a
+    process past ``vm.max_map_count`` (65530) dies in whatever maps memory
+    next (here: the engine's thread, with a segmentation fault).  So the
+    caches are dropped after a test that leaves the process half way there."""
+    yield
+    if os.path.exists("/proc/self/maps"):
+        with open("/proc/self/maps") as f:
+            if sum(1 for _ in f) > 30000:
+                jax.clear_caches()
 
 
 @pytest.fixture(scope="module")
@@ -84,13 +98,36 @@ def close(a, b):
     return float(np.max(np.abs(np.asarray(a) - b)) / np.max(np.abs(b)))
 
 
+def small_prefill_blocks(monkeypatch):
+    """The prompt-chunk kernel's sizes at this file's shapes (as
+    test_kimi_k2.py's): key blocks of 2 pages, query tiles of 8 tokens, 2 of
+    the 4 heads a program, 16 tokens of a step resident: several of each a
+    row, and rows that lie across two."""
+    monkeypatch.setattr(dense_mla, "PREFILL_BLOCK_K", 8)
+    monkeypatch.setattr(dense_mla, "PREFILL_BLOCK_Q", 8)
+    monkeypatch.setattr(dense_mla, "PREFILL_HEADS", 2)
+    monkeypatch.setattr(dense_mla, "PREFILL_STEP_TOKENS", 16)
+
+
+@pytest.fixture(params=["absorbed", "decompressed"])
+def form(request, monkeypatch):
+    """The form a prompt program attends in.  This file's chunks have 16 or
+    32 tokens, far under ``PREFILL_KERNEL_TOKENS``: ``decompressed`` lowers
+    the constant to 16 so that every one of them takes the kernel."""
+    if request.param == "decompressed":
+        monkeypatch.setattr(sparse_mla, "PREFILL_KERNEL_TOKENS", 16)
+        small_prefill_blocks(monkeypatch)
+    return request.param
+
+
 # ------------------------------------------------------------- (a) logits
 @pytest.mark.parametrize("sealed_prefix", [False, True], ids=["cold", "sealed-prefix"])
-def test_chunked_prefill_then_decode_matches_the_reference(model, sealed_prefix):
+def test_chunked_prefill_then_decode_matches_the_reference(model, sealed_prefix, form):
     """Chunks of 16 through the paged latent and indexer caches, then decode
     (the fused program's path and a one-token row riding a mixed step), with
     index_topk 8 far under the context of 40.  ``sealed-prefix``: the first
-    16 tokens were computed by ANOTHER request into pages this one shares."""
+    16 tokens were computed by ANOTHER request into pages this one shares.
+    In both forms of the prompt chunks' attention (``form``)."""
     cfg, params, toks, want, _ = model
     cache = ds.LatentKVCache.create(cfg, NPAGES, PS, dtype=jnp.float32)
     table = np.arange(5, 5 + PP).astype(np.int32)
@@ -108,7 +145,9 @@ def test_chunked_prefill_then_decode_matches_the_reference(model, sealed_prefix)
     lg, cache, aux = ds.forward_ragged(params, cfg, batch(toks, table, start, 13, 16), cache, **kw)
     assert close(lg[0], want[28]) < LOGIT_TOL
     assert int(aux[1]) == 13 * 2  # real tokens x MoE layers; padding is not counted
-    for t in range(29, 40):
+    # The decode program is one in both forms: under ``decompressed`` a fused
+    # step and a row riding a mixed step once each, behind the kernel's pages.
+    for t in range(29, 40 if form == "absorbed" else 31):
         decode = t % 2 == 0
         lg, cache, _ = ds.forward_ragged(
             params, cfg, batch(toks, table, t, 1, S if decode else 16, decode), cache,
@@ -117,7 +156,7 @@ def test_chunked_prefill_then_decode_matches_the_reference(model, sealed_prefix)
 
 
 # ---------------------------------------------------------- (b) selection
-def test_selection_equals_the_references(model):
+def test_selection_equals_the_references(model, form):
     """S_t of every layer, for t + 1 < index_topk (keeps all) and beyond, from
     a prefill chunk (mask) and from decode rows (positions)."""
     cfg, params, toks, _, masks = model
@@ -160,7 +199,7 @@ def test_select_mask_is_exact_top_k_with_ties_to_the_lowest_index(k):
     assert sorted(live.tolist()) == np.flatnonzero(got[0]).tolist()
 
 
-def test_a_forced_tie_selects_the_lowest_positions_in_both_paths(model):
+def test_a_forced_tie_selects_the_lowest_positions_in_both_paths(model, form):
     """Zero selector weights make every score 0: S_t must be the first
     index_topk positions, as the reference's stable sort gives."""
     cfg, params, toks, _, _ = model
@@ -271,10 +310,11 @@ def test_off_the_chip_the_decode_kernel_is_interpreted_only_when_asked(monkeypat
     assert np.isfinite(np.asarray(out)).all()
 
 
-def test_a_decode_row_riding_a_mixed_step_goes_through_the_kernel(model, monkeypatch):
+def test_a_decode_row_riding_a_mixed_step_goes_through_the_kernel(model, monkeypatch, form):
     """Row A (29 tokens cached) decodes one token in the step that prefills
     8 tokens of row B: A's logits equal the reference's and equal what the
-    XLA one-query path gives in the kernel's place."""
+    XLA one-query path gives in the kernel's place, whichever form row B's
+    chunk attends in."""
     cfg, params, toks, want, _ = model
     cache = ds.LatentKVCache.create(cfg, NPAGES, PS, dtype=jnp.float32)
     ta, tb = np.arange(2, 2 + PP).astype(np.int32), np.arange(39, 39 - PP, -1).astype(np.int32)
@@ -300,6 +340,173 @@ def test_a_decode_row_riding_a_mixed_step_goes_through_the_kernel(model, monkeyp
     monkeypatch.setattr(ds, "fused_sparse_decode_attention", xla_path)
     lg_xla, _, _ = ds.forward_ragged(params, cfg, rb, cache, **kw)
     assert close(lg, np.asarray(lg_xla)) < 1e-5
+
+
+# -------------------------------- (b'') the prompt-chunk kernel, under S_t
+PROMPT_STEPS = {  # query tokens a row | kv_len a row; index_topk 8, key blocks of 8, 16 resident tokens
+    "past-longer-than-topk": ([12], [40]),  # a chunk behind 28 cached positions
+    "context-shorter-than-topk": ([5], [7]),  # every position is kept
+    "forced-tie": ([11], [37]),  # every score 0.0: the lowest positions win
+    "two-prompt-rows-and-a-decode-row": ([9, 1, 21], [30, 17, 48]),
+    "a-row-across-two-token-blocks": ([40], [47]),  # the kernel's second grid axis
+    "kv-len-off-the-key-block": ([11], [37]),
+    "stale-pages-beyond-kv-len": ([9, 1, 21], [30, 17, 46]),  # NaN in every page that is no row's live page
+}
+
+
+def _prompt_step(case, dtype):
+    """A step's prompt chunks over a shared pool of latent and indexer pages:
+    4 heads of 16 + 8 / 16, rank 32, latent pages of 4 in 128 lanes, 4
+    selector heads of 16, 12 pages a row."""
+    rs = np.random.RandomState(11)
+    H, dn, dr, dv, rank, W, Hi, di, NP = 4, 16, 8, 16, 32, 128, 4, 16, 64
+    f = lambda *shape: jnp.asarray(rs.standard_normal(shape), jnp.float32).astype(dtype)  # noqa: E731
+    lat, idx = f(NP, PS, W).at[:, :, rank + dr:].set(0), f(NP, PS, di)
+    w_uk, w_uv = f(H, rank, dn) * 0.3, f(H, rank, dv) * 0.3
+    q_lens, kv = PROMPT_STEPS[case]
+    n = len(q_lens)
+    T = -(-sum(q_lens) // 16) * 16
+    cu = np.full(S + 1, sum(q_lens), np.int32)
+    cu[: n + 1] = np.concatenate([[0], np.cumsum(q_lens)])
+    kv_lens, pos = np.zeros(S, np.int32), np.zeros(T, np.int32)
+    kv_lens[:n] = kv
+    for r in range(n):
+        pos[cu[r]:cu[r + 1]] = np.arange(kv[r] - q_lens[r], kv[r])
+    tables = np.stack([rs.permutation(NP)[:PP] for _ in range(S)]).astype(np.int32)
+    q, qi = f(T, H, dn + dr), f(T, Hi, di)
+    wi = jnp.asarray(rs.standard_normal((T, Hi)), jnp.float32)
+    if case == "forced-tie":
+        wi = jnp.zeros_like(wi)
+    if case == "stale-pages-beyond-kv-len":
+        live = np.zeros(NP, bool)
+        for r in range(n):
+            live[tables[r, : -(-kv[r] // PS)]] = True
+        lat = jnp.where(jnp.asarray(live)[:, None, None], lat, jnp.nan)
+        idx = jnp.where(jnp.asarray(live)[:, None, None], idx, jnp.nan)
+    rows = tuple(jnp.asarray(a) for a in (pos, kv_lens, tables, cu)) + (jnp.asarray([n], jnp.int32),)
+    return (q, w_uk, w_uv, lat, idx, qi, wi) + rows
+
+
+def _plain_selected_attention(q, lat, w_uk, w_uv, mask, tables, cu, n, sm_scale):
+    """Float32, a whole softmax a query over the GATHERED entries of S_t in
+    the decompressed form; rows of one token at zero."""
+    q, lat, w_uk, w_uv = (np.asarray(a, np.float32) for a in (q, lat, w_uk, w_uv))
+    (H, rank, dn), dr = w_uk.shape, q.shape[2] - w_uk.shape[2]
+    out = np.zeros(q.shape[:2] + (w_uv.shape[2],), np.float32)
+    for r in range(n):
+        if cu[r + 1] - cu[r] <= 1:
+            continue
+        ctx = lat[tables[r]].reshape(-1, lat.shape[-1])
+        for t in range(cu[r], cu[r + 1]):
+            kept = ctx[np.flatnonzero(mask[t])]
+            c, k_rope = kept[:, :rank], kept[:, rank:rank + dr]
+            k = np.concatenate([np.einsum("sc,hcn->hsn", c, w_uk),
+                                np.broadcast_to(k_rope, (H,) + k_rope.shape)], axis=-1)
+            sc = np.einsum("hd,hsd->hs", q[t], k) * sm_scale
+            p = np.exp(sc - sc.max(-1, keepdims=True))
+            out[t] = np.einsum("hs,hsv->hv", p / p.sum(-1, keepdims=True),
+                               np.einsum("sc,hcv->hsv", c, w_uv))
+    return out
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5), ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("case", list(PROMPT_STEPS))
+def test_the_sparse_prefill_kernel_equals_the_xla_loop_and_plain_attention(
+    case, dtype, tol, monkeypatch
+):
+    """``sparse_prefill_selection`` + ``latent_prefill_attention`` (S_t as a
+    mask, the decompressed Pallas kernel under the interpreter) against
+    ``sparse_prefill_attention`` (the absorbed XLA loop, its output taken per
+    head through W^UV) and against plain float32 attention over the gathered
+    S_t: the same S_t exactly, the same output to 1e-5 of its largest value
+    in float32.  In bfloat16 the forms round at different places (keys and
+    values rounded after decompression against the absorbed query): 2e-2.  A
+    row of one token, padding tokens and rows past ``num_seqs`` stay at zero.
+    The loop multiplies a weight of 0 by whatever a key block's stale pages
+    hold, so under ``stale-pages`` it reads those pages as zeros; the kernel
+    and the selection read them as they are, NaN."""
+    small_prefill_blocks(monkeypatch)
+    q, w_uk, w_uv, lat, idx, qi, wi, pos, kv_lens, tables, cu, num = _prompt_step(
+        case, jnp.dtype(dtype))
+    n, rank, dn = int(num[0]), 32, 16
+    rows = (pos, kv_lens, tables, cu, num)
+    mask = sparse_mla.sparse_prefill_selection(qi, wi, idx, *rows, topk=8, block_q=8, block_k=16)
+    got = dense_mla.latent_prefill_attention(
+        q, mask, lat, w_uk, w_uv, *rows[1:], sm_scale=0.2, name=sparse_mla.SCOPES["prefill"])
+    assert got.dtype == q.dtype and got.shape == q.shape[:2] + (16,)
+    got, mask = np.asarray(got, np.float32), np.asarray(mask)
+    assert np.isfinite(got).all()
+
+    q_abs = jnp.concatenate([jnp.einsum("thn,hcn->thc", q[..., :dn], w_uk), q[..., dn:],
+                             jnp.zeros(q.shape[:2] + (lat.shape[-1] - rank - 8,), q.dtype)], axis=-1)
+    o_lat, loop_mask = sparse_mla.sparse_prefill_attention(
+        q_abs, qi, wi, jnp.nan_to_num(lat), jnp.nan_to_num(idx), *rows, topk=8, sm_scale=0.2,
+        rank_v=rank, block_q=8, block_k=8, return_mask=True)
+    assert (mask == np.asarray(loop_mask)).all()
+    loop = np.asarray(jnp.einsum("thc,hcv->thv", o_lat, w_uv), np.float32)
+    plain = _plain_selected_attention(q, jnp.nan_to_num(lat), w_uk, w_uv, mask,
+                                      np.asarray(tables), np.asarray(cu), n, 0.2)
+    assert np.abs(got - plain).max() <= tol * np.abs(plain).max()
+    assert np.abs(got - loop).max() <= tol * np.abs(loop).max()
+
+    served = np.zeros(q.shape[0], bool)
+    for r in range(n):
+        t0, t1 = int(cu[r]), int(cu[r + 1])
+        served[t0:t1] = t1 - t0 > 1
+        for t in range(t0, t1 if t1 - t0 > 1 else t0):
+            kept = np.flatnonzero(mask[t])
+            assert len(kept) == min(8, int(pos[t]) + 1) and kept.max() <= int(pos[t])
+            if case == "forced-tie":
+                assert kept.tolist() == list(range(len(kept)))
+    assert not mask[~served].any()
+    assert (got[~served] == 0).all() and np.abs(got[served]).min(axis=(1, 2)).min() > 0
+
+
+def test_a_prompt_programs_token_count_decides_its_form_and_the_counter_follows(
+    model, engine, monkeypatch
+):
+    """One rule, ``prefill_form``: a program below ``PREFILL_KERNEL_TOKENS``
+    attends in the absorbed XLA loop, one at or above it in the decompressed
+    kernel; the model traces by it and the dispatch counter counts the
+    prompt-chunk rows' tokens by it (a row of one token is the one-query
+    kernel's and is counted under neither)."""
+    from dynamo_tpu.llm.metrics import sparse_model_metrics
+
+    K = sparse_mla.PREFILL_KERNEL_TOKENS
+    assert 16 < K <= 512
+    assert [sparse_mla.prefill_form(t) for t in (16, K // 2, K, 512, 1024)] == [
+        "absorbed", "absorbed", "decompressed", "decompressed", "decompressed"]
+
+    cfg, params, toks, want, _ = model
+    calls = []
+    kernel, loop = ds.latent_prefill_attention, ds.sparse_prefill_attention
+    monkeypatch.setattr(ds, "latent_prefill_attention",
+                        lambda q, *a, **k: calls.append(("decompressed", q.shape[0])) or kernel(q, *a, **k))
+    monkeypatch.setattr(ds, "sparse_prefill_attention",
+                        lambda q, *a, **k: calls.append(("absorbed", q.shape[0])) or loop(q, *a, **k))
+    monkeypatch.setattr(sparse_mla, "PREFILL_KERNEL_TOKENS", 32)
+    table = np.arange(5, 5 + PP).astype(np.int32)
+    cache = ds.LatentKVCache.create(cfg, NPAGES, PS, dtype=jnp.float32)
+    _, cache, _ = ds.forward_ragged(params, cfg, batch(toks, table, 0, 16, 16), cache,
+                                    block_q=8, block_k=8)
+    lg, cache, _ = ds.forward_ragged(params, cfg, batch(toks, table, 16, 20, 32), cache,
+                                     block_q=8, block_k=8)
+    assert close(lg[0], want[35]) < LOGIT_TOL
+    # Traced twice a program: the unrolled dense layer and the scan's body.
+    assert calls == [("absorbed", 16)] * 2 + [("decompressed", 32)] * 2
+    ds.forward_ragged(params, cfg, batch(toks, table, 36, 1, S, True), cache, decode=True)
+    assert len(calls) == 4  # a decode program has neither
+
+    sparse_model_metrics.reset()
+    engine._count_dispatch("unified", [0, 30, 7], [12, 1, 3], 16)
+    engine._count_dispatch("unified", [24, 9], [20, 1], 32)
+    engine._count_dispatch("decode", [40, 41], [2, 2])
+    assert sparse_model_metrics.dsa_prefill_tokens == {"absorbed": 15, "decompressed": 20}
+    assert sparse_model_metrics.summary()["dsa_prefill_tokens"] == {"absorbed": 15, "decompressed": 20}
+    text = sparse_model_metrics.render()
+    assert 'dynamo_tpu_dsa_prefill_query_tokens_total{form="absorbed"} 15' in text
+    assert 'dynamo_tpu_dsa_prefill_query_tokens_total{form="decompressed"} 20' in text
+    sparse_model_metrics.reset()
 
 
 # ---------------------------------------------------------------- (c) gate

@@ -193,14 +193,17 @@ def test_one_kv_head_per_shard_is_refused_with_a_sentence():
 _DSV32_PAGES = 32768 * 16 * (640 + 128) * 2 * 6  # latent and indexer pages, six layers
 
 
-def _family_step(topo, config_file: str, pages_bytes: int, traced=None, **static_kw):
+def _family_step(topo, config_file: str, pages_bytes: int, traced=None, prompt_tokens=None,
+                 **static_kw):
     """``compiled(decode)``: the whole step of a configuration file of the
     latent or the hybrid family for a described v5e, each program compiled
     once for the tests that share it (which turn the persistent cache off
     around it: ``no_persistent_cache``).  The kernels go through Mosaic as on
     the chip: conftest's interpreter switch is off, and nothing is patched.
     ``static_kw`` and ``traced`` (name -> (shape, dtype)): the engine's options
-    on the chip, where the family's defaults are not those."""
+    on the chip, where the family's defaults are not those.  ``prompt_tokens``:
+    the prompt program's token bucket (the configuration's ``prefill_chunk``
+    unless given)."""
     import functools
     import json
     import os
@@ -231,7 +234,7 @@ def _family_step(topo, config_file: str, pages_bytes: int, traced=None, **static
         assert sum(a.size * a.dtype.itemsize
                    for a in jax.tree_util.tree_leaves(cache)) == pages_bytes
         S, PP = serve["max_batch"], serve["max_model_len"] // serve["block_size"]
-        T = S if decode else serve["prefill_chunk"]
+        T = S if decode else prompt_tokens or serve["prefill_chunk"]
         i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)  # noqa: E731
         rb = RaggedBatch(i32(T), i32(T), i32(T), i32(S), i32(S, PP), i32(S + 1), i32(1))
         traced_args = {k: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -392,6 +395,67 @@ def test_deepseek_v32_decode_program_attends_in_the_kernel_and_gathers_nothing(
 
 def _custom_calls(text: str) -> list:
     return [ln for ln in text.splitlines() if "tpu_custom_call" in ln and " custom-call(" in ln]
+
+
+def _op_names(text: str) -> set:
+    """The short names (``chipbench.trace_reduce.short_name``: what the
+    harness prints in ``breakdown.device_ops``) of a compiled program's ops
+    outside its fused computations."""
+    from chipbench.trace_reduce import short_name
+
+    names, fused = set(), False
+    for ln in text.splitlines():
+        if ln.endswith("{") and " -> " in ln:  # a computation's head
+            fused = "fused" in ln.split(" ", 1)[0]
+        if not fused and " = " in ln:
+            names.add(short_name(ln.strip().removeprefix("ROOT ")))
+    return names
+
+
+@pytest.fixture(scope="module")
+def dsv32_small_step(topo):
+    """The same configuration's 64-token prompt program: under
+    ``ops/sparse_mla.py::PREFILL_KERNEL_TOKENS``."""
+    return _family_step(topo, "chipbench/configs/deepseek-v3.2-exp-6l-ep16.json", _DSV32_PAGES,
+                        prompt_tokens=64)
+
+
+def test_deepseek_v32_prompt_programs_attend_in_the_form_their_token_count_pays_for(
+    dsv32_step, dsv32_small_step, no_persistent_cache
+):
+    """The cell's 512-token program holds the Pallas call
+    ``mla_sparse_prefill_attention`` exactly twice (the unrolled dense layer
+    and the scan's body), under the name the harness prints in
+    ``breakdown.device_ops``, and nothing of the absorbed loop's attention
+    stage: no float32 state ``f32[64,128,512]`` / ``f32[64,128]`` carried
+    through HBM, no score block ``f32[64,128,1024]``, and no temporary the
+    size of a layer's latent pages (an operand of the call in another layout
+    would be copied whole).  The selector's stages are still XLA's, under the
+    names ``dsa_select_time_share`` lists.  The 64-token program holds no
+    such call and the loop's stages under the names
+    ``mla_sparse_attn_time_share`` lists: the metric still reads the small
+    programs."""
+    from dynamo_tpu.ops import sparse_mla
+
+    assert 64 < sparse_mla.PREFILL_KERNEL_TOKENS <= 512
+    big = dsv32_step(False)
+    text = big.as_text()
+    calls = [ln for ln in _custom_calls(text) if "mla_sparse_prefill_attention" in ln]
+    assert len(calls) == 2, calls
+    names = _op_names(text)
+    assert {n for n in names if n.startswith("mla_sparse_prefill_attention")} == {
+        "mla_sparse_prefill_attention bf16[512,16384]"}
+    for gone in ("fusion f32[64,128]", "fusion f32[64,128,512]", "broadcast f32[64,128,512]",
+                 "constant_dynamic-slice_fusion bf16[64,128,640]"):
+        assert gone not in names, gone
+    assert "f32[64,128,512]" not in text and "f32[64,128,1024]" not in text
+    assert {"fusion f32[64,1024]", "reduce-window s32[64,72,128]"} <= names
+    layer_latent = 32768 * 16 * 640 * 2
+    assert big.memory_analysis().temp_size_in_bytes < layer_latent, big.memory_analysis()
+
+    small = dsv32_small_step(False).as_text()
+    assert not [ln for ln in _custom_calls(small) if "mla_sparse_prefill_attention" in ln]
+    assert {"fusion f32[64,128]", "fusion f32[64,128,512]"} <= _op_names(small)
 
 
 @pytest.mark.parametrize("decode", [False, True], ids=["unified-512", "decode-16"])
@@ -555,12 +619,7 @@ def test_kimi_k2_prompt_program_attends_in_the_prefill_kernel_and_keeps_no_state
     text = kimi_step(False).as_text()
     calls = [ln for ln in _custom_calls(text) if "mla_dense_prefill_attention" in ln]
     assert len(calls) == 2, calls
-    names, fused = set(), False
-    for ln in text.splitlines():
-        if ln.endswith("{") and " -> " in ln:  # a computation's head
-            fused = "fused" in ln.split(" ", 1)[0]
-        if not fused and " = " in ln:
-            names.add(short_name(ln.strip().removeprefix("ROOT ")))
+    names = _op_names(text)
     kernel = {n for n in names if n.startswith("mla_dense_prefill_attention")}
     assert kernel == {"mla_dense_prefill_attention bf16[512,8192]"}, kernel
     for gone in ("dynamic_update_slice f32[64,640,128]", "dynamic_update_slice f32[64,640]",
