@@ -153,16 +153,15 @@ class TpuEngine(
                 "attention kernels need at least 2 — use a smaller tp or "
                 "bfloat16 pages"
             )
+        # State slots of a family whose recurrent state lives beside the pages
+        # (models/mamba2.py): live ones for the running rows, and snapshots.
+        live, snaps = fam.state_slots(model_config, cfg) if fam.state_slots else (0, 0)
         self.kv = KvBlockManager(
-            cfg.num_blocks,
-            cfg.block_size,
-            event_callback=event_callback,
+            cfg.num_blocks, cfg.block_size, event_callback=event_callback,
             enable_prefix_caching=cfg.enable_prefix_caching,
+            live_slots=live, snapshot_slots=snaps,
         )
-        self.scheduler = Scheduler(
-            cfg, self.kv,
-            full_hit_recompute=cfg.block_size if fam.state_per_page else 1,
-        )
+        self.scheduler = Scheduler(cfg, self.kv, resume=fam.resume)
         # Draft-free speculative decoding (engine/spec.py): None = off.
         self._spec_ctl = (
             AcceptanceController(cfg.spec_decode)
@@ -441,12 +440,13 @@ class TpuEngine(
                 self.model_config, cfg.lora.max_adapters, cfg.lora.rank
             ).items():
                 params["layers"][name] = jnp.asarray(leaf, dt)
+        # A family with state slots gets its pools here, beside the pages.
+        # (Frames of this file are in the call stacks of every Mosaic kernel's
+        # lowered text, which keys the compile cache: moving ``warmup`` and
+        # what it calls costs every configuration one cold start.)
         make_cache = partial(
-            fam.create_cache,
-            self.model_config,
-            cfg.num_blocks,
-            cfg.block_size,
-            dtype=jnp.dtype(cfg.cache_dtype),
+            fam.create_cache, self.model_config, cfg.num_blocks, cfg.block_size,
+            dtype=jnp.dtype(cfg.cache_dtype), **({"state_slots": live + snaps} if live else {}),
         )
         if self.mesh is None:
             cache = make_cache()
@@ -935,17 +935,16 @@ class TpuEngine(
         buckets.append(hi)
         return buckets
 
-    def warmup(self) -> Dict[str, int]:
-        """Pre-compile every device program the serving loop can dispatch —
-        one unified step per reachable token bucket plus the fused decode
-        program — so no cold XLA compile (~15s on TPU) ever lands inside a
-        request.  All runs carry slot/pos = -1 so cache writes are dropped
-        (write_kv_ragged) and contents are untouched.  Returns compile_counts.
-        """
+    def _warm_operands(self) -> Tuple[List[Tuple], Optional[Tuple]]:
+        """What warm-up hands each program after ``params`` and ``cache``: a
+        unified step per reachable token bucket, then the fused decode
+        program's operands (None at ``decode_steps`` 1).  All carry slot/pos =
+        -1 so cache writes are dropped (write_kv_ragged) and contents are
+        untouched."""
         cfg = self.cfg
-        t_warm = time.monotonic()
         S, PP = cfg.max_batch, cfg.max_blocks_per_seq
         samp = self._sampling_arrays([])  # greedy defaults, cached counts
+        steps = []
         for T in self.reachable_token_buckets():
             cu = np.zeros((S + 1,), np.int32)
             cu[1:] = T  # one row owns every token; others empty
@@ -959,37 +958,48 @@ class TpuEngine(
                 page_indices=np.zeros((S, PP), np.int32),
                 cu_q_lens=cu,
                 num_seqs=np.asarray([1], np.int32),
-                adapter_slots=(
-                    np.full((T,), -1, np.int32) if self._lora_rank else None
-                ),
+                adapter_slots=np.full((T,), -1, np.int32) if self._lora_rank else None,
+                # No state slot is read or written either (models/mamba2.py).
+                state_slots=np.full((S, 3), -1, np.int32) if self.kv.live_slots else None,
             )
-            out, self.cache = self._step_fn(
-                self.params, self.cache, self._prep(rb), self._prep(samp)
-            )
+            steps.append((rb, samp))
+        multi = None
         if cfg.decode_steps > 1:
-            args = self._prep(
-                (
-                    np.full((S,), -1, np.int32),  # every row inactive
-                    np.zeros((S, PP), np.int32),
-                    np.zeros((S,), np.int32),
-                )
-            )
-            _, last, steps_f, counts_f, self.cache = self._multi_fn(
-                self.params,
-                self.cache,
-                self._prep(np.zeros((S,), np.int32)),
-                self._prep(samp.steps),
+            multi = (
+                np.zeros((S,), np.int32),
+                samp.steps,
                 samp.counts,
-                *args,
-                self._prep(samp),
+                np.full((S,), -1, np.int32),  # every row inactive
+                np.zeros((S, PP), np.int32),
+                np.zeros((S,), np.int32),
+                samp,
+            )
+        return steps, multi
+
+    def warmup(self) -> Dict[str, int]:
+        """Pre-compile every device program the serving loop can dispatch —
+        one unified step per reachable token bucket plus the fused decode
+        program — so no cold XLA compile (~15s on TPU) ever lands inside a
+        request.  Returns compile_counts.
+        """
+        cfg = self.cfg
+        t_warm = time.monotonic()
+        steps, multi = self._warm_operands()
+        self._compile_side_by_side(steps, multi)
+        for ops in steps:
+            out, self.cache = self._step_fn(self.params, self.cache, *self._prep(ops))
+        if multi is not None:
+            tokens, steps_h, counts, *rows = multi
+            rows = self._prep(tuple(rows))
+            _, last, steps_f, counts_f, self.cache = self._multi_fn(
+                self.params, self.cache, self._prep(tokens), self._prep(steps_h), counts, *rows
             )
             # Chain once more with the DEVICE carry: pipeline dispatches 2+
             # feed the previous outputs back in, and committed device arrays
             # key a different executable-cache entry than the uncommitted
             # numpy first dispatch.
             _, last, _, _, self.cache = self._multi_fn(
-                self.params, self.cache, last, steps_f, counts_f,
-                *args, self._prep(samp)
+                self.params, self.cache, last, steps_f, counts_f, *rows
             )
             # Fetch: warmup must not return with compiles/executions still
             # queued (the first real request would absorb them).
@@ -1016,6 +1026,36 @@ class TpuEngine(
                 t *= 2
         self.warmup_s = round(time.monotonic() - t_warm, 3)
         return self.compile_counts()
+
+    def _compile_side_by_side(self, steps: List[Tuple], multi: Optional[Tuple]) -> None:
+        """Before ``warmup`` walks its programs one after another, compile
+        them SIDE BY SIDE into the persistent compilation cache, where there
+        is one and no mesh (under a mesh the walk's operands are global
+        arrays): each program is lowered with the operands the walk will call
+        it with (the trace is shared, so the walk lowers to the same text and
+        finds every executable in the cache) and compiled on a thread of its
+        own (XLA compiles outside the interpreter lock).  A cold start of nine
+        programs of half a minute each then takes about as long as the
+        slowest; a start that finds them in the cache pays one more lowering
+        a program (PERF.md section 6, PR 44, has both readings of every cell)."""
+        if not self.compile_cache_dir or self.mesh is not None:
+            return
+        from concurrent.futures import ThreadPoolExecutor
+
+        t0 = time.monotonic()
+        # Lowered one after another on this thread (tracing holds the
+        # interpreter lock anyway, and traces that interleave do not always
+        # lower to the same text: one run in six missed the cache for three
+        # programs; my chip run, PR 44), compiled side by side.
+        lowered = [self._step_fn.lower(self.params, self.cache, *ops) for ops in steps]
+        if multi is not None:
+            lowered.append(self._multi_fn.lower(self.params, self.cache, *multi))
+        t_lowered = time.monotonic()
+        with ThreadPoolExecutor(len(lowered)) as pool:
+            for done in [pool.submit(low.compile) for low in lowered]:
+                done.result()
+        logger.info("compiled %d programs side by side in %.1f s (lowering %.1f s)",
+                    len(lowered), time.monotonic() - t0, t_lowered - t0)
 
     # ----------------------------------------------------------- tenancy API
     def register_adapter(self, adapter) -> None:

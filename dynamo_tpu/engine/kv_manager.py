@@ -10,6 +10,15 @@ blocks (evicting the coldest reusable ones).  Every store/evict emits a
 Host-side bookkeeping only — the device never sees hashes, just block ids.
 Physical block order is irrelevant to the device (attention gathers via block
 tables), so allocation never copies anything in HBM.
+
+A family whose recurrent state lives in SLOTS beside the pages
+(models/mamba2.py; docs/granite_hybrid.md) keeps them under this manager too:
+``live_slots`` slots of which a running row owns one from admission to
+retirement or preemption, and ``snapshot_slots`` slots each attached to the
+sealed block at whose end its copy of the state was taken.  A prefix is
+resumable where such a block is; a snapshot is freed with its block and
+dropped first, least recently used, when the pool is full: a block without
+its snapshot is not resumable, never wrong.
 """
 
 from __future__ import annotations
@@ -22,6 +31,7 @@ from ..llm.kv_router.protocols import (
     KvCacheEvent,
     KvCacheStoredBlockData,
 )
+from ..llm.metrics import ssm_metrics
 from ..tokens import TokenBlock
 
 
@@ -52,7 +62,19 @@ class KvBlockManager:
         block_size: int,
         event_callback: Optional[EventCallback] = None,
         enable_prefix_caching: bool = True,
+        live_slots: int = 0,
+        snapshot_slots: int = 0,
     ):
+        # State slots (module docstring): live slots are ids [0, live_slots),
+        # snapshot slots [live_slots, live_slots + snapshot_slots).
+        self.live_slots = live_slots
+        self.snapshot_slots = snapshot_slots
+        self._live_free: List[int] = list(range(live_slots - 1, -1, -1))
+        self._snap_free: List[int] = list(range(live_slots + snapshot_slots - 1,
+                                                live_slots - 1, -1))
+        # block id -> its snapshot's slot, least recently used first.
+        self._snap_of: "OrderedDict[int, int]" = OrderedDict()
+        self._snap_pins: Dict[int, int] = {}  # slot -> rows about to read it
         self.num_blocks = num_blocks
         self.block_size = block_size
         self._blocks = [_Block(i) for i in range(num_blocks)]
@@ -92,6 +114,84 @@ class KvBlockManager:
     @property
     def hit_rate(self) -> float:
         return self.matched_blocks / self.lookup_blocks if self.lookup_blocks else 0.0
+
+    # ------------------------------------------------------------ state slots
+    def take_live_slot(self) -> Optional[int]:
+        slot = self._live_free.pop() if self._live_free else None
+        self._slot_gauges()
+        return slot
+
+    def free_live_slot(self, slot: int) -> None:
+        self._live_free.append(slot)
+        self._slot_gauges()
+
+    def _slot_gauges(self) -> None:
+        ssm_metrics.slots_in_use = {
+            "live": self.live_slots - len(self._live_free), "snapshot": len(self._snap_of)}
+
+    def resumable(self, block_ids: Sequence[int], below: int) -> Tuple[int, int]:
+        """(n, slot): the longest run ``block_ids[:n]`` of a matched prefix
+        that ends at a block holding a snapshot and covers fewer than
+        ``below`` tokens (a prompt's last token is always computed), with the
+        snapshot's slot; (0, -1) where there is none.  The slot is PINNED
+        until ``unpin_snapshot``: the row that resumes from it reads it in its
+        first step, and until that step is enqueued ``reserve_snapshot`` hands
+        the slot to nobody."""
+        for n in range(min(len(block_ids), (below - 1) // self.block_size), 0, -1):
+            slot = self._snap_of.get(block_ids[n - 1])
+            if slot is not None:
+                self._snap_of.move_to_end(block_ids[n - 1])
+                self._snap_pins[slot] = self._snap_pins.get(slot, 0) + 1
+                return n, slot
+        return 0, -1
+
+    def unpin_snapshot(self, slot: int) -> None:
+        left = self._snap_pins.get(slot, 0) - 1
+        if left > 0:
+            self._snap_pins[slot] = left
+        else:
+            self._snap_pins.pop(slot, None)
+
+    def has_snapshot(self, seq_hash: int) -> bool:
+        return self._by_hash.get(seq_hash) in self._snap_of
+
+    def reserve_snapshot(self) -> int:
+        """A slot for a snapshot about to be taken: a free one, else the least
+        recently used snapshot's that no admitted row is about to read, else
+        -1.  The caller attaches it (``attach_snapshot``) once its block is
+        sealed."""
+        if self._snap_free:
+            return self._snap_free.pop()
+        for bid, slot in self._snap_of.items():
+            if slot not in self._snap_pins:
+                del self._snap_of[bid]
+                ssm_metrics.snapshots["evicted"] += 1
+                return slot
+        ssm_metrics.snapshots["no_slot"] += 1
+        return -1
+
+    def free_snapshot(self, slot: int) -> None:
+        """A reserved slot that no step wrote goes back to the pool."""
+        self._snap_free.append(slot)
+
+    def attach_snapshot(self, seq_hash: int, slot: int) -> None:
+        """``slot`` holds the state at the end of the sealed block of
+        ``seq_hash``.  Where that block is gone already or has a snapshot
+        (two rows computed the same prefix side by side) the slot goes back."""
+        bid = self._by_hash.get(seq_hash)
+        if bid is None or bid in self._snap_of:
+            self.free_snapshot(slot)
+        else:
+            self._snap_of[bid] = slot
+            ssm_metrics.snapshots["taken"] += 1
+        self._slot_gauges()
+
+    def _drop_snapshot(self, block_id: int) -> None:
+        slot = self._snap_of.pop(block_id, None)
+        if slot is not None:
+            self._snap_free.append(slot)
+            ssm_metrics.snapshots["evicted"] += 1
+            self._slot_gauges()
 
     # ----------------------------------------------------------------- events
     def _emit(self, event: KvCacheEvent) -> None:
@@ -159,6 +259,7 @@ class KvBlockManager:
         token_blocks: Sequence[TokenBlock],
         num_blocks_needed: int,
         count_hits: bool = True,
+        share: Optional[int] = None,
     ) -> Optional[Tuple[List[int], int]]:
         """Allocate ``num_blocks_needed`` blocks for a prompt whose complete
         blocks are ``token_blocks`` (hashed).  Leading blocks already resident
@@ -169,9 +270,13 @@ class KvBlockManager:
         and counting them would skew gpu_prefix_cache_hit_rate the same way
         acquire_prefix's docstring warns about pinning.
 
+        ``share``: share at most that many of the resident leading blocks (a
+        hit cut back to where the sequence can be resumed); the others are
+        computed again into fresh blocks.
+
         Returns (block_ids, num_cached_tokens) or None if out of capacity.
         """
-        matched = self.match_prefix(token_blocks)
+        matched = self.match_prefix(token_blocks)[:share]
         if count_hits:
             self.lookup_blocks += len(token_blocks)
             self.matched_blocks += len(matched)
@@ -254,6 +359,7 @@ class KvBlockManager:
         if self._free_reusable:
             bid, _ = self._free_reusable.popitem(last=False)  # LRU evict
             blk = self._blocks[bid]
+            self._drop_snapshot(bid)
             if blk.sequence_hash is not None:
                 self._by_hash.pop(blk.sequence_hash, None)
                 # Tiered cache: a lower tier still holding the contents
@@ -336,4 +442,6 @@ class KvBlockManager:
         self._free_anon = list(range(self.num_blocks))
         self._free_reusable.clear()
         self._by_hash.clear()
+        for bid in list(self._snap_of):
+            self._drop_snapshot(bid)
         self._emit(KvCacheEvent(self._next_event_id(), None))

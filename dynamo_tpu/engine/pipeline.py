@@ -192,8 +192,13 @@ class DecodePipelineMixin:
             if self._lora_registry is not None
             else None
         )
+        # State slots (models/mamba2.py): where each row's recurrent state
+        # starts from, its live slot, and the slot its snapshot goes to.
+        sslots = np.full((S, 3), -1, np.int32) if self.kv.live_slots else None
         at = 0
         for i, (seq, start, n) in enumerate(items):
+            if sslots is not None:
+                sslots[i] = self._state_slots_row(seq, start, n)
             all_toks = seq.prompt + seq.output
             tok[at : at + n] = all_toks[start : start + n]
             p = np.arange(start, start + n, dtype=np.int32)
@@ -220,7 +225,30 @@ class DecodePipelineMixin:
             cu_q_lens=cu,
             num_seqs=np.asarray([len(items)], np.int32),
             adapter_slots=aslots,
+            state_slots=sslots,
         )
+
+    def _state_slots_row(self, seq: SequenceState, start: int, n: int):
+        """(read, write, snapshot) slots of one row of a unified step: see
+        ``RaggedBatch.state_slots``.  A prompt row that ends ON a multiple of
+        the resume stride leaves a snapshot there, unless its block has one
+        or the pool has no slot to give (engine/kv_manager.py).  The snapshot
+        a row starts from stays PINNED until the step is enqueued
+        (``_run_unified``): no row of the step under construction is handed
+        it as the slot to write."""
+        read = seq.state_slot if seq.state_start is None else seq.state_start
+        snap, end = -1, start + n
+        if (
+            self.cfg.enable_prefix_caching
+            and end <= len(seq.prompt)
+            and end % self.scheduler.resume_stride == 0
+        ):
+            h = seq.block_seq.blocks[end // self.cfg.block_size - 1].sequence_hash
+            if not self.kv.has_snapshot(h):
+                snap = self.kv.reserve_snapshot()
+                if snap >= 0:
+                    seq.snapshot_due = (h, snap)
+        return read, seq.state_slot, snap
 
     async def _run_unified(self, plan: StepPlan) -> None:
         with self._phase("prompt_build"):
@@ -316,14 +344,23 @@ class DecodePipelineMixin:
                     seq.t_last_chunk = t0 + wall
             pending_rows: List[Tuple[SequenceState, int]] = []
             for i, (seq, start, n) in enumerate(plan.items):
+                due, seq.snapshot_due = seq.snapshot_due, None
+                # The step that read the row's start is enqueued: the row goes
+                # on from its own live slot, and whatever writes the snapshot
+                # it started from runs behind that read.
+                self.scheduler.state_started(seq)
                 if seq.finished:
                     seq.awaiting_fetch = False  # pre-marked above; never parked
+                    if due is not None:  # no block of its was sealed: the slot goes back
+                        self.kv.attach_snapshot(*due)
                     continue
                 if start >= len(seq.prompt):
                     # Decode row: the fed token joins the hash stream.
                     seq.block_seq.append((seq.prompt + seq.output)[start])
                 seq.num_computed = start + n
                 self._seal_completed_blocks(seq)
+                if due is not None:  # the step left a snapshot at this row's end
+                    self.kv.attach_snapshot(*due)
                 if not seq.in_prefill:
                     # This row's sampled token is in flight (pre-marked before
                     # the dispatch); park the row until a harvest point applies
@@ -759,7 +796,7 @@ class DecodePipelineMixin:
                     or not seq.in_prefill
                 ):
                     continue
-                chunk = min(budget, len(seq.prompt) - seq.num_computed)
+                chunk = self.scheduler.prompt_chunk(seq, budget)
                 items.append((seq, seq.num_computed, chunk))
                 budget -= chunk
             if items:

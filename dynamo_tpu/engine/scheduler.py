@@ -33,6 +33,7 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
 
+from ..llm.metrics import ssm_metrics
 from ..llm.protocols import PreprocessedRequest
 from ..llm.qos import BATCH, INTERACTIVE, normalize_priority
 from ..tokens import TokenBlockSequence
@@ -156,6 +157,17 @@ class SequenceState:
     # is behind this check).  The CONTEXT travels in the migration snapshot
     # (SequenceSnapshot.trace) so a migrated stream stays one trace.
     trace: Any = None
+    # --- state slots (engine/kv_manager.py; docs/granite_hybrid.md) ---
+    # The live slot this row's recurrent state is in while it runs (-1: the
+    # family has none, or the row is not running).  ``state_start``: where the
+    # row's NEXT step reads its state from, set at admission and cleared once
+    # that step is enqueued: -1 zeros, a snapshot's slot (pinned until then),
+    # or None once the row goes on from its own live slot.
+    state_slot: int = -1
+    state_start: Optional[int] = None
+    # (block hash, slot) of the snapshot the row's step in flight leaves at
+    # its end, attached once that step's blocks are sealed (pipeline.py).
+    snapshot_due: Optional[Tuple[int, int]] = None
 
     def __post_init__(self) -> None:
         if self.orig_prompt_len == 0:
@@ -469,7 +481,16 @@ class RowSlots:
         self._pending: set = set()  # retired, awaiting the write barrier
 
     def assign(self, seq: SequenceState) -> int:
-        i = self._free.pop()
+        """A row of the fused program for ``seq``: the lowest free one, or,
+        for a family with state slots, the row of its live slot (the program
+        updates row i's state in slot i, in place: models/mamba2.py ``step``).
+        That row is free: its last owner gave the slot back when it left the
+        scheduler, which is when its row here was freed."""
+        if seq.state_slot >= 0:
+            i = seq.state_slot
+            self._free.remove(i)
+        else:
+            i = self._free.pop()
         self.rows[i] = seq
         return i
 
@@ -511,14 +532,19 @@ class StepPlan:
 
 
 class Scheduler:
-    def __init__(self, cfg: EngineConfig, kv: KvBlockManager, full_hit_recompute: int = 1):
+    def __init__(self, cfg: EngineConfig, kv: KvBlockManager, resume: str = "token"):
         self.cfg = cfg
         self.kv = kv
-        # Tokens a fully cached prompt computes again for its logits: the
-        # last one, or the whole last block for a family whose state is held
-        # by PAGE (models/family.py ``state_per_page``): its sealed entry
-        # ends AT the last token and cannot restart one position earlier.
-        self.full_hit_recompute = full_hit_recompute
+        # Where a sequence of the family can be resumed, which is where a
+        # prefix hit may end (models/family.py ``resume``).  A prompt's last
+        # token is always computed for its logits, so a fully cached prompt
+        # gives back: its last token ("token"); its whole last block
+        # ("block": state held by PAGE, whose sealed entry ends AT the last
+        # token and cannot restart one position earlier); everything after
+        # the last snapshot BEFORE its end ("snapshot": state held by slot,
+        # kept at multiples of ``resume_stride`` only).
+        self.resume = resume
+        self.resume_stride = cfg.prefill_chunk if resume == "snapshot" else 0
         self.waiting: WfqQueue = WfqQueue(
             tenant_weights=cfg.qos.tenant_weights,
             default_weight=cfg.qos.default_weight,
@@ -574,6 +600,35 @@ class Scheduler:
             self.kv.free_sequence(seq.block_ids)
             seq.block_ids = []
         self._release_pin(seq)
+        self._release_state(seq)
+
+    def _release_state(self, seq: SequenceState) -> None:
+        if seq.state_slot >= 0:
+            self.kv.free_live_slot(seq.state_slot)
+            seq.state_slot = -1
+        self.state_started(seq)
+        if seq.snapshot_due is not None:
+            # Reserved for a step that was built and has not run to its end:
+            # the slot goes back with nothing attached to it.
+            self.kv.free_snapshot(seq.snapshot_due[1])
+            seq.snapshot_due = None
+
+    def state_started(self, seq: SequenceState) -> None:
+        """``seq`` reads its state from its own live slot from now on: the
+        step that read where it started is enqueued, or the row is gone."""
+        if seq.state_start is not None and seq.state_start >= 0:
+            self.kv.unpin_snapshot(seq.state_start)
+        seq.state_start = None
+
+    def prompt_chunk(self, seq: SequenceState, budget: int) -> int:
+        """Prompt tokens of ``seq`` the next step takes of ``budget``.  Under
+        snapshots a row's share of a step never crosses a multiple of the
+        resume stride, so that the state at every boundary passed is a row's
+        LAST in some step and can be kept."""
+        chunk = min(budget, len(seq.prompt) - seq.num_computed)
+        if self.resume_stride:
+            chunk = min(chunk, self.resume_stride - seq.num_computed % self.resume_stride)
+        return chunk
 
     def _release_pin(self, seq: SequenceState) -> None:
         if seq.pin_ids:
@@ -647,7 +702,7 @@ class Scheduler:
             if budget <= 0 or len(items) >= self.cfg.max_batch:
                 break
             if seq.in_prefill and not seq.finished and not seq.frozen:
-                chunk = min(budget, len(seq.prompt) - seq.num_computed)
+                chunk = self.prompt_chunk(seq, budget)
                 items.append((seq, seq.num_computed, chunk))
                 budget -= chunk
 
@@ -689,7 +744,7 @@ class Scheduler:
             self._record_admission(seq)
             # Admission always leaves >= 1 prompt token to compute (a fully
             # cached prompt still recomputes its last token for logits).
-            chunk = min(budget, len(seq.prompt) - seq.num_computed)
+            chunk = self.prompt_chunk(seq, budget)
             items.append((seq, seq.num_computed, chunk))
             budget -= chunk
 
@@ -805,25 +860,75 @@ class Scheduler:
         reserve = self._pressure_reserve()
         if reserve and prompt_blocks + reserve > self.kv.free_blocks:
             return False  # kv_pressure fault: pool squeezed, head waits
+        slotted = self.resume == "snapshot"
+        if slotted and len(self.running) >= self.kv.live_slots:
+            return False  # every live slot is a running row's
         seq.block_seq.extend(seq.prompt)
-        alloc = self.kv.allocate_sequence(seq.block_seq.blocks, prompt_blocks)
+        share = start = None
+        if slotted:
+            # The hit is cut back to the last block that holds a snapshot;
+            # the blocks past it are computed again into fresh blocks.
+            matched = self.kv.match_prefix(seq.block_seq.blocks)
+            share, start = self.kv.resumable(matched, below=len(seq.prompt))
+            if self._snapshot_ahead(seq, share * self.cfg.block_size):
+                # The head waits (admission stops at it, as for a head that
+                # does not fit): a later pass finds the snapshot.
+                if start >= 0:
+                    self.kv.unpin_snapshot(start)
+                seq.block_seq = TokenBlockSequence(
+                    block_size=self.cfg.block_size, salt=seq.kv_salt
+                )
+                return False
+        alloc = self.kv.allocate_sequence(seq.block_seq.blocks, prompt_blocks, share=share)
         if alloc is None:
             seq.block_seq = TokenBlockSequence(
                 block_size=self.cfg.block_size, salt=seq.kv_salt
             )
+            if slotted and start >= 0:
+                self.kv.unpin_snapshot(start)
             return False
         seq.block_ids, cached_tokens = alloc
         # Admission holds its own references now; the pre-admission pin
         # (sp-prefill / host-restore) has done its job.
         self._release_pin(seq)
-        # A fully-cached prompt must still recompute its last token to get
-        # logits for sampling the first output token.
-        if cached_tokens >= len(seq.prompt):
-            cached_tokens = len(seq.prompt) - self.full_hit_recompute
+        if slotted:
+            seq.state_slot, seq.state_start = self.kv.take_live_slot(), start
+            ssm_metrics.add_start(len(matched) * self.cfg.block_size, cached_tokens)
+        elif cached_tokens >= len(seq.prompt):
+            # A fully-cached prompt must still recompute its last token to
+            # get logits for sampling the first output token.
+            cached_tokens = len(seq.prompt) - (
+                self.cfg.block_size if self.resume == "block" else 1)
         seq.num_computed = cached_tokens
         seq.num_cached_prompt = cached_tokens
         seq.num_sealed_blocks = cached_tokens // self.cfg.block_size
         return True
+
+    def _snapshot_ahead(self, seq: SequenceState, resumed: int) -> bool:
+        """A running row is still computing, inside its own prompt, the
+        stretch of ``seq``'s prompt from ``resumed`` tokens (where ``seq``
+        could be resumed now) to the next multiple of the resume stride, and
+        will leave a snapshot there that ``seq`` can start from.  ``seq``
+        then waits for it: admitted now it would compute the same tokens
+        beside that row, from a state fixed at admission (32 prompts of 8
+        shared prefixes at once started 8 to 23 rows from zeros, and the
+        window's tails moved with that count: PERF.md section 6, PR 44).
+        The wait ends with that row's prompt at the latest."""
+        if not self.cfg.enable_prefix_caching:
+            return False
+        ahead = resumed + self.resume_stride
+        if ahead >= len(seq.prompt):  # a snapshot ends before the prompt's last token
+            return False
+        last = ahead // self.cfg.block_size - 1
+        want = seq.block_seq.blocks[last].sequence_hash
+        return any(
+            r.num_computed < ahead <= len(r.prompt)
+            and not r.finished
+            and not r.frozen
+            and len(r.block_seq.blocks) > last
+            and r.block_seq.blocks[last].sequence_hash == want
+            for r in self.running
+        )
 
     def _ensure_slot(self, seq: SequenceState, lookahead: int = 1) -> bool:
         """Allocate KV blocks so ``lookahead`` tokens past num_computed have
@@ -847,6 +952,7 @@ class Scheduler:
         self.running.remove(seq)
         self.kv.free_sequence(seq.block_ids)
         seq.block_ids = []
+        self._release_state(seq)
         # Fold generated tokens into the prompt so recompute resumes exactly.
         seq.prompt = seq.prompt + seq.output
         seq.output = []

@@ -292,6 +292,7 @@ class HttpService:
             objstore_metrics,
             request_hop_metrics,
             spec_metrics,
+            ssm_metrics,
             tenancy_metrics,
         )
 
@@ -310,6 +311,7 @@ class HttpService:
             + qos_metrics.render(self._metrics_prefix).encode()
             + engine_dispatch_metrics.render(self._metrics_prefix).encode()
             + sparse_model_metrics.render(self._metrics_prefix).encode()
+            + ssm_metrics.render(self._metrics_prefix).encode()
             + request_hop_metrics.render(self._metrics_prefix).encode()
             + kv_tier_metrics.render(self._metrics_prefix).encode()
             + kv_integrity_metrics.render(self._metrics_prefix).encode()

@@ -934,6 +934,54 @@ class SparseModelMetrics:
 sparse_model_metrics = SparseModelMetrics()
 
 
+class SsmMetrics:
+    """The state slots' account (engine/kv_manager.py, engine/scheduler.py;
+    docs/granite_hybrid.md, docs/tracing.md): where admitted requests' state
+    came from, what became of block-level hits, and the snapshots' fate.
+    Renders nothing until a family with state slots admitted a request."""
+
+    def __init__(self):
+        # ONE count a request admitted, or admitted again after preemption.
+        self.request_starts = {"zero": 0, "snapshot": 0}
+        # Tokens of block-level hits kept, and cut back for want of a snapshot.
+        self.hit_tokens = {"resumed": 0, "given_back": 0}
+        self.snapshots = {"taken": 0, "no_slot": 0, "evicted": 0}
+        self.slots_in_use = {"live": 0, "snapshot": 0}
+
+    def reset(self) -> None:
+        self.__init__()
+
+    def add_start(self, matched_tokens: int, resumed_tokens: int) -> None:
+        self.request_starts["snapshot" if resumed_tokens else "zero"] += 1
+        self.hit_tokens["resumed"] += resumed_tokens
+        self.hit_tokens["given_back"] += matched_tokens - resumed_tokens
+
+    def render(self, prefix: str = "dynamo_tpu") -> str:
+        if not sum(self.request_starts.values()):
+            return ""
+        lines = []
+        for name, kind, label, help_, acc in (
+            ("ssm_request_starts_total", "counter", "state",
+             "Requests admitted (or admitted again after preemption) to a family with state "
+             "slots, by where their recurrent state started: zeros or a snapshot",
+             self.request_starts),
+            ("ssm_hit_tokens_total", "counter", "outcome",
+             "Tokens of block-level prefix hits: resumed from a snapshot, or given back "
+             "(computed again) for want of one", self.hit_tokens),
+            ("ssm_snapshots_total", "counter", "outcome",
+             "Snapshots of the recurrent state at a resume stride: taken, not taken for want "
+             "of a slot, or dropped (pool full, or their block evicted)", self.snapshots),
+            ("ssm_slots_in_use", "gauge", "kind",
+             "State slots in use: live (running rows) and snapshot", self.slots_in_use),
+        ):
+            lines += [f"# HELP {prefix}_{name} {help_}", f"# TYPE {prefix}_{name} {kind}"]
+            lines += [f'{prefix}_{name}{{{label}="{escape_label(k)}"}} {v}' for k, v in acc.items()]
+        return "\n".join(lines) + "\n"
+
+
+ssm_metrics = SsmMetrics()
+
+
 class RequestHopMetrics:
     """The always-on per-request TTFT/TPOT hop account (docs/tracing.md):
     sums and counts of the intervals between the O(1) stamps a request

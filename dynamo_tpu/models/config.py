@@ -24,9 +24,10 @@ from typing import Any, Dict, Optional
 LATENT_MODEL_TYPES = ("deepseek_v32", "deepseek_v3", "kimi_k2")
 # model_types of the dense GQA block of models/llama.py (an absent key too).
 LLAMA_MODEL_TYPES = ("llama", "qwen2", "mistral", "mixtral")
-# The hybrid family (models/lfm2.py): gated short convolutions among GQA
-# attention layers, dense then sparse feed-forwards.
-HYBRID_MODEL_TYPES = ("lfm2_moe",)
+# The hybrid family (models/lfm2.py): a layer's mixer is a gated short
+# convolution (lfm2_moe), a Mamba-2 scan (granitemoehybrid) or GQA attention;
+# dense then sparse feed-forwards.
+HYBRID_MODEL_TYPES = ("lfm2_moe", "granitemoehybrid")
 
 
 @dataclass(frozen=True)
@@ -86,6 +87,24 @@ class ModelConfig:
     # conv_L_cache - 1 positions before a token); () and 0 elsewhere.
     layer_types: tuple = ()
     conv_L_cache: int = 0
+    qk_norm: bool = False  # an RMSNorm a head over q and k before the rotation
+    # granitemoehybrid (docs/granite_hybrid.md): four scalar multipliers, each
+    # static and without an op where it is 1 (attention_multiplier None: the
+    # softmax scale is head_dim ** -0.5); attention without rotation; the
+    # gate's scoring ("sigmoid", or "softmax" over the chosen logits); a
+    # shared SwiGLU of its own width beside the experts of every layer; the
+    # Mamba-2 mixer's sizes (models/mamba2.py), all 0 elsewhere.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
+    use_rope: bool = True
+    gate_scoring: str = "sigmoid"
+    shared_intermediate_size: int = 0
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 0
 
     @property
     def is_moe(self) -> bool:
@@ -112,6 +131,8 @@ class ModelConfig:
         model_type = cfg.get("model_type")
         if model_type in LATENT_MODEL_TYPES:
             return cls._from_latent(cfg, name)
+        if model_type == "granitemoehybrid":
+            return cls._from_granite_hybrid(cfg, name)
         if model_type in HYBRID_MODEL_TYPES:
             return cls._from_hybrid(cfg, name)
         if model_type is not None and model_type not in LLAMA_MODEL_TYPES:
@@ -270,6 +291,73 @@ class ModelConfig:
             gate_norm_eps=1e-6,
             layer_types=layer_types,
             conv_L_cache=cfg.get("conv_L_cache", 3),
+            qk_norm=True,
+        )
+
+    @classmethod
+    def _from_granite_hybrid(cls, cfg: Dict[str, Any], name: str) -> "ModelConfig":
+        """``granitemoehybrid``'s keys (docs/granite_hybrid.md).
+        ``num_local_experts`` counts the experts held here; a file cut to one
+        chip's share states the router's width beside it
+        (``num_local_experts_published``) with ``ep_size``/``ep_rank``."""
+        L = cfg["num_hidden_layers"]
+        layer_types = tuple(cfg["layer_types"])
+        if len(layer_types) != L or set(layer_types) - {"mamba", "attention"}:
+            raise ValueError(f"layer_types must name {L} layers, each 'mamba' or 'attention'")
+        for key, want in (("mamba_n_groups", 1), ("mamba_proj_bias", False),
+                          ("mamba_conv_bias", True), ("attention_bias", False),
+                          ("position_embedding_type", "nope"), ("hidden_act", "silu"),
+                          ("normalization_function", "rmsnorm")):
+            if cfg.get(key, want) != want:
+                raise ValueError(f"{key} {cfg[key]!r} is not supported (the release has {want!r})")
+        Hm, P = cfg["mamba_n_heads"], cfg["mamba_d_head"]
+        if Hm * P != cfg.get("mamba_expand", 2) * cfg["hidden_size"]:
+            raise ValueError("mamba_n_heads x mamba_d_head is not mamba_expand x hidden_size")
+        held = cfg["num_local_experts"]
+        ep_size = cfg.get("ep_size", 1)
+        total = cfg.get("num_local_experts_published", held * ep_size)
+        ep_rank = cfg.get("ep_rank", 0)
+        if held * ep_size != total or not 0 <= ep_rank < ep_size:
+            raise ValueError(
+                f"num_local_experts {held} x ep_size {ep_size} (ep_rank {ep_rank}) is not the "
+                f"router's width {total}")
+        num_heads = cfg["num_attention_heads"]
+        eos = cfg.get("eos_token_id", ())
+        if isinstance(eos, int):
+            eos = (eos,)
+        return cls(
+            name=name or cfg.get("_name_or_path", "hf-model"),
+            model_type=cfg["model_type"],
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            num_layers=L,
+            num_heads=num_heads,
+            num_kv_heads=cfg.get("num_key_value_heads", num_heads),
+            head_dim=cfg.get("head_dim") or cfg["hidden_size"] // num_heads,
+            intermediate_size=cfg["intermediate_size"],
+            rope_theta=cfg.get("rope_theta", 10000.0),
+            rms_norm_eps=cfg.get("rms_norm_eps", 1e-5),
+            max_position=cfg.get("max_position_embeddings", 131072),
+            tie_word_embeddings=cfg.get("tie_word_embeddings", True),
+            num_experts=held,
+            num_experts_per_token=cfg["num_experts_per_tok"],
+            moe_intermediate_size=cfg["intermediate_size"],
+            eos_token_ids=tuple(eos),
+            router_experts=total,
+            ep_size=ep_size,
+            ep_rank=ep_rank,
+            layer_types=layer_types,
+            embedding_multiplier=float(cfg.get("embedding_multiplier", 1.0)),
+            residual_multiplier=float(cfg.get("residual_multiplier", 1.0)),
+            attention_multiplier=float(cfg["attention_multiplier"]),
+            logits_scaling=float(cfg.get("logits_scaling", 1.0)),
+            use_rope=False,
+            gate_scoring="softmax",
+            shared_intermediate_size=cfg.get("shared_intermediate_size", 0),
+            mamba_n_heads=Hm,
+            mamba_d_head=P,
+            mamba_d_state=cfg["mamba_d_state"],
+            mamba_d_conv=cfg["mamba_d_conv"],
         )
 
     @classmethod

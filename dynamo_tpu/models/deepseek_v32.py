@@ -131,12 +131,13 @@ _S0 = np.float32(0.02 / 73.0)  # uniform int8 has std ~73: N(0, 0.02)-like weigh
 
 
 def _draw(config: ModelConfig, key: jax.Array, quant: bool, shapes=None,
-          quant_axes=None, ones=None) -> Params:
+          quant_axes=None, ones=None, draws=None) -> Params:
     """Every leaf from ``key``, each straight into its stored type.  Traced
     under ONE jit (``init_params*``): no eager temporaries, so the 1.2 GB
     expert leaves and the 5.6 GB whole fit beside the pages.  ``shapes``,
     ``quant_axes``, ``ones``: another family's layout in this one's form
-    (models/lfm2.py); this family's own by default."""
+    (models/lfm2.py); this family's own by default.  ``draws``: leaves with a
+    draw of their own, name -> (key, shape, dtype) -> leaf."""
     dt = jnp.dtype(config.dtype)
     shapes = leaf_shapes(config) if shapes is None else shapes
     axes = QUANT_AXES if quant_axes is None else quant_axes
@@ -150,6 +151,8 @@ def _draw(config: ModelConfig, key: jax.Array, quant: bool, shapes=None,
             n += 1
             if name in ones:
                 dst[name] = jnp.ones(shape, dt)
+            elif draws and name in draws:
+                dst[name] = draws[name](k, shape, dt)
             elif name == "idx_k_norm_b":
                 dst[name] = jnp.zeros(shape, dt)
             elif name == "router_bias":
@@ -224,13 +227,17 @@ def held_experts(config: ModelConfig) -> range:
 
 
 def gate(x: jnp.ndarray, lp: Params, config: ModelConfig):
-    """(chosen ids [T, K], weights [T, K] f32) over ALL the router's experts:
-    sigmoid scores; the bias enters the choice only; the best ``topk_group``
+    """(chosen ids [T, K], weights [T, K] f32) over ALL the router's experts.
+    ``gate_scoring`` "softmax": the K largest logits and a softmax over them.
+    Else sigmoid scores; the bias enters the choice only; the best ``topk_group``
     of ``n_group`` groups by the sum of each group's two largest (one group:
     nothing to limit); top-K of what is left; weights normalised over the
     chosen and scaled."""
     T = x.shape[0]
     Et, G, K = config.router_experts, config.n_group, config.num_experts_per_token
+    if config.gate_scoring == "softmax":  # static: the K largest logits, softmax over them
+        w, chosen = jax.lax.top_k((x @ lp["router"]).astype(jnp.float32), K)
+        return chosen, jax.nn.softmax(w, axis=-1)
     s = jax.nn.sigmoid((x @ lp["router"]).astype(jnp.float32))  # [T, Et]
     biased = s + lp["router_bias"].astype(jnp.float32)
     if G > 1:

@@ -50,13 +50,19 @@ class ModelFamily(NamedTuple):
     # (config) -> the head width the attention kernels see, where it is not
     # ``config.head_dim`` (heads packed into one lane tile); None = head_dim.
     attn_lanes: Optional[Callable] = None
-    # The cache holds state by PAGE that a position inside the page cannot
-    # be resumed from: a prompt that is a whole number of cached blocks gives
-    # its last block back (engine/scheduler.py ``full_hit_recompute``).
-    state_per_page: bool = False
+    # Where a sequence of this family can be resumed, which is where a prefix
+    # hit may end (engine/scheduler.py ``_try_admit``): at every "token" (the
+    # K/V of a position is all a later one needs); at a "block"'s end (state
+    # held by PAGE: a prompt that is a whole number of cached blocks gives its
+    # last block back); or at a "snapshot" (state held by SLOT: a hit is cut
+    # back to the last sealed block that holds a snapshot of it).
+    resume: str = "token"
+    # (config, engine config) -> (live slots, snapshot slots) of a family whose
+    # state lives in slots beside the pages; None = it has no such state.
+    state_slots: Optional[Callable] = None
 
 
-def _llama() -> ModelFamily:
+def _llama(config: ModelConfig) -> ModelFamily:
     from . import llama, quant
 
     def forward(params, config, rb, cache, **kw):
@@ -104,7 +110,7 @@ def _llama() -> ModelFamily:
     )
 
 
-def _latent() -> ModelFamily:
+def _latent(config: ModelConfig) -> ModelFamily:
     """models/deepseek_v32.py: with the selector (``index_topk`` > 0) or
     without it, by the configuration."""
     import jax.numpy as jnp
@@ -177,17 +183,26 @@ def _unmovable_blocks(cfg: Any) -> list:
     return bad
 
 
-def _hybrid() -> ModelFamily:
+def _hybrid(config: ModelConfig) -> ModelFamily:
     """models/lfm2.py: gated short convolutions whose state lives in the page
-    cache beside the attention layers' K/V."""
+    cache beside the attention layers' K/V (resumed at a block's end), or
+    Mamba-2 layers whose state lives in slots (resumed at a snapshot)."""
     from ..llm.metrics import sparse_model_metrics
     from . import lfm2
 
+    slotted = lfm2.mamba_layers(config) > 0
+
     def kinds(config, cache):
-        """K/V bytes a token an attention layer, and the convolution state's
-        bytes a PAGE a convolution layer (it does not grow inside a page)."""
-        return {"kv": 2 * config.num_kv_heads * config.head_dim * cache.pages.dtype.itemsize,
-                "conv_page": cache.conv.shape[2] * cache.conv.shape[3] * cache.conv.dtype.itemsize}
+        """K/V bytes a token an attention layer; the convolution state's
+        bytes a PAGE a convolution layer (it does not grow inside a page); a
+        Mamba-2 layer's state and tail bytes a SLOT."""
+        out = {"kv": 2 * config.num_kv_heads * config.head_dim * cache.pages.dtype.itemsize}
+        if cache.conv is not None:
+            out["conv_page"] = cache.conv.shape[2] * cache.conv.shape[3] * cache.conv.dtype.itemsize
+        if cache.ssm is not None:
+            out["ssm_slot"] = cache.ssm[0, 0].size * cache.ssm.dtype.itemsize
+            out["conv_tail"] = cache.tail[0, :, 0].size * cache.tail.dtype.itemsize
+        return out
 
     def check(config: ModelConfig, cfg: Any) -> None:
         bad = []
@@ -195,6 +210,9 @@ def _hybrid() -> ModelFamily:
             bad.append("--tp/--dp/--ep/--sp > 1 (no PartitionSpecs for the state pages; the "
                        "configuration's ep_size/ep_rank say which experts this chip holds)")
         _refuse(config, cfg, bad + _unmovable_blocks(cfg))
+
+    def state_slots(config: ModelConfig, cfg: Any):
+        return cfg.max_batch, lfm2.snapshot_slots(cfg.num_blocks, cfg.block_size, cfg.prefill_chunk)
 
     return ModelFamily(
         name="hybrid",
@@ -210,12 +228,15 @@ def _hybrid() -> ModelFamily:
         forward_sp_prefill=None,
         cache_kinds=kinds,
         check=check,
-        count_dispatch=lambda config, kind, starts, ns, step_tokens=None: (
-            sparse_model_metrics.add_conv(kind, starts, ns)),
+        # The slots' account is the block manager's (admissions, snapshots).
+        count_dispatch=None if slotted else (
+            lambda config, kind, starts, ns, step_tokens=None: (
+                sparse_model_metrics.add_conv(kind, starts, ns))),
         count_aux=sparse_model_metrics.add_moe,
         counts=sparse_model_metrics.summary,
         attn_lanes=lfm2.attn_lanes,
-        state_per_page=True,
+        resume="snapshot" if slotted else "block",
+        state_slots=state_slots if slotted else None,
     )
 
 
@@ -225,7 +246,7 @@ _FAMILIES = {"llama": _llama, **{t: _latent for t in LATENT_MODEL_TYPES},
 
 def family_of(config: ModelConfig) -> ModelFamily:
     try:
-        return _FAMILIES[config.model_type]()
+        return _FAMILIES[config.model_type](config)
     except KeyError:
         raise ValueError(
             f"model_type {config.model_type!r} has no family; known: {sorted(_FAMILIES)}"

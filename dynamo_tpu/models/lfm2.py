@@ -5,6 +5,13 @@ or sigmoid-gated experts of which this chip holds ``num_experts``.
 docs/lfm2.md has the equations; models/reference/lfm2_moe.py is the plain
 float32 reference.
 
+``granitemoehybrid`` runs in the same loop with a THIRD mixer kind, the
+Mamba-2 scan of models/mamba2.py, whose state lives in slots beside the pages
+(``HybridCache.ssm`` / ``.tail``: docs/granite_hybrid.md), attention without
+rotation or QK-norm at the configuration's softmax scale, a softmax gate, a
+shared SwiGLU beside the experts of every layer and four scalar multipliers,
+each a static field of ``ModelConfig`` that adds no op where it is 1.
+
 Beside models/llama.py and models/deepseek_v32.py, sharing ``linear``,
 ``rms_norm``, ``mlp``, ``embed_lookup``, ``lm_logits``, the attention ops of
 the dense family, the latent family's ``gate`` and the dispatch of
@@ -41,6 +48,7 @@ import jax.numpy as jnp
 from ..ops.ragged_attention import ragged_attention, write_kv_ragged
 from ..ops.rope import apply_rope, rope_frequencies
 from . import deepseek_v32 as latent
+from . import mamba2
 from .config import ModelConfig
 from .llama import RaggedBatch, embed_lookup, linear, lm_logits, mlp, rms_norm
 from .moe import expert_dispatch
@@ -57,8 +65,10 @@ QUANT_AXES = {
     "dense": {"w_gate": 1, "w_up": 1, "w_down": 1},
     "moe": {"moe_gate": 2, "moe_up": 2, "moe_down": 2},
     "top": {"embed": 1, "lm_head": 0},
+    "mamba": mamba2.QUANT_AXES,
+    "shared": {"w_gate": 1, "w_up": 1, "w_down": 1},
 }
-_ONES = ("op_norm", "ffn_norm", "q_norm", "k_norm", "final_norm")
+_ONES = ("op_norm", "ffn_norm", "q_norm", "k_norm", "final_norm") + mamba2.ONES
 EXPERT_LEAVES = latent.EXPERT_LEAVES
 
 
@@ -80,11 +90,21 @@ def attn_lanes(config: ModelConfig) -> int:
     return config.head_dim * head_pack(config)
 
 
+def mamba_layers(config: ModelConfig) -> int:
+    return sum(t == "mamba" for t in config.layer_types)
+
+
 def layer_counts(config: ModelConfig) -> Tuple[int, int, int, int]:
     """(convolution, attention, dense, expert) layers."""
     Lc = sum(t == "conv" for t in config.layer_types)
     Ld = min(config.first_k_dense_replace, config.num_layers)
-    return Lc, config.num_layers - Lc, Ld, config.num_layers - Ld
+    return Lc, config.num_layers - Lc - mamba_layers(config), Ld, config.num_layers - Ld
+
+
+def snapshot_slots(num_pages: int, page_size: int, stride: int) -> int:
+    """The snapshot pool's ONE rule: a snapshot for every five resume strides
+    the pages can hold (docs/granite_hybrid.md)."""
+    return num_pages * page_size // (5 * stride)
 
 
 class HybridCache(NamedTuple):
@@ -92,21 +112,39 @@ class HybridCache(NamedTuple):
     layers' K/V in the dense family's layout (K rows even, V rows odd), the
     page dtype the engine was asked for.  ``conv`` [Lc, P, K, D]: the
     convolution layers' entry of each page, in the ACTIVATION dtype whatever
-    the K/V pages' (what a hit reads is then what a cold run computed)."""
+    the K/V pages' (what a hit reads is then what a cold run computed); None
+    without such layers.  ``ssm`` [Lm, slots, heads * d_head, d_state] FLOAT32
+    and ``tail`` [Lm, d_conv - 1, slots, channels] in the activation dtype:
+    the Mamba-2 layers' state by SLOT (models/mamba2.py), the first
+    ``max_batch`` slots the running rows', the others snapshots; None without
+    such layers (a None leaf is no operand of a program).  The shapes leave
+    the chip's compiler ONE layout for a pool: over [.., heads, d_head,
+    d_state] it chose another order than the parameter's for the prompt
+    program's matmuls and copied 5 GB into and out of every step, and over
+    [.., slots, 3, channels] it moved the slots inward (compile for a
+    described v5e, tests/test_tpu_compile.py)."""
 
     pages: jnp.ndarray
-    conv: jnp.ndarray
+    conv: Any
+    ssm: Any = None
+    tail: Any = None
 
     @classmethod
     def create(cls, config: ModelConfig, num_pages: int, page_size: int,
-               dtype=jnp.bfloat16) -> "HybridCache":
+               dtype=jnp.bfloat16, state_slots: int = 1) -> "HybridCache":
         Lc, La, _, _ = layer_counts(config)
+        Lm = mamba_layers(config)
         pack = head_pack(config)
+        act = jnp.dtype(config.dtype)
+        _, Hm, P, N, K = mamba2.dims(config)
         return cls(
             pages=jnp.zeros((La, num_pages, page_size, 2 * config.num_kv_heads // pack,
                              pack * config.head_dim), dtype),
             conv=jnp.zeros((Lc, num_pages, config.conv_L_cache - 1, config.hidden_size),
-                           jnp.dtype(config.dtype)),
+                           act) if Lc else None,
+            ssm=jnp.zeros((Lm, state_slots, Hm * P, N), jnp.float32) if Lm else None,
+            tail=jnp.zeros((Lm, K - 1, state_slots, mamba2.conv_width(config)),
+                           act) if Lm else None,
         )
 
 
@@ -120,23 +158,31 @@ def leaf_shapes(config: ModelConfig) -> Dict[str, Dict[str, tuple]]:
     top = {"embed": (V, D), "final_norm": (D,)}
     if not c.tie_word_embeddings:
         top["lm_head"] = (D, V)
-    return {
-        "top": top,
-        "layers": {"op_norm": (L, D), "ffn_norm": (L, D)},
+    groups = {"top": top, "layers": {"op_norm": (L, D), "ffn_norm": (L, D)}}
+    if Lc:
         # in_proj's columns: B, C, x (three parts of D); taps[k] multiplies u_{t-K+k}.
-        "conv": {"in_proj": (Lc, D, 3 * D), "taps": (Lc, c.conv_L_cache, D),
-                 "out_proj": (Lc, D, D)},
-        # wqkv's columns: q (H heads), k, v (KV heads each).
-        "attn": {"wqkv": (La, D, (H + 2 * KV) * hd), "q_norm": (La, hd), "k_norm": (La, hd),
-                 "wo": (La, H * hd, D)},
-        "dense": {"w_gate": (Ld, D, F), "w_up": (Ld, D, F), "w_down": (Ld, F, D)},
-        "moe": {"router": (Lm, D, Et), "router_bias": (Lm, Et),
-                "moe_gate": (Lm, E, D, Fm), "moe_up": (Lm, E, D, Fm), "moe_down": (Lm, E, Fm, D)},
-    }
+        groups["conv"] = {"in_proj": (Lc, D, 3 * D), "taps": (Lc, c.conv_L_cache, D),
+                          "out_proj": (Lc, D, D)}
+    # wqkv's columns: q (H heads), k, v (KV heads each).
+    norms = {"q_norm": (La, hd), "k_norm": (La, hd)} if c.qk_norm else {}
+    groups["attn"] = {"wqkv": (La, D, (H + 2 * KV) * hd), **norms, "wo": (La, H * hd, D)}
+    if Ld:
+        groups["dense"] = {"w_gate": (Ld, D, F), "w_up": (Ld, D, F), "w_down": (Ld, F, D)}
+    # The bias steers the sigmoid gate's choice; [a | b] = W_1 x is (moe_gate | moe_up).
+    bias = {} if c.gate_scoring == "softmax" else {"router_bias": (Lm, Et)}
+    groups["moe"] = {"router": (Lm, D, Et), **bias, "moe_gate": (Lm, E, D, Fm),
+                     "moe_up": (Lm, E, D, Fm), "moe_down": (Lm, E, Fm, D)}
+    if mamba_layers(c):
+        groups["mamba"] = mamba2.leaf_shapes(c, mamba_layers(c))
+    if c.shared_intermediate_size:
+        Fs = c.shared_intermediate_size
+        groups["shared"] = {"w_gate": (Lm, D, Fs), "w_up": (Lm, D, Fs), "w_down": (Lm, Fs, D)}
+    return groups
 
 
 def _draw(config: ModelConfig, key: jax.Array, quant: bool) -> Params:
-    return latent._draw(config, key, quant, leaf_shapes(config), QUANT_AXES, _ONES)
+    return latent._draw(config, key, quant, leaf_shapes(config), QUANT_AXES, _ONES,
+                        draws=mamba2.DRAWS)
 
 
 def init_params(config: ModelConfig, key: jax.Array) -> Params:
@@ -164,12 +210,28 @@ def _rounded(v: jnp.ndarray, dtype) -> jnp.ndarray:
 
 
 def moe_block(x, lp: Params, config: ModelConfig, real, layer):
-    """The routed experts chosen AND held (no shared expert): (y [T, D],
-    pairs of real tokens landed on each held expert [E])."""
+    """The routed experts chosen AND held (the shared expert is the layer
+    loop's): (y [T, D], pairs of real tokens landed on each held expert [E])."""
     chosen, w = latent.gate(x, lp, config)
     local = chosen - config.ep_rank * config.num_experts
     here = (local >= 0) & (local < config.num_experts) & real[:, None]
     return expert_dispatch(x, local, w, lp, config.num_experts, valid=here, layer=layer)
+
+
+def mamba_rows(rb: RaggedBatch, live_row, first) -> mamba2.Rows:
+    """The step's rows as the Mamba-2 layers read them.  Without
+    ``rb.state_slots`` (a caller that holds no slots: the calibration probe,
+    a test) row i lives in slot i and starts from zeros at position 0."""
+    (T,) = rb.token_ids.shape
+    S = rb.kv_lens.shape[0]
+    count = jnp.where(live_row, rb.cu_q_lens[1:] - rb.cu_q_lens[:-1], 0)
+    row_of = jnp.sum(jnp.arange(T)[:, None] >= rb.cu_q_lens[None, 1:], axis=1)  # S: padding
+    if rb.state_slots is None:
+        own = jnp.arange(S, dtype=jnp.int32)
+        read, write, snap = jnp.where(rb.positions[first] > 0, own, -1), own, own * 0 - 1
+    else:
+        read, write, snap = (rb.state_slots[:, i] for i in range(3))
+    return mamba2.Rows(first, count, rb.num_seqs[0], row_of, read, write, snap)
 
 
 def forward_ragged(
@@ -184,6 +246,7 @@ def forward_ragged(
     decode_kernel: str = "stock",
     prefill_kernel: str = "stock",
     drop_state_at_page_boundary: bool = False,  # chip_smoke.py's control, never the engine
+    drop_state_at_stride: int = 0,  # the same for the slots: rows starting on a multiple start from zeros
     **_other_families,  # mesh, lora_rank: family.py's check refuses what they stand for
 ) -> Tuple[jnp.ndarray, HybridCache, Any]:
     """The unified step of models/llama.py for this family: (logits [S, V] of
@@ -199,7 +262,8 @@ def forward_ragged(
     Lc, La, Ld, Lm = layer_counts(c)
     K = c.conv_L_cache - 1
     pack, G = head_pack(c), H // KV
-    inv_freq = rope_frequencies(hd, c.rope_theta, None)
+    inv_freq = rope_frequencies(hd, c.rope_theta, None) if c.use_rope else None
+    sm_scale = hd**-0.5 if c.attention_multiplier is None else c.attention_multiplier
     P_layer, ps = cache.pages.shape[1:3]
     pos = rb.positions
     real = rb.slot_mapping >= 0  # [T] padding tokens carry slot -1
@@ -211,24 +275,29 @@ def forward_ragged(
     live_row = (jnp.arange(S) < rb.num_seqs[0]) & (rb.cu_q_lens[1:] > rb.cu_q_lens[:-1])
     first = jnp.clip(rb.cu_q_lens[:-1], 0, T - 1)  # a row's first token
     t0 = pos[first]
-    has_tail = live_row & (t0 > 0)
-    if drop_state_at_page_boundary:
-        has_tail &= t0 % ps != 0
-    tail_page = rb.page_indices[jnp.arange(S), jnp.maximum(t0 - 1, 0) // ps]
-    # [Lc, S, K, D]: read before any layer writes (a row may write the page it reads).
-    tails = jnp.where(has_tail[None, :, None, None], cache.conv[:, tail_page], 0)
-    if decode:  # every row is one token, the last of its page so far
-        write_page = jnp.where(real, rb.slot_mapping // ps, P_layer)
-    else:
-        first_at = jnp.where(live_row, rb.cu_q_lens[:-1], T)  # out of range: dropped
-        is_first = jnp.zeros((T,), bool).at[first_at].set(True, mode="drop")
-        last_at = jnp.where(live_row, rb.cu_q_lens[1:] - 1, T)
-        ends_run = jnp.zeros((T,), bool).at[last_at].set(True, mode="drop")
-        writes = real & (ends_run | (pos % ps == ps - 1))
-        # A run of n tokens ends at most n // ps + 1 pages.
-        writers = jnp.nonzero(writes, size=min(T, T // ps + S), fill_value=T)[0]
-        write_page = jnp.where(writers < T, rb.slot_mapping[jnp.minimum(writers, T - 1)] // ps,
-                               P_layer)
+    if Lc:
+        has_tail = live_row & (t0 > 0)
+        if drop_state_at_page_boundary:
+            has_tail &= t0 % ps != 0
+        tail_page = rb.page_indices[jnp.arange(S), jnp.maximum(t0 - 1, 0) // ps]
+        # [Lc, S, K, D]: read before any layer writes (a row may write the page it reads).
+        tails = jnp.where(has_tail[None, :, None, None], cache.conv[:, tail_page], 0)
+        if decode:  # every row is one token, the last of its page so far
+            write_page = jnp.where(real, rb.slot_mapping // ps, P_layer)
+        else:
+            first_at = jnp.where(live_row, rb.cu_q_lens[:-1], T)  # out of range: dropped
+            is_first = jnp.zeros((T,), bool).at[first_at].set(True, mode="drop")
+            last_at = jnp.where(live_row, rb.cu_q_lens[1:] - 1, T)
+            ends_run = jnp.zeros((T,), bool).at[last_at].set(True, mode="drop")
+            writes = real & (ends_run | (pos % ps == ps - 1))
+            # A run of n tokens ends at most n // ps + 1 pages.
+            writers = jnp.nonzero(writes, size=min(T, T // ps + S), fill_value=T)[0]
+            write_page = jnp.where(writers < T, rb.slot_mapping[jnp.minimum(writers, T - 1)] // ps,
+                                   P_layer)
+    if cache.ssm is not None and not decode:
+        rows = mamba_rows(rb, live_row, first)
+        if drop_state_at_stride:
+            rows = rows._replace(read=jnp.where(t0 % drop_state_at_stride == 0, -1, rows.read))
 
     def short_conv(x, lp, tail):
         """h += W_out (C * conv(B * x)): returns (the mixer's output, the
@@ -258,9 +327,13 @@ def forward_ragged(
 
     def attention(x, lp, a, pages):
         q, k, v = jnp.split(linear(x, lp, "wqkv"), [H * hd, (H + KV) * hd], axis=-1)
-        q = rms_norm(q.reshape(T, H, hd), lp["q_norm"], eps)
-        k = rms_norm(k.reshape(T, KV, hd), lp["k_norm"], eps)
-        q, k = apply_rope(q, pos, inv_freq), apply_rope(k, pos, inv_freq)
+        if config.qk_norm:  # static
+            q = rms_norm(q.reshape(T, H, hd), lp["q_norm"], eps)
+            k = rms_norm(k.reshape(T, KV, hd), lp["k_norm"], eps)
+        else:
+            q, k = q.reshape(T, H, hd), k.reshape(T, KV, hd)
+        if inv_freq is not None:
+            q, k = apply_rope(q, pos, inv_freq), apply_rope(k, pos, inv_freq)
         if pack > 1:
             # KV head g lies in half g % pack of row g // pack; its G queries
             # carry zeros in the other halves.
@@ -277,7 +350,7 @@ def forward_ragged(
             q = (q.astype(jnp.float32) * s_a).astype(q.dtype)
         o = ragged_attention(
             q, pages, rb.kv_lens, rb.page_indices + a * P_layer, rb.cu_q_lens, rb.num_seqs,
-            sm_scale=hd**-0.5, impl=attn_impl, decode=decode, decode_kernel=decode_kernel,
+            sm_scale=sm_scale, impl=attn_impl, decode=decode, decode_kernel=decode_kernel,
             prefill_kernel=prefill_kernel, kv_scale=s_a if fused_dequant else None)
         if fold:
             o = (o.astype(jnp.float32) * s_a).astype(o.dtype)
@@ -309,13 +382,62 @@ def forward_ragged(
     def moe_layer(x, j):
         return moe_block(x, at_layer("moe", j), c, real, j)
 
+    def residual(h, y):
+        # Static: a model whose multiplier is 1 gets no op for it.
+        return h + y if c.residual_multiplier == 1.0 else h + c.residual_multiplier * y
+
+    def experts(h, l, pairs, read):
+        """``h += experts(norm_2(h))`` (+ the shared MLP where the model has
+        one), with the account of the pairs that landed."""
+        x = rms_norm(h, params["layers"]["ffn_norm"][l], eps)
+        y, load = moe_layer(x, jnp.int32(l - Ld))
+        if "shared" in params:
+            y = y + mlp(x, at_layer("shared", l - Ld))
+        return (residual(h, y), pairs + jnp.sum(load),
+                read + jnp.sum(load > 0, dtype=jnp.int32))
+
+    @jax.jit
+    def mamba_layer(l, m, h, ssm, tail, pairs, read):
+        """One Mamba-2 layer with its feed-forward, as a function of its
+        number ``l`` and of its place ``m`` among the Mamba-2 layers."""
+        x = rms_norm(h, params["layers"]["op_norm"][l], eps)
+        lp = at_layer("mamba", m)
+        if decode:
+            y, ssm, tail = mamba2.step(x, lp, c, ssm, tail, m, real)
+        else:
+            y, ssm, tail = mamba2.scan(x, lp, c, ssm, tail, m, rows)
+        h, pairs, read = experts(residual(h, y), l, pairs, read)
+        return h, ssm, tail, pairs, read
+
     h = embed_lookup(params, rb.token_ids, dt)
+    if c.embedding_multiplier != 1.0:
+        h = h * jnp.asarray(c.embedding_multiplier, dt)
     pages = cache.pages.reshape((La * P_layer,) + cache.pages.shape[2:])
+    ssm, tail = cache.ssm, cache.tail
     entries = []
     pairs = jnp.zeros((), jnp.int32)
     read = jnp.zeros((), jnp.int32)
-    ci = ai = 0
-    for l, kind in enumerate(c.layer_types):  # constant layer numbers: see models/llama.py on decode
+    ci = ai = mi = l = 0
+    while l < c.num_layers:  # constant layer numbers: see models/llama.py on decode
+        kind = c.layer_types[l]
+        if kind == "mamba":
+            # A run of Mamba-2 layers.  The decode program unrolls it (its
+            # weights stream: models/llama.py); a prompt program walks it as
+            # ONE loop over the layer's number, so its eight token buckets
+            # trace and compile one body a run and not one a layer.
+            run = 1
+            while l + run < c.num_layers and c.layer_types[l + run] == "mamba":
+                run += 1
+            carry = (h, ssm, tail, pairs, read)
+            if decode or run == 1:
+                for i in range(run):
+                    carry = mamba_layer(jnp.int32(l + i), jnp.int32(mi + i), *carry)
+            else:
+                carry = jax.lax.fori_loop(
+                    l, l + run, lambda i, cr, d=mi - l: mamba_layer(i, i + d, *cr), carry)
+            h, ssm, tail, pairs, read = carry
+            l, mi = l + run, mi + run
+            continue
         x = rms_norm(h, params["layers"]["op_norm"][l], eps)
         if kind == "conv":
             y, entry = conv_block(x, jnp.int32(ci), tails[ci])
@@ -324,21 +446,23 @@ def forward_ragged(
         else:
             y, pages = attn_block(x, jnp.int32(ai), pages)
             ai += 1
-        h = h + y
-        x = rms_norm(h, params["layers"]["ffn_norm"][l], eps)
+        h = residual(h, y)
         if l < Ld:
+            x = rms_norm(h, params["layers"]["ffn_norm"][l], eps)
             h = h + mlp(x, at_layer("dense", l))
         else:
-            y, load = moe_layer(x, jnp.int32(l - Ld))
-            h = h + y
-            pairs += jnp.sum(load)
-            read += jnp.sum(load > 0, dtype=jnp.int32)
+            h, pairs, read = experts(h, l, pairs, read)
+        l += 1
 
-    with jax.named_scope("short_conv"):
-        conv = cache.conv.at[:, write_page].set(jnp.stack(entries), mode="drop")
+    conv = cache.conv
+    if Lc:
+        with jax.named_scope("short_conv"):
+            conv = cache.conv.at[:, write_page].set(jnp.stack(entries), mode="drop")
     h = rms_norm(h, params["final_norm"], eps)
-    rows = jnp.clip(rb.cu_q_lens[1:] - 1, 0, T - 1)
-    logits = lm_logits(params, h[rows])
+    last = jnp.clip(rb.cu_q_lens[1:] - 1, 0, T - 1)
+    logits = lm_logits(params, h[last])
+    if c.logits_scaling != 1.0:
+        logits = logits / c.logits_scaling
     aux = jnp.stack([pairs, jnp.sum(real, dtype=jnp.int32) * Lm, read,
                      jnp.asarray(c.num_experts * Lm, jnp.int32)])
-    return logits, HybridCache(pages.reshape(cache.pages.shape), conv), aux
+    return logits, HybridCache(pages.reshape(cache.pages.shape), conv, ssm, tail), aux

@@ -143,6 +143,11 @@ class RaggedBatch(NamedTuple):
     # (-1 = base model).  None on LoRA-less engines — a None leaf vanishes
     # from the jit treedef, so existing programs are byte-identical.
     adapter_slots: Any = None  # [T] int32 | None
+    # State slots of a family whose recurrent state lives beside the pages
+    # (models/mamba2.py): per row (slot its state starts from or -1 for
+    # zeros, its live slot, a slot that gets a copy after the step or -1).
+    # None for every other family: no operand of their programs.
+    state_slots: Any = None  # [S, 3] int32 | None
 
 
 def _dtype(config: ModelConfig):

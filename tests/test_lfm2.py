@@ -340,7 +340,8 @@ def test_from_hf_config_reads_the_catalog_row_and_the_benchmarks_file():
     cache = jax.eval_shape(lambda: lfm2.HybridCache.create(cfg, 16384, 16, dtype=jnp.int8))
     assert cache.pages.shape == (6, 16384, 16, 8, 128) and cache.conv.shape == (18, 16384, 2, 2048)
     assert cache.conv.dtype == jnp.bfloat16
-    per_page = sum(a.size * a.dtype.itemsize for a in cache) // 16384
+    assert cache.ssm is None and cache.tail is None  # no Mamba-2 layers: no slot pools
+    per_page = sum(a.size * a.dtype.itemsize for a in cache if a is not None) // 16384
     assert per_page == 16 * 6144 + 147456 == 245760
 
 
@@ -403,7 +404,7 @@ def test_the_cache_is_two_page_arrays_under_one_table(engine):
     assert engine.block_nbytes() == 2 * ps * 64 * 4 + 3 * 2 * 64 * 4
     assert engine.device_summary()["cache_kinds"] == "kv:256,conv_page:512"
     assert "inject" not in engine.compile_counts()
-    assert engine.scheduler.full_hit_recompute == ps
+    assert engine.scheduler.resume == "block"
 
     async def main():
         with pytest.raises(ValueError, match="not K-plus-V pages"):

@@ -194,7 +194,7 @@ _DSV32_PAGES = 32768 * 16 * (640 + 128) * 2 * 6  # latent and indexer pages, six
 
 
 def _family_step(topo, config_file: str, pages_bytes: int, traced=None, prompt_tokens=None,
-                 **static_kw):
+                 state_slots: int = 0, **static_kw):
     """``compiled(decode)``: the whole step of a configuration file of the
     latent or the hybrid family for a described v5e, each program compiled
     once for the tests that share it (which turn the persistent cache off
@@ -203,7 +203,8 @@ def _family_step(topo, config_file: str, pages_bytes: int, traced=None, prompt_t
     ``static_kw`` and ``traced`` (name -> (shape, dtype)): the engine's options
     on the chip, where the family's defaults are not those.  ``prompt_tokens``:
     the prompt program's token bucket (the configuration's ``prefill_chunk``
-    unless given)."""
+    unless given).  ``state_slots``: the slots of a family whose state lives
+    in slots beside the pages (the prompt program then names a row's)."""
     import functools
     import json
     import os
@@ -230,13 +231,15 @@ def _family_step(topo, config_file: str, pages_bytes: int, traced=None, prompt_t
             lambda k: fam.init_params_quantized(mc, k), jax.random.PRNGKey(0)))
         cache = on_chip(jax.eval_shape(lambda: fam.create_cache(
             mc, serve["num_blocks"], serve["block_size"],
-            dtype=jnp.dtype(serve["kv_cache_dtype"]))))
+            dtype=jnp.dtype(serve["kv_cache_dtype"]),
+            **({"state_slots": state_slots} if state_slots else {}))))
         assert sum(a.size * a.dtype.itemsize
                    for a in jax.tree_util.tree_leaves(cache)) == pages_bytes
         S, PP = serve["max_batch"], serve["max_model_len"] // serve["block_size"]
         T = S if decode else prompt_tokens or serve["prefill_chunk"]
         i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)  # noqa: E731
-        rb = RaggedBatch(i32(T), i32(T), i32(T), i32(S), i32(S, PP), i32(S + 1), i32(1))
+        rb = RaggedBatch(i32(T), i32(T), i32(T), i32(S), i32(S, PP), i32(S + 1), i32(1),
+                         state_slots=i32(S, 3) if state_slots and not decode else None)
         traced_args = {k: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
                        for k, (shape, dtype) in (traced or {}).items()}
         with pytest.MonkeyPatch.context() as mp:
@@ -354,6 +357,90 @@ def test_lfm2_short_conv_metric_matches_the_scopes_ops_and_no_others(
         {"copy bf16[64,18,2,2048]", "fusion bf16[512,1,2048]", "fusion bf16[64,2,2048]",
          "reduce-precision_convert_fusion bf16[512,2048]"})
     assert want <= matched, matched
+
+
+_GRANITE_KV_PAGES = 16384 * 16 * 2 * 8 * 128  # int8 K/V of the one attention layer
+_GRANITE_SLOTS = 134  # 32 live + 102 snapshots (lfm2.snapshot_slots(16384, 16, 512))
+_GRANITE_STATE = _GRANITE_SLOTS * 9 * (128 * 64 * 128 * 4 + 3 * 8448 * 2)
+
+
+@pytest.fixture(scope="module")
+def granite_step(topo):
+    """chipbench/configs/granite-4.0-h-small-10l-ep2.json with the options the
+    engine resolves on a TPU and its 134 state slots."""
+    return _family_step(
+        topo, "chipbench/configs/granite-4.0-h-small-10l-ep2.json",
+        _GRANITE_KV_PAGES + _GRANITE_STATE, traced={"kv_scale": ((1,), jnp.float32)},
+        state_slots=_GRANITE_SLOTS, attn_impl="tpu", decode_kernel="pallas_fused",
+        prefill_kernel="pallas")
+
+
+@pytest.mark.parametrize("decode", [False, True], ids=["unified-512", "decode-32"])
+def test_granite_step_compiles_at_the_cells_shapes_without_copying_either_pool(
+    granite_step, no_persistent_cache, decode
+):
+    """chipbench/configs/granite-4.0-h-small-10l-ep2.json: 4.8 GB of weights
+    (10 layers, 36 of 72 experts, half the vocabulary), 16384 K/V pages of one
+    attention layer and 134 slots of 38.2 MB of scan state and tail, a
+    512-token chunk (or 32 decode rows).  The pages and both slot pools are
+    updated in place: the step's temporaries stay far under the 5.1 GB of the
+    state pool (a copy of it, or of one layer's slots, 0.57 GB, into or out of
+    a step would show).  Attention goes through the dense family's Pallas
+    kernels, once, and the experts through the grouped matmul."""
+    compiled = granite_step(decode)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _GRANITE_KV_PAGES + _GRANITE_STATE
+    assert mem.temp_size_in_bytes < 0.5e9, mem
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13.5e9, mem
+    calls = _custom_calls(compiled.as_text())
+    attn = "fused_decode_attention" if decode else "fused_prefill_attention"
+    assert len([ln for ln in calls if attn in ln]) == 1, calls
+    # decode: unrolled, two calls a layer; a prompt program: two runs of Mamba-2
+    # layers each ONE loop body, and the attention layer's own feed-forward
+    assert len([ln for ln in calls if "moe_grouped_matmul" in ln]) == (20 if decode else 6), calls
+    assert all(attn in ln or "moe_grouped_matmul" in ln for ln in calls), calls
+
+
+@pytest.mark.parametrize("decode,metric", [(False, "ssm_scan_time_share"),
+                                           (True, "ssm_step_time_share")],
+                         ids=["unified-512", "decode-32"])
+def test_granite_ssm_metrics_match_the_scopes_ops_and_no_others(
+    granite_step, no_persistent_cache, decode, metric
+):
+    """``ssm_scan_time_share`` / ``ssm_step_time_share`` match XLA's op names
+    (the harness keeps an op's name and shape, not its scope).  In the program
+    compiled for a described v5e every op the pattern matches lies under the
+    scope ``mamba2_scan`` / ``mamba2_step``, and the ops that move the state
+    are matched: another compiler or shape fails HERE, not as a metric that
+    reads 0.  A trace does not keep an op's program either, so the pattern
+    matches NOTHING in the other program: the two shares count no op twice."""
+    import json
+    import os
+    import re
+
+    from chipbench.trace_reduce import short_name
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, f"chipbench/layer_metrics/{metric}.json")) as f:
+        spec = json.load(f)
+    pattern, scope = re.compile(spec["args"]["pattern"]), "mamba2_step" if decode else "mamba2_scan"
+
+    def ops(program):
+        """(name as a trace has it, HLO line) of every op outside a fusion's body."""
+        fused = False
+        for ln in program.as_text().splitlines():
+            if ln.endswith("{") and " -> " in ln:  # a computation's head
+                fused = "fused" in ln.split(" ", 1)[0]
+            if not fused and " = " in ln:
+                yield short_name(ln.strip().removeprefix("ROOT ")), ln
+
+    matched = set()
+    for name, ln in ops(granite_step(decode)):
+        if pattern.search(name):
+            matched.add(name)
+            assert scope in ln, ln
+    assert set(spec["holds"]) <= matched, matched
+    assert not [ln for name, ln in ops(granite_step(not decode)) if pattern.search(name)]
 
 
 @pytest.mark.parametrize("decode", [False, True], ids=["unified-512", "decode-16"])

@@ -357,6 +357,9 @@ SNAPSHOT_EXEMPT = {
     "num_cached_prompt": "target-side admission metric",
     "num_sealed_blocks": "target-side sealing cursor",
     "pin_ids": "pre-admission pin never outlives the source scheduler",
+    "state_slot": "target-side live state slot (engine/kv_manager.py), taken at its own admission",
+    "state_start": "target-side admission state: where the row's next step reads its state from",
+    "snapshot_due": "a snapshot slot of the source's pool, attached within the step that took it",
     # Transient scheduler/engine flags that must NOT travel:
     "awaiting_fetch": "in-flight fetch is quiesced before freeze",
     "frozen": "migration-local flag",
