@@ -864,7 +864,7 @@ class TpuEngine(
         counts = {
             "step": self._step_fn._cache_size(),
             "multi": self._multi_fn._cache_size(),
-        }
+            "join": self._join_fn._cache_size()}  # (no line more: see make_cache)
         if self._inject_fn is not None:
             counts["inject"] = self._inject_fn._cache_size()
         return counts
@@ -1003,7 +1003,7 @@ class TpuEngine(
             )
             # Fetch: warmup must not return with compiles/executions still
             # queued (the first real request would absorb them).
-            np.asarray(last)
+            np.asarray(self._warm_join(out, last, steps_f, counts_f, rows))
         else:
             np.asarray(out.tokens)
         if self._sp_fn is not None:
@@ -1872,6 +1872,7 @@ class TpuEngine(
                 "continuous_retired": self.continuous_retired,
                 "first_harvest": dict(self.first_harvest),
                 "prompt_step": dict(self.prompt_step_order),
+                "joins": dict(self.pipeline_joins),
                 "wall_s": round(wall, 4),
                 "host_gap_frac": round(gap, 4),
                 # Stall-watchdog surface (DYN_DECODE_STALL_S): the health

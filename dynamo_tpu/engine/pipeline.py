@@ -9,6 +9,7 @@ engine-wide invariants (device lock, dispatch ordering, trace format).
 from __future__ import annotations
 
 import asyncio
+import functools
 import time
 import logging
 from typing import Any, Dict, List, Optional, Tuple
@@ -24,6 +25,7 @@ from collections import deque
 from ..llm.metrics import request_hop_metrics, tenancy_metrics
 from ..llm.protocols import FinishReason, LLMEngineOutput
 from ..ops.sampling import SamplingParams
+from .join import join_rows
 from .scheduler import RowSlots, SequenceState, StepPlan
 from ..models.llama import RaggedBatch
 
@@ -34,6 +36,39 @@ class DecodePipelineMixin:
     # Numpy fast path for per-chunk token acceptance (_accept_chunk); tests
     # flip this off to prove equivalence against the scalar loop.
     _vectorized_accept = True
+
+    # Made on first use, not in ``TpuEngine.__init__``: lines of engine.py at
+    # or above ``warmup`` are in the call stacks that key every Mosaic
+    # kernel's compile-cache entry (the note beside ``make_cache`` there).
+    @functools.cached_property
+    def _join_fn(self):
+        """The device-side join's program (engine/join.py), compiled in
+        ``warmup`` (``_warm_join``) and counted by ``compile_counts``."""
+        return jax.jit(join_rows)
+
+    @functools.cached_property
+    def pipeline_joins(self) -> Dict[str, int]:
+        """Rows that joined a fused chain, by how: ``device`` (behind their
+        prompt step, the chain unbroken) or ``break`` (the host merge)."""
+        return {"device": 0, "break": 0}
+
+    def _warm_join(self, out, last, steps_f, counts_f, rows):
+        """Warm-up of the device-side join, in the order the loop runs it: the
+        join behind a step's tokens and a chunk's carry, then a chunk fed by
+        the join's carry (committed arrays of another program).  No row joins
+        (``src`` -1) and ``rows`` are inactive: nothing is written.  Returns
+        the last chunk's token carry for ``warmup`` to fetch."""
+        S = self.cfg.max_batch
+        nobody = self._prep((np.full((S,), -1, np.int32), np.zeros((S,), np.int32)))
+        # Behind a merge the chain's state is the HOST's seed (no chunk went
+        # out yet): host arrays in the carry's place, another cache entry.
+        seed = self._prep((np.zeros((S,), np.int32), np.zeros((S,), np.int32)))
+        for tok, steps in (seed, (last, steps_f)):
+            tok, steps = self._join_fn(tok, steps, out.tokens, *nobody)
+        _, last, _, _, self.cache = self._multi_fn(
+            self.params, self.cache, tok, steps, counts_f, *rows
+        )
+        return last
 
     def _start_d2h(self, out, need_lp: bool) -> None:
         """Start the sampled-output device→host copies for a dispatched
@@ -250,7 +285,9 @@ class DecodePipelineMixin:
                     seq.snapshot_due = (h, snap)
         return read, seq.state_slot, snap
 
-    async def _run_unified(self, plan: StepPlan) -> None:
+    async def _run_unified(self, plan: StepPlan):
+        """One unified step of ``plan``'s rows; returns its sampled output,
+        still on the device (row ``i`` is ``plan.items[i]``'s)."""
         with self._phase("prompt_build"):
             rb = self._build_ragged(plan.items)
             samp = self._sampling_arrays([s for s, _, _ in plan.items])
@@ -371,6 +408,7 @@ class DecodePipelineMixin:
                 self._stash_fetch(
                     "first", out, need_lp, pending_rows, first_rows=first_rows
                 )
+        return out
 
     async def _pace(self) -> None:
         """Await the injectable test pace hook (engine.py pace_hook)
@@ -535,6 +573,10 @@ class DecodePipelineMixin:
         if kind == "first":
             for seq, i in entry[2]:
                 seq.awaiting_fetch = False
+                # A row that joined the chain on the device rides chunks in
+                # flight: if this token ends it, its blocks go back past the
+                # session's write barrier (sweep_retire), not here.
+                riding, seq.riding_chain = seq.riding_chain, False
                 if seq.finished:
                     continue  # cancelled while the token was in flight
                 if seq.t_first_token == 0.0:
@@ -548,6 +590,7 @@ class DecodePipelineMixin:
                 self._accept_token(
                     seq,
                     int(sampled[i]),
+                    defer_removal=riding,
                     logprobs=self._lp_info(seq, i, logp, top_ids, top_lp),
                 )
         else:  # "spec": speculative verification (engine/spec.py)
@@ -567,8 +610,12 @@ class DecodePipelineMixin:
         - **In-loop admission**: compatible waiting sequences are admitted
           into free row slots mid-session; their prompts prefill through
           ordinary unified steps INTERLEAVED between fused chunks (the
-          fused cadence never stops), and once the first token lands they
-          join the chain at the next chain-break merge — a drain of
+          fused cadence never stops), and they join the chain ON THE
+          DEVICE, right behind the step that carries their last prompt
+          chunk (``join_on_device``: the step's sampled token goes into the
+          carry of the chunk enqueued next; no drain, no host sync).  What
+          that cannot carry joins once its first token has landed, at the
+          next chain-break merge — a drain of
           in-flight chunks only, never an exit to the scheduler.  Rows the
           scheduler admitted before the session began, still in their
           prompts, are hosted the same way (``rejoin_strays``).
@@ -591,8 +638,9 @@ class DecodePipelineMixin:
         speculation-session flip.  Everything else is absorbed in-loop.
 
         Exactness: samples depend only on (seed, rng-step, committed
-        prefix), and a chain-break merge re-seeds the device carry with
-        exactly the values it already holds — so a request under churn
+        prefix), a chain-break merge re-seeds the device carry with
+        exactly the values it already holds, and a device-side join hands
+        it the token and the rng-step a merge would — so a request under churn
         gets the stream it gets when served alone, at any temperature
         (tests/test_continuous_batching.py gates it, spec on/off).
 
@@ -638,6 +686,36 @@ class DecodePipelineMixin:
         ready: List[SequenceState] = list(members)
         rebuild = False
         dispatched_any = False
+        # What a device-side join owes, and to whom.  A chain break did
+        # more than make the joiner wait: its drain gave the prompt steps an
+        # iteration without a chunk, and the re-seeded chain's first two
+        # chunks went out back to back, the next prompt step behind them.  A
+        # join that breaks nothing does neither, and on a busy device a
+        # closed loop pays either way (a client's cycle is TTFT + decode
+        # time and the device's work a request is fixed: PERF.md section 6,
+        # PR 46).  So a join owes ONE slot of the device (``owed``, at most
+        # two), to the side that is short of it, by the prompt work queued
+        # (``prompt_steps_queued``): more than a step of it and the slot is
+        # the prompt steps' (``yield_slot``: an iteration that enqueues a
+        # step and no chunk); else the chain's (``chain_turn``: the next
+        # prompt step waits one chunk; a turn not taken at once lapses).
+        # With more steps queued than rows ride the chain the prompt steps
+        # bound the whole load, and the row takes the break path: a joiner
+        # keeps chunks of two or three rows going that a break would not
+        # have run.  ``joined_now``: this iteration joined.
+        owed = 0
+        joined_now = False
+
+        def prompt_steps_queued(beyond: int = 0) -> float:
+            """Prompt work queued, in steps of the prefill budget, ``beyond``
+            tokens from now: what is left of the rows in prefill, and a
+            step for each request still waiting."""
+            left = sum(
+                len(seq.prompt) - seq.num_computed
+                for seq in prefilling
+                if seq.in_prefill and not (seq.finished or seq.frozen)
+            )
+            return self.scheduler.num_waiting + (left - beyond) / cfg.prefill_chunk
 
         def merge_ready() -> None:
             """Chain-break merge: assign slots to joining sequences and
@@ -648,6 +726,7 @@ class DecodePipelineMixin:
             nonlocal samp, samp_np, need_lp, carry
             for seq in ready:
                 slots.assign(seq)
+            self.pipeline_joins["break"] += len(ready)
             ready.clear()
             for i, seq in slots.active():
                 all_toks = seq.prompt + seq.output
@@ -667,6 +746,132 @@ class DecodePipelineMixin:
             )
             need_lp = bool(samp.need_logprobs)
             carry = None  # next dispatch re-seeds (tok, steps, counts)
+
+        def join_on_device(out, items) -> None:
+            """Device-side join, behind the prompt step just enqueued (``out``
+            its sampled output, ``items`` its rows): the rows whose LAST
+            prompt chunk rode it take their slots NOW, and the step's tokens
+            move into the chain's carry on the device (engine/join.py), ahead
+            of the next chunk.  No drain, no ``carry = None``, no host sync.
+            The first token still goes home through the step's own fetch,
+            and ``riding_chain`` holds every accept of a chunk behind it.
+
+            Who joins here is decided by what the loop observes: a chain to
+            join (seeded at least: before a session's first merge there is
+            none), no break already on its way (``ready`` rows take the
+            joiners with them), a truly free slot (a pending one may still be
+            written by a chunk in flight), no penalty (the ``[S, V]`` counts
+            row would have to be built on the device), no publisher
+            (followers replay ``unified`` and ``multi`` alone), and no more
+            prompt steps queued than rows ride the chain (a load that the
+            prompt steps bound does better by the break).  Everyone else
+            joins at a chain break."""
+            nonlocal samp, need_lp, carry, owed, joined_now
+            if (
+                samp is None or ready or rebuild
+                or self._publisher is not None
+                or prompt_steps_queued() > slots.num_active
+            ):
+                return
+            step_row: Dict[int, int] = {}
+            for row, (seq, _, _) in enumerate(items):
+                if (
+                    seq.awaiting_fetch  # its LAST prompt chunk rode the step
+                    and not seq.in_prefill
+                    and not (seq.finished or seq.frozen)
+                    and seq.freq_penalty == 0
+                    and seq.pres_penalty == 0
+                    # (A family's state slot IS the row, and it is free.)
+                    and (seq.state_slot >= 0 or slots.num_free > len(step_row))
+                ):
+                    step_row[id(seq)] = row
+            if not step_row:
+                return
+            with self._phase("merge"):
+                for seq, _, _ in items:
+                    if id(seq) in step_row:
+                        prefilling.remove(seq)
+                        slots.assign(seq)  # released by sweep_retire, like any member's
+                        seq.riding_chain = True
+                src = np.full((S,), -1, np.int32)
+                first_steps = np.zeros((S,), np.int32)
+                for i, seq in slots.active():
+                    if id(seq) in step_row:
+                        pos_disp[i] = seq.num_computed  # its prompt: the token feeds there
+                        src[i] = step_row[id(seq)]
+                        first_steps[i] = seq.num_output_tokens + 1
+                # The joiners' sampling scalars, by the one mapping there is
+                # (no penalty among them: the cached zero counts, no upload),
+                # laid over the chain's into FRESH host arrays: the ones in
+                # ``samp`` were handed to the dispatches in flight.
+                # ``need_logprobs`` is an operand of the chunk, carried a
+                # dispatch (``inflight`` keeps each chunk's own): a join may
+                # flip it.
+                theirs = self._sampling_arrays(
+                    [seq if src[i] >= 0 else None for i, seq in enumerate(slots.rows)]
+                )
+                need_lp = need_lp or bool(theirs.need_logprobs)
+                samp = samp._replace(
+                    need_logprobs=np.asarray(need_lp),
+                    **{
+                        f: np.where(src >= 0, getattr(theirs, f), getattr(samp, f))
+                        for f in ("seeds", "temperature", "top_k", "top_p",
+                                  "freq_penalty", "pres_penalty")
+                        + (() if samp.adapter_slots is None else ("adapter_slots",))
+                    },
+                )
+                # No carry: a merge just seeded the chain and no chunk went out
+                # since (this step is the merge's own iteration's), so the
+                # host's seed IS the chain's state, and the join writes into
+                # that: a break does not beget a break.
+                tok, steps, counts = carry or (tok0.copy(), samp.steps, samp.counts)
+                tok, steps = self._join_fn(tok, steps, out.tokens, src, first_steps)
+                carry = (tok, steps, counts)
+                self.pipeline_joins["device"] += len(step_row)
+                owed, joined_now = min(owed + 1, 2), True
+
+        def chain_turn(due: bool) -> bool:
+            """Does this iteration's prompt step (``due``: there is one)
+            wait one chunk?  In the iteration after a device-side join, where
+            no more than a step of prompt work is queued behind it and a
+            chunk can go out in its place (a seeded chain with a row in it,
+            room in the window, no break on its way); a turn not taken then
+            lapses."""
+            nonlocal owed
+            if not owed or prompt_steps_queued(cfg.prefill_chunk) > 1:
+                return False  # (the slot is the prompts': yield_slot)
+            take = (
+                due and samp is not None
+                and not (ready or rebuild or inflight)
+                and slots.num_active > 0
+            )
+            owed = owed - 1 if take else 0
+            return take
+
+        def yield_slot(prompt_step: bool, in_flight_now: int) -> bool:
+            """Does this iteration's top-up go to the prompt steps?  After a
+            device-side join with more than a step of prompt work queued, in
+            an iteration that enqueued a prompt step (the device has that to
+            run) behind a chunk still in flight, and never the chunk a joined
+            row has been waiting for since its join (the window was full
+            then)."""
+            nonlocal owed
+            if not (owed and prompt_step and in_flight_now) or joined_now:
+                return False
+            if any(pos_disp[i] == seq.num_computed for i, seq in slots.active()):
+                return False
+            if prompt_steps_queued() <= 1:
+                return False
+            owed -= 1
+            return True
+
+        def riding(pos0: Optional[np.ndarray] = None) -> bool:
+            """Is a row in the chain (of the chunk dispatched at ``pos0``)
+            whose first token is still on its way home?"""
+            return any(
+                seq.riding_chain and (pos0 is None or pos0[i] >= 0)
+                for i, seq in slots.active()
+            )
 
         def sweep_retire() -> None:
             """Retire finished, client-cancelled and migration-frozen
@@ -799,10 +1004,6 @@ class DecodePipelineMixin:
                 chunk = self.scheduler.prompt_chunk(seq, budget)
                 items.append((seq, seq.num_computed, chunk))
                 budget -= chunk
-            if items:
-                self.prompt_step_order[
-                    "behind" if chunk_id > iter_chunk0 else "ahead"
-                ] += 1
             return items
 
         def promote_ready() -> None:
@@ -919,13 +1120,16 @@ class DecodePipelineMixin:
         while True:
             with self._phase("retire"):
                 iter_chunk0 = chunk_id
+                joined_now = False
                 sweep_retire()
                 flush_retired()
                 if not rebuild:
                     rejoin_strays()
                 if want_rebuild():
                     rebuild = True
-            if ready and not inflight and not rebuild:
+            if ready and not inflight and not rebuild and not riding():
+                # (A row riding the chain has no host token to re-seed from
+                # yet: its fetch lands first, the merge an iteration later.)
                 with self._phase("merge"):
                     merge_ready()
 
@@ -960,10 +1164,15 @@ class DecodePipelineMixin:
                 if not rebuild:
                     admit()
                     items = prompt_rows()
+                    if chain_turn(bool(items)):
+                        items = []
                 if items:
+                    self.prompt_step_order[
+                        "behind" if chunk_id > iter_chunk0 else "ahead"
+                    ] += 1
                     dispatched_any = progressed = True
             if items:
-                await self._run_unified(StepPlan(items))
+                join_on_device(await self._run_unified(StepPlan(items)), items)
             # First tokens that landed while the loop was busy apply here,
             # still ahead of the top-up: a row that is ``ready`` holds it.
             while self._pending_fetches and self._pending_fetches[0][1].done():
@@ -985,7 +1194,10 @@ class DecodePipelineMixin:
                 in_flight_now = len(inflight) + (
                     1 if fetch_task is not None else 0
                 )
-                pos0 = plan_top_up(in_flight_now, depth)
+                pos0 = (
+                    None if yield_slot(bool(items), in_flight_now)
+                    else plan_top_up(in_flight_now, depth)
+                )
             while pos0 is not None:
                 await dispatch_chunk(pos0)
                 with self._phase("schedule"):
@@ -1024,7 +1236,12 @@ class DecodePipelineMixin:
                                     fetch_task, "decode_wait", slots.num_active
                                 )
                             )
-                            break
+                            # A row that joined on the device rides this chunk
+                            # BEHIND its prompt step: that step's fetch is
+                            # complete too, and its token is applied before
+                            # the chunk's (never the other way round).
+                            if not (self._pending_fetches and riding(pos0_c)):
+                                break
                     await self._harvest_pending(at="landed")
                 with self._phase("emit"):
                     wait_wall = time.perf_counter() - wait_t0
@@ -1045,7 +1262,7 @@ class DecodePipelineMixin:
                     )
                     harvested = cid
                     if not rebuild and self._spec_session_probe(
-                        [s for _, s in slots.active()]
+                        [s for _, s in slots.active() if not s.riding_chain]
                     ):
                         # Output grew repetitive enough that in-step
                         # speculation now beats the fused chunks: drain and
@@ -1073,6 +1290,11 @@ class DecodePipelineMixin:
                 # side and the other coroutines do with the thread.
                 await asyncio.sleep(0)
 
+        # A row that joined on the device and never rode an accepted chunk
+        # (a drain came first) takes its first token before the session ends:
+        # from here the scheduler owns it, as a decode row.
+        while self._pending_fetches and riding():
+            await self._harvest_pending(at="landed")
         with self._phase("retire"):
             # Drained: every dispatched chunk was harvested, so every write
             # barrier has passed — release whatever retirement is pending.
@@ -1140,9 +1362,8 @@ class DecodePipelineMixin:
         for i, seq in enumerate(members):
             if seq is None:
                 continue  # free/retired row slot (continuous pipeline)
-            seq.awaiting_fetch = False
             if seq.finished or pos0[i] < 0:
-                continue
+                continue  # (pos0 < 0: the row joined behind this chunk)
             p0 = int(pos0[i])
             if seq.num_computed != p0:
                 continue  # stopped/hit the allocation wall in a prior chunk

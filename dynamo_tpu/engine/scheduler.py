@@ -71,6 +71,11 @@ class SequenceState:
     # deferred first-token fetch): the scheduler must not plan the row
     # until the engine harvests it (engine.py _harvest_pending).
     awaiting_fetch: bool = False
+    # The row joined a fused chain ON THE DEVICE, behind its last prompt
+    # chunk, and its first token (``awaiting_fetch``) has not been applied:
+    # it rides chunks in flight, so no chunk is accepted and no block of its
+    # is freed before that token (pipeline.py ``join_on_device``).
+    riding_chain: bool = False
     # Live-migration freeze (engine/migrate.py): the sequence keeps its KV
     # blocks and queue but is never planned, never a preemption victim, and
     # blocks no one — the brief final-delta window of a migration, ended by
@@ -514,6 +519,12 @@ class RowSlots:
     @property
     def capacity_left(self) -> int:
         return len(self._free) + len(self._pending)
+
+    @property
+    def num_free(self) -> int:
+        """Rows assignable NOW, with chunks in flight (a device-side join):
+        pending ones wait for their write barrier."""
+        return len(self._free)
 
 
 @dataclass

@@ -654,19 +654,23 @@ class EngineDispatchMetrics:
         # The first token's path through a session's iteration (engine/
         # pipeline.py _decode_pipeline; docs/decode_pipeline.md).  OUTSIDE
         # the _dispatch ns, like the stall counter below.
-        for name, label, help_ in (
-            ("first_harvest", "at",
+        for series, name, label, help_ in (
+            ("pipeline_first_harvest", "first_harvest", "at",
              "First-token fetches applied when they landed (during the "
              "wait for a fused chunk) or at an iteration's harvest point"),
-            ("prompt_step", "order",
+            ("pipeline_prompt_step", "prompt_step", "order",
              "In-session prompt steps enqueued ahead of or behind a fused "
              "chunk of the same iteration"),
+            ("engine_joins", "joins", "how",
+             "Rows that joined a fused decode chain: on the device, behind "
+             "their last prompt chunk with the chain unbroken, or at a "
+             "chain-break merge from host state"),
         ):
-            lines.append(f"# HELP {prefix}_pipeline_{name}_total {help_}")
-            lines.append(f"# TYPE {prefix}_pipeline_{name}_total counter")
+            lines.append(f"# HELP {prefix}_{series}_total {help_}")
+            lines.append(f"# TYPE {prefix}_{series}_total counter")
             for value, n in (pipe.get(name) or {}).items():
                 lines.append(
-                    f'{prefix}_pipeline_{name}_total'
+                    f'{prefix}_{series}_total'
                     f'{{{label}="{escape_label(value)}"}} {n}'
                 )
         # Decode-stall watchdog (decode_stall_s / DYN_DECODE_STALL_S;
@@ -1036,8 +1040,10 @@ class RequestHopMetrics:
         s[self.FIRST_FETCH_HARVEST] += t_first_token - t_fetch_done
         for i in range(self.QUEUE_WAIT, self.FIRST_FETCH_HARVEST + 1):
             c[i] += 1
-        if t_join >= t_first_token:  # 0.0: never rode a fused dispatch
-            s[self.JOIN_WAIT] += t_join - t_first_token
+        if t_join > 0.0:  # 0.0: never rode a fused dispatch
+            # A row that joined on the device rides its first chunk BEFORE
+            # its first token is home: it waited for no join.
+            s[self.JOIN_WAIT] += max(0.0, t_join - t_first_token)
             c[self.JOIN_WAIT] += 1
         return True
 

@@ -534,6 +534,22 @@ def _record_enqueues(engine):
     return log
 
 
+def _record_joins(engine, log, then=None):
+    """``J`` in ``log`` for every call of the device-side join's program
+    (made on first use: a ``cached_property`` of the mixin), ``then()`` before
+    the call goes out."""
+    join = engine._join_fn
+
+    def joined(*a):
+        log.append("J")
+        if then is not None:
+            then()
+        return join(*a)
+
+    joined._cache_size = join._cache_size
+    engine.__dict__["_join_fn"] = joined
+
+
 def test_prompt_step_is_enqueued_ahead_of_the_top_up_chunk():
     """ISSUE 29 (a).  An iteration with an admitted prompt AND room in the
     fused window enqueues the prompt step first (device queue ``C_k, P_k,
@@ -655,6 +671,384 @@ def test_first_token_is_applied_when_it_lands_not_an_iteration_later():
     assert 'dynamo_tpu_pipeline_first_harvest_total{at="iteration"} ' in text
     assert 'dynamo_tpu_pipeline_prompt_step_total{order="ahead"} ' in text
     assert 'dynamo_tpu_pipeline_prompt_step_total{order="behind"} 0' in text
+
+
+# ------------------------------------------------ the device-side join (ISSUE 46)
+
+
+def _join_req(i, how=None, n=12, osl=9, temperature=0.0, logprobs=None):
+    """Request ``i`` as a newcomer to a live session.  ``how`` makes its FIRST
+    token end it (``max1``; ``stop``: a tuple of stop token ids) or gives it
+    a penalty (``penalty``: the break path's case)."""
+    stops = how if isinstance(how, tuple) else ()
+    return PreprocessedRequest(
+        token_ids=_prompt(i, n),
+        stop_conditions=StopConditions(
+            max_tokens=1 if how == "max1" else osl, ignore_eos=True,
+            stop_token_ids=list(stops),
+        ),
+        sampling_options=SamplingOptions(
+            temperature=temperature, seed=i + 1, logprobs=logprobs,
+            frequency_penalty=0.6 if how == "penalty" else None,
+        ),
+    ).to_dict()
+
+
+def _stream_of(items):
+    """What a client can tell apart: the tokens and each token's numbers."""
+    return [
+        (it["token_ids"], (it.get("logprobs") or {}).get("logprob")) for it in items
+    ]
+
+
+async def _alone_then_joined(engine, reqs, on_join=None, rows=1):
+    """``reqs`` one at a time on the idle engine (each its own session's
+    FIRST member: a host merge), then all at once beside a long row's live
+    session in lockstep (``rows``: that many long rows decode in it).
+    Returns (alone, joined, joins while alone, joins of the shared session,
+    prompt steps of the shared session, long row's two streams)."""
+    compiled = await asyncio.to_thread(engine.warmup)
+    # The join's program, behind a chunk's carry and behind a merge's host
+    # seed: both made in warm-up (the count is the process's: two a row
+    # count that some engine of it has warmed).
+    assert compiled["join"] >= 2 and compiled["join"] % 2 == 0, compiled
+    _lockstep(engine)
+    long_req = _req(_prompt(1), max_tokens=96, seed=2, temperature=0.7)
+    long_alone = await collect(await engine.generate(Context(long_req)))
+    alone = []
+    for r in reqs:
+        await _session(engine, live=False)
+        alone.append(await collect(await engine.generate(Context(r))))
+    await _session(engine, live=False)
+    joins_alone = dict(engine.pipeline_joins)
+    steps0 = sum(engine.prompt_step_order.values())
+    long_row = asyncio.create_task(collect(await engine.generate(Context(long_req))))
+    await _session(engine, live=True)
+    more = [
+        asyncio.create_task(collect(await engine.generate(Context(
+            _req(_prompt(4 + k), max_tokens=96, seed=9 + k, temperature=0.7)))))
+        for k in range(rows - 1)
+    ]
+    for _ in range(4000 if more else 0):
+        running = engine.scheduler.running
+        if len(running) == rows and all(q.num_output_tokens > 4 for q in running):
+            break
+        await asyncio.sleep(0.002)
+    # Park the loop at its next device op until every newcomer is queued: one
+    # admit() then takes them all, and their prompts share one step.
+    gate = asyncio.Event()
+    engine.pace_hook = gate.wait
+    ctxs = [Context(r) for r in reqs]
+    streams = [await engine.generate(c) for c in ctxs]
+    for _ in range(4000):
+        if engine.scheduler.num_waiting == len(reqs):
+            break
+        await asyncio.sleep(0.002)
+    assert engine.scheduler.num_waiting == len(reqs)
+    if on_join is not None:
+        on_join(ctxs)
+    _lockstep(engine)
+    gate.set()
+    joined = await asyncio.gather(*[collect(s) for s in streams])
+    long_joined = await long_row
+    await asyncio.gather(*more)
+    joins = {k: v - joins_alone[k] for k, v in engine.pipeline_joins.items()}
+    steps = sum(engine.prompt_step_order.values()) - steps0
+    assert engine.compile_counts() == compiled, "a join compiled a program"
+    return alone, list(joined), joins_alone, joins, steps, (long_alone, long_joined)
+
+
+@pytest.mark.parametrize("logprobs", [None, 2], ids=["tokens", "logprobs"])
+@pytest.mark.parametrize("temperature", [0.0, 0.8], ids=["greedy", "seeded-temp08"])
+def test_device_join_gives_the_stream_the_row_gets_alone(temperature, logprobs):
+    """A row whose last prompt chunk is enqueued beside a live chain joins it
+    ON THE DEVICE (no chain break, no host merge) and gets, token for token
+    and number for number, what it gets served alone; so does the row it
+    joined.  Alone, its session starts with nothing in flight: the host
+    merge."""
+
+    async def main():
+        engine = TpuEngine(EngineConfig(**CFG))
+        try:
+            req = _join_req(2, temperature=temperature, logprobs=logprobs)
+            return await _alone_then_joined(engine, [req])
+        finally:
+            await engine.close()
+
+    alone, joined, joins_alone, joins, _, long_row = asyncio.run(main())
+    assert _stream_of(joined[0]) == _stream_of(alone[0])
+    assert sum(len(it["token_ids"]) for it in joined[0]) == 9
+    assert _stream_of(long_row[1]) == _stream_of(long_row[0])
+    # Alone: every session's first member came through the merge.
+    assert joins_alone == {"device": 0, "break": 2}, joins_alone
+    # Beside the long row (its own session start is the one break).
+    assert joins == {"device": 1, "break": 1}, joins
+
+
+def test_two_rows_whose_prompts_end_in_one_step_both_join_on_the_device():
+    async def main():
+        engine = TpuEngine(EngineConfig(**CFG))
+        try:
+            reqs = [_join_req(i, n=5, temperature=0.8) for i in (2, 3)]
+            return await _alone_then_joined(engine, reqs)
+        finally:
+            await engine.close()
+
+    alone, joined, _, joins, steps, _ = asyncio.run(main())
+    assert [_stream_of(j) for j in joined] == [_stream_of(a) for a in alone]
+    assert steps == 1, steps  # ONE prompt step carried both prompts' ends
+    assert joins == {"device": 2, "break": 1}, joins
+
+
+def test_penalty_row_takes_the_break_path_and_still_matches():
+    """What the device-side join cannot carry (a ``counts`` row built on the
+    host) it leaves to the chain-break merge, by the row's own options."""
+
+    async def main():
+        engine = TpuEngine(EngineConfig(**CFG))
+        try:
+            req = _join_req(2, how="penalty", temperature=0.8)
+            return await _alone_then_joined(engine, [req])
+        finally:
+            await engine.close()
+
+    alone, joined, _, joins, _, long_row = asyncio.run(main())
+    assert _stream_of(joined[0]) == _stream_of(alone[0])
+    assert _stream_of(long_row[1]) == _stream_of(long_row[0])
+    assert joins == {"device": 0, "break": 2}, joins
+
+
+@pytest.mark.parametrize("how", ["max1", "stop", "cancelled"])
+def test_row_ended_by_its_first_token_after_a_device_join(how):
+    """The first token of a device-joined row ends it (``max_tokens`` 1, a
+    stop token, a client gone) when the row already rides a chunk: it emits
+    no token of that chunk, and its blocks go back only past the write
+    barrier: every chunk enqueued while it was in the chain is accepted
+    first."""
+
+    async def main():
+        engine = TpuEngine(EngineConfig(**CFG))
+        try:
+            log = _record_enqueues(engine)
+            first = (await _gen(engine, 2, 12, 1, 0.0))[0]
+            stop = (first,) if how == "stop" else None
+            req = _join_req(2, how=stop or ("max1" if how == "max1" else None))
+            remove = engine.scheduler.remove
+            rid, ctxs = [], []
+
+            def removed(seq):
+                if rid and seq.request_id == rid[0]:
+                    log.append("freed" if seq.block_ids else "gone")
+                return remove(seq)
+
+            def leave():
+                if how == "cancelled" and ctxs:
+                    ctxs[0].stop_generating()
+
+            engine.scheduler.remove = removed
+            _record_joins(engine, log, leave)
+
+            def on_join(cs):
+                ctxs.extend(cs)
+                rid.append(cs[0].id)
+                del log[:]
+
+            out = await _alone_then_joined(engine, [req], on_join)
+            return out, "".join(x if len(x) == 1 else f"<{x}>" for x in log)
+        finally:
+            await engine.close()
+
+    (alone, joined, _, joins, _, long_row), log = asyncio.run(main())
+    assert joins["device"] == 1, (joins, log)
+    toks = [t for it in joined[0] for t in it["token_ids"]]
+    want = [t for it in alone[0] for t in it["token_ids"]]
+    # (A client that left may or may not have been sent its first token, by
+    # whether the token landed before the sweep saw the client gone.)
+    assert toks == want[: len(toks)] and len(toks) <= 1, (toks, want)
+    assert how == "cancelled" or toks == want
+    assert _stream_of(long_row[1]) == _stream_of(long_row[0])
+    # P J: the join goes out right behind the row's prompt step, and this
+    # iteration's top-up carries the row (where the window was full an accept
+    # comes first, and the sweep behind it may find the row ended already:
+    # then no chunk ever carries it)...
+    j = log.index("J")
+    assert log[j - 1] == "P" and "C" in log[j:], log
+    # ...and its blocks were freed once, after as many accepts as chunks had
+    # been enqueued up to that one: nothing that could write them in flight.
+    before, _, _ = log.partition("<freed>")
+    assert "<freed>" in log and "<gone>" not in log, log
+    rode_at_once = "|" not in log[j : log.index("C", j)]
+    ridden = log[:j].count("C") + rode_at_once
+    assert before.count("|") >= ridden, log
+
+
+def test_row_whose_prompt_ends_in_a_merges_own_iteration_joins_against_the_host_seed():
+    """Behind a merge no chunk has gone out yet: the chain's state is the
+    host's seed, and a row whose last prompt chunk rides that iteration's
+    step joins it there (the join's host-seeded variant, warmed too) instead
+    of breaking the chain the merge just seeded.  Two prompts into an idle
+    engine in lockstep: the short one's first token starts the session (the
+    merge), whose first iteration carries the long one's third and last
+    chunk."""
+    reqs = ((0, 5, 24), (1, 35, 8))
+
+    async def main():
+        engine = TpuEngine(EngineConfig(**CFG))
+        try:
+            compiled = await asyncio.to_thread(engine.warmup)
+            _lockstep(engine)
+            streams = await _all_at_once(engine, reqs, 0.9)
+            assert engine.compile_counts() == compiled
+            return streams, dict(engine.pipeline_joins), engine.pipeline_sessions
+        finally:
+            await engine.close()
+
+    streams, joins, sessions = asyncio.run(main())
+    assert list(streams) == _serial(reqs, 0.9)
+    assert sessions == 1 and joins == {"device": 1, "break": 1}, (sessions, joins)
+
+
+def test_row_with_prompt_work_queued_deep_behind_it_takes_the_break_path():
+    """With prompt work queued more than a step deep the prompt steps are
+    the bottleneck, and a break serves them better than a join (its drain
+    gives them iterations without a chunk; a joiner keeps chunks of a row or
+    two going): decided by what the loop sees behind the row's last chunk.
+    A short prompt and a seven-chunk prompt arrive together: the short one
+    finds 89 tokens queued behind its step and waits for its token and a
+    break; the long one finds nothing behind its last chunk and joins on the
+    device."""
+
+    async def main():
+        # (No prefix cache: the long prompt computes its chunks both times.)
+        engine = TpuEngine(EngineConfig(**dict(CFG, enable_prefix_caching=False)))
+        try:
+            log = _record_enqueues(engine)
+            _record_joins(engine, log)
+            reqs = [_join_req(2, n=5), _join_req(3, n=100)]
+            out = await _alone_then_joined(engine, reqs, lambda _: log.clear())
+            return out, "".join(log)
+        finally:
+            await engine.close()
+
+    (alone, joined, _, joins, _, long_row), log = asyncio.run(main())
+    assert [_stream_of(j) for j in joined] == [_stream_of(a) for a in alone]
+    assert _stream_of(long_row[1]) == _stream_of(long_row[0])
+    assert joins == {"device": 1, "break": 2} and log.count("J") == 1, (joins, log)
+    j = log.index("J")
+    assert log[j - 1 : j + 2] == "PJC" and "P" not in log[j:], log
+
+
+def test_device_join_yields_one_chunk_slot_to_the_prompt_steps_that_are_queued():
+    """With more than a step of prompt work queued behind a join (and no
+    more steps than rows in the chain: else the break path) the slot the
+    join owes is the prompt steps': ONE iteration that enqueues a step and no
+    chunk, as a break's drain did, and never the joined row's own first
+    chunk.  Three rows decode; a five-token prompt and one of 55 arrive
+    together, and the first step leaves 44 tokens of the long one queued."""
+
+    async def main():
+        cfg = dict(CFG, enable_prefix_caching=False, max_batch=8)
+        engine = TpuEngine(EngineConfig(**cfg))
+        try:
+            log = _record_enqueues(engine)
+            _record_joins(engine, log)
+            reqs = [_join_req(2, n=5), _join_req(3, n=55)]
+            out = await _alone_then_joined(engine, reqs, lambda _: log.clear(), rows=3)
+            return out, "".join(log)
+        finally:
+            await engine.close()
+
+    (alone, joined, _, joins, _, long_row), log = asyncio.run(main())
+    assert [_stream_of(j) for j in joined] == [_stream_of(a) for a in alone]
+    assert _stream_of(long_row[1]) == _stream_of(long_row[0])
+    # (The session's first member at a break; the two rows beside it and the
+    # two newcomers on the device.)
+    assert joins == {"device": 4, "break": 1} and log.count("J") == 2, (joins, log)
+    first, second = log.index("J"), log.rindex("J")
+    assert log[first - 1] == log[second - 1] == "P", log
+    between = log[first:second]
+    assert between.count("|P|") == 1, log  # the slot, given once...
+    # ...and not the chunk the first joiner was waiting for (the window was
+    # full when it joined): that one goes out first.
+    assert "C" in between[: between.index("|P|")], log
+
+
+def test_device_join_gives_the_chain_its_turn_where_no_prompt_backlog_waits():
+    """Without a prompt backlog the slot a join owes is the chain's: the
+    prompt step of the NEXT iteration waits one chunk (a re-seeded chain's
+    first two chunks went out back to back too), once.  A five-token prompt
+    and one of 21 arrive together: the first step takes the short one whole
+    and 11 tokens of the other, whose last ten then wait for a chunk."""
+
+    async def main():
+        engine = TpuEngine(EngineConfig(**dict(CFG, enable_prefix_caching=False)))
+        try:
+            log = _record_enqueues(engine)
+            _record_joins(engine, log)
+            reqs = [_join_req(2, n=5), _join_req(3, n=21)]
+            out = await _alone_then_joined(engine, reqs, lambda _: log.clear())
+            return out, "".join(log)
+        finally:
+            await engine.close()
+
+    (alone, joined, _, joins, steps, _), log = asyncio.run(main())
+    assert [_stream_of(j) for j in joined] == [_stream_of(a) for a in alone]
+    assert joins["device"] == 2 and steps == 2, (joins, steps, log)
+    first, second = log.index("J"), log.rindex("J")
+    between = log[first + 1 : second]
+    # One iteration with a chunk and no prompt step, then the step, its join
+    # and its chunk at once; nothing is withheld a second time.
+    assert "|C|P" in between and between.count("P") == 1, log
+    assert log[second - 1 : second + 2] == "PJC", log
+
+
+def test_chunk_that_carries_a_joined_row_is_never_accepted_before_its_first_token():
+    """The prompt step is ahead of the chunk on the device, so its fetch is
+    complete when the chunk's is; where the HOST sees them the other way
+    round (the first-token fetch held on its thread), the loop waits on it
+    and does not reorder."""
+    import threading
+
+    async def main():
+        engine = TpuEngine(EngineConfig(**CFG))
+        try:
+            release = threading.Event()
+            fetch_first, accept = engine._fetch_first, engine._accept_chunk
+            seen = []
+
+            def held_first(*a):
+                if seen == ["armed"]:
+                    seen.append("held")
+                    release.wait(timeout=60)
+                return fetch_first(*a)
+
+            def accept_chunk(members, pos0, *a):
+                riders = [
+                    s.request_id for i, s in enumerate(members)
+                    if s is not None and s.riding_chain and pos0[i] >= 0
+                ]
+                if "held" in seen:
+                    seen.append(("accept", riders, release.is_set()))
+                return accept(members, pos0, *a)
+
+            engine._fetch_first, engine._accept_chunk = held_first, accept_chunk
+
+            def on_join(_):
+                seen.append("armed")
+                # Long after the chunk behind the prompt step has come home.
+                threading.Timer(1.0, release.set).start()
+
+            out = await _alone_then_joined(engine, [_join_req(2)], on_join)
+            return out, seen
+        finally:
+            release.set()
+            await engine.close()
+
+    (alone, joined, _, joins, _, _), seen = asyncio.run(main())
+    assert _stream_of(joined[0]) == _stream_of(alone[0])
+    assert joins["device"] == 1 and "held" in seen, (joins, seen)
+    accepts = [e for e in seen if isinstance(e, tuple)]
+    assert accepts and not any(riders for _, riders, _ in accepts), seen
 
 
 def test_zero_new_compiles_in_loop_admission():

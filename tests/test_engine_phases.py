@@ -74,6 +74,14 @@ def traced(tmp_path_factory):
             # device op, stands for a device that takes its time.
             engine.pace_hook = lambda: asyncio.sleep(0.002)
             sessions = _record_sessions(engine)
+            join, join_calls = engine._join_fn, []
+
+            def join_fn(*a):
+                join_calls.append(1)
+                return join(*a)
+
+            join_fn._cache_size = join._cache_size
+            engine.__dict__["_join_fn"] = join_fn
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0
             opts.host_tracer_level = 1
@@ -83,6 +91,7 @@ def traced(tmp_path_factory):
             finally:
                 jax.profiler.stop_trace()
             return {"summary": engine.dispatch_summary(), "sessions": sessions,
+                    "join_calls": len(join_calls),
                     "text": engine_dispatch_metrics.render(),
                     "waited_s": engine.pipeline_waited_s, "wall_s": engine.pipeline_wall_s}
         finally:
@@ -135,6 +144,21 @@ def test_one_enqueue_and_one_jitted_call_a_fused_chunk(traced):
     # begins an iteration before its wait does, so it may outlast it)
     for call, phase in (("dispatch:decode", "enqueue:decode"), ("dispatch:unified", "enqueue:unified")):
         assert 0 < s["phases"]["calls"][call]["sum"] <= s["phases"]["loop"][phase]["sum"]
+
+
+def test_the_merge_phase_is_entered_once_a_join_and_the_counter_counts_each_kind(traced):
+    """Phase ``merge`` is the host's cost of a join of either kind: one pass
+    for each device-side join (slots, the row's sampling scalars, the small
+    program's enqueue) and one for each chain-break merge, each of which may
+    take several rows.  ``dynamo_tpu_engine_joins_total{how=}`` counts rows."""
+    joins = traced["summary"]["pipeline"]["joins"]
+    assert joins["device"] >= 1 and joins["break"] >= 1, joins
+    assert 1 <= traced["join_calls"] <= joins["device"]
+    merges = traced["summary"]["phases"]["loop"]["merge"]["count"]
+    assert traced["join_calls"] + 1 <= merges <= traced["join_calls"] + joins["break"]
+    for how, n in joins.items():
+        assert f'dynamo_tpu_engine_joins_total{{how="{how}"}} {n}' in traced["text"]
+    assert "# TYPE dynamo_tpu_engine_joins_total counter" in traced["text"]
 
 
 def test_host_gap_frac_is_made_from_the_account_and_counts_a_second_once(traced):

@@ -178,6 +178,55 @@ def test_fold_arithmetic_on_hand_written_stamps():
     assert m.counts[H.EDGE_PRE] == m.counts[H.EDGE_HANDOFF] == 1
 
 
+def test_a_device_joined_row_counts_a_join_wait_of_zero():
+    """Joined on the device, a row rides its first fused dispatch BEFORE its
+    first token is home (``t_join`` < ``t_first_token``): the hop's count
+    grows, its sum does not, and the TTFT hops are what they were."""
+    m = RequestHopMetrics()
+    assert m.fold_engine(1.0, 1.5, 1.75, 2.0, 2.5, 2.625, 2.125)
+    assert m.counts[H.JOIN_WAIT] == 1 and m.sums[H.JOIN_WAIT] == 0.0
+    assert m.fold_engine(1.0, 1.5, 1.75, 2.0, 2.5, 2.625, 3.0)  # a chain break
+    assert m.counts[H.JOIN_WAIT] == 2 and m.sums[H.JOIN_WAIT] == 0.375
+    assert m.sums[H.FIRST_FETCH_HARVEST] == 0.25 and m.counts[H.QUEUE_WAIT] == 2
+
+
+async def test_the_account_closes_when_rows_join_on_the_device(monkeypatch):
+    """A long answer keeps one chain alive while short prompts join it on the
+    device (ISSUE 46): each is folded complete with ``t_join`` ahead of
+    ``t_first_token``, ``join_wait`` counts every one of them and adds
+    nothing, and the eight TTFT hops still sum to ``server_ttft``."""
+    folds = []
+    fold = request_hop_metrics.fold_engine
+
+    def fold_engine(*stamps):
+        folds.append(stamps)
+        return fold(*stamps)
+
+    monkeypatch.setattr(request_hop_metrics, "fold_engine", fold_engine)
+    n = 0
+    async with Served() as s:
+        long_answer = asyncio.ensure_future(s.complete(0, max_tokens=400))
+        while not s.engine._pipeline_members:
+            await asyncio.sleep(0.002)
+        while not long_answer.done():
+            await s.complete(n + 1, max_tokens=6)
+            n += 1
+        await long_answer
+        joins = dict(s.engine.pipeline_joins)
+        text = await s.metrics()
+    early = [st for st in folds if 0.0 < st[6] < st[5]]
+    assert joins["device"] >= 1 and len(early) == joins["device"], (joins, len(early), n)
+    sums = _series(text, "dynamo_tpu_request_hop_seconds_sum")
+    counts = _series(text, "dynamo_tpu_request_hop_seconds_count")
+    assert all(c == n + 1 for c in counts.values()), counts
+    late = sum(st[6] - st[5] for st in folds if st[6] >= st[5])
+    assert sums["join_wait"] == pytest.approx(late, abs=1e-9)
+    parts = sum(sums[H.HOPS[i]] for i in TTFT_HOPS)
+    assert abs(parts - sums["server_ttft"]) < 1e-6 * (n + 1)
+    assert _series(text, "dynamo_tpu_request_hop_incomplete_total") == {
+        "engine": 0.0, "edge": 0.0}
+
+
 @pytest.mark.parametrize("stamps", [
     (1.0, 1.5, 1.75, 2.0, 2.5, 0.0, 0.0),    # ended before its first token
     (1.0, 0.0, 0.0, 0.0, 0.0, 0.0, 0.0),     # cancelled in the queue

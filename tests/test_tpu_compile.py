@@ -737,3 +737,25 @@ def test_the_dense_prefill_kernel_compiles_for_a_step_of_two_token_blocks(
         sds((H, 512, 128), bf16), sds((H, 512, 128), bf16),
         sds((S,), i32), sds((S, PP), i32), sds((S + 1,), i32), sds((1,), i32))
     assert any("mla_dense_prefill_attention" in ln for ln in _custom_calls(text))
+
+
+def test_the_device_side_join_compiles_and_neither_copies_counts_nor_aliases(
+    sds, no_persistent_cache
+):
+    """``engine/join.py`` at ``max_batch`` 32: five ``[S]`` operands and two
+    ``[S]`` results.  The ``[S, V]`` penalty counts are no operand of it (a row
+    with a penalty takes the chain-break merge), so a join moves 4 bytes a
+    row; and nothing is donated: the prompt step's tokens are on their way to
+    the host, the carry may feed a chunk in flight."""
+    from dynamo_tpu.engine.join import join_rows
+
+    S = 32
+    ops = [sds((S,), jnp.int32)] * 5
+    compiled = jax.jit(join_rows).lower(*ops).compile()
+    text = compiled.as_text()
+    assert "input_output_alias" not in text, "the join donates an operand"
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes <= 5 * S * 4 * 8, mem  # tiles, not [S, V]
+    assert mem.alias_size_in_bytes == 0
+    shapes = jax.eval_shape(join_rows, *ops)
+    assert [(x.shape, x.dtype) for x in shapes] == [((S,), jnp.int32)] * 2

@@ -362,6 +362,7 @@ SNAPSHOT_EXEMPT = {
     "snapshot_due": "a snapshot slot of the source's pool, attached within the step that took it",
     # Transient scheduler/engine flags that must NOT travel:
     "awaiting_fetch": "in-flight fetch is quiesced before freeze",
+    "riding_chain": "set only while awaiting_fetch is: quiesced before freeze",
     "frozen": "migration-local flag",
     "finished": "finished sequences are not migrated",
     "enqueue_t": "per-queue latency bookkeeping",
@@ -528,7 +529,7 @@ DEVICE_LOCK_NAME = "_device_lock"
 # Functions sanctioned to dispatch without the lock: startup-only warmup
 # compilation runs before the serving loop exists (single task, no
 # concurrent dispatch possible).
-DEVICE_LOCK_EXEMPT_FUNCS = {"warmup"}
+DEVICE_LOCK_EXEMPT_FUNCS = {"warmup", "_warm_join"}
 # Functions whose CONTRACT is "caller holds _device_lock" (sync bodies run
 # via asyncio.to_thread under the caller's lock).  Their bodies check as
 # locked; every reference to them OUTSIDE the lock is itself a DYN502
