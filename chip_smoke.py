@@ -1861,6 +1861,402 @@ def child_parity_granite(rehearse: bool) -> None:
     print(json.dumps(dev), flush=True)
 
 
+# `--child parity-k-exaone`: chipbench/configs/k-exaone-236b-a23b-8l-ep8.json at
+# its published widths (two whole periods: six window layers and two full
+# ones) against the float32 reference (dynamo_tpu/models/reference/
+# exaone_moe.py: the window a mask over the full score matrix) on the same
+# dequantised weights, computed layer by layer with the attention in query
+# blocks.
+#
+# What runs.  (1) The ENGINE'S OWN programs decide every token: row A prefills
+# a 3072-token prompt COLD in 512-token chunks (`engine._step_fn`) through
+# both page pools, its window pages taken and given back by the engine's own
+# block manager; row B the same prompt behind a 2048-token PREFIX HIT (its K/V
+# table names A's first 128 pages, its window table the 8 window pages A held
+# before position 2048, as a retained entry hands them to a resumed row);
+# then both decode 64 tokens side by side in 16 fused chunks
+# (`engine._multi_fn`: a chunk's window table begins at the page its first
+# position's window reaches).  B's tokens and top-20 log-probabilities must
+# EQUAL A's (`hit_vs_cold`).  (2) The check's own jit of the same forward with
+# the engine's options, teacher-forced on A's tokens into fresh pages of both
+# pools, gives whole logits 8 tokens into and at the end of every chunk and at
+# every decode step; the engine's top-20 log-probabilities are read against
+# them (`engine_link`).  (3) Those logits against the reference at the same
+# positions, and the same for the LEADING LAYER alone (one window layer with
+# the dense MLP: `depth1_*`, where one position of 128 shows above W8A8's
+# noise).  Three controls, each of which must be over a limit: the window
+# pages before the hit DROPPED (zeros read by a row resumed at 2048: the
+# system's own programs, `dropped_*`); the window ONE POSITION SHORT (127: the
+# system's leading layer with `sliding_window` 127 against the reference's
+# 128, `short_depth1_*`, and the whole reference at 127 against itself at
+# 128, `short_ref_*`); the window layers attending to the WHOLE CONTEXT (the
+# reference without the mask against itself with it, `nowindow_ref_*`: the
+# system's window tables hold the window's pages only and cannot express it).
+# Limits and the readings they come from: PERF.md section 6.
+EXAONE = {"config": "chipbench/configs/k-exaone-236b-a23b-8l-ep8.json", "prefix": 2048,
+          "prompt": 3072, "decode": 64, "num_blocks": 1024, "q_block": 512}
+EXAONE_REHEARSAL = dict(EXAONE, prefix=128, prompt=192, decode=8, num_blocks=128, q_block=64)
+EXAONE_READINGS = ("rms_err", "rel_err", "rms_err_worst_position", "rms_err_past_hit")
+# Each limit lies between what the system read and what a control read on the
+# chip over seeds 28 / 29 / 30 (my chip run, PR 47, the final tree; PERF.md
+# section 6), near their geometric mean: system | control.
+EXAONE_LIMITS = {  # the most each may read
+    # 0.087-0.100 | the window one position short 0.148-0.158 (the whole
+    # reference at 127 against itself at 128), no window 1.29-1.32.  Without
+    # the V gain (int8 pages under ONE scale a layer, K normed and V not) the
+    # system read 0.540: a lower precision of the pages than the file states
+    # fails this one.
+    "rms_err": 0.12,
+    "rel_err": 0.30,  # 0.181-0.189 | no window 1.31-1.39: the largest single logit error
+    "rms_err_worst_position": 0.35,  # 0.193-0.222 | no window 1.34-1.36, dropped pages 1.31-1.33
+    "rms_err_past_hit": 0.25,  # 0.042-0.090 | 1.31-1.33: 8 past the hit, the pages before it dropped
+    "behind_hit_rms_err": 0.15,  # 0.085-0.099 | 0.233-0.257: every compared position behind the hit
+    # The leading layer alone (a window layer and the dense MLP): 0.0343-0.0347 |
+    # the SYSTEM with the window one position short 0.106-0.113.
+    "depth1_rms_err": 0.06,
+    "engine_link": 0.15,  # 0.054-0.078: the engine's own top-20 at chunk ends and decode steps
+    "hit_vs_cold": 0.0,  # the same programs over the same values: equal to the bit
+}
+
+
+def child_parity_k_exaone(rehearse: bool) -> None:
+    t0 = time.time()
+    dev = child_device(rehearse)
+    import types
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dynamo_tpu.engine.config import EngineConfig
+    from dynamo_tpu.engine.engine import TpuEngine
+    from dynamo_tpu.models import lfm2
+    from dynamo_tpu.models.config import ModelConfig, register_config
+    from dynamo_tpu.models.family import RaggedBatch
+    from dynamo_tpu.models.reference import exaone_moe as ref
+
+    par = EXAONE_REHEARSAL if rehearse else EXAONE
+    seed = int(os.environ.get("DSV32_PARITY_SEED", "28"))
+    with open(os.path.join(HERE, par["config"])) as f:
+        body = json.load(f)
+    serve = dict(body["serve"])
+    if rehearse:
+        hf = dict(body["rehearsal"]["model"])
+        serve.update(body["rehearsal"]["serve"])
+    else:
+        hf = {k: v for k, v in body.items() if k not in (
+            "name", "source", "serve", "chips", "reduced", "assumed", "stands_for",
+            "rehearsal", "notes")}
+    mc = register_config(ModelConfig.from_hf_config(hf, name="parity-k-exaone"))
+    kv_scale = serve.get("kv_scale", 1.0)
+    cfg = EngineConfig(
+        model=mc.name, block_size=serve["block_size"], num_blocks=par["num_blocks"],
+        max_batch=serve["max_batch"], max_model_len=serve["max_model_len"],
+        prefill_chunk=serve["prefill_chunk"], decode_steps=serve["decode_steps"],
+        dtype=serve["dtype"], cache_dtype=serve["kv_cache_dtype"],
+        kv_scale=kv_scale if kv_scale == "auto" else float(kv_scale),
+        weight_quant=serve.get("weight_quant"), seed=20260900 + seed)
+    engine = TpuEngine(cfg)
+    mc, fam, kv = engine.model_config, engine.family, engine.kv
+    W, wb = mc.sliding_window, kv.window_blocks
+    emit("k_exaone_engine", t0, **dev, attn_impl=engine.attn_impl,
+         decode_kernel=engine.decode_kernel, prefill_kernel=engine.prefill_kernel,
+         window_pool=[kv.window_pages, kv.window_tokens, kv.window_row_pages],
+         hbm=(jax.local_devices()[0].memory_stats() or {}).get("bytes_in_use"))
+
+    bs, S, PP, chunk = cfg.block_size, cfg.max_batch, cfg.max_blocks_per_seq, cfg.prefill_chunk
+    steps = cfg.decode_steps
+    n_prefix, n_prompt, n_dec = par["prefix"], par["prompt"], par["decode"]
+    assert n_prefix % chunk == 0 and n_prompt % chunk == 0 and n_dec % steps == 0
+    T = n_prompt + n_dec
+    own = -(-T // bs) + 1  # K/V pages a row needs
+    assert 4 * own <= cfg.num_blocks and own <= PP and T - n_prompt < W
+    rng = np.random.default_rng(seed)
+    tokens = np.zeros((T + 1,), np.int32)
+    tokens[:n_prompt] = rng.integers(16, mc.vocab_size, n_prompt)
+
+    def row(first, shared=0):
+        """A row as the engine's batch builder and block manager see one: its
+        K/V pages (the first ``shared`` of them row A's) and no window page yet."""
+        table = list(range(shared)) + [first + i for i in range(own - shared)]
+        return types.SimpleNamespace(prompt=[], output=[], block_ids=table, adapter_slot=-1,
+                                     window_ids=[], window_base=0, num_computed=0)
+
+    def prefill_batch(seq, start, n):
+        """The engine's own batch of one prompt row (window pages taken and
+        given back by ``Scheduler.window_span`` as for a running row)."""
+        seq.prompt, seq.num_computed = [int(t) for t in tokens[:start + n]], start
+        return engine._build_ragged([(seq, start, n)])
+
+    def resumed(first, zeros=False):
+        """A row resumed at ``n_prefix`` behind row A's K/V pages, with the
+        window pages A held before that point (``zeros``: with window pages
+        that hold nothing instead: the control)."""
+        seq = row(first, n_prefix // bs)
+        at = n_prefix // bs
+        seq.window_base = max(0, at - wb)
+        if zeros:
+            seq.window_ids = [kv.take_window_page() for _ in range(at - seq.window_base)]
+        else:
+            seq.window_ids = list(kept_pages)
+            for p in seq.window_ids:
+                kv._win_ref(p, rows=1)
+        return seq
+
+    samp = engine._sampling_arrays([])._replace(need_logprobs=np.asarray(True))
+    chunks = lambda a: [(s, chunk) for s in range(a, n_prompt, chunk)]
+
+    # ---- (1) the engine's own programs: A cold, B behind the hit, both decode
+    t1 = time.time()
+    params, cache = engine.params, engine.cache
+    top = {"A": {}, "B": {}}  # position -> (token, top ids, their log-probabilities)
+    row_a, kept_pages, pages_held = row(0), None, []
+    for a, n in chunks(0):
+        out, cache = engine._step_fn(params, cache, prefill_batch(row_a, a, n), samp)
+        pages_held.append(len(row_a.window_ids))
+        if a + n == n_prefix:  # what a retained entry keeps: the pages before the point
+            at = n_prefix // bs
+            kept_pages = row_a.window_ids[max(0, at - wb) - row_a.window_base:
+                                          at - row_a.window_base]
+            for p in kept_pages:
+                kv._win_ref(p, kept=1)
+        top["A"][a + n - 1] = (int(np.asarray(out.tokens)[0]), np.asarray(out.top_ids)[0],
+                               np.asarray(out.top_logprobs)[0])
+    row_b = resumed(own)
+    for a, n in chunks(n_prefix):
+        out, cache = engine._step_fn(params, cache, prefill_batch(row_b, a, n), samp)
+        top["B"][a + n - 1] = (int(np.asarray(out.tokens)[0]), np.asarray(out.top_ids)[0],
+                               np.asarray(out.top_logprobs)[0])
+    tokens[n_prompt] = top["A"][n_prompt - 1][0]
+    pos0 = np.full((S,), -1, np.int32)
+    tables, limits = np.zeros((S, PP), np.int32), np.zeros((S,), np.int32)
+    wtables = np.zeros((S, kv.window_row_pages), np.int32)
+    tok0 = np.zeros((S,), np.int32)
+    for i, (name, seq) in enumerate((("A", row_a), ("B", row_b))):
+        pos0[i], limits[i], tok0[i] = n_prompt, own * bs, top[name][n_prompt - 1][0]
+        tables[i, :own] = seq.block_ids
+    carry = (tok0, samp.steps, samp.counts)
+    for d in range(n_dec // steps):
+        at = pos0 + np.where(pos0 >= 0, d * steps, 0)
+        for i, seq in enumerate((row_a, row_b)):
+            seq.num_computed = int(at[i])
+            engine.scheduler.window_span(seq, int(at[i]) + steps)
+            engine._window_row(wtables, i, seq, int(at[i]))
+        pages_held.append(len(row_a.window_ids))
+        outs, last, steps_f, counts_f, cache = engine._multi_fn(
+            params, cache, *carry, at, (tables, wtables.copy()), limits, samp)
+        carry = (last, steps_f, counts_f)
+        toks, ids, lps = (np.asarray(x) for x in (outs.tokens, outs.top_ids, outs.top_logprobs))
+        for k in range(steps):
+            p = n_prompt + d * steps + k
+            tokens[p + 1] = toks[k, 0]
+            top["A"][p] = (int(toks[k, 0]), ids[k, 0], lps[k, 0])
+            top["B"][p] = (int(toks[k, 1]), ids[k, 1], lps[k, 1])
+    shared = sorted(set(top["A"]) & set(top["B"]))
+    hit_vs_cold = max(float(np.abs(top["A"][p][2] - top["B"][p][2]).max()) for p in shared)
+    hit_same = sum(int(top["A"][p][0] == top["B"][p][0]
+                       and np.array_equal(top["A"][p][1], top["B"][p][1])) for p in shared)
+    emit("k_exaone_engine_programs", t1, positions=len(shared), hit_same_tokens_and_top20=hit_same,
+         hit_vs_cold_nats=hit_vs_cold, window_pages_held_by_a_row=[min(pages_held), max(pages_held)])
+
+    # ---- (2) whole logits by the check's jit, teacher-forced on A's tokens
+    def forward_of(config, scale):
+        return jax.jit(
+            lambda p, c, rb, dec: fam.forward(
+                p, config, rb, c, decode=dec, attn_impl=engine.attn_impl, kv_scale=scale,
+                decode_kernel=engine.decode_kernel, prefill_kernel=engine.prefill_kernel)[:2],
+            static_argnums=3, donate_argnums=1)
+
+    def decode_batch(seq, p):
+        seq.num_computed = p
+        engine.scheduler.window_span(seq, p + 1)
+        t, ps_, kvl, wl = (np.zeros((S,), np.int32) for _ in range(4))
+        sl, wsl = np.full((S,), -1, np.int32), np.full((S,), -1, np.int32)
+        tb, wt = np.zeros((S, PP), np.int32), np.zeros((S, kv.window_row_pages), np.int32)
+        base = engine._window_row(wt, 0, seq, p)
+        t[0], ps_[0], kvl[0], wl[0] = tokens[p], p, p + 1, p + 1 - base * bs
+        tb[0, :own] = seq.block_ids
+        sl[0] = int(seq.block_ids[p // bs]) * bs + p % bs
+        wsl[0] = int(seq.window_ids[p // bs - seq.window_base]) * bs + p % bs
+        return RaggedBatch(t, ps_, sl, kvl, tb, np.arange(S + 1, dtype=np.int32),
+                           np.asarray([S], np.int32), window_indices=wt, window_lens=wl,
+                           window_slots=wsl)
+
+    # The check's own chunking: every 512-token chunk as its first 8 tokens and
+    # the rest, so that logits are read 8 tokens PAST each boundary, inside the
+    # window of what lies before it.
+    pieces = lambda a: [pc for s, n in chunks(a) for pc in ((s, 8), (s + 8, n - 8))]
+
+    def system(cache, seq, start, fwd, params):
+        """Logits [compared positions, V] of one pass of ``seq`` from ``start``."""
+        out = []
+        for a, n in pieces(start):
+            logits, cache = fwd(params, cache, prefill_batch(seq, a, n), False)
+            out.append(np.asarray(logits, np.float32)[0])
+        for p in range(n_prompt, T):
+            logits, cache = fwd(params, cache, decode_batch(seq, p), True)
+            out.append(np.asarray(logits, np.float32)[0])
+        kv.release_window(seq.window_ids)
+        return np.stack(out), cache
+
+    compare = np.asarray([a + n - 1 for a, n in pieces(0)] + list(range(n_prompt, T)))
+    behind = compare >= n_prefix  # what a row resumed at the hit computes
+    fwd = forward_of(mc, engine.kv_scale)
+    t1 = time.time()
+    sys_logits, cache = system(cache, row(2 * own), 0, fwd, params)
+    emit("k_exaone_system", t1, positions=len(compare))
+    t1 = time.time()
+    ctl_logits, cache = system(cache, resumed(3 * own, zeros=True), n_prefix, fwd, params)
+    emit("k_exaone_dropped_window_pages", t1)
+
+    # ---- the leading layer alone (a window layer and the dense MLP), as stated and
+    # with the window one position short
+    t1 = time.time()
+    depth1 = {}
+    for tag, window in (("", W), ("short_", W - 1)):
+        mc_1 = mc.with_overrides(num_layers=1, layer_types=mc.layer_types[:1],
+                                 sliding_window=window)
+        kept = {"layers": 1, "wattn": 1, "dense": 1}
+        params_1 = {g: {k: a[:kept[g]] for k, a in v.items()} if g in kept else v
+                    for g, v in params.items() if g not in ("attn", "moe", "shared")}
+        params_1["attn"] = {k: a[:0] for k, a in params["attn"].items()}
+        cache_1 = fam.create_cache(mc_1, cfg.num_blocks, bs, dtype=cache.pages.dtype,
+                                   window_pages=kv.window_pages)
+        # The leading layer is the first WINDOW layer: its scale and its gain.
+        La, n_attn = lfm2.layer_counts(mc)[1], len(mc.layer_types)
+        scale_1 = None if engine.kv_scale is None else np.asarray(engine.kv_scale)[[La, n_attn + La]]
+        depth1[tag], cache_1 = system(cache_1, row(2 * own), 0, forward_of(mc_1, scale_1), params_1)
+        del cache_1, params_1
+    emit("k_exaone_depth1", t1)
+
+    # ---- the engine leaves the chip; its weights stay on the host
+    host_params = jax.tree_util.tree_map(np.asarray, engine.params)
+    del cache, params
+    engine.params = engine.cache = None
+    engine = None
+
+    # ---- (3) the reference, layer by layer, on the dequantised weights
+    t1 = time.time()
+
+    def f32_leaf(group, name, i=None):
+        leaves = host_params if group == "top" else host_params[group]
+        w = leaves[name] if i is None else leaves[name][i]
+        w = jnp.asarray(w, jnp.float32)
+        if name + "_scale" in leaves:
+            sc = leaves[name + "_scale"] if i is None else leaves[name + "_scale"][i]
+            axis = lfm2.QUANT_AXES[group][name] - (0 if i is None else 1)
+            w = w * jnp.expand_dims(jnp.asarray(sc), axis)
+        return w
+
+    Ld = mc.first_k_dense_replace
+
+    def layer_f32(l):
+        kinds = mc.layer_types
+        group = "wattn" if kinds[l] == "sliding_attention" else "attn"
+        i = sum(k == kinds[l] for k in kinds[:l])
+        lp = {"dense_mlp": l < Ld}
+        mlp_groups = (("dense", l),) if l < Ld else (("moe", l - Ld), ("shared", l - Ld))
+        for g, at in (("layers", l), (group, i)) + mlp_groups:
+            for name in host_params[g]:
+                if not name.endswith("_scale"):
+                    lp[name] = f32_leaf(g, name, at)
+        return lp
+
+    with jax.default_matmul_precision("highest"):
+        embed, head = f32_leaf("top", "embed"), f32_leaf("top", "lm_head")
+        final_norm = jnp.asarray(host_params["final_norm"], jnp.float32)
+        pos = jnp.arange(T, dtype=jnp.int32)
+        held = ref.held_experts(hf)
+        logits_of = lambda h: np.asarray(
+            ref.rms_norm(h[compare], final_norm, hf.get("rms_norm_eps", 1e-5)) @ head)
+        ref_logits, ref_depth1 = {}, {}
+        for tag, window in (("", None), ("short_", W - 1), ("nowindow_", 0)):
+            h = embed[jnp.asarray(tokens[:T])]
+            for l, kind in enumerate(mc.layer_types):
+                h = ref.layer(layer_f32(l), hf, h, pos, kind, held, par["q_block"], window=window)
+                if l == 0:
+                    ref_depth1[tag] = logits_of(h)
+            ref_logits[tag] = logits_of(h)
+    emit("k_exaone_reference", t1)
+
+    def rel_err(a, b):
+        return float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
+
+    def rms_err(a, b):
+        return float(np.sqrt(np.mean((a - b) ** 2) / np.mean(b ** 2)))
+
+    def worst_position(a, b):
+        """The largest root-mean-square error of ONE position's logits."""
+        return float(np.max(np.sqrt(np.mean((a - b) ** 2, axis=-1) / np.mean(b ** 2, axis=-1))))
+
+    def log_softmax(x):
+        x = np.asarray(x, np.float64)
+        return x - x.max(-1, keepdims=True) - np.log(np.exp(x - x.max(-1, keepdims=True)).sum(
+            -1, keepdims=True))
+
+    want = ref_logits[""]
+    ref_max = float(np.max(np.abs(want)))
+    lp = log_softmax(sys_logits)
+    link = max(float(np.abs(np.asarray(top["A"][int(p)][2], np.float64)
+                            - lp[j][top["A"][int(p)][1]]).max())
+               for j, p in enumerate(compare) if int(p) in top["A"]) / ref_max
+
+    def readings(tag, got, want):
+        return {tag + "rms_err": rms_err(got, want), tag + "rel_err": rel_err(got, want),
+                tag + "rms_err_worst_position": worst_position(got, want)}
+
+    past = [j for j, p in enumerate(compare) if p == n_prefix + 7]  # 8 past the hit
+    n_pre = len(pieces(0))
+    out = {
+        **readings("", sys_logits, want), "engine_link": link,
+        "rms_err_past_hit": rms_err(sys_logits[past], want[past]),
+        "hit_vs_cold": hit_vs_cold / ref_max, "hit_same_tokens_and_top20": hit_same,
+        "hit_positions": len(shared),
+        "rms_err_prefill": rms_err(sys_logits[:n_pre], want[:n_pre]),
+        "rms_err_decode": rms_err(sys_logits[n_pre:], want[n_pre:]),
+        "argmax_agree": int((sys_logits.argmax(-1) == want.argmax(-1)).sum()),
+        # control 1: the window pages before the hit dropped (the system's programs)
+        **readings("dropped_", ctl_logits, want[behind]),
+        "dropped_rms_err_past_hit": rms_err(ctl_logits[:1], want[past]),
+        "behind_hit_rms_err": rms_err(sys_logits[behind], want[behind]),
+        # the leading layer alone, and control 2: its window one position short
+        **readings("depth1_", depth1[""], ref_depth1[""]),
+        **readings("short_depth1_", depth1["short_"], ref_depth1[""]),
+        **readings("short_ref_depth1_", ref_depth1["short_"], ref_depth1[""]),
+        **readings("short_ref_", ref_logits["short_"], want),
+        # control 3: the window layers attending to the whole context (reference side)
+        **readings("nowindow_ref_", ref_logits["nowindow_"], want),
+        **readings("nowindow_ref_depth1_", ref_depth1["nowindow_"], ref_depth1[""]),
+        "ref_max_abs_logit": ref_max, "positions": int(len(compare)), "context": int(T),
+        "seed": seed, "limits": EXAONE_LIMITS,
+    }
+    emit("k_exaone_parity", t0, **out)
+    if not rehearse:
+        over = [f"{n} {out[n]} against its limit {limit}"
+                for n, limit in EXAONE_LIMITS.items() if out[n] > limit]
+        if over:
+            fail("k-exaone: " + "; ".join(over))
+        if hit_same != len(shared):
+            fail(f"k-exaone: the hit's tokens or top-20 differ from the cold prefill's at "
+                 f"{len(shared) - hit_same} of {len(shared)} positions")
+        for control, names in EXAONE_CONTROLS.items():
+            if not any(out[got] > EXAONE_LIMITS[limit] for got, limit in names
+                       if limit in EXAONE_LIMITS):
+                fail(f"k-exaone: the control {control} passes every limit: too loose")
+    print(json.dumps(dev), flush=True)
+
+
+# Each control and the (its reading, the limit it must be over) pairs of which one suffices.
+EXAONE_CONTROLS = {
+    "window pages before the hit dropped": (
+        ("dropped_rms_err", "behind_hit_rms_err"), ("dropped_rms_err_past_hit", "rms_err_past_hit")),
+    "window one position short": (
+        ("short_depth1_rms_err", "depth1_rms_err"), ("short_ref_rms_err", "rms_err")),
+    "window layers attending to the whole context": (
+        ("nowindow_ref_rms_err", "rms_err"), ("nowindow_ref_depth1_rms_err", "depth1_rms_err")),
+}
+
+
 def _tp_engine(cfg: dict, tp: int, kv_scale, layers: int = 0):
     """The comparison's engine; ``layers`` > 0 cuts DEPTH only (widths stay
     the published ones) for the shallow, tightly-toleranced comparison."""
@@ -2122,6 +2518,7 @@ def main() -> None:
          "parity-kimi-k2": child_parity_kimi_k2,
          "parity-lfm2": child_parity_lfm2,
          "parity-granite": child_parity_granite,
+         "parity-k-exaone": child_parity_k_exaone,
          "tp1": lambda r: child_tp1(r, args.ref),
          "tp4": lambda r: child_tp4(r, args.ref)}[args.child](args.rehearse_cpu)
         return

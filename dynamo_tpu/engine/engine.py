@@ -156,10 +156,14 @@ class TpuEngine(
         # State slots of a family whose recurrent state lives beside the pages
         # (models/mamba2.py): live ones for the running rows, and snapshots.
         live, snaps = fam.state_slots(model_config, cfg) if fam.state_slots else (0, 0)
+        # ... and a family with window layers its second page pool.
+        win_pages, win_tokens, win_row = (
+            fam.window_pool(model_config, cfg) if fam.window_pool else (0, 0, 0))
         self.kv = KvBlockManager(
             cfg.num_blocks, cfg.block_size, event_callback=event_callback,
             enable_prefix_caching=cfg.enable_prefix_caching,
             live_slots=live, snapshot_slots=snaps,
+            window_pages=win_pages, window_tokens=win_tokens, window_row_pages=win_row,
         )
         self.scheduler = Scheduler(cfg, self.kv, resume=fam.resume)
         # Draft-free speculative decoding (engine/spec.py): None = off.
@@ -447,6 +451,7 @@ class TpuEngine(
         make_cache = partial(
             fam.create_cache, self.model_config, cfg.num_blocks, cfg.block_size,
             dtype=jnp.dtype(cfg.cache_dtype), **({"state_slots": live + snaps} if live else {}),
+            **({"window_pages": win_pages} if win_pages else {}),
         )
         if self.mesh is None:
             cache = make_cache()
@@ -540,6 +545,12 @@ class TpuEngine(
             cu = jnp.arange(S + 1, dtype=jnp.int32)
             num = jnp.full((1,), S, jnp.int32)
             active = pos0 >= 0
+            if win_pages:
+                # The chunk's window tables ride with the K/V tables: they begin
+                # at the block the window of the chunk's FIRST position reaches
+                # and cover every position the chunk writes (pipeline.py).
+                tables, wtab = tables
+                wbase = jnp.maximum(pos0 + 1 - win_tokens, 0) // bs
 
             def body(carry, _):
                 cache, tok, pos, steps, counts = carry
@@ -549,19 +560,27 @@ class TpuEngine(
                 )
                 writable = active & (posc < limits)
                 slot = jnp.where(writable, slot, -1)
+                kv_lens = jnp.where(active, jnp.minimum(pos + 1, limits), 0)
+                window = {} if not win_pages else dict(
+                    window_indices=wtab,
+                    window_lens=jnp.maximum(kv_lens - wbase * bs, 0),
+                    window_slots=jnp.where(
+                        writable, wtab[jnp.arange(S), posc // bs - wbase] * bs + posc % bs, -1),
+                )
                 rb = RaggedBatch(
                     token_ids=tok,
                     positions=posc,
                     slot_mapping=slot,
                     # Padding rows have no context (0): a decode kernel returns
                     # them zeros and the fused one does no work for them.
-                    kv_lens=jnp.where(active, jnp.minimum(pos + 1, limits), 0),
+                    kv_lens=kv_lens,
                     page_indices=tables,
                     cu_q_lens=cu,
                     num_seqs=num,
                     # Decode rows: one token per row, so the per-row slots
                     # (llm/tenancy multi-LoRA) are the per-token slots.
                     adapter_slots=samp.adapter_slots,
+                    **window,
                 )
                 logits, cache, aux = fam.forward(
                     params, model_config, rb, cache, attn_impl=attn_impl,
@@ -699,7 +718,9 @@ class TpuEngine(
         T = min(128, (cfg.max_blocks_per_seq - 1) * cfg.block_size)
         nb = (T + cfg.block_size - 1) // cfg.block_size + 1
         fam = self.family
-        probe = fam.create_cache(mc, nb, cfg.block_size, dtype=jnp.bfloat16)
+        windowed = self.kv.window_pages > 0  # its probe pages: the same nb, window and all
+        probe = fam.create_cache(mc, nb, cfg.block_size, dtype=jnp.bfloat16,
+                                 **({"window_pages": nb} if windowed else {}))
         if self.mesh is not None:
             probe = shard_tree(probe, fam.cache_pspec(), self.mesh)
         toks = ((np.arange(T) * 2654435761) % mc.vocab_size).astype(np.int32)
@@ -720,6 +741,8 @@ class TpuEngine(
             page_indices=tables,
             cu_q_lens=cu,
             num_seqs=np.asarray([1], np.int32),
+            **(dict(window_indices=tables, window_slots=pos,
+                    window_lens=np.asarray([T] + [0] * (S - 1), np.int32)) if windowed else {}),
         )
         _, probe = jax.jit(
             lambda p, c: fam.forward(
@@ -732,12 +755,23 @@ class TpuEngine(
                 jnp.abs(probe.pages.astype(jnp.float32)), axis=(1, 2, 3, 4)
             )
         )
+        if windowed:
+            # The window layers' scales follow the full layers'.  This family
+            # norms K a head and V not at all, so a layer's scale is K's and V
+            # is stored times a GAIN that brings it to K's size: the scales,
+            # then the gains (models/lfm2.py; K rows even, V rows odd).
+            both = jnp.concatenate([probe.pages, probe.window]).astype(jnp.float32)
+            k_max = np.asarray(jnp.max(jnp.abs(both[:, :, :, 0::2]), axis=(1, 2, 3, 4)))
+            v_max = np.asarray(jnp.max(jnp.abs(both[:, :, :, 1::2]), axis=(1, 2, 3, 4)))
+            gains = np.maximum(k_max, 1e-6) / np.maximum(v_max, 1e-6)
         dt = jnp.dtype(cfg.cache_dtype)
         if jnp.issubdtype(dt, jnp.integer):
             qmax = float(jnp.iinfo(dt).max)
         else:
             qmax = float(jnp.finfo(dt).max)  # e4m3 → 448
         scales = np.maximum(maxabs / qmax, 1e-6).astype(np.float32)
+        if windowed:
+            scales = np.concatenate([np.maximum(k_max / qmax, 1e-6), gains]).astype(np.float32)
         logger.info(
             "calibrated per-layer kv scales (dtype %s): min %.4g max %.4g",
             dt, scales.min(), scales.max(),
@@ -961,6 +995,7 @@ class TpuEngine(
                 adapter_slots=np.full((T,), -1, np.int32) if self._lora_rank else None,
                 # No state slot is read or written either (models/mamba2.py).
                 state_slots=np.full((S, 3), -1, np.int32) if self.kv.live_slots else None,
+                **self._warm_window(T),
             )
             steps.append((rb, samp))
         multi = None
@@ -970,11 +1005,23 @@ class TpuEngine(
                 samp.steps,
                 samp.counts,
                 np.full((S,), -1, np.int32),  # every row inactive
-                np.zeros((S, PP), np.int32),
+                # (the window tables beside the K/V tables where the family has them)
+                (np.zeros((S, PP), np.int32), self._warm_window(0)["window_indices"])
+                if self.kv.window_pages else np.zeros((S, PP), np.int32),
                 np.zeros((S,), np.int32),
                 samp,
             )
         return steps, multi
+
+    def _warm_window(self, T: int) -> Dict[str, np.ndarray]:
+        """The window side of a warm-up step of ``T`` tokens: the step's
+        three window fields, none for a family without window layers."""
+        S, WP = self.cfg.max_batch, self.kv.window_row_pages
+        if not self.kv.window_pages:
+            return {}
+        return dict(window_indices=np.zeros((S, WP), np.int32),
+                    window_lens=np.asarray([T] + [0] * (S - 1), np.int32),
+                    window_slots=np.full((T,), -1, np.int32))
 
     def warmup(self) -> Dict[str, int]:
         """Pre-compile every device program the serving loop can dispatch —

@@ -19,6 +19,19 @@ sealed block at whose end its copy of the state was taken.  A prefix is
 resumable where such a block is; a snapshot is freed with its block and
 dropped first, least recently used, when the pool is full: a block without
 its snapshot is not resumable, never wrong.
+
+A family with layers that keep a WINDOW of the last positions only
+(models/lfm2.py ``sliding_attention``; docs/k_exaone.md) gets a SECOND page
+pool under this manager: ``window_pages`` pages of which a running row holds
+the few its next query's window reaches (``take_window_page`` as it grows,
+``release_window`` as pages fall behind), whatever its length.  The pages
+before a resume point are RETAINED with that block's hash exactly as a
+snapshot is (``retain_window``: evictable least recently used, dropped with
+the block), and ``resumable`` cuts a hit back to the last block whose window
+pages are still whole.  Pages are shared by count: a retained page a row
+resumed from is both.  Admission counts both pools (``would_fit``): a running
+row may hold ``window_row_pages`` pages, everything else can be evicted, so a
+row that was admitted never finds the pool empty.
 """
 
 from __future__ import annotations
@@ -31,7 +44,7 @@ from ..llm.kv_router.protocols import (
     KvCacheEvent,
     KvCacheStoredBlockData,
 )
-from ..llm.metrics import ssm_metrics
+from ..llm.metrics import ssm_metrics, swa_metrics
 from ..tokens import TokenBlock
 
 
@@ -64,6 +77,9 @@ class KvBlockManager:
         enable_prefix_caching: bool = True,
         live_slots: int = 0,
         snapshot_slots: int = 0,
+        window_pages: int = 0,
+        window_tokens: int = 0,
+        window_row_pages: int = 0,
     ):
         # State slots (module docstring): live slots are ids [0, live_slots),
         # snapshot slots [live_slots, live_slots + snapshot_slots).
@@ -75,6 +91,19 @@ class KvBlockManager:
         # block id -> its snapshot's slot, least recently used first.
         self._snap_of: "OrderedDict[int, int]" = OrderedDict()
         self._snap_pins: Dict[int, int] = {}  # slot -> rows about to read it
+        # The window pool (module docstring): ``window_tokens`` positions a
+        # window layer keeps, so ``window_blocks`` pages before a resume point.
+        self.window_pages = window_pages
+        self.window_tokens = window_tokens
+        self.window_row_pages = window_row_pages
+        self.window_blocks = -(-(window_tokens - 1) // block_size) if window_pages else 0
+        self.window_rows = 0  # running rows: each may hold window_row_pages pages
+        self._win_free: List[int] = list(range(window_pages - 1, -1, -1))
+        self._win_rows = [0] * window_pages  # references held by rows
+        self._win_kept = [0] * window_pages  # references held by retained entries
+        self._win_live = self._win_retained = 0  # pages a row holds; pages only retained
+        # block id -> the window pages before its end, least recently used first.
+        self._win_of: "OrderedDict[int, Tuple[int, ...]]" = OrderedDict()
         self.num_blocks = num_blocks
         self.block_size = block_size
         self._blocks = [_Block(i) for i in range(num_blocks)]
@@ -129,23 +158,37 @@ class KvBlockManager:
         ssm_metrics.slots_in_use = {
             "live": self.live_slots - len(self._live_free), "snapshot": len(self._snap_of)}
 
-    def resumable(self, block_ids: Sequence[int], below: int) -> Tuple[int, int]:
-        """(n, slot): the longest run ``block_ids[:n]`` of a matched prefix
-        that ends at a block holding a snapshot and covers fewer than
-        ``below`` tokens (a prompt's last token is always computed), with the
-        snapshot's slot; (0, -1) where there is none.  The slot is PINNED
-        until ``unpin_snapshot``: the row that resumes from it reads it in its
-        first step, and until that step is enqueued ``reserve_snapshot`` hands
-        the slot to nobody."""
+    def resumable(self, block_ids: Sequence[int], below: int):
+        """(n, start): the longest run ``block_ids[:n]`` of a matched prefix
+        that ends at a block holding what a row needs to go on from there and
+        covers fewer than ``below`` tokens (a prompt's last token is always
+        computed).  ``start`` is a snapshot's slot, (0, -1) where there is
+        none; under a window pool the window pages before the block's end,
+        (0, ()) where none are whole.  Either is PINNED until
+        ``unpin_snapshot``: the slot is handed to nobody by
+        ``reserve_snapshot`` until the row's first step is enqueued, and the
+        pages are referenced for the row, which keeps them as its own."""
         for n in range(min(len(block_ids), (below - 1) // self.block_size), 0, -1):
-            slot = self._snap_of.get(block_ids[n - 1])
+            bid = block_ids[n - 1]
+            if self.window_pages:
+                pages = self._win_of.get(bid)
+                if pages is not None:
+                    self._win_of.move_to_end(bid)
+                    for p in pages:
+                        self._win_ref(p, rows=1)
+                    return n, pages
+                continue
+            slot = self._snap_of.get(bid)
             if slot is not None:
-                self._snap_of.move_to_end(block_ids[n - 1])
+                self._snap_of.move_to_end(bid)
                 self._snap_pins[slot] = self._snap_pins.get(slot, 0) + 1
                 return n, slot
-        return 0, -1
+        return (0, ()) if self.window_pages else (0, -1)
 
-    def unpin_snapshot(self, slot: int) -> None:
+    def unpin_snapshot(self, slot) -> None:
+        if isinstance(slot, tuple):  # window pages referenced by ``resumable``
+            self.release_window(slot)
+            return
         left = self._snap_pins.get(slot, 0) - 1
         if left > 0:
             self._snap_pins[slot] = left
@@ -186,7 +229,57 @@ class KvBlockManager:
             ssm_metrics.snapshots["taken"] += 1
         self._slot_gauges()
 
+    # ------------------------------------------------------------ window pool
+    def _win_ref(self, page: int, rows: int = 0, kept: int = 0) -> None:
+        """Add references to ``page``; one that loses its last goes back to
+        the pool.  Keeps the gauge's counts as it goes."""
+        r0, k0 = self._win_rows[page], self._win_kept[page]
+        r1, k1 = r0 + rows, k0 + kept
+        self._win_rows[page], self._win_kept[page] = r1, k1
+        self._win_live += (r1 > 0) - (r0 > 0)
+        self._win_retained += (r1 == 0 and k1 > 0) - (r0 == 0 and k0 > 0)
+        if r1 == 0 and k1 == 0:
+            self._win_free.append(page)
+        swa_metrics.pool_pages.update(
+            live=self._win_live, retained=self._win_retained, free=len(self._win_free))
+
+    def window_fits(self) -> bool:
+        """Room for one more running row in the window pool."""
+        return (self.window_rows + 1) * self.window_row_pages <= self.window_pages
+
+    def take_window_page(self) -> int:
+        """A page for a running row, dropping retained pages least recently
+        used while none is free.  An admitted row always finds one."""
+        while not self._win_free and self._win_of:
+            self._drop_window(next(iter(self._win_of)))
+        page = self._win_free.pop()
+        self._win_ref(page, rows=1)
+        return page
+
+    def release_window(self, pages: Sequence[int]) -> None:
+        for p in pages:
+            self._win_ref(p, rows=-1)
+
+    def retain_window(self, seq_hash: int, pages: Sequence[int]) -> None:
+        """``pages`` hold the window layers' K/V of the positions before the
+        end of the sealed block of ``seq_hash``: kept with that block, as a
+        snapshot is.  Nothing where the block is gone or has them already."""
+        bid = self._by_hash.get(seq_hash)
+        if bid is None or bid in self._win_of:
+            return
+        self._win_of[bid] = tuple(pages)
+        for p in pages:
+            self._win_ref(p, kept=1)
+
+    def has_window(self, seq_hash: int) -> bool:
+        return self._by_hash.get(seq_hash) in self._win_of
+
+    def _drop_window(self, block_id: int) -> None:
+        for p in self._win_of.pop(block_id, ()):
+            self._win_ref(p, kept=-1)
+
     def _drop_snapshot(self, block_id: int) -> None:
+        self._drop_window(block_id)
         slot = self._snap_of.pop(block_id, None)
         if slot is not None:
             self._snap_free.append(slot)
@@ -252,6 +345,8 @@ class KvBlockManager:
         # Matched blocks sitting in the reuse pool get revived and stop
         # counting as free, so subtract them from available capacity.
         revived = sum(1 for b in matched if self._blocks[b].ref_count == 0)
+        if self.window_pages and not self.window_fits():
+            return False  # both pools: a row that fits one and not the other waits
         return fresh_needed <= self.free_blocks - revived
 
     def allocate_sequence(
@@ -442,6 +537,6 @@ class KvBlockManager:
         self._free_anon = list(range(self.num_blocks))
         self._free_reusable.clear()
         self._by_hash.clear()
-        for bid in list(self._snap_of):
+        for bid in list(self._snap_of) + list(self._win_of):
             self._drop_snapshot(bid)
         self._emit(KvCacheEvent(self._next_event_id(), None))

@@ -22,7 +22,7 @@ logger = logging.getLogger(__name__)
 
 from collections import deque
 
-from ..llm.metrics import request_hop_metrics, tenancy_metrics
+from ..llm.metrics import request_hop_metrics, swa_metrics, tenancy_metrics
 from ..llm.protocols import FinishReason, LLMEngineOutput
 from ..ops.sampling import SamplingParams
 from .join import join_rows
@@ -209,6 +209,17 @@ class DecodePipelineMixin:
         ids = seq.block_ids[: out.shape[1]]
         out[i, : len(ids)] = ids
 
+    def _window_row(self, out: np.ndarray, i: int, seq: SequenceState, start: int) -> int:
+        """Row ``i`` of a window table for a step (or fused chunk) whose first
+        query is at position ``start``: the row's window pages from the block
+        that query's window reaches (engine/kv_manager.py); returns that
+        block.  The pages up to the step's last position are the row's already
+        (``Scheduler.window_span``)."""
+        base = max(0, start + 1 - self.kv.window_tokens) // self.cfg.block_size
+        ids = seq.window_ids[max(0, base - seq.window_base):][: out.shape[1]]
+        out[i, : len(ids)] = ids
+        return base
+
     def _build_ragged(self, items) -> RaggedBatch:
         bs = self.cfg.block_size
         S = self.cfg.max_batch
@@ -230,10 +241,25 @@ class DecodePipelineMixin:
         # State slots (models/mamba2.py): where each row's recurrent state
         # starts from, its live slot, and the slot its snapshot goes to.
         sslots = np.full((S, 3), -1, np.int32) if self.kv.live_slots else None
+        # Window layers (docs/k_exaone.md): a second, short table a row, its
+        # context counted from the table's first page, and the tokens' slots.
+        window = self.kv.window_pages > 0
+        if window:
+            wtab = np.zeros((S, self.kv.window_row_pages), np.int32)
+            wlens, wslots = np.zeros((S,), np.int32), np.full((T,), -1, np.int32)
+            held = []
         at = 0
         for i, (seq, start, n) in enumerate(items):
             if sslots is not None:
                 sslots[i] = self._state_slots_row(seq, start, n)
+            if window:
+                self.scheduler.window_span(seq, start + n)
+                base = self._window_row(wtab, i, seq, start)
+                p = np.arange(start, start + n, dtype=np.int32)
+                wids = np.asarray(seq.window_ids, np.int32)
+                wslots[at : at + n] = wids[p // bs - seq.window_base] * bs + p % bs
+                wlens[i] = start + n - base * bs
+                held.append(len(seq.window_ids))
             all_toks = seq.prompt + seq.output
             tok[at : at + n] = all_toks[start : start + n]
             p = np.arange(start, start + n, dtype=np.int32)
@@ -251,6 +277,8 @@ class DecodePipelineMixin:
             self._count_dispatch(
                 "unified", [st for _, st, _ in items], [n for _, _, n in items], T
             )
+        if window:
+            swa_metrics.add_rows(held)
         return RaggedBatch(
             token_ids=tok,
             positions=pos,
@@ -261,6 +289,7 @@ class DecodePipelineMixin:
             num_seqs=np.asarray([len(items)], np.int32),
             adapter_slots=aslots,
             state_slots=sslots,
+            **(dict(window_indices=wtab, window_lens=wlens, window_slots=wslots) if window else {}),
         )
 
     def _state_slots_row(self, seq: SequenceState, start: int, n: int):
@@ -398,6 +427,8 @@ class DecodePipelineMixin:
                 self._seal_completed_blocks(seq)
                 if due is not None:  # the step left a snapshot at this row's end
                     self.kv.attach_snapshot(*due)
+                if seq.window_ids is not None:  # ... or the window pages before it
+                    self.scheduler.retain_window(seq, start + n)
                 if not seq.in_prefill:
                     # This row's sampled token is in flight (pre-marked before
                     # the dispatch); park the row until a harvest point applies
@@ -662,6 +693,12 @@ class DecodePipelineMixin:
         tok0 = np.zeros((S,), np.int32)
         pos_disp = np.full((S,), -1, np.int32)  # dispatch frontier (-1 = free)
         tables = np.zeros((S, cfg.max_blocks_per_seq), np.int32)
+        # Window layers: the chunk's second table, begun at the block the
+        # window of the chunk's first position reaches (``_multi`` finds that
+        # block from ``pos0`` as ``_window_row`` does).
+        wtables = (
+            np.zeros((S, self.kv.window_row_pages), np.int32) if self.kv.window_pages else None
+        )
         limits = np.zeros((S,), np.int32)
         slots = RowSlots(S)
         samp: Optional[SamplingParams] = None
@@ -1035,6 +1072,8 @@ class DecodePipelineMixin:
                 if not self.scheduler._ensure_slot(seq, lookahead=need):
                     ok = False
                 self._tables_row(tables, i, seq)
+                if wtables is not None and ok:
+                    self._window_row(wtables, i, seq, int(pos_disp[i]))
                 limits[i] = min(
                     len(seq.block_ids) * bs, cfg.max_blocks_per_seq * bs
                 )
@@ -1043,6 +1082,8 @@ class DecodePipelineMixin:
                 # so schedule() can preempt with nothing pending.
                 rebuild = True
                 return None
+            if wtables is not None:
+                swa_metrics.add_rows([len(seq.window_ids) for _, seq in slots.active()])
             return pos_disp.copy()
 
         def plan_top_up(in_flight_now: int, depth: int) -> Optional[np.ndarray]:
@@ -1077,6 +1118,10 @@ class DecodePipelineMixin:
                     c_tok, c_steps, c_counts = carry
                 if self._rep_sharding is not None:
                     d_args = self._prep((pos0, tables.copy(), limits.copy(), samp))
+                elif wtables is not None:
+                    # (A copy: the next chunk's plan shifts these rows while
+                    # this one may still be reading its operands.)
+                    d_args = (pos0, (tables, wtables.copy()), limits, samp)
                 else:
                     d_args = (pos0, tables, limits, samp)
 

@@ -33,7 +33,7 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
 
-from ..llm.metrics import ssm_metrics
+from ..llm.metrics import ssm_metrics, swa_metrics
 from ..llm.protocols import PreprocessedRequest
 from ..llm.qos import BATCH, INTERACTIVE, normalize_priority
 from ..tokens import TokenBlockSequence
@@ -173,6 +173,13 @@ class SequenceState:
     # (block hash, slot) of the snapshot the row's step in flight leaves at
     # its end, attached once that step's blocks are sealed (pipeline.py).
     snapshot_due: Optional[Tuple[int, int]] = None
+    # --- window pages (engine/kv_manager.py; docs/k_exaone.md) ---
+    # The pages of the window pool this row holds, for the logical blocks
+    # ``window_base`` onward: what its next query's window reaches, and what
+    # its steps in flight write.  None: the family keeps no window, or the row
+    # is not running.
+    window_ids: Optional[List[int]] = None
+    window_base: int = 0
 
     def __post_init__(self) -> None:
         if self.orig_prompt_len == 0:
@@ -553,9 +560,12 @@ class Scheduler:
         # ("block": state held by PAGE, whose sealed entry ends AT the last
         # token and cannot restart one position earlier); everything after
         # the last snapshot BEFORE its end ("snapshot": state held by slot,
-        # kept at multiples of ``resume_stride`` only).
+        # kept at multiples of ``resume_stride`` only); everything after the
+        # last block before its end whose WINDOW pages are still held
+        # ("window": layers that keep the last positions only, whose pages
+        # before a multiple of the stride are retained with that block).
         self.resume = resume
-        self.resume_stride = cfg.prefill_chunk if resume == "snapshot" else 0
+        self.resume_stride = cfg.prefill_chunk if resume in ("snapshot", "window") else 0
         self.waiting: WfqQueue = WfqQueue(
             tenant_weights=cfg.qos.tenant_weights,
             default_weight=cfg.qos.default_weight,
@@ -623,6 +633,46 @@ class Scheduler:
             # the slot goes back with nothing attached to it.
             self.kv.free_snapshot(seq.snapshot_due[1])
             seq.snapshot_due = None
+        if seq.window_ids is not None:  # freed, preempted or failed: every page goes back
+            self.kv.release_window(seq.window_ids)
+            self.kv.window_rows -= 1
+            seq.window_ids, seq.window_base = None, 0
+
+    def window_span(self, seq: SequenceState, upto: int) -> None:
+        """``seq`` holds window pages for exactly what is ahead of it: pages
+        wholly behind the window of its NEXT query (position
+        ``num_computed``) go back to the pool, and pages are taken for the
+        positions up to ``upto`` that its next steps write.  (A step in flight
+        may still read a page given back here: whoever takes it writes it in
+        a step enqueued later, and the device runs them in order.)"""
+        bs, ids = self.cfg.block_size, seq.window_ids
+        first = max(0, seq.num_computed + 1 - self.kv.window_tokens) // bs
+        drop = min(max(first - seq.window_base, 0), len(ids))
+        self.kv.release_window(ids[:drop])
+        del ids[:drop]
+        # (Nothing held: the row's pages begin where its window does.)
+        seq.window_base = seq.window_base + drop if ids else max(seq.window_base + drop, first)
+        for _ in range((upto - 1) // bs + 1 - seq.window_base - len(ids)):
+            ids.append(self.kv.take_window_page())
+
+    def retain_window(self, seq: SequenceState, end: int) -> None:
+        """The step just enqueued ended ``seq``'s share ON a multiple of the
+        resume stride inside its prompt: the window pages before that point
+        are kept with the block that ends there (sealed by now), so that a
+        later hit may end at it."""
+        if not (
+            self.cfg.enable_prefix_caching
+            and end <= len(seq.prompt)
+            and end % self.resume_stride == 0
+        ):
+            return
+        b1 = end // self.cfg.block_size
+        b0 = max(0, b1 - self.kv.window_blocks)
+        if b0 >= seq.window_base:
+            self.kv.retain_window(
+                seq.block_seq.blocks[b1 - 1].sequence_hash,
+                seq.window_ids[b0 - seq.window_base : b1 - seq.window_base],
+            )
 
     def state_started(self, seq: SequenceState) -> None:
         """``seq`` reads its state from its own live slot from now on: the
@@ -794,6 +844,8 @@ class Scheduler:
         reserve = self._pressure_reserve()
         if reserve and prompt_blocks + reserve > self.kv.free_blocks:
             return False  # squeezed pool: the head cannot land right now
+        if self.kv.window_pages and not self.kv.window_fits():
+            return False  # the window pool has no room for one more row
         if prompt_blocks <= self.kv.free_blocks:
             return True  # fits even with zero prefix hits: skip the hashing
         # The fused pipeline polls this twice per chunk at saturation; the
@@ -872,20 +924,21 @@ class Scheduler:
         if reserve and prompt_blocks + reserve > self.kv.free_blocks:
             return False  # kv_pressure fault: pool squeezed, head waits
         slotted = self.resume == "snapshot"
+        cut = slotted or self.resume == "window"
         if slotted and len(self.running) >= self.kv.live_slots:
             return False  # every live slot is a running row's
         seq.block_seq.extend(seq.prompt)
         share = start = None
-        if slotted:
-            # The hit is cut back to the last block that holds a snapshot;
-            # the blocks past it are computed again into fresh blocks.
+        if cut:
+            # The hit is cut back to the last block that holds a snapshot (or
+            # whose window pages are held: ONE notion, ``resumable``); the
+            # blocks past it are computed again into fresh blocks.
             matched = self.kv.match_prefix(seq.block_seq.blocks)
             share, start = self.kv.resumable(matched, below=len(seq.prompt))
             if self._snapshot_ahead(seq, share * self.cfg.block_size):
                 # The head waits (admission stops at it, as for a head that
                 # does not fit): a later pass finds the snapshot.
-                if start >= 0:
-                    self.kv.unpin_snapshot(start)
+                self._unpin(start)
                 seq.block_seq = TokenBlockSequence(
                     block_size=self.cfg.block_size, salt=seq.kv_salt
                 )
@@ -895,8 +948,8 @@ class Scheduler:
             seq.block_seq = TokenBlockSequence(
                 block_size=self.cfg.block_size, salt=seq.kv_salt
             )
-            if slotted and start >= 0:
-                self.kv.unpin_snapshot(start)
+            if cut:
+                self._unpin(start)
             return False
         seq.block_ids, cached_tokens = alloc
         # Admission holds its own references now; the pre-admission pin
@@ -905,6 +958,11 @@ class Scheduler:
         if slotted:
             seq.state_slot, seq.state_start = self.kv.take_live_slot(), start
             ssm_metrics.add_start(len(matched) * self.cfg.block_size, cached_tokens)
+        elif cut:
+            # The pages ``resumable`` referenced are the row's own from here.
+            seq.window_ids, seq.window_base = list(start), share - len(start)
+            self.kv.window_rows += 1
+            swa_metrics.add_hit(len(matched) * self.cfg.block_size, cached_tokens)
         elif cached_tokens >= len(seq.prompt):
             # A fully-cached prompt must still recompute its last token to
             # get logits for sampling the first output token.
@@ -914,6 +972,11 @@ class Scheduler:
         seq.num_cached_prompt = cached_tokens
         seq.num_sealed_blocks = cached_tokens // self.cfg.block_size
         return True
+
+    def _unpin(self, start) -> None:
+        """Give back what ``resumable`` pinned for a row that is not admitted."""
+        if start != -1:
+            self.kv.unpin_snapshot(start)
 
     def _snapshot_ahead(self, seq: SequenceState, resumed: int) -> bool:
         """A running row is still computing, inside its own prompt, the
@@ -956,6 +1019,8 @@ class Scheduler:
             if bid is None:
                 return False
             seq.block_ids.append(bid)
+        if seq.window_ids is not None:
+            self.window_span(seq, min(seq.num_computed + lookahead, self.cfg.max_model_len))
         return True
 
     def _preempt(self, seq: SequenceState) -> None:

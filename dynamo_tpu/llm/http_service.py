@@ -293,6 +293,7 @@ class HttpService:
             request_hop_metrics,
             spec_metrics,
             ssm_metrics,
+            swa_metrics,
             tenancy_metrics,
         )
 
@@ -312,6 +313,7 @@ class HttpService:
             + engine_dispatch_metrics.render(self._metrics_prefix).encode()
             + sparse_model_metrics.render(self._metrics_prefix).encode()
             + ssm_metrics.render(self._metrics_prefix).encode()
+            + swa_metrics.render(self._metrics_prefix).encode()
             + request_hop_metrics.render(self._metrics_prefix).encode()
             + kv_tier_metrics.render(self._metrics_prefix).encode()
             + kv_integrity_metrics.render(self._metrics_prefix).encode()

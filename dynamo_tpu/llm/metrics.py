@@ -986,6 +986,85 @@ class SsmMetrics:
 ssm_metrics = SsmMetrics()
 
 
+class SwaMetrics:
+    """The window layers' account (engine/kv_manager.py's second page pool,
+    engine/scheduler.py; docs/k_exaone.md, docs/tracing.md): host arithmetic
+    at dispatch and admission, from lengths the scheduler holds.  Renders
+    nothing until a family with window layers dispatched a row."""
+
+    def __init__(self):
+        # Window pages held by the rows of each dispatch, and those rows.
+        self.window_pages = 0
+        self.window_rows = 0
+        self.pool_pages = {"live": 0, "retained": 0, "free": 0}
+        # Tokens of block-level hits kept, and cut back for want of window pages.
+        self.hit_tokens = {"resumed": 0, "cut": 0}
+        # Positions a query token attends to in a window layer and in a full
+        # layer, summed over the query tokens dispatched (as the mla account).
+        self.attended = {"window": 0, "full": 0}
+        self.query_tokens = 0
+
+    def reset(self) -> None:
+        self.__init__()
+
+    def add_rows(self, pages_held) -> None:
+        """``pages_held``: the window pages each row of a dispatch holds."""
+        self.window_rows += len(pages_held)
+        self.window_pages += sum(pages_held)
+
+    def add_hit(self, matched_tokens: int, resumed_tokens: int) -> None:
+        self.hit_tokens["resumed"] += resumed_tokens
+        self.hit_tokens["cut"] += matched_tokens - resumed_tokens
+
+    def add_queries(self, window: int, starts, ns) -> None:
+        """A dispatch's query tokens: the token at position t attends to
+        t + 1 positions in a full layer and to min(t + 1, window) in a window
+        layer.  ``starts[i]``, ``ns[i]``: first position and token count of
+        row i (a fused chunk counts its steps)."""
+        for start, n in zip(starts, ns):
+            start, n = int(start), int(n)
+            if n <= 0 or start < 0:
+                continue
+            self.query_tokens += n
+            self.attended["full"] += n * start + n * (n + 1) // 2
+            short = max(0, min(n, window - 1 - start))  # tokens with t + 1 < window
+            self.attended["window"] += (
+                short * start + short * (short + 1) // 2 + (n - short) * window)
+
+    def render(self, prefix: str = "dynamo_tpu") -> str:
+        if not self.window_rows:
+            return ""
+        lines = []
+        for name, kind, help_, v in (
+            ("kv_window_pages_total", "counter",
+             "Window pages held by the rows of each dispatch, summed over dispatches",
+             self.window_pages),
+            ("kv_window_rows_total", "counter",
+             "Rows of those dispatches", self.window_rows),
+            ("swa_query_tokens_total", "counter",
+             "Query tokens dispatched to a model with window layers", self.query_tokens),
+        ):
+            lines += [f"# HELP {prefix}_{name} {help_}", f"# TYPE {prefix}_{name} {kind}",
+                      f"{prefix}_{name} {v}"]
+        for name, kind, label, help_, acc in (
+            ("kv_window_pool_pages", "gauge", "state",
+             "Pages of the window pool: live (a running row's), retained (before a resume "
+             "point, with its block's hash, evictable) and free", self.pool_pages),
+            ("swa_hit_tokens_total", "counter", "outcome",
+             "Tokens of block-level prefix hits: resumed where the window pages before the "
+             "point are held, or cut back (computed again) for want of them", self.hit_tokens),
+            ("swa_attended_positions_total", "counter", "kind",
+             "Positions a query token attends to in a window layer (min(t + 1, window)) and "
+             "in a full layer (t + 1), summed over the query tokens dispatched", self.attended),
+        ):
+            lines += [f"# HELP {prefix}_{name} {help_}", f"# TYPE {prefix}_{name} {kind}"]
+            lines += [f'{prefix}_{name}{{{label}="{escape_label(k)}"}} {v}' for k, v in acc.items()]
+        return "\n".join(lines) + "\n"
+
+
+swa_metrics = SwaMetrics()
+
+
 class RequestHopMetrics:
     """The always-on per-request TTFT/TPOT hop account (docs/tracing.md):
     sums and counts of the intervals between the O(1) stamps a request

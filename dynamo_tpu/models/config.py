@@ -26,8 +26,9 @@ LATENT_MODEL_TYPES = ("deepseek_v32", "deepseek_v3", "kimi_k2")
 LLAMA_MODEL_TYPES = ("llama", "qwen2", "mistral", "mixtral")
 # The hybrid family (models/lfm2.py): a layer's mixer is a gated short
 # convolution (lfm2_moe), a Mamba-2 scan (granitemoehybrid) or GQA attention;
-# dense then sparse feed-forwards.
-HYBRID_MODEL_TYPES = ("lfm2_moe", "granitemoehybrid")
+# dense then sparse feed-forwards.  exaone_moe: attention in every layer, of
+# which most keep a window of the last positions only (docs/k_exaone.md).
+HYBRID_MODEL_TYPES = ("lfm2_moe", "granitemoehybrid", "exaone_moe")
 
 
 @dataclass(frozen=True)
@@ -105,6 +106,14 @@ class ModelConfig:
     mamba_d_head: int = 0
     mamba_d_state: int = 0
     mamba_d_conv: int = 0
+    # exaone_moe (docs/k_exaone.md): a "sliding_attention" layer attends to
+    # the last ``sliding_window`` positions (the query's own among them) and
+    # keeps no more; the full-attention layers of a model that mixes both do
+    # not rotate (``rope_full_attention`` False); a branch's OUTPUT is normed
+    # (``post_norm``: h + norm(f(h))) where the other models norm its input.
+    sliding_window: int = 0
+    rope_full_attention: bool = True
+    post_norm: bool = False
 
     @property
     def is_moe(self) -> bool:
@@ -133,6 +142,8 @@ class ModelConfig:
             return cls._from_latent(cfg, name)
         if model_type == "granitemoehybrid":
             return cls._from_granite_hybrid(cfg, name)
+        if model_type == "exaone_moe":
+            return cls._from_exaone_moe(cfg, name)
         if model_type in HYBRID_MODEL_TYPES:
             return cls._from_hybrid(cfg, name)
         if model_type is not None and model_type not in LLAMA_MODEL_TYPES:
@@ -358,6 +369,79 @@ class ModelConfig:
             mamba_d_head=P,
             mamba_d_state=cfg["mamba_d_state"],
             mamba_d_conv=cfg["mamba_d_conv"],
+        )
+
+    @classmethod
+    def _from_exaone_moe(cls, cfg: Dict[str, Any], name: str) -> "ModelConfig":
+        """``exaone_moe``'s keys (docs/k_exaone.md).  ``num_experts`` counts
+        the experts held here; a file cut to one chip's share states the
+        router's width beside it (``num_experts_published``) with
+        ``ep_size``/``ep_rank``.  The multi-token-prediction module
+        (``num_nextn_predict_layers``) is a draft head and is not served."""
+        L = cfg["num_hidden_layers"]
+        layer_types = tuple(cfg["layer_types"])
+        if len(layer_types) != L or set(layer_types) - {"sliding_attention", "full_attention"}:
+            raise ValueError(
+                f"layer_types must name {L} layers, each 'sliding_attention' or 'full_attention'")
+        window = int(cfg.get("sliding_window") or 0)
+        if "sliding_attention" in layer_types and window < 1:
+            raise ValueError("sliding_window must be at least 1 where a layer keeps a window")
+        mlp_types = tuple(cfg.get("mlp_layer_types") or ())
+        dense = cfg.get("first_k_dense_replace", sum(t == "dense" for t in mlp_types))
+        if mlp_types and mlp_types != ("dense",) * dense + ("sparse",) * (L - dense):
+            raise ValueError(
+                f"mlp_layer_types must be {dense} 'dense' layers (first_k_dense_replace) and "
+                f"then 'sparse' ones over {L} layers")
+        for key, want in (("n_group", 1), ("topk_group", 1), ("scoring_func", "sigmoid"),
+                          ("hidden_act", "silu")):
+            if cfg.get(key, want) != want:
+                raise ValueError(f"{key} {cfg[key]!r} is not supported (the release has {want!r})")
+        rope = cfg.get("rope_parameters") or {}
+        if rope.get("rope_type", "default") != "default":
+            raise ValueError(f"rope_type {rope['rope_type']!r} is not supported")
+        held = cfg["num_experts"]
+        ep_size = cfg.get("ep_size", 1)
+        total = cfg.get("num_experts_published", held * ep_size)
+        ep_rank = cfg.get("ep_rank", 0)
+        if held * ep_size != total or not 0 <= ep_rank < ep_size:
+            raise ValueError(
+                f"num_experts {held} x ep_size {ep_size} (ep_rank {ep_rank}) is not the "
+                f"router's width {total}")
+        num_heads = cfg["num_attention_heads"]
+        eos = cfg.get("eos_token_id", ())
+        if isinstance(eos, int):
+            eos = (eos,)
+        return cls(
+            name=name or cfg.get("_name_or_path", "hf-model"),
+            model_type=cfg["model_type"],
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            num_layers=L,
+            num_heads=num_heads,
+            num_kv_heads=cfg.get("num_key_value_heads", num_heads),
+            head_dim=cfg.get("head_dim") or cfg["hidden_size"] // num_heads,
+            intermediate_size=cfg["intermediate_size"],
+            rope_theta=float(rope.get("rope_theta", cfg.get("rope_theta", 1000000.0))),
+            rms_norm_eps=cfg.get("rms_norm_eps", 1e-5),
+            max_position=cfg.get("max_position_embeddings", 262144),
+            tie_word_embeddings=cfg.get("tie_word_embeddings", False),
+            num_experts=held,
+            num_experts_per_token=cfg["num_experts_per_tok"],
+            num_shared_experts=cfg.get("num_shared_experts", 0),
+            moe_intermediate_size=cfg["moe_intermediate_size"],
+            eos_token_ids=tuple(eos),
+            first_k_dense_replace=dense,
+            routed_scaling_factor=float(cfg.get("routed_scaling_factor", 1.0)),
+            norm_topk_prob=cfg.get("norm_topk_prob", True),
+            router_experts=total,
+            ep_size=ep_size,
+            ep_rank=ep_rank,
+            layer_types=layer_types,
+            qk_norm=True,
+            shared_intermediate_size=cfg.get("num_shared_experts", 0) * cfg["moe_intermediate_size"],
+            sliding_window=window,
+            rope_full_attention="sliding_attention" not in layer_types,
+            post_norm=True,
         )
 
     @classmethod

@@ -55,11 +55,18 @@ class ModelFamily(NamedTuple):
     # K/V of a position is all a later one needs); at a "block"'s end (state
     # held by PAGE: a prompt that is a whole number of cached blocks gives its
     # last block back); or at a "snapshot" (state held by SLOT: a hit is cut
-    # back to the last sealed block that holds a snapshot of it).
+    # back to the last sealed block that holds a snapshot of it); or behind a
+    # "window" (layers that keep the last positions only, in a second page
+    # pool: a hit is cut back to the last block before which those pages are
+    # still held).
     resume: str = "token"
     # (config, engine config) -> (live slots, snapshot slots) of a family whose
     # state lives in slots beside the pages; None = it has no such state.
     state_slots: Optional[Callable] = None
+    # (config, engine config) -> (pages of the window pool, positions a window
+    # layer keeps, most pages a running row holds) of a family with such
+    # layers; None = it has none.
+    window_pool: Optional[Callable] = None
 
 
 def _llama(config: ModelConfig) -> ModelFamily:
@@ -187,10 +194,11 @@ def _hybrid(config: ModelConfig) -> ModelFamily:
     """models/lfm2.py: gated short convolutions whose state lives in the page
     cache beside the attention layers' K/V (resumed at a block's end), or
     Mamba-2 layers whose state lives in slots (resumed at a snapshot)."""
-    from ..llm.metrics import sparse_model_metrics
+    from ..llm.metrics import sparse_model_metrics, swa_metrics
     from . import lfm2
 
     slotted = lfm2.mamba_layers(config) > 0
+    windowed = lfm2.window_layers(config) > 0
 
     def kinds(config, cache):
         """K/V bytes a token an attention layer; the convolution state's
@@ -202,17 +210,36 @@ def _hybrid(config: ModelConfig) -> ModelFamily:
         if cache.ssm is not None:
             out["ssm_slot"] = cache.ssm[0, 0].size * cache.ssm.dtype.itemsize
             out["conv_tail"] = cache.tail[0, :, 0].size * cache.tail.dtype.itemsize
+        if cache.window is not None:  # a token a WINDOW layer, for the last positions only
+            out["kv_window"] = out["kv"]
         return out
 
     def check(config: ModelConfig, cfg: Any) -> None:
         bad = []
         if cfg.tp * cfg.dp * cfg.ep * cfg.sp != 1:
-            bad.append("--tp/--dp/--ep/--sp > 1 (no PartitionSpecs for the state pages; the "
-                       "configuration's ep_size/ep_rank say which experts this chip holds)")
+            bad.append("--tp/--dp/--ep/--sp > 1 (no PartitionSpecs for the state pages or the "
+                       "window pool; the configuration's ep_size/ep_rank say which experts this "
+                       "chip holds)")
+        if windowed and cfg.prefill_chunk % cfg.block_size:
+            bad.append("--prefill-chunk that is no multiple of --block-size (the window pages "
+                       "before a resume point are kept by whole blocks)")
         _refuse(config, cfg, bad + _unmovable_blocks(cfg))
 
     def state_slots(config: ModelConfig, cfg: Any):
         return cfg.max_batch, lfm2.snapshot_slots(cfg.num_blocks, cfg.block_size, cfg.prefill_chunk)
+
+    def window_pool(config: ModelConfig, cfg: Any):
+        """The window pool's ONE rule (docs/k_exaone.md): the pages before
+        every resume stride the K/V pages can hold, and never fewer than twice
+        what ``max_batch`` running rows can hold at once."""
+        row = lfm2.window_row_pages(config, cfg.block_size, max(
+            cfg.prefill_chunk, (cfg.pipeline_depth + 1) * cfg.decode_steps))
+        kept = lfm2.window_blocks(config, cfg.block_size) * (
+            cfg.num_blocks * cfg.block_size // cfg.prefill_chunk)
+        return max(kept, 2 * cfg.max_batch * row), config.sliding_window, row
+
+    def count_window(config, kind, starts, ns, step_tokens=None):
+        swa_metrics.add_queries(config.sliding_window, starts, ns)
 
     return ModelFamily(
         name="hybrid",
@@ -229,14 +256,15 @@ def _hybrid(config: ModelConfig) -> ModelFamily:
         cache_kinds=kinds,
         check=check,
         # The slots' account is the block manager's (admissions, snapshots).
-        count_dispatch=None if slotted else (
+        count_dispatch=None if slotted else count_window if windowed else (
             lambda config, kind, starts, ns, step_tokens=None: (
                 sparse_model_metrics.add_conv(kind, starts, ns))),
         count_aux=sparse_model_metrics.add_moe,
         counts=sparse_model_metrics.summary,
         attn_lanes=lfm2.attn_lanes,
-        resume="snapshot" if slotted else "block",
+        resume="snapshot" if slotted else "window" if windowed else "block",
         state_slots=state_slots if slotted else None,
+        window_pool=window_pool if windowed else None,
     )
 
 

@@ -12,6 +12,16 @@ rotation or QK-norm at the configuration's softmax scale, a softmax gate, a
 shared SwiGLU beside the experts of every layer and four scalar multipliers,
 each a static field of ``ModelConfig`` that adds no op where it is 1.
 
+``exaone_moe`` (docs/k_exaone.md) has attention in every layer; most are a
+FOURTH mixer kind, ``sliding_attention``: the same GQA over the last
+``sliding_window`` positions only, whose K/V live in a SECOND page pool
+(``HybridCache.window``) under a second, short page table a row
+(``RaggedBatch.window_*``; engine/kv_manager.py gives the pages back as they
+fall behind the window).  Its leaves are a group of their own (``wattn``), so
+the full-attention block of the other models is traced as it was.  Rotation by
+layer kind, a branch's OUTPUT normed (``post_norm``), a leading dense layer,
+one shared expert beside the routed ones: static fields all.
+
 Beside models/llama.py and models/deepseek_v32.py, sharing ``linear``,
 ``rms_norm``, ``mlp``, ``embed_lookup``, ``lm_logits``, the attention ops of
 the dense family, the latent family's ``gate`` and the dispatch of
@@ -62,6 +72,7 @@ QUANT_AXES = {
     "layers": {},
     "conv": {"in_proj": 1, "out_proj": 1},
     "attn": {"wqkv": 1, "wo": 1},
+    "wattn": {"wqkv": 1, "wo": 1},
     "dense": {"w_gate": 1, "w_up": 1, "w_down": 1},
     "moe": {"moe_gate": 2, "moe_up": 2, "moe_down": 2},
     "top": {"embed": 1, "lm_head": 0},
@@ -94,11 +105,28 @@ def mamba_layers(config: ModelConfig) -> int:
     return sum(t == "mamba" for t in config.layer_types)
 
 
+def window_layers(config: ModelConfig) -> int:
+    return sum(t == "sliding_attention" for t in config.layer_types)
+
+
 def layer_counts(config: ModelConfig) -> Tuple[int, int, int, int]:
-    """(convolution, attention, dense, expert) layers."""
+    """(convolution, full attention, dense, expert) layers."""
     Lc = sum(t == "conv" for t in config.layer_types)
     Ld = min(config.first_k_dense_replace, config.num_layers)
-    return Lc, config.num_layers - Lc - mamba_layers(config), Ld, config.num_layers - Ld
+    La = config.num_layers - Lc - mamba_layers(config) - window_layers(config)
+    return Lc, La, Ld, config.num_layers - Ld
+
+
+def window_blocks(config: ModelConfig, page_size: int) -> int:
+    """Pages that hold the ``sliding_window - 1`` positions before a block
+    boundary: what a row resumed there reads of the window layers."""
+    return -(-(config.sliding_window - 1) // page_size)
+
+
+def window_row_pages(config: ModelConfig, page_size: int, step_tokens: int) -> int:
+    """The width of a row's window table: the pages a step of ``step_tokens``
+    query tokens can touch, ``ceil((window - 1 + q) / page) + 1``."""
+    return -(-(config.sliding_window - 1 + step_tokens) // page_size) + 1
 
 
 def snapshot_slots(num_pages: int, page_size: int, stride: int) -> int:
@@ -117,7 +145,9 @@ class HybridCache(NamedTuple):
     and ``tail`` [Lm, d_conv - 1, slots, channels] in the activation dtype:
     the Mamba-2 layers' state by SLOT (models/mamba2.py), the first
     ``max_batch`` slots the running rows', the others snapshots; None without
-    such layers (a None leaf is no operand of a program).  The shapes leave
+    such layers (a None leaf is no operand of a program).  ``window`` [Lw, Pw,
+    ps, 2 * KV / pack, pack * head_dim]: the window layers' K/V, pages of a
+    pool of their own in the K/V pages' dtype; None without such layers.  The shapes leave
     the chip's compiler ONE layout for a pool: over [.., heads, d_head,
     d_state] it chose another order than the parameter's for the prompt
     program's matmuls and copied 5 GB into and out of every step, and over
@@ -128,18 +158,20 @@ class HybridCache(NamedTuple):
     conv: Any
     ssm: Any = None
     tail: Any = None
+    window: Any = None
 
     @classmethod
     def create(cls, config: ModelConfig, num_pages: int, page_size: int,
-               dtype=jnp.bfloat16, state_slots: int = 1) -> "HybridCache":
+               dtype=jnp.bfloat16, state_slots: int = 1, window_pages: int = 0) -> "HybridCache":
         Lc, La, _, _ = layer_counts(config)
-        Lm = mamba_layers(config)
+        Lm, Lw = mamba_layers(config), window_layers(config)
         pack = head_pack(config)
         act = jnp.dtype(config.dtype)
         _, Hm, P, N, K = mamba2.dims(config)
+        page = (page_size, 2 * config.num_kv_heads // pack, pack * config.head_dim)
         return cls(
-            pages=jnp.zeros((La, num_pages, page_size, 2 * config.num_kv_heads // pack,
-                             pack * config.head_dim), dtype),
+            pages=jnp.zeros((La, num_pages) + page, dtype),
+            window=jnp.zeros((Lw, window_pages) + page, dtype) if Lw else None,
             conv=jnp.zeros((Lc, num_pages, config.conv_L_cache - 1, config.hidden_size),
                            act) if Lc else None,
             ssm=jnp.zeros((Lm, state_slots, Hm * P, N), jnp.float32) if Lm else None,
@@ -166,6 +198,10 @@ def leaf_shapes(config: ModelConfig) -> Dict[str, Dict[str, tuple]]:
     # wqkv's columns: q (H heads), k, v (KV heads each).
     norms = {"q_norm": (La, hd), "k_norm": (La, hd)} if c.qk_norm else {}
     groups["attn"] = {"wqkv": (La, D, (H + 2 * KV) * hd), **norms, "wo": (La, H * hd, D)}
+    Lw = window_layers(c)
+    if Lw:
+        groups["wattn"] = {"wqkv": (Lw, D, (H + 2 * KV) * hd), "q_norm": (Lw, hd),
+                           "k_norm": (Lw, hd), "wo": (Lw, H * hd, D)}
     if Ld:
         groups["dense"] = {"w_gate": (Ld, D, F), "w_up": (Ld, D, F), "w_down": (Ld, F, D)}
     # The bias steers the sigmoid gate's choice; [a | b] = W_1 x is (moe_gate | moe_up).
@@ -268,6 +304,15 @@ def forward_ragged(
     pos = rb.positions
     real = rb.slot_mapping >= 0  # [T] padding tokens carry slot -1
     ks_vec = None if kv_scale is None else jnp.asarray(kv_scale, jnp.float32).reshape(-1)
+    # A model that norms K a head and V not at all (QK-norm with output-side
+    # norms: V is as small as the layer's input, K of size 1) gets a GAIN a
+    # layer beside its scale, the second half of ``kv_scale``: V is stored
+    # times the gain, so that one scale a page serves both, and the call's
+    # output divided by it (attention is linear in V).  Static: by the shape.
+    n_attn = La + window_layers(c)
+    gains = None
+    if c.post_norm and ks_vec is not None and ks_vec.shape[0] == 2 * n_attn:
+        ks_vec, gains = ks_vec[:n_attn], ks_vec[n_attn:]
     # The fused kernels take the scale themselves (models/llama.py).
     fused_dequant = decode_kernel == "pallas_fused" if decode else prefill_kernel == "pallas"
 
@@ -325,14 +370,18 @@ def forward_ragged(
             entry = window if decode else window[jnp.minimum(writers, T - 1)]
         return linear(y, lp, "out_proj"), entry
 
-    def attention(x, lp, a, pages):
+    def attention(x, lp, a, pages, windowed=False):
+        """GQA over the row's pages: the full-attention layers' (``pages``
+        the G pool, table and slots ``rb.page_indices`` / ``rb.slot_mapping``)
+        or, ``windowed``, the window layers' (the window pool under
+        ``rb.window_indices`` / ``rb.window_slots`` / ``rb.window_lens``)."""
         q, k, v = jnp.split(linear(x, lp, "wqkv"), [H * hd, (H + KV) * hd], axis=-1)
         if config.qk_norm:  # static
             q = rms_norm(q.reshape(T, H, hd), lp["q_norm"], eps)
             k = rms_norm(k.reshape(T, KV, hd), lp["k_norm"], eps)
         else:
             q, k = q.reshape(T, H, hd), k.reshape(T, KV, hd)
-        if inv_freq is not None:
+        if inv_freq is not None and (windowed or c.rope_full_attention):  # static
             q, k = apply_rope(q, pos, inv_freq), apply_rope(k, pos, inv_freq)
         if pack > 1:
             # KV head g lies in half g % pack of row g // pack; its G queries
@@ -342,18 +391,30 @@ def forward_ragged(
                  ).reshape(T, H, pack * hd)
         k = k.reshape(T, KV // pack, pack * hd)
         v = v.reshape(T, KV // pack, pack * hd)
-        s_a = None if ks_vec is None else ks_vec[jnp.minimum(a, ks_vec.shape[0] - 1)]
-        slots = jnp.where(real, rb.slot_mapping + a * (P_layer * ps), -1)
+        # The pool's side of the step: a window layer's scale follows the full
+        # layers', its pages are the window pool's under the window table.
+        P_pool = cache.window.shape[1] if windowed else P_layer
+        at = La + a if windowed else a
+        s_a = None if ks_vec is None else ks_vec[jnp.minimum(at, ks_vec.shape[0] - 1)]
+        slot_of = rb.window_slots if windowed else rb.slot_mapping
+        slots = jnp.where(real, slot_of + a * (P_pool * ps), -1)
+        if gains is not None:
+            v = (v.astype(jnp.float32) * gains[at]).astype(v.dtype)
         pages = write_kv_ragged(pages, k, v, slots, kv_scale=s_a)
         fold = s_a is not None and not fused_dequant
         if fold:  # models/llama.py: the scale folded around the call
             q = (q.astype(jnp.float32) * s_a).astype(q.dtype)
+        lens, table, kw = (
+            (rb.window_lens, rb.window_indices, {"window": c.sliding_window}) if windowed
+            else (rb.kv_lens, rb.page_indices, {}))
         o = ragged_attention(
-            q, pages, rb.kv_lens, rb.page_indices + a * P_layer, rb.cu_q_lens, rb.num_seqs,
+            q, pages, lens, table + a * P_pool, rb.cu_q_lens, rb.num_seqs,
             sm_scale=sm_scale, impl=attn_impl, decode=decode, decode_kernel=decode_kernel,
-            prefill_kernel=prefill_kernel, kv_scale=s_a if fused_dequant else None)
+            prefill_kernel=prefill_kernel, kv_scale=s_a if fused_dequant else None, **kw)
         if fold:
             o = (o.astype(jnp.float32) * s_a).astype(o.dtype)
+        if gains is not None:
+            o = (o.astype(jnp.float32) / gains[at]).astype(o.dtype)
         if pack > 1:
             o = o.reshape(T, KV // pack, pack, G, pack, hd)
             o = jnp.stack([o[:, :, i, :, i] for i in range(pack)], axis=2)
@@ -379,6 +440,10 @@ def forward_ragged(
         return attention(x, at_layer("attn", a), a, pages)
 
     @jax.jit
+    def window_block(x, w, wpages):
+        return attention(x, at_layer("wattn", w), w, wpages, windowed=True)
+
+    @jax.jit
     def moe_layer(x, j):
         return moe_block(x, at_layer("moe", j), c, real, j)
 
@@ -386,14 +451,22 @@ def forward_ragged(
         # Static: a model whose multiplier is 1 gets no op for it.
         return h + y if c.residual_multiplier == 1.0 else h + c.residual_multiplier * y
 
+    # Where a branch's norm stands (static): on its input, or (``post_norm``)
+    # on its output; the other side adds no op.
+    def norm_in(h, name, l):
+        return h if c.post_norm else rms_norm(h, params["layers"][name][l], eps)
+
+    def norm_out(y, name, l):
+        return rms_norm(y, params["layers"][name][l], eps) if c.post_norm else y
+
     def experts(h, l, pairs, read):
         """``h += experts(norm_2(h))`` (+ the shared MLP where the model has
         one), with the account of the pairs that landed."""
-        x = rms_norm(h, params["layers"]["ffn_norm"][l], eps)
+        x = norm_in(h, "ffn_norm", l)
         y, load = moe_layer(x, jnp.int32(l - Ld))
         if "shared" in params:
             y = y + mlp(x, at_layer("shared", l - Ld))
-        return (residual(h, y), pairs + jnp.sum(load),
+        return (residual(h, norm_out(y, "ffn_norm", l)), pairs + jnp.sum(load),
                 read + jnp.sum(load > 0, dtype=jnp.int32))
 
     @jax.jit
@@ -413,11 +486,14 @@ def forward_ragged(
     if c.embedding_multiplier != 1.0:
         h = h * jnp.asarray(c.embedding_multiplier, dt)
     pages = cache.pages.reshape((La * P_layer,) + cache.pages.shape[2:])
+    wpages = cache.window
+    if wpages is not None:
+        wpages = wpages.reshape((-1,) + wpages.shape[2:])
     ssm, tail = cache.ssm, cache.tail
     entries = []
     pairs = jnp.zeros((), jnp.int32)
     read = jnp.zeros((), jnp.int32)
-    ci = ai = mi = l = 0
+    ci = ai = mi = wi = l = 0
     while l < c.num_layers:  # constant layer numbers: see models/llama.py on decode
         kind = c.layer_types[l]
         if kind == "mamba":
@@ -438,18 +514,21 @@ def forward_ragged(
             h, ssm, tail, pairs, read = carry
             l, mi = l + run, mi + run
             continue
-        x = rms_norm(h, params["layers"]["op_norm"][l], eps)
+        x = norm_in(h, "op_norm", l)
         if kind == "conv":
             y, entry = conv_block(x, jnp.int32(ci), tails[ci])
             entries.append(entry)
             ci += 1
+        elif kind == "sliding_attention":
+            y, wpages = window_block(x, jnp.int32(wi), wpages)
+            wi += 1
         else:
             y, pages = attn_block(x, jnp.int32(ai), pages)
             ai += 1
-        h = residual(h, y)
+        h = residual(h, norm_out(y, "op_norm", l))
         if l < Ld:
-            x = rms_norm(h, params["layers"]["ffn_norm"][l], eps)
-            h = h + mlp(x, at_layer("dense", l))
+            x = norm_in(h, "ffn_norm", l)
+            h = h + norm_out(mlp(x, at_layer("dense", l)), "ffn_norm", l)
         else:
             h, pairs, read = experts(h, l, pairs, read)
         l += 1
@@ -465,4 +544,6 @@ def forward_ragged(
         logits = logits / c.logits_scaling
     aux = jnp.stack([pairs, jnp.sum(real, dtype=jnp.int32) * Lm, read,
                      jnp.asarray(c.num_experts * Lm, jnp.int32)])
-    return logits, HybridCache(pages.reshape(cache.pages.shape), conv, ssm, tail), aux
+    if wpages is not None:
+        wpages = wpages.reshape(cache.window.shape)
+    return logits, HybridCache(pages.reshape(cache.pages.shape), conv, ssm, tail, wpages), aux

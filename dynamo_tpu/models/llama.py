@@ -148,6 +148,15 @@ class RaggedBatch(NamedTuple):
     # zeros, its live slot, a slot that gets a copy after the step or -1).
     # None for every other family: no operand of their programs.
     state_slots: Any = None  # [S, 3] int32 | None
+    # The window layers' side of the step (models/lfm2.py ``sliding_attention``;
+    # engine/kv_manager.py's second pool): a row's window table, which BEGINS
+    # at the first page the window of the row's first query reaches; its
+    # context length counted from that page's first position; each token's
+    # slot in the window pool.  None (absent, not empty) for every family
+    # without such layers: no operand of their programs.
+    window_indices: Any = None  # [S, window pages a row] int32 | None
+    window_lens: Any = None  # [S] int32 | None
+    window_slots: Any = None  # [T] int32 (-1 = padding) | None
 
 
 def _dtype(config: ModelConfig):

@@ -202,6 +202,7 @@ def _make_kernel(
     pages_per_seq: int,
     split_pages: int,
     ppcb: int,
+    window: Optional[int] = None,
 ):
     """Build the kernel body for a static geometry.
 
@@ -210,6 +211,11 @@ def _make_kernel(
     UNNORMALIZED partial (o, m, l) — combined host-side by LSE.  Its
     block loop and its page copies are bounded by the pages the row HAS
     in that split, never by ``split_pages``.
+
+    ``window``: the query attends to the last ``window`` positions of its
+    context only (its own among them).  The caller's table then BEGINS at
+    the first page the window reaches (``fused_decode_attention``), so the
+    walk is over the window's pages and this is one more term of the mask.
     """
     C = ppcb * page_size  # context positions per compute block
 
@@ -328,6 +334,8 @@ def _make_kernel(
                     jax.lax.broadcasted_iota(jnp.int32, (1, C), 1)
                 )
                 mask = pos < split_end  # [1, C]
+                if window is not None:
+                    mask &= pos >= kv_len - window
                 out = []
                 for h in range(num_kv):
                     m_h, l_h, acc_h = carry[3 * h], carry[3 * h + 1], carry[3 * h + 2]
@@ -402,8 +410,16 @@ def fused_decode_attention(
     num_kv_splits: Optional[int] = None,
     pages_per_block: Optional[int] = None,
     interpret: Optional[bool] = None,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Host wrapper: fused-dequant decode attention + LSE split combine.
+
+    ``window``: a layer that keeps the last ``window`` positions only.
+    ``page_indices`` and ``kv_lens`` are then those of the row's WINDOW
+    pages (the table begins at the first page the window reaches and
+    ``kv_lens`` counts from that page's first position), so the walk is over
+    a dozen pages whatever the context; the call carries a name of its own in
+    the device trace (``window_decode_attention``).
 
     Knobs (env > tuned table > default; tools/tune_decode.py sweeps them):
     - ``DYN_DECODE_SPLITS`` / splits: KV-split grid width (0 = auto: 1 —
@@ -443,6 +459,7 @@ def fused_decode_attention(
         pages_per_seq=PP,
         split_pages=split_pages,
         ppcb=ppcb,
+        window=window,
     )
     scale_arr = jnp.asarray(
         1.0 if kv_scale is None else kv_scale, jnp.float32
@@ -494,7 +511,7 @@ def fused_decode_attention(
             vmem_limit_bytes=64 << 20,
         ),
         interpret=interpret,
-        name="fused_decode_attention",
+        name="fused_decode_attention" if window is None else "window_decode_attention",
     )(
         jnp.asarray(kv_lens, jnp.int32),
         jnp.asarray(page_indices, jnp.int32),

@@ -90,6 +90,7 @@ def _make_kernel(
     split_pages: int,
     ppcb: int,
     q_block: int,
+    window: Optional[int] = None,
 ):
     """Build the kernel body for a static geometry.  ``group`` is the
     PADDED q-heads-per-kv-head count (a sublane-tile multiple, see the
@@ -99,6 +100,11 @@ def _make_kernel(
     against KV split ``j`` (pages [j*split_pages, (j+1)*split_pages)) and
     writes UNNORMALIZED partials (o, m, l) per token — combined host-side
     by LSE over the split axis.
+
+    ``window``: a query attends to the last ``window`` positions up to its
+    own; the caller's table begins at the first page the row's first query's
+    window reaches (``fused_prefill_attention``), and a q-block's walk begins
+    at the first compute block ITS first query's window reaches.
     """
     C = ppcb * page_size  # context positions per compute block
     QB = q_block
@@ -203,6 +209,8 @@ def _make_kernel(
                     )
                     # Causal + split-coverage + live-query mask [QB*G, C].
                     mask = (pos <= qpos) & (pos < split_end) & valid_q
+                    if window is not None:
+                        mask &= pos > qpos - window
                     out = []
                     for h in range(num_kv):
                         m_h = carry[3 * h]
@@ -249,13 +257,21 @@ def _make_kernel(
                         )
                     )
 
-                @pl.when(nblocks > 0)
+                # A window's walk begins at the block that holds the first
+                # position the q-block's first query attends to (the blocks'
+                # parity picks the buffer, so any start is as good as 0).
+                b0 = 0
+                if window is not None:
+                    first_pos = kv_len - q_len + qb * QB - (window - 1)
+                    b0 = jnp.clip((first_pos - base_page * page_size) // C, 0, nblocks)
+
+                @pl.when(nblocks > b0)
                 def _():
-                    fetch(0, 0, start=True)
+                    fetch(b0, jax.lax.rem(b0, 2) if window is not None else 0, start=True)
 
                 # An empty split runs zero trips: the init carry IS the
                 # neutral partial (o=0, m=NEG_INF, l=0).
-                final = jax.lax.fori_loop(0, nblocks, block_step, tuple(init))
+                final = jax.lax.fori_loop(b0, nblocks, block_step, tuple(init))
                 lane = jax.lax.broadcasted_iota(
                     jnp.int32, (QB * group, LANES), 1
                 )
@@ -306,8 +322,14 @@ def fused_prefill_attention(
     num_kv_splits: Optional[int] = None,
     pages_per_block: Optional[int] = None,
     interpret: Optional[bool] = None,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Host wrapper: chunked paged prefill attention + LSE split combine.
+
+    ``window``: a layer that keeps the last ``window`` positions only;
+    ``page_indices`` and ``kv_lens`` are those of the rows' WINDOW pages (as
+    ``fused_decode_attention``), and the call is named
+    ``window_prefill_attention`` in the device trace.
 
     Knobs (env > tuned table > default; tools/tune_decode.py sweeps them):
     - ``DYN_PREFILL_QB`` / prefill_qb: query tokens per compute block.
@@ -361,6 +383,7 @@ def fused_prefill_attention(
         split_pages=split_pages,
         ppcb=ppcb,
         q_block=QB,
+        window=window,
     )
     scale_arr = jnp.asarray(
         1.0 if kv_scale is None else kv_scale, jnp.float32
@@ -407,7 +430,7 @@ def fused_prefill_attention(
             vmem_limit_bytes=64 << 20,
         ),
         interpret=interpret,
-        name="fused_prefill_attention",
+        name="fused_prefill_attention" if window is None else "window_prefill_attention",
     )(
         jnp.asarray(kv_lens, jnp.int32),
         jnp.asarray(page_indices, jnp.int32),

@@ -215,6 +215,7 @@ def ragged_decode_attention(
     impl: str = "xla",  # "tpu" | "xla"
     kv_scale: float | None = None,
     kernel: str = "stock",  # "pallas_fused" | "stock" | "xla"
+    window: int | None = None,  # see ragged_attention
 ) -> jnp.ndarray:
     """Decode-specialized attention: every row is exactly ONE query token
     (the fused multi-step decode program's shape — engine/pipeline.py).
@@ -254,11 +255,14 @@ def ragged_decode_attention(
             num_seqs,
             sm_scale=sm_scale,
             kv_scale=kv_scale,
+            window=window,
         )
     if kernel == "xla":
         impl = "xla"
     elif kernel != "stock":
         raise ValueError(f"unknown decode kernel {kernel!r}")
+    if impl == "tpu" and window is not None:
+        raise ValueError("the stock kernel has no window; use decode_kernel pallas_fused or xla")
     if impl == "tpu":
         from jax.experimental.pallas.ops.tpu.ragged_paged_attention import (
             ragged_paged_attention,
@@ -322,6 +326,8 @@ def ragged_decode_attention(
     qf = q.reshape(S, KV, G, D).astype(jnp.float32) * sm_scale
     logits = jnp.einsum("skgd,swkd->skgw", qf, k)  # [S, KV, G, W]
     mask = (ctx[None, :] < kv_lens[:, None]) & valid[:, None]  # [S, W]
+    if window is not None:
+        mask &= ctx[None, :] >= kv_lens[:, None] - window
     logits = jnp.where(mask[:, None, None, :], logits, NEG_INF)
     m = jnp.max(logits, axis=-1, keepdims=True)
     p = jnp.exp(logits - m) * mask[:, None, None, :]
@@ -345,8 +351,17 @@ def ragged_attention(
     decode: bool = False,  # static hint: every row is a 1-token decode row
     decode_kernel: str = "stock",  # decode-path kernel (resolve_decode_kernel)
     prefill_kernel: str = "stock",  # non-decode kernel (resolve_prefill_kernel)
+    window: int | None = None,  # a layer that keeps the last ``window`` positions
 ) -> jnp.ndarray:
     """Causal attention of each token against its sequence's paged context.
+
+    ``window``: a query at position t attends to positions j with
+    ``0 <= t - j < window``.  The caller hands such a layer the rows' WINDOW
+    tables (engine/kv_manager.py): ``page_indices[i]`` begins at the first
+    page the window of row i's first query reaches, ``kv_lens[i]`` counts
+    from that page's first position, and positions are relative to it in
+    every implementation alike, so a window layer WALKS its window's pages
+    and masks nothing of the context before them.
 
     Row i's queries are the LAST (cu_q_lens[i+1]-cu_q_lens[i]) tokens of its
     kv_lens[i]-token context (their K/V must already be written — callers run
@@ -383,6 +398,7 @@ def ragged_attention(
             impl=impl,
             kv_scale=kv_scale,
             kernel=decode_kernel,
+            window=window,
         )
     if prefill_kernel == "pallas":
         from .prefill_attention import fused_prefill_attention
@@ -397,11 +413,14 @@ def ragged_attention(
             num_seqs,
             sm_scale=sm_scale,
             kv_scale=kv_scale,
+            window=window,
         )
     if prefill_kernel == "xla":
         impl = "xla"
     elif prefill_kernel != "stock":
         raise ValueError(f"unknown prefill kernel {prefill_kernel!r}")
+    if impl == "tpu" and window is not None:
+        raise ValueError("the stock kernel has no window; use prefill_kernel pallas or xla")
     if impl == "tpu":
         from jax.experimental.pallas.ops.tpu.ragged_paged_attention import (
             ragged_paged_attention,
@@ -476,6 +495,8 @@ def ragged_attention(
     qf = q.reshape(T, KV, G, D).astype(jnp.float32) * sm_scale
     logits = jnp.einsum("tkgd,twkd->tkgw", qf, k)  # [T, KV, G, W]
     mask = (ctx[None, :] <= qpos[:, None]) & valid[:, None]  # [T, W]
+    if window is not None:
+        mask &= ctx[None, :] > qpos[:, None] - window
     logits = jnp.where(mask[:, None, None, :], logits, NEG_INF)
     m = jnp.max(logits, axis=-1, keepdims=True)
     p = jnp.exp(logits - m) * mask[:, None, None, :]

@@ -194,7 +194,7 @@ _DSV32_PAGES = 32768 * 16 * (640 + 128) * 2 * 6  # latent and indexer pages, six
 
 
 def _family_step(topo, config_file: str, pages_bytes: int, traced=None, prompt_tokens=None,
-                 state_slots: int = 0, **static_kw):
+                 state_slots: int = 0, window=(), **static_kw):
     """``compiled(decode)``: the whole step of a configuration file of the
     latent or the hybrid family for a described v5e, each program compiled
     once for the tests that share it (which turn the persistent cache off
@@ -204,7 +204,9 @@ def _family_step(topo, config_file: str, pages_bytes: int, traced=None, prompt_t
     on the chip, where the family's defaults are not those.  ``prompt_tokens``:
     the prompt program's token bucket (the configuration's ``prefill_chunk``
     unless given).  ``state_slots``: the slots of a family whose state lives
-    in slots beside the pages (the prompt program then names a row's)."""
+    in slots beside the pages (the prompt program then names a row's).
+    ``window``: (pages of the window pool, a row's window table) of a family
+    with layers that keep a window (the step then carries the window side)."""
     import functools
     import json
     import os
@@ -232,14 +234,17 @@ def _family_step(topo, config_file: str, pages_bytes: int, traced=None, prompt_t
         cache = on_chip(jax.eval_shape(lambda: fam.create_cache(
             mc, serve["num_blocks"], serve["block_size"],
             dtype=jnp.dtype(serve["kv_cache_dtype"]),
-            **({"state_slots": state_slots} if state_slots else {}))))
+            **({"state_slots": state_slots} if state_slots else {}),
+            **({"window_pages": window[0]} if window else {}))))
         assert sum(a.size * a.dtype.itemsize
                    for a in jax.tree_util.tree_leaves(cache)) == pages_bytes
         S, PP = serve["max_batch"], serve["max_model_len"] // serve["block_size"]
         T = S if decode else prompt_tokens or serve["prefill_chunk"]
         i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)  # noqa: E731
         rb = RaggedBatch(i32(T), i32(T), i32(T), i32(S), i32(S, PP), i32(S + 1), i32(1),
-                         state_slots=i32(S, 3) if state_slots and not decode else None)
+                         state_slots=i32(S, 3) if state_slots and not decode else None,
+                         **(dict(window_indices=i32(S, window[1]), window_lens=i32(S),
+                                 window_slots=i32(T)) if window else {}))
         traced_args = {k: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
                        for k, (shape, dtype) in (traced or {}).items()}
         with pytest.MonkeyPatch.context() as mp:
@@ -478,6 +483,68 @@ def test_deepseek_v32_decode_program_attends_in_the_kernel_and_gathers_nothing(
     assert "[32768,640]" not in text and "s32[32768]" not in text
     layer_latent = 32768 * 16 * 640 * 2
     assert compiled.memory_analysis().temp_size_in_bytes < layer_latent, compiled.memory_analysis()
+
+
+_EXAONE_KV = 2 * 32768 * 16 * 2 * 8 * 128  # int8 K/V of the two full-attention layers
+_EXAONE_WINDOW = 6 * 8192 * 16 * 2 * 8 * 128  # ... and the six window layers' pool
+
+
+@pytest.fixture(scope="module")
+def exaone_step(topo):
+    """chipbench/configs/k-exaone-236b-a23b-8l-ep8.json with the options the
+    engine resolves on a TPU, its window pool of 8192 pages and a window
+    table of 41 pages a row (models/family.py ``window_pool``)."""
+    return _family_step(
+        topo, "chipbench/configs/k-exaone-236b-a23b-8l-ep8.json", _EXAONE_KV + _EXAONE_WINDOW,
+        traced={"kv_scale": ((8,), jnp.float32)}, window=(8192, 41), attn_impl="tpu",
+        decode_kernel="pallas_fused", prefill_kernel="pallas")
+
+
+@pytest.mark.parametrize("decode", [False, True], ids=["unified-512", "decode-16"])
+def test_k_exaone_step_compiles_at_the_cells_shapes_and_names_its_window_calls(
+    exaone_step, no_persistent_cache, decode
+):
+    """chipbench/configs/k-exaone-236b-a23b-8l-ep8.json: 6 GB of weights (8
+    layers, 16 of 128 experts, an eighth of the vocabulary), 32768 K/V pages
+    of two full layers and 8192 window pages of six, a 512-token chunk (or 16
+    decode rows).  Both pools are updated in place (no copy of either into or
+    out of the step).  The full layers attend in ``fused_*_attention``, the
+    window layers in ``window_*_attention``: names of their own in the device
+    trace, so that ``attn_decode_time_share`` / ``attn_prefill_time_share``
+    (``^fused_...``) read the full layers' calls and count no window call, and
+    the ``swa_window_*`` patterns read the window calls alone."""
+    import json
+    import os
+    import re
+
+    compiled = exaone_step(decode)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _EXAONE_KV + _EXAONE_WINDOW
+    assert mem.temp_size_in_bytes < 1.0e9, mem
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13.5e9, mem
+    calls = _custom_calls(compiled.as_text())
+    phase = "decode" if decode else "prefill"
+    full = [ln for ln in calls if f"fused_{phase}_attention" in ln]
+    win = [ln for ln in calls if f"window_{phase}_attention" in ln]
+    assert (len(full), len(win)) == (2, 6), calls
+    assert len([ln for ln in calls if "moe_grouped_matmul" in ln]) == 14, calls
+    assert len(full) + len(win) + 14 == len(calls), calls
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def pattern(metric):
+        with open(os.path.join(root, f"chipbench/layer_metrics/{metric}.json")) as f:
+            return re.compile(json.load(f)["args"]["pattern"])
+
+    names = _op_names(compiled.as_text())
+    old, new = pattern(f"attn_{phase}_time_share"), pattern(f"swa_window_{phase}_time_share")
+    matched_old = {n for n in names if old.search(n)}
+    matched_new = {n for n in names if new.search(n)}
+    assert matched_old and matched_new and not matched_old & matched_new
+    assert all(n.startswith(f"fused_{phase}_attention") for n in matched_old), matched_old
+    assert all(n.startswith(f"window_{phase}_attention") for n in matched_new), matched_new
+    other = "prefill" if decode else "decode"
+    for metric in (f"attn_{other}_time_share", f"swa_window_{other}_time_share"):
+        assert not {n for n in names if pattern(metric).search(n)}
 
 
 def _custom_calls(text: str) -> list:
