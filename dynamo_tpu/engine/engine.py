@@ -1897,6 +1897,8 @@ class TpuEngine(
         and a second of a session is counted once.  0.0 when no session has
         run.  ``phases`` is the account itself: histogram rows of the loop's
         tiling phases and of the worker threads' device calls."""
+        from ..ops.decode_attention import built_operands
+
         wall = self.pipeline_wall_s
         gap = (
             min(1.0, max(0.0, wall - self.pipeline_waited_s) / wall)
@@ -1906,6 +1908,12 @@ class TpuEngine(
         return {
             "kinds": self.step_summary(),
             "decode_kernel": self.decode_kernel,
+            # What the fused kernel's dots take, as the kernel built in
+            # this process chose from its q and pages (None: it serves no
+            # program here).
+            "decode_kernel_operands": (
+                built_operands() if self.decode_kernel == "pallas_fused" else None
+            ),
             "prefill_kernel": self.prefill_kernel,
             "device": self.device_summary(),
             "prefill": self.prefill_summary(),

@@ -689,6 +689,17 @@ class EngineDispatchMetrics:
             lines.append(
                 f'{ns}_decode_kernel_info{{kernel="{escape_label(kern)}"}} 1'
             )
+        # What its dots take (set where the kernel is built:
+        # ops/decode_attention.py operand_dtype).
+        operands = s.get("decode_kernel_operands", "")
+        if operands:
+            lines.append(f"# HELP {ns}_decode_kernel_operands_info Operand "
+                         "type of the fused decode kernel's two dots")
+            lines.append(f"# TYPE {ns}_decode_kernel_operands_info gauge")
+            lines.append(
+                f'{ns}_decode_kernel_operands_info'
+                f'{{operands="{escape_label(operands)}"}} 1'
+            )
         pkern = s.get("prefill_kernel", "")
         if pkern:
             lines.append(f"# HELP {ns}_prefill_kernel_info Active prefill "

@@ -7,15 +7,21 @@ around the call (q pre-scaled, output post-scaled — models/llama.py) and
 the decode step's dominant HBM stream still rides a generic mixed
 prefill/decode kernel.  This kernel is specialised for the one shape the
 fused decode program dispatches — ONE query token per row, identity row map
-(``ragged_decode_attention``); whether it beats the stock kernel is not
-measured on this machine:
+(``ragged_decode_attention``); at the benchmark cells' shapes it runs 1.7 to
+6 times faster than the stock kernel (chip sweep: docs/decode_kernel.md):
 
-1. **Fused dequant**: int8/fp8 KV pages are DMA'd quantized and scaled by
-   ``kv_scale`` in VMEM right before the QK/AV dots — the KV stream is
-   read from HBM ONCE at 1 byte/value and never materialized dequantized.
-   The scale is an SMEM scalar operand, so per-layer TRACED calibration
-   scales work natively (the stock kernel's k_scale/v_scale must be static
-   floats, which is why dequant lived outside it).
+1. **Fused dequant**: int8/fp8 KV pages are DMA'd quantized and reach the
+   QK/AV dots as bf16, the narrowest type the matrix units take that
+   holds every such value exactly; ``kv_scale`` multiplies the logits
+   (with ``sm_scale``) and the program's output, never a cached value
+   (``q·(K·s) = (q·K)·s``, ``p·(V·s) = (p·V)·s``) — the KV stream is read
+   from HBM ONCE at 1 byte/value and never materialized dequantized.
+   int8 pages are read as the 32-bit WORDS they are stored as, so a shift
+   pair a byte takes a head's rows out with no transposition
+   (``_make_kernel``).  The scale is an SMEM scalar operand, so per-layer
+   TRACED calibration scales work natively (the stock kernel's
+   k_scale/v_scale must be static floats, which is why dequant lived
+   outside it).
 2. **Work follows the live pages**: a row's program walks
    ``cdiv(live pages, ppcb)`` compute blocks of a few hundred positions
    and starts ONE page copy per page the row has — a short row, and a
@@ -43,6 +49,7 @@ from __future__ import annotations
 
 import json
 import logging
+import math
 import os
 from typing import Any, Dict, Optional
 
@@ -191,12 +198,61 @@ def _default_ppcb(page_size: int, kv2: int, head_dim: int, itemsize: int) -> int
 
 # ------------------------------------------------------------------ kernel
 
+P_PIECES = 3  # bf16 pieces the float32 softmax weights enter the AV dot as
+
+# Operands of the last kernel built in this process ("bf16" | "float32"):
+# what /metrics reports beside the kernel's name (llm/metrics.py).
+_BUILT_OPERANDS: Optional[str] = None
+
+
+def operand_dtype(q_dtype, pages_dtype):
+    """The type K, V and q enter the dots in: bf16 where it holds every
+    value exactly (pages one byte wide, int8 or fp8, or bf16 themselves,
+    under a bf16 ``q``) — a product of two such values is exact in the
+    float32 accumulator.  Nothing is given up for it: a float32 dot in a
+    Mosaic kernel is ONE bf16 pass on a v5e that ROUNDS both operands
+    (measured: docs/decode_kernel.md), so handing the matrix units exact
+    bf16 is the more precise form, not the cheaper one.  float32 otherwise
+    (the tests' oracle shapes)."""
+    pages_dtype = jnp.dtype(pages_dtype)
+    exact = pages_dtype == jnp.bfloat16 or pages_dtype.itemsize == 1
+    if exact and jnp.dtype(q_dtype) == jnp.bfloat16:
+        return jnp.dtype(jnp.bfloat16)
+    return jnp.dtype(jnp.float32)
+
+
+def built_operands() -> Optional[str]:
+    return _BUILT_OPERANDS
+
+
+def _bf16_pieces(x, n: int):
+    """``n`` bf16 arrays that sum to float32 ``x`` to within 2**(-8n) of
+    it: each takes the leading 8 bits of what the others left."""
+    pieces = []
+    for i in range(n):
+        piece = x.astype(jnp.bfloat16)
+        pieces.append(piece)
+        if i + 1 < n:
+            x = x - piece.astype(jnp.float32)
+    return pieces
+
+
+def word_rows(pages_dtype, kv2: int) -> int:
+    """32-bit words a cached position's ``2KV`` rows make (the WORD VIEW of
+    int8 pages), or 0 where the view does not apply (another page type, or
+    ``2KV`` no multiple of 4): ``_make_kernel``."""
+    if jnp.dtype(pages_dtype) == jnp.int8 and kv2 % 4 == 0:
+        return kv2 // 4
+    return 0
+
 
 def _make_kernel(
     *,
     sm_scale: float,
-    num_kv: int,
-    group: int,
+    operands,
+    groups: int,
+    rows: int,
+    words: int,
     head_dim: int,
     page_size: int,
     pages_per_seq: int,
@@ -216,8 +272,54 @@ def _make_kernel(
     context only (its own among them).  The caller's table then BEGINS at
     the first page the window reaches (``fused_decode_attention``), so the
     walk is over the window's pages and this is one more term of the mask.
+
+    ``operands`` (``operand_dtype``): the type of both dots' operands.
+    Every scalar factor is applied on the small side: ``sm_scale *
+    kv_scale`` to the logits, ``kv_scale`` to the program's output
+    (``alpha * acc`` is linear, so the running carry needs nothing else);
+    the cached block is converted, never multiplied.
+
+    A block's work is ``groups`` pairs of dots over ``rows`` query rows
+    each (``q_ref`` holds them in that order: the wrapper's):
+
+    - ``words`` 0: a group is one K/V head and its ``G`` query heads; the
+      block is transposed heads-first while page-dtype wide.
+    - ``words`` J > 0 (int8 pages, ``word_rows``): the block is read as
+      the 32-bit WORDS it is stored as.  A position's ``2KV`` int8 rows lie
+      four to a word, J words a position, so byte ``b`` of all words is
+      ``[C * J, D]``: row ``(position, j)`` holds page row ``4 j + b`` — K
+      (b even) or V (b odd) of head ``2 j + b // 2``.  ONE shift pair a
+      byte yields a dot's operand with NO transposition (the int8
+      transposition was two fifths of the kernel's compute; chip sweep,
+      docs/decode_kernel.md).  The two groups are the byte pairs (0, 1)
+      and (2, 3); a group's J heads share its dots, ``rows`` = J x the
+      padded ``G``, and a logit whose column is another head's position is
+      masked like one past the row's end (its softmax weight 0 also keeps
+      that head's V out of the sum).  The matrix units see every cached
+      value once either way; only the small side grows.
     """
     C = ppcb * page_size  # context positions per compute block
+    cols = C * max(words, 1)  # columns of a group's logits
+    heads = groups * rows
+    bf16 = operands == jnp.bfloat16
+    AV = (((1,), (0,)), ((), ()))
+
+    def av(p, v):
+        """``p @ v`` with float32 ``p`` [rows, cols].  Under bf16 operands
+        p is small and meets the SAME exact V as bf16 pieces that sum to
+        it, smallest first; the large operand is never split.  The pieces
+        are stacked along rows (whole bf16 tiles: the wrapper's padding)
+        into ONE dot, so V enters the matrix units once."""
+        if not bf16:
+            return jax.lax.dot_general(p, v, AV, preferred_element_type=jnp.float32)
+        stacked = jax.lax.dot_general(
+            jnp.concatenate(_bf16_pieces(p, P_PIECES), axis=0), v, AV,
+            preferred_element_type=jnp.float32,
+        )  # [P_PIECES * rows, D]
+        out = stacked[(P_PIECES - 1) * rows :]
+        for n in reversed(range(P_PIECES - 1)):
+            out = out + stacked[n * rows : (n + 1) * rows]
+        return out
 
     def kernel(
         # scalar prefetch (SMEM)
@@ -225,13 +327,13 @@ def _make_kernel(
         page_indices_ref,  # [S, PP] int32
         num_seqs_ref,  # [1] int32
         # operands
-        q_ref,  # [1, H, D] VMEM (row s)
+        q_ref,  # [1, heads, D] VMEM (row s), rows in the groups' order
         pages_ref,  # [P, ps, 2KV, D] HBM/ANY — DMA'd manually
         scale_ref,  # [1, 1] f32 SMEM — kv_scale (traced OK)
         # outputs (VMEM blocks at (s, j))
-        o_ref,  # [1, 1, H, D] f32 — unnormalized sum(p·V)
-        m_ref,  # [1, 1, H, 1] f32 — split max
-        l_ref,  # [1, 1, H, 1] f32 — split sum(exp)
+        o_ref,  # [1, 1, heads, D] f32 — unnormalized sum(p·V)
+        m_ref,  # [1, 1, heads, 1] f32 — split max
+        l_ref,  # [1, 1, heads, 1] f32 — split sum(exp)
         # scratch
         kv_buf,  # [2, ppcb, ps, 2KV, D] pages dtype
         sems,  # DMA semaphores (2,)
@@ -266,9 +368,9 @@ def _make_kernel(
 
         # Inactive programs still own their out blocks: neutral partials
         # (o=0, m=NEG_INF, l=0) vanish in the LSE combine.
-        o_ref[0, 0] = jnp.zeros((num_kv * group, head_dim), jnp.float32)
-        m_ref[0, 0] = jnp.full((num_kv * group, 1), NEG_INF, jnp.float32)
-        l_ref[0, 0] = jnp.zeros((num_kv * group, 1), jnp.float32)
+        o_ref[0, 0] = jnp.zeros((heads, head_dim), jnp.float32)
+        m_ref[0, 0] = jnp.full((heads, 1), NEG_INF, jnp.float32)
+        l_ref[0, 0] = jnp.zeros((heads, 1), jnp.float32)
 
         def fetch(block, slot, start):
             # One DMA per LIVE page of the block: page ids are arbitrary
@@ -309,7 +411,23 @@ def _make_kernel(
         def _():
             nblocks = pl.cdiv(pages_here, ppcb)
             fetch(0, 0, start=True)
-            scale = scale_ref[0, 0]
+            kv_scale = scale_ref[0, 0]
+            logit_scale = sm_scale * kv_scale
+            # The model's q, unscaled, sliced once a program.
+            q_all = q_ref[0].astype(operands)  # [heads, D]
+            q_groups = [q_all[g * rows : (g + 1) * rows] for g in range(groups)]
+            # The block position each logits column stands for, and (word
+            # view) whether the column's head is the row's.
+            col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+            if words:
+                col_pos = col // words
+                own = (
+                    jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1) % words
+                ) == jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0) // (
+                    rows // words
+                )
+            else:
+                col_pos = col
 
             def block_step(b, carry):
                 slot = jax.lax.rem(b, 2)
@@ -319,79 +437,73 @@ def _make_kernel(
                     fetch(b + 1, jax.lax.rem(b + 1, 2), start=True)
 
                 fetch(b, slot, start=False)
-                buf = kv_buf[slot].reshape(C, 2 * num_kv, head_dim)
-                # Heads to the front ONCE a block, while the values are
-                # still page-dtype wide: slicing head h out of
-                # [C, 2KV, D] gathers one sublane from each of C tiles,
-                # and 2KV such slices were most of a block's time (sweep:
-                # PERF.md section 6, PR 26).  Same values into the same
-                # dots — the output is bit-identical.
-                buf = jnp.transpose(buf, (1, 0, 2))  # [2KV, C, D]
-                # Fused dequant: the ONLY f32 materialization of this KV
-                # block is here in VMEM, one compute block at a time.
-                kvf = buf.astype(jnp.float32) * scale
-                pos = (base_page + b * ppcb) * page_size + (
-                    jax.lax.broadcasted_iota(jnp.int32, (1, C), 1)
-                )
-                mask = pos < split_end  # [1, C]
+                if words:
+                    # The scratch as the words it holds: a view, no copy.
+                    block = kv_buf.bitcast(jnp.int32).reshape(2, cols, head_dim)[slot]
+
+                    def operand(r):  # byte r of every word, sign-extended
+                        x = jax.lax.shift_left(block, 24 - 8 * r) if r < 3 else block
+                        x = jax.lax.shift_right_arithmetic(x, 24)
+                        return x.astype(jnp.float32).astype(operands)
+
+                else:
+                    block = kv_buf[slot].reshape(C, -1, head_dim)  # [C, 2KV, D]
+                    # Heads to the front ONCE a block, while the values are
+                    # still page-dtype wide: slicing head h out of
+                    # [C, 2KV, D] gathers one sublane from each of C tiles,
+                    # and 2KV such slices were most of a block's time
+                    # (sweep: PERF.md section 6, PR 26).
+                    block = jnp.transpose(block, (1, 0, 2))  # [2KV, C, D]
+                    # In the dots' operand type, UNSCALED: a conversion
+                    # alone (none at all for bf16 or float32 pages).
+                    block = block.astype(operands)
+
+                    def operand(r):  # page row r of every position
+                        return block[r]
+
+                pos = (base_page + b * ppcb) * page_size + col_pos
+                mask = pos < split_end  # [1, cols]
                 if window is not None:
                     mask &= pos >= kv_len - window
+                if words:
+                    mask = own & mask  # [rows, cols]
                 out = []
-                for h in range(num_kv):
-                    m_h, l_h, acc_h = carry[3 * h], carry[3 * h + 1], carry[3 * h + 2]
-                    k_h = kvf[2 * h]  # [C, D]
-                    v_h = kvf[2 * h + 1]
-                    qf = (
-                        q_ref[0, h * group : (h + 1) * group, :].astype(
-                            jnp.float32
-                        )
-                        * sm_scale
-                    )  # [G, D]
+                for g in range(groups):
+                    m_g, l_g, acc_g = carry[3 * g], carry[3 * g + 1], carry[3 * g + 2]
                     logits = jax.lax.dot_general(
-                        qf,
-                        k_h,
+                        q_groups[g],
+                        operand(2 * g),  # K [cols, D]
                         (((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32,
-                    )  # [G, C]
+                    ) * logit_scale  # [rows, cols]
                     logits = jnp.where(mask, logits, NEG_INF)
                     m_new = jnp.maximum(
-                        m_h, jnp.max(logits, axis=1, keepdims=True)
-                    )  # [G, 1]
+                        m_g, jnp.max(logits, axis=1, keepdims=True)
+                    )  # [rows, 1]
                     # Mask the exp explicitly: a fully-masked block has
-                    # m_new == m_h and exp(NEG_INF - m) can round to a
+                    # m_new == m_g and exp(NEG_INF - m) can round to a
                     # nonzero subnormal only through the mask, never here.
                     p = jnp.where(mask, jnp.exp(logits - m_new), 0.0)
-                    alpha = jnp.exp(m_h - m_new)  # [G, 1]
-                    l_new = alpha * l_h + jnp.sum(p, axis=1, keepdims=True)
-                    acc_new = alpha * acc_h + jax.lax.dot_general(
-                        p,
-                        v_h,
-                        (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32,
-                    )  # [G, D]
+                    alpha = jnp.exp(m_g - m_new)  # [rows, 1]
+                    l_new = alpha * l_g + jnp.sum(p, axis=1, keepdims=True)
+                    acc_new = alpha * acc_g + av(p, operand(2 * g + 1))  # V
                     out.extend((m_new, l_new, acc_new))
                 return tuple(out)
 
             init = []
-            for _h in range(num_kv):
+            for _g in range(groups):
                 init.extend(
                     (
-                        jnp.full((group, 1), NEG_INF, jnp.float32),
-                        jnp.zeros((group, 1), jnp.float32),
-                        jnp.zeros((group, head_dim), jnp.float32),
+                        jnp.full((rows, 1), NEG_INF, jnp.float32),
+                        jnp.zeros((rows, 1), jnp.float32),
+                        jnp.zeros((rows, head_dim), jnp.float32),
                     )
                 )
             final = jax.lax.fori_loop(0, nblocks, block_step, tuple(init))
-            m_all = jnp.concatenate(
-                [final[3 * h] for h in range(num_kv)], axis=0
-            )  # [H, 1]
-            l_all = jnp.concatenate(
-                [final[3 * h + 1] for h in range(num_kv)], axis=0
-            )
-            o_all = jnp.concatenate(
-                [final[3 * h + 2] for h in range(num_kv)], axis=0
-            )  # [H, D]
-            o_ref[0, 0] = o_all
+            m_all = jnp.concatenate(final[0::3], axis=0)  # [heads, 1]
+            l_all = jnp.concatenate(final[1::3], axis=0)
+            o_all = jnp.concatenate(final[2::3], axis=0)  # [heads, D]
+            o_ref[0, 0] = o_all * kv_scale
             m_ref[0, 0] = m_all
             l_ref[0, 0] = l_all
 
@@ -430,6 +542,20 @@ def fused_decode_attention(
       ``MAX_BLOCK_CTX`` positions, fewer where the DYN_DECODE_NKV_MB VMEM
       budget at the PAGE dtype's width holds fewer).
     """
+    out = _attend(
+        q, pages, kv_lens, page_indices, num_seqs, sm_scale=sm_scale,
+        kv_scale=kv_scale, num_kv_splits=num_kv_splits,
+        pages_per_block=pages_per_block, interpret=interpret, window=window,
+    )
+    return out.astype(q.dtype)
+
+
+def _attend(
+    q, pages, kv_lens, page_indices, num_seqs, *, sm_scale, kv_scale=None,
+    num_kv_splits=None, pages_per_block=None, interpret=None, window=None,
+) -> jnp.ndarray:
+    """``fused_decode_attention`` before the result takes ``q``'s type:
+    float32 [S, H, D] (what the precision tests compare)."""
     S, H, D = q.shape
     P, ps, KV2, _ = pages.shape
     KV = KV2 // 2
@@ -450,10 +576,31 @@ def fused_decode_attention(
     splits = pl.cdiv(PP, split_pages)  # drop now-empty tail splits
 
     interpret = pallas_interpret() if interpret is None else interpret
+    global _BUILT_OPERANDS
+    operands = operand_dtype(q.dtype, pages.dtype)
+    _BUILT_OPERANDS = operands.name.replace("bfloat16", "bf16")
+    words = word_rows(pages.dtype, KV2)
+    # The kernel's query rows: ``groups`` dots of ``rows`` rows each.  Word
+    # view: group i (a byte pair) holds the heads 2j + i, j < words; else a
+    # group is one K/V head.  Under bf16 operands G is padded so that a
+    # group's rows are whole bf16 tiles of 16 (what lets p's pieces ride ONE
+    # dot); the padding is zeros — finite logits — and is dropped below.
+    groups, per = (2, words) if words else (KV, 1)
+    tile = 16 // math.gcd(per, 16) if operands == jnp.bfloat16 else 1
+    gp = -(-G // tile) * tile
+    rows = per * gp
+    qk = jnp.pad(
+        q.reshape(S, per, groups, G, D),
+        ((0, 0), (0, 0), (0, 0), (0, gp - G), (0, 0)),
+    )
+    qk = jnp.transpose(qk, (0, 2, 1, 3, 4)).reshape(S, groups * rows, D)
+    HK = groups * rows
     kernel = _make_kernel(
         sm_scale=sm_scale,
-        num_kv=KV,
-        group=G,
+        operands=operands,
+        groups=groups,
+        rows=rows,
+        words=words,
         head_dim=D,
         page_size=ps,
         pages_per_seq=PP,
@@ -470,24 +617,24 @@ def fused_decode_attention(
         grid=(S, splits),
         in_specs=[
             pl.BlockSpec(
-                (1, H, D), lambda s, j, *_: (s, 0, 0), memory_space=pltpu.VMEM
+                (1, HK, D), lambda s, j, *_: (s, 0, 0), memory_space=pltpu.VMEM
             ),
             pl.BlockSpec(memory_space=pl.ANY),  # pages stay in HBM
             pl.BlockSpec(memory_space=pltpu.SMEM),  # kv_scale
         ],
         out_specs=(
             pl.BlockSpec(
-                (1, 1, H, D),
+                (1, 1, HK, D),
                 lambda s, j, *_: (s, j, 0, 0),
                 memory_space=pltpu.VMEM,
             ),
             pl.BlockSpec(
-                (1, 1, H, 1),
+                (1, 1, HK, 1),
                 lambda s, j, *_: (s, j, 0, 0),
                 memory_space=pltpu.VMEM,
             ),
             pl.BlockSpec(
-                (1, 1, H, 1),
+                (1, 1, HK, 1),
                 lambda s, j, *_: (s, j, 0, 0),
                 memory_space=pltpu.VMEM,
             ),
@@ -501,9 +648,9 @@ def fused_decode_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=(
-            jax.ShapeDtypeStruct((S, splits, H, D), jnp.float32),
-            jax.ShapeDtypeStruct((S, splits, H, 1), jnp.float32),
-            jax.ShapeDtypeStruct((S, splits, H, 1), jnp.float32),
+            jax.ShapeDtypeStruct((S, splits, HK, D), jnp.float32),
+            jax.ShapeDtypeStruct((S, splits, HK, 1), jnp.float32),
+            jax.ShapeDtypeStruct((S, splits, HK, 1), jnp.float32),
         ),
         compiler_params=pltpu.CompilerParams(
             # Same headroom as the stock path: the default 16MB scoped
@@ -516,18 +663,20 @@ def fused_decode_attention(
         jnp.asarray(kv_lens, jnp.int32),
         jnp.asarray(page_indices, jnp.int32),
         jnp.asarray(num_seqs, jnp.int32),
-        q,
+        qk,
         pages,
         scale_arr,
     )
     # Flash-Decoding LSE combine over the split axis.  All-masked rows
     # (padding / kv_len 0) have every m == NEG_INF and every l == 0:
     # alpha == 1 but o == 0, so out == 0 — matching the XLA oracle.
-    m = m_part[..., 0]  # [S, J, H]
+    m = m_part[..., 0]  # [S, J, HK]
     l = l_part[..., 0]
-    m_max = jnp.max(m, axis=1)  # [S, H]
-    alpha = jnp.exp(m - m_max[:, None, :])  # [S, J, H]
-    l_tot = jnp.sum(alpha * l, axis=1)  # [S, H]
-    o_tot = jnp.sum(alpha[..., None] * o_part, axis=1)  # [S, H, D]
+    m_max = jnp.max(m, axis=1)  # [S, HK]
+    alpha = jnp.exp(m - m_max[:, None, :])  # [S, J, HK]
+    l_tot = jnp.sum(alpha * l, axis=1)  # [S, HK]
+    o_tot = jnp.sum(alpha[..., None] * o_part, axis=1)  # [S, HK, D]
     out = o_tot / (l_tot[..., None] + 1e-30)
-    return out.astype(q.dtype)
+    # Back to the model's head order, the padding dropped.
+    out = jnp.transpose(out.reshape(S, groups, per, gp, D), (0, 2, 1, 3, 4))
+    return out[:, :, :, :G].reshape(S, H, D)

@@ -1104,6 +1104,18 @@ def test_dispatch_metrics_exported():
             assert (
                 "dynamo_tpu_engine_dispatch_pipeline_sessions_total" in text
             )
+            # What the fused kernel's dots take rides beside its name, and
+            # only where that kernel served.
+            operands = s["decode_kernel_operands"]
+            if s["decode_kernel"] == "pallas_fused":
+                assert operands in ("bf16", "float32"), operands
+                assert (
+                    f'decode_kernel_operands_info{{operands="{operands}"}} 1'
+                    in text
+                )
+            else:
+                assert operands is None
+                assert "decode_kernel_operands_info" not in text
         finally:
             engine_dispatch_metrics.reset()
             await engine.close()

@@ -45,19 +45,25 @@ pytestmark = pytest.mark.decode_kernel
 # --------------------------------------------------------------- parity
 
 
+FP8 = getattr(jnp, "float8_e4m3fn", None)  # where the backend has it
+
+
 def _case(seed, S, PP, ps, KV, G, D, kv_lens_list, nvalid,
-          dtype=jnp.float32, kv_scale=None):
+          dtype=jnp.float32, kv_scale=None, q_dtype=jnp.float32):
     """Ragged decode batch: shuffled page tables, per-row chain lengths,
-    optionally int8-quantized pages stored as value/scale."""
+    optionally quantized pages (int8, fp8) stored as value/scale, or bf16
+    pages; ``q`` in float32 or bf16."""
     keys = jax.random.split(jax.random.PRNGKey(seed), 2)
     H = KV * G
     P = S * PP + 3  # spare pages: tables must be a strict subset
-    q = jax.random.normal(keys[0], (S, H, D), jnp.float32)
+    q = jax.random.normal(keys[0], (S, H, D), jnp.float32).astype(q_dtype)
     vals = jax.random.normal(keys[1], (P, ps, 2 * KV, D), jnp.float32) * 3.0
     if dtype == jnp.int8:
         pages = jnp.clip(jnp.round(vals / kv_scale), -127, 127).astype(jnp.int8)
-    else:
+    elif dtype == jnp.float32:
         pages = vals
+    else:  # fp8 (value/scale, rounded by the cast) or bf16
+        pages = (vals / (kv_scale or 1.0)).astype(dtype)
     kv_lens = np.zeros(S, np.int32)
     kv_lens[: len(kv_lens_list)] = kv_lens_list
     tables = np.asarray(
@@ -65,6 +71,24 @@ def _case(seed, S, PP, ps, KV, G, D, kv_lens_list, nvalid,
     ).reshape(S, PP)
     num = np.asarray([nvalid], np.int32)
     return q, pages, jnp.asarray(kv_lens), jnp.asarray(tables), jnp.asarray(num)
+
+
+def _fused_f32(q, *args, **kw):
+    """The fused kernel's result BEFORE it takes ``q``'s type (float32), so
+    that a bf16 ``q`` is held to the file's tolerance and not to bf16's
+    spacing; the public wrapper is that, cast
+    (test_operands_follow_the_dtypes_the_kernel_sees)."""
+    from dynamo_tpu.ops import decode_attention as da
+
+    return da._attend(q, *args, **{"interpret": True, **kw})
+
+
+def _oracle(q, *args, **kw):
+    """The XLA fallback on the same VALUES in float32 (it casts its result
+    to ``q``'s type too)."""
+    return ragged_decode_attention(
+        q.astype(jnp.float32), *args, impl="xla", **kw
+    )
 
 
 GEOMETRIES = [
@@ -81,24 +105,51 @@ GEOMETRIES = [
     (6, 8, 4, 2, 2, 16, [13, 0, 8, 1, 0, 32], 6, jnp.float32, None),
     (6, 8, 4, 1, 4, 16, [13, 0, 8, 1, 0, 32], 6, jnp.int8, 0.05),
 ]
-
-
-@pytest.mark.parametrize("geom", GEOMETRIES, ids=lambda g: f"S{g[0]}PP{g[1]}")
-@pytest.mark.parametrize(
-    "splits,ppcb", [(1, 1), (2, 2), (3, 1), (4, 2), (1, 2), (1, 3), (2, 3)]
+# The other page types whose values bf16 holds exactly (ISSUE 48).
+MORE_PAGE_TYPES = [
+    (6, 8, 4, 2, 2, 16, [13, 0, 8, 1, 0, 32], 6, jnp.bfloat16, None),
+] + (
+    [(6, 8, 4, 1, 4, 16, [13, 0, 8, 1, 0, 32], 6, FP8, 0.05)] if FP8 else []
 )
-def test_fused_kernel_parity_vs_xla_oracle(geom, splits, ppcb):
+SPLITS_PPCB = [(1, 1), (2, 2), (3, 1), (4, 2), (1, 2), (1, 3), (2, 3)]
+F32, BF16 = jnp.float32, jnp.bfloat16
+
+
+def _parity_cases():
+    """(geom, splits, ppcb, q dtype, window).  The operand axis (ISSUE
+    48): the kernel's dots take bf16 where the pages are one byte wide or
+    bf16 AND ``q`` is bf16, float32 otherwise; every page type meets both
+    ``q`` types, with and without a window, at this file's one tolerance."""
+    cases = [(g, s, b, F32, None) for s, b in SPLITS_PPCB for g in GEOMETRIES]
+    cases += [(g, s, b, F32, None) for s, b in SPLITS_PPCB[-3:]
+              for g in MORE_PAGE_TYPES]
+    for q_dtype, window in ((BF16, None), (BF16, 6), (F32, 6)):
+        cases += [(g, s, b, q_dtype, window) for s, b in ((1, 2), (2, 3))
+                  for g in GEOMETRIES + MORE_PAGE_TYPES]
+    return cases
+
+
+def _parity_id(case):
+    g, splits, ppcb, q_dtype, window = case
+    return (f"{splits}-{ppcb}-S{g[0]}PP{g[1]}n{g[7]}-{jnp.dtype(g[8]).name}"
+            f"-q{jnp.dtype(q_dtype).name}" + (f"-w{window}" if window else ""))
+
+
+@pytest.mark.parametrize("case", _parity_cases(), ids=_parity_id)
+def test_fused_kernel_parity_vs_xla_oracle(case):
+    geom, splits, ppcb, q_dtype, window = case
     S, PP, ps, KV, G, D, lens, nv, dt, scale = geom
     q, pages, kv_lens, tables, num = _case(0, S, PP, ps, KV, G, D, lens, nv,
-                                           dt, scale)
+                                           dt, scale, q_dtype)
     sm = D**-0.5
-    want = ragged_decode_attention(
-        q, pages, kv_lens, tables, num, sm_scale=sm, impl="xla",
-        kv_scale=scale,
+    want = _oracle(
+        q, pages, kv_lens, tables, num, sm_scale=sm, kv_scale=scale,
+        window=window,
     )
-    got = fused_decode_attention(
+    got = _fused_f32(
         q, pages, kv_lens, tables, num, sm_scale=sm, kv_scale=scale,
         num_kv_splits=splits, pages_per_block=ppcb, interpret=True,
+        window=window,
     )
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
@@ -108,6 +159,34 @@ def test_fused_kernel_parity_vs_xla_oracle(geom, splits, ppcb):
     for i in range(S):
         if i >= nv or int(kv_lens[i]) == 0:
             np.testing.assert_array_equal(np.asarray(got)[i], 0.0)
+
+
+def test_operands_follow_the_dtypes_the_kernel_sees():
+    """bf16 exactly where it holds every value of both dots' operands."""
+    from dynamo_tpu.ops.decode_attention import built_operands, operand_dtype
+
+    exact = [jnp.int8, BF16] + ([FP8] if FP8 else [])
+    for pages_dtype in exact:
+        assert operand_dtype(BF16, pages_dtype) == BF16
+        assert operand_dtype(F32, pages_dtype) == F32
+    assert operand_dtype(BF16, F32) == F32
+    for q_dtype, name in ((BF16, "bf16"), (F32, "float32")):
+        q, pages, kv_lens, tables, num = _case(
+            0, 3, 4, 4, 2, 2, 8, [16, 5, 0], 3, jnp.int8, 0.1, q_dtype
+        )
+        args = (q, pages, kv_lens, tables, num)
+        got = fused_decode_attention(
+            *args, sm_scale=1.0, kv_scale=0.1, interpret=True
+        )
+        assert built_operands() == name
+        assert got.dtype == q_dtype
+        np.testing.assert_array_equal(
+            np.asarray(got.astype(F32)),
+            np.asarray(
+                _fused_f32(*args, sm_scale=1.0, kv_scale=0.1)
+                .astype(q_dtype).astype(F32)
+            ),
+        )
 
 
 class _CountingTpu:
@@ -218,31 +297,117 @@ def test_stock_branch_floors_context_and_zeroes_padding_rows(monkeypatch):
     np.testing.assert_array_equal(np.asarray(out)[[1, 3]], 0.0)
 
 
-def test_fused_kernel_traced_scale_under_jit():
+@pytest.mark.parametrize("q_dtype", [F32, BF16], ids=["qf32", "qbf16"])
+@pytest.mark.parametrize(
+    "dtype", [jnp.int8] + ([FP8] if FP8 else []), ids=lambda d: jnp.dtype(d).name
+)
+def test_fused_kernel_traced_scale_under_jit(dtype, q_dtype):
     """The fused kernel's dequant contract: kv_scale is an SMEM operand,
     so a TRACED per-layer calibration scale works without the algebraic
-    q/out fold the stock path needs."""
+    q/out fold the stock path needs — with either operand type."""
     S, PP, ps, KV, G, D = 5, 8, 4, 1, 4, 16
     q, pages, kv_lens, tables, num = _case(
-        0, S, PP, ps, KV, G, D, [32, 0, 5, 17, 2], 5, jnp.int8, 0.05
+        0, S, PP, ps, KV, G, D, [32, 0, 5, 17, 2], 5, dtype, 0.05, q_dtype
     )
     sm = D**-0.5
 
     @jax.jit
     def f(q, pages, s):
-        return fused_decode_attention(
+        return _fused_f32(
             q, pages, kv_lens, tables, num, sm_scale=sm, kv_scale=s,
             num_kv_splits=2, pages_per_block=2, interpret=True,
         )
 
     got = f(q, pages, jnp.float32(0.05))
-    want = ragged_decode_attention(
-        q, pages, kv_lens, tables, num, sm_scale=sm, impl="xla",
-        kv_scale=0.05,
-    )
+    want = _oracle(q, pages, kv_lens, tables, num, sm_scale=sm, kv_scale=0.05)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
     )
+
+
+def _float64_reference(q, pages, kv_lens, tables, sm_scale, kv_scale):
+    """Plain attention a row in numpy float64 over the same stored values."""
+    q, pages = np.asarray(q.astype(F32), np.float64), np.asarray(pages.astype(F32), np.float64)
+    S, H, D = q.shape
+    KV = pages.shape[2] // 2
+    G = H // KV
+    out = np.zeros((S, H, D))
+    for i, n in enumerate(np.asarray(kv_lens)):
+        if n == 0:
+            continue
+        kv = pages[np.asarray(tables)[i]].reshape(-1, 2 * KV, D)[:n] * kv_scale
+        for h in range(KV):
+            logits = (q[i, h * G:(h + 1) * G] * sm_scale) @ kv[:, 2 * h].T
+            p = np.exp(logits - logits.max(axis=1, keepdims=True))
+            out[i, h * G:(h + 1) * G] = (p / p.sum(axis=1, keepdims=True)) @ kv[:, 2 * h + 1]
+    return out
+
+
+def test_bf16_operands_are_no_less_precise_than_float32_ones():
+    """"No lower precision" as a test: on the same int8 pages and the same
+    bf16 ``q`` values, the bf16-operand form (exact K, V and q in the dots,
+    the scales on the logits and the output, p as bf16 pieces) errs against
+    float64 no more than the float32-operand form does (the values as
+    float32 into float32 dots): over six draws together, and in no draw by
+    more than a tenth."""
+    S, PP, ps, KV, G, D = 4, 16, 8, 2, 4, 64
+    lens = [128, 77, 1, 40]
+    sm = D**-0.5
+    kw = dict(sm_scale=sm, kv_scale=0.05, pages_per_block=4)
+    sq_bf16 = sq_f32 = 0.0
+    for seed in range(6):
+        q, pages, kv_lens, tables, num = _case(
+            seed, S, PP, ps, KV, G, D, lens, S, jnp.int8, 0.05, BF16
+        )
+        args = (pages, kv_lens, tables, num)
+        ref = _float64_reference(q, pages, kv_lens, tables, sm, 0.05)
+
+        def mean_sq(got):
+            return float(np.mean((np.asarray(got, np.float64) - ref) ** 2))
+
+        err_bf16 = mean_sq(_fused_f32(q, *args, **kw))
+        err_f32 = mean_sq(_fused_f32(q.astype(F32), *args, **kw))
+        # Both are float32-grade (bf16's spacing would read 1e-5).
+        assert err_f32 < 1e-12 * float(np.mean(ref**2))
+        assert err_bf16 <= 1.1**2 * err_f32, (seed, err_bf16, err_f32)
+        sq_bf16, sq_f32 = sq_bf16 + err_bf16, sq_f32 + err_f32
+    assert sq_bf16 <= sq_f32, (sq_bf16, sq_f32)
+
+
+@pytest.mark.parametrize("dtype", [jnp.int8, BF16], ids=["int8", "bf16"])
+def test_scale_is_folded_onto_logits_and_output(dtype):
+    """The cached block is converted, never multiplied: (a) with the logits
+    held still (sm_scale / 4 against kv_scale * 4) the outputs stand in
+    exactly the scales' ratio; (b) the kernel's program holds no multiply
+    the size of ONE dot's cached operand or larger.  int8 pages take the
+    word view, bf16 pages the transposition."""
+    S, PP, ps, KV, G, D = 3, 16, 4, 2, 2, 32
+    q, pages, kv_lens, tables, num = _case(
+        4, S, PP, ps, KV, G, D, [64, 9, 20], S, dtype, 0.05, BF16
+    )
+    args, sm = (q, pages, kv_lens, tables, num), D**-0.5
+    one = _fused_f32(*args, sm_scale=sm, kv_scale=0.05, pages_per_block=16)
+    four = _fused_f32(*args, sm_scale=sm / 4, kv_scale=0.2, pages_per_block=16)
+    assert float(jnp.max(jnp.abs(one))) > 0.1
+    np.testing.assert_array_equal(np.asarray(four), 4 * np.asarray(one))
+
+    def muls(jaxpr):
+        """Sizes of every product in a jaxpr and the jaxprs inside it."""
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "mul":
+                yield int(np.prod(eqn.outvars[0].aval.shape))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from muls(sub)
+
+    closed = jax.make_jaxpr(
+        lambda *a: fused_decode_attention(
+            *a, sm_scale=sm, kv_scale=0.05, pages_per_block=16, interpret=False
+        )
+    )(*args)
+    (call,) = [e for e in closed.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    sizes = list(muls(call.params["jaxpr"]))
+    C = 16 * ps  # positions a compute block: K of one head is [C, D]
+    assert sizes and max(sizes) < C * D, sizes
 
 
 def test_routed_through_ragged_decode_attention():
@@ -462,12 +627,14 @@ def test_stall_counter_on_metrics():
         lambda: {
             "kinds": {},
             "decode_kernel": "pallas_fused",
+            "decode_kernel_operands": "bf16",
             "pipeline": {"stalls": 3, "host_gap_frac": 0.1},
         }
     )
     text = m.render()
     assert "dynamo_tpu_engine_stall_total 3" in text
     assert 'decode_kernel_info{kernel="pallas_fused"} 1' in text
+    assert 'decode_kernel_operands_info{operands="bf16"} 1' in text
 
 
 # ------------------------------------------------------ autotuner table

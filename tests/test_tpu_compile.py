@@ -21,7 +21,11 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from dynamo_tpu.ops.decode_attention import fused_decode_attention
+from dynamo_tpu.ops.decode_attention import (
+    MAX_BLOCK_CTX,
+    P_PIECES,
+    fused_decode_attention,
+)
 from dynamo_tpu.ops.prefill_attention import fused_prefill_attention
 from dynamo_tpu.ops.ragged_attention import ragged_decode_attention
 
@@ -114,21 +118,76 @@ def test_fused_decode_kernel_compiles(
     )
 
 
+def _mosaic_bodies(text):
+    """The kernels of a compiled program as Mosaic wrote them: each
+    ``tpu_custom_call`` carries its module as base64 MLIR bytecode."""
+    import base64
+    import re
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+
+    ctx = jax_mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True  # the versioned ``stable_mosaic``
+    with ctx:
+        return [
+            str(ir.Module.parse(base64.b64decode(body)))
+            for body in re.findall(r'"body":"([A-Za-z0-9+/=]+)"', text)
+        ]
+
+
+@pytest.mark.parametrize(
+    "model,rows_,ctx,heads,pages",
+    [
+        # chipbench/configs/qwen2.5-7b.json: 32 rows, 256 pages a row,
+        # 12288 int8 pages a layer.
+        ("qwen2.5-7b", 32, 4096, (28, 4), 28 * 12288),
+        # chipbench/configs/k-exaone-236b-a23b-8l-ep8.json, its two full
+        # layers: 16 rows, 832 pages a row, 2KV 16, 32768 int8 pages a layer.
+        ("k-exaone-236b-a23b-8l-ep8", 16, 13312, (64, 8), 2 * 32768),
+    ],
+)
 def test_fused_decode_kernel_compiles_at_the_cells_shape(
-    sds, no_persistent_cache
+    sds, no_persistent_cache, model, rows_, ctx, heads, pages
 ):
-    """The benchmark cells' decode shape (chipbench/configs/qwen2.5-7b.json):
-    32 rows, 256 pages a row, 12288 int8 pages a layer.  With the built-in
-    defaults the call has ONE split, and keeps the name the trace readers
-    find it by."""
-    H, _ = GEOMETRY["qwen2.5-7b"]
+    """The benchmark cells' decode shapes.  With the built-in defaults the
+    call has ONE split and keeps the name the trace readers find it by; the
+    LARGE operand of both dots, the cached block, is bf16 and unscaled
+    (ISSUE 48), and p meets V as P_PIECES stacked bf16 pieces in ONE dot."""
+    import re
+
+    H, KV = heads
     text = _compile(
         _fused_decode,
-        *_decode_shapes(sds, "qwen2.5-7b", "int8", rows=32, pages=28 * 12288),
+        sds((rows_, H, D), jnp.bfloat16),
+        sds((pages, PS, 2 * KV, D), jnp.int8),
+        sds((rows_,), jnp.int32),
+        sds((rows_, ctx // PS), jnp.int32),
+        sds((1,), jnp.int32),
         sds((), jnp.float32),
     )
     assert "fused_decode_attention" in text
-    assert f"f32[32,1,{H},{D}]" in text
+    # The word view: two groups of dots over J = 2KV / 4 heads each, their
+    # query rows padded to whole tiles (here 8 a head).
+    J = 2 * KV // 4
+    rows = J * 8
+    assert f"f32[{rows_},1,{2 * rows},{D}]" in text
+    (body,) = _mosaic_bodies(text)
+    cached = f"vector<{MAX_BLOCK_CTX * J}x{D}xbf16>"  # a dot's K or V
+    dots = re.findall(r"tpu\.matmul.*?: \((vector<[^>]+>), (vector<[^>]+>)", body)
+    assert dots == 2 * [
+        (f"vector<{rows}x{D}xbf16>", cached),  # q K^T
+        (f"vector<{P_PIECES * rows}x{MAX_BLOCK_CTX * J}xbf16>", cached),  # p V
+    ], dots
+    # The cached side is converted (int32 -> float32 -> bf16, a vreg at a
+    # time) and never multiplied: no product the size of a dot's operand.
+    assert not re.search(
+        rf"arith\.mulf.*vector<{MAX_BLOCK_CTX * J}x{D}xf32>", body
+    )
+    # No transposition and no byte shuffle of the block: the int8 rows are
+    # taken out of the stored words by shifts.
+    assert "vector.transpose" not in body and "tpu.transpose" not in body
+    assert "arith.shrsi" in body
 
 
 @pytest.mark.parametrize("page_dtype", ["int8", "bfloat16"])
