@@ -1561,6 +1561,7 @@ def child_parity_granite(rehearse: bool) -> None:
 
     from dynamo_tpu.engine.config import EngineConfig
     from dynamo_tpu.engine.engine import TpuEngine
+    from dynamo_tpu.engine.resume import Beside
     from dynamo_tpu.models import lfm2
     from dynamo_tpu.models.config import ModelConfig, register_config
     from dynamo_tpu.models.family import RaggedBatch
@@ -1591,14 +1592,14 @@ def child_parity_granite(rehearse: bool) -> None:
     mc, fam = engine.model_config, engine.family
     emit("granite_engine", t0, **dev, attn_impl=engine.attn_impl,
          decode_kernel=engine.decode_kernel, prefill_kernel=engine.prefill_kernel,
-         slots=[engine.kv.live_slots, engine.kv.snapshot_slots],
+         slots=[engine.kv.beside.live.size, engine.kv.beside.snapshots.size],
          hbm=(jax.local_devices()[0].memory_stats() or {}).get("bytes_in_use"))
 
     bs, S, PP, chunk = cfg.block_size, cfg.max_batch, cfg.max_blocks_per_seq, cfg.prefill_chunk
     steps = cfg.decode_steps
     n_prefix, n_prompt, n_dec = par["prefix"], par["prompt"], par["decode"]
     assert n_prefix % chunk == 0 and n_dec % steps == 0 and n_prefix % bs == 0
-    assert engine.kv.snapshot_slots >= 1
+    assert engine.kv.beside.snapshots.size >= 1
     T = n_prompt + n_dec
     own = -(-T // bs) + 1  # pages a row needs
     assert 4 * own <= cfg.num_blocks and own <= PP
@@ -1623,11 +1624,11 @@ def child_parity_granite(rehearse: bool) -> None:
         (read, write, snapshot) slots, or None: row 0 lives in slot 0."""
         seq = types.SimpleNamespace(prompt=[int(t) for t in tokens[:start + n]], output=[],
                                     block_ids=[int(x) for x in table], adapter_slot=-1)
-        live, engine.kv.live_slots = engine.kv.live_slots, 0  # the slots are named here
+        kind, engine.kv.beside = engine.kv.beside, Beside()  # the slots are named here
         try:
             rb = engine._build_ragged([(seq, start, n)])
         finally:
-            engine.kv.live_slots = live
+            engine.kv.beside = kind
         state = np.full((S, 3), -1, np.int32)
         state[0] = slots if slots is not None else (0 if start else -1, 0, -1)
         return rb._replace(state_slots=state)
@@ -1958,10 +1959,11 @@ def child_parity_k_exaone(rehearse: bool) -> None:
         weight_quant=serve.get("weight_quant"), seed=20260900 + seed)
     engine = TpuEngine(cfg)
     mc, fam, kv = engine.model_config, engine.family, engine.kv
-    W, wb = mc.sliding_window, kv.window_blocks
+    kind = kv.beside  # engine/resume.py ``WindowPages``: the rows below hold pages as its rows do
+    W, wb, wpool = mc.sliding_window, kind.blocks, kind.pool
     emit("k_exaone_engine", t0, **dev, attn_impl=engine.attn_impl,
          decode_kernel=engine.decode_kernel, prefill_kernel=engine.prefill_kernel,
-         window_pool=[kv.window_pages, kv.window_tokens, kv.window_row_pages],
+         window_pool=[wpool.size, kind.tokens, kind.row_pages],
          hbm=(jax.local_devices()[0].memory_stats() or {}).get("bytes_in_use"))
 
     bs, S, PP, chunk = cfg.block_size, cfg.max_batch, cfg.max_blocks_per_seq, cfg.prefill_chunk
@@ -1980,11 +1982,11 @@ def child_parity_k_exaone(rehearse: bool) -> None:
         K/V pages (the first ``shared`` of them row A's) and no window page yet."""
         table = list(range(shared)) + [first + i for i in range(own - shared)]
         return types.SimpleNamespace(prompt=[], output=[], block_ids=table, adapter_slot=-1,
-                                     window_ids=[], window_base=0, num_computed=0)
+                                     beside=types.SimpleNamespace(ids=[], base=0), num_computed=0)
 
     def prefill_batch(seq, start, n):
         """The engine's own batch of one prompt row (window pages taken and
-        given back by ``Scheduler.window_span`` as for a running row)."""
+        given back by ``WindowPages.grow`` as for a running row)."""
         seq.prompt, seq.num_computed = [int(t) for t in tokens[:start + n]], start
         return engine._build_ragged([(seq, start, n)])
 
@@ -1994,13 +1996,11 @@ def child_parity_k_exaone(rehearse: bool) -> None:
         that hold nothing instead: the control)."""
         seq = row(first, n_prefix // bs)
         at = n_prefix // bs
-        seq.window_base = max(0, at - wb)
+        seq.beside.base = max(0, at - wb)
         if zeros:
-            seq.window_ids = [kv.take_window_page() for _ in range(at - seq.window_base)]
-        else:
-            seq.window_ids = list(kept_pages)
-            for p in seq.window_ids:
-                kv._win_ref(p, rows=1)
+            seq.beside.ids = [wpool.take() for _ in range(at - seq.beside.base)]
+        else:  # as a hit does: the pages kept with the block that ends at the point
+            seq.beside.ids = list(wpool.resume([at - 1])[1])
         return seq
 
     samp = engine._sampling_arrays([])._replace(need_logprobs=np.asarray(True))
@@ -2010,16 +2010,13 @@ def child_parity_k_exaone(rehearse: bool) -> None:
     t1 = time.time()
     params, cache = engine.params, engine.cache
     top = {"A": {}, "B": {}}  # position -> (token, top ids, their log-probabilities)
-    row_a, kept_pages, pages_held = row(0), None, []
+    row_a, pages_held = row(0), []
     for a, n in chunks(0):
         out, cache = engine._step_fn(params, cache, prefill_batch(row_a, a, n), samp)
-        pages_held.append(len(row_a.window_ids))
-        if a + n == n_prefix:  # what a retained entry keeps: the pages before the point
-            at = n_prefix // bs
-            kept_pages = row_a.window_ids[max(0, at - wb) - row_a.window_base:
-                                          at - row_a.window_base]
-            for p in kept_pages:
-                kv._win_ref(p, kept=1)
+        pages_held.append(len(row_a.beside.ids))
+        if a + n == n_prefix:  # what a kept entry holds: the pages before the point
+            at, held = n_prefix // bs, row_a.beside
+            assert wpool.keep(at - 1, held.ids[max(0, at - wb) - held.base:at - held.base])
         top["A"][a + n - 1] = (int(np.asarray(out.tokens)[0]), np.asarray(out.top_ids)[0],
                                np.asarray(out.top_logprobs)[0])
     row_b = resumed(own)
@@ -2030,7 +2027,7 @@ def child_parity_k_exaone(rehearse: bool) -> None:
     tokens[n_prompt] = top["A"][n_prompt - 1][0]
     pos0 = np.full((S,), -1, np.int32)
     tables, limits = np.zeros((S, PP), np.int32), np.zeros((S,), np.int32)
-    wtables = np.zeros((S, kv.window_row_pages), np.int32)
+    wtables = np.zeros((S, kind.row_pages), np.int32)
     tok0 = np.zeros((S,), np.int32)
     for i, (name, seq) in enumerate((("A", row_a), ("B", row_b))):
         pos0[i], limits[i], tok0[i] = n_prompt, own * bs, top[name][n_prompt - 1][0]
@@ -2040,9 +2037,9 @@ def child_parity_k_exaone(rehearse: bool) -> None:
         at = pos0 + np.where(pos0 >= 0, d * steps, 0)
         for i, seq in enumerate((row_a, row_b)):
             seq.num_computed = int(at[i])
-            engine.scheduler.window_span(seq, int(at[i]) + steps)
-            engine._window_row(wtables, i, seq, int(at[i]))
-        pages_held.append(len(row_a.window_ids))
+            kind.grow(seq, int(at[i]) + steps)
+            kind.table_row(wtables, i, seq, int(at[i]))
+        pages_held.append(len(row_a.beside.ids))
         outs, last, steps_f, counts_f, cache = engine._multi_fn(
             params, cache, *carry, at, (tables, wtables.copy()), limits, samp)
         carry = (last, steps_f, counts_f)
@@ -2069,15 +2066,15 @@ def child_parity_k_exaone(rehearse: bool) -> None:
 
     def decode_batch(seq, p):
         seq.num_computed = p
-        engine.scheduler.window_span(seq, p + 1)
+        kind.grow(seq, p + 1)
         t, ps_, kvl, wl = (np.zeros((S,), np.int32) for _ in range(4))
         sl, wsl = np.full((S,), -1, np.int32), np.full((S,), -1, np.int32)
-        tb, wt = np.zeros((S, PP), np.int32), np.zeros((S, kv.window_row_pages), np.int32)
-        base = engine._window_row(wt, 0, seq, p)
+        tb, wt = np.zeros((S, PP), np.int32), np.zeros((S, kind.row_pages), np.int32)
+        base = kind.table_row(wt, 0, seq, p)
         t[0], ps_[0], kvl[0], wl[0] = tokens[p], p, p + 1, p + 1 - base * bs
         tb[0, :own] = seq.block_ids
         sl[0] = int(seq.block_ids[p // bs]) * bs + p % bs
-        wsl[0] = int(seq.window_ids[p // bs - seq.window_base]) * bs + p % bs
+        wsl[0] = int(seq.beside.ids[p // bs - seq.beside.base]) * bs + p % bs
         return RaggedBatch(t, ps_, sl, kvl, tb, np.arange(S + 1, dtype=np.int32),
                            np.asarray([S], np.int32), window_indices=wt, window_lens=wl,
                            window_slots=wsl)
@@ -2096,7 +2093,7 @@ def child_parity_k_exaone(rehearse: bool) -> None:
         for p in range(n_prompt, T):
             logits, cache = fwd(params, cache, decode_batch(seq, p), True)
             out.append(np.asarray(logits, np.float32)[0])
-        kv.release_window(seq.window_ids)
+        wpool.release(seq.beside.ids)
         return np.stack(out), cache
 
     compare = np.asarray([a + n - 1 for a, n in pieces(0)] + list(range(n_prompt, T)))
@@ -2121,7 +2118,7 @@ def child_parity_k_exaone(rehearse: bool) -> None:
                     for g, v in params.items() if g not in ("attn", "moe", "shared")}
         params_1["attn"] = {k: a[:0] for k, a in params["attn"].items()}
         cache_1 = fam.create_cache(mc_1, cfg.num_blocks, bs, dtype=cache.pages.dtype,
-                                   window_pages=kv.window_pages)
+                                   **kind.cache_kw)
         # The leading layer is the first WINDOW layer: its scale and its gain.
         La, n_attn = lfm2.layer_counts(mc)[1], len(mc.layer_types)
         scale_1 = None if engine.kv_scale is None else np.asarray(engine.kv_scale)[[La, n_attn + La]]

@@ -153,19 +153,19 @@ class TpuEngine(
                 "attention kernels need at least 2 — use a smaller tp or "
                 "bfloat16 pages"
             )
-        # State slots of a family whose recurrent state lives beside the pages
-        # (models/mamba2.py): live ones for the running rows, and snapshots.
-        live, snaps = fam.state_slots(model_config, cfg) if fam.state_slots else (0, 0)
-        # ... and a family with window layers its second page pool.
-        win_pages, win_tokens, win_row = (
-            fam.window_pool(model_config, cfg) if fam.window_pool else (0, 0, 0))
+        # What the family keeps beside the K/V pages (recurrent state in slots,
+        # window layers' pages: engine/resume.py) is ONE object under the block
+        # manager: the scheduler and the step builder ask it, and the cache is
+        # made with its pools' sizes.
         self.kv = KvBlockManager(
             cfg.num_blocks, cfg.block_size, event_callback=event_callback,
             enable_prefix_caching=cfg.enable_prefix_caching,
-            live_slots=live, snapshot_slots=snaps,
-            window_pages=win_pages, window_tokens=win_tokens, window_row_pages=win_row,
+            beside=fam.beside(model_config, cfg) if fam.beside else None,
         )
-        self.scheduler = Scheduler(cfg, self.kv, resume=fam.resume)
+        self.scheduler = Scheduler(cfg, self.kv)
+        pools = self.kv.beside.cache_kw
+        # (The fused program finds a window layer's first block from these.)
+        win_pages, win_tokens = pools.get("window_pages", 0), model_config.sliding_window
         # Draft-free speculative decoding (engine/spec.py): None = off.
         self._spec_ctl = (
             AcceptanceController(cfg.spec_decode)
@@ -450,8 +450,8 @@ class TpuEngine(
         # what it calls costs every configuration one cold start.)
         make_cache = partial(
             fam.create_cache, self.model_config, cfg.num_blocks, cfg.block_size,
-            dtype=jnp.dtype(cfg.cache_dtype), **({"state_slots": live + snaps} if live else {}),
-            **({"window_pages": win_pages} if win_pages else {}),
+            dtype=jnp.dtype(cfg.cache_dtype),
+            **pools,
         )
         if self.mesh is None:
             cache = make_cache()
@@ -718,9 +718,9 @@ class TpuEngine(
         T = min(128, (cfg.max_blocks_per_seq - 1) * cfg.block_size)
         nb = (T + cfg.block_size - 1) // cfg.block_size + 1
         fam = self.family
-        windowed = self.kv.window_pages > 0  # its probe pages: the same nb, window and all
         probe = fam.create_cache(mc, nb, cfg.block_size, dtype=jnp.bfloat16,
-                                 **({"window_pages": nb} if windowed else {}))
+                                 **self.kv.beside.probe_kw(nb))
+        windowed = getattr(probe, "window", None) is not None  # a second K/V array: nb pages too
         if self.mesh is not None:
             probe = shard_tree(probe, fam.cache_pspec(), self.mesh)
         toks = ((np.arange(T) * 2654435761) % mc.vocab_size).astype(np.int32)
@@ -993,9 +993,9 @@ class TpuEngine(
                 cu_q_lens=cu,
                 num_seqs=np.asarray([1], np.int32),
                 adapter_slots=np.full((T,), -1, np.int32) if self._lora_rank else None,
-                # No state slot is read or written either (models/mamba2.py).
-                state_slots=np.full((S, 3), -1, np.int32) if self.kv.live_slots else None,
-                **self._warm_window(T),
+                # Nothing beside the pages is read or written either: the
+                # family's own operands of a step without rows (engine/resume.py).
+                **self.kv.beside.operands((), S, T),
             )
             steps.append((rb, samp))
         multi = None
@@ -1005,23 +1005,23 @@ class TpuEngine(
                 samp.steps,
                 samp.counts,
                 np.full((S,), -1, np.int32),  # every row inactive
-                # (the window tables beside the K/V tables where the family has them)
-                (np.zeros((S, PP), np.int32), self._warm_window(0)["window_indices"])
-                if self.kv.window_pages else np.zeros((S, PP), np.int32),
+                # (the family's second table beside the K/V tables, where it has one)
+                self._warm_tables(np.zeros((S, PP), np.int32)),
+                # (A line kept: frames of this file key the compile cache, see ``make_cache``.)
                 np.zeros((S,), np.int32),
                 samp,
             )
         return steps, multi
 
-    def _warm_window(self, T: int) -> Dict[str, np.ndarray]:
-        """The window side of a warm-up step of ``T`` tokens: the step's
-        three window fields, none for a family without window layers."""
-        S, WP = self.cfg.max_batch, self.kv.window_row_pages
-        if not self.kv.window_pages:
-            return {}
-        return dict(window_indices=np.zeros((S, WP), np.int32),
-                    window_lens=np.asarray([T] + [0] * (S - 1), np.int32),
-                    window_slots=np.full((T,), -1, np.int32))
+    def _warm_tables(self, tables: np.ndarray) -> Any:
+        """The fused decode program's table operand at warm-up: the K/V
+        tables, with what the family's chunks take beside them where it has
+        such an operand (``Beside.chunk_operand``, as ``dispatch_chunk`` pairs
+        them).  (This method keeps the lines of the one it replaced: what
+        follows it is in the call stacks that key the compile cache.)"""
+        beside = self.kv.beside.chunk_operand((), self.cfg.max_batch)
+        return tables if beside is None else (tables, beside)
+
 
     def warmup(self) -> Dict[str, int]:
         """Pre-compile every device program the serving loop can dispatch —

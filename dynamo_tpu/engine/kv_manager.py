@@ -11,27 +11,13 @@ Host-side bookkeeping only — the device never sees hashes, just block ids.
 Physical block order is irrelevant to the device (attention gathers via block
 tables), so allocation never copies anything in HBM.
 
-A family whose recurrent state lives in SLOTS beside the pages
-(models/mamba2.py; docs/granite_hybrid.md) keeps them under this manager too:
-``live_slots`` slots of which a running row owns one from admission to
-retirement or preemption, and ``snapshot_slots`` slots each attached to the
-sealed block at whose end its copy of the state was taken.  A prefix is
-resumable where such a block is; a snapshot is freed with its block and
-dropped first, least recently used, when the pool is full: a block without
-its snapshot is not resumable, never wrong.
-
-A family with layers that keep a WINDOW of the last positions only
-(models/lfm2.py ``sliding_attention``; docs/k_exaone.md) gets a SECOND page
-pool under this manager: ``window_pages`` pages of which a running row holds
-the few its next query's window reaches (``take_window_page`` as it grows,
-``release_window`` as pages fall behind), whatever its length.  The pages
-before a resume point are RETAINED with that block's hash exactly as a
-snapshot is (``retain_window``: evictable least recently used, dropped with
-the block), and ``resumable`` cuts a hit back to the last block whose window
-pages are still whole.  Pages are shared by count: a retained page a row
-resumed from is both.  Admission counts both pools (``would_fit``): a running
-row may hold ``window_row_pages`` pages, everything else can be evicted, so a
-row that was admitted never finds the pool empty.
+What a family keeps BESIDE the pages (recurrent state in slots, the pages of
+window layers: engine/resume.py; docs/granite_hybrid.md, "State beside the
+pages") lives under this manager too, in ``UnitPool``s: a fixed range of units
+that running rows hold and that sealed blocks keep as the place a later hit may
+resume from, least recently used first.  A block's kept units go with the
+block (``_take_free_block``); ``would_fit`` asks the family's object whether
+one more row has room there too.
 """
 
 from __future__ import annotations
@@ -44,8 +30,101 @@ from ..llm.kv_router.protocols import (
     KvCacheEvent,
     KvCacheStoredBlockData,
 )
-from ..llm.metrics import ssm_metrics, swa_metrics
 from ..tokens import TokenBlock
+from .resume import Beside
+
+
+class UnitPool:
+    """Units ``first .. first + size - 1`` (state slots, pages of a second
+    pool).  A unit is free, HELD by running rows (a count), KEPT with a sealed
+    block as the place a later hit may resume from (a count), or both.
+    ``publish(dropped)`` is called after every change with the kept entries it
+    dropped: the ONE place the gauges are written from."""
+
+    def __init__(self, first: int, size: int, publish: Optional[Callable[[int], None]] = None):
+        self.first, self.size = first, size
+        self._free: List[int] = list(range(first + size - 1, first - 1, -1))
+        self._rows = [0] * size  # references held by rows
+        self._kept = [0] * size  # references held by kept entries
+        # block id -> the units kept with it, least recently used first.
+        self._of: "OrderedDict[int, Tuple[int, ...]]" = OrderedDict()
+        self.held = self.kept_only = 0  # units a row holds; units only kept
+        self._publish = publish or (lambda dropped: None)
+
+    free = property(lambda self: len(self._free))
+    entries = property(lambda self: len(self._of))
+
+    def __contains__(self, block_id: Optional[int]) -> bool:
+        return block_id in self._of
+
+    def _ref(self, units: Sequence[int], rows: int = 0, kept: int = 0) -> None:
+        """Add references; a unit that loses its last goes back to the pool."""
+        for u in units:
+            i = u - self.first
+            r0, k0 = self._rows[i], self._kept[i]
+            r1, k1 = r0 + rows, k0 + kept
+            self._rows[i], self._kept[i] = r1, k1
+            self.held += (r1 > 0) - (r0 > 0)
+            self.kept_only += (r1 == 0 and k1 > 0) - (r0 == 0 and k0 > 0)
+            if r1 == 0 and k1 == 0:
+                self._free.append(u)
+
+    def take(self) -> Optional[int]:
+        """A unit for a row, which holds it: a free one, else one of the least
+        recently used kept entry that gives any back (an entry whose every
+        unit a row holds is skipped: dropping it frees nothing and loses a
+        resume point), else None."""
+        dropped = 0
+        while not self._free:
+            gives = (b for b, units in self._of.items() if any(
+                self._rows[u - self.first] == 0 and self._kept[u - self.first] == 1 for u in units))
+            victim = next(gives, None)
+            if victim is None:
+                break
+            self._ref(self._of.pop(victim), kept=-1)
+            dropped += 1
+        unit = self._free.pop() if self._free else None
+        if unit is not None:
+            self._ref((unit,), rows=1)
+        self._publish(dropped)
+        return unit
+
+    def release(self, units: Sequence[int]) -> None:
+        """Rows' references to ``units`` go."""
+        self._ref(units, rows=-1)
+        self._publish(0)
+
+    def keep(self, block_id: int, units: Sequence[int]) -> bool:
+        """``units`` hold what a row resumed at the end of sealed block
+        ``block_id`` needs; False (and nothing kept) where it has an entry."""
+        if block_id in self._of:
+            return False
+        self._of[block_id] = tuple(units)
+        self._ref(units, kept=1)
+        self._publish(0)
+        return True
+
+    def resume(self, block_ids: Sequence[int]) -> Tuple[int, Tuple[int, ...]]:
+        """(n, units): the longest run ``block_ids[:n]`` that ends at a block
+        with an entry, now the most recently used, whose units are HELD for
+        the row that resumes there until it ``release``s them; (0, ()) where
+        no block has one."""
+        for n in range(len(block_ids), 0, -1):
+            units = self._of.get(block_ids[n - 1])
+            if units is not None:
+                self._of.move_to_end(block_ids[n - 1])
+                self._ref(units, rows=1)
+                self._publish(0)
+                return n, units
+        return 0, ()
+
+    def drop(self, block_id: Optional[int] = None) -> None:
+        """The entry of ``block_id`` goes, if it has one (no id: every entry)."""
+        gone = list(self._of) if block_id is None else [block_id] * (block_id in self._of)
+        for bid in gone:
+            self._ref(self._of.pop(bid), kept=-1)
+        if gone:
+            self._publish(len(gone))
 
 
 @dataclass
@@ -75,35 +154,12 @@ class KvBlockManager:
         block_size: int,
         event_callback: Optional[EventCallback] = None,
         enable_prefix_caching: bool = True,
-        live_slots: int = 0,
-        snapshot_slots: int = 0,
-        window_pages: int = 0,
-        window_tokens: int = 0,
-        window_row_pages: int = 0,
+        beside: Optional[Beside] = None,
     ):
-        # State slots (module docstring): live slots are ids [0, live_slots),
-        # snapshot slots [live_slots, live_slots + snapshot_slots).
-        self.live_slots = live_slots
-        self.snapshot_slots = snapshot_slots
-        self._live_free: List[int] = list(range(live_slots - 1, -1, -1))
-        self._snap_free: List[int] = list(range(live_slots + snapshot_slots - 1,
-                                                live_slots - 1, -1))
-        # block id -> its snapshot's slot, least recently used first.
-        self._snap_of: "OrderedDict[int, int]" = OrderedDict()
-        self._snap_pins: Dict[int, int] = {}  # slot -> rows about to read it
-        # The window pool (module docstring): ``window_tokens`` positions a
-        # window layer keeps, so ``window_blocks`` pages before a resume point.
-        self.window_pages = window_pages
-        self.window_tokens = window_tokens
-        self.window_row_pages = window_row_pages
-        self.window_blocks = -(-(window_tokens - 1) // block_size) if window_pages else 0
-        self.window_rows = 0  # running rows: each may hold window_row_pages pages
-        self._win_free: List[int] = list(range(window_pages - 1, -1, -1))
-        self._win_rows = [0] * window_pages  # references held by rows
-        self._win_kept = [0] * window_pages  # references held by retained entries
-        self._win_live = self._win_retained = 0  # pages a row holds; pages only retained
-        # block id -> the window pages before its end, least recently used first.
-        self._win_of: "OrderedDict[int, Tuple[int, ...]]" = OrderedDict()
+        # What the family keeps beside the pages (engine/resume.py), and the
+        # pools it made for it here.
+        self.pools: List[UnitPool] = []
+        self.beside = beside if beside is not None else Beside()
         self.num_blocks = num_blocks
         self.block_size = block_size
         self._blocks = [_Block(i) for i in range(num_blocks)]
@@ -115,7 +171,7 @@ class KvBlockManager:
         self._by_hash: Dict[int, int] = {}
         self._event_callback = event_callback
         self._event_id = 0
-        self._enable_prefix_caching = enable_prefix_caching
+        self.enable_prefix_caching = enable_prefix_caching
         # Tiered KV cache (engine/{host_cache,disk_cache}.py): maps a
         # sequence hash to the lower tier still holding its contents
         # ("host"/"disk") or None.  When set, HBM eviction of a block a
@@ -126,6 +182,7 @@ class KvBlockManager:
         # cumulative counters for metrics
         self.lookup_blocks = 0
         self.matched_blocks = 0
+        self.beside.bind(self)
 
     # ------------------------------------------------------------------ stats
     @property
@@ -144,147 +201,15 @@ class KvBlockManager:
     def hit_rate(self) -> float:
         return self.matched_blocks / self.lookup_blocks if self.lookup_blocks else 0.0
 
-    # ------------------------------------------------------------ state slots
-    def take_live_slot(self) -> Optional[int]:
-        slot = self._live_free.pop() if self._live_free else None
-        self._slot_gauges()
-        return slot
+    # ------------------------------------------------------ beside the pages
+    def add_pool(self, first: int, size: int, publish=None) -> UnitPool:
+        """A pool of units whose kept entries go with this manager's blocks."""
+        self.pools.append(UnitPool(first, size, publish))
+        return self.pools[-1]
 
-    def free_live_slot(self, slot: int) -> None:
-        self._live_free.append(slot)
-        self._slot_gauges()
-
-    def _slot_gauges(self) -> None:
-        ssm_metrics.slots_in_use = {
-            "live": self.live_slots - len(self._live_free), "snapshot": len(self._snap_of)}
-
-    def resumable(self, block_ids: Sequence[int], below: int):
-        """(n, start): the longest run ``block_ids[:n]`` of a matched prefix
-        that ends at a block holding what a row needs to go on from there and
-        covers fewer than ``below`` tokens (a prompt's last token is always
-        computed).  ``start`` is a snapshot's slot, (0, -1) where there is
-        none; under a window pool the window pages before the block's end,
-        (0, ()) where none are whole.  Either is PINNED until
-        ``unpin_snapshot``: the slot is handed to nobody by
-        ``reserve_snapshot`` until the row's first step is enqueued, and the
-        pages are referenced for the row, which keeps them as its own."""
-        for n in range(min(len(block_ids), (below - 1) // self.block_size), 0, -1):
-            bid = block_ids[n - 1]
-            if self.window_pages:
-                pages = self._win_of.get(bid)
-                if pages is not None:
-                    self._win_of.move_to_end(bid)
-                    for p in pages:
-                        self._win_ref(p, rows=1)
-                    return n, pages
-                continue
-            slot = self._snap_of.get(bid)
-            if slot is not None:
-                self._snap_of.move_to_end(bid)
-                self._snap_pins[slot] = self._snap_pins.get(slot, 0) + 1
-                return n, slot
-        return (0, ()) if self.window_pages else (0, -1)
-
-    def unpin_snapshot(self, slot) -> None:
-        if isinstance(slot, tuple):  # window pages referenced by ``resumable``
-            self.release_window(slot)
-            return
-        left = self._snap_pins.get(slot, 0) - 1
-        if left > 0:
-            self._snap_pins[slot] = left
-        else:
-            self._snap_pins.pop(slot, None)
-
-    def has_snapshot(self, seq_hash: int) -> bool:
-        return self._by_hash.get(seq_hash) in self._snap_of
-
-    def reserve_snapshot(self) -> int:
-        """A slot for a snapshot about to be taken: a free one, else the least
-        recently used snapshot's that no admitted row is about to read, else
-        -1.  The caller attaches it (``attach_snapshot``) once its block is
-        sealed."""
-        if self._snap_free:
-            return self._snap_free.pop()
-        for bid, slot in self._snap_of.items():
-            if slot not in self._snap_pins:
-                del self._snap_of[bid]
-                ssm_metrics.snapshots["evicted"] += 1
-                return slot
-        ssm_metrics.snapshots["no_slot"] += 1
-        return -1
-
-    def free_snapshot(self, slot: int) -> None:
-        """A reserved slot that no step wrote goes back to the pool."""
-        self._snap_free.append(slot)
-
-    def attach_snapshot(self, seq_hash: int, slot: int) -> None:
-        """``slot`` holds the state at the end of the sealed block of
-        ``seq_hash``.  Where that block is gone already or has a snapshot
-        (two rows computed the same prefix side by side) the slot goes back."""
-        bid = self._by_hash.get(seq_hash)
-        if bid is None or bid in self._snap_of:
-            self.free_snapshot(slot)
-        else:
-            self._snap_of[bid] = slot
-            ssm_metrics.snapshots["taken"] += 1
-        self._slot_gauges()
-
-    # ------------------------------------------------------------ window pool
-    def _win_ref(self, page: int, rows: int = 0, kept: int = 0) -> None:
-        """Add references to ``page``; one that loses its last goes back to
-        the pool.  Keeps the gauge's counts as it goes."""
-        r0, k0 = self._win_rows[page], self._win_kept[page]
-        r1, k1 = r0 + rows, k0 + kept
-        self._win_rows[page], self._win_kept[page] = r1, k1
-        self._win_live += (r1 > 0) - (r0 > 0)
-        self._win_retained += (r1 == 0 and k1 > 0) - (r0 == 0 and k0 > 0)
-        if r1 == 0 and k1 == 0:
-            self._win_free.append(page)
-        swa_metrics.pool_pages.update(
-            live=self._win_live, retained=self._win_retained, free=len(self._win_free))
-
-    def window_fits(self) -> bool:
-        """Room for one more running row in the window pool."""
-        return (self.window_rows + 1) * self.window_row_pages <= self.window_pages
-
-    def take_window_page(self) -> int:
-        """A page for a running row, dropping retained pages least recently
-        used while none is free.  An admitted row always finds one."""
-        while not self._win_free and self._win_of:
-            self._drop_window(next(iter(self._win_of)))
-        page = self._win_free.pop()
-        self._win_ref(page, rows=1)
-        return page
-
-    def release_window(self, pages: Sequence[int]) -> None:
-        for p in pages:
-            self._win_ref(p, rows=-1)
-
-    def retain_window(self, seq_hash: int, pages: Sequence[int]) -> None:
-        """``pages`` hold the window layers' K/V of the positions before the
-        end of the sealed block of ``seq_hash``: kept with that block, as a
-        snapshot is.  Nothing where the block is gone or has them already."""
-        bid = self._by_hash.get(seq_hash)
-        if bid is None or bid in self._win_of:
-            return
-        self._win_of[bid] = tuple(pages)
-        for p in pages:
-            self._win_ref(p, kept=1)
-
-    def has_window(self, seq_hash: int) -> bool:
-        return self._by_hash.get(seq_hash) in self._win_of
-
-    def _drop_window(self, block_id: int) -> None:
-        for p in self._win_of.pop(block_id, ()):
-            self._win_ref(p, kept=-1)
-
-    def _drop_snapshot(self, block_id: int) -> None:
-        self._drop_window(block_id)
-        slot = self._snap_of.pop(block_id, None)
-        if slot is not None:
-            self._snap_free.append(slot)
-            ssm_metrics.snapshots["evicted"] += 1
-            self._slot_gauges()
+    def block_of(self, seq_hash: int) -> Optional[int]:
+        """The block that holds the sealed contents ``seq_hash``, or None."""
+        return self._by_hash.get(seq_hash)
 
     # ----------------------------------------------------------------- events
     def _emit(self, event: KvCacheEvent) -> None:
@@ -299,7 +224,7 @@ class KvBlockManager:
         """Publish a tier change for blocks this manager does not hold in
         HBM (host→disk demotion, disk→host promotion) — the engine's tier
         stores have no event plane of their own."""
-        if block_hashes and self._enable_prefix_caching:
+        if block_hashes and self.enable_prefix_caching:
             self._emit(
                 KvCacheEvent.tiered(
                     self._next_event_id(), tier, list(block_hashes)
@@ -309,7 +234,7 @@ class KvBlockManager:
     def emit_removed(self, block_hashes: Sequence[int]) -> None:
         """Publish the loss of blocks evicted from the LAST tier holding
         them (see emit_tiered)."""
-        if block_hashes and self._enable_prefix_caching:
+        if block_hashes and self.enable_prefix_caching:
             self._emit(
                 KvCacheEvent.removed(self._next_event_id(), list(block_hashes))
             )
@@ -319,7 +244,7 @@ class KvBlockManager:
         """Longest run of leading blocks already resident; returns block ids
         (does NOT take references — pair with allocate_sequence)."""
         matched: List[int] = []
-        if not self._enable_prefix_caching:
+        if not self.enable_prefix_caching:
             return matched
         for tb in token_blocks:
             bid = self._by_hash.get(tb.sequence_hash)
@@ -345,8 +270,8 @@ class KvBlockManager:
         # Matched blocks sitting in the reuse pool get revived and stop
         # counting as free, so subtract them from available capacity.
         revived = sum(1 for b in matched if self._blocks[b].ref_count == 0)
-        if self.window_pages and not self.window_fits():
-            return False  # both pools: a row that fits one and not the other waits
+        if not self.beside.fits():
+            return False  # a row that fits the pages and not what lies beside them waits
         return fresh_needed <= self.free_blocks - revived
 
     def allocate_sequence(
@@ -454,7 +379,8 @@ class KvBlockManager:
         if self._free_reusable:
             bid, _ = self._free_reusable.popitem(last=False)  # LRU evict
             blk = self._blocks[bid]
-            self._drop_snapshot(bid)
+            for pool in self.pools:
+                pool.drop(bid)
             if blk.sequence_hash is not None:
                 self._by_hash.pop(blk.sequence_hash, None)
                 # Tiered cache: a lower tier still holding the contents
@@ -488,7 +414,7 @@ class KvBlockManager:
         another block already holds this hash (a race between two identical
         prompts), the newer block stays anonymous (no double-publish).
         """
-        if not self._enable_prefix_caching:
+        if not self.enable_prefix_caching:
             return
         blk = self._blocks[block_id]
         if token_block.sequence_hash in self._by_hash:
@@ -537,6 +463,6 @@ class KvBlockManager:
         self._free_anon = list(range(self.num_blocks))
         self._free_reusable.clear()
         self._by_hash.clear()
-        for bid in list(self._snap_of) + list(self._win_of):
-            self._drop_snapshot(bid)
+        for pool in self.pools:
+            pool.drop()
         self._emit(KvCacheEvent(self._next_event_id(), None))

@@ -22,7 +22,7 @@ logger = logging.getLogger(__name__)
 
 from collections import deque
 
-from ..llm.metrics import request_hop_metrics, swa_metrics, tenancy_metrics
+from ..llm.metrics import request_hop_metrics, tenancy_metrics
 from ..llm.protocols import FinishReason, LLMEngineOutput
 from ..ops.sampling import SamplingParams
 from .join import join_rows
@@ -209,17 +209,6 @@ class DecodePipelineMixin:
         ids = seq.block_ids[: out.shape[1]]
         out[i, : len(ids)] = ids
 
-    def _window_row(self, out: np.ndarray, i: int, seq: SequenceState, start: int) -> int:
-        """Row ``i`` of a window table for a step (or fused chunk) whose first
-        query is at position ``start``: the row's window pages from the block
-        that query's window reaches (engine/kv_manager.py); returns that
-        block.  The pages up to the step's last position are the row's already
-        (``Scheduler.window_span``)."""
-        base = max(0, start + 1 - self.kv.window_tokens) // self.cfg.block_size
-        ids = seq.window_ids[max(0, base - seq.window_base):][: out.shape[1]]
-        out[i, : len(ids)] = ids
-        return base
-
     def _build_ragged(self, items) -> RaggedBatch:
         bs = self.cfg.block_size
         S = self.cfg.max_batch
@@ -238,28 +227,8 @@ class DecodePipelineMixin:
             if self._lora_registry is not None
             else None
         )
-        # State slots (models/mamba2.py): where each row's recurrent state
-        # starts from, its live slot, and the slot its snapshot goes to.
-        sslots = np.full((S, 3), -1, np.int32) if self.kv.live_slots else None
-        # Window layers (docs/k_exaone.md): a second, short table a row, its
-        # context counted from the table's first page, and the tokens' slots.
-        window = self.kv.window_pages > 0
-        if window:
-            wtab = np.zeros((S, self.kv.window_row_pages), np.int32)
-            wlens, wslots = np.zeros((S,), np.int32), np.full((T,), -1, np.int32)
-            held = []
         at = 0
         for i, (seq, start, n) in enumerate(items):
-            if sslots is not None:
-                sslots[i] = self._state_slots_row(seq, start, n)
-            if window:
-                self.scheduler.window_span(seq, start + n)
-                base = self._window_row(wtab, i, seq, start)
-                p = np.arange(start, start + n, dtype=np.int32)
-                wids = np.asarray(seq.window_ids, np.int32)
-                wslots[at : at + n] = wids[p // bs - seq.window_base] * bs + p % bs
-                wlens[i] = start + n - base * bs
-                held.append(len(seq.window_ids))
             all_toks = seq.prompt + seq.output
             tok[at : at + n] = all_toks[start : start + n]
             p = np.arange(start, start + n, dtype=np.int32)
@@ -277,8 +246,6 @@ class DecodePipelineMixin:
             self._count_dispatch(
                 "unified", [st for _, st, _ in items], [n for _, _, n in items], T
             )
-        if window:
-            swa_metrics.add_rows(held)
         return RaggedBatch(
             token_ids=tok,
             positions=pos,
@@ -288,31 +255,10 @@ class DecodePipelineMixin:
             cu_q_lens=cu,
             num_seqs=np.asarray([len(items)], np.int32),
             adapter_slots=aslots,
-            state_slots=sslots,
-            **(dict(window_indices=wtab, window_lens=wlens, window_slots=wslots) if window else {}),
+            # What the family keeps beside the pages (engine/resume.py): where
+            # each row's state is read and written, its second page table.
+            **self.kv.beside.operands(items, S, T),
         )
-
-    def _state_slots_row(self, seq: SequenceState, start: int, n: int):
-        """(read, write, snapshot) slots of one row of a unified step: see
-        ``RaggedBatch.state_slots``.  A prompt row that ends ON a multiple of
-        the resume stride leaves a snapshot there, unless its block has one
-        or the pool has no slot to give (engine/kv_manager.py).  The snapshot
-        a row starts from stays PINNED until the step is enqueued
-        (``_run_unified``): no row of the step under construction is handed
-        it as the slot to write."""
-        read = seq.state_slot if seq.state_start is None else seq.state_start
-        snap, end = -1, start + n
-        if (
-            self.cfg.enable_prefix_caching
-            and end <= len(seq.prompt)
-            and end % self.scheduler.resume_stride == 0
-        ):
-            h = seq.block_seq.blocks[end // self.cfg.block_size - 1].sequence_hash
-            if not self.kv.has_snapshot(h):
-                snap = self.kv.reserve_snapshot()
-                if snap >= 0:
-                    seq.snapshot_due = (h, snap)
-        return read, seq.state_slot, snap
 
     async def _run_unified(self, plan: StepPlan):
         """One unified step of ``plan``'s rows; returns its sampled output,
@@ -410,25 +356,17 @@ class DecodePipelineMixin:
                     seq.t_last_chunk = t0 + wall
             pending_rows: List[Tuple[SequenceState, int]] = []
             for i, (seq, start, n) in enumerate(plan.items):
-                due, seq.snapshot_due = seq.snapshot_due, None
-                # The step that read the row's start is enqueued: the row goes
-                # on from its own live slot, and whatever writes the snapshot
-                # it started from runs behind that read.
-                self.scheduler.state_started(seq)
                 if seq.finished:
                     seq.awaiting_fetch = False  # pre-marked above; never parked
-                    if due is not None:  # no block of its was sealed: the slot goes back
-                        self.kv.attach_snapshot(*due)
+                    self.kv.beside.enqueued(seq, None)  # no block of its was sealed
                     continue
                 if start >= len(seq.prompt):
                     # Decode row: the fed token joins the hash stream.
                     seq.block_seq.append((seq.prompt + seq.output)[start])
                 seq.num_computed = start + n
                 self._seal_completed_blocks(seq)
-                if due is not None:  # the step left a snapshot at this row's end
-                    self.kv.attach_snapshot(*due)
-                if seq.window_ids is not None:  # ... or the window pages before it
-                    self.scheduler.retain_window(seq, start + n)
+                # What the step left at this row's end is kept with that block.
+                self.kv.beside.enqueued(seq, start + n)
                 if not seq.in_prefill:
                     # This row's sampled token is in flight (pre-marked before
                     # the dispatch); park the row until a harvest point applies
@@ -693,14 +631,12 @@ class DecodePipelineMixin:
         tok0 = np.zeros((S,), np.int32)
         pos_disp = np.full((S,), -1, np.int32)  # dispatch frontier (-1 = free)
         tables = np.zeros((S, cfg.max_blocks_per_seq), np.int32)
-        # Window layers: the chunk's second table, begun at the block the
-        # window of the chunk's first position reaches (``_multi`` finds that
-        # block from ``pos0`` as ``_window_row`` does).
-        wtables = (
-            np.zeros((S, self.kv.window_row_pages), np.int32) if self.kv.window_pages else None
-        )
+        # What a chunk takes beside its K/V tables (``Beside.chunk_operand``:
+        # a family's second table, made anew by every plan), or None.
+        beside = self.kv.beside
+        tables_beside: Optional[np.ndarray] = None
         limits = np.zeros((S,), np.int32)
-        slots = RowSlots(S)
+        slots = RowSlots(S, beside.row)
         samp: Optional[SamplingParams] = None
         samp_np: Any = None
         need_lp = False
@@ -818,8 +754,8 @@ class DecodePipelineMixin:
                     and not (seq.finished or seq.frozen)
                     and seq.freq_penalty == 0
                     and seq.pres_penalty == 0
-                    # (A family's state slot IS the row, and it is free.)
-                    and (seq.state_slot >= 0 or slots.num_free > len(step_row))
+                    # (A row the family's state binds to ITS row: that one is free.)
+                    and (beside.row(seq) is not None or slots.num_free > len(step_row))
                 ):
                     step_row[id(seq)] = row
             if not step_row:
@@ -1060,7 +996,7 @@ class DecodePipelineMixin:
             """Host-side planning for one fused chunk: KV slot ensure,
             table refresh, per-row write limits.  None = nothing worth
             dispatching (or KV exhausted → rebuild)."""
-            nonlocal rebuild
+            nonlocal rebuild, tables_beside
             # Don't dispatch chunks no row can still use — checked BEFORE
             # allocating lookahead blocks: a never-dispatched chunk must
             # not take KV capacity from other sequences.
@@ -1072,8 +1008,6 @@ class DecodePipelineMixin:
                 if not self.scheduler._ensure_slot(seq, lookahead=need):
                     ok = False
                 self._tables_row(tables, i, seq)
-                if wtables is not None and ok:
-                    self._window_row(wtables, i, seq, int(pos_disp[i]))
                 limits[i] = min(
                     len(seq.block_ids) * bs, cfg.max_blocks_per_seq * bs
                 )
@@ -1082,8 +1016,8 @@ class DecodePipelineMixin:
                 # so schedule() can preempt with nothing pending.
                 rebuild = True
                 return None
-            if wtables is not None:
-                swa_metrics.add_rows([len(seq.window_ids) for _, seq in slots.active()])
+            tables_beside = beside.chunk_operand(
+                [(i, seq, int(pos_disp[i])) for i, seq in slots.active()], S)
             return pos_disp.copy()
 
         def plan_top_up(in_flight_now: int, depth: int) -> Optional[np.ndarray]:
@@ -1118,10 +1052,8 @@ class DecodePipelineMixin:
                     c_tok, c_steps, c_counts = carry
                 if self._rep_sharding is not None:
                     d_args = self._prep((pos0, tables.copy(), limits.copy(), samp))
-                elif wtables is not None:
-                    # (A copy: the next chunk's plan shifts these rows while
-                    # this one may still be reading its operands.)
-                    d_args = (pos0, (tables, wtables.copy()), limits, samp)
+                elif tables_beside is not None:
+                    d_args = (pos0, (tables, tables_beside), limits, samp)
                 else:
                     d_args = (pos0, tables, limits, samp)
 

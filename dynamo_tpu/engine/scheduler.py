@@ -33,7 +33,6 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
 
-from ..llm.metrics import ssm_metrics, swa_metrics
 from ..llm.protocols import PreprocessedRequest
 from ..llm.qos import BATCH, INTERACTIVE, normalize_priority
 from ..tokens import TokenBlockSequence
@@ -162,24 +161,12 @@ class SequenceState:
     # is behind this check).  The CONTEXT travels in the migration snapshot
     # (SequenceSnapshot.trace) so a migrated stream stays one trace.
     trace: Any = None
-    # --- state slots (engine/kv_manager.py; docs/granite_hybrid.md) ---
-    # The live slot this row's recurrent state is in while it runs (-1: the
-    # family has none, or the row is not running).  ``state_start``: where the
-    # row's NEXT step reads its state from, set at admission and cleared once
-    # that step is enqueued: -1 zeros, a snapshot's slot (pinned until then),
-    # or None once the row goes on from its own live slot.
-    state_slot: int = -1
-    state_start: Optional[int] = None
-    # (block hash, slot) of the snapshot the row's step in flight leaves at
-    # its end, attached once that step's blocks are sealed (pipeline.py).
-    snapshot_due: Optional[Tuple[int, int]] = None
-    # --- window pages (engine/kv_manager.py; docs/k_exaone.md) ---
-    # The pages of the window pool this row holds, for the logical blocks
-    # ``window_base`` onward: what its next query's window reaches, and what
-    # its steps in flight write.  None: the family keeps no window, or the row
-    # is not running.
-    window_ids: Optional[List[int]] = None
-    window_base: int = 0
+    # --- state beside the pages (engine/resume.py) ---
+    # What the row holds of what its family keeps beside the K/V pages (a
+    # live slot and where its state starts; its pages of a window pool): the
+    # family's ``Beside`` object writes and reads it, nothing else does.  None:
+    # the family keeps nothing there, or the row is not running.
+    beside: Any = None
 
     def __post_init__(self) -> None:
         if self.orig_prompt_len == 0:
@@ -483,8 +470,10 @@ class RowSlots:
     has passed).
     """
 
-    def __init__(self, size: int):
+    def __init__(self, size: int, row_of=None):
         self.size = size
+        # (sequence) -> the row it MUST take, or None (``Beside.row``).
+        self._row_of = row_of or (lambda seq: None)
         self.rows: List[Optional[SequenceState]] = [None] * size
         # Pop from the end → lowest index first (matches the legacy
         # members-list row order, keeping device row assignment stable for
@@ -493,16 +482,16 @@ class RowSlots:
         self._pending: set = set()  # retired, awaiting the write barrier
 
     def assign(self, seq: SequenceState) -> int:
-        """A row of the fused program for ``seq``: the lowest free one, or,
-        for a family with state slots, the row of its live slot (the program
+        """A row of the fused program for ``seq``: the lowest free one, or
+        the one its family's state binds it to (a live state slot: the program
         updates row i's state in slot i, in place: models/mamba2.py ``step``).
         That row is free: its last owner gave the slot back when it left the
         scheduler, which is when its row here was freed."""
-        if seq.state_slot >= 0:
-            i = seq.state_slot
-            self._free.remove(i)
-        else:
+        i = self._row_of(seq)
+        if i is None:
             i = self._free.pop()
+        else:
+            self._free.remove(i)
         self.rows[i] = seq
         return i
 
@@ -550,22 +539,13 @@ class StepPlan:
 
 
 class Scheduler:
-    def __init__(self, cfg: EngineConfig, kv: KvBlockManager, resume: str = "token"):
+    def __init__(self, cfg: EngineConfig, kv: KvBlockManager):
         self.cfg = cfg
         self.kv = kv
-        # Where a sequence of the family can be resumed, which is where a
-        # prefix hit may end (models/family.py ``resume``).  A prompt's last
-        # token is always computed for its logits, so a fully cached prompt
-        # gives back: its last token ("token"); its whole last block
-        # ("block": state held by PAGE, whose sealed entry ends AT the last
-        # token and cannot restart one position earlier); everything after
-        # the last snapshot BEFORE its end ("snapshot": state held by slot,
-        # kept at multiples of ``resume_stride`` only); everything after the
-        # last block before its end whose WINDOW pages are still held
-        # ("window": layers that keep the last positions only, whose pages
-        # before a multiple of the stride are retained with that block).
-        self.resume = resume
-        self.resume_stride = cfg.prefill_chunk if resume in ("snapshot", "window") else 0
+        # What the family keeps beside the K/V pages decides where a prefix
+        # hit may end, what a row holds and where a prompt row's share of a
+        # step stops (engine/resume.py): every such question goes to it.
+        self.beside = kv.beside
         self.waiting: WfqQueue = WfqQueue(
             tenant_weights=cfg.qos.tenant_weights,
             default_weight=cfg.qos.default_weight,
@@ -621,74 +601,16 @@ class Scheduler:
             self.kv.free_sequence(seq.block_ids)
             seq.block_ids = []
         self._release_pin(seq)
-        self._release_state(seq)
-
-    def _release_state(self, seq: SequenceState) -> None:
-        if seq.state_slot >= 0:
-            self.kv.free_live_slot(seq.state_slot)
-            seq.state_slot = -1
-        self.state_started(seq)
-        if seq.snapshot_due is not None:
-            # Reserved for a step that was built and has not run to its end:
-            # the slot goes back with nothing attached to it.
-            self.kv.free_snapshot(seq.snapshot_due[1])
-            seq.snapshot_due = None
-        if seq.window_ids is not None:  # freed, preempted or failed: every page goes back
-            self.kv.release_window(seq.window_ids)
-            self.kv.window_rows -= 1
-            seq.window_ids, seq.window_base = None, 0
-
-    def window_span(self, seq: SequenceState, upto: int) -> None:
-        """``seq`` holds window pages for exactly what is ahead of it: pages
-        wholly behind the window of its NEXT query (position
-        ``num_computed``) go back to the pool, and pages are taken for the
-        positions up to ``upto`` that its next steps write.  (A step in flight
-        may still read a page given back here: whoever takes it writes it in
-        a step enqueued later, and the device runs them in order.)"""
-        bs, ids = self.cfg.block_size, seq.window_ids
-        first = max(0, seq.num_computed + 1 - self.kv.window_tokens) // bs
-        drop = min(max(first - seq.window_base, 0), len(ids))
-        self.kv.release_window(ids[:drop])
-        del ids[:drop]
-        # (Nothing held: the row's pages begin where its window does.)
-        seq.window_base = seq.window_base + drop if ids else max(seq.window_base + drop, first)
-        for _ in range((upto - 1) // bs + 1 - seq.window_base - len(ids)):
-            ids.append(self.kv.take_window_page())
-
-    def retain_window(self, seq: SequenceState, end: int) -> None:
-        """The step just enqueued ended ``seq``'s share ON a multiple of the
-        resume stride inside its prompt: the window pages before that point
-        are kept with the block that ends there (sealed by now), so that a
-        later hit may end at it."""
-        if not (
-            self.cfg.enable_prefix_caching
-            and end <= len(seq.prompt)
-            and end % self.resume_stride == 0
-        ):
-            return
-        b1 = end // self.cfg.block_size
-        b0 = max(0, b1 - self.kv.window_blocks)
-        if b0 >= seq.window_base:
-            self.kv.retain_window(
-                seq.block_seq.blocks[b1 - 1].sequence_hash,
-                seq.window_ids[b0 - seq.window_base : b1 - seq.window_base],
-            )
-
-    def state_started(self, seq: SequenceState) -> None:
-        """``seq`` reads its state from its own live slot from now on: the
-        step that read where it started is enqueued, or the row is gone."""
-        if seq.state_start is not None and seq.state_start >= 0:
-            self.kv.unpin_snapshot(seq.state_start)
-        seq.state_start = None
+        self.beside.release(seq)
 
     def prompt_chunk(self, seq: SequenceState, budget: int) -> int:
-        """Prompt tokens of ``seq`` the next step takes of ``budget``.  Under
-        snapshots a row's share of a step never crosses a multiple of the
-        resume stride, so that the state at every boundary passed is a row's
-        LAST in some step and can be kept."""
+        """Prompt tokens of ``seq`` the next step takes of ``budget``.  Where
+        the family keeps state at resume points, a row's share of a step never
+        crosses a multiple of their stride, so that the state at every
+        boundary passed is a row's LAST in some step and can be kept."""
         chunk = min(budget, len(seq.prompt) - seq.num_computed)
-        if self.resume_stride:
-            chunk = min(chunk, self.resume_stride - seq.num_computed % self.resume_stride)
+        if self.beside.stride:
+            chunk = min(chunk, self.beside.stride - seq.num_computed % self.beside.stride)
         return chunk
 
     def _release_pin(self, seq: SequenceState) -> None:
@@ -844,8 +766,8 @@ class Scheduler:
         reserve = self._pressure_reserve()
         if reserve and prompt_blocks + reserve > self.kv.free_blocks:
             return False  # squeezed pool: the head cannot land right now
-        if self.kv.window_pages and not self.kv.window_fits():
-            return False  # the window pool has no room for one more row
+        if not self.beside.fits():
+            return False  # no room for one more row beside the pages
         if prompt_blocks <= self.kv.free_blocks:
             return True  # fits even with zero prefix hits: skip the hashing
         # The fused pipeline polls this twice per chunk at saturation; the
@@ -923,86 +845,32 @@ class Scheduler:
         reserve = self._pressure_reserve()
         if reserve and prompt_blocks + reserve > self.kv.free_blocks:
             return False  # kv_pressure fault: pool squeezed, head waits
-        slotted = self.resume == "snapshot"
-        cut = slotted or self.resume == "window"
-        if slotted and len(self.running) >= self.kv.live_slots:
-            return False  # every live slot is a running row's
         seq.block_seq.extend(seq.prompt)
-        share = start = None
-        if cut:
-            # The hit is cut back to the last block that holds a snapshot (or
-            # whose window pages are held: ONE notion, ``resumable``); the
-            # blocks past it are computed again into fresh blocks.
-            matched = self.kv.match_prefix(seq.block_seq.blocks)
-            share, start = self.kv.resumable(matched, below=len(seq.prompt))
-            if self._snapshot_ahead(seq, share * self.cfg.block_size):
-                # The head waits (admission stops at it, as for a head that
-                # does not fit): a later pass finds the snapshot.
-                self._unpin(start)
-                seq.block_seq = TokenBlockSequence(
-                    block_size=self.cfg.block_size, salt=seq.kv_salt
-                )
-                return False
-        alloc = self.kv.allocate_sequence(seq.block_seq.blocks, prompt_blocks, share=share)
+        # The hit is cut back to the last block the row can be resumed from
+        # (None: any block), and what is kept there is held for it; the blocks
+        # past it are computed again into fresh blocks.
+        share = self.beside.cut(seq)
+        # A head whose resume point a running row is about to leave waits for
+        # it (admission stops at it, as for a head that does not fit).
+        alloc = None if self.beside.ahead(seq, share, self.running) else (
+            self.kv.allocate_sequence(seq.block_seq.blocks, prompt_blocks, share=share))
         if alloc is None:
+            self.beside.uncut(seq)
             seq.block_seq = TokenBlockSequence(
                 block_size=self.cfg.block_size, salt=seq.kv_salt
             )
-            if cut:
-                self._unpin(start)
             return False
         seq.block_ids, cached_tokens = alloc
         # Admission holds its own references now; the pre-admission pin
         # (sp-prefill / host-restore) has done its job.
         self._release_pin(seq)
-        if slotted:
-            seq.state_slot, seq.state_start = self.kv.take_live_slot(), start
-            ssm_metrics.add_start(len(matched) * self.cfg.block_size, cached_tokens)
-        elif cut:
-            # The pages ``resumable`` referenced are the row's own from here.
-            seq.window_ids, seq.window_base = list(start), share - len(start)
-            self.kv.window_rows += 1
-            swa_metrics.add_hit(len(matched) * self.cfg.block_size, cached_tokens)
-        elif cached_tokens >= len(seq.prompt):
-            # A fully-cached prompt must still recompute its last token to
-            # get logits for sampling the first output token.
-            cached_tokens = len(seq.prompt) - (
-                self.cfg.block_size if self.resume == "block" else 1)
+        # (A fully cached prompt still computes its last token, or block, to
+        # get logits for sampling the first output token.)
+        cached_tokens = self.beside.admit(seq, cached_tokens)
         seq.num_computed = cached_tokens
         seq.num_cached_prompt = cached_tokens
         seq.num_sealed_blocks = cached_tokens // self.cfg.block_size
         return True
-
-    def _unpin(self, start) -> None:
-        """Give back what ``resumable`` pinned for a row that is not admitted."""
-        if start != -1:
-            self.kv.unpin_snapshot(start)
-
-    def _snapshot_ahead(self, seq: SequenceState, resumed: int) -> bool:
-        """A running row is still computing, inside its own prompt, the
-        stretch of ``seq``'s prompt from ``resumed`` tokens (where ``seq``
-        could be resumed now) to the next multiple of the resume stride, and
-        will leave a snapshot there that ``seq`` can start from.  ``seq``
-        then waits for it: admitted now it would compute the same tokens
-        beside that row, from a state fixed at admission (32 prompts of 8
-        shared prefixes at once started 8 to 23 rows from zeros, and the
-        window's tails moved with that count: PERF.md section 6, PR 44).
-        The wait ends with that row's prompt at the latest."""
-        if not self.cfg.enable_prefix_caching:
-            return False
-        ahead = resumed + self.resume_stride
-        if ahead >= len(seq.prompt):  # a snapshot ends before the prompt's last token
-            return False
-        last = ahead // self.cfg.block_size - 1
-        want = seq.block_seq.blocks[last].sequence_hash
-        return any(
-            r.num_computed < ahead <= len(r.prompt)
-            and not r.finished
-            and not r.frozen
-            and len(r.block_seq.blocks) > last
-            and r.block_seq.blocks[last].sequence_hash == want
-            for r in self.running
-        )
 
     def _ensure_slot(self, seq: SequenceState, lookahead: int = 1) -> bool:
         """Allocate KV blocks so ``lookahead`` tokens past num_computed have
@@ -1019,8 +887,7 @@ class Scheduler:
             if bid is None:
                 return False
             seq.block_ids.append(bid)
-        if seq.window_ids is not None:
-            self.window_span(seq, min(seq.num_computed + lookahead, self.cfg.max_model_len))
+        self.beside.grow(seq, min(seq.num_computed + lookahead, self.cfg.max_model_len))
         return True
 
     def _preempt(self, seq: SequenceState) -> None:
@@ -1028,7 +895,7 @@ class Scheduler:
         self.running.remove(seq)
         self.kv.free_sequence(seq.block_ids)
         seq.block_ids = []
-        self._release_state(seq)
+        self.beside.release(seq)
         # Fold generated tokens into the prompt so recompute resumes exactly.
         seq.prompt = seq.prompt + seq.output
         seq.output = []

@@ -950,7 +950,7 @@ sparse_model_metrics = SparseModelMetrics()
 
 
 class SsmMetrics:
-    """The state slots' account (engine/kv_manager.py, engine/scheduler.py;
+    """The state slots' account (engine/resume.py ``SlotState``;
     docs/granite_hybrid.md, docs/tracing.md): where admitted requests' state
     came from, what became of block-level hits, and the snapshots' fate.
     Renders nothing until a family with state slots admitted a request."""
@@ -998,8 +998,8 @@ ssm_metrics = SsmMetrics()
 
 
 class SwaMetrics:
-    """The window layers' account (engine/kv_manager.py's second page pool,
-    engine/scheduler.py; docs/k_exaone.md, docs/tracing.md): host arithmetic
+    """The window layers' account (engine/resume.py ``WindowPages``, the second
+    page pool; docs/k_exaone.md, docs/tracing.md): host arithmetic
     at dispatch and admission, from lengths the scheduler holds.  Renders
     nothing until a family with window layers dispatched a row."""
 

@@ -5,6 +5,20 @@ nothing else: how to draw or quantize its parameters, how to build its page
 cache, its unified forward, and the few cache operations whose layout a family
 owns.  The engine names no family; a new one is a module beside models/llama.py
 and one entry here, chosen by ``ModelConfig.model_type``.
+
+A new family with state of its own beside the K/V pages writes three things
+and edits NO file under ``engine/``:
+
+- a model file (its forward reads and writes the state through operands of
+  ``RaggedBatch``, its ``create_cache`` takes the pools' sizes as keywords);
+- one entry here, whose ``beside`` field builds the object that owns every
+  host-side decision about that state (``engine/resume.py``: room for a row,
+  where a prefix hit may end, what a row holds, a step's operands, what is kept
+  at a resume point and what goes back);
+- one kind, a subclass of ``engine.resume.Beside``, only if none fits: state
+  in slots with snapshots (``SlotState``) and pages of a window pool
+  (``WindowPages``) exist, over ``KvBlockManager.add_pool``'s ``UnitPool``
+  (tests/test_beside_seam.py defines a third in thirty lines).
 """
 
 from __future__ import annotations
@@ -50,23 +64,11 @@ class ModelFamily(NamedTuple):
     # (config) -> the head width the attention kernels see, where it is not
     # ``config.head_dim`` (heads packed into one lane tile); None = head_dim.
     attn_lanes: Optional[Callable] = None
-    # Where a sequence of this family can be resumed, which is where a prefix
-    # hit may end (engine/scheduler.py ``_try_admit``): at every "token" (the
-    # K/V of a position is all a later one needs); at a "block"'s end (state
-    # held by PAGE: a prompt that is a whole number of cached blocks gives its
-    # last block back); or at a "snapshot" (state held by SLOT: a hit is cut
-    # back to the last sealed block that holds a snapshot of it); or behind a
-    # "window" (layers that keep the last positions only, in a second page
-    # pool: a hit is cut back to the last block before which those pages are
-    # still held).
-    resume: str = "token"
-    # (config, engine config) -> (live slots, snapshot slots) of a family whose
-    # state lives in slots beside the pages; None = it has no such state.
-    state_slots: Optional[Callable] = None
-    # (config, engine config) -> (pages of the window pool, positions a window
-    # layer keeps, most pages a running row holds) of a family with such
-    # layers; None = it has none.
-    window_pool: Optional[Callable] = None
+    # (config, engine config) -> the ``engine.resume.Beside`` that owns what
+    # this family keeps beside the K/V pages, which is also where a sequence
+    # can be resumed and so where a prefix hit may end; None = K/V alone,
+    # resumed at every token.
+    beside: Optional[Callable] = None
 
 
 def _llama(config: ModelConfig) -> ModelFamily:
@@ -225,8 +227,18 @@ def _hybrid(config: ModelConfig) -> ModelFamily:
                        "before a resume point are kept by whole blocks)")
         _refuse(config, cfg, bad + _unmovable_blocks(cfg))
 
-    def state_slots(config: ModelConfig, cfg: Any):
-        return cfg.max_batch, lfm2.snapshot_slots(cfg.num_blocks, cfg.block_size, cfg.prefill_chunk)
+    def beside(config: ModelConfig, cfg: Any):
+        """Scan state in slots, resumed at a snapshot; else window layers'
+        pages in a second pool, resumed behind kept pages; else convolution
+        state held by PAGE, resumed at a block's end."""
+        from ..engine.resume import Beside, SlotState, WindowPages
+
+        if slotted:
+            snapshots = lfm2.snapshot_slots(cfg.num_blocks, cfg.block_size, cfg.prefill_chunk)
+            return SlotState(cfg.max_batch, snapshots, stride=cfg.prefill_chunk)
+        if windowed:
+            return WindowPages(*window_pool(config, cfg), stride=cfg.prefill_chunk)
+        return Beside(whole_blocks=True)
 
     def window_pool(config: ModelConfig, cfg: Any):
         """The window pool's ONE rule (docs/k_exaone.md): the pages before
@@ -262,9 +274,7 @@ def _hybrid(config: ModelConfig) -> ModelFamily:
         count_aux=sparse_model_metrics.add_moe,
         counts=sparse_model_metrics.summary,
         attn_lanes=lfm2.attn_lanes,
-        resume="snapshot" if slotted else "window" if windowed else "block",
-        state_slots=state_slots if slotted else None,
-        window_pool=window_pool if windowed else None,
+        beside=beside,
     )
 
 

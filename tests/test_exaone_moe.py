@@ -352,7 +352,7 @@ def test_from_hf_config_reads_the_catalog_row_and_the_benchmarks_file():
     assert cfg.post_norm and cfg.qk_norm and not cfg.rope_full_attention
     assert not cfg.tie_word_embeddings and cfg.norm_topk_prob and cfg.gate_scoring == "sigmoid"
     fam = family_of(cfg)
-    assert fam.name == "hybrid" and fam.resume == "window"
+    assert fam.name == "hybrid" and fam.beside is not None
     assert lfm2.layer_counts(cfg) == (0, 2, 1, 7) and lfm2.window_layers(cfg) == 6
     shapes = lfm2.leaf_shapes(cfg)
     assert shapes["wattn"]["wqkv"] == (6, 6144, 10240) and shapes["attn"]["wo"] == (2, 8192, 6144)
@@ -364,7 +364,9 @@ def test_from_hf_config_reads_the_catalog_row_and_the_benchmarks_file():
     serve = body["serve"]
     ecfg = EngineConfig(model="cut", **{k: serve[k] for k in (
         "block_size", "num_blocks", "max_model_len", "max_batch", "prefill_chunk", "decode_steps")})
-    assert fam.window_pool(cfg, ecfg) == (8192, 128, 41)
+    kind = fam.beside(cfg, ecfg)
+    assert type(kind).__name__ == "WindowPages" and kind.stride == ecfg.prefill_chunk
+    assert (kind.cache_kw["window_pages"], kind.tokens, kind.row_pages) == (8192, 128, 41)
     cache = jax.eval_shape(lambda: lfm2.HybridCache.create(cfg, 32768, 16, dtype=jnp.int8,
                                                            window_pages=8192))
     assert cache.pages.shape == (2, 32768, 16, 16, 128) and cache.window.shape == (6, 8192, 16, 16, 128)
@@ -425,11 +427,12 @@ def test_the_cache_is_two_page_pools_under_one_manager(engine):
     assert len(jax.tree_util.tree_leaves(engine.cache)) == 2
     # ONE rule from flags that exist: the 2 pages before every stride of 16
     # tokens the 64 K/V pages hold (32), or twice what 4 rows can hold (7 each).
-    assert (engine.kv.window_pages, engine.kv.window_tokens, engine.kv.window_row_pages,
-            engine.kv.window_blocks) == (56, W, 7, 2)
+    kind = engine.kv.beside
+    assert engine.kv.pools == [kind.pool]
+    assert (kind.pool.size, kind.tokens, kind.row_pages, kind.blocks) == (56, W, 7, 2)
     assert engine.cache.pages.shape[:2] == (2, 64) and engine.cache.window.shape[:2] == (6, 56)
     assert engine.device_summary()["cache_kinds"] == "kv:256,kv_window:256"
-    assert engine.scheduler.resume == "window" and engine.scheduler.resume_stride == 16
+    assert engine.scheduler.beside is kind and kind.stride == 16
     assert "inject" not in engine.compile_counts()
 
 
@@ -473,7 +476,7 @@ def test_the_engine_resumes_hits_behind_retained_window_pages_and_counts(engine)
     def spy(items):
         chunks.extend((st, n) for s, st, n in items if st < len(s.prompt))
         rb = build(items)
-        held.extend(len(s.window_ids) for s, _, _ in items)
+        held.extend(len(s.beside.ids) for s, _, _ in items)
         return rb
 
     engine._build_ragged = spy
@@ -492,7 +495,7 @@ def test_the_engine_resumes_hits_behind_retained_window_pages_and_counts(engine)
         check(first, await gen(first, 20))  # decodes to position 61: 2.5 windows past the prompt
         await idle()
         assert chunks == [(0, 16), (16, 16), (32, 9)]
-        assert max(held) <= engine.kv.window_row_pages
+        assert max(held) <= engine.kv.beside.row_pages
         assert swa_metrics.hit_tokens == {"resumed": 0, "cut": 0}
         assert swa_metrics.pool_pages["retained"] == 4 and swa_metrics.pool_pages["live"] == 0
         # a hit of 36 tokens (9 blocks) is cut back to the resume point at 32
@@ -509,9 +512,9 @@ def test_the_engine_resumes_hits_behind_retained_window_pages_and_counts(engine)
         # the pages before 32 dropped: the hit is cut back to 16
         from dynamo_tpu.tokens import hash_token_blocks
 
-        at32 = engine.kv._by_hash[hash_token_blocks(doc, 4, None)[7].sequence_hash]
-        assert len(engine.kv._win_of[at32]) == 2
-        engine.kv._drop_window(at32)
+        at32 = engine.kv.block_of(hash_token_blocks(doc, 4, None)[7].sequence_hash)
+        assert len(engine.kv.beside.pool._of[at32]) == 2
+        engine.kv.beside.pool.drop(at32)
         del chunks[:]
         third = doc + rs.randint(16, 128, 2).tolist()
         check(third, await gen(third, 3))
@@ -519,7 +522,7 @@ def test_the_engine_resumes_hits_behind_retained_window_pages_and_counts(engine)
         assert chunks[0][0] in (16, 32) and sum(n for _, n in chunks) == 40 - chunks[0][0]
         assert swa_metrics.pool_pages["live"] == 0
         assert (swa_metrics.pool_pages["retained"] + swa_metrics.pool_pages["free"]
-                == engine.kv.window_pages)
+                == engine.kv.beside.pool.size)
         assert swa_metrics.window_rows > 0 and swa_metrics.window_pages / swa_metrics.window_rows < 7
         assert swa_metrics.attended["window"] < swa_metrics.attended["full"]
         text = swa_metrics.render()
@@ -570,7 +573,7 @@ def test_preemption_returns_every_window_page_and_the_rows_go_on():
             if not engine.scheduler.running:
                 break
             await asyncio.sleep(0.01)
-        assert engine.kv.window_rows == 0 and swa_metrics.pool_pages["live"] == 0
+        assert engine.kv.beside.rows == 0 and swa_metrics.pool_pages["live"] == 0
         await engine.close()
 
     asyncio.run(main())

@@ -324,7 +324,7 @@ def test_from_hf_config_reads_the_catalog_row_and_the_benchmarks_file():
     assert not cfg.use_rope and cfg.gate_scoring == "softmax" and cfg.tie_word_embeddings
     assert mamba2.dims(cfg) == (8192, 128, 64, 128, 4) and mamba2.conv_width(cfg) == 8448
     fam = family_of(cfg)
-    assert fam.name == "hybrid" and fam.resume == "snapshot"
+    assert fam.name == "hybrid" and fam.beside is not None
     assert lfm2.layer_counts(cfg) == (0, 1, 0, 10) and lfm2.mamba_layers(cfg) == 9
     shapes = lfm2.leaf_shapes(cfg)
     assert shapes["mamba"]["in_proj"] == (9, 4096, 16768)
@@ -344,7 +344,7 @@ def test_from_hf_config_reads_the_catalog_row_and_the_benchmarks_file():
                                         "intermediate_size": 8})
     assert (other.embedding_multiplier, other.residual_multiplier, other.logits_scaling) == (1, 1, 1)
     assert other.attention_multiplier is None and other.use_rope and other.gate_scoring == "sigmoid"
-    assert family_of(other).resume == "token"
+    assert family_of(other).beside is None
 
 
 @pytest.mark.parametrize("bad,match", [
@@ -393,11 +393,13 @@ def test_the_cache_is_pages_and_slots_under_one_manager(engine):
     assert len(jax.tree_util.tree_leaves(engine.cache)) == 3
     # ONE rule from flags that exist: max_batch live slots, and a snapshot for
     # every five resume strides (prefill_chunk) the pages can hold: 64 x 4 / 80.
-    assert (engine.kv.live_slots, engine.kv.snapshot_slots) == (4, 3)
+    kind = engine.kv.beside
+    assert type(kind).__name__ == "SlotState" and engine.kv.pools == [kind.live, kind.snapshots]
+    assert (kind.live.first, kind.live.size, kind.snapshots.first, kind.snapshots.size) == (0, 4, 4, 3)
     assert engine.cache.ssm.shape == (3, 7, 128, 16) and engine.cache.ssm.dtype == jnp.float32
     assert engine.cache.tail.shape == (3, 3, 7, 160)
     assert engine.device_summary()["cache_kinds"] == "kv:256,ssm_slot:8192,conv_tail:1920"
-    assert engine.scheduler.resume == "snapshot" and engine.scheduler.resume_stride == 16
+    assert engine.scheduler.beside is kind and kind.stride == 16
     assert "inject" not in engine.compile_counts()
 
 
@@ -513,7 +515,7 @@ def test_preemption_gives_the_slot_back_and_resumes_from_a_snapshot():
     from dynamo_tpu.llm.metrics import ssm_metrics
 
     engine = make_engine(num_blocks=20)
-    assert engine.kv.snapshot_slots == 1
+    assert engine.kv.beside.snapshots.size == 1
     gen, check = _requests(engine)
 
     async def main():
@@ -530,7 +532,7 @@ def test_preemption_gives_the_slot_back_and_resumes_from_a_snapshot():
             if not engine.scheduler.running:
                 break
             await asyncio.sleep(0.01)
-        assert ssm_metrics.slots_in_use["live"] == 0 and len(engine.kv._live_free) == 4
+        assert ssm_metrics.slots_in_use["live"] == 0 and engine.kv.beside.live.free == 4
         await engine.close()
 
     asyncio.run(main())
@@ -586,7 +588,8 @@ def test_a_pinned_start_is_no_rows_snapshot_target_and_an_unrun_batch_leaks_noth
     from dynamo_tpu.tokens import TokenBlockSequence
 
     engine = make_engine(num_blocks=20)
-    assert engine.kv.snapshot_slots == 1
+    snaps, live = engine.kv.beside.snapshots, engine.kv.beside.live
+    assert snaps.size == 1
     gen, check = _requests(engine)
     rs = np.random.RandomState(17)
     doc = rs.randint(16, 128, 16).tolist()
@@ -603,30 +606,32 @@ def test_a_pinned_start_is_no_rows_snapshot_target_and_an_unrun_batch_leaks_noth
             if not engine.scheduler.running:
                 break
             await asyncio.sleep(0.01)
-        (slot,) = engine.kv._snap_of.values()
+        ((slot,),) = snaps._of.values()
         sched, kv = engine.scheduler, engine.kv
+        pins = lambda: {u + snaps.first: n for u, n in enumerate(snaps._rows) if n}
         hit = seq_of("hit", doc + rs.randint(16, 128, 5).tolist())
         other = seq_of("other", rs.randint(16, 128, 24).tolist())
         assert sched._try_admit(hit) and sched._try_admit(other)
         sched.running.extend([hit, other])
-        assert hit.state_start == slot and kv._snap_pins == {slot: 1}
+        assert hit.beside.start == slot and pins() == {slot: 1}
         other.num_computed = 11  # its share of this step ends ON the stride
         rb = engine._build_ragged([(hit, 16, 5), (other, 11, 5)])
-        assert rb.state_slots[0].tolist() == [slot, hit.state_slot, -1]
-        assert rb.state_slots[1].tolist() == [-1, other.state_slot, -1]
-        assert ssm_metrics.snapshots["no_slot"] == 1 and kv._snap_pins == {slot: 1}
+        assert rb.state_slots[0].tolist() == [slot, hit.beside.slot, -1]
+        assert rb.state_slots[1].tolist() == [-1, other.beside.slot, -1]
+        assert ssm_metrics.snapshots["no_slot"] == 1 and pins() == {slot: 1}
         # enqueued: the pin goes, and a later step may take the slot
-        sched.state_started(hit)
-        assert kv._snap_pins == {} and hit.state_start is None
-        other.state_start = None
+        kv.beside.enqueued(hit, 21)
+        assert pins() == {} and hit.beside.start is None
+        other.beside.start = None
         rb = engine._build_ragged([(other, 11, 5)])
-        assert rb.state_slots[0].tolist() == [other.state_slot, other.state_slot, slot]
-        assert other.snapshot_due is not None and not kv._snap_of and not kv._snap_free
+        assert rb.state_slots[0].tolist() == [other.beside.slot, other.beside.slot, slot]
+        assert other.beside.due is not None and not snaps.entries and not snaps.free
+        assert pins() == {slot: 1}  # reserved for the step: a row's reference
         # the step never ran: both rows go, the reserved slot is free again
         sched.remove(hit)
         sched.remove(other)
-        assert kv._snap_free == [slot] and other.snapshot_due is None
-        assert len(kv._live_free) == 4 and kv._snap_pins == {}
+        assert snaps._free == [slot] and other.beside is None and hit.beside is None
+        assert live.free == 4 and pins() == {}
         await engine.close()
 
     asyncio.run(main())

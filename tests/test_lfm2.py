@@ -404,7 +404,7 @@ def test_the_cache_is_two_page_arrays_under_one_table(engine):
     assert engine.block_nbytes() == 2 * ps * 64 * 4 + 3 * 2 * 64 * 4
     assert engine.device_summary()["cache_kinds"] == "kv:256,conv_page:512"
     assert "inject" not in engine.compile_counts()
-    assert engine.scheduler.resume == "block"
+    assert engine.kv.beside.whole_blocks and not engine.kv.beside.stride  # resumed at a block
 
     async def main():
         with pytest.raises(ValueError, match="not K-plus-V pages"):

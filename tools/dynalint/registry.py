@@ -357,11 +357,8 @@ SNAPSHOT_EXEMPT = {
     "num_cached_prompt": "target-side admission metric",
     "num_sealed_blocks": "target-side sealing cursor",
     "pin_ids": "pre-admission pin never outlives the source scheduler",
-    "state_slot": "target-side live state slot (engine/kv_manager.py), taken at its own admission",
-    "state_start": "target-side admission state: where the row's next step reads its state from",
-    "snapshot_due": "a snapshot slot of the source's pool, attached within the step that took it",
-    "window_ids": "target-side window pages (engine/kv_manager.py's second pool), taken at its own admission",
-    "window_base": "target-side admission state: the logical block the row's window pages begin at",
+    "beside": "target-side holdings of what the family keeps beside the pages (engine/resume.py: "
+              "a live slot, window pages), taken at its own admission from the target's pools",
     # Transient scheduler/engine flags that must NOT travel:
     "awaiting_fetch": "in-flight fetch is quiesced before freeze",
     "riding_chain": "set only while awaiting_fetch is: quiesced before freeze",
