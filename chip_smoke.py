@@ -1551,8 +1551,60 @@ GRANITE_LIMITS = {  # the most each may read
 
 
 def child_parity_granite(rehearse: bool) -> None:
+    parity_slotted(rehearse, "granite", "granitemoehybrid",
+                   GRANITE_REHEARSAL if rehearse else GRANITE, GRANITE_LIMITS)
+
+
+# `--child parity-kimi-linear`: chipbench/configs/kimi-linear-48b-a3b-8l-ep8.json
+# at its published widths (two whole periods: six KDA layers and two latent
+# attention layers) under reason-shared's shapes, against the float32 reference
+# (dynamo_tpu/models/reference/kimi_linear.py: the delta rule one token at a
+# time, MLA decompressed) on the same dequantised weights.  A sibling of
+# `parity-granite` and the SAME walk (``parity_slotted``): row A prefills a
+# 1500-token prompt cold in 512-token chunks, leaving a snapshot at 1024; row B
+# the same prompt behind a 1024-token hit (A's 64 latent pages, the state from
+# the snapshot); both decode 64 tokens in 16 fused chunks; B must EQUAL A; the
+# check's jit teacher-forced on A's tokens against the reference.  Controls as
+# granite's: the state dropped at every stride boundary must fail; the state
+# rounded to bfloat16 after every step and the error at depths 1 (a KDA layer
+# with the dense MLP) and 4 (one period) are reported and held to nothing.
+KIMI_LINEAR = {"config": "chipbench/configs/kimi-linear-48b-a3b-8l-ep8.json", "prefix": 1024,
+               "prompt": 1500, "decode": 64, "num_blocks": 1024, "q_block": 512, "depths": (1, 4)}
+KIMI_LINEAR_REHEARSAL = dict(KIMI_LINEAR, prefix=128, prompt=200, decode=8, num_blocks=128,
+                             q_block=64, depths=(1,))
+# As GRANITE_LIMITS: each between the largest the system read and the least the
+# control read on the chip over seeds 28 / 29 / 30 (my chip run, PR 53; PERF.md
+# section 6): system | control.
+KIMI_LINEAR_LIMITS = {
+    # 0.088-0.091 | 0.205-0.212.  By depth (`depth_rms_err`, seeds 28 / 29 / 30): 0.0335 / 0.0333 /
+    # 0.0333 after one layer (KDA and the dense MLP), 0.059 / 0.061 / 0.057 after one period: the
+    # root of the depth, as granite's: every layer adds W8A8 noise and router near-ties of its own
+    # size.  A state ROUNDED TO BFLOAT16 after every step reads 0.084-0.092 and passes every limit
+    # here (`bf16_state_*`): the state's precision is held by tests/test_kimi_linear.py on the CPU.
+    "rms_err": 0.14,
+    "rel_err": 0.35,  # 0.112-0.135 | 0.920-1.136: the largest single logit error
+    "rms_err_worst_position": 0.40,  # 0.135-0.152 | 1.056-1.075: a position 8 past a boundary
+    "rms_err_past_boundary": 0.33,  # 0.078-0.103 | 1.049-1.058: the two positions 8 past a boundary
+    # The engine's own top-20 are at chunk ENDS and decode steps, hundreds of tokens past a
+    # drop: the control reads 0.078-0.094 against the system's 0.043-0.067 and is not held to
+    # this one (half again the system's largest).
+    "engine_link": 0.10,
+    "hit_vs_cold": 0.0,  # the same programs over the same values: equal to the bit
+}
+
+
+def child_parity_kimi_linear(rehearse: bool) -> None:
+    parity_slotted(rehearse, "kimi_linear", "kimi_linear",
+                   KIMI_LINEAR_REHEARSAL if rehearse else KIMI_LINEAR, KIMI_LINEAR_LIMITS)
+
+
+def parity_slotted(rehearse: bool, tag: str, reference: str, par: dict, held_to: dict) -> None:
+    """The parity walk of a hybrid model whose mixers keep their state in
+    SLOTS (Mamba-2, KDA): see `parity-granite` above.  ``reference``: the
+    module under dynamo_tpu/models/reference; ``tag`` names the emitted lines."""
     t0 = time.time()
     dev = child_device(rehearse)
+    import importlib
     import types
 
     import jax
@@ -1565,9 +1617,8 @@ def child_parity_granite(rehearse: bool) -> None:
     from dynamo_tpu.models import lfm2
     from dynamo_tpu.models.config import ModelConfig, register_config
     from dynamo_tpu.models.family import RaggedBatch
-    from dynamo_tpu.models.reference import granitemoehybrid as ref
 
-    par = GRANITE_REHEARSAL if rehearse else GRANITE
+    ref = importlib.import_module("dynamo_tpu.models.reference." + reference)
     seed = int(os.environ.get("DSV32_PARITY_SEED", "28"))
     with open(os.path.join(HERE, par["config"])) as f:
         body = json.load(f)
@@ -1579,7 +1630,7 @@ def child_parity_granite(rehearse: bool) -> None:
         hf = {k: v for k, v in body.items() if k not in (
             "name", "source", "serve", "chips", "reduced", "assumed", "stands_for",
             "rehearsal", "notes")}
-    mc = register_config(ModelConfig.from_hf_config(hf, name="parity-granite"))
+    mc = register_config(ModelConfig.from_hf_config(hf, name="parity-" + tag.replace("_", "-")))
     kv_scale = serve.get("kv_scale", 1.0)
     cfg = EngineConfig(
         model=mc.name, block_size=serve["block_size"], num_blocks=par["num_blocks"],
@@ -1590,7 +1641,14 @@ def child_parity_granite(rehearse: bool) -> None:
         weight_quant=serve.get("weight_quant"), seed=20260900 + seed)
     engine = TpuEngine(cfg)
     mc, fam = engine.model_config, engine.family
-    emit("granite_engine", t0, **dev, attn_impl=engine.attn_impl,
+
+    def mixer_group(kind: str) -> str:
+        """The leaf group of a layer kind's mixer (models/lfm2.py)."""
+        if kind in ("mamba", "kda"):
+            return kind
+        return "mla" if lfm2.latent_attention(mc) else "attn"
+
+    emit(tag + "_engine", t0, **dev, attn_impl=engine.attn_impl,
          decode_kernel=engine.decode_kernel, prefill_kernel=engine.prefill_kernel,
          slots=[engine.kv.beside.live.size, engine.kv.beside.snapshots.size],
          hbm=(jax.local_devices()[0].memory_stats() or {}).get("bytes_in_use"))
@@ -1669,7 +1727,7 @@ def child_parity_granite(rehearse: bool) -> None:
     hit_vs_cold = max(float(np.abs(top["A"][p][2] - top["B"][p][2]).max()) for p in shared)
     hit_same = sum(int(top["A"][p][0] == top["B"][p][0]
                        and np.array_equal(top["A"][p][1], top["B"][p][1])) for p in shared)
-    emit("granite_engine_programs", t1, positions=len(shared), hit_same_tokens_and_top20=hit_same,
+    emit(tag + "_engine_programs", t1, positions=len(shared), hit_same_tokens_and_top20=hit_same,
          hit_vs_cold_nats=hit_vs_cold, snapshot_nonzero=snapshot_is_a_copy)
 
     # ---- (2) whole logits by the check's jit, teacher-forced on A's tokens
@@ -1717,22 +1775,23 @@ def child_parity_granite(rehearse: bool) -> None:
     compare = np.asarray([a + n - 1 for a, n in check_chunks] + list(range(n_prompt, T)))
     t1 = time.time()
     sys_logits, cache = system(cache, tab_c, 0)
-    emit("granite_system", t1, positions=len(compare))
+    emit(tag + "_system", t1, positions=len(compare))
     t1 = time.time()
     ctl_logits, cache = system(cache, tab_d, chunk)
-    emit("granite_dropped_state", t1)
+    emit(tag + "_dropped_state", t1)
     t1 = time.time()
     bf16_logits, cache = system(cache, tab_d, 0, after=as_bf16)
-    emit("granite_bf16_state", t1)
+    emit(tag + "_bf16_state", t1)
 
     # ---- (4) the leading layers of the same weights, for the error's growth with depth
     t1 = time.time()
     sys_at = {}
     for depth in par["depths"]:
         mc_d = mc.with_overrides(num_layers=depth, layer_types=mc.layer_types[:depth])
-        n_mamba = lfm2.mamba_layers(mc_d)
-        kept = {"layers": depth, "moe": depth, "shared": depth, "mamba": n_mamba,
-                "attn": depth - n_mamba}
+        dense = min(depth, mc.first_k_dense_replace)
+        kept = {"layers": depth, "dense": dense, "moe": depth - dense, "shared": depth - dense,
+                **{g: sum(mixer_group(k) == g for k in mc_d.layer_types)
+                   for g in ("mamba", "attn", "kda", "mla")}}
         params_d = {g: {k: a[:kept[g]] for k, a in v.items()} if g in kept else v
                     for g, v in params.items()}
         cache_d = fam.create_cache(mc_d, cfg.num_blocks, bs, dtype=cache.pages.dtype,
@@ -1740,7 +1799,7 @@ def child_parity_granite(rehearse: bool) -> None:
         sys_at[depth], cache_d = system(cache_d, tab_c, 0, fwd=forward_of(mc_d, engine.kv_scale),
                                         params=params_d)
         del cache_d, params_d
-    emit("granite_depths", t1, depths=list(par["depths"]))
+    emit(tag + "_depths", t1, depths=list(par["depths"]))
 
     # ---- the engine leaves the chip; its weights stay on the host
     host_params = jax.tree_util.tree_map(np.asarray, engine.params)
@@ -1762,11 +1821,12 @@ def child_parity_granite(rehearse: bool) -> None:
         return w
 
     def layer_f32(l):
-        kinds = mc.layer_types
-        mixer = "mamba" if kinds[l] == "mamba" else "attn"
-        i = sum(k == kinds[l] for k in kinds[:l])
+        kinds, dense = mc.layer_types, mc.first_k_dense_replace
+        i = sum(mixer_group(k) == mixer_group(kinds[l]) for k in kinds[:l])
+        ffn = [("dense", l)] if l < dense else [("moe", l - dense)] + (
+            [("shared", l - dense)] if "shared" in host_params else [])
         lp = {}
-        for g, at in (("layers", l), (mixer, i), ("moe", l), ("shared", l)):
+        for g, at in [("layers", l), (mixer_group(kinds[l]), i)] + ffn:
             for name in host_params[g]:
                 if not name.endswith("_scale"):
                     lp[name] = f32_leaf(g, name, at)
@@ -1778,16 +1838,18 @@ def child_parity_granite(rehearse: bool) -> None:
         pos = jnp.arange(T, dtype=jnp.int32)
         h = hf.get("embedding_multiplier", 1.0) * embed[jnp.asarray(tokens[:T])]
         held = ref.held_experts(hf)
+        head = f32_leaf("top", "lm_head") if "lm_head" in host_params else embed.T
         logits_of = lambda h: np.asarray(
-            ref.rms_norm(h[compare], final_norm, hf.get("rms_norm_eps", 1e-5)) @ embed.T
+            ref.rms_norm(h[compare], final_norm, hf.get("rms_norm_eps", 1e-5)) @ head
         ) / hf.get("logits_scaling", 1.0)
         ref_at = {}
-        for l, kind in enumerate(mc.layer_types):
+        # The reference's own names of the kinds, where it has them.
+        for l, kind in enumerate(getattr(ref, "layer_kinds", lambda _: mc.layer_types)(hf)):
             h = ref.layer(layer_f32(l), hf, h, pos, kind, held, par["q_block"])
             if l + 1 in sys_at:
                 ref_at[l + 1] = logits_of(h)
         ref_logits = logits_of(h)
-    emit("granite_reference", t1)
+    emit(tag + "_reference", t1)
 
     def rel_err(a, b):
         return float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
@@ -1843,21 +1905,21 @@ def child_parity_granite(rehearse: bool) -> None:
             **{str(d): rms_err(sys_at[d][n_pre:], ref_at[d][n_pre:]) for d in sys_at},
             str(mc.num_layers): rms_err(sys_logits[n_pre:], ref_logits[n_pre:])},
         "ref_max_abs_logit": ref_max, "positions": int(len(compare)), "context": int(T),
-        "seed": seed, "limits": GRANITE_LIMITS,
+        "seed": seed, "limits": held_to,
     }
     out["bf16_state_over"] = [n for n in GRANITE_READINGS
-                              if out["bf16_state_" + n] > GRANITE_LIMITS[n]]
-    emit("granite_parity", t0, **out)
+                              if out["bf16_state_" + n] > held_to[n]]
+    emit(tag + "_parity", t0, **out)
     if not rehearse:
         over = [f"{n} {out[n]} against its limit {limit}"
-                for n, limit in GRANITE_LIMITS.items() if out[n] > limit]
+                for n, limit in held_to.items() if out[n] > limit]
         if over:
-            fail("granite: " + "; ".join(over))
+            fail(tag + ": " + "; ".join(over))
         if hit_same != len(shared):
-            fail(f"granite: the hit's tokens or top-20 differ from the cold prefill's at "
+            fail(f"{tag}: the hit's tokens or top-20 differ from the cold prefill's at "
                  f"{len(shared) - hit_same} of {len(shared)} positions")
-        if not any(out["dropped_state_" + n] > GRANITE_LIMITS[n] for n in GRANITE_READINGS):
-            fail("granite: the control (state dropped at the stride) passes every limit: "
+        if not any(out["dropped_state_" + n] > held_to[n] for n in GRANITE_READINGS):
+            fail(tag + ": the control (state dropped at the stride) passes every limit: "
                  "too loose")
     print(json.dumps(dev), flush=True)
 
@@ -2515,6 +2577,7 @@ def main() -> None:
          "parity-kimi-k2": child_parity_kimi_k2,
          "parity-lfm2": child_parity_lfm2,
          "parity-granite": child_parity_granite,
+         "parity-kimi-linear": child_parity_kimi_linear,
          "parity-k-exaone": child_parity_k_exaone,
          "tp1": lambda r: child_tp1(r, args.ref),
          "tp4": lambda r: child_tp4(r, args.ref)}[args.child](args.rehearse_cpu)

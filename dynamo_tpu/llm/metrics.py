@@ -806,9 +806,17 @@ class SparseModelMetrics:
         self.moe_experts_held = 0
         self.conv_row_starts = {"zero": 0, "tail": 0}  # prompt-chunk rows by their start
         self.conv_tokens = 0
+        self.kda_tokens: Dict[str, int] = {}  # form -> tokens
 
     def reset(self) -> None:
         self.__init__()
+
+    def add_kda(self, form: str, ns) -> None:
+        """Add a dispatch's tokens to the KDA layers' account
+        (models/kda.py) under the form they went through: ``scan`` (the
+        chunked form, a unified step's rows) or ``step`` (one token a row: a
+        fused decode chunk counts its steps)."""
+        self.kda_tokens[form] = self.kda_tokens.get(form, 0) + sum(max(0, int(n)) for n in ns)
 
     def add_dsa(self, kind: str, topk: int, starts, ns, prefill_form: Optional[str] = None) -> None:
         """Add a dispatch's query tokens to the selector's account: the token
@@ -867,6 +875,7 @@ class SparseModelMetrics:
                 "mla": {k: list(v) for k, v in self.mla.items()},
                 "conv_row_starts": dict(self.conv_row_starts),
                 "conv_tokens": self.conv_tokens,
+                "kda_tokens": dict(self.kda_tokens),
                 "moe_local_pairs": self.moe_local_pairs,
                 "moe_routed_tokens": self.moe_routed_tokens,
                 "moe_experts_read": self.moe_experts_read,
@@ -898,6 +907,14 @@ class SparseModelMetrics:
             lines += [f"# HELP {name} Tokens dispatched through the short-convolution layers "
                       "(a fused decode chunk counts its steps)",
                       f"# TYPE {name} counter", f"{name} {self.conv_tokens}"]
+        if self.kda_tokens:
+            name = f"{prefix}_kda_tokens_total"
+            lines += [f"# HELP {name} Tokens dispatched through the Kimi Delta Attention layers, "
+                      "by form: scan (the chunked form of a unified step) or step (one token a "
+                      "row; a fused decode chunk counts its steps)",
+                      f"# TYPE {name} counter"]
+            lines += [f'{name}{{form="{escape_label(k)}"}} {v}'
+                      for k, v in sorted(self.kda_tokens.items())]
         for acc, series in (
             (self.dsa, (
                 ("dsa_context_positions_total",

@@ -28,7 +28,10 @@ LLAMA_MODEL_TYPES = ("llama", "qwen2", "mistral", "mixtral")
 # convolution (lfm2_moe), a Mamba-2 scan (granitemoehybrid) or GQA attention;
 # dense then sparse feed-forwards.  exaone_moe: attention in every layer, of
 # which most keep a window of the last positions only (docs/k_exaone.md).
-HYBRID_MODEL_TYPES = ("lfm2_moe", "granitemoehybrid", "exaone_moe")
+# kimi_linear: Kimi Delta Attention layers (models/kda.py, state in slots as
+# Mamba-2's) among LATENT attention layers without rotation, whose pages are
+# the latent family's (docs/kimi_linear.md).
+HYBRID_MODEL_TYPES = ("lfm2_moe", "granitemoehybrid", "exaone_moe", "kimi_linear")
 
 
 @dataclass(frozen=True)
@@ -114,6 +117,14 @@ class ModelConfig:
     sliding_window: int = 0
     rope_full_attention: bool = True
     post_norm: bool = False
+    # kimi_linear (docs/kimi_linear.md): a "kda" layer's heads, its head size
+    # (keys and values alike) and its taps (models/kda.py), all 0 elsewhere;
+    # its attention layers are latent (the MLA keys above with q_lora_rank 0:
+    # one q projection) and ``mla_rope`` False: nothing is rotated.
+    kda_n_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 0
+    mla_rope: bool = True
 
     @property
     def is_moe(self) -> bool:
@@ -144,6 +155,8 @@ class ModelConfig:
             return cls._from_granite_hybrid(cfg, name)
         if model_type == "exaone_moe":
             return cls._from_exaone_moe(cfg, name)
+        if model_type == "kimi_linear":
+            return cls._from_kimi_linear(cfg, name)
         if model_type in HYBRID_MODEL_TYPES:
             return cls._from_hybrid(cfg, name)
         if model_type is not None and model_type not in LLAMA_MODEL_TYPES:
@@ -442,6 +455,82 @@ class ModelConfig:
             sliding_window=window,
             rope_full_attention="sliding_attention" not in layer_types,
             post_norm=True,
+        )
+
+    @classmethod
+    def _from_kimi_linear(cls, cfg: Dict[str, Any], name: str) -> "ModelConfig":
+        """``kimi_linear``'s keys (docs/kimi_linear.md).  ``linear_attn_config``
+        names the layers of each kind, counted from 1.  ``num_experts`` counts
+        the experts held here; a file cut to one chip's share states the
+        router's width beside it (``num_experts_published``) with
+        ``ep_size``/``ep_rank``."""
+        L = cfg["num_hidden_layers"]
+        lin = cfg["linear_attn_config"]
+        kda, full = set(lin["kda_layers"]), set(lin["full_attn_layers"])
+        if kda & full or kda | full != set(range(1, L + 1)):
+            raise ValueError(
+                f"linear_attn_config: kda_layers and full_attn_layers must name each of the "
+                f"layers 1..{L} once")
+        for key, want, why in (
+                ("mla_use_nope", True, "the latent layers rotate nothing"),
+                ("q_lora_rank", None, "one q projection, no compressed query"),
+                ("num_expert_group", 1, "the gate chooses in one group"),
+                ("topk_group", 1, "the gate chooses in one group"),
+                ("num_nextn_predict_layers", 0, "a draft head is not served"),
+                ("moe_router_activation_func", "sigmoid", "the gate scores with a sigmoid"),
+                ("moe_layer_freq", 1, "every layer past the dense ones has experts"),
+                ("rope_scaling", None, "nothing is rotated"),
+                ("hidden_act", "silu", "SwiGLU")):
+            if cfg.get(key, want) != want:
+                raise ValueError(f"{key} {cfg[key]!r} is not supported ({why}: {want!r})")
+        held = cfg["num_experts"]
+        ep_size = cfg.get("ep_size", 1)
+        total = cfg.get("num_experts_published", held * ep_size)
+        ep_rank = cfg.get("ep_rank", 0)
+        if held * ep_size != total or not 0 <= ep_rank < ep_size:
+            raise ValueError(
+                f"num_experts {held} x ep_size {ep_size} (ep_rank {ep_rank}) is not the "
+                f"router's width {total}")
+        eos = cfg.get("eos_token_id", ())
+        if isinstance(eos, int):
+            eos = (eos,)
+        shared = cfg.get("num_shared_experts", 0)
+        return cls(
+            name=name or cfg.get("_name_or_path", "hf-model"),
+            model_type=cfg["model_type"],
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            num_layers=L,
+            num_heads=cfg["num_attention_heads"],
+            num_kv_heads=cfg.get("num_key_value_heads", cfg["num_attention_heads"]),
+            head_dim=cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"],
+            intermediate_size=cfg["intermediate_size"],
+            rope_theta=float(cfg.get("rope_theta", 10000.0)),
+            rms_norm_eps=cfg.get("rms_norm_eps", 1e-5),
+            max_position=cfg.get("model_max_length", cfg.get("max_position_embeddings", 1048576)),
+            tie_word_embeddings=cfg.get("tie_word_embeddings", False),
+            num_experts=held,
+            num_experts_per_token=cfg["num_experts_per_token"],
+            num_shared_experts=shared,
+            moe_intermediate_size=cfg["moe_intermediate_size"],
+            eos_token_ids=tuple(eos),
+            kv_lora_rank=cfg["kv_lora_rank"],
+            qk_nope_head_dim=cfg["qk_nope_head_dim"],
+            qk_rope_head_dim=cfg["qk_rope_head_dim"],
+            v_head_dim=cfg["v_head_dim"],
+            first_k_dense_replace=cfg.get("first_k_dense_replace", 0),
+            routed_scaling_factor=float(cfg.get("routed_scaling_factor", 1.0)),
+            norm_topk_prob=cfg.get("moe_renormalize", True),
+            router_experts=total,
+            ep_size=ep_size,
+            ep_rank=ep_rank,
+            layer_types=tuple("kda" if l in kda else "full_attention" for l in range(1, L + 1)),
+            use_rope=False,
+            shared_intermediate_size=shared * cfg["moe_intermediate_size"],
+            kda_n_heads=lin["num_heads"],
+            kda_head_dim=lin["head_dim"],
+            kda_conv=lin["short_conv_kernel_size"],
+            mla_rope=False,
         )
 
     @classmethod

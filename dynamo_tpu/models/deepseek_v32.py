@@ -298,6 +298,114 @@ def _write(pages, rows, slots):
         NP, ps, d)
 
 
+class MlaStep(NamedTuple):
+    """What the latent layers of ONE step share: made once a step by
+    ``mla_step`` from the step's rows, static parts included."""
+
+    pos: jnp.ndarray  # [T]
+    real: jnp.ndarray  # [T] False for a padding token (slot -1)
+    first: jnp.ndarray  # [S] a row's first token (a single-token row's token)
+    single: jnp.ndarray  # [S] rows of one query token
+    inv_freq: Any  # the rotation's frequencies; None: nothing is rotated
+    sm_scale: float
+    decode: bool
+
+
+def mla_step(c: ModelConfig, rb: RaggedBatch, decode: bool) -> MlaStep:
+    T = rb.token_ids.shape[0]
+    # ``mla_rope`` False (kimi_linear): the "rope" lanes of q and of the
+    # latent entry are used as they come out of the projections.
+    inv_freq = (rope_frequencies(c.qk_rope_head_dim, c.rope_theta, c.rope_scaling)
+                if c.mla_rope else None)
+    sm_scale = (c.qk_nope_head_dim + c.qk_rope_head_dim) ** -0.5 * yarn_mscale(c.rope_scaling) ** 2
+    real = rb.slot_mapping >= 0
+    S = rb.kv_lens.shape[0]
+    q_lens = rb.cu_q_lens[1:] - rb.cu_q_lens[:-1]
+    first = jnp.clip(rb.cu_q_lens[:-1], 0, T - 1)
+    single = (q_lens == 1) & (jnp.arange(S) < rb.num_seqs[0])
+    return MlaStep(rb.positions, real, first, single, inv_freq, sm_scale, decode)
+
+
+def mla_project(x, lp: Params, c: ModelConfig, st: MlaStep, width: int):
+    """A latent layer's projections of the step's tokens ``x`` [T, D]: (the
+    compressed query, None without ``q_lora_rank``: then ONE projection
+    ``wq`` and no ``q_norm``; q [T, H, dn + dr]; its last dr lanes, rotated
+    where the model rotates; the cache entry [T, ``width``] = [RMSNorm(c) |
+    k^R | zero lanes]; ``absorbed(rows)``: the queries of those tokens in the
+    absorbed form, q~ = W^UK^T q^N, which scores the cached entry directly)."""
+    T = x.shape[0]
+    H, dn, dr, Rkv = c.num_heads, c.qk_nope_head_dim, c.qk_rope_head_dim, c.kv_lora_rank
+    if c.q_lora_rank:  # static
+        cq = rms_norm(linear(x, lp, "wq_a"), lp["q_norm"], c.rms_norm_eps)
+        q = linear(cq, lp, "wq_b").reshape(T, H, dn + dr)
+    else:
+        cq, q = None, linear(x, lp, "wq").reshape(T, H, dn + dr)
+    rotate = (lambda v: v) if st.inv_freq is None else (
+        lambda v: apply_rope_interleaved(v, st.pos, st.inv_freq))
+    q_rope = rotate(q[..., dn:])
+    kv = linear(x, lp, "wkv_a")
+    k_rope = rotate(kv[:, None, Rkv:])[:, 0]
+    tail = width - Rkv - dr  # zero lanes up to the stored width
+    entry = jnp.concatenate(
+        [rms_norm(kv[:, :Rkv], lp["kv_norm"], c.rms_norm_eps), k_rope,
+         jnp.zeros((T, tail), kv.dtype)], axis=-1)
+
+    def absorbed(rows=slice(None)):
+        return jnp.concatenate(
+            [jnp.einsum("thn,hcn->thc", q[rows, :, :dn], lp["w_uk"]), q_rope[rows],
+             jnp.zeros(q_rope[rows].shape[:2] + (tail,), q.dtype)], axis=-1)
+
+    return cq, q, q_rope, entry, absorbed
+
+
+def mla_places(rb: RaggedBatch, l, pages_a_layer: int, page_size: int):
+    """(flat slots [T], page tables [S, PP]) of layer ``l`` in the latent
+    pages of all layers laid end to end."""
+    slots = jnp.where(rb.slot_mapping < 0, -1, rb.slot_mapping + l * (pages_a_layer * page_size))
+    return slots, rb.page_indices + l * pages_a_layer
+
+
+def dense_attention(c: ModelConfig, rb: RaggedBatch, st: MlaStep, absorbed, q, q_rope, entry,
+                    lp: Params, lat, slots, tables):
+    """No selector: a one-token row attends to its whole context in the
+    absorbed form (the kernel), a prompt chunk in the decompressed form,
+    and a chunk's output needs no W^UV: it is per head already.  Returns (the
+    layer's output [T, D], the pages with the step's entries written)."""
+    T = q.shape[0]
+    S = rb.kv_lens.shape[0]
+    H, dn, dv, Rkv = c.num_heads, c.qk_nope_head_dim, c.v_head_dim, c.kv_lora_rank
+    lat = _write(lat, entry, slots)
+    kw = dict(sm_scale=st.sm_scale, rank_v=Rkv)
+
+    def one_token_rows(_):
+        o_lat = dense_decode_attention(
+            absorbed(st.first), lat, jnp.where(st.single, rb.kv_lens, 0), tables, **kw)
+        return jnp.einsum("shc,hcv->shv", o_lat, lp["w_uv"])  # [S, H, dv]
+
+    if st.decode:
+        o = one_token_rows(None)
+    else:
+        o = dense_prefill_attention(
+            jnp.concatenate([q[..., :dn], q_rope], axis=-1), lat, lp["w_uk"], lp["w_uv"],
+            rb.kv_lens, tables, rb.cu_q_lens, rb.num_seqs, sm_scale=st.sm_scale)
+        o1 = jax.lax.cond(jnp.any(st.single), one_token_rows,
+                          lambda _: jnp.zeros((S, H, dv), o.dtype), None)
+        o = o.at[jnp.where(st.single, st.first, T)].set(o1, mode="drop")
+    return linear(o.reshape(T, H * dv), lp, "wo"), lat
+
+
+def mla_block(x, lp: Params, c: ModelConfig, rb: RaggedBatch, st: MlaStep, l, lat,
+              pages_a_layer: int):
+    """One latent attention layer WITHOUT a selector over the flat pages
+    ``lat`` [layers * P, ps, width], of which layer ``l``'s are the l-th
+    ``pages_a_layer``: this family's layers where ``index_topk`` is 0, and
+    the hybrid family's latent layers (models/lfm2.py).  Returns (the layer's
+    output [T, D], the pages)."""
+    _, q, q_rope, entry, absorbed = mla_project(x, lp, c, st, lat.shape[-1])
+    slots, tables = mla_places(rb, l, pages_a_layer, lat.shape[1])
+    return dense_attention(c, rb, st, absorbed, q, q_rope, entry, lp, lat, slots, tables)
+
+
 def forward_ragged(
     params: Params,
     config: ModelConfig,
@@ -326,38 +434,17 @@ def forward_ragged(
     H, dn, dr, dv, Rkv = c.num_heads, c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim, c.kv_lora_rank
     Hi, di, eps = c.index_n_heads, c.index_head_dim, c.rms_norm_eps
     selector = c.index_topk > 0
-    inv_freq = rope_frequencies(dr, c.rope_theta, c.rope_scaling)
-    sm_scale = (dn + dr) ** -0.5 * yarn_mscale(c.rope_scaling) ** 2
+    st = mla_step(c, rb, decode)
+    pos, real, first, single, inv_freq, sm_scale = st[:6]
     L, P_layer, ps = cache.latent.shape[:3]
     Ld = min(c.first_k_dense_replace, L)
-    pos = rb.positions
-    real = rb.slot_mapping >= 0  # [T] padding tokens carry slot -1
     S = rb.kv_lens.shape[0]
-    q_lens = rb.cu_q_lens[1:] - rb.cu_q_lens[:-1]
-    first = jnp.clip(rb.cu_q_lens[:-1], 0, T - 1)  # a single-token row's token
-    single = (q_lens == 1) & (jnp.arange(S) < rb.num_seqs[0])
 
     def attention(x, lp, l, lat, idx):
-        cq = rms_norm(linear(x, lp, "wq_a"), lp["q_norm"], eps)
-        q = linear(cq, lp, "wq_b").reshape(T, H, dn + dr)
-        q_rope = apply_rope_interleaved(q[..., dn:], pos, inv_freq)
-        kv = linear(x, lp, "wkv_a")
-        k_rope = apply_rope_interleaved(kv[:, None, Rkv:], pos, inv_freq)[:, 0]
-        tail = lat.shape[-1] - Rkv - dr  # zero lanes up to the stored width
-        entry = jnp.concatenate(
-            [rms_norm(kv[:, :Rkv], lp["kv_norm"], eps), k_rope, jnp.zeros((T, tail), kv.dtype)],
-            axis=-1)
-        slots = jnp.where(rb.slot_mapping < 0, -1, rb.slot_mapping + l * (P_layer * ps))
-        tables = rb.page_indices + l * P_layer
-
-        # Absorbed form: q~ = W^UK^T q^N scores the cached latent directly.
-        def absorbed(rows=slice(None)):
-            return jnp.concatenate(
-                [jnp.einsum("thn,hcn->thc", q[rows, :, :dn], lp["w_uk"]), q_rope[rows],
-                 jnp.zeros(q_rope[rows].shape[:2] + (tail,), q.dtype)], axis=-1)
-
         if not selector:
-            return dense_attention(absorbed, q, q_rope, entry, lp, lat, slots, tables)
+            return mla_block(x, lp, c, rb, st, l, lat, P_layer) + (None, None)  # no indexer pages, no S_t
+        cq, q, q_rope, entry, absorbed = mla_project(x, lp, c, st, lat.shape[-1])
+        slots, tables = mla_places(rb, l, P_layer, ps)
         # A prompt program attends in the form its token count pays for least
         # (ops/sparse_mla.py ``prefill_form``): the absorbed XLA loop, or S_t
         # as a mask and the decompressed kernel, whose output is per head.
@@ -417,29 +504,6 @@ def forward_ragged(
         if not kernel:
             o = per_head(o)
         return linear(o.reshape(T, H * dv), lp, "wo"), lat, idx, sel
-
-    def dense_attention(absorbed, q, q_rope, entry, lp, lat, slots, tables):
-        """No selector: a one-token row attends to its whole context in the
-        absorbed form (the kernel), a prompt chunk in the decompressed form,
-        and a chunk's output needs no W^UV: it is per head already."""
-        lat = _write(lat, entry, slots)
-        kw = dict(sm_scale=sm_scale, rank_v=Rkv)
-
-        def one_token_rows(_):
-            o_lat = dense_decode_attention(
-                absorbed(first), lat, jnp.where(single, rb.kv_lens, 0), tables, **kw)
-            return jnp.einsum("shc,hcv->shv", o_lat, lp["w_uv"])  # [S, H, dv]
-
-        if decode:
-            o = one_token_rows(None)
-        else:
-            o = dense_prefill_attention(
-                jnp.concatenate([q[..., :dn], q_rope], axis=-1), lat, lp["w_uk"], lp["w_uv"],
-                rb.kv_lens, tables, rb.cu_q_lens, rb.num_seqs, sm_scale=sm_scale)
-            o1 = jax.lax.cond(jnp.any(single), one_token_rows,
-                              lambda _: jnp.zeros((S, H, dv), o.dtype), None)
-            o = o.at[jnp.where(single, first, T)].set(o1, mode="drop")
-        return linear(o.reshape(T, H * dv), lp, "wo"), lat, None, None  # no indexer pages, no S_t
 
     def layer(h, lat, idx, lp, l, is_moe: bool):
         a, lat, idx, sel = attention(rms_norm(h, lp["attn_norm"], eps), lp, l, lat, idx)

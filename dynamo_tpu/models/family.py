@@ -195,22 +195,30 @@ def _unmovable_blocks(cfg: Any) -> list:
 def _hybrid(config: ModelConfig) -> ModelFamily:
     """models/lfm2.py: gated short convolutions whose state lives in the page
     cache beside the attention layers' K/V (resumed at a block's end), or
-    Mamba-2 layers whose state lives in slots (resumed at a snapshot)."""
+    Mamba-2 or KDA layers whose state lives in slots (resumed at a snapshot);
+    the attention layers' pages K/V or, for a model with MLA, latent entries."""
     from ..llm.metrics import sparse_model_metrics, swa_metrics
     from . import lfm2
 
-    slotted = lfm2.mamba_layers(config) > 0
+    with_kda = lfm2.kda_layers(config) > 0
+    slotted = lfm2.mamba_layers(config) > 0 or with_kda
     windowed = lfm2.window_layers(config) > 0
+    latent_pages = lfm2.latent_attention(config)
 
     def kinds(config, cache):
         """K/V bytes a token an attention layer; the convolution state's
         bytes a PAGE a convolution layer (it does not grow inside a page); a
-        Mamba-2 layer's state and tail bytes a SLOT."""
-        out = {"kv": 2 * config.num_kv_heads * config.head_dim * cache.pages.dtype.itemsize}
+        Mamba-2 or KDA layer's state and tail bytes a SLOT; a latent entry's
+        bytes a token a latent layer."""
+        if latent_pages:
+            out = {"latent": cache.pages.shape[-1] * cache.pages.dtype.itemsize}
+        else:
+            out = {"kv": 2 * config.num_kv_heads * config.head_dim * cache.pages.dtype.itemsize}
         if cache.conv is not None:
             out["conv_page"] = cache.conv.shape[2] * cache.conv.shape[3] * cache.conv.dtype.itemsize
         if cache.ssm is not None:
-            out["ssm_slot"] = cache.ssm[0, 0].size * cache.ssm.dtype.itemsize
+            out["kda_slot" if with_kda else "ssm_slot"] = (
+                cache.ssm[0, 0].size * cache.ssm.dtype.itemsize)
             out["conv_tail"] = cache.tail[0, :, 0].size * cache.tail.dtype.itemsize
         if cache.window is not None:  # a token a WINDOW layer, for the last positions only
             out["kv_window"] = out["kv"]
@@ -222,6 +230,10 @@ def _hybrid(config: ModelConfig) -> ModelFamily:
             bad.append("--tp/--dp/--ep/--sp > 1 (no PartitionSpecs for the state pages or the "
                        "window pool; the configuration's ep_size/ep_rank say which experts this "
                        "chip holds)")
+        import jax.numpy as jnp
+
+        if latent_pages and jnp.dtype(cfg.cache_dtype).itemsize == 1:
+            bad.append("--kv-cache-dtype int8/fp8 (latent pages are bfloat16 or wider)")
         if windowed and cfg.prefill_chunk % cfg.block_size:
             bad.append("--prefill-chunk that is no multiple of --block-size (the window pages "
                        "before a resume point are kept by whole blocks)")
@@ -253,6 +265,13 @@ def _hybrid(config: ModelConfig) -> ModelFamily:
     def count_window(config, kind, starts, ns, step_tokens=None):
         swa_metrics.add_queries(config.sliding_window, starts, ns)
 
+    def count_kda(config, kind, starts, ns, step_tokens=None):
+        """The KDA layers' tokens by the form they went through, and the
+        latent layers' queries (whole-context attention: the ``mla_*``
+        account of the latent family)."""
+        sparse_model_metrics.add_kda("scan" if kind == "unified" else "step", ns)
+        sparse_model_metrics.add_mla(kind, starts, ns)
+
     return ModelFamily(
         name="hybrid",
         init_params=lfm2.init_params,
@@ -268,7 +287,7 @@ def _hybrid(config: ModelConfig) -> ModelFamily:
         cache_kinds=kinds,
         check=check,
         # The slots' account is the block manager's (admissions, snapshots).
-        count_dispatch=None if slotted else count_window if windowed else (
+        count_dispatch=count_kda if with_kda else None if slotted else count_window if windowed else (
             lambda config, kind, starts, ns, step_tokens=None: (
                 sparse_model_metrics.add_conv(kind, starts, ns))),
         count_aux=sparse_model_metrics.add_moe,

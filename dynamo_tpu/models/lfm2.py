@@ -22,6 +22,13 @@ the full-attention block of the other models is traced as it was.  Rotation by
 layer kind, a branch's OUTPUT normed (``post_norm``), a leading dense layer,
 one shared expert beside the routed ones: static fields all.
 
+``kimi_linear`` (docs/kimi_linear.md) adds a FIFTH mixer kind, ``kda``: Kimi
+Delta Attention (models/kda.py), whose state (a matrix a head and the tail of
+three convolutions) lives in the slots Mamba-2's lives in.  Its attention
+layers are LATENT (MLA without rotation): the block is the latent family's own
+(``deepseek_v32.mla_block``), and ``HybridCache.pages`` then holds latent
+entries in that family's layout (leaf group ``mla`` in place of ``attn``).
+
 Beside models/llama.py and models/deepseek_v32.py, sharing ``linear``,
 ``rms_norm``, ``mlp``, ``embed_lookup``, ``lm_logits``, the attention ops of
 the dense family, the latent family's ``gate`` and the dispatch of
@@ -58,7 +65,7 @@ import jax.numpy as jnp
 from ..ops.ragged_attention import ragged_attention, write_kv_ragged
 from ..ops.rope import apply_rope, rope_frequencies
 from . import deepseek_v32 as latent
-from . import mamba2
+from . import kda, mamba2
 from .config import ModelConfig
 from .llama import RaggedBatch, embed_lookup, linear, lm_logits, mlp, rms_norm
 from .moe import expert_dispatch
@@ -78,8 +85,10 @@ QUANT_AXES = {
     "top": {"embed": 1, "lm_head": 0},
     "mamba": mamba2.QUANT_AXES,
     "shared": {"w_gate": 1, "w_up": 1, "w_down": 1},
+    "kda": kda.QUANT_AXES,
+    "mla": {"wq": 1, "wkv_a": 1, "wo": 1},
 }
-_ONES = ("op_norm", "ffn_norm", "q_norm", "k_norm", "final_norm") + mamba2.ONES
+_ONES = ("op_norm", "ffn_norm", "q_norm", "k_norm", "final_norm", "kv_norm") + mamba2.ONES + kda.ONES
 EXPERT_LEAVES = latent.EXPERT_LEAVES
 
 
@@ -109,11 +118,22 @@ def window_layers(config: ModelConfig) -> int:
     return sum(t == "sliding_attention" for t in config.layer_types)
 
 
+def kda_layers(config: ModelConfig) -> int:
+    return sum(t == "kda" for t in config.layer_types)
+
+
+def latent_attention(config: ModelConfig) -> bool:
+    """The full-attention layers are latent (MLA): their pages hold latent
+    entries, their leaves are the group ``mla``."""
+    return config.kv_lora_rank > 0
+
+
 def layer_counts(config: ModelConfig) -> Tuple[int, int, int, int]:
     """(convolution, full attention, dense, expert) layers."""
     Lc = sum(t == "conv" for t in config.layer_types)
     Ld = min(config.first_k_dense_replace, config.num_layers)
-    La = config.num_layers - Lc - mamba_layers(config) - window_layers(config)
+    La = (config.num_layers - Lc - mamba_layers(config) - window_layers(config)
+          - kda_layers(config))
     return Lc, La, Ld, config.num_layers - Ld
 
 
@@ -145,7 +165,11 @@ class HybridCache(NamedTuple):
     and ``tail`` [Lm, d_conv - 1, slots, channels] in the activation dtype:
     the Mamba-2 layers' state by SLOT (models/mamba2.py), the first
     ``max_batch`` slots the running rows', the others snapshots; None without
-    such layers (a None leaf is no operand of a program).  ``window`` [Lw, Pw,
+    such layers (a None leaf is no operand of a program).  A model with KDA
+    layers keeps THEIR state in the same two leaves: ``ssm`` [Lk, slots, heads
+    * d_key, d_value] float32, ``tail`` [Lk, taps - 1, slots, 3 * heads *
+    d_key].  With latent attention ``pages`` is [La, P, ps, latent width]: the
+    latent family's entries (``deepseek_v32.LatentKVCache.latent``).  ``window`` [Lw, Pw,
     ps, 2 * KV / pack, pack * head_dim]: the window layers' K/V, pages of a
     pool of their own in the K/V pages' dtype; None without such layers.  The shapes leave
     the chip's compiler ONE layout for a pool: over [.., heads, d_head,
@@ -164,19 +188,28 @@ class HybridCache(NamedTuple):
     def create(cls, config: ModelConfig, num_pages: int, page_size: int,
                dtype=jnp.bfloat16, state_slots: int = 1, window_pages: int = 0) -> "HybridCache":
         Lc, La, _, _ = layer_counts(config)
-        Lm, Lw = mamba_layers(config), window_layers(config)
+        Lm, Lw, Lk = mamba_layers(config), window_layers(config), kda_layers(config)
         pack = head_pack(config)
         act = jnp.dtype(config.dtype)
-        _, Hm, P, N, K = mamba2.dims(config)
-        page = (page_size, 2 * config.num_kv_heads // pack, pack * config.head_dim)
+        if latent_attention(config):
+            page = (page_size, latent.latent_width(config))
+        else:
+            page = (page_size, 2 * config.num_kv_heads // pack, pack * config.head_dim)
+        # The slots' two leaves, by the mixer that lives in them (a model has one).
+        if Lk:
+            Hk, dk, taps = kda.dims(config)
+            state, tail = (Hk * dk, dk), (taps - 1, state_slots, kda.conv_width(config))
+        else:
+            _, Hm, P, N, K = mamba2.dims(config)
+            state, tail = (Hm * P, N), (K - 1, state_slots, mamba2.conv_width(config))
+        Ls = Lm + Lk
         return cls(
             pages=jnp.zeros((La, num_pages) + page, dtype),
             window=jnp.zeros((Lw, window_pages) + page, dtype) if Lw else None,
             conv=jnp.zeros((Lc, num_pages, config.conv_L_cache - 1, config.hidden_size),
                            act) if Lc else None,
-            ssm=jnp.zeros((Lm, state_slots, Hm * P, N), jnp.float32) if Lm else None,
-            tail=jnp.zeros((Lm, K - 1, state_slots, mamba2.conv_width(config)),
-                           act) if Lm else None,
+            ssm=jnp.zeros((Ls, state_slots) + state, jnp.float32) if Ls else None,
+            tail=jnp.zeros((Ls,) + tail, act) if Ls else None,
         )
 
 
@@ -197,7 +230,14 @@ def leaf_shapes(config: ModelConfig) -> Dict[str, Dict[str, tuple]]:
                           "out_proj": (Lc, D, D)}
     # wqkv's columns: q (H heads), k, v (KV heads each).
     norms = {"q_norm": (La, hd), "k_norm": (La, hd)} if c.qk_norm else {}
-    groups["attn"] = {"wqkv": (La, D, (H + 2 * KV) * hd), **norms, "wo": (La, H * hd, D)}
+    if latent_attention(c):
+        # The latent family's attention leaves without the compressed query.
+        Rkv, dn, dr, dv = c.kv_lora_rank, c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim
+        groups["mla"] = {"wq": (La, D, H * (dn + dr)), "wkv_a": (La, D, Rkv + dr),
+                         "kv_norm": (La, Rkv), "w_uk": (La, H, Rkv, dn),
+                         "w_uv": (La, H, Rkv, dv), "wo": (La, H * dv, D)}
+    else:
+        groups["attn"] = {"wqkv": (La, D, (H + 2 * KV) * hd), **norms, "wo": (La, H * hd, D)}
     Lw = window_layers(c)
     if Lw:
         groups["wattn"] = {"wqkv": (Lw, D, (H + 2 * KV) * hd), "q_norm": (Lw, hd),
@@ -213,12 +253,14 @@ def leaf_shapes(config: ModelConfig) -> Dict[str, Dict[str, tuple]]:
     if c.shared_intermediate_size:
         Fs = c.shared_intermediate_size
         groups["shared"] = {"w_gate": (Lm, D, Fs), "w_up": (Lm, D, Fs), "w_down": (Lm, Fs, D)}
+    if kda_layers(c):
+        groups["kda"] = kda.leaf_shapes(c, kda_layers(c))
     return groups
 
 
 def _draw(config: ModelConfig, key: jax.Array, quant: bool) -> Params:
     return latent._draw(config, key, quant, leaf_shapes(config), QUANT_AXES, _ONES,
-                        draws=mamba2.DRAWS)
+                        draws={**mamba2.DRAWS, **kda.DRAWS})
 
 
 def init_params(config: ModelConfig, key: jax.Array) -> Params:
@@ -443,6 +485,13 @@ def forward_ragged(
     def window_block(x, w, wpages):
         return attention(x, at_layer("wattn", w), w, wpages, windowed=True)
 
+    if latent_attention(c):
+        mla = latent.mla_step(c, rb, decode)
+
+    @jax.jit
+    def mla_block(x, a, lat):
+        return latent.mla_block(x, at_layer("mla", a), c, rb, mla, a, lat, P_layer)
+
     @jax.jit
     def moe_layer(x, j):
         return moe_block(x, at_layer("moe", j), c, real, j)
@@ -482,6 +531,30 @@ def forward_ragged(
         h, pairs, read = experts(residual(h, y), l, pairs, read)
         return h, ssm, tail, pairs, read
 
+    def kda_layer_with(dense: bool):
+        @jax.jit
+        def kda_layer(l, m, h, ssm, tail, pairs, read):
+            """One KDA layer with its feed-forward (``dense``: the leading
+            SwiGLU, else the experts), as ``mamba_layer``."""
+            x = rms_norm(h, params["layers"]["op_norm"][l], eps)
+            lp = at_layer("kda", m)
+            if decode:
+                y, ssm, tail = kda.step(x, lp, c, ssm, tail, m, real)
+            else:
+                y, ssm, tail = kda.scan(x, lp, c, ssm, tail, m, rows)
+            h = residual(h, y)
+            if dense:
+                x = norm_in(h, "ffn_norm", l)
+                h = h + norm_out(mlp(x, at_layer("dense", l)), "ffn_norm", l)
+            else:
+                h, pairs, read = experts(h, l, pairs, read)
+            return h, ssm, tail, pairs, read
+
+        return kda_layer
+
+    slot_layers = {("mamba", False): mamba_layer,
+                   **{("kda", dense): kda_layer_with(dense) for dense in (False, True)}}
+
     h = embed_lookup(params, rb.token_ids, dt)
     if c.embedding_multiplier != 1.0:
         h = h * jnp.asarray(c.embedding_multiplier, dt)
@@ -496,21 +569,24 @@ def forward_ragged(
     ci = ai = mi = wi = l = 0
     while l < c.num_layers:  # constant layer numbers: see models/llama.py on decode
         kind = c.layer_types[l]
-        if kind == "mamba":
-            # A run of Mamba-2 layers.  The decode program unrolls it (its
+        if kind in ("mamba", "kda"):
+            # A run of layers whose state lives in slots, of one mixer and one
+            # kind of feed-forward.  The decode program unrolls it (its
             # weights stream: models/llama.py); a prompt program walks it as
             # ONE loop over the layer's number, so its eight token buckets
             # trace and compile one body a run and not one a layer.
             run = 1
-            while l + run < c.num_layers and c.layer_types[l + run] == "mamba":
+            while (l + run < c.num_layers and c.layer_types[l + run] == kind
+                   and (l + run < Ld) == (l < Ld)):
                 run += 1
+            one = slot_layers[kind, l < Ld]
             carry = (h, ssm, tail, pairs, read)
             if decode or run == 1:
                 for i in range(run):
-                    carry = mamba_layer(jnp.int32(l + i), jnp.int32(mi + i), *carry)
+                    carry = one(jnp.int32(l + i), jnp.int32(mi + i), *carry)
             else:
                 carry = jax.lax.fori_loop(
-                    l, l + run, lambda i, cr, d=mi - l: mamba_layer(i, i + d, *cr), carry)
+                    l, l + run, lambda i, cr, d=mi - l: one(i, i + d, *cr), carry)
             h, ssm, tail, pairs, read = carry
             l, mi = l + run, mi + run
             continue
@@ -522,6 +598,9 @@ def forward_ragged(
         elif kind == "sliding_attention":
             y, wpages = window_block(x, jnp.int32(wi), wpages)
             wi += 1
+        elif latent_attention(c):
+            y, pages = mla_block(x, jnp.int32(ai), pages)
+            ai += 1
         else:
             y, pages = attn_block(x, jnp.int32(ai), pages)
             ai += 1

@@ -121,6 +121,74 @@ def _gated_out(y, z, lp: Params, c: ModelConfig, dtype):
     return linear((g * lp["norm_w"].astype(jnp.float32)).astype(dtype), lp, "out_proj")
 
 
+def row_taps(xbc, tail, m, rows: Rows, K: int):
+    """The taps' inputs over the rows of a ragged step: (``prev``, with
+    ``prev[k - 1]`` [T, C] the input k tokens before each token, from its run
+    or, at the run's start, from the tail its row starts from; that tail ``t0``
+    [S, K-1, C], zeros for a row that starts from nothing)."""
+    (T,) = rows.row_of.shape
+    S = rows.first.shape[0]
+    row = jnp.minimum(rows.row_of, S - 1)
+    idx = jnp.arange(T) - rows.first[row]  # a token's place in its row
+    t0 = jnp.take(tail[m], jnp.maximum(rows.read, 0), axis=1).transpose(1, 0, 2)
+    t0 = jnp.where((rows.read >= 0)[:, None, None], t0, 0)  # [S, K-1, C]
+    prev = []
+    for k in range(1, K):
+        run = jnp.concatenate([jnp.zeros((k, xbc.shape[1]), xbc.dtype), xbc[:-k]], axis=0)
+        old = t0[row, jnp.clip(K - 1 - k + idx, 0, K - 2)]
+        prev.append(jnp.where((idx >= k)[:, None], run, old))
+    return prev, t0
+
+
+def leave_tails(xbc, t0, tail, m, rows: Rows, K: int):
+    """What a row leaves in its ``write`` slot and its ``snap`` slot: its last
+    K-1 inputs (older ones from its old tail ``t0``)."""
+    (T,) = rows.row_of.shape
+    S = rows.first.shape[0]
+    j = jnp.arange(K - 1)[None, :]
+    at = rows.count[:, None] - (K - 1) + j  # [S, K-1] place in the row
+    from_run = xbc[jnp.clip(rows.first[:, None] + at, 0, T - 1)]
+    from_old = jnp.take_along_axis(
+        t0, jnp.clip(rows.count[:, None] + j, 0, K - 2)[:, :, None], axis=1)
+    new_tail = jnp.where((at >= 0)[:, :, None], from_run, from_old).astype(tail.dtype)
+    live = (jnp.arange(S) < rows.num) & (rows.count > 0) & (rows.write >= 0)
+    size = tail.shape[2]
+    for to in (jnp.where(live, rows.write, size),
+               jnp.where(live & (rows.snap >= 0), rows.snap, size)):
+        for k in range(K - 1):
+            tail = tail.at[m, k, to].set(new_tail[:, k], mode="drop")
+    return tail
+
+
+def walk_rows(chunk, ssm, m, rows: Rows, Q: int, y, state_shape):
+    """The recurrence a row at a time and ``Q`` of its tokens at a time:
+    ``chunk(k, (y, state), first, count)`` -> (y, state) is one pass; a row's
+    state ``state_shape`` starts from its ``read`` slot of ``ssm[m]`` (zeros
+    without one) and ends in its ``write`` slot and, where ``snap`` names one,
+    there too.  A slot is one block of its pool.  Returns (y, ssm)."""
+    block = (1, 1) + ssm.shape[2:]
+
+    def one_row(r, carry):
+        y, ssm = carry
+        first, count = rows.first[r], rows.count[r]
+        read, write, snap = rows.read[r], rows.write[r], rows.snap[r]
+        slot = lambda i: jax.lax.dynamic_slice(ssm, (m, i, 0, 0), block).reshape(state_shape)
+        state = jnp.where(read >= 0, slot(jnp.maximum(read, 0)), 0.0)
+        y, state = jax.lax.fori_loop(
+            0, (count + Q - 1) // Q, lambda k, cr: chunk(k, cr, first, count), (y, state))
+        # A row of no tokens, or without a slot (warm-up), leaves things as they were.
+        to = jnp.maximum(write, 0)
+        state = jnp.where((count > 0) & (write >= 0), state, slot(to))
+        state = state.reshape(block)
+        ssm = jax.lax.dynamic_update_slice(ssm, state, (m, to, 0, 0))
+        # A row without a snapshot writes its live slot twice.
+        ssm = jax.lax.dynamic_update_slice(
+            ssm, state, (m, jnp.where(snap >= 0, snap, to), 0, 0))
+        return y, ssm
+
+    return jax.lax.fori_loop(0, rows.num, one_row, (y, ssm))
+
+
 def step(x, lp: Params, c: ModelConfig, ssm, tail, m, ok):
     """One token a row: ``x`` [S, D]; ``ssm`` [Lm, S', Hm * P, N] / ``tail``
     [Lm, K-1, S', C] the slot pools (``lfm2.HybridCache`` on their shapes), of
@@ -155,36 +223,13 @@ def scan(x, lp: Params, c: ModelConfig, ssm, tail, m, rows: Rows):
     through a copy of the layer's slots."""
     di, Hm, P, N, K = dims(c)
     (T,) = rows.row_of.shape
-    S = rows.first.shape[0]
     Q = min(SSD_CHUNK, T)
     dtype = x.dtype
     z, xbc, dt = _project(x, lp, c)
     with jax.named_scope("mamba2_scan"):
-        # ---- the taps, over rows: a token's predecessors come from its run
-        # or, at the run's start, from the tail its row starts from.
-        row = jnp.minimum(rows.row_of, S - 1)
-        idx = jnp.arange(T) - rows.first[row]  # a token's place in its row
-        t0 = jnp.take(tail[m], jnp.maximum(rows.read, 0), axis=1).transpose(1, 0, 2)
-        t0 = jnp.where((rows.read >= 0)[:, None, None], t0, 0)  # [S, K-1, C]
-        prev = []
-        for k in range(1, K):
-            run = jnp.concatenate([jnp.zeros((k, xbc.shape[1]), xbc.dtype), xbc[:-k]], axis=0)
-            old = t0[row, jnp.clip(K - 1 - k + idx, 0, K - 2)]
-            prev.append(jnp.where((idx >= k)[:, None], run, old))
+        prev, t0 = row_taps(xbc, tail, m, rows, K)
         act = _taps(prev, xbc, lp, dtype)
-        # What a row leaves: its last K-1 inputs (older ones from its old tail).
-        j = jnp.arange(K - 1)[None, :]
-        at = rows.count[:, None] - (K - 1) + j  # [S, K-1] place in the row
-        from_run = xbc[jnp.clip(rows.first[:, None] + at, 0, T - 1)]
-        from_old = jnp.take_along_axis(
-            t0, jnp.clip(rows.count[:, None] + j, 0, K - 2)[:, :, None], axis=1)
-        new_tail = jnp.where((at >= 0)[:, :, None], from_run, from_old).astype(tail.dtype)
-        live = (jnp.arange(S) < rows.num) & (rows.count > 0) & (rows.write >= 0)
-        size = tail.shape[2]
-        for to in (jnp.where(live, rows.write, size),
-                   jnp.where(live & (rows.snap >= 0), rows.snap, size)):
-            for k in range(K - 1):
-                tail = tail.at[m, k, to].set(new_tail[:, k], mode="drop")
+        tail = leave_tails(xbc, t0, tail, m, rows, K)
 
         # ---- the recurrence, a row at a time and a chunk of its tokens at a time.
         act = act.astype(jnp.float32)
@@ -219,26 +264,7 @@ def scan(x, lp: Params, c: ModelConfig, ssm, tail, m, rows: Rows):
                 y, jnp.where(valid[:, None, None], yq, old), at0, axis=0)
             return y, state
 
-        def one_row(r, carry):
-            y, ssm = carry
-            first, count = rows.first[r], rows.count[r]
-            read, write, snap = rows.read[r], rows.write[r], rows.snap[r]
-            slot = lambda i: jax.lax.dynamic_slice(
-                ssm, (m, i, 0, 0), (1, 1, Hm * P, N)).reshape(Hm, P, N)
-            state = jnp.where(read >= 0, slot(jnp.maximum(read, 0)), 0.0)
-            y, state = jax.lax.fori_loop(
-                0, (count + Q - 1) // Q, lambda k, cr: chunk(k, cr, first, count), (y, state))
-            # A row of no tokens, or without a slot (warm-up), leaves things as they were.
-            to = jnp.maximum(write, 0)
-            state = jnp.where((count > 0) & (write >= 0), state, slot(to))
-            state = state.reshape(1, 1, Hm * P, N)
-            ssm = jax.lax.dynamic_update_slice(ssm, state, (m, to, 0, 0))
-            # A row without a snapshot writes its live slot twice.
-            ssm = jax.lax.dynamic_update_slice(
-                ssm, state, (m, jnp.where(snap >= 0, snap, to), 0, 0))
-            return y, ssm
-
-        y, ssm = jax.lax.fori_loop(0, rows.num, one_row,
-                                   (jnp.zeros((T + Q, Hm, P), jnp.float32), ssm))
+        y, ssm = walk_rows(chunk, ssm, m, rows, Q, jnp.zeros((T + Q, Hm, P), jnp.float32),
+                           (Hm, P, N))
         y = y[:T] + lp["D"].astype(jnp.float32)[None, :, None] * u[:T]
     return _gated_out(y.reshape(T, di), z, lp, c, dtype), ssm, tail

@@ -465,19 +465,14 @@ def test_granite_step_compiles_at_the_cells_shapes_without_copying_either_pool(
     assert all(attn in ln or "moe_grouped_matmul" in ln for ln in calls), calls
 
 
-@pytest.mark.parametrize("decode,metric", [(False, "ssm_scan_time_share"),
-                                           (True, "ssm_step_time_share")],
-                         ids=["unified-512", "decode-32"])
-def test_granite_ssm_metrics_match_the_scopes_ops_and_no_others(
-    granite_step, no_persistent_cache, decode, metric
-):
-    """``ssm_scan_time_share`` / ``ssm_step_time_share`` match XLA's op names
+def _metric_matches_its_scope_alone(step, decode: bool, metric: str, scope: str) -> None:
+    """A ``trace_time_share`` metric of a plain-XLA mixer matches XLA's op names
     (the harness keeps an op's name and shape, not its scope).  In the program
-    compiled for a described v5e every op the pattern matches lies under the
-    scope ``mamba2_scan`` / ``mamba2_step``, and the ops that move the state
-    are matched: another compiler or shape fails HERE, not as a metric that
-    reads 0.  A trace does not keep an op's program either, so the pattern
-    matches NOTHING in the other program: the two shares count no op twice."""
+    compiled for a described v5e every op the pattern matches lies under
+    ``scope``, and the ops its file ``holds`` are matched: another compiler or
+    shape fails HERE, not as a metric that reads 0.  A trace does not keep an
+    op's program either, so the pattern matches NOTHING in the other program:
+    the two shares of a mixer count no op twice."""
     import json
     import os
     import re
@@ -487,7 +482,7 @@ def test_granite_ssm_metrics_match_the_scopes_ops_and_no_others(
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, f"chipbench/layer_metrics/{metric}.json")) as f:
         spec = json.load(f)
-    pattern, scope = re.compile(spec["args"]["pattern"]), "mamba2_step" if decode else "mamba2_scan"
+    pattern = re.compile(spec["args"]["pattern"])
 
     def ops(program):
         """(name as a trace has it, HLO line) of every op outside a fusion's body."""
@@ -499,12 +494,77 @@ def test_granite_ssm_metrics_match_the_scopes_ops_and_no_others(
                 yield short_name(ln.strip().removeprefix("ROOT ")), ln
 
     matched = set()
-    for name, ln in ops(granite_step(decode)):
+    for name, ln in ops(step(decode)):
         if pattern.search(name):
             matched.add(name)
             assert scope in ln, ln
     assert set(spec["holds"]) <= matched, matched
-    assert not [ln for name, ln in ops(granite_step(not decode)) if pattern.search(name)]
+    assert not [ln for name, ln in ops(step(not decode)) if pattern.search(name)]
+
+
+@pytest.mark.parametrize("decode,metric", [(False, "ssm_scan_time_share"),
+                                           (True, "ssm_step_time_share")],
+                         ids=["unified-512", "decode-32"])
+def test_granite_ssm_metrics_match_the_scopes_ops_and_no_others(
+    granite_step, no_persistent_cache, decode, metric
+):
+    """``ssm_scan_time_share`` / ``ssm_step_time_share`` against the scopes
+    ``mamba2_scan`` / ``mamba2_step``."""
+    _metric_matches_its_scope_alone(
+        granite_step, decode, metric, "mamba2_step" if decode else "mamba2_scan")
+
+
+_KIMI_LINEAR_SLOTS = 32 + 32768 * 16 // (5 * 512)  # max_batch live + lfm2.snapshot_slots
+_KIMI_LINEAR_PAGES = 32768 * 16 * 640 * 2 * 2  # latent pages of the two MLA layers
+_KIMI_LINEAR_STATE = _KIMI_LINEAR_SLOTS * 6 * (32 * 128 * 128 * 4 + 3 * 12288 * 2)
+
+
+@pytest.fixture(scope="module")
+def kimi_linear_step(topo):
+    """chipbench/configs/kimi-linear-48b-a3b-8l-ep8.json with its 236 state slots."""
+    return _family_step(
+        topo, "chipbench/configs/kimi-linear-48b-a3b-8l-ep8.json",
+        _KIMI_LINEAR_PAGES + _KIMI_LINEAR_STATE, state_slots=_KIMI_LINEAR_SLOTS)
+
+
+@pytest.mark.parametrize("decode", [False, True], ids=["unified-512", "decode-32"])
+def test_kimi_linear_step_compiles_at_the_cells_shapes_without_copying_a_pool(
+    kimi_linear_step, no_persistent_cache, decode
+):
+    """chipbench/configs/kimi-linear-48b-a3b-8l-ep8.json: 2.8 GB of weights (8
+    layers, 32 of 256 experts, the whole vocabulary), 32768 latent pages of two
+    MLA layers (1.34 GB) and 236 slots of 13 MB of KDA state and tails (3.07
+    GB), a 512-token chunk (or 32 decode rows): 7.2 GB of arguments, the 7.3 GB
+    ISSUE 53 reckons.  The pages and both slot pools are updated in place: the
+    step's temporaries stay far under the 0.51 GB of ONE layer's slots (a copy
+    of a pool, or of a layer of it, into or out of a step would show).  The MLA
+    layers attend in the latent family's two Pallas calls (32 heads) and the
+    experts go through the grouped matmul."""
+    compiled = kimi_linear_step(decode)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _KIMI_LINEAR_PAGES + _KIMI_LINEAR_STATE
+    assert mem.temp_size_in_bytes < 0.2e9, mem
+    assert 7.1e9 < mem.argument_size_in_bytes < 7.4e9, mem
+    calls = _custom_calls(compiled.as_text())
+    count = lambda name: len([ln for ln in calls if name in ln])  # noqa: E731
+    # decode: unrolled, two calls an expert layer; a prompt program: the two runs
+    # of KDA layers with experts each ONE loop body, and the MLA layers' own
+    assert count("moe_grouped_matmul") == (14 if decode else 8), calls
+    assert count("mla_dense_decode_attention") == 2  # riding rows too, in a prompt program
+    assert count("mla_dense_prefill_attention") == (0 if decode else 2)
+    assert all(any(n in ln for n in ("moe_grouped_matmul", "mla_dense_")) for ln in calls), calls
+
+
+@pytest.mark.parametrize("decode,metric", [(False, "kda_scan_time_share"),
+                                           (True, "kda_step_time_share")],
+                         ids=["unified-512", "decode-32"])
+def test_kimi_linear_kda_metrics_match_the_scopes_ops_and_no_others(
+    kimi_linear_step, no_persistent_cache, decode, metric
+):
+    """``kda_scan_time_share`` / ``kda_step_time_share`` (standing by) against
+    the scopes ``kda_scan`` / ``kda_step``."""
+    _metric_matches_its_scope_alone(
+        kimi_linear_step, decode, metric, "kda_step" if decode else "kda_scan")
 
 
 @pytest.mark.parametrize("decode", [False, True], ids=["unified-512", "decode-16"])
