@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 from jax.scipy.linalg import solve_triangular
 
+from ..ops.kda_step import kda_step
 from . import mamba2
 from .config import ModelConfig
 from .llama import linear
@@ -135,27 +136,17 @@ def step(x, lp: Params, c: ModelConfig, ssm, tail, m, ok):
     [Lk, K-1, S', 3 H d] the slot pools (``lfm2.HybridCache`` on their
     shapes), of which this layer's are ``[m]`` and row i's is slot i; ``ok``
     [S] False leaves a row's slot as it was.  Returns (y [S, D], ssm, tail)."""
-    H, d, K = dims(c)
+    _, _, K = dims(c)
     S = x.shape[0]
     dtype = x.dtype
     qkv, g, beta, gate = _project(x, lp, c)
     with jax.named_scope("kda_step"):
         old_tail = tail[m, :, :S]  # [K-1, S, C]
         q, k, v = _heads(_taps([old_tail[K - 1 - j] for j in range(1, K)], qkv, lp, dtype), c)
-        old = ssm[m, :S].reshape(S, H, d, d)  # [S, H, key, value]
-        eg = jnp.exp(g)
-        # S'^T k and S'^T q in ONE pass over the stored state, the decay folded
-        # into the vectors ((diag(e) S)^T k = S^T (e * k)) and the sums taken on
-        # the vector unit in float32: S' is never written out, and a matmul of
-        # two columns a head would load 1024 state tiles into the MXU six times
-        # over.  o = S_t^T q follows without a second pass: S'^T q + u (k . q).
-        read_k = jnp.sum(old * (eg * k)[..., None], axis=2)
-        read_q = jnp.sum(old * (eg * q)[..., None], axis=2)
-        u = beta[..., None] * (v - read_k)
-        o = read_q + u * jnp.sum(k * q, axis=-1, keepdims=True)
-        new = eg[..., None] * old + k[..., :, None] * u[..., None, :]
-        ssm = ssm.at[m, :S].set(
-            jnp.where(ok[:, None, None, None], new, old).reshape(S, H * d, d))
+        # The state's part is ONE call that holds a row's tile in VMEM: both
+        # reads off the OLD state with the decay folded into k and q, u, o and
+        # S = diag(exp g) S + k u^T written back to the slot (ops/kda_step.py).
+        o, ssm = kda_step(ssm, m, jnp.exp(g), k, q, v, beta, ok)
         new_tail = jnp.concatenate([old_tail[1:], qkv[None].astype(tail.dtype)], axis=0)
         tail = tail.at[m, :, :S].set(jnp.where(ok[None, :, None], new_tail, old_tail))
     return _gated_out(o, gate, lp, c, dtype), ssm, tail
