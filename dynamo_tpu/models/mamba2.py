@@ -17,8 +17,8 @@ Two forms of ONE recurrence from ONE set of leaves:
           shares the step: a chunk resumed from a snapshot is, to the bit, the
           chunk of the cold run.  The loops run as many times as the step has
           rows and chunks (padding rows cost nothing).
-``step``  one token a row, every row at once (the fused decode program): row
-          ``i``'s state is slot ``i``, updated in place.
+``step``  one token a row, every row at once (the fused decode program): row ``i``'s state
+          is slot ``i``, updated in place by ONE call a layer (ops/mamba2_step.py).
 """
 
 from __future__ import annotations
@@ -27,7 +27,7 @@ from typing import Any, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
-
+from ..ops.mamba2_step import mamba2_step
 from .config import ModelConfig
 from .llama import linear
 
@@ -204,12 +204,12 @@ def step(x, lp: Params, c: ModelConfig, ssm, tail, m, ok):
         u, B, C = jnp.split(act.astype(jnp.float32), [di, di + N], axis=-1)
         u = u.reshape(S, Hm, P)
         a = jnp.exp(dt * -jnp.exp(lp["A_log"].astype(jnp.float32)))  # [S, Hm]
-        old = ssm[m, :S].reshape(S, Hm, P, N)
-        new = (a[:, :, None, None] * old
-               + (dt[:, :, None] * u)[..., None] * B[:, None, None, :])
-        y = jnp.einsum("shpn,sn->shp", new, C) + lp["D"].astype(jnp.float32)[:, None] * u
-        ssm = ssm.at[m, :S].set(
-            jnp.where(ok[:, None, None, None], new, old).reshape(S, Hm * P, N))
+        # S = a S + dt u B^T written back to the slot and y = S C off the tile
+        # that was written: the pool is aliased through the call, which names
+        # this layer's first S slots and nothing else of it, and leaves a row
+        # whose ``ok`` is False as it was (ops/mamba2_step.py).
+        y, ssm = mamba2_step(ssm, m, a, dt[:, :, None] * u, B, C, ok)
+        y = y + lp["D"].astype(jnp.float32)[:, None] * u
         new_tail = jnp.concatenate([old_tail[1:], xbc[None].astype(tail.dtype)], axis=0)
         tail = tail.at[m, :, :S].set(jnp.where(ok[None, :, None], new_tail, old_tail))
     return _gated_out(y.reshape(S, di), z, lp, c, dtype), ssm, tail
