@@ -807,6 +807,7 @@ class SparseModelMetrics:
         self.conv_row_starts = {"zero": 0, "tail": 0}  # prompt-chunk rows by their start
         self.conv_tokens = 0
         self.kda_tokens: Dict[str, int] = {}  # form -> tokens
+        self.mamba1_tokens: Dict[str, int] = {}  # form -> tokens
 
     def reset(self) -> None:
         self.__init__()
@@ -816,7 +817,15 @@ class SparseModelMetrics:
         (models/kda.py) under the form they went through: ``scan`` (the
         chunked form, a unified step's rows) or ``step`` (one token a row: a
         fused decode chunk counts its steps)."""
-        self.kda_tokens[form] = self.kda_tokens.get(form, 0) + sum(max(0, int(n)) for n in ns)
+        self._add_tokens(self.kda_tokens, form, ns)
+
+    def add_mamba1(self, form: str, ns) -> None:
+        """The same account for the Mamba-1 layers (models/mamba1.py)."""
+        self._add_tokens(self.mamba1_tokens, form, ns)
+
+    @staticmethod
+    def _add_tokens(acc: Dict[str, int], form: str, ns) -> None:
+        acc[form] = acc.get(form, 0) + sum(max(0, int(n)) for n in ns)
 
     def add_dsa(self, kind: str, topk: int, starts, ns, prefill_form: Optional[str] = None) -> None:
         """Add a dispatch's query tokens to the selector's account: the token
@@ -876,6 +885,7 @@ class SparseModelMetrics:
                 "conv_row_starts": dict(self.conv_row_starts),
                 "conv_tokens": self.conv_tokens,
                 "kda_tokens": dict(self.kda_tokens),
+                "mamba1_tokens": dict(self.mamba1_tokens),
                 "moe_local_pairs": self.moe_local_pairs,
                 "moe_routed_tokens": self.moe_routed_tokens,
                 "moe_experts_read": self.moe_experts_read,
@@ -892,7 +902,7 @@ class SparseModelMetrics:
         self.moe_experts_held += int(a[3])
 
     def render(self, prefix: str = "dynamo_tpu") -> str:
-        if not self.dsa and not self.mla and not self.moe_routed_tokens:
+        if not (self.dsa or self.mla or self.moe_routed_tokens or self.mamba1_tokens):
             return ""
         lines = []
         if self.conv_tokens:
@@ -907,14 +917,16 @@ class SparseModelMetrics:
             lines += [f"# HELP {name} Tokens dispatched through the short-convolution layers "
                       "(a fused decode chunk counts its steps)",
                       f"# TYPE {name} counter", f"{name} {self.conv_tokens}"]
-        if self.kda_tokens:
-            name = f"{prefix}_kda_tokens_total"
-            lines += [f"# HELP {name} Tokens dispatched through the Kimi Delta Attention layers, "
+        for mixer, layers, acc in (("kda", "Kimi Delta Attention", self.kda_tokens),
+                                   ("mamba1", "Mamba-1", self.mamba1_tokens)):
+            if not acc:
+                continue
+            name = f"{prefix}_{mixer}_tokens_total"
+            lines += [f"# HELP {name} Tokens dispatched through the {layers} layers, "
                       "by form: scan (the chunked form of a unified step) or step (one token a "
                       "row; a fused decode chunk counts its steps)",
                       f"# TYPE {name} counter"]
-            lines += [f'{name}{{form="{escape_label(k)}"}} {v}'
-                      for k, v in sorted(self.kda_tokens.items())]
+            lines += [f'{name}{{form="{escape_label(k)}"}} {v}' for k, v in sorted(acc.items())]
         for acc, series in (
             (self.dsa, (
                 ("dsa_context_positions_total",

@@ -30,8 +30,10 @@ LLAMA_MODEL_TYPES = ("llama", "qwen2", "mistral", "mixtral")
 # which most keep a window of the last positions only (docs/k_exaone.md).
 # kimi_linear: Kimi Delta Attention layers (models/kda.py, state in slots as
 # Mamba-2's) among LATENT attention layers without rotation, whose pages are
-# the latent family's (docs/kimi_linear.md).
-HYBRID_MODEL_TYPES = ("lfm2_moe", "granitemoehybrid", "exaone_moe", "kimi_linear")
+# the latent family's (docs/kimi_linear.md).  jamba: Mamba-1 selective-scan
+# layers (models/mamba1.py, state in the same slots) among GQA layers of ONE
+# K/V head without rotation, a dense SwiGLU in every layer (docs/jamba.md).
+HYBRID_MODEL_TYPES = ("lfm2_moe", "granitemoehybrid", "exaone_moe", "kimi_linear", "jamba")
 
 
 @dataclass(frozen=True)
@@ -125,6 +127,12 @@ class ModelConfig:
     kda_head_dim: int = 0
     kda_conv: int = 0
     mla_rope: bool = True
+    # jamba (docs/jamba.md): a "mamba1" layer's inner width is ``mamba_expand``
+    # x hidden_size and its step size comes through a bottleneck of
+    # ``mamba_dt_rank`` (models/mamba1.py; ``mamba_d_state`` / ``mamba_d_conv``
+    # as above), both 0 elsewhere.
+    mamba_expand: int = 0
+    mamba_dt_rank: int = 0
 
     @property
     def is_moe(self) -> bool:
@@ -157,6 +165,8 @@ class ModelConfig:
             return cls._from_exaone_moe(cfg, name)
         if model_type == "kimi_linear":
             return cls._from_kimi_linear(cfg, name)
+        if model_type == "jamba":
+            return cls._from_jamba(cfg, name)
         if model_type in HYBRID_MODEL_TYPES:
             return cls._from_hybrid(cfg, name)
         if model_type is not None and model_type not in LLAMA_MODEL_TYPES:
@@ -531,6 +541,54 @@ class ModelConfig:
             kda_head_dim=lin["head_dim"],
             kda_conv=lin["short_conv_kernel_size"],
             mla_rope=False,
+        )
+
+    @classmethod
+    def _from_jamba(cls, cfg: Dict[str, Any], name: str) -> "ModelConfig":
+        """``jamba``'s keys (docs/jamba.md).  Layer l is attention where
+        ``l % attn_layer_period == attn_layer_offset`` and Mamba-1 elsewhere;
+        with ``num_experts`` 1 every layer's feed-forward is the dense SwiGLU
+        (``expert_layer_*`` then say nothing)."""
+        L = cfg["num_hidden_layers"]
+        period, offset = cfg["attn_layer_period"], cfg["attn_layer_offset"]
+        if period < 1 or not 0 <= offset < period:
+            raise ValueError(f"attn_layer_offset {offset} outside attn_layer_period {period}")
+        for key, want, why in (
+                ("num_experts", 1, "experts beside Mamba-1 layers are not served"),
+                ("mamba_proj_bias", False, "the mixer's projections carry no bias"),
+                ("mamba_conv_bias", True, "the taps carry a bias"),
+                ("sliding_window", None, "the attention layers keep every position"),
+                ("hidden_act", "silu", "SwiGLU")):
+            if cfg.get(key, want) != want:
+                raise ValueError(f"{key} {cfg[key]!r} is not supported ({why}: {want!r})")
+        D = cfg["hidden_size"]
+        rank = cfg.get("mamba_dt_rank", "auto")
+        num_heads = cfg["num_attention_heads"]
+        eos = cfg.get("eos_token_id", ())
+        if isinstance(eos, int):
+            eos = (eos,)
+        return cls(
+            name=name or cfg.get("_name_or_path", "hf-model"),
+            model_type=cfg["model_type"],
+            vocab_size=cfg["vocab_size"],
+            hidden_size=D,
+            num_layers=L,
+            num_heads=num_heads,
+            num_kv_heads=cfg.get("num_key_value_heads", num_heads),
+            head_dim=cfg.get("head_dim") or D // num_heads,
+            intermediate_size=cfg["intermediate_size"],
+            rms_norm_eps=cfg.get("rms_norm_eps", 1e-6),
+            max_position=cfg.get("max_position_embeddings", 262144),
+            tie_word_embeddings=cfg.get("tie_word_embeddings", True),
+            eos_token_ids=tuple(eos),
+            first_k_dense_replace=L,
+            layer_types=tuple(
+                "full_attention" if l % period == offset else "mamba1" for l in range(L)),
+            use_rope=False,
+            mamba_d_state=cfg.get("mamba_d_state", 16),
+            mamba_d_conv=cfg.get("mamba_d_conv", 4),
+            mamba_expand=cfg.get("mamba_expand", 2),
+            mamba_dt_rank=-(-D // 16) if rank == "auto" else rank,
         )
 
     @classmethod

@@ -195,20 +195,21 @@ def _unmovable_blocks(cfg: Any) -> list:
 def _hybrid(config: ModelConfig) -> ModelFamily:
     """models/lfm2.py: gated short convolutions whose state lives in the page
     cache beside the attention layers' K/V (resumed at a block's end), or
-    Mamba-2 or KDA layers whose state lives in slots (resumed at a snapshot);
+    Mamba-2, KDA or Mamba-1 layers whose state lives in slots (resumed at a snapshot);
     the attention layers' pages K/V or, for a model with MLA, latent entries."""
     from ..llm.metrics import sparse_model_metrics, swa_metrics
     from . import lfm2
 
     with_kda = lfm2.kda_layers(config) > 0
-    slotted = lfm2.mamba_layers(config) > 0 or with_kda
+    with_mamba1 = lfm2.mamba1_layers(config) > 0
+    slotted = lfm2.mamba_layers(config) > 0 or with_kda or with_mamba1
     windowed = lfm2.window_layers(config) > 0
     latent_pages = lfm2.latent_attention(config)
 
     def kinds(config, cache):
         """K/V bytes a token an attention layer; the convolution state's
         bytes a PAGE a convolution layer (it does not grow inside a page); a
-        Mamba-2 or KDA layer's state and tail bytes a SLOT; a latent entry's
+        Mamba-2, KDA or Mamba-1 layer's state and tail bytes a SLOT; a latent entry's
         bytes a token a latent layer."""
         if latent_pages:
             out = {"latent": cache.pages.shape[-1] * cache.pages.dtype.itemsize}
@@ -217,7 +218,7 @@ def _hybrid(config: ModelConfig) -> ModelFamily:
         if cache.conv is not None:
             out["conv_page"] = cache.conv.shape[2] * cache.conv.shape[3] * cache.conv.dtype.itemsize
         if cache.ssm is not None:
-            out["kda_slot" if with_kda else "ssm_slot"] = (
+            out["kda_slot" if with_kda else "mamba1_slot" if with_mamba1 else "ssm_slot"] = (
                 cache.ssm[0, 0].size * cache.ssm.dtype.itemsize)
             out["conv_tail"] = cache.tail[0, :, 0].size * cache.tail.dtype.itemsize
         if cache.window is not None:  # a token a WINDOW layer, for the last positions only
@@ -272,6 +273,9 @@ def _hybrid(config: ModelConfig) -> ModelFamily:
         sparse_model_metrics.add_kda("scan" if kind == "unified" else "step", ns)
         sparse_model_metrics.add_mla(kind, starts, ns)
 
+    def count_mamba1(config, kind, starts, ns, step_tokens=None):
+        sparse_model_metrics.add_mamba1("scan" if kind == "unified" else "step", ns)
+
     return ModelFamily(
         name="hybrid",
         init_params=lfm2.init_params,
@@ -287,7 +291,7 @@ def _hybrid(config: ModelConfig) -> ModelFamily:
         cache_kinds=kinds,
         check=check,
         # The slots' account is the block manager's (admissions, snapshots).
-        count_dispatch=count_kda if with_kda else None if slotted else count_window if windowed else (
+        count_dispatch=count_kda if with_kda else count_mamba1 if with_mamba1 else None if slotted else count_window if windowed else (
             lambda config, kind, starts, ns, step_tokens=None: (
                 sparse_model_metrics.add_conv(kind, starts, ns))),
         count_aux=sparse_model_metrics.add_moe,

@@ -29,6 +29,11 @@ layers are LATENT (MLA without rotation): the block is the latent family's own
 (``deepseek_v32.mla_block``), and ``HybridCache.pages`` then holds latent
 entries in that family's layout (leaf group ``mla`` in place of ``attn``).
 
+``jamba`` (docs/jamba.md) adds a SIXTH, ``mamba1``: the Mamba-1 selective scan
+(models/mamba1.py; ``mamba`` stays Mamba-2), its state in the same slots, GQA
+of ONE K/V head without rotation, and a dense SwiGLU in EVERY layer: no expert
+leaves at all.
+
 Beside models/llama.py and models/deepseek_v32.py, sharing ``linear``,
 ``rms_norm``, ``mlp``, ``embed_lookup``, ``lm_logits``, the attention ops of
 the dense family, the latent family's ``gate`` and the dispatch of
@@ -65,7 +70,7 @@ import jax.numpy as jnp
 from ..ops.ragged_attention import ragged_attention, write_kv_ragged
 from ..ops.rope import apply_rope, rope_frequencies
 from . import deepseek_v32 as latent
-from . import kda, mamba2
+from . import kda, mamba1, mamba2
 from .config import ModelConfig
 from .llama import RaggedBatch, embed_lookup, linear, lm_logits, mlp, rms_norm
 from .moe import expert_dispatch
@@ -87,8 +92,10 @@ QUANT_AXES = {
     "shared": {"w_gate": 1, "w_up": 1, "w_down": 1},
     "kda": kda.QUANT_AXES,
     "mla": {"wq": 1, "wkv_a": 1, "wo": 1},
+    "mamba1": mamba1.QUANT_AXES,
 }
-_ONES = ("op_norm", "ffn_norm", "q_norm", "k_norm", "final_norm", "kv_norm") + mamba2.ONES + kda.ONES
+_ONES = (("op_norm", "ffn_norm", "q_norm", "k_norm", "final_norm", "kv_norm") + mamba2.ONES
+         + kda.ONES + mamba1.ONES)
 EXPERT_LEAVES = latent.EXPERT_LEAVES
 
 
@@ -122,6 +129,10 @@ def kda_layers(config: ModelConfig) -> int:
     return sum(t == "kda" for t in config.layer_types)
 
 
+def mamba1_layers(config: ModelConfig) -> int:
+    return sum(t == "mamba1" for t in config.layer_types)
+
+
 def latent_attention(config: ModelConfig) -> bool:
     """The full-attention layers are latent (MLA): their pages hold latent
     entries, their leaves are the group ``mla``."""
@@ -133,7 +144,7 @@ def layer_counts(config: ModelConfig) -> Tuple[int, int, int, int]:
     Lc = sum(t == "conv" for t in config.layer_types)
     Ld = min(config.first_k_dense_replace, config.num_layers)
     La = (config.num_layers - Lc - mamba_layers(config) - window_layers(config)
-          - kda_layers(config))
+          - kda_layers(config) - mamba1_layers(config))
     return Lc, La, Ld, config.num_layers - Ld
 
 
@@ -168,7 +179,9 @@ class HybridCache(NamedTuple):
     such layers (a None leaf is no operand of a program).  A model with KDA
     layers keeps THEIR state in the same two leaves: ``ssm`` [Lk, slots, heads
     * d_key, d_value] float32, ``tail`` [Lk, taps - 1, slots, 3 * heads *
-    d_key].  With latent attention ``pages`` is [La, P, ps, latent width]: the
+    d_key]; one with Mamba-1 layers ``ssm`` [L1, slots, d_state, inner]
+    float32 (the 16 on sublanes), ``tail`` [L1, taps - 1, slots, inner].  With
+    latent attention ``pages`` is [La, P, ps, latent width]: the
     latent family's entries (``deepseek_v32.LatentKVCache.latent``).  ``window`` [Lw, Pw,
     ps, 2 * KV / pack, pack * head_dim]: the window layers' K/V, pages of a
     pool of their own in the K/V pages' dtype; None without such layers.  The shapes leave
@@ -196,13 +209,17 @@ class HybridCache(NamedTuple):
         else:
             page = (page_size, 2 * config.num_kv_heads // pack, pack * config.head_dim)
         # The slots' two leaves, by the mixer that lives in them (a model has one).
+        L1 = mamba1_layers(config)
         if Lk:
             Hk, dk, taps = kda.dims(config)
             state, tail = (Hk * dk, dk), (taps - 1, state_slots, kda.conv_width(config))
+        elif L1:
+            di, N, K, _ = mamba1.dims(config)
+            state, tail = (N, di), (K - 1, state_slots, di)
         else:
             _, Hm, P, N, K = mamba2.dims(config)
             state, tail = (Hm * P, N), (K - 1, state_slots, mamba2.conv_width(config))
-        Ls = Lm + Lk
+        Ls = Lm + Lk + L1
         return cls(
             pages=jnp.zeros((La, num_pages) + page, dtype),
             window=jnp.zeros((Lw, window_pages) + page, dtype) if Lw else None,
@@ -246,8 +263,9 @@ def leaf_shapes(config: ModelConfig) -> Dict[str, Dict[str, tuple]]:
         groups["dense"] = {"w_gate": (Ld, D, F), "w_up": (Ld, D, F), "w_down": (Ld, F, D)}
     # The bias steers the sigmoid gate's choice; [a | b] = W_1 x is (moe_gate | moe_up).
     bias = {} if c.gate_scoring == "softmax" else {"router_bias": (Lm, Et)}
-    groups["moe"] = {"router": (Lm, D, Et), **bias, "moe_gate": (Lm, E, D, Fm),
-                     "moe_up": (Lm, E, D, Fm), "moe_down": (Lm, E, Fm, D)}
+    if Lm:  # a model whose every feed-forward is dense has no such group
+        groups["moe"] = {"router": (Lm, D, Et), **bias, "moe_gate": (Lm, E, D, Fm),
+                         "moe_up": (Lm, E, D, Fm), "moe_down": (Lm, E, Fm, D)}
     if mamba_layers(c):
         groups["mamba"] = mamba2.leaf_shapes(c, mamba_layers(c))
     if c.shared_intermediate_size:
@@ -255,12 +273,15 @@ def leaf_shapes(config: ModelConfig) -> Dict[str, Dict[str, tuple]]:
         groups["shared"] = {"w_gate": (Lm, D, Fs), "w_up": (Lm, D, Fs), "w_down": (Lm, Fs, D)}
     if kda_layers(c):
         groups["kda"] = kda.leaf_shapes(c, kda_layers(c))
+    if mamba1_layers(c):
+        groups["mamba1"] = mamba1.leaf_shapes(c, mamba1_layers(c))
     return groups
 
 
 def _draw(config: ModelConfig, key: jax.Array, quant: bool) -> Params:
     return latent._draw(config, key, quant, leaf_shapes(config), QUANT_AXES, _ONES,
-                        draws={**mamba2.DRAWS, **kda.DRAWS})
+                        draws=mamba1.DRAWS if mamba1_layers(config) else
+                        {**mamba2.DRAWS, **kda.DRAWS})
 
 
 def init_params(config: ModelConfig, key: jax.Array) -> Params:
@@ -552,7 +573,20 @@ def forward_ragged(
 
         return kda_layer
 
-    slot_layers = {("mamba", False): mamba_layer,
+    @jax.jit
+    def mamba1_layer(l, m, h, ssm, tail, pairs, read):
+        """One Mamba-1 layer with its dense feed-forward, as ``mamba_layer``."""
+        x = rms_norm(h, params["layers"]["op_norm"][l], eps)
+        lp = at_layer("mamba1", m)
+        if decode:
+            y, ssm, tail = mamba1.step(x, lp, c, ssm, tail, m, real)
+        else:
+            y, ssm, tail = mamba1.scan(x, lp, c, ssm, tail, m, rows)
+        h = residual(h, y)
+        h = h + mlp(rms_norm(h, params["layers"]["ffn_norm"][l], eps), at_layer("dense", l))
+        return h, ssm, tail, pairs, read
+
+    slot_layers = {("mamba", False): mamba_layer, ("mamba1", True): mamba1_layer,
                    **{("kda", dense): kda_layer_with(dense) for dense in (False, True)}}
 
     h = embed_lookup(params, rb.token_ids, dt)
@@ -569,7 +603,7 @@ def forward_ragged(
     ci = ai = mi = wi = l = 0
     while l < c.num_layers:  # constant layer numbers: see models/llama.py on decode
         kind = c.layer_types[l]
-        if kind in ("mamba", "kda"):
+        if kind in ("mamba", "kda", "mamba1"):
             # A run of layers whose state lives in slots, of one mixer and one
             # kind of feed-forward.  The decode program unrolls it (its
             # weights stream: models/llama.py); a prompt program walks it as

@@ -93,6 +93,18 @@ def pytest_configure(config):
     )
 
 
+# Files that take minutes, first: under ``--dist loadfile`` a file goes whole to
+# one worker in collection order, and tests/test_tpu_compile.py (ten minutes
+# of compiles for a described chip) sorts near the end, so that one worker
+# began it when the others were nearly done and tier-1 ran into its time
+# limit (PR 56: cut at 1470 s twice; 587 s of that file alone).
+LONG_FILES_FIRST = ("tests/test_tpu_compile.py",)
+
+
+def pytest_collection_modifyitems(items):
+    items.sort(key=lambda item: not item.nodeid.startswith(LONG_FILES_FIRST))  # stable
+
+
 @pytest.fixture(scope="session")
 def cpu_devices():
     import jax

@@ -288,8 +288,9 @@ def _family_step(topo, config_file: str, pages_bytes: int, traced=None, prompt_t
 
     @functools.lru_cache(maxsize=None)
     def compiled(decode: bool):
-        params = on_chip(jax.eval_shape(
-            lambda k: fam.init_params_quantized(mc, k), jax.random.PRNGKey(0)))
+        # int8 leaves where the configuration serves them, the release's dtype elsewhere
+        init = fam.init_params_quantized if serve.get("weight_quant") else fam.init_params
+        params = on_chip(jax.eval_shape(lambda k: init(mc, k), jax.random.PRNGKey(0)))
         cache = on_chip(jax.eval_shape(lambda: fam.create_cache(
             mc, serve["num_blocks"], serve["block_size"],
             dtype=jnp.dtype(serve["kv_cache_dtype"]),
@@ -610,6 +611,64 @@ def test_kimi_linear_kda_metrics_match_the_scopes_ops_and_no_others(
     ``benchmark`` issue re-points it at the call's name)."""
     _metric_matches_its_scope_alone(
         kimi_linear_step, decode, metric, "kda_step" if decode else "kda_scan", holds=not decode)
+
+
+_JAMBA_SLOTS = 32 + 32768 * 16 // (5 * 512)  # max_batch live + lfm2.snapshot_slots
+_JAMBA_PAGES = 32768 * 16 * 2 * 128 * 2 * 2  # bfloat16 K/V of ONE head in the two attention layers
+_JAMBA_STATE = _JAMBA_SLOTS * 26 * (16 * 5120 * 4 + 3 * 5120 * 2)
+
+
+@pytest.fixture(scope="module")
+def jamba_step(topo):
+    """chipbench/configs/jamba2-3b.json whole (bfloat16 weights: the
+    configuration serves no ``weight_quant``) with the options the engine
+    resolves on a TPU and its 236 state slots."""
+    return _family_step(
+        topo, "chipbench/configs/jamba2-3b.json", _JAMBA_PAGES + _JAMBA_STATE,
+        state_slots=_JAMBA_SLOTS, attn_impl="tpu", decode_kernel="pallas_fused",
+        prefill_kernel="pallas")
+
+
+@pytest.mark.parametrize("decode", [False, True], ids=["unified-512", "decode-32"])
+def test_jamba_step_compiles_at_the_cells_shapes_without_copying_a_pool(
+    jamba_step, no_persistent_cache, decode
+):
+    """chipbench/configs/jamba2-3b.json: 6.06 GB of bfloat16 weights (28 of 28
+    layers, the whole vocabulary), 32768 K/V pages of ONE head in two attention
+    layers (0.54 GB) and 236 slots of 9.3 MB of Mamba-1 state and tails (2.2
+    GB), a 512-token chunk (or 32 decode rows): 8.8 GB of arguments, the 8.79
+    GB ISSUE 56 reckons.  The pages and both slot pools are updated in place:
+    the step's temporaries stay far under the 77 MB of ONE layer's state slots
+    (a copy of a pool, or of a layer of it, into or out of a step would show).
+    Attention goes through the dense family's two Pallas kernels at 20 query
+    heads over one K/V head, once a layer, and there is no other custom call:
+    no experts, and the recurrence is plain XLA under its scopes."""
+    compiled = jamba_step(decode)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _JAMBA_PAGES + _JAMBA_STATE
+    assert mem.temp_size_in_bytes < 0.06e9, mem
+    assert 8.7e9 < mem.argument_size_in_bytes < 8.9e9, mem
+    text = compiled.as_text()
+    calls = _custom_calls(text)
+    attn = "fused_decode_attention" if decode else "fused_prefill_attention"
+    assert len(calls) == 2 and all(attn in ln for ln in calls), calls
+    scope = "/mamba1_step/" if decode else "/mamba1_scan/"
+    assert scope in text and ("/mamba1_scan/" if decode else "/mamba1_step/") not in text
+    assert "/mamba1_taps/" in text
+
+
+@pytest.mark.parametrize("decode,metric", [(False, "mamba1_scan_time_share"),
+                                           (True, "mamba1_step_time_share")],
+                         ids=["unified-512", "decode-32"])
+def test_jamba_mamba1_metrics_match_the_scopes_ops_and_no_others(
+    jamba_step, no_persistent_cache, decode, metric
+):
+    """``mamba1_scan_time_share`` / ``mamba1_step_time_share`` (standing by)
+    against the scopes ``mamba1_scan`` / ``mamba1_step``: the recurrence's ops
+    alone (the taps and the tail lie under ``mamba1_taps``, which neither
+    pattern may match)."""
+    _metric_matches_its_scope_alone(
+        jamba_step, decode, metric, "/mamba1_step/" if decode else "/mamba1_scan/")
 
 
 @pytest.mark.parametrize("decode", [False, True], ids=["unified-512", "decode-16"])
