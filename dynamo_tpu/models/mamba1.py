@@ -37,6 +37,7 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..ops import mamba1_scan
 from . import mamba2
 from .config import ModelConfig
 from .llama import linear
@@ -92,44 +93,32 @@ def _project(u, lp: Params, c: ModelConfig, prev, dtype):
     return act, jax.nn.softplus(dt), norm(B, "b_norm"), norm(C, "c_norm")
 
 
-# Tokens a pass of ``scan``'s middle loop: the decays and the inputs of a chunk
-# are taken at once ([chunk, N, di] float32 each, 21 MB at 64), the recurrence
-# itself a token at a time; any size gives the same sums.
-SCAN_CHUNK = 64
+# Tokens a call of the recurrence (``ops/mamba1_scan.py``) at most: a row's
+# last block costs its tokens, a block past a row's end is not entered, and any
+# size gives the same sums.
+SCAN_CHUNK = 256
 
 
 def _recurrence(ssm, m, A, dt, B, C, cf, rows: Rows):
     """(y [T, di] float32 without the ``D c`` term, ssm): the recurrence over
-    the step's rows (``mamba2.walk_rows``), strictly in token order."""
+    the step's rows (``mamba2.walk_rows``), strictly in token order, a block of
+    a row's tokens one Pallas call that carries the state in registers and
+    leaves ``y_t`` where it found ``dt_t c_t``."""
     T, di = dt.shape
     N = A.shape[0]
     Q = min(SCAN_CHUNK, T)
-    pad = lambda a: jnp.pad(a, ((0, Q), (0, 0)))  # noqa: E731
-    dtp, dtc, Bp, Cp = pad(dt), pad(dt * cf), pad(B), pad(C)
+    whole = lambda a: jnp.pad(a, ((0, -T % mamba1_scan.SLACK),) + ((0, 0),) * (a.ndim - 1))  # noqa: E731
+    dt8, bc = whole(dt), whole(mamba1_scan.lane_broadcast(B, C))
 
     def chunk(k, carry, first, count):
         """Tokens [first + k Q, first + (k + 1) Q) of a row of ``count``."""
         y, state = carry
-        at0 = first + k * Q
-        valid = ((k * Q + jnp.arange(Q)) < count)[:, None]  # [Q, 1]
-        cut = lambda v: jax.lax.dynamic_slice_in_dim(v, at0, Q, axis=0)  # noqa: E731
-        # Past the row's end: no decay and no input, so the state stands.
-        decay = jnp.exp(jnp.where(valid, cut(dtp), 0.0)[:, None, :] * A[None])  # [Q, N, di]
-        inp = jnp.where(valid, cut(dtc), 0.0)[:, None, :] * cut(Bp)[:, :, None]
+        return mamba1_scan.mamba1_scan(state, A, dt8, y, bc, first + k * Q,
+                                       jnp.minimum(Q, count - k * Q), block=Q)
 
-        def token(S, t):
-            a, b, c_t = t
-            S = a * S + b
-            return S, jnp.sum(c_t[:, None] * S, axis=0)
-
-        state, yq = jax.lax.scan(token, state, (decay, inp, cut(Cp)), unroll=8)
-        old = jax.lax.dynamic_slice_in_dim(y, at0, Q, axis=0)
-        y = jax.lax.dynamic_update_slice_in_dim(y, jnp.where(valid, yq, old), at0, axis=0)
-        return y, state
-
-    y, ssm = mamba2.walk_rows(chunk, ssm, m, rows, Q, jnp.zeros((T + Q, di), jnp.float32),
-                              (N, di))
-    return y[:T], ssm
+    y, ssm = mamba2.walk_rows(chunk, ssm, m, rows, Q, whole(dt * cf), (N, di))
+    # A token of no row (the step's padding) has had no recurrence.
+    return jnp.where((rows.row_of < rows.count.shape[0])[:, None], y[:T], 0.0), ssm
 
 
 def _gated_out(y, z, lp: Params, dtype):

@@ -618,6 +618,22 @@ _JAMBA_PAGES = 32768 * 16 * 2 * 128 * 2 * 2  # bfloat16 K/V of ONE head in the t
 _JAMBA_STATE = _JAMBA_SLOTS * 26 * (16 * 5120 * 4 + 3 * 5120 * 2)
 
 
+# What XLA made of the prompt-side token loop (the names
+# ``mamba1_scan_time_share``'s file holds), off the path since the call
+# ``mamba1_scan`` took their place (PR 57): a block's decays, its inputs, a
+# token's update with its read-out.
+_JAMBA_SCAN_OPS_GONE = {"multiply_exponential_fusion f32[8,8,16,5120]",
+                        "broadcast_multiply_fusion f32[8,8,16,5120]",
+                        "multiply_reduce_fusion f32[5120]"}
+# The float ops left under the scope ``mamba1_scan`` beside the call: B and C
+# side by side and along the lanes, A, a row's state out of its slot and into
+# its live slot and its snapshot's.
+_JAMBA_SCAN_SCOPE = {"mamba1_scan f32[16,5120]", "pad_maximum_fusion f32[512,32]",
+                     "broadcast_in_dim f32[512,32,128]", "negate_bitcast_fusion f32[16,5120]",
+                     "bitcast_select_fusion f32[16,5120]", "select_bitcast_fusion f32[16,5120]",
+                     "bitcast_dynamic-update-slice_fusion f32[26,236,16,5120]"}
+
+
 @pytest.fixture(scope="module")
 def jamba_step(topo):
     """chipbench/configs/jamba2-3b.json whole (bfloat16 weights: the
@@ -641,8 +657,12 @@ def test_jamba_step_compiles_at_the_cells_shapes_without_copying_a_pool(
     the step's temporaries stay far under the 77 MB of ONE layer's state slots
     (a copy of a pool, or of a layer of it, into or out of a step would show).
     Attention goes through the dense family's two Pallas kernels at 20 query
-    heads over one K/V head, once a layer, and there is no other custom call:
-    no experts, and the recurrence is plain XLA under its scopes."""
+    heads over one K/V head, once a layer.  The prompt program's recurrence is
+    the call ``mamba1_scan`` under the scope ``mamba1_scan`` (ops/
+    mamba1_scan.py), once a run of Mamba layers (7, 13 and 6 of them, each run
+    ONE loop body), and none of the three ops XLA made of the token loop is
+    left; the decode program holds no such call, and neither holds another
+    custom call: no experts, the one-step form plain XLA."""
     compiled = jamba_step(decode)
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= _JAMBA_PAGES + _JAMBA_STATE
@@ -651,10 +671,45 @@ def test_jamba_step_compiles_at_the_cells_shapes_without_copying_a_pool(
     text = compiled.as_text()
     calls = _custom_calls(text)
     attn = "fused_decode_attention" if decode else "fused_prefill_attention"
-    assert len(calls) == 2 and all(attn in ln for ln in calls), calls
+    assert len([ln for ln in calls if attn in ln]) == 2, calls
+    scans = [ln for ln in calls if "%mamba1_scan" in ln]
+    assert len(scans) == (0 if decode else 3) and len(calls) == 2 + len(scans), calls
+    assert all("/mamba1_scan/" in ln.split("op_name=", 1)[1] for ln in scans), scans
     scope = "/mamba1_step/" if decode else "/mamba1_scan/"
     assert scope in text and ("/mamba1_scan/" if decode else "/mamba1_step/") not in text
     assert "/mamba1_taps/" in text
+    assert not _op_names(text) & _JAMBA_SCAN_OPS_GONE
+
+
+def test_jamba_mamba1_scan_scope_is_the_call_and_the_rows_bookkeeping(jamba_step, no_persistent_cache):
+    """Under the scope ``mamba1_scan`` the 512-token program computes in
+    float32 the call, its operands' layout and a row's slot reads and writes,
+    and nothing else: no decay and no input of a block of tokens is written
+    out ([tokens, 16, 5120] appears nowhere)."""
+    import re
+
+    text = jamba_step(False).as_text()
+    moved = {n for n in _op_names(text, "/mamba1_scan/")
+             if " f32[" in n and n.split(" ")[0] not in ("get-tuple-element", "while")}
+    assert moved == _JAMBA_SCAN_SCOPE, moved ^ _JAMBA_SCAN_SCOPE
+    assert not [n for n in _op_names(text)  # but the 26 layers' pool and ``A_log``
+                if re.search(r"f32\[\d+,(\d+,)?16,5120\]", n) and " f32[26," not in n]
+
+
+@pytest.mark.parametrize("tokens,block", [(512, 256), (16, 16)], ids=["of-512-tokens", "of-16-tokens"])
+def test_mamba1_scan_call_compiles_at_both_prompt_programs_shapes(sds, no_persistent_cache, tokens, block):
+    """ops/mamba1_scan.py alone at the cell's state [16, 5120] for a described
+    v5e: a block of the 512-token program's and the 16-token program's one
+    (what Mosaic refuses, an unaligned window or too much VMEM, raises here)."""
+    from dynamo_tpu.models import mamba1
+    from dynamo_tpu.ops import mamba1_scan as ks
+
+    assert block == min(mamba1.SCAN_CHUNK, tokens)
+    f32 = lambda *shape: sds(shape, jnp.float32)  # noqa: E731
+    compiled = jax.jit(functools.partial(ks._call, block=block, tile=ks.TILE, interpret=False)).lower(
+        f32(16, 5120), f32(16, 5120), f32(tokens, 5120), f32(tokens, 5120), f32(tokens, 32, 128),
+        sds((), jnp.int32), sds((), jnp.int32)).compile()
+    assert len([ln for ln in _custom_calls(compiled.as_text()) if "%mamba1_scan" in ln]) == 1
 
 
 @pytest.mark.parametrize("decode,metric", [(False, "mamba1_scan_time_share"),
@@ -666,9 +721,14 @@ def test_jamba_mamba1_metrics_match_the_scopes_ops_and_no_others(
     """``mamba1_scan_time_share`` / ``mamba1_step_time_share`` (standing by)
     against the scopes ``mamba1_scan`` / ``mamba1_step``: the recurrence's ops
     alone (the taps and the tail lie under ``mamba1_taps``, which neither
-    pattern may match)."""
+    pattern may match).  Since PR 57 the prompt side's arithmetic is the call
+    ``mamba1_scan``: the three XLA names its file holds are gone from the
+    512-token program, and its pattern matches a row's slot reads and writes
+    alone there (PERF.md section 7: the next ``benchmark`` issue re-points it
+    at the call's name)."""
     _metric_matches_its_scope_alone(
-        jamba_step, decode, metric, "/mamba1_step/" if decode else "/mamba1_scan/")
+        jamba_step, decode, metric, "/mamba1_step/" if decode else "/mamba1_scan/",
+        holds=decode, gone=_JAMBA_SCAN_OPS_GONE)
 
 
 @pytest.mark.parametrize("decode", [False, True], ids=["unified-512", "decode-16"])
@@ -774,17 +834,17 @@ def _custom_calls(text: str) -> list:
     return [ln for ln in text.splitlines() if "tpu_custom_call" in ln and " custom-call(" in ln]
 
 
-def _op_names(text: str) -> set:
+def _op_names(text: str, scope: str = "") -> set:
     """The short names (``chipbench.trace_reduce.short_name``: what the
     harness prints in ``breakdown.device_ops``) of a compiled program's ops
-    outside its fused computations."""
+    outside its fused computations; under ``scope`` alone where given."""
     from chipbench.trace_reduce import short_name
 
     names, fused = set(), False
     for ln in text.splitlines():
         if ln.endswith("{") and " -> " in ln:  # a computation's head
             fused = "fused" in ln.split(" ", 1)[0]
-        if not fused and " = " in ln:
+        if not fused and " = " in ln and scope in ln:
             names.add(short_name(ln.strip().removeprefix("ROOT ")))
     return names
 
