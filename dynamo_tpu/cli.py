@@ -219,6 +219,9 @@ async def _run_http_frontend(args) -> None:
 async def _run(args) -> None:
     inp = args.inp
     engine, level = _build_engine(args.out, args)
+    # The engine's account of its start (engine/phases.py): it ends where the
+    # HTTP service accepts.  Engines without one (echo) have nothing to close.
+    setup = getattr(engine, "setup", None)
     tokenizer = make_tokenizer(_tokenizer_spec(args))
 
     # Multi-host: followers only replay the leader's dispatch stream; the
@@ -384,7 +387,9 @@ async def _run(args) -> None:
             flush=True,
         )
         try:
-            await service.run(_stop_event())
+            await service.run(
+                _stop_event(), on_listening=setup.mark_ready if setup else None
+            )
         finally:
             if exporter is not None:
                 await exporter.stop()
@@ -506,6 +511,8 @@ async def _run(args) -> None:
                     "decode": _switch_decode,
                 },
             ).start()
+        if setup is not None:
+            setup.mark_ready()  # a worker is ready where its endpoint serves
         print(
             f"worker serving {inp} (model {args.model!r}"
             + (f", disagg={role}" if role else "")

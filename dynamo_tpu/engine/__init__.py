@@ -16,15 +16,17 @@ def build_tpu_engine(args):
     """CLI factory (``run out=tpu`` — reference: launch/dynamo-run engine
     selection, lib.rs:198-453).  Imports jax lazily."""
     from .engine import TpuEngine
+    from .phases import SetupAccount
 
+    # What came before this line is the process's ``import`` (engine/phases.py).
+    setup = SetupAccount(from_process_start=True)
     arch = getattr(args, "arch", None)
     checkpoint = getattr(args, "checkpoint", None)
     model_config_path = getattr(args, "model_config", None)
     if checkpoint:
-        # Resolve BEFORE anything else, like the reference's dynamo-run
-        # (launch/dynamo-run/src/lib.rs:125-130): local dirs pass through,
-        # names/repo-ids acquire via models/hub.py (HF snapshot or the
-        # pre-staged offline cache).
+        # Resolve BEFORE anything else, like the reference's dynamo-run (launch/
+        # dynamo-run/src/lib.rs:125-130): local dirs pass through, names/repo-ids
+        # acquire via models/hub.py (HF snapshot or the pre-staged offline cache).
         from ..models.hub import resolve_model
 
         args.checkpoint_source = checkpoint  # pre-resolution spec (registry)
@@ -36,14 +38,12 @@ def build_tpu_engine(args):
         and not checkpoint.endswith(".gguf")
         and not model_config_path
     ):
-        # The checkpoint's own config.json is the architecture source of
-        # truth (reference: MDC from checkpoint metadata).
+        # The checkpoint's own config.json is the architecture source of truth (as the MDC).
         from ..models.config import ModelConfig, register_config
 
         arch = register_config(ModelConfig.from_local_path(checkpoint)).name
     if checkpoint and checkpoint.endswith(".gguf") and not arch:
-        # GGUF carries its own architecture metadata (reference: the
-        # ModelDeploymentCard's gguf path, lib/llm/src/gguf/*).
+        # GGUF carries its own architecture metadata (reference: lib/llm/src/gguf/*).
         from ..models.config import register_config
         from ..models.gguf import GGUFFile
 
@@ -93,7 +93,7 @@ def build_tpu_engine(args):
     )
     if getattr(args, "kv_pull_mb", None) is not None:
         cfg.kv_pull_max_bytes = int(args.kv_pull_mb) << 20
-    engine = TpuEngine(cfg)
+    engine = TpuEngine(cfg, setup=setup)
     _load_adapters(engine, lora_adapters, getattr(args, "model", None))
     return engine
 

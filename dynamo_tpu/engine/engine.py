@@ -62,7 +62,7 @@ logger = logging.getLogger(__name__)
 
 from .migrate import MigrationMixin
 from .offload import HostOffloadMixin
-from .phases import PhaseAccount
+from .phases import PhaseAccount, SetupAccount
 from .pipeline import _FINISHED, DecodePipelineMixin
 from .spec import AcceptanceController, SpecDecodeMixin
 from .transfer import KvTransferMixin, _scales_close, transfer_blocks_device  # noqa: F401 — compat re-export
@@ -78,11 +78,11 @@ class TpuEngine(
         self,
         cfg: EngineConfig,
         event_callback: Optional[Callable[[KvCacheEvent], None]] = None,
-        params: Any = None,
+        params: Any = None, setup: Optional[SetupAccount] = None,
     ):
         self.cfg = cfg
         from .xla_cache import setup_compilation_cache
-
+        self.setup = setup if setup is not None else SetupAccount()  # the start's account
         self.compile_cache_dir = setup_compilation_cache()
         self.model_config: ModelConfig = get_config(cfg.model).with_overrides(
             dtype=cfg.dtype
@@ -182,7 +182,7 @@ class TpuEngine(
         self._device_lock = asyncio.Lock()
         self._rng = jax.random.PRNGKey(cfg.seed)
         self._steps = 0
-        self.warmup_s = 0.0  # wall of the last warmup() (0 = never warmed)
+        # (``warmup_s`` is the start's account's: ``self.setup.warm_s()``.)
         self._device_static: Optional[Dict[str, Any]] = None  # device_summary
         # Multi-host: leader broadcasts every dispatch over this plane so
         # followers keep their device queues in SPMD lockstep (multihost.py).
@@ -378,7 +378,7 @@ class TpuEngine(
                     tree, param_pspecs(self.model_config), self.mesh
                 )
             return jax.device_put(tree, jax.devices()[0])
-
+        self.setup.enter("build:params")  # the HOST's wall: the device fills them behind it
         if params is None:
             if cfg.checkpoint_path:
                 from ..models.loader import load_params
@@ -423,14 +423,14 @@ class TpuEngine(
             and self.mesh is None  # single-shard only (see fuse_projections)
         ):
             params = fam.fuse_projections(params)
+        self.setup.enter("build:other")
         if cfg.lora.enable:
-            # Fixed-shape multi-LoRA device banks (llm/tenancy/lora.py):
-            # R resident slots × rank-r A/B factors per attention
-            # projection, zero-initialized (an all-zero slot is exactly the
-            # base model).  Added AFTER quantize/fuse so the base tree is
-            # final — adapters are merge-free and never touch it.  The
-            # leaves live in params["layers"] so the layer scan slices them
-            # per layer like any other stacked weight.
+            # Fixed-shape multi-LoRA device banks (llm/tenancy/lora.py): R
+            # resident slots × rank-r A/B factors per attention projection,
+            # zero-initialized (an all-zero slot is exactly the base model).
+            # Added AFTER quantize/fuse so the base tree is final — adapters are
+            # merge-free and never touch it.  The leaves live in params["layers"]
+            # so the layer scan slices them per layer like any other stacked weight.
             if self.mesh is not None:
                 raise ValueError(
                     "lora.enable requires a single-shard engine in this "
@@ -444,10 +444,10 @@ class TpuEngine(
                 self.model_config, cfg.lora.max_adapters, cfg.lora.rank
             ).items():
                 params["layers"][name] = jnp.asarray(leaf, dt)
-        # A family with state slots gets its pools here, beside the pages.
-        # (Frames of this file are in the call stacks of every Mosaic kernel's
-        # lowered text, which keys the compile cache: moving ``warmup`` and
-        # what it calls costs every configuration one cold start.)
+        self.setup.enter("build:cache")
+        # A family with state slots gets its pools here, beside the pages.  (Frames of
+        # this file are in the call stacks of every Mosaic kernel's lowered text, which keys the
+        # compile cache: moving ``warmup`` and what it calls costs every configuration one cold start.)
         make_cache = partial(
             fam.create_cache, self.model_config, cfg.num_blocks, cfg.block_size,
             dtype=jnp.dtype(cfg.cache_dtype),
@@ -466,11 +466,11 @@ class TpuEngine(
             )()
         self.params = params
         self.cache = cache
-        # Quantized-scale resolution AFTER sharding: the calibration probe
-        # runs over the (possibly tp/dp-sharded) params on the engine's own
-        # mesh — a single-device probe would materialize the whole model on
-        # one chip, OOMing exactly the tp>1 configurations quantized KV
-        # exists for.
+        self.setup.enter("build:other")
+        # Quantized-scale resolution AFTER sharding: the calibration probe runs
+        # over the (possibly tp/dp-sharded) params on the engine's own mesh — a
+        # single-device probe would materialize the whole model on one chip,
+        # OOMing exactly the tp>1 configurations quantized KV exists for.
         if jnp.dtype(cfg.cache_dtype).itemsize == 1:
             if isinstance(cfg.kv_scale, str):
                 if cfg.kv_scale != "auto":
@@ -704,10 +704,10 @@ class TpuEngine(
         """Per-layer quantization scales from a probe forward: run a short
         deterministic token run through the model with a throwaway bf16
         cache, take each layer's max |K/V|, and map it to the target
-        dtype's representable max.  Runs on the engine's own mesh (sharded
-        params + sharded probe cache), so tp>1 models that don't fit one
-        chip calibrate fine; multi-host deployments pass the calibrated
-        vector explicitly via kv_scale."""
+        dtype's representable max.  Runs on the engine's own mesh (sharded params +
+        sharded probe cache), so tp>1 models that don't fit one chip calibrate fine;
+        multi-host deployments pass the calibrated vector explicitly via kv_scale."""
+        self.setup.enter("build:calibrate")  # its fetch is the first wait for the weights
         if jax.process_count() > 1:
             raise ValueError(
                 "kv_scale='auto' calibrates on one process; run calibration "
@@ -772,10 +772,10 @@ class TpuEngine(
         scales = np.maximum(maxabs / qmax, 1e-6).astype(np.float32)
         if windowed:
             scales = np.concatenate([np.maximum(k_max / qmax, 1e-6), gains]).astype(np.float32)
-        logger.info(
-            "calibrated per-layer kv scales (dtype %s): min %.4g max %.4g",
-            dt, scales.min(), scales.max(),
-        )
+        logger.info("calibrated per-layer kv scales (dtype %s): min %.4g max %.4g",
+                    dt, scales.min(), scales.max())
+        self.setup.enter("build:other")
+
         return scales
 
     def _kv_scale_repr(self):
@@ -908,7 +908,7 @@ class TpuEngine(
         process itself sees it (``/metrics`` dynamo_tpu_engine_* and the
         CLI's start-up line): a result that does not name its device
         cannot be compared with anything."""
-        from .xla_cache import cache_entries, cache_events
+        from .xla_cache import cache_entries, cache_events, compile_stages
 
         if self._device_static is None:
             # Fixed for the life of the process: looked up once, not on
@@ -946,12 +946,12 @@ class TpuEngine(
         stats = [d.memory_stats() or {} for d in jax.local_devices()]
         return {
             **self._device_static,
-            "warmup_s": self.warmup_s,
+            "warmup_s": round(self.setup.warm_s(), 3), "setup": self.setup.summary(),
             "compile_counts": self.compile_counts(),
             "compile_cache_dir": self.compile_cache_dir or "",
             "compile_cache_entries": cache_entries(self.compile_cache_dir),
-            "compile_cache_hits": cache_events["hits"],
-            "compile_cache_misses": cache_events["misses"],
+            "compile_cache_hits": cache_events["hits"], "compile_cache_misses": cache_events["misses"],
+            "jax_compile": {stage: dict(row) for stage, row in compile_stages.items()},
             "hbm_bytes_in_use": [s.get("bytes_in_use", 0) for s in stats],
             "hbm_bytes_limit": [s.get("bytes_limit", 0) for s in stats],
         }
@@ -1030,7 +1030,7 @@ class TpuEngine(
         request.  Returns compile_counts.
         """
         cfg = self.cfg
-        t_warm = time.monotonic()
+        self.setup.enter("warm:walk")  # the operands, then (behind the side-by-side pass) the walk
         steps, multi = self._warm_operands()
         self._compile_side_by_side(steps, multi)
         for ops in steps:
@@ -1054,9 +1054,9 @@ class TpuEngine(
         else:
             np.asarray(out.tokens)
         if self._sp_fn is not None:
-            # Every reachable sp-prefill token bucket (pow2, sp multiple,
-            # sp_prefill_min..max_model_len) — a cold whole-model compile
-            # must never land inside a request.
+            self.setup.enter("warm:sp")
+            # Every reachable sp-prefill token bucket (pow2, sp multiple, sp_prefill_min..
+            # max_model_len) — a cold whole-model compile must never land inside a request.
             lo = max(cfg.sp, 1 << (max(1, cfg.sp_prefill_min) - 1).bit_length())
             hi = max(lo, 1 << (cfg.max_model_len - 1).bit_length())
             t = lo
@@ -1071,7 +1071,7 @@ class TpuEngine(
                 if t >= hi:
                     break
                 t *= 2
-        self.warmup_s = round(time.monotonic() - t_warm, 3)
+        self.setup.enter("serve:listen")  # (until ``mark_ready``: cli.py, the service accepting)
         return self.compile_counts()
 
     def _compile_side_by_side(self, steps: List[Tuple], multi: Optional[Tuple]) -> None:
@@ -1079,17 +1079,17 @@ class TpuEngine(
         them SIDE BY SIDE into the persistent compilation cache, where there
         is one and no mesh (under a mesh the walk's operands are global
         arrays): each program is lowered with the operands the walk will call
-        it with (the trace is shared, so the walk lowers to the same text and
-        finds every executable in the cache) and compiled on a thread of its
-        own (XLA compiles outside the interpreter lock).  A cold start of nine
-        programs of half a minute each then takes about as long as the
-        slowest; a start that finds them in the cache pays one more lowering
-        a program (PERF.md section 6, PR 44, has both readings of every cell)."""
+        it with (the walk then finds every executable in the process's own
+        cache and lowers nothing again: 0.1-0.3 s in every cell, PERF.md
+        section 5, PR 58) and compiled on a thread of its own (XLA compiles
+        outside the interpreter lock).  A cold start of nine programs of half a
+        minute each then takes about as long as the slowest (PERF.md section 6,
+        PR 44, has both readings of every cell)."""
         if not self.compile_cache_dir or self.mesh is not None:
             return
         from concurrent.futures import ThreadPoolExecutor
 
-        t0 = time.monotonic()
+        self.setup.enter("warm:lower")
         # Lowered one after another on this thread (tracing holds the
         # interpreter lock anyway, and traces that interleave do not always
         # lower to the same text: one run in six missed the cache for three
@@ -1097,12 +1097,12 @@ class TpuEngine(
         lowered = [self._step_fn.lower(self.params, self.cache, *ops) for ops in steps]
         if multi is not None:
             lowered.append(self._multi_fn.lower(self.params, self.cache, *multi))
-        t_lowered = time.monotonic()
+        self.setup.enter("warm:compile")
         with ThreadPoolExecutor(len(lowered)) as pool:
             for done in [pool.submit(low.compile) for low in lowered]:
                 done.result()
-        logger.info("compiled %d programs side by side in %.1f s (lowering %.1f s)",
-                    len(lowered), time.monotonic() - t0, t_lowered - t0)
+        # (How long each half took is the account's: ``warm:lower``, ``warm:compile``.)
+        self.setup.enter("warm:walk")
 
     # ----------------------------------------------------------- tenancy API
     def register_adapter(self, adapter) -> None:

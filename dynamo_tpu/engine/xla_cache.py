@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import logging
 import os
+import threading
 from typing import Optional
 
 from .. import REPO_ROOT
@@ -34,8 +35,27 @@ CHECKOUT_CACHE_DIR = os.path.join(REPO_ROOT, ".xla_cache")
 
 # Persistent-cache hits and misses of this process, counted from JAX's own
 # monitoring events (reported on /metrics; chip_smoke.py reads them to show
-# that a second start really replayed from disk).
+# that a second start really replayed from disk).  A hit is one executable
+# read back from the directory; a miss is one executable compiled anew AND
+# written there (JAX records it at the write, so a process without a cache
+# directory counts neither).
 cache_events = {"hits": 0, "misses": 0}
+# JAX's own seconds for the WHOLE life of the process, by stage: sums of
+# WORK, not of wall (``warm:compile`` runs the stages on several threads at
+# once), lying inside the start's phases (engine/phases.py SETUP_PHASES) and,
+# where a program compiles after ``ready``, inside the serving loop.
+# ``backend_compile`` is JAX's ``compile_or_get_cached``: it holds the cache's
+# key and ``cache_retrieval`` where the cache answers, the compiler where not.
+STAGE_OF_EVENT = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval",
+}
+compile_stages = {
+    stage: {"seconds": 0.0, "events": 0} for stage in STAGE_OF_EVENT.values()
+}
+_stages_lock = threading.Lock()  # the side-by-side pass compiles on threads
 _listening = False
 
 
@@ -46,6 +66,15 @@ def _on_event(event: str, **_kw) -> None:
         cache_events["misses"] += 1
 
 
+def _on_duration(event: str, duration_secs: float, **_kw) -> None:
+    stage = STAGE_OF_EVENT.get(event)
+    if stage is not None:
+        row = compile_stages[stage]
+        with _stages_lock:
+            row["seconds"] += duration_secs
+            row["events"] += 1
+
+
 def setup_compilation_cache() -> Optional[str]:
     """Apply the rule above (idempotent); returns the active cache
     directory or None."""
@@ -54,6 +83,7 @@ def setup_compilation_cache() -> Optional[str]:
 
     if not _listening:
         jax.monitoring.register_event_listener(_on_event)
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
         _listening = True
 
     path = os.environ.get(CACHE_ENV)

@@ -18,7 +18,7 @@ import asyncio
 import json
 import logging
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Callable, Dict, Optional
 
 from aiohttp import web
 
@@ -243,8 +243,16 @@ class HttpService:
                     "%.0f" % ttft_p95_ms if ttft_p95_ms is not None else "-",
                 )
 
-    async def run(self, shutdown: Optional[asyncio.Event] = None) -> None:
+    async def run(
+        self,
+        shutdown: Optional[asyncio.Event] = None,
+        on_listening: Optional[Callable[[], None]] = None,
+    ) -> None:
+        """``on_listening`` is called once the socket accepts (the colocated
+        engine closes its start's account there: engine/phases.py)."""
         await self.start()
+        if on_listening is not None:
+            on_listening()
         try:
             if shutdown is None:
                 await asyncio.Event().wait()

@@ -726,9 +726,33 @@ class EngineDispatchMetrics:
                          "attention backend of the serving process")
             lines.append(f"# TYPE {en}_info gauge")
             lines.append(f"{en}_info{{{labels}}} 1")
-            lines.append(f"# HELP {en}_warmup_seconds Wall of engine warmup")
+            lines.append(f"# HELP {en}_warmup_seconds Wall of engine warmup "
+                         "(the warm:* phases of the start's account)")
             lines.append(f"# TYPE {en}_warmup_seconds gauge")
             lines.append(f"{en}_warmup_seconds {dev['warmup_s']}")
+            # The start's account (engine/phases.py SETUP_PHASES): the
+            # phases tile process start to ready; static once ready.
+            setup = dev.get("setup")
+            if setup:
+                lines.append(f"# HELP {en}_setup_phase_seconds Wall of one "
+                             "phase of the start; the phases tile process "
+                             "start to ready (docs/tracing.md)")
+                lines.append(f"# TYPE {en}_setup_phase_seconds gauge")
+                for phase, row in setup["phases"].items():
+                    lines.append(
+                        f'{en}_setup_phase_seconds{{phase="{escape_label(phase)}"}} '
+                        f"{row['sum']}"
+                    )
+                lines.append(f"# HELP {en}_setup_seconds Process start to "
+                             "ready: the sum of the start's phases closed so far")
+                lines.append(f"# TYPE {en}_setup_seconds gauge")
+                lines.append(f"{en}_setup_seconds {setup['seconds']}")
+                lines.append(f"# HELP {prefix}_process_start_time_seconds "
+                             "Start of the process since the epoch, as the "
+                             "operating system has it")
+                lines.append(f"# TYPE {prefix}_process_start_time_seconds gauge")
+                lines.append(f"{prefix}_process_start_time_seconds "
+                             f"{setup['process_start_time']}")
             lines.append(f"# HELP {en}_compiled_programs Compiled programs "
                          "per jitted entry (must not grow after warmup)")
             lines.append(f"# TYPE {en}_compiled_programs gauge")
@@ -742,11 +766,30 @@ class EngineDispatchMetrics:
             lines.append(
                 f"{en}_compile_cache_entries {dev['compile_cache_entries']}"
             )
-            for key in ("compile_cache_hits", "compile_cache_misses"):
-                lines.append(f"# HELP {en}_{key} Persistent compilation "
-                             "cache events in this process")
+            for key, what in (
+                ("compile_cache_hits", "executables read back from the "
+                 "persistent compilation cache"),
+                ("compile_cache_misses", "executables compiled anew and "
+                 "written to the persistent compilation cache"),
+            ):
+                lines.append(f"# HELP {en}_{key} Events of this process: "
+                             f"{what} (0 without a cache directory)")
                 lines.append(f"# TYPE {en}_{key} gauge")
                 lines.append(f"{en}_{key} {dev[key]}")
+            # JAX's own seconds by stage for the whole life of the process
+            # (engine/xla_cache.py): work on several threads, not wall.
+            stages = dev.get("jax_compile")
+            if stages:
+                for key, what in (("seconds", "Seconds of work"), ("events", "Events")):
+                    name = f"{en}_jax_compile_{key}_total"
+                    lines.append(f"# HELP {name} {what} JAX reported by "
+                                 "stage of compiling (work on several threads, "
+                                 "not wall; backend_compile holds cache_retrieval)")
+                    lines.append(f"# TYPE {name} counter")
+                    for stage, row in stages.items():
+                        lines.append(
+                            f'{name}{{stage="{escape_label(stage)}"}} {row[key]}'
+                        )
             for key in ("hbm_bytes_in_use", "hbm_bytes_limit"):
                 lines.append(f"# HELP {en}_{key} Device memory_stats() "
                              "per local device (0 where not reported)")

@@ -581,12 +581,12 @@ def test_cancel_while_token_fetch_in_flight():
     asyncio.run(main())
 
 
-def test_warmup_compiles_side_by_side_what_the_walk_calls(tmp_path, caplog):
+def test_warmup_compiles_side_by_side_what_the_walk_calls(tmp_path):
     """Warm-up is ONE path for every family: with a compile cache directory
     (and no mesh) the programs are lowered from the operands the walk uses
-    (``_warm_operands``) and compiled side by side first; the walk then adds
-    exactly the programs it adds without it, and a request runs on them."""
-    import logging
+    (``_warm_operands``) and compiled side by side first (the start's account
+    says so: ``warm:lower``, ``warm:compile``); the walk then adds exactly the
+    programs it adds without it, and a request runs on them."""
 
     async def main():
         plain = TpuEngine(EngineConfig(**CFG))
@@ -596,9 +596,11 @@ def test_warmup_compiles_side_by_side_what_the_walk_calls(tmp_path, caplog):
             steps, multi = engine._warm_operands()
             assert len(steps) == len(engine.reachable_token_buckets()) and multi is not None
             engine.compile_cache_dir = str(tmp_path)  # as on an accelerator backend
-            with caplog.at_level(logging.INFO, logger="dynamo_tpu.engine.engine"):
-                assert engine.warmup() == counts
-            assert f"compiled {len(steps) + 1} programs side by side" in caplog.text
+            assert engine.warmup() == counts
+            for account, passes in ((engine.setup, 1), (plain.setup, 0)):
+                rows = account.summary()["phases"]
+                assert rows["warm:lower"]["count"] == rows["warm:compile"]["count"] == passes
+                assert rows["warm:walk"]["count"] == 1 + passes
             assert await _generate(engine, [1, 2, 3, 4, 5]) == await _generate(plain, [1, 2, 3, 4, 5])
             assert engine.compile_counts() == counts
         finally:

@@ -8,7 +8,11 @@ count or a ratio of two clock readings of ONE stretch, never a duration:
 the phases of the loop's thread tile a session (their sums add up to its
 wall, none overlaps another, nothing is left bare), every phase of the
 account is in the trace under ``engine.<phase>``, and ``host_gap_frac`` is
-made from the account.
+made from the account.  Where the operating system takes the loop's thread
+between two phases (the driver runs six workers on these cores) the glue it
+stretches is a FEW long gaps: they are counted, and the sum is taken over
+the others, so a phase left out of an iteration (a gap in every one) still
+fails both tests of the tiling.
 """
 
 import asyncio
@@ -34,6 +38,16 @@ SEEN = tuple(p for p in LOOP_PHASES if p != "harvest:spec")
 CALLS_SEEN = tuple(c for c in DEVICE_CALLS if c != "fetch:spec")
 # The four names the benchmark read before the account (chipbench/README.md).
 OLD_NAMES = ("engine.schedule", "engine.dispatch:decode", "engine.harvest:decode", "engine.emit")
+SESSION = "engine.test_session"  # (trace_reduce keeps what begins with "engine.")
+# A gap between two phases longer than this is the operating system's (the
+# glue is 3 us of Python) or a hole in the tiling: the first are few.
+LONG_GAP_NS = 200_000
+
+
+def _few(gaps: list) -> int:
+    """How many long gaps the operating system may account for: one in fifty
+    (a hole in the tiling shows in every iteration, one gap in a dozen)."""
+    return max(1, len(gaps) // 50)
 
 
 def _loop_total(engine) -> float:
@@ -42,13 +56,17 @@ def _loop_total(engine) -> float:
 
 def _record_sessions(engine) -> list:
     """Per fused session: growth of the loop phases' sums, of the time in
-    ``harvest:*`` and of ``pipeline_wall_s``."""
+    ``harvest:*`` and of ``pipeline_wall_s``; on the profiler's clock the
+    session is the span ``SESSION`` (the test's own, around the engine's)."""
+    from jax.profiler import TraceAnnotation
+
     grown, run = [], engine._decode_pipeline
 
     async def session(members):
         t0, h0, w0 = _loop_total(engine), engine.phases.waited_s(), engine.pipeline_wall_s
         try:
-            return await run(members)
+            with TraceAnnotation(SESSION):
+                return await run(members)
         finally:
             grown.append((_loop_total(engine) - t0, engine.phases.waited_s() - h0,
                           engine.pipeline_wall_s - w0))
@@ -101,7 +119,9 @@ def traced(tmp_path_factory):
     out = asyncio.run(main())
     _, _, host = trace_reduce.load_xplane(
         trace_reduce.find_xplane(trace_dir), re.compile(r"^/host:CPU$"), lines=None)
-    out["annotations"] = sorted(host["annotations"], key=lambda e: e[1])
+    spans = sorted(host["annotations"], key=lambda e: e[1])
+    out["annotations"] = [e for e in spans if e[0] != SESSION]
+    out["session_spans"] = [e for e in spans if e[0] == SESSION]
     return out
 
 
@@ -126,7 +146,24 @@ def test_every_tiling_phase_of_a_session_with_churn_is_observed(traced):
 def test_the_phases_of_a_session_add_up_to_its_wall(traced):
     assert traced["sessions"], "no fused session ran"
     for phases_s, _, wall_s in traced["sessions"]:
-        assert wall_s > 0 and abs(phases_s - wall_s) <= 0.02 * wall_s, (phases_s, wall_s)
+        # no phase inside another: the sums cannot pass the wall they tile
+        assert 0 < phases_s <= wall_s * (1 + 1e-6), (phases_s, wall_s)
+    # What the wall holds beside the phases is the glue between them.  The
+    # same seams are on the profiler's clock (the account's clock reads are
+    # around the annotation, so a seam there is no shorter): the few that the
+    # operating system stretched are counted there and taken off the sum.
+    assert len(traced["session_spans"]) == len(traced["sessions"])
+    loop = _loop_thread(traced["annotations"])
+    gaps = []
+    for _, s0, d0, _ in traced["session_spans"]:
+        inside = [e for e in loop if e[1] >= s0 and e[1] + e[2] <= s0 + d0]
+        edges = [(s0, s0)] + [(s, s + d) for _, s, d, _ in inside] + [(s0 + d0, s0 + d0)]
+        gaps += [b[0] - a[1] for a, b in zip(edges, edges[1:])]
+    long_gaps = [g for g in gaps if g > LONG_GAP_NS]
+    assert len(long_gaps) <= _few(gaps), sorted(gaps)[-5:]
+    wall_s = sum(w for _, _, w in traced["sessions"])
+    glue_s = wall_s - sum(p for p, _, _ in traced["sessions"])
+    assert glue_s - sum(long_gaps) * 1e-9 <= 0.02 * wall_s, (glue_s, long_gaps, wall_s)
 
 
 def test_one_enqueue_and_one_jitted_call_a_fused_chunk(traced):
@@ -222,8 +259,9 @@ def test_the_loop_threads_phases_tile_no_two_overlap_and_nothing_is_left_bare(tr
         bare.append(s1 - (s0 + d0))
     # A hole in the tiling shows in every iteration (one gap in a dozen); the
     # operating system taking the thread between two phases shows once.
-    assert sum(1 for b in bare if b > 200_000) <= max(1, len(bare) // 50), sorted(bare)[-5:]
-    assert sum(bare) <= 0.02 * (last - first), (sum(bare), last - first)
+    assert sum(1 for b in bare if b > LONG_GAP_NS) <= _few(bare), sorted(bare)[-5:]
+    short = [b for b in bare if b <= LONG_GAP_NS]
+    assert sum(short) <= 0.02 * (last - first), (sum(short), last - first)
 
 
 def test_a_device_call_lies_inside_the_loop_phase_that_waits_for_it(traced):
